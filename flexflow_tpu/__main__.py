@@ -17,7 +17,7 @@ from flexflow_tpu.config import FFConfig
 def main(argv=None):
     argv = list(sys.argv[1:] if argv is None else argv)
     # --platform cpu [--cpu-devices N]: configure the backend BEFORE any
-    # jax backend touch (env vars alone can be overridden by site plugins)
+    # jax backend touch
     if "--platform" in argv:
         i = argv.index("--platform")
         platform = argv[i + 1]
@@ -46,6 +46,9 @@ def main(argv=None):
     import flexflow_tpu
 
     flexflow_tpu._driver_config = FFConfig.from_args(rest)
+    from flexflow_tpu.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.argv = [script] + rest
     runpy.run_path(script, run_name="__main__")
     return 0

@@ -667,6 +667,7 @@ class FFModel:
 
                 strategy = search_strategy(
                     self.graph, self._mesh, cfg, candidates_out=collect,
+                    stats_out=self.search_stats,
                 )
                 # every process must lower the identical strategy: ship
                 # process 0's search result to all (candidate pool too —
@@ -821,6 +822,10 @@ class FFModel:
         from flexflow_tpu.runtime import distributed as dist
 
         results = []  # (timed, modeled_rank, graph, strategy, executor)
+        # a candidate that fails to compile or run loses the playoff; on
+        # a chip that can be a kernel the compiler refused, so the count
+        # is reported (search_stats, strategy_validation), not just warned
+        self.search_stats["failed_candidates"] = 0
         for rank, (modeled, graph, strategy) in enumerate(candidates):
             try:
                 # candidates may alias the same Graph object (winner-vs-
@@ -851,7 +856,7 @@ class FFModel:
                 # the step donates (tr, ntr, opt): rebind every call
                 tr, ntr, opt_state, m = step(tr, ntr, opt_state, rng,
                                              labels, *inputs)
-                float(np.asarray(m["loss"]))  # sync (tunnel-safe)
+                float(np.asarray(m["loss"]))  # sync
                 t0 = _time.perf_counter()
                 for _ in range(3):
                     tr, ntr, opt_state, m = step(tr, ntr, opt_state, rng,
@@ -862,7 +867,13 @@ class FFModel:
             except Exception as e:  # an uncompilable candidate loses, only
                 import warnings
 
+                self.search_stats["failed_candidates"] += 1
                 warnings.warn(f"strategy candidate failed validation: {e}")
+            finally:
+                # one candidate's params + optimizer state at a time: at
+                # real sizes two copies do not fit the chips, and the
+                # second candidate would "fail validation" on memory
+                params = opt_state = tr = ntr = m = None
         if not results:
             _, g, s = candidates[0]
             return g, s, None

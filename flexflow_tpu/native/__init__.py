@@ -11,6 +11,7 @@ compiler is available: callers must check `available()`.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -23,14 +24,30 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _src_hash(src: str) -> str:
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def _build_so(src: str, lib_path: str, extra_flags=()) -> bool:
-    """Compile `src` to `lib_path` if stale; atomic tmp+replace so a
-    concurrent process never dlopens a partially written .so."""
+    """Compile `src` to `lib_path` unless the library on disk was built
+    from exactly this source: the sha256 of the source it came from sits
+    beside it in `<lib>.src-sha256` (native/Makefile writes the same
+    sidecar). File times say nothing — a copy or a checkout scrambles
+    them — so a library without a matching sidecar is rebuilt, never
+    loaded. Atomic tmp+replace, so a concurrent process never dlopens a
+    partially written .so."""
     src = os.path.abspath(src)
     if not os.path.exists(src):
         return False
-    if os.path.exists(lib_path) and os.path.getmtime(lib_path) >= os.path.getmtime(src):
-        return True
+    want = _src_hash(src)
+    stamp = lib_path + ".src-sha256"
+    try:
+        with open(stamp) as f:
+            if os.path.exists(lib_path) and f.read().strip() == want:
+                return True
+    except OSError:
+        pass
     tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
@@ -39,6 +56,9 @@ def _build_so(src: str, lib_path: str, extra_flags=()) -> bool:
             check=True, capture_output=True, timeout=120,
         )
         os.replace(tmp, lib_path)
+        with open(tmp, "w") as f:
+            f.write(want + "\n")
+        os.replace(tmp, stamp)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -97,6 +117,17 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def engine() -> str:
+    """Which search engine this process runs, for entry points that
+    must say so (chip_smoke.py): the C++ one, or the pure-Python
+    fallback and why."""
+    if available():
+        return "native (libffsim.so, built from native/ffsim.cc)"
+    if os.environ.get("FLEXFLOW_NATIVE", "1") == "0":
+        return "python (FLEXFLOW_NATIVE=0)"
+    return "python (libffsim.so could not be built or loaded: no g++?)"
 
 
 class NativeSimGraph:

@@ -28,7 +28,7 @@ from flexflow_tpu.obs.ledger import TickLedger, parse_shape_key
 
 # Report schema: v2 added the created-at stamp consumers use for
 # staleness (search/servesearch.py refuses reports older than its
-# max-age window, mirroring bench.py's last-green guard).
+# max-age window).
 CALIBRATION_SCHEMA_VERSION = 2
 
 

@@ -63,18 +63,18 @@ def _on_duration_event(name: str, seconds: float, **_kw) -> None:
 
 def _install_listener() -> bool:
     """Register the compile-event listener once per process; False when
-    this jax build doesn't expose the monitoring hook (the wrapper then
-    falls back to cache-size deltas)."""
+    jax cannot be imported (the wrapper then falls back to cache-size
+    deltas — the tracker itself works on plain callables)."""
     if _listener_state["installed"] is None:
         with _install_lock:
             if _listener_state["installed"] is None:
                 try:
-                    from jax._src import monitoring
+                    import jax.monitoring
 
-                    monitoring.register_event_duration_secs_listener(
+                    jax.monitoring.register_event_duration_secs_listener(
                         _on_duration_event)
                     _listener_state["installed"] = True
-                except Exception:
+                except ImportError:
                     _listener_state["installed"] = False
     return _listener_state["installed"]
 

@@ -257,12 +257,22 @@ def fused_attention(q, k, v, *, causal, scale, dropout=0.0, dropout_rng=None,
     if avail and single:
         LAST_ATTENTION_KERNEL = "pallas_flash"
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               interpret=True if force_interp else None)
+                               interpret=force_interp)
     if avail and not single:
         LAST_ATTENTION_KERNEL = "pallas_flash_shard_map"
         return _sharded_flash(q, k, v, mesh, causal, scale,
                               interpret=force_interp)
     LAST_ATTENTION_KERNEL = "xla_dot_product"
+    if jax.default_backend() == "tpu":
+        # on a TPU the S x T score matrix in HBM is a degraded mode
+        # somebody has to see, not a quiet alternative
+        import logging
+
+        logging.getLogger(__name__).warning(
+            "attention: Pallas flash kernel unavailable for q %s / k %s "
+            "(dropout=%s, FF_TPU_NO_FLASH=%s); using XLA dot-product "
+            "attention", q.shape, k.shape, dropout,
+            os.environ.get("FF_TPU_NO_FLASH"))
     return _dot_product_attention(q, k, v, causal, scale,
                                   dropout_rate=dropout, dropout_rng=dropout_rng)
 

@@ -588,7 +588,7 @@ def flash_attention_available(S: int, T: int, *, dropout: float = 0.0,
 def flash_attention(q, k, v, *, causal: bool = False, scale: float = 1.0,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     """Flash attention. q: (B,S,H,D); k,v: (B,T,Hkv,D) with H % Hkv == 0.
     Returns (B,S,H,D) in q.dtype; softmax statistics accumulate in fp32.
 
@@ -597,7 +597,9 @@ def flash_attention(q, k, v, *, causal: bool = False, scale: float = 1.0,
     128-aligned lane block, so neither a head-major transpose nor a
     kv-head repeat ever materializes in HBM (GQA is resolved by the index
     maps). Smaller head dims fall back to the padded (BH,S,D) transpose
-    path. Default blocking is picked by head dim (measured on v5e,
+    path. `interpret=True` runs the Pallas interpreter (CPU tests);
+    it is never chosen for the caller — off a TPU the compiled kernel
+    simply fails. Default blocking is picked by head dim (measured on v5e,
     fwd+bwd at S=1024-4096): d<=64 runs ~16-20% faster at 1024x1024
     blocks, while d=128 doubles the VMEM footprint per tile and prefers
     512x512."""
@@ -605,8 +607,6 @@ def flash_attention(q, k, v, *, causal: bool = False, scale: float = 1.0,
     T, Hkv = k.shape[1], k.shape[2]
     if H % Hkv != 0:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if block_q is None:
         block_q = 1024 if D <= 64 else 512
     if block_k is None:

@@ -31,6 +31,17 @@ horizon (pos + q_len - 1) are skipped, as are padded batch entries
 (rep, S, D) block, so kv pages are read once per GROUP and never
 repeated.
 
+Pool layout: FLAT-LANE pages, (num_pages, page_size, Hkv * D) — a cache
+row is one token's K (or V) for every kv head side by side on the lane
+dim, the same layout the flash kernels read projections in
+(ops/pallas/flash_attention.py). The kernel's page block is
+(page_size, D) and the kv head coordinate picks its 128-aligned lane
+block, which is the only way Mosaic can window one head of a page: a
+block that squeezes the head out of a second-minor (…, Hkv, D) dim is
+refused, and reshaping a head-minor pool at the pallas_call boundary
+is a physical relayout of the whole pool under TPU tiling, per layer
+per step. The append is one Hkv*D-lane row per token.
+
 The single pure-JAX fallback (`ragged_gather_attention`) gathers
 ``pool[page_table]`` and applies the same visibility as a materialized
 (B, S, L) mask (`ragged_visibility_mask`) — it runs anywhere and is the
@@ -71,14 +82,19 @@ def _reject(reason: str, cfg: tuple) -> bool:
     discipline: a silent fallback looks like a 10x paged-decode
     slowdown with no explanation in any log). Keying on the gate config
     too means two servers with different shapes each get their own
-    line."""
+    line. On a TPU backend the line is a WARNING — there the gather
+    path is a degraded mode somebody has to see; elsewhere (CPU tests)
+    it is the expected path and stays at INFO."""
     key = (reason, cfg)
     if key not in _fallback_logged:
         _fallback_logged.add(key)
-        logger.info(
+        level = (logging.WARNING if jax.default_backend() == "tpu"
+                 else logging.INFO)
+        logger.log(
+            level,
             "paged attention: ragged Pallas kernel rejected (%s) for "
-            "gate config %s; using the jnp.take gather fallback",
-            reason, cfg)
+            "gate config (head_dim, page_size, pool dtype, backend)=%s; "
+            "using the jnp.take gather fallback", reason, cfg)
     return False
 
 
@@ -90,13 +106,16 @@ def paged_attention_available(head_dim: int, page_size: int,
     (there is no per-variant rejection matrix any more).
     FF_TPU_NO_PAGED=1 disables the kernel everywhere (A/B runs and
     kernel-bug escape hatch, like FF_TPU_NO_FLASH). On real TPUs the
-    head dim must be a lane multiple (the kernel reads lane-aligned D
-    blocks; smaller head dims take the gather fallback, mirroring the
-    flash bshd gate) and pages must tile the sublane dim AT THE POOL'S
-    DTYPE — (8, 128) tiles for fp32 but (16, 128) for bf16/fp16 and
-    (32, 128) for int8/fp8, so a bf16 pool needs page_size % 16 == 0
-    and a QUANTIZED int8 pool (kv_dtype="int8", paged/quant.py) needs
-    page_size % 32 == 0 for the kernel's dequant-on-load path.
+    head dim must be a lane multiple (the kernel windows one head's
+    D-wide lane block out of a flat-lane page row; smaller head dims
+    take the gather fallback, mirroring the flash bshd gate) and pages
+    must tile the sublane dim AT THE POOL'S DTYPE — (8, 128) tiles for
+    fp32 but (16, 128) for bf16/fp16 and (32, 128) for int8/fp8, so a
+    bf16 pool needs page_size % 16 == 0 and a QUANTIZED int8 pool
+    (kv_dtype="int8", paged/quant.py) needs page_size % 32 == 0. These
+    are the shapes Mosaic compiled and the gather reference confirmed on
+    a v5e (tools/chip_kernels.py): decode, chunk and tree windows, GQA
+    with 8 kv heads, every pool dtype at one-tile and 128-row pages.
     Rejections log their concrete reason once per (reason, config)."""
     dt = jnp.dtype(dtype)
     cfg = (head_dim, page_size, dt.name, jax.default_backend())
@@ -164,7 +183,7 @@ def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
     """Pure-JAX fallback AND numerical reference for the ragged kernel:
     gather every table-mapped page (`pool[page_table]`) and run dense
     masked dot-product attention under ragged_visibility_mask. q:
-    (B, S, H, D); kc/vc_pages: (N, P, Hkv, D); page_tables:
+    (B, S, H, D); kc/vc_pages: (N, P, Hkv*D); page_tables:
     (B, max_pages) int32; pos/q_lens: (B,) int32; anc_mask: (B, S, S)
     bool. For a quantized pool, k_scales/v_scales are the (N, Hkv)
     per-page sidecar (paged/quant.py) and the gathered int8 pages are
@@ -174,7 +193,7 @@ def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
     q_len bookkeeping already discards, exactly like the kernel's
     zero rows."""
     B, S, _, D = q.shape
-    Hkv = kc_pages.shape[2]
+    Hkv = kc_pages.shape[2] // D
     P = kc_pages.shape[1]
     dt = q.dtype
     if k_scales is not None:
@@ -210,72 +229,91 @@ def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
 # query lengths prefetched; window visibility derived in-kernel
 
 
-def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref,
-                   anc_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
-                   page_size, n_pages, window, ks_ref=None,
-                   vs_ref=None):
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref, *rest,
+                   scale, page_size, n_pages, rep, quantized):
+    """One (batch entry, kv head, page) grid step. Every tile is 2-D —
+    (rows, D) q per head of the group, (P, D) K/V page, (rows, P)
+    scores — the only shapes Mosaic's matmul takes; the q heads of the
+    group are a static loop over the leading block dim."""
+    if quantized:
+        ks_ref, vs_ref, anc_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        anc_ref, o_ref, m_scr, l_scr, acc_scr = rest
     b, j = pl.program_id(0), pl.program_id(2)
+    rows, window = anc_ref.shape
 
     @pl.when(j == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    pos = pos_ref[b]
     qlen = qlen_ref[b]
     # pages wholly past the slot's visible horizon (committed prefix +
     # its own q_len window rows) contribute nothing, and padded batch
     # entries (q_len == 0) do no work at all — skip the MXU work
     # entirely (the masked-out math would be exp(-inf) = 0)
-    @pl.when((j * page_size <= pos_ref[b] + qlen - 1) & (qlen > 0))
+    @pl.when((j * page_size <= pos + qlen - 1) & (qlen > 0))
     def _():
-        q = q_ref[...]                       # (rep, S, D)
         k = k_ref[...]                       # (P, D)
         v = v_ref[...]
-        if ks_ref is not None:
-            # quantized pool: this grid step's page/head scale rode in
-            # as a (1, 1) block addressed by the SAME prefetched-table
-            # index map as the page itself, so dequant-on-load is one
-            # broadcast multiply in VMEM — the int8 page is what DMA'd
-            # from HBM, the fp K/V never round-trips
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0]
-        elif k.dtype != q.dtype:
-            # mixed-precision pool (e.g. bf16 kv_dtype under an fp32
-            # model): dot_general needs matching operand dtypes
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-        s = lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
         # window visibility without a gather and without an HBM mask:
         # column c holds cache row j*P + c, i.e. window index
         # rel[c] = j*P + c - pos. One-hot it against the window rows
-        # (zeroing indices past q_len) and contract with the (S, S)
-        # anc relation: (anc @ onehot)[t, c] = anc[t, rel[c]] when
-        # 0 <= rel[c] < q_len, else 0.
-        col = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (window, page_size), 1)          # (S, P) abs row
-        rel = col - pos_ref[b]
-        krow = lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
-        onehot = ((rel == krow) & (krow < qlen)).astype(jnp.float32)
+        # (zeroing indices past q_len) and contract with the anc
+        # relation: (anc @ onehot)[t, c] = anc[t, rel[c]] when
+        # 0 <= rel[c] < q_len, else 0. The contraction dim is the
+        # window padded to a lane multiple, so the matmul is aligned.
+        wrow = lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
+        rel = j * page_size - pos + lax.broadcasted_iota(
+            jnp.int32, (window, page_size), 1)
+        onehot = ((rel == wrow) & (wrow < qlen)).astype(jnp.float32)
         tree_vis = lax.dot_general(
             anc_ref[...], onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) > 0.5   # (S, P)
-        vis = (col < pos_ref[b]) | tree_vis
-        s = jnp.where(vis[None], s, NEG_INF)
-        m_prev = m_scr[:, :, 0:1]
-        l_prev = l_scr[:, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
-        pv = lax.dot_general(p.astype(v.dtype), v,
-                             (((2,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            preferred_element_type=jnp.float32) > 0.5   # (rows, P)
+        col = j * page_size + lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
+        vis = (col < pos) | tree_vis
+        if quantized:
+            # quantized pool: the page's per-head scale rode in as a
+            # (1, P) row addressed by the same (b, j) as the page, so
+            # dequant-on-load is a row-broadcast multiply on the SCORES
+            # and the PROBABILITIES — the int8 page is what DMA'd from
+            # HBM and what the MXU contracts; fp K/V never exist
+            cdt = jnp.float32
+        else:
+            # a mixed-precision pool (e.g. bf16 kv_dtype under an fp32
+            # model) computes at q's dtype: dot_general needs matching
+            # operand dtypes
+            cdt = q_ref.dtype
+        k = k.astype(cdt)
+        v = v.astype(cdt)
+        for r in range(rep):  # fflint: host-ok (static unroll in the kernel trace)
+            q = q_ref[r].astype(cdt)         # (rows, D)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                s = s * ks_ref[...]
+            s = jnp.where(vis, s, NEG_INF)
+            m_prev = m_scr[r, :, 0:1]
+            l_prev = l_scr[r, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            if quantized:
+                p = p * vs_ref[...]
+            pc = p.astype(cdt)  # fflint: dtype-ok (this head's p, in VMEM)
+            pv = lax.dot_general(pc, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            acc_scr[r] = acc_scr[r] * corr + pv
+            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     # finalize UNCONDITIONALLY: a padded entry whose every page was
     # skipped must still write (zeros), not leave o_ref as garbage —
@@ -284,22 +322,11 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref,
     # the compute loop cannot skip them row-wise)
     @pl.when(j == n_pages - 1)
     def _():
-        l_safe = jnp.maximum(l_scr[:, :, 0:1], 1e-30)
-        live = lax.broadcasted_iota(jnp.int32, acc_scr.shape, 1) < qlen
-        o_ref[...] = jnp.where(live, acc_scr[:] / l_safe,
-                               0.0).astype(o_ref.dtype)
-
-
-def _ragged_kernel_quant(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref,
-                         ks_ref, vs_ref, anc_ref, o_ref, m_scr, l_scr,
-                         acc_scr, *, scale, page_size, n_pages, window):
-    """Positional-arity shim for the quantized launch: same body, two
-    extra (1, 1) scale blocks between the pool inputs and the anc
-    relation (matching the in_specs order below)."""
-    _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref,
-                   anc_ref, o_ref, m_scr, l_scr, acc_scr, scale=scale,
-                   page_size=page_size, n_pages=n_pages, window=window,
-                   ks_ref=ks_ref, vs_ref=vs_ref)
+        live = lax.broadcasted_iota(jnp.int32, acc_scr.shape[1:], 0) < qlen
+        for r in range(rep):  # fflint: host-ok (static unroll in the kernel trace)
+            l_safe = jnp.maximum(l_scr[r, :, 0:1], 1e-30)
+            o_ref[r] = jnp.where(live, acc_scr[r] / l_safe,
+                                 0.0).astype(o_ref.dtype)
 
 
 def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
@@ -308,45 +335,49 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                            v_scales=None):
     """The ragged Pallas launch. q: (B, S, H, D) — S is the launch's
     window width, per-entry real work is q_lens[b] <= S rows;
-    kc/vc_pages: (N, P, Hkv, D); page_tables: (B, max_pages); pos,
-    q_lens: (B,); anc_mask: (B, S, S) bool window visibility. The page
-    table, positions AND query lengths ride scalar prefetch, so each
-    grid step's BlockSpec index map resolves `pt[b, j]` BEFORE the DMA
-    and the horizon/padding skip predicates on prefetched scalars. The
-    anc relation is one (S, S) VMEM block per batch entry — the only
-    mask state, O(B*S^2) instead of the old (B, S, L) HBM add_mask.
-    For a quantized pool, k_scales/v_scales are the (N, Hkv) sidecar;
-    each grid step's (page, head) scale arrives as a (1, 1) block
-    through the SAME pt[b, j] index map as its page, and the kernel
-    dequantizes in VMEM (paged/quant.py has the layout story). Rows at
+    kc/vc_pages: (N, P, Hkv*D) flat-lane pages (module docstring);
+    page_tables: (B, max_pages); pos, q_lens: (B,); anc_mask: (B, S, S)
+    bool window visibility. The page table, positions AND query lengths
+    ride scalar prefetch, so each grid step's BlockSpec index map
+    resolves `pt[b, j]` BEFORE the DMA and the horizon/padding skip
+    predicates on prefetched scalars; the kv head coordinate picks the
+    page's D-wide lane block. The anc relation is one VMEM block per
+    batch entry — the only mask state, O(B*S^2) instead of a (B, S, L)
+    HBM mask. q rows are padded to the sublane tile and the window to a
+    lane multiple so every in-kernel matmul is tile-aligned. For a
+    quantized pool, k_scales/v_scales are the (N, Hkv) sidecar: the
+    table-mapped scales are gathered here (B * max_pages * Hkv floats)
+    and each grid step reads its page's scale as a (1, P) row. Rows at
     or past q_lens[b] output zeros."""
     B, S, H, D = q.shape
-    N, P, Hkv, _ = kc_pages.shape
+    P = kc_pages.shape[1]
+    Hkv = kc_pages.shape[2] // D
     rep = H // Hkv
     n_pages = page_tables.shape[1]
-    qr = q.transpose(0, 2, 1, 3).reshape(B, Hkv, rep, S, D)
-    anc_f = anc_mask.astype(jnp.float32)
+    rows = _round_up(S, 8 * (4 // q.dtype.itemsize))
+    window = _round_up(S, LANES)
+    qr = jnp.pad(q.transpose(0, 2, 1, 3),
+                 ((0, 0), (0, 0), (0, rows - S), (0, 0)))   # (B, H, rows, D)
+    anc_f = jnp.pad(anc_mask.astype(jnp.float32),
+                    ((0, 0), (0, rows - S), (0, window - S)))
+    quantized = k_scales is not None
 
+    qmap = lambda b, g, j, pt, ps, ql: (b, g, 0, 0)         # noqa: E731
+    kvmap = lambda b, g, j, pt, ps, ql: (pt[b, j], 0, g)    # noqa: E731
     in_specs = [
-        pl.BlockSpec((None, None, rep, S, D),
-                     lambda b, g, j, pt, ps, ql: (b, g, 0, 0, 0)),
-        pl.BlockSpec((None, P, None, D),
-                     lambda b, g, j, pt, ps, ql: (pt[b, j], 0, g, 0)),
-        pl.BlockSpec((None, P, None, D),
-                     lambda b, g, j, pt, ps, ql: (pt[b, j], 0, g, 0)),
+        pl.BlockSpec((None, rep, rows, D), qmap),
+        pl.BlockSpec((None, P, D), kvmap),
+        pl.BlockSpec((None, P, D), kvmap),
     ]
     operands = [qr, kc_pages, vc_pages]
-    kernel = _ragged_kernel
-    if k_scales is not None:
-        in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda b, g, j, pt, ps, ql: (pt[b, j], g)),
-            pl.BlockSpec((1, 1),
-                         lambda b, g, j, pt, ps, ql: (pt[b, j], g)),
-        ]
-        operands += [k_scales, v_scales]
-        kernel = _ragged_kernel_quant
-    in_specs.append(pl.BlockSpec((None, S, S),
+    if quantized:
+        smap = lambda b, g, j, pt, ps, ql: (b, g, j, 0, 0)  # noqa: E731
+        for sc in (k_scales, v_scales):  # fflint: host-ok (trace-time, K then V)
+            rows_sc = sc[page_tables].transpose(0, 2, 1)    # (B, Hkv, pages)
+            operands.append(jnp.broadcast_to(
+                rows_sc[..., None, None], (B, Hkv, n_pages, 1, P)))
+            in_specs.append(pl.BlockSpec((None, None, None, 1, P), smap))
+    in_specs.append(pl.BlockSpec((None, rows, window),
                                  lambda b, g, j, pt, ps, ql: (b, 0, 0)))
     operands.append(anc_f)
 
@@ -354,23 +385,23 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
         num_scalar_prefetch=3,
         grid=(B, Hkv, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, rep, S, D),
-                               lambda b, g, j, pt, ps, ql: (b, g, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, rep, rows, D), qmap),
         scratch_shapes=[
-            pltpu.VMEM((rep, S, LANES), jnp.float32),
-            pltpu.VMEM((rep, S, LANES), jnp.float32),
-            pltpu.VMEM((rep, S, D), jnp.float32),
+            pltpu.VMEM((rep, rows, LANES), jnp.float32),
+            pltpu.VMEM((rep, rows, LANES), jnp.float32),
+            pltpu.VMEM((rep, rows, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, page_size=P,
-                          n_pages=n_pages, window=S),
+        functools.partial(_ragged_kernel, scale=scale, page_size=P,
+                          n_pages=n_pages, rep=rep, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, rows, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q_lens.astype(jnp.int32), *operands)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D)
+    return out[:, :, :S].transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -423,8 +454,10 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
         kc, ks = quantized_append(cache_k, k_scales, k, page, off, live)
         vc, vs = quantized_append(cache_v, v_scales, v, page, off, live)
     else:
-        kc = cache_k.at[page, off].set(k.astype(cache_k.dtype))
-        vc = cache_v.at[page, off].set(v.astype(cache_v.dtype))
+        kc = cache_k.at[page, off].set(
+            k.reshape(B, S, -1).astype(cache_k.dtype))
+        vc = cache_v.at[page, off].set(
+            v.reshape(B, S, -1).astype(cache_v.dtype))
         ks = vs = None
 
     force_interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"

@@ -9,10 +9,13 @@ model; 2x vs bf16) at the cost of a bounded logit error.
 Layout. A quantized pool keeps, per attention node, FOUR buffers in the
 caches dict instead of two::
 
-    {"k":       (num_pages, page_size, Hkv, D)  int8,
-     "v":       (num_pages, page_size, Hkv, D)  int8,
-     "k_scale": (num_pages, Hkv)                float32,
-     "v_scale": (num_pages, Hkv)                float32}
+    {"k":       (num_pages, page_size, Hkv * D)  int8,
+     "v":       (num_pages, page_size, Hkv * D)  int8,
+     "k_scale": (num_pages, Hkv)                 float32,
+     "v_scale": (num_pages, Hkv)                 float32}
+
+(flat-lane pages, paged/attention.py: head h owns lanes h*D..(h+1)*D of
+every row; `_by_head` below is the one place that split is written.)
 
 The scale granularity is per (page, head, K-or-V): one float per KV
 head per page, symmetric around zero (stored = round(x / scale),
@@ -104,9 +107,34 @@ def scale_entry_names(bufs) -> bool:
     return "k_scale" in bufs
 
 
+def _by_head(rows, n_heads: int):
+    """(..., Hkv*D) flat-lane rows -> (..., Hkv, D)."""
+    return rows.reshape(*rows.shape[:-1], n_heads, -1)
+
+
+def rescale_pages(pages, ratio):
+    """Re-quantize gathered int8 ``pages`` (..., P, Hkv*D) onto a grown
+    grid: ``ratio`` (..., Hkv) is old_scale / new_scale per head."""
+    import jax.numpy as jnp
+
+    blk = _by_head(pages.astype(jnp.float32), ratio.shape[-1])
+    blk = blk * ratio[..., None, :, None]
+    return jnp.clip(jnp.round(blk), -QMAX, QMAX).astype(
+        pages.dtype).reshape(pages.shape)
+
+
+def quantize_rows(rows, scales, dtype):
+    """Quantize fp ``rows`` (..., Hkv, D) at per-head ``scales``
+    (..., Hkv) onto the int8 grid; returns flat-lane (..., Hkv*D)."""
+    import jax.numpy as jnp
+
+    q = jnp.clip(jnp.round(rows / scales[..., None]), -QMAX, QMAX)
+    return q.astype(dtype).reshape(*rows.shape[:-2], -1)
+
+
 def quantized_append(pool, scales, x, page, off, live):
     """Scatter fp rows ``x`` into an int8 ``pool`` under grow-only
-    per-(page, head) ``scales``. pool: (N, P, Hkv, D) int8; scales:
+    per-(page, head) ``scales``. pool: (N, P, Hkv*D) int8; scales:
     (N, Hkv) f32; x: (B, S, Hkv, D) fp; page/off/live: (B, S). Returns
     (new pool, new scales).
 
@@ -138,22 +166,20 @@ def quantized_append(pool, scales, x, page, off, live):
     new_t = new_scales[page]
     ratio = jnp.where(new_t > 0, old_t / jnp.maximum(new_t, f32(SCALE_EPS)),
                       zero)
-    blk = pool[page].astype(f32)                    # (B, S, P, Hkv, D)
-    blk = blk * ratio[:, :, None, :, None]
-    pool = pool.at[page].set(
-        jnp.clip(jnp.round(blk), -QMAX, QMAX).astype(pool.dtype))
-    s_rows = jnp.where(new_t > 0, new_t, f32(1.0))[..., None]  # (B,S,Hkv,1)
-    qx = jnp.clip(jnp.round(xf / s_rows), -QMAX, QMAX).astype(pool.dtype)
-    pool = pool.at[page, off].set(qx)
+    pool = pool.at[page].set(rescale_pages(pool[page], ratio))
+    s_rows = jnp.where(new_t > 0, new_t, f32(1.0))           # (B, S, Hkv)
+    pool = pool.at[page, off].set(quantize_rows(xf, s_rows, pool.dtype))
     return pool, new_scales
 
 
 def dequantize_pages(pages, scales):
-    """pages: (..., P, Hkv, D) int8 gathered by page; scales:
-    (..., Hkv) f32 gathered the same way. Returns float32 pages."""
+    """pages: (..., P, Hkv*D) int8 gathered by page; scales:
+    (..., Hkv) f32 gathered the same way. Returns float32 pages of the
+    same flat-lane shape."""
     import jax.numpy as jnp
 
-    return pages.astype(jnp.float32) * scales[..., None, :, None]
+    blk = _by_head(pages.astype(jnp.float32), scales.shape[-1])
+    return (blk * scales[..., None, :, None]).reshape(pages.shape)
 
 
 def quantize_leaf(arr):

@@ -127,6 +127,21 @@ class PagedGenerationServer(_GenerationServerBase):
         # (n_items, window<=8) family instead of per-chunk pow2 buckets
         self._chunk_rows = PREFILL_WINDOW_ROWS
         ex = ff.executor
+        if (jax.default_backend() == "tpu" and ex.mesh is not None
+                and ex.mesh.devices.size > 1):
+            # the pools are plain unsharded buffers and the ragged
+            # pallas_call has no shard_map around it (unlike the flash
+            # kernels' _sharded_flash): on a multi-chip mesh Mosaic
+            # refuses to be partitioned and GSPMD would replicate the
+            # whole pool on every chip. Sharded serving does not exist
+            # yet — say so here, not in the first trace.
+            raise NotImplementedError(
+                f"paged serving runs on ONE chip: this model was compiled "
+                f"on a {dict(zip(ex.mesh.axis_names, ex.mesh.devices.shape))}"
+                " mesh. Compile the serving model with "
+                "FFConfig(num_devices=1) — several one-chip replicas can "
+                "sit behind disagg.PrefixAffinityRouter — or serve with "
+                "paged=False.")
         # one ragged step serves decode AND chunked prefill (and tree
         # verify in the speculative subclass): K/V writes land straight
         # in pool pages, there is no dense staging cache
@@ -309,9 +324,15 @@ class PagedGenerationServer(_GenerationServerBase):
         )
 
         reset_rejection_log()
-        kbuf = next(iter(self._caches.values()))["k"]
+        attn_key, kbufs = next(iter(self._caches.items()))
+        kbuf = kbufs["k"]
+        # pool rows are flat-lane (Hkv*D); the gate wants the head dim
+        from flexflow_tpu.runtime.executor import node_key as _node_key
+
+        head_dim = next(n.attrs.kdim for n in ex.topo
+                        if _node_key(n) == attn_key)
         self.kernel_variant = "ragged_pallas" if paged_attention_available(
-            kbuf.shape[-1], self.page_size,
+            head_dim, self.page_size,
             interpret=os.environ.get("FF_TPU_FLASH_INTERPRET") == "1",
             dtype=kbuf.dtype) else "ragged_gather"
         self._g_kernel = self.registry.gauge("ragged_kernel_active")

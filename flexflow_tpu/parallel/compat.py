@@ -1,4 +1,4 @@
-"""jax version-compat shims shared by the parallel subsystems."""
+"""Thin wrappers over jax entry points the parallel subsystems share."""
 
 from __future__ import annotations
 
@@ -6,34 +6,14 @@ import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map on current jax; falls back to the pre-0.8
-    jax.experimental.shard_map (where check_vma was named check_rep).
-    check_vma=False opts out of the replication check — pallas_call outputs
-    carry no varying-mesh-axes annotation."""
-    kw = {} if check_vma else {"check_vma": False}
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    except (AttributeError, TypeError):  # older jax
-        from jax.experimental.shard_map import shard_map as legacy
-
-        kw = {} if check_vma else {"check_rep": False}
-        return legacy(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """jax.shard_map with positional mesh/specs. check_vma=False opts out
+    of the replication check — pallas_call outputs carry no
+    varying-mesh-axes annotation."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def ensure_cpu_devices(n: int) -> None:
-    """Force `n` virtual CPU devices, on any jax version. jax >= 0.5 has
-    the jax_num_cpu_devices config; older jax falls back to the XLA host
-    platform flag, which is honored as long as the backend has not been
-    initialized yet (any pre-set count flag is replaced, not appended —
-    XLA_FLAGS parsing is last-wins)."""
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:  # older jax (< 0.5)
-        import os
-
-        flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-                 if "xla_force_host_platform_device_count" not in f]
-        flags.append(f"--xla_force_host_platform_device_count={n}")
-        os.environ["XLA_FLAGS"] = " ".join(flags)
+    """Force `n` virtual CPU devices (effective until the first backend
+    initialization)."""
+    jax.config.update("jax_num_cpu_devices", n)

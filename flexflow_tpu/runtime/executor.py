@@ -91,7 +91,8 @@ class _TracedStep:
     the object train_step() returns. Disabled-mode cost is one module
     attribute load + an `is None` test per step."""
 
-    __slots__ = ("_fn", "_name")
+    # __weakref__: jax.jit(step) weak-references the callable it wraps
+    __slots__ = ("_fn", "_name", "__weakref__")
 
     def __init__(self, fn, name: str):
         self._fn = fn
@@ -363,14 +364,13 @@ class Executor:
                     weight_dtype: Optional[str] = None):
         """Initialize (trainable, nontrainable) param pytrees, resharding
         each weight to its strategy NamedSharding as it is drawn. The
-        draws run UNPARTITIONED on purpose: under GSPMD a sharded
-        out_sharding partitions the threefry stream, and with the
-        non-partitionable RNG (jax < 0.5 default) a partitioned draw
-        produces DIFFERENT values than the replicated one — a sharded
-        model would train/decode from different weights than the
-        unsharded reference (seed failure: test_decode_sp_pp token
-        identity). Values first, layout second — leaf by leaf, so the
-        whole model never resides unsharded on one device.
+        draws run UNPARTITIONED on purpose: a sharded model must
+        train/decode from the SAME weights as the unsharded reference
+        at the same seed whatever the RNG's partitioning mode (seed
+        failure: test_decode_sp_pp token identity; the four-chip TP
+        loss is compared with the one-chip loss on that footing).
+        Values first, layout second — leaf by leaf, so the whole model
+        never resides unsharded on one device.
         `overrides` maps node_key -> weight name -> Initializer (the layer
         methods' kernel_initializer arguments).
 
@@ -820,7 +820,7 @@ class Executor:
             dt = dtype
             if dt is None:
                 dt = ins[0].dtype.jnp_dtype if ins else jnp.bfloat16
-            shape = (num_pages, page_size, n.attrs.num_kv, n.attrs.kdim)
+            shape = (num_pages, page_size, n.attrs.num_kv * n.attrs.kdim)
             specs[node_key(n)] = {
                 "k": jax.ShapeDtypeStruct(shape, dt),
                 "v": jax.ShapeDtypeStruct(shape, dt),
@@ -841,9 +841,10 @@ class Executor:
     def init_paged_kv_cache(self, num_pages: int, page_size: int,
                             dtype=None):
         """Per-attention-node paged K/V POOLS for the paged decode path
-        (flexflow_tpu.paged): (num_pages, page_size, Hkv, D) buffers
-        shared by every request through per-slot page tables, so HBM
-        scales with TOKENS IN FLIGHT instead of slots x max_len. PIPELINE
+        (flexflow_tpu.paged): flat-lane (num_pages, page_size, Hkv*D)
+        buffers (paged/attention.py has the layout story) shared by
+        every request through per-slot page tables, so HBM scales with
+        TOKENS IN FLIGHT instead of slots x max_len. PIPELINE
         composites keep their layer-scan threaded dense caches and are
         not paged (their cache lives inside the scan carry)."""
         return jax.tree.map(
@@ -1271,7 +1272,12 @@ class Executor:
 
         # grid and eps shared with quantized_append — one definition of
         # the int8 grid, one floor under scale ratios
-        from flexflow_tpu.paged.quant import QMAX, SCALE_EPS
+        from flexflow_tpu.paged.quant import (
+            SCALE_EPS,
+            dequantize_pages,
+            quantize_rows,
+            rescale_pages,
+        )
 
         def _copy_rows_quant(buf, sc, sp, so, dp, do):
             f32 = jnp.float32
@@ -1281,13 +1287,11 @@ class Executor:
             ratio = jnp.where(new_d > 0,
                               old_d / jnp.maximum(new_d, f32(SCALE_EPS)),
                               zero)
-            blk = buf[dp].astype(f32) * ratio[:, :, None, :, None]
-            buf = buf.at[dp].set(
-                jnp.clip(jnp.round(blk), -QMAX, QMAX).astype(buf.dtype))
-            den = jnp.where(new_d > 0, new_d, f32(1.0))[..., None]
-            row = buf[sp, so].astype(f32) * sc2[sp][..., None] / den
-            buf = buf.at[dp, do].set(
-                jnp.clip(jnp.round(row), -QMAX, QMAX).astype(buf.dtype))
+            buf = buf.at[dp].set(rescale_pages(buf[dp], ratio))
+            den = jnp.where(new_d > 0, new_d, f32(1.0))
+            row = dequantize_pages(buf[sp, so][..., None, :], sc2[sp])
+            row = row.reshape(*den.shape, -1)         # (slots, C, Hkv, D)
+            buf = buf.at[dp, do].set(quantize_rows(row, den, buf.dtype))
             return buf, sc2
 
         def commit(caches, page_tables, src, dst):

@@ -17,7 +17,10 @@ from typing import Dict, Tuple
 from flexflow_tpu.parallel.sharding import ShardingView
 from flexflow_tpu.pcg.graph import Graph
 from flexflow_tpu.search.cost_model import CostModel
-from flexflow_tpu.search.machine_model import TPUMachineModel
+from flexflow_tpu.search.machine_model import (
+    TPUMachineModel,
+    chip_for_device,
+)
 
 
 def _cost_model(mesh, config) -> CostModel:
@@ -43,7 +46,8 @@ def _cost_model(mesh, config) -> CostModel:
     machine = (
         TPUMachineModel.from_file(config.machine_model_file)
         if config.machine_model_file
-        else TPUMachineModel.make("v5e", num_chips=num_chips)
+        else TPUMachineModel.make(chip_for_device(mesh.devices.flat[0]),
+                                  num_chips=num_chips)
     )
     # slice-crossing detection needs the mesh axis ORDER (outer axes span
     # slices under row-major device placement), not just participant counts
@@ -68,12 +72,13 @@ def _cost_model(mesh, config) -> CostModel:
     return cm
 
 
-def _maybe_measure(cost, graph, config, mesh=None) -> None:
+def _maybe_measure(cost, graph, config, mesh=None, stats_out=None) -> None:
     """When measure_costs is on, run the on-device microbenchmarks for the
     graph's ops AND the mesh's collectives, then calibrate the analytic
     knobs BEFORE searching (the reference measures inside the cost query,
     simulator.cc:537; here the sweep is up-front so the search loop stays
-    cheap)."""
+    cheap). `stats_out` receives the count of microbenchmarks that
+    raised (priced analytically instead) as "failed_measurements"."""
     from flexflow_tpu.search.measured import MeasuredCostModel
 
     if mesh is not None:
@@ -89,8 +94,11 @@ def _maybe_measure(cost, graph, config, mesh=None) -> None:
     if isinstance(cost, MeasuredCostModel):
         cost.measure_graph(graph, {}, training=True)
         knobs = cost.calibrate(graph, {}, mesh=mesh)
+        if stats_out is not None:
+            stats_out["failed_measurements"] = len(cost.failures)
         if config.profiling:
-            print(f"[search] measured {len(cost._measured)} op shards; "
+            print(f"[search] measured {len(cost._measured)} op shards "
+                  f"({len(cost.failures)} microbenchmarks failed); "
                   f"mxu_eff={cost.machine.mxu_efficiency:.3f}; "
                   f"ici samples={knobs.get('ici_samples', 0)} "
                   f"eff={cost.machine.ici_efficiency:.3f} "
@@ -156,8 +164,8 @@ def _simulate_rerank(candidates_out, cost, config):
     return reranked[0]
 
 
-def search_strategy(graph, mesh, config,
-                    candidates_out=None) -> Dict[str, ShardingView]:
+def search_strategy(graph, mesh, config, candidates_out=None,
+                    stats_out=None) -> Dict[str, ShardingView]:
     """Views-only search on a fixed graph (MCMC). `candidates_out`: when a
     list is passed, receives the (modeled_cost, graph, strategy) pair of
     the MCMC winner and the plain-DP baseline for the validate_top_k timed
@@ -165,7 +173,7 @@ def search_strategy(graph, mesh, config,
     from flexflow_tpu.search.mcmc import mcmc_search
 
     cost = _cost_model(mesh, config)
-    _maybe_measure(cost, graph, config, mesh=mesh)
+    _maybe_measure(cost, graph, config, mesh=mesh, stats_out=stats_out)
     strategy = mcmc_search(graph, mesh, config, cost=cost)
     # no playoff pool under memory_search: the DP baseline (full weight
     # replication) may exceed the memory limit the search honored, and the
@@ -204,7 +212,7 @@ def graph_optimize(graph: Graph, mesh, config, candidates_out=None,
 
     _t0 = _time.perf_counter()
     cost = _cost_model(mesh, config)
-    _maybe_measure(cost, graph, config, mesh=mesh)
+    _maybe_measure(cost, graph, config, mesh=mesh, stats_out=stats_out)
     if (stats_out is not None
             and getattr(cost.machine, "chips_per_slice", None)):
         # which mesh axes' collectives ride DCN on this multi-slice

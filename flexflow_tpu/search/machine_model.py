@@ -36,6 +36,44 @@ CHIPS: Dict[str, TPUChipSpec] = {
 }
 
 
+# `jax.Device.device_kind` substrings -> CHIPS key, most specific first
+# ("TPU v5 lite" must not match the bare "v5" of a v5p).
+_DEVICE_KINDS = (
+    ("v5 lite", "v5e"), ("v5e", "v5e"), ("v5p", "v5p"), ("v5", "v5p"),
+    ("v6 lite", "v6e"), ("v6e", "v6e"), ("v4", "v4"),
+)
+
+
+def chip_for_device_kind(device_kind: str) -> str:
+    """The CHIPS key of a TPU `device_kind` as JAX reports it. A kind
+    that is not in the table is an error, never a default: a search
+    priced, or a utilization computed, for the wrong chip is worse than
+    none."""
+    kind = device_kind.lower()
+    for sub, chip in _DEVICE_KINDS:
+        if sub in kind:
+            return chip
+    raise ValueError(
+        f"unknown TPU device_kind {device_kind!r}: add it to "
+        "flexflow_tpu/search/machine_model.py (CHIPS, _DEVICE_KINDS) or "
+        "pass a machine_model_file")
+
+
+def chip_for_device(device) -> str:
+    """The CHIPS key the strategy search models for the attached
+    `jax.Device`: the chip itself on a TPU backend; off one (CPU tests,
+    searching from a laptop) a v5e, said in the log."""
+    if device.platform == "tpu":
+        return chip_for_device_kind(device.device_kind)
+    import logging
+
+    logging.getLogger(__name__).info(
+        "strategy search: no TPU attached (platform %r); modelling a v5e "
+        "— pass machine_model_file to search for another machine",
+        device.platform)
+    return "v5e"
+
+
 @dataclasses.dataclass
 class TPUMachineModel:
     """Cost oracle for compute and collectives on a TPU slice.

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -31,6 +32,8 @@ from flexflow_tpu.ffconst import OpType, PARALLEL_OP_TYPES
 from flexflow_tpu.parallel.sharding import ShardingView
 from flexflow_tpu.pcg.graph import Graph, Node
 from flexflow_tpu.search.cost_model import CostModel, spec_degree, _in_shapes
+
+logger = logging.getLogger(__name__)
 
 
 def _shard_shape(shape, spec, axis_sizes) -> Tuple[int, ...]:
@@ -69,8 +72,18 @@ class MeasuredCostModel(CostModel):
     # serving-tick calibration (fftrace): per-tick-shape scale factors
     # (measured / predicted) from obs.calibrate.calibration_report
     _tick_scale: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # microbenchmarks that RAISED (what was timed, why): the analytic
+    # model prices those entries instead, which on a chip can hide a
+    # kernel the compiler refused — callers report the count
+    failures: List[str] = dataclasses.field(default_factory=list)
 
     # ------------------------------------------------------------------
+
+    def _failed(self, what: str, err: Exception) -> None:
+        self.failures.append(f"{what}: {type(err).__name__}: {err}")
+        logger.warning("measured cost model: %s did not run, priced "
+                       "analytically instead (%s: %s)", what,
+                       type(err).__name__, " ".join(str(err).split())[:300])
 
     def _key(self, node: Node, view: Optional[ShardingView],
              in_shards, w_shards) -> str:
@@ -176,8 +189,9 @@ class MeasuredCostModel(CostModel):
                 out = fn(inputs, params)
             jax.block_until_ready(out)
             return (time.perf_counter() - t0) / self.repeats
-        except Exception:
-            return None  # unmeasurable op (shape constraints, rng needs…)
+        except Exception as e:  # shape constraints, rng needs, a refusal
+            self._failed(f"op {node.name} ({node.op_type.name})", e)
+            return None
 
     # ------------------------------------------------------------------
 
@@ -314,8 +328,9 @@ class MeasuredCostModel(CostModel):
                         self._measured[ck] = dt  # disk-cached with the ops
                         self._coll_samples.append(
                             (kind, axis, n, rec_bytes, dt))
-                    except Exception:
-                        continue  # collective unsupported on this backend
+                    except Exception as e:  # unsupported on this backend
+                        self._failed(f"collective {kind} over {axis}", e)
+                        continue
         self.save_cache()
         return len(self._coll_samples)
 

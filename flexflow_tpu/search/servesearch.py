@@ -26,7 +26,7 @@ closes that gap:
   4. `fftrace calibrate` reports feed `MeasuredCostModel.
      set_tick_calibration`, so measured per-tick-shape wall times scale
      the analytic prices (reports older than the staleness window are
-     REFUSED, mirroring bench.py's last-green guard).
+     REFUSED).
 
 Surface: `serve_generation(search_budget=...)` /
 `FFModel.serve_generation(...)` run the search at serve time;
@@ -58,8 +58,8 @@ from flexflow_tpu.spec.config import SpecConfig
 
 logger = logging.getLogger(__name__)
 
-# Same freshness window as bench.py's last-green artifacts: a calibration
-# report older than this is refused (with a warning), not silently used.
+# A calibration report older than this is refused (with a warning), not
+# silently used.
 CALIBRATION_MAX_AGE_S = 7 * 24 * 3600
 
 # Objective assigned to knob combinations serve_generation would reject

@@ -147,7 +147,7 @@ int ffc_init(int argc, char **argv) {
   if (Py_IsInitialized()) return 0;
   Py_Initialize();
   // FFC_PLATFORM / FFC_CPU_DEVICES pin the jax backend BEFORE any backend
-  // touch (site plugins can override env vars; jax.config cannot be)
+  // touch
   PyRun_SimpleString(
       "import os\n"
       "_p = os.environ.get('FFC_PLATFORM')\n"

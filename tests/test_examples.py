@@ -11,15 +11,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EX = os.path.join(ROOT, "examples", "python")
 
 
-def _run(script, *flags, timeout=420):
+def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    # the CLI driver places the persistent compile cache; the suite
+    # compiles what it tests, so the cache stays off here
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    return env
+
+
+def _run(script, *flags, timeout=420):
     # the CLI driver's --platform flag configures the backend before any
-    # jax touch (env vars alone can be overridden by TPU site plugins)
+    # jax touch
     p = subprocess.run(
         [sys.executable, "-m", "flexflow_tpu", "--platform", "cpu",
          "--cpu-devices", "8", os.path.join(EX, script), "-e", "1", *flags],
-        env=env, capture_output=True, text=True, timeout=timeout,
+        env=_env(), capture_output=True, text=True, timeout=timeout,
     )
     assert p.returncode == 0, f"{script} failed:\n{p.stdout}\n{p.stderr}"
     return p.stdout
@@ -47,12 +54,10 @@ def test_example_runs(script, flags):
 
 
 def test_cli_driver():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
     p = subprocess.run(
         [sys.executable, "-m", "flexflow_tpu", "--platform", "cpu",
          os.path.join(EX, "mnist_mlp.py"), "-b", "64", "-e", "1"],
-        env=env, capture_output=True, text=True, timeout=420,
+        env=_env(), capture_output=True, text=True, timeout=420,
     )
     assert p.returncode == 0, p.stdout + p.stderr
     assert "samples=" in p.stdout

@@ -30,6 +30,44 @@ def _cost():
 
 def test_native_builds():
     assert native.available(), "g++ build of libffsim.so failed"
+    assert native.engine().startswith("native")
+
+
+def test_stale_library_is_rebuilt_not_loaded(tmp_path):
+    """The rebuild is keyed on a hash of the source, not on file times (a
+    copy or a checkout scrambles those): a library whose sidecar hash
+    differs from the source — or that has no sidecar at all, like one
+    built before this rule — is rebuilt."""
+    import ctypes
+    import os
+
+    src = tmp_path / "answer.cc"
+    lib = str(tmp_path / "libanswer.so")
+
+    def answer():
+        fn = ctypes.CDLL(lib).answer
+        fn.restype = ctypes.c_int
+        return fn()
+
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    assert native._build_so(str(src), lib)
+    stamp = lib + ".src-sha256"
+    assert open(stamp).read().strip() == native._src_hash(str(src))
+    built = os.stat(lib).st_ino
+    assert native._build_so(str(src), lib)          # same source: reused
+    assert os.stat(lib).st_ino == built
+
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    os.utime(lib, (2e9, 2e9))                       # library looks NEWER
+    assert native._build_so(str(src), lib)
+    assert os.stat(lib).st_ino != built             # rebuilt all the same
+    assert open(stamp).read().strip() == native._src_hash(str(src))
+    assert answer() == 2
+
+    os.remove(stamp)                                # pre-rule library
+    built = os.stat(lib).st_ino
+    assert native._build_so(str(src), lib)
+    assert os.stat(lib).st_ino != built
 
 
 def test_table_matches_graph_cost():

@@ -198,8 +198,8 @@ def test_ragged_kernel_matches_gather_reference(H, Hkv, S, mix):
     rs = np.random.RandomState(1000 * S + len(mix))
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-    kc = jax.random.normal(ks[1], (N, P, Hkv, D), jnp.float32)
-    vc = jax.random.normal(ks[2], (N, P, Hkv, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (N, P, Hkv * D), jnp.float32)
+    vc = jax.random.normal(ks[2], (N, P, Hkv * D), jnp.float32)
     perm = rs.permutation(N - 1)[:B * MAXP] + 1  # distinct non-null pages
     pt = jnp.asarray(perm.reshape(B, MAXP).astype(np.int32))
     entries = [_ragged_entry(k, S, rs) for k in mix]
@@ -245,7 +245,7 @@ def test_paged_decode_logits_match_dense():
     for key in pools:
         pools[key] = {
             n: pools[key][n].at[ids].set(
-                dense[key][n][0].reshape(MAXP, P, *dense[key][n].shape[2:])[:2])
+                dense[key][n][0].reshape(MAXP, P, -1)[:2])
             for n in ("k", "v")
         }
     tables = jnp.asarray(np.array([[1, 2, 3, 0]], np.int32))

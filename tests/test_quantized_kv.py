@@ -86,7 +86,7 @@ def test_quantized_append_grow_only_roundtrip():
     scale; everything dequantizes back within half a grid step. Dead
     rows never inflate a scale."""
     N, P, Hkv, D = 4, 4, 1, 3
-    pool = jnp.zeros((N, P, Hkv, D), jnp.int8)
+    pool = jnp.zeros((N, P, Hkv * D), jnp.int8)   # flat-lane pages
     scales = jnp.zeros((N, Hkv), jnp.float32)
     small = jnp.asarray([[[[0.11, -0.07, 0.05]], [[0.02, 0.09, -0.12]]]])
     page = jnp.asarray([[1, 1]])
@@ -97,7 +97,8 @@ def test_quantized_append_grow_only_roundtrip():
     assert s1 == pytest.approx(0.12 / QMAX)
     got = dequantize_pages(pool[1], scales[1])
     np.testing.assert_allclose(np.asarray(got[:2]),
-                               np.asarray(small[0]), atol=s1 * ROUNDTRIP)
+                               np.asarray(small[0, :, 0]),
+                               atol=s1 * ROUNDTRIP)
 
     big = jnp.asarray([[[[1.27, -0.6, 0.3]]]])
     pool, scales = quantized_append(pool, scales, big,
@@ -108,9 +109,10 @@ def test_quantized_append_grow_only_roundtrip():
     got = dequantize_pages(pool[1], scales[1])
     # the ORIGINAL small rows survived the in-place rescale: one
     # round-trip through the old grid plus one through the new one
-    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(small[0]),
+    np.testing.assert_allclose(np.asarray(got[:2]),
+                               np.asarray(small[0, :, 0]),
                                atol=s1 * ROUNDTRIP + s2 * ROUNDTRIP)
-    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(big[0, 0]),
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(big[0, 0, 0]),
                                atol=s2 * ROUNDTRIP)
 
     # a dead row full of garbage touches neither payload nor scale
@@ -164,14 +166,14 @@ def _mixed_ragged_outputs(quantized: bool):
         return jnp.asarray(rs.randn(*shape).astype(np.float32))
 
     if quantized:
-        kc = jnp.zeros((N, P, Hkv, D), jnp.int8)
-        vc = jnp.zeros((N, P, Hkv, D), jnp.int8)
+        kc = jnp.zeros((N, P, Hkv * D), jnp.int8)
+        vc = jnp.zeros((N, P, Hkv * D), jnp.int8)
         ks = jnp.zeros((N, Hkv), jnp.float32)
         vs = jnp.zeros((N, Hkv), jnp.float32)
         sc = {"k_scales": ks, "v_scales": vs}
     else:
-        kc = jnp.zeros((N, P, Hkv, D), jnp.float32)
-        vc = jnp.zeros((N, P, Hkv, D), jnp.float32)
+        kc = jnp.zeros((N, P, Hkv * D), jnp.float32)
+        vc = jnp.zeros((N, P, Hkv * D), jnp.float32)
         sc = {}
 
     # phase 1: causal 4-token chunk at pos 0 for every slot
@@ -237,7 +239,7 @@ def test_scale_aware_commit_copies_across_scales(lm):
     big = rs.uniform(-2.0, 2.0, (P, Hkv, D)).astype(np.float32)
 
     def build():
-        pool = jnp.zeros((3, P, Hkv, D), jnp.int8)
+        pool = jnp.zeros((3, P, Hkv * D), jnp.int8)
         scales = jnp.zeros((3, Hkv), jnp.float32)
         for pg, rows in ((1, small), (2, big)):
             pool, scales = quantized_append(
@@ -256,9 +258,9 @@ def test_scale_aware_commit_copies_across_scales(lm):
     s_dst = float(out["k_scale"][1, 0])
     assert s_dst == pytest.approx(float(np.abs(big).max()) / QMAX)
     got = np.asarray(dequantize_pages(out["k"][1], out["k_scale"][1]))
-    np.testing.assert_allclose(got[:2], big[:2], atol=s_dst * REGROW)
+    np.testing.assert_allclose(got[:2], big[:2, 0], atol=s_dst * REGROW)
     # surviving rows re-snapped to the grown grid, still within it
-    np.testing.assert_allclose(got[2:], small[2:], atol=s_dst * REGROW)
+    np.testing.assert_allclose(got[2:], small[2:, 0], atol=s_dst * REGROW)
 
     # small -> big: the destination's scale and untouched bytes are
     # byte-identical (no grow, ratio 1)

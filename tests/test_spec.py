@@ -124,8 +124,8 @@ def test_tree_kernel_matches_gather_reference(H, Hkv):
     B, D, P, N, T = 3, 32, 8, 12, 6
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
-    kc = jax.random.normal(ks[1], (N, P, Hkv, D), jnp.float32)
-    vc = jax.random.normal(ks[2], (N, P, Hkv, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (N, P, Hkv * D), jnp.float32)
+    vc = jax.random.normal(ks[2], (N, P, Hkv * D), jnp.float32)
     pt = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0],
                                [6, 7, 8, 9]], np.int32))
     pos = jnp.asarray(np.array([14, 6, 24], np.int32))
@@ -170,8 +170,7 @@ def test_tree_verify_matches_sequential_decode():
     for key in pools:
         pools[key] = {
             n: pools[key][n].at[ids].set(
-                dense[key][n][0].reshape(MAXP, P,
-                                         *dense[key][n].shape[2:])[:2])
+                dense[key][n][0].reshape(MAXP, P, -1)[:2])
             for n in ("k", "v")
         }
     tables = jnp.asarray(np.array([[1, 2, 3, 0]], np.int32))

@@ -1,0 +1,259 @@
+"""Every Pallas entry point at the chip smoke's shapes, one catalog, two uses.
+
+  * tier-1 (CPU, tests/test_tpu_lowering.py): each case is lowered for TPU
+    with `jax.export` — the Pallas->Mosaic lowering runs on any host, so a
+    block-shape refusal is caught before chip time is spent;
+  * on the chip (`python tools/chip_kernels.py`, through the chip tool): each
+    case is compiled by Mosaic, run, and compared with its plain-XLA
+    reference. Exit code 1 if any case fails. What the Mosaic compiler itself
+    accepts only this run can say.
+
+Shapes: Llama-3-8B attention width (32 q / 8 kv heads x 128) for the ragged
+kernel and the D=128 flash kernels, plus the D=64 padded flash path.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+H, HKV, D = 32, 8, 128
+# page sizes the availability gate admits per pool dtype (one sublane tile
+# and one MXU-wide page each)
+RAGGED_POOLS = (("bfloat16", "bfloat16", 16), ("bfloat16", "bfloat16", 128),
+                ("float32", "float32", 8), ("bfloat16", "int8", 32),
+                ("bfloat16", "int8", 128))
+RAGGED_WINDOWS = (("decode", 1), ("chunk", 8), ("chunk", 64), ("tree", 8))
+
+
+def _tree_anc(S):
+    from flexflow_tpu.spec.tree import ancestor_masks
+
+    parents = np.full((S,), -1, np.int32)
+    parents[:min(S, 6)] = np.array([-1, 0, 1, 2, 1, 0], np.int32)[:S]
+    return ancestor_masks(parents[None])[0]
+
+
+def _ragged_case(kind, S, qdt, pdt, P, seed=0):
+    """(fn, args, ref_fn) for one ragged launch: 4 entries (two live at
+    different depths, one short, one padded) over a shuffled page table."""
+    from flexflow_tpu.paged.attention import (
+        ragged_flash_attention,
+        ragged_gather_attention,
+    )
+    from flexflow_tpu.paged.quant import quantized_append
+
+    B, MAXP = 4, max(4, -(-(96 + S) // P))
+    N = B * MAXP + 1
+    rs = np.random.RandomState(seed)
+    quant = pdt == "int8"
+    q = jnp.asarray(rs.randn(B, S, H, D), qdt)
+    pt = jnp.asarray((rs.permutation(N - 1)[:B * MAXP] + 1)
+                     .reshape(B, MAXP).astype(np.int32))
+    pos = jnp.asarray(np.array([90, 37, 5, 0], np.int32))
+    q_lens = jnp.asarray(np.array([S, S, max(1, S // 2), 0], np.int32))
+    if kind == "tree":
+        anc = np.tile(_tree_anc(S), (B, 1, 1))
+    else:
+        anc = np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1))
+    anc = jnp.asarray(anc)
+    scales = ()
+    if quant:
+        # a real quantized pool: append random rows page by page
+        rows = jnp.asarray(rs.randn(N, P, HKV, D), jnp.float32)
+        page = jnp.broadcast_to(jnp.arange(N)[:, None], (N, P))
+        off = jnp.broadcast_to(jnp.arange(P)[None], (N, P))
+        pools = []
+        for x in (rows, rows[::-1]):
+            pool, sc = quantized_append(
+                jnp.zeros((N, P, HKV * D), jnp.int8),
+                jnp.zeros((N, HKV), jnp.float32), x, page, off,
+                jnp.ones((N, P), bool))
+            pools.append(pool)
+            scales += (sc,)
+        kc, vc = pools
+    else:
+        kc = jnp.asarray(rs.randn(N, P, HKV * D), pdt)
+        vc = jnp.asarray(rs.randn(N, P, HKV * D), pdt)
+    scale = 1.0 / np.sqrt(D)
+
+    def run(impl, **kw):
+        def fn(q, kc, vc, pt, pos, q_lens, anc, *sc):
+            skw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+            out = impl(q, kc, vc, pt, pos, q_lens, anc, scale=scale,
+                       **skw, **kw)
+            # rows at or past q_len are garbage by contract on both paths
+            live = jnp.arange(S)[None, :] < q_lens[:, None]
+            return jnp.where(live[..., None, None], out, 0)
+        return fn
+
+    return (run(ragged_flash_attention), (q, kc, vc, pt, pos, q_lens, anc)
+            + scales, run(ragged_gather_attention))
+
+
+def _loss_grads(attn, w):
+    """(q, k, v) -> (loss, grads) of a fixed random projection of `attn`'s
+    output: one function that runs the forward and the backward kernels."""
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+    return lambda q, k, v: jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+
+def _flash_case(d, seed=0):
+    """flash fwd+bwd through the public entry (D=128 flat-lane kernels,
+    D=64 padded head-major kernels), GQA, causal."""
+    from flexflow_tpu.ops.jax_ops import _dot_product_attention
+    from flexflow_tpu.ops.pallas import flash_attention
+
+    B, S, h, hkv = 2, 1024, 16, 8
+    rs = np.random.RandomState(seed)
+    q = jnp.asarray(rs.randn(B, S, h, d), jnp.bfloat16)
+    k = jnp.asarray(rs.randn(B, S, hkv, d), jnp.bfloat16)
+    v = jnp.asarray(rs.randn(B, S, hkv, d), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(B, S, h, d), jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    return (_loss_grads(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, scale=scale), w),
+            (q, k, v),
+            _loss_grads(lambda q, k, v: _dot_product_attention(
+                q, k, v, True, scale), w))
+
+
+def _ring_carry_case(seed=0):
+    """One ring step (`_fwd_carry`) from empty statistics: acc / l is plain
+    causal attention over the block."""
+    from flexflow_tpu.ops.jax_ops import _dot_product_attention
+    from flexflow_tpu.ops.pallas.flash_attention import (
+        LANES,
+        NEG_INF,
+        _fwd_carry,
+    )
+
+    BH, S = 8, 512
+    rs = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rs.randn(BH, S, D), jnp.bfloat16)
+               for _ in range(3))
+    scale = 1.0 / np.sqrt(D)
+
+    def fn(q, k, v):
+        m = jnp.full((BH, S, LANES), NEG_INF, jnp.float32)
+        l = jnp.zeros((BH, S, LANES), jnp.float32)
+        acc = jnp.zeros((BH, S, D), jnp.float32)
+        m, l, acc = _fwd_carry(q, k, v, m, l, acc, True, scale, 512, 512,
+                               False)
+        return acc / l[:, :, 0:1]
+
+    def ref(q, k, v):
+        return _dot_product_attention(q[:, :, None], k[:, :, None],
+                                      v[:, :, None], True,
+                                      scale)[:, :, 0].astype(jnp.float32)
+
+    return fn, (q, k, v), ref
+
+
+def _ring_flash_case(n_shards, seed=0):
+    """Ring flash fwd+bwd under shard_map over a `seq` axis of the
+    attached devices."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from flexflow_tpu.ops.jax_ops import _dot_product_attention
+    from flexflow_tpu.ops.pallas.ring_flash import ring_flash_attention
+    from flexflow_tpu.parallel.compat import shard_map
+
+    B, S, h, hkv = 2, 512 * n_shards, 8, 4
+    rs = np.random.RandomState(seed)
+    q = jnp.asarray(rs.randn(B, S, h, D), jnp.bfloat16)
+    k = jnp.asarray(rs.randn(B, S, hkv, D), jnp.bfloat16)
+    v = jnp.asarray(rs.randn(B, S, hkv, D), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(B, S, h, D), jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+    mesh = Mesh(np.array(jax.devices()[:n_shards]), ("seq",))
+    spec = P(None, "seq", None, None)
+
+    def ring(q, k, v):
+        return shard_map(
+            lambda q, k, v: ring_flash_attention(
+                q, k, v, axis_name="seq", n_shards=n_shards, causal=True,
+                scale=scale),
+            mesh, (spec, spec, spec), spec, check_vma=False)(q, k, v)
+
+    return (_loss_grads(ring, w), (q, k, v),
+            _loss_grads(lambda q, k, v: _dot_product_attention(
+                q, k, v, True, scale), w))
+
+
+def kernel_cases(n_devices: int = 1):
+    """name -> zero-argument builder of (fn, args, ref_fn)."""
+    cases = {
+        "flash_fwd_bwd_d128": lambda: _flash_case(128),
+        "flash_fwd_bwd_d64": lambda: _flash_case(64),
+        "ring_carry_d128": _ring_carry_case,
+    }
+    if n_devices > 1:
+        cases[f"ring_flash_x{n_devices}"] = (
+            lambda: _ring_flash_case(n_devices))
+    for qdt, pdt, P in RAGGED_POOLS:
+        for kind, S in RAGGED_WINDOWS:
+            cases[f"ragged_{kind}{S}_q{qdt}_kv{pdt}_p{P}"] = (
+                lambda kind=kind, S=S, qdt=qdt, pdt=pdt, P=P:
+                _ragged_case(kind, S, qdt, pdt, P))
+    return cases
+
+
+def _rel_err(got, ref):
+    """Largest per-leaf ||got - ref|| / ||ref||."""
+    errs = []
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        g = np.asarray(g, np.float32)
+        r = np.asarray(r, np.float32)
+        if not np.isfinite(g).all():
+            return float("inf")
+        errs.append(float(np.linalg.norm(g - r)
+                          / max(np.linalg.norm(r), 1e-30)))
+    return max(errs)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--only", default="", help="substring filter on names")
+    args = ap.parse_args(argv)
+    dev = jax.devices()
+    print(f"platform={dev[0].platform} device_kind={dev[0].device_kind} "
+          f"count={len(dev)} jax={jax.__version__}", flush=True)
+    if dev[0].platform != "tpu":
+        print("no TPU attached: this check compiles with Mosaic",
+              file=sys.stderr)
+        return 2
+    failed = 0
+    for name, build in kernel_cases(len(dev)).items():
+        if args.only not in name:
+            continue
+        try:
+            fn, fargs, ref = build()
+            got = jax.block_until_ready(jax.jit(fn)(*fargs))
+            want = jax.block_until_ready(jax.jit(ref)(*fargs))
+            err = _rel_err(got, want)
+            # bf16 inputs, f32 accumulation on both sides
+            ok = err < 2e-2
+            print(f"{'OK  ' if ok else 'FAIL'} {name} rel_err={err:.3e}",
+                  flush=True)
+        except Exception as e:  # report every case, then fail the run
+            ok = False
+            msg = " ".join(str(e).split())
+            print(f"FAIL {name} {type(e).__name__}: {msg[:1500]}",
+                  flush=True)
+        failed += not ok
+    print(f"{failed} case(s) failed")
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
