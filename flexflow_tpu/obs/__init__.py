@@ -101,6 +101,14 @@ def span(name: str):
     return Span(rec, name)
 
 
+def beacon() -> None:
+    """Emit a clock beacon (TraceRecorder.beacon) when enabled; one
+    global load and an `is None` test when not."""
+    rec = _recorder
+    if rec is not None:
+        rec.beacon()
+
+
 __all__ = [
     "COUNT_BUCKETS",
     "BoundedRing",
@@ -117,6 +125,7 @@ __all__ = [
     "TIME_BUCKETS_S",
     "TickLedger",
     "TraceRecorder",
+    "beacon",
     "disable",
     "dump_jsonl",
     "enable",

@@ -39,6 +39,7 @@ plus one clock read per wrapped call on the hit path.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -48,12 +49,24 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 # frame is the call any compile event belongs to
 _tls = threading.local()
 _listener_state = {"installed": None}  # None = not tried yet
+# jax.monitoring duration events -> the keys of compile_split()'s dict;
+# backend_compile is the XLA compile or, on a persistent-cache hit, the load
+_SPLIT_KEYS = {"jaxpr_trace_duration": "trace_s",
+               "jaxpr_to_mlir_module_duration": "lower_s",
+               "backend_compile_duration": "backend_s"}
 _install_lock = threading.Lock()
 
 
 def _on_duration_event(name: str, seconds: float, **_kw) -> None:
+    if not name.startswith("/jax/core/compile/"):
+        return
+    split = getattr(_tls, "split", None)
+    if split is not None:
+        key = _SPLIT_KEYS.get(name.rsplit("/", 1)[-1])
+        if key is not None:
+            split[key] += float(seconds)
     stack = getattr(_tls, "stack", None)
-    if not stack or not name.startswith("/jax/core/compile/"):
+    if not stack:
         return
     frame = stack[-1]
     frame["seconds"] += float(seconds)
@@ -77,6 +90,23 @@ def _install_listener() -> bool:
                 except ImportError:
                     _listener_state["installed"] = False
     return _listener_state["installed"]
+
+
+@contextlib.contextmanager
+def compile_split():
+    """Seconds jax spends tracing, lowering and backend-compiling (or
+    loading from the persistent cache) on THIS thread while the context
+    is open, as {"trace_s", "lower_s", "backend_s"}; zeros when the
+    monitoring hook is unavailable. For set-up records only (the
+    `warm_shape` span): the tick path never opens one."""
+    split = dict.fromkeys(_SPLIT_KEYS.values(), 0.0)
+    _install_listener()
+    outer = getattr(_tls, "split", None)
+    _tls.split = split
+    try:
+        yield split
+    finally:
+        _tls.split = outer
 
 
 def _default_sig(args: Sequence[Any]) -> Tuple[int, ...]:

@@ -19,6 +19,19 @@ Design constraints, in order:
      host span names alongside the `jax.named_scope` Node.stable_key()
      metadata the executor stamps into HLO (see analysis/hloaudit.py) —
      one vocabulary from scheduler tick down to fused kernel.
+  4. Links. Every live span carries an `id` (recorder counter) and a
+     `parent` (the id of the span open on the same thread when it
+     started, else None) in its attrs, so a reader computes self time
+     (duration minus its children's) and walks from a leaf to its tick
+     without guessing from intervals. The event tuple stays
+     `(name, t0_ns, dur_ns, tid, attrs)`.
+  5. One clock with the device trace. `beacon()` emits, at most every
+     `BEACON_NS`, a zero-length TraceAnnotation named
+     `ffclock:<time.monotonic_ns()>` plus an `ffclock` instant event.
+     The profiler stamps the annotation on ITS clock, so a reader of
+     the xplane host plane recovers (profiler clock - monotonic clock)
+     from the names alone and lays any span, with its attributes, on
+     the device timeline.
 
 Export is Chrome-trace/Perfetto `trace_event` JSON: tick-phase spans as
 complete ("X") events on their thread's track, per-request lifecycle as
@@ -28,11 +41,13 @@ queued/prefill/decode "X" events on one synthetic track per request
 
 from __future__ import annotations
 
+import collections
 import gzip
+import itertools
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional
 
 from flexflow_tpu.obs.ledger import TickLedger
 
@@ -57,6 +72,9 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+BEACON_NAME = "ffclock"
+BEACON_NS = 250_000_000     # at most one clock beacon per 250 ms
+
 
 class Span:
     """One live span; created only when a recorder is installed."""
@@ -66,7 +84,7 @@ class Span:
     def __init__(self, rec: "TraceRecorder", name: str):
         self._rec = rec
         self.name = name
-        self.attrs: Optional[Dict] = None
+        self.attrs: Dict = {"id": next(rec._ids), "parent": None}
         self._t0 = 0
         self._tid = 0
         self._ann = None
@@ -75,14 +93,15 @@ class Span:
         return True
 
     def set(self, **attrs) -> "Span":
-        if self.attrs is None:
-            self.attrs = attrs
-        else:
-            self.attrs.update(attrs)
+        self.attrs.update(attrs)
         return self
 
     def __enter__(self):
         self._tid = threading.get_ident()
+        stack = self._rec._open_spans()
+        if stack:
+            self.attrs["parent"] = stack[-1]
+        stack.append(self.attrs["id"])
         ann_cls = self._rec._annotation
         if ann_cls is not None:
             try:
@@ -100,29 +119,43 @@ class Span:
                 self._ann.__exit__(*exc)
             except Exception:
                 pass
+        # pop down to this span: one left open by an exception between a
+        # manual __enter__/__exit__ pair must not adopt later spans
+        stack = self._rec._open_spans()
+        me = self.attrs["id"]
+        while stack and stack.pop() != me:
+            pass
         self._rec._finish(self.name, self._t0, t1 - self._t0, self._tid,
                           self.attrs)
         return False
 
 
 class TraceRecorder:
-    """Collects span events in memory (bounded), owns the TickLedger,
-    and exports Chrome-trace JSON. Appends happen from the scheduler
-    thread while readers may export from another — all mutation is
-    list.append / int adds, safe under the GIL, and export snapshots
-    with list() first."""
+    """Collects span events in memory, owns the TickLedger, and exports
+    Chrome-trace JSON. The events are a RING of `max_events`: once full,
+    each new event pushes out the oldest and `dropped` counts it — a
+    flight recorder keeps what happened last (an idle loop records three
+    events a millisecond, so keeping the FIRST 200k left a server that
+    idled a minute, or warmed up cold, with no span of its traffic).
+    Appends happen from the scheduler thread while readers may export
+    from another — all mutation is deque.append / int adds, safe under
+    the GIL, and export snapshots with copy() first."""
 
     def __init__(self, max_events: int = 200_000,
                  annotate_device: bool = True):
         self.max_events = int(max_events)
-        # (name, ts_ns, dur_ns, tid, attrs) complete events
-        self.events: List[tuple] = []
+        # (name, ts_ns, dur_ns, tid, attrs) complete events, newest kept
+        self.events: Deque[tuple] = collections.deque(
+            maxlen=self.max_events)
         self.dropped = 0
         # (rid, label, submit_ns, admit_ns, first_ns, done_ns, attrs)
         self.requests: List[tuple] = []
         self._req_seq = 0
         self.ledger = TickLedger()
         self.t0_ns = time.monotonic_ns()
+        self._ids = itertools.count(1)      # next() is atomic under the GIL
+        self._tls = threading.local()       # per-thread stack of open ids
+        self._beacon_ns = 0
         self._annotation = None
         if annotate_device:
             try:
@@ -137,16 +170,39 @@ class TraceRecorder:
     def span(self, name: str) -> Span:
         return Span(self, name)
 
+    def _open_spans(self) -> List[int]:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
     def _finish(self, name, t0, dur, tid, attrs):
         if len(self.events) >= self.max_events:
-            self.dropped += 1
-            return
+            self.dropped += 1       # the append below pushes the oldest out
         self.events.append((name, t0, dur, tid, attrs))
 
     def instant(self, name: str, **attrs):
-        if len(self.events) < self.max_events:
-            self.events.append((name, time.monotonic_ns(), 0,
-                                threading.get_ident(), attrs or None))
+        self._finish(name, time.monotonic_ns(), 0, threading.get_ident(),
+                     attrs or None)
+
+    def beacon(self) -> None:
+        """Tie this recorder's clock to the profiler's, at most every
+        BEACON_NS: a zero-length annotation whose NAME carries the
+        monotonic stamp taken just before it opens (the profiler stamps
+        its start on its own clock), and an instant event with the same
+        stamp for readers of the span list."""
+        stamp = time.monotonic_ns()
+        if stamp - self._beacon_ns < BEACON_NS:
+            return
+        self._beacon_ns = stamp
+        ann_cls = self._annotation
+        if ann_cls is not None:
+            try:
+                with ann_cls(f"{BEACON_NAME}:{stamp}"):
+                    pass
+            except Exception:
+                pass
+        self.instant(BEACON_NAME, stamp=stamp)
 
     def record_request(self, submit_t: float, admit_t: Optional[float],
                        first_token_t: Optional[float], done_t: float,
@@ -183,7 +239,7 @@ class TraceRecorder:
              "args": {"name": "fftrace: requests"}},
         ]
         tids = set()
-        for name, t0, dur, tid, attrs in list(self.events):
+        for name, t0, dur, tid, attrs in self.events.copy():
             tids.add(tid)
             e = {"name": name, "ph": "X", "cat": "tick", "pid": 1,
                  "tid": tid, "ts": self._us(t0 - self.t0_ns),

@@ -822,7 +822,12 @@ class PagedGenerationServer(_GenerationServerBase):
             raise RuntimeError(
                 "absorb_requests() requires a server whose loop has not "
                 "started (construct with defer_start=True)")
-        self._requeue[:0] = list(reqs)
+        reqs = list(reqs)
+        with self._lock:
+            for req in reqs:    # the predecessor's seq means nothing here
+                self._submitted += 1
+                req.seq = self._submitted
+        self._requeue[:0] = reqs
 
     def adopt_pool_from(self, old: "PagedGenerationServer") -> bool:
         """Take over the predecessor's PagePool and device caches when
@@ -1242,43 +1247,67 @@ class PagedGenerationServer(_GenerationServerBase):
         import jax.numpy as jnp
 
         B = len(items)
-        ids = np.zeros((B, window), np.int32)
-        pos = np.zeros((B,), np.int32)
-        qls = np.zeros((B,), np.int32)
-        slot_idx = np.zeros((B,), np.int32)
-        # the causal-chain default (decode rows, chunk pieces) is a pure
-        # function of the launch shape — reuse its device copy instead of
-        # re-uploading it every tick; only drafted trees override it
-        chain = all(d is None and a is None for (_s, _p, _t, d, a) in items)
-        if chain:
-            deps_d, anc_d = self._chain_descriptor_device(B, window)
-        else:
-            deps = np.tile(np.arange(window, dtype=np.int32), (B, 1))
-            anc = np.tile(np.tril(np.ones((window, window), np.bool_)),
-                          (B, 1, 1))
-        for i, (slot, p, toks, d, a) in enumerate(items):
-            ql = len(toks)
-            ids[i, :ql] = toks
-            pos[i] = p
-            qls[i] = ql
-            slot_idx[i] = slot
-            if d is not None:
-                deps[i] = d
-            if a is not None:
-                anc[i] = a
-        if not chain:
-            deps_d, anc_d = jnp.asarray(deps), jnp.asarray(anc)
-        # page tables ride the dirty-flagged device mirror: the canonical
-        # one-item-per-slot decode launch uses it as-is, packed launches
-        # gather their rows on device from a (B,) index upload
-        tbl = self._tables_device()
-        if B != self.slots or not np.array_equal(
-                slot_idx, np.arange(self.slots, dtype=np.int32)):
-            tbl = jnp.take(tbl, jnp.asarray(slot_idx), axis=0)
-        probs, upd = self._step(
-            tr, ntr, self._caches, tbl,
-            jnp.asarray(pos), jnp.asarray(qls), deps_d, anc_d,
-            jnp.asarray(ids))
+        with obs.span("launch_build") as sp:
+            if sp:
+                sp.set(items=B, window=window)
+            ids = np.zeros((B, window), np.int32)
+            pos = np.zeros((B,), np.int32)
+            qls = np.zeros((B,), np.int32)
+            slot_idx = np.zeros((B,), np.int32)
+            # the causal-chain default (decode rows, chunk pieces) is a
+            # pure function of the launch shape — reuse its device copy
+            # instead of re-uploading it every tick; only drafted trees
+            # override it
+            chain = all(d is None and a is None
+                        for (_s, _p, _t, d, a) in items)
+            if not chain:
+                deps = np.tile(np.arange(window, dtype=np.int32), (B, 1))
+                anc = np.tile(np.tril(np.ones((window, window), np.bool_)),
+                              (B, 1, 1))
+            for i, (slot, p, toks, d, a) in enumerate(items):
+                ql = len(toks)
+                ids[i, :ql] = toks
+                pos[i] = p
+                qls[i] = ql
+                slot_idx[i] = slot
+                if d is not None:
+                    deps[i] = d
+                if a is not None:
+                    anc[i] = a
+        with obs.span("launch_h2d") as sp:
+            if sp:
+                sp.set(tables_dirty=self._tables_dev is None)
+            if chain:
+                deps_d, anc_d = self._chain_descriptor_device(B, window)
+            else:
+                deps_d, anc_d = jnp.asarray(deps), jnp.asarray(anc)
+            # page tables ride the dirty-flagged device mirror: the
+            # canonical one-item-per-slot decode launch uses it as-is,
+            # packed launches gather their rows on device from a (B,)
+            # index upload
+            tbl = self._tables_device()
+            if B != self.slots or not np.array_equal(
+                    slot_idx, np.arange(self.slots, dtype=np.int32)):
+                tbl = jnp.take(tbl, jnp.asarray(slot_idx), axis=0)
+            pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
+                                   jnp.asarray(ids))
+        total = B * window
+        padded = total - int(qls.sum())
+        with obs.span("launch_dispatch") as sp:
+            if sp:
+                # what the ragged kernel has to walk for THIS launch,
+                # counted at the launch: KV rows and pages of the items
+                # with work, and causal (query, key) pairs
+                q = qls[qls > 0].astype(np.int64)
+                p0 = pos[qls > 0].astype(np.int64)
+                P = self.page_size
+                sp.set(rows=total, padded_rows=padded,
+                       kv_rows=int((p0 + q).sum()),
+                       kv_pages=int((-(-(p0 + q) // P)).sum()),
+                       qk_pairs=int((q * p0 + q * (q + 1) // 2).sum()))
+            probs, upd = self._step(
+                tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
+                ids_d)
         self._caches = upd
         if self._caches_ref is not None:
             # quant-error sampling (FF_TPU_KV_QUANT_DEBUG=1): the same
@@ -1286,17 +1315,14 @@ class PagedGenerationServer(_GenerationServerBase):
             # output delta over LIVE rows stays on device — metrics()
             # materializes it into the kv_quant_error gauge on scrape
             probs_ref, upd_ref = self._step(
-                tr, ntr, self._caches_ref, tbl,
-                jnp.asarray(pos), jnp.asarray(qls), deps_d, anc_d,
-                jnp.asarray(ids))
+                tr, ntr, self._caches_ref, tbl, pos_d, qls_d, deps_d,
+                anc_d, ids_d)
             self._caches_ref = upd_ref
             live_rows = jnp.asarray(
                 np.arange(window)[None, :] < qls[:, None])
             delta = jnp.max(jnp.abs(probs - probs_ref)
                             * live_rows[:, :, None])
             self._quant_err_dev = jnp.maximum(self._quant_err_dev, delta)
-        total = B * window
-        padded = total - int(qls.sum())
         self._c_rows.inc(total)
         self._c_pad.inc(padded)
         return probs, padded, total
@@ -1308,6 +1334,7 @@ class PagedGenerationServer(_GenerationServerBase):
         (nothing live; sleeps briefly when nothing was admitted
         either)."""
         with obs.span("tick_prep") as sp:
+            obs.beacon()    # ties the spans' clock to a device trace's
             if self._defrag_req.is_set():
                 self._defrag_req.clear()
                 with obs.span("defrag"):
@@ -1395,7 +1422,8 @@ class PagedGenerationServer(_GenerationServerBase):
                                   None, None))
                 ends.append((len(items) - 1, (take - 1) % W))
             probs, padded, total = self._launch(items, W, tr, ntr)
-            rows = [probs[i:i + 1, r, :] for i, r in ends]
+            with obs.span("sample"):    # the rows to pick from: eager slices
+                rows = [probs[i:i + 1, r, :] for i, r in ends]
         else:
             rows = []
             for s, req, start, take in plan:
@@ -1406,30 +1434,40 @@ class PagedGenerationServer(_GenerationServerBase):
                 rows.append(p[0:1, take - 1, :])
                 padded += pad
                 total += tot
-        for (s, req, start, take), row in zip(plan, rows):
-            req.prefill_pos = start + take
-            req.prefill_tokens += take
-            self._publish_prefix(req, req.prefill_pos)
-            if req.prefill_pos >= req.prefill_target:
-                # publish the PROMPT's partial tail now, before decode
-                # appends rows to the same page: the entry only names
-                # rows [0, tail) and those are immutable, so an
-                # identical or extending prompt can COW-clone this page
-                # while this request keeps decoding into it (the first
-                # token is appended below, so seq_tokens() still equals
-                # prefill_seq here)
-                self._publish_tail(req)
-                self._sample_first_token(s, req, row)
-                self._finish_if_done(s)
-                if self._active[s] is not None:
-                    # disagg hook: a PrefillWorker hands the request off
-                    # to its decode worker here instead of decoding it
-                    self._on_prefill_complete(s)
+        with obs.span("commit") as csp:
+            first = []  # seq of each request that got its first token
+            for (s, req, start, take), row in zip(plan, rows):
+                req.prefill_pos = start + take
+                req.prefill_tokens += take
+                self._publish_prefix(req, req.prefill_pos)
+                if req.prefill_pos >= req.prefill_target:
+                    # publish the PROMPT's partial tail now, before
+                    # decode appends rows to the same page: the entry
+                    # only names rows [0, tail) and those are immutable,
+                    # so an identical or extending prompt can COW-clone
+                    # this page while this request keeps decoding into
+                    # it (the first token is appended below, so
+                    # seq_tokens() still equals prefill_seq here)
+                    self._publish_tail(req)
+                    self._sample_first_token(s, req, row)
+                    first.append(req.seq)
+                    self._finish_if_done(s)
+                    if self._active[s] is not None:
+                        # disagg hook: a PrefillWorker hands the request
+                        # off to its decode worker here instead of
+                        # decoding it
+                        self._on_prefill_complete(s)
+            if csp:
+                csp.set(rids=first,
+                        finished=sum(1 for _s, req, _a, _t in plan
+                                     if req.future.done()))
         chunked = self.prefill_chunk - budget
         self._g_waste.set(padded / total if total else 0.0)
         if sp:
             sp.set(slots=len(slots), chunk_tokens=chunked,
-                   padded_rows=padded, total_rows=total)
+                   padded_rows=padded, total_rows=total,
+                   rids=[req.seq for _s, req, _a, _t in plan],
+                   takes=[take for _s, _r, _a, take in plan])
         sp.__exit__(None, None, None)
         dt = time.monotonic() - t0
         self._h_prefill.observe(dt)
@@ -1462,9 +1500,14 @@ class PagedGenerationServer(_GenerationServerBase):
         self._g_waste.set(padded / total if total else 0.0)
         if sp:
             sp.set(padded_rows=padded, total_rows=total)
-        self._rng, sub = jax.random.split(self._rng)
-        toks = np.asarray(self._pick(probs[:, -1, :],
-                                     self._temps_device(), sub))
+        with obs.span("sample"):
+            self._rng, sub = jax.random.split(self._rng)
+            picked = self._pick(probs[:, -1, :], self._temps_device(), sub)
+        with obs.span("fetch") as fsp:
+            # the host's wait for the device: step, pick and the copy out
+            toks = np.asarray(picked)
+            if fsp:
+                fsp.set(bytes=int(toks.nbytes))
         self._steps += 1
         # one host round-trip bought len(live) tokens — the same
         # counters the megastep path feeds, so N=1 vs N>1 compare
@@ -1475,13 +1518,21 @@ class PagedGenerationServer(_GenerationServerBase):
         for s in self._admit_order:
             if self._mid_prefill(s):
                 self._active[s].decode_overlap_ticks += 1
-        for s in live:
-            req = self._active[s]
-            req.pos += 1
-            req.tokens.append(int(toks[s]))
-            self._tokens[s] = toks[s]
-            self._publish_prefix(req, req.pos)
-            self._finish_if_done(s)
+        with obs.span("commit") as csp:
+            if csp:
+                rids = [self._active[s].seq for s in live]
+                csp.set(rids=rids)
+                sp.set(rids=rids)
+            for s in live:
+                req = self._active[s]
+                req.pos += 1
+                req.tokens.append(int(toks[s]))
+                self._tokens[s] = toks[s]
+                self._publish_prefix(req, req.pos)
+                self._finish_if_done(s)
+            if csp:
+                csp.set(finished=sum(1 for s in live
+                                     if self._active[s] is None))
         sp.__exit__(None, None, None)
         dt = time.monotonic() - t0
         self._h_tick.observe(dt)

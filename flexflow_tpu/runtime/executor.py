@@ -1398,6 +1398,12 @@ class Executor:
         "rng_ref"} — the serving layer warms its (batch, vocab) sampling
         program (the one entry the executor does not own) from slices of
         probs_ref and splits of rng_ref."""
+        import contextlib
+        import time
+
+        from flexflow_tpu import obs
+        from flexflow_tpu.obs.compile_tracker import compile_split
+
         cfg = dict(catalog.get("config", {}))
         entries = catalog.get("entries", {})
         tr, ntr = params
@@ -1440,10 +1446,24 @@ class Executor:
                 # launch outputs from the first tick on — warm both;
                 # the first call's output IS the serve-loop committed
                 # pool state
-                probs, caches_out = step(tr, ntr, caches_u, *args)
-                if caches_c is None:
-                    caches_c = caches_out
-                probs, _ = step(tr, ntr, caches_c, *args)
+                with obs.span("warm_shape") as sp:
+                    t0 = time.monotonic()
+                    with (compile_split() if sp
+                          else contextlib.nullcontext()) as split:
+                        probs, caches_out = step(tr, ntr, caches_u, *args)
+                    if sp:
+                        # for the record of set-up: what THIS shape's
+                        # first call cost (jax's own compile phases; they
+                        # nest, so they need not sum to call_s) and how
+                        # long its first run then kept the device
+                        t1 = time.monotonic()
+                        probs.block_until_ready()
+                        sp.set(window=W, rows=B * W, **split,
+                               call_s=t1 - t0,
+                               first_run_s=time.monotonic() - t1)
+                    if caches_c is None:
+                        caches_c = caches_out
+                    probs, _ = step(tr, ntr, caches_c, *args)
                 if probs_ref is None or B == slots:
                     probs_ref = probs
                 warmed += 1

@@ -426,7 +426,7 @@ class _GenRequest:
                  "cached_prefill_tokens", "prefill_pos", "prefill_target",
                  "prefill_seq", "hashed_blocks", "decode_overlap_ticks",
                  "compile_s_at_submit", "first_compile_s",
-                 "spilled_pages", "fetched_pages", "routed_to")
+                 "spilled_pages", "fetched_pages", "routed_to", "seq")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float):
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -434,6 +434,11 @@ class _GenRequest:
         self.temperature = float(temperature)
         self.future: Future = Future()
         self.tokens: List[int] = []
+        # this server's id for the request from submission on (spans'
+        # `rids`, the request log's `seq`); `rid` exists only once it
+        # completes. 0 = not submitted; a handed-off request is stamped
+        # again by the server that takes it
+        self.seq = 0
         self.pos = 0  # next cache write position for this slot
         # paged-path bookkeeping / per-request metrics
         self.pages: List[int] = []      # pool pages held (paged only)
@@ -592,6 +597,7 @@ class _GenerationServerBase:
         # guards the _running/queue.put pair against a submit racing stop()
         self._lock = threading.Lock()
         self._running = True
+        self._submitted = 0     # stamps _GenRequest.seq, under _lock
         self._served = 0
         self._steps = 0
         # per-request records ride a ring buffer (cumulative counters and
@@ -681,9 +687,14 @@ class _GenerationServerBase:
         # compile-clock baseline: compile seconds accrued later, before
         # this request's first token, are ITS attributable compile cost
         req.compile_s_at_submit = self._compile_tracker.compile_seconds_total
+        return self._enqueue(req)
+
+    def _enqueue(self, req: _GenRequest) -> Future:
         with self._lock:
             if not self._running:
                 raise RuntimeError(f"{type(self).__name__} is stopped")
+            self._submitted += 1
+            req.seq = self._submitted
             self._queue.put(req)
         return req.future
 
@@ -699,11 +710,7 @@ class _GenerationServerBase:
         if req.compile_s_at_submit == 0.0 and not req.tokens:
             req.compile_s_at_submit = (
                 self._compile_tracker.compile_seconds_total)
-        with self._lock:
-            if not self._running:
-                raise RuntimeError(f"{type(self).__name__} is stopped")
-            self._queue.put(req)
-        return req.future
+        return self._enqueue(req)
 
     def generate(self, prompt_ids: np.ndarray, max_new_tokens: int,
                  temperature: float = 0.0) -> np.ndarray:
@@ -881,10 +888,17 @@ class _GenerationServerBase:
         import jax
         import jax.numpy as jnp
 
-        self._rng, sub = jax.random.split(self._rng)
-        tok = int(np.asarray(self._pick(
-            row_probs, jnp.full((1,), req.temperature, jnp.float32),
-            sub))[0])
+        with obs.span("sample"):
+            self._rng, sub = jax.random.split(self._rng)
+            picked = self._pick(
+                row_probs, jnp.full((1,), req.temperature, jnp.float32),
+                sub)
+        with obs.span("fetch") as sp:
+            # the host's wait for the device: the launch, the pick, the copy
+            fetched = np.asarray(picked)
+            if sp:
+                sp.set(bytes=int(fetched.nbytes))
+        tok = int(fetched[0])
         req.pos = len(req.seq_tokens())  # before the append below
         req.tokens.append(tok)
         self._tokens[slot] = tok
@@ -961,6 +975,7 @@ class _GenerationServerBase:
                    else done_t)
         rec = {
             "rid": self._served + 1,
+            "seq": req.seq,
             "label": f"req {self._served + 1}",
             "submit_ns": int(req.submit_t * 1e9),
             "admit_ns": int(admit_t * 1e9),

@@ -5,6 +5,7 @@ Chrome-trace export, tick ledger, and predicted-vs-measured calibration
 import gzip
 import json
 import threading
+import time
 import tracemalloc
 
 import numpy as np
@@ -805,3 +806,301 @@ def test_fftrace_replay_cli(tmp_path, capsys):
         "decode_tokens"]
     for k in ("ttft_p50_s", "ttft_p95_s", "tokens_per_s"):
         assert k in paced["delta"]
+
+
+# ---------------------------------------------------------------------------
+# span links, request ids from submission, the phases inside a tick, the
+# clock beacon (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+
+def test_span_links_across_two_threads():
+    """Every span has an id of its own and the id of the span open on the
+    SAME thread when it started; another thread's open span is no parent."""
+    rec = obs.enable()
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with obs.span("worker"):
+            with obs.span("worker_child"):
+                inside.set()
+                release.wait(10)
+
+    with obs.span("tick"):
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with obs.span("inner"):     # opened while `worker` is open elsewhere
+            with obs.span("leaf"):
+                pass
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+    with obs.span("after"):
+        pass
+    obs.disable()
+    by = {e[0]: e[4] for e in rec.events}
+    ids = [a["id"] for a in by.values()]
+    assert len(set(ids)) == len(ids) == 6
+    assert by["tick"]["parent"] is None and by["worker"]["parent"] is None
+    assert by["inner"]["parent"] == by["tick"]["id"]
+    assert by["leaf"]["parent"] == by["inner"]["id"]
+    assert by["worker_child"]["parent"] == by["worker"]["id"]
+    assert by["after"]["parent"] is None     # the stack unwound
+    # set() adds to the links, it does not replace them
+    rec = obs.enable()
+    with obs.span("a") as sp:
+        sp.set(live=2)
+    assert set(rec.events[0][4]) == {"id", "parent", "live"}
+
+
+def test_recorder_is_a_ring_that_keeps_the_newest():
+    """Once full, the recorder pushes out the OLDEST event: a loop that
+    idled through a long warm-up still has the spans of its traffic."""
+    rec = obs.enable(max_events=5)
+    for i in range(8):
+        with obs.span("idle") as sp:
+            sp.set(i=i)
+    rec.instant("mark", n=1)
+    with obs.span("traffic"):
+        pass
+    obs.disable()
+    assert len(rec.events) == 5 and rec.dropped == 5
+    assert [e[0] for e in rec.events] == ["idle"] * 3 + ["mark", "traffic"]
+    assert [e[4]["i"] for e in rec.events if e[0] == "idle"] == [5, 6, 7]
+    assert rec.chrome_trace()["otherData"]["dropped_events"] == 5
+
+
+def test_span_left_open_does_not_adopt_later_spans():
+    """A manual __enter__ whose __exit__ an exception skipped must not
+    become the parent of every later span on the thread."""
+    rec = obs.enable()
+    with obs.span("outer"):
+        obs.span("lost").__enter__()        # never exited
+    with obs.span("next"):
+        pass
+    by = {e[0]: e[4] for e in rec.events}
+    assert by["next"]["parent"] is None
+
+
+def test_beacon_is_rate_limited_and_named_by_its_stamp():
+    names = []
+
+    class Ann:
+        def __init__(self, name):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    rec = obs.enable()
+    rec._annotation = Ann
+    t_lo = time.monotonic_ns()
+    obs.beacon()
+    obs.beacon()                    # within BEACON_NS of the first: dropped
+    rec._beacon_ns -= 2 * obs.trace.BEACON_NS
+    obs.beacon()
+    t_hi = time.monotonic_ns()
+    obs.disable()
+    obs.beacon()                    # disabled: nothing, and no error
+    assert len(names) == 2 and all(n.startswith("ffclock:") for n in names)
+    stamps = [int(n.split(":", 1)[1]) for n in names]
+    assert t_lo <= stamps[0] <= stamps[1] <= t_hi
+    inst = [e for e in rec.events if e[0] == "ffclock"]
+    assert [e[4]["stamp"] for e in inst] == stamps
+    assert all(e[2] == 0 for e in inst)
+
+
+def _paged_pair(ff, lcfg, rec_annotation=None, **kw):
+    """Two scripted requests (prompts of 5 and 11 tokens, 3 new tokens
+    each) through a 2-slot paged server whose loop starts only after both
+    are queued: page 8, prefill budget 8 a tick, so request 1 finishes its
+    prompt in the first prefill tick and request 2 in the second."""
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(0, lcfg.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 11)]
+    srv = ff.serve_generation(slots=2, max_len=32, paged=True, page_size=8,
+                              prefill_chunk=8, defer_start=True, **kw)
+    try:
+        futs = [srv.submit(p, max_new_tokens=3) for p in prompts]
+        srv.start()
+        tokens = [f.result(timeout=300) for f in futs]
+        records = srv.request_log.records()
+    finally:
+        srv.stop()
+    return prompts, tokens, records
+
+
+def test_disabled_paged_tick_builds_no_span_attrs_or_beacon(monkeypatch):
+    """With tracing off a paged server's whole tick path (launch phases,
+    sample, fetch, commit, beacon) touches NULL_SPAN only: no Span is
+    built, no attribute is set, no beacon is emitted; a request still gets
+    its `seq` at submission."""
+    ff, lcfg = _causal_lm()
+    spans = []
+    real_span = obs.span
+
+    def watched(name):
+        sp = real_span(name)
+        spans.append((name, sp))
+        return sp
+
+    def forbidden(*a, **kw):
+        raise AssertionError("built while tracing is off")
+
+    monkeypatch.setattr(obs, "span", watched)
+    monkeypatch.setattr(obs.trace.Span, "__init__", forbidden)
+    monkeypatch.setattr(obs.trace._NullSpan, "set", forbidden)
+    monkeypatch.setattr(obs.trace.TraceRecorder, "beacon", forbidden)
+    monkeypatch.setattr(obs.trace.TraceRecorder, "instant", forbidden)
+    _prompts, tokens, records = _paged_pair(ff, lcfg)
+    assert [len(t) for t in tokens] == [3, 3]
+    seen = {name for name, _sp in spans}
+    assert {"tick_prep", "prefill_tick", "decode_tick", "launch_build",
+            "launch_h2d", "launch_dispatch", "sample", "fetch",
+            "commit"} <= seen
+    assert all(sp is obs.NULL_SPAN for _name, sp in spans)
+    assert sorted(r["seq"] for r in records) == [1, 2]
+
+
+def test_traced_paged_tick_phases(tmp_path):
+    """A traced tiny paged server: (a) every phase lies inside its parent
+    and siblings never overlap; (b) token times rebuilt from `commit.rids`
+    give len(tokens) per request, the first within 1 ms of the record's
+    `first_token_ns`; (c) `kv_rows`/`kv_pages`/`qk_pairs` of every launch
+    equal an independent count from the requests' own progress; (d) the
+    beacons parse to stamps inside the run; the request log carries `seq`
+    and `fftrace summarize` prints self time from the links."""
+    import tools.fftrace as fft
+    from flexflow_tpu.paged.scheduler import PREFILL_WINDOW_ROWS
+
+    ff, lcfg = _causal_lm()
+    rec = obs.enable()
+    t_lo = time.monotonic_ns()
+    prompts, tokens, records = _paged_pair(ff, lcfg)
+    t_hi = time.monotonic_ns()
+    obs.disable()
+    assert rec.dropped == 0
+    events = [e for e in rec.events if e[2] > 0 or e[0] != "ffclock"]
+    by_id = {e[4]["id"]: e for e in events}
+    kids = {}
+    for e in events:
+        if e[4]["parent"] is not None:
+            kids.setdefault(e[4]["parent"], []).append(e)
+
+    # (a) containment and no overlap among siblings
+    leaves = {"launch_build", "launch_h2d", "launch_dispatch", "sample",
+              "fetch", "commit"}
+    assert leaves <= {e[0] for e in events}
+    for e in events:
+        if e[0] in leaves:
+            top = e
+            while top[4]["parent"] is not None:
+                par = by_id[top[4]["parent"]]
+                assert par[1] <= top[1] and top[1] + top[2] <= par[1] + par[2]
+                top = par
+            assert top[0] in ("prefill_tick", "decode_tick")
+    for sibs in kids.values():
+        sibs.sort(key=lambda e: e[1])
+        for a, b in zip(sibs, sibs[1:]):
+            assert a[1] + a[2] <= b[1]
+    # nested sample/fetch of a finishing prompt are the commit's children
+    assert any(by_id[e[4]["parent"]][0] == "commit"
+               for e in events if e[0] == "fetch")
+
+    # (b) token times from commit.rids
+    rec_by_seq = {r["seq"]: r for r in records}
+    assert sorted(rec_by_seq) == [1, 2]
+    times = {1: [], 2: []}
+    for e in sorted(events, key=lambda e: e[1] + e[2]):
+        if e[0] == "commit":
+            for seq in e[4]["rids"]:
+                times[seq].append(e[1] + e[2])
+    for seq, toks in zip((1, 2), tokens):
+        assert len(times[seq]) == len(toks) == 3
+        assert abs(times[seq][0] - rec_by_seq[seq]["first_token_ns"]) < 1e6
+    finished = sum(e[4]["finished"] for e in events if e[0] == "commit")
+    assert finished == 2
+
+    # (c) the launch counts against the requests' own progress
+    P, W_MAX = 8, PREFILL_WINDOW_ROWS
+    plen = {1: len(prompts[0]), 2: len(prompts[1])}
+    filled = {1: 0, 2: 0}       # prompt rows in the cache
+    made = {1: 0, 2: 0}         # tokens generated
+    ticks = sorted((e for e in events
+                    if e[0] in ("prefill_tick", "decode_tick")),
+                   key=lambda e: e[1])
+    assert ticks[0][4]["rids"] == [1, 2] and ticks[0][4]["takes"] == [5, 3]
+    for tick in ticks:
+        launch = [k for k in kids[tick[4]["id"]]
+                  if k[0] == "launch_dispatch"]
+        assert len(launch) == 1
+        got = launch[0][4]
+        items = []                              # (pos, q_len) with work
+        if tick[0] == "prefill_tick":
+            W = min(W_MAX, max(tick[4]["takes"]))
+            for seq, take in zip(tick[4]["rids"], tick[4]["takes"]):
+                for off in range(0, take, W):
+                    items.append((filled[seq] + off, min(W, take - off)))
+                filled[seq] += take
+        else:
+            items = [(plen[seq] + made[seq] - 1, 1)
+                     for seq in tick[4]["rids"]]
+        assert got["kv_rows"] == sum(p + q for p, q in items)
+        assert got["kv_pages"] == sum(-(-(p + q) // P) for p, q in items)
+        assert got["qk_pairs"] == sum(
+            sum(p + i for i in range(1, q + 1)) for p, q in items)
+        assert got["rows"] - got["padded_rows"] == sum(q for _p, q in items)
+        commit = [k for k in kids[tick[4]["id"]] if k[0] == "commit"]
+        assert len(commit) == 1
+        for seq in commit[0][4]["rids"]:
+            made[seq] += 1
+    assert filled == plen and made == {1: 3, 2: 3}
+
+    # (d) beacons
+    stamps = [e[4]["stamp"] for e in rec.events if e[0] == "ffclock"]
+    assert stamps and all(t_lo <= s <= t_hi for s in stamps)
+
+    # the operator's use of the links: self time beside total
+    path = rec.export_chrome_trace(str(tmp_path / "t.json"))
+    import contextlib
+    import io
+
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        assert fft.main(["summarize", path]) == 0
+    rows = {ln.split()[0]: ln.split() for ln in out.getvalue().splitlines()}
+    assert rows["span"][2:4] == ["total_ms", "self_ms"]
+    total, self_ = float(rows["decode_tick"][2]), float(rows["decode_tick"][3])
+    assert 0.0 <= self_ < total
+    assert float(rows["fetch"][2]) == float(rows["fetch"][3])   # a leaf
+
+
+def test_warm_shape_spans_split_compile_seconds():
+    """Traced, warm_launch_shapes() records one `warm_shape` span a ragged
+    launch shape with what it cost, by jax's own compile phases."""
+    ff, lcfg = _causal_lm()
+    rec = obs.enable()
+    srv = ff.serve_generation(slots=2, max_len=16, paged=True, page_size=8,
+                              defer_start=True)
+    try:
+        catalog = srv.warm_launch_shapes()
+    finally:
+        srv.stop()
+    obs.disable()
+    shapes = catalog["entries"]["ragged_step"]["shapes"]
+    warm = [e[4] for e in rec.events if e[0] == "warm_shape"]
+    assert len(warm) == len(shapes)
+    assert sorted((w["rows"] // w["window"], w["window"]) for w in warm) \
+        == sorted((int(b), int(w)) for b, w in shapes)
+    for w in warm:
+        assert {"trace_s", "lower_s", "backend_s", "call_s",
+                "first_run_s"} <= set(w)
+        assert min(w["trace_s"], w["lower_s"], w["backend_s"],
+                   w["first_run_s"]) >= 0.0
+        assert w["call_s"] >= w["backend_s"]
+    assert sum(w["backend_s"] for w in warm) > 0.0

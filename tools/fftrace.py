@@ -41,8 +41,10 @@ Subcommands:
       tools/servesearch.py) refuse reports older than 7 days.
 
   summarize TRACE
-      Per-span-name counts and total/mean durations of a trace written
-      by `smoke` (or TraceRecorder.export_chrome_trace), .gz or plain.
+      Per-span-name counts, total and SELF time (a span's duration minus
+      its children's, by the spans' id/parent links) and mean durations
+      of a trace written by `smoke` (or
+      TraceRecorder.export_chrome_trace), .gz or plain.
 
 Open trace.json.gz directly in https://ui.perfetto.dev (it accepts
 gzipped Chrome traces) — pid 1 is the tick loop, pid 2 the per-request
@@ -282,19 +284,27 @@ def cmd_summarize(args) -> int:
     with opener(args.trace, "rt") as f:
         doc = json.load(f)
     events = doc["traceEvents"] if isinstance(doc, dict) else doc
+    spans = [ev for ev in events if ev.get("ph") == "X"]
+    # time under each span's children, by the parent links spans carry
+    under = {}
+    for ev in spans:
+        parent = (ev.get("args") or {}).get("parent")
+        if parent is not None:
+            under[parent] = under.get(parent, 0.0) + float(ev.get("dur", 0.0))
     by_name = {}
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
+    for ev in spans:
         name = ev["name"].split(":", 1)[0]  # collapse per-request labels
-        n, total = by_name.get(name, (0, 0.0))
-        by_name[name] = (n + 1, total + float(ev.get("dur", 0.0)))
+        dur = float(ev.get("dur", 0.0))
+        n, total, self_ = by_name.get(name, (0, 0.0, 0.0))
+        by_name[name] = (n + 1, total + dur, self_ + dur - under.get(
+            (ev.get("args") or {}).get("id"), 0.0))
     width = max((len(n) for n in by_name), default=4)
-    print(f"{'span':<{width}}  {'count':>6}  {'total_ms':>10}  {'mean_us':>9}")
-    for name, (n, total) in sorted(by_name.items(),
-                                   key=lambda kv: -kv[1][1]):
+    print(f"{'span':<{width}}  {'count':>6}  {'total_ms':>10}  "
+          f"{'self_ms':>10}  {'mean_us':>9}")
+    for name, (n, total, self_) in sorted(by_name.items(),
+                                          key=lambda kv: -kv[1][1]):
         print(f"{name:<{width}}  {n:>6}  {total / 1e3:>10.2f}  "
-              f"{total / n:>9.1f}")
+              f"{self_ / 1e3:>10.2f}  {total / n:>9.1f}")
     return 0
 
 
