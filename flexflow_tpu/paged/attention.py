@@ -19,28 +19,37 @@ per-slot metadata:
                         (chunks: lower-triangular causal; trees: the
                         ancestor-or-self mask; decode: ones((1, 1))).
 
-One Pallas kernel consumes that descriptor: the grid walks
-(batch, kv head, page) with the page table, positions and query lengths
-SCALAR-PREFETCHED, so each page's K/V block DMAs straight from its
-pooled HBM location into VMEM — no gathered copy of the sequence ever
-materializes, and no (B, S, L) HBM mask is built either: the window
-visibility is derived IN-KERNEL from `anc` via a one-hot matmul against
-the page's relative positions. Pages wholly past a slot's visible
-horizon (pos + q_len - 1) are skipped, as are padded batch entries
-(q_len == 0). GQA groups the q heads of one kv head into a single
-(rep, S, D) block, so kv pages are read once per GROUP and never
-repeated.
+One Pallas kernel consumes that descriptor. The grid is (batch,), with
+the page table, positions and query lengths SCALAR-PREFETCHED and the
+pools left in HBM: an entry's grid step walks the entry's LIVE pages —
+up to its visible horizon pos + q_len - 1, never the table's width — in
+blocks of `ragged_block_pages` pages, copying each page's whole
+(page_size, Hkv * D) row block from its pooled HBM location into a
+double-buffered VMEM block with one DMA, the next block (or the next
+entry's first) in flight while this one computes. No gathered copy of
+the sequence ever materializes, and no (B, S, L) HBM mask is built
+either: blocks wholly below pos take no mask, and the block(s) the
+window reaches derive its visibility IN-KERNEL from `anc` via a one-hot
+matmul against the block's relative positions. Padded batch entries
+(q_len == 0) walk nothing and write zeros. Every kv head is computed in
+the step its block arrives in, and GQA folds the q heads of one kv head
+into the ROW dim (row = window row x rep + head), so a head's scores are
+ONE (rows, D) x (D, keys) matmul and kv pages are read once per entry.
+
+The block size is derived at trace time from the page's bytes, the
+window's rows, the table's width and the core's VMEM
+(`ragged_block_pages`); there is nothing to configure.
 
 Pool layout: FLAT-LANE pages, (num_pages, page_size, Hkv * D) — a cache
 row is one token's K (or V) for every kv head side by side on the lane
 dim, the same layout the flash kernels read projections in
-(ops/pallas/flash_attention.py). The kernel's page block is
-(page_size, D) and the kv head coordinate picks its 128-aligned lane
-block, which is the only way Mosaic can window one head of a page: a
-block that squeezes the head out of a second-minor (…, Hkv, D) dim is
-refused, and reshaping a head-minor pool at the pallas_call boundary
-is a physical relayout of the whole pool under TPU tiling, per layer
-per step. The append is one Hkv*D-lane row per token.
+(ops/pallas/flash_attention.py). That makes a page one contiguous HBM
+region, which a single copy moves, and a kv head a 128-aligned lane
+slice of the block in VMEM, which is the only way Mosaic can window one
+head of a page: a block that squeezes the head out of a second-minor
+(…, Hkv, D) dim is refused, and reshaping a head-minor pool at the
+pallas_call boundary is a physical relayout of the whole pool under TPU
+tiling, per layer per step. The append is one Hkv*D-lane row per token.
 
 The single pure-JAX fallback (`ragged_gather_attention`) gathers
 ``pool[page_table]`` and applies the same visibility as a materialized
@@ -106,7 +115,7 @@ def paged_attention_available(head_dim: int, page_size: int,
     (there is no per-variant rejection matrix any more).
     FF_TPU_NO_PAGED=1 disables the kernel everywhere (A/B runs and
     kernel-bug escape hatch, like FF_TPU_NO_FLASH). On real TPUs the
-    head dim must be a lane multiple (the kernel windows one head's
+    head dim must be a lane multiple (the kernel slices one head's
     D-wide lane block out of a flat-lane page row; smaller head dims
     take the gather fallback, mirroring the flash bshd gate) and pages
     must tile the sublane dim AT THE POOL'S DTYPE — (8, 128) tiles for
@@ -225,110 +234,227 @@ def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
 
 
 # ---------------------------------------------------------------------------
-# the ragged Pallas kernel: grid (B, Hkv, page); page table, positions and
-# query lengths prefetched; window visibility derived in-kernel
+# the ragged Pallas kernel: grid (B,); per entry a double-buffered walk of
+# the slot's LIVE pages in blocks, every kv head a step; page table,
+# positions and query lengths prefetched; window visibility derived
+# in-kernel, and only in the blocks the window reaches
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, page_size, n_pages, rep, quantized):
-    """One (batch entry, kv head, page) grid step. Every tile is 2-D —
-    (rows, D) q per head of the group, (P, D) K/V page, (rows, P)
-    scores — the only shapes Mosaic's matmul takes; the q heads of the
-    group are a static loop over the leading block dim."""
-    if quantized:
-        ks_ref, vs_ref, anc_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        anc_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    b, j = pl.program_id(0), pl.program_id(2)
-    rows, window = anc_ref.shape
+# What the block size is derived from. The K and V block buffers, double
+# buffered, take this share of the core's VMEM (4 MiB of a v5e's 128:
+# a quarter of the 16 MiB a kernel is scoped to by default, the rest is
+# q / out / statistics and Mosaic's own score temporaries); one block's
+# float32 score tile (rows x keys) is held to _SCORE_TILE_BYTES so a wide
+# window shortens the block instead of spilling it.
+_KV_VMEM_SHARE = 32
+_SCORE_TILE_BYTES = 1 << 20
+_VMEM_BYTES_ASSUMED = 128 << 20    # v4 / v5e / v5p / v6e, per core
 
-    @pl.when(j == 0)
+
+def _vmem_capacity_bytes() -> int:
+    """The attached TPU core's VMEM; off the chip (the CPU tests lower
+    and interpret the same kernel) the 128 MiB every generation since
+    v4 has, so a lowering test sees the block the chip will run."""
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except Exception:  # no TPU attached: nothing to ask
+        return _VMEM_BYTES_ASSUMED
+
+
+def ragged_block_pages(page_size: int, table_width: int, lane_width: int,
+                       pool_dtype, q_rows: int) -> int:
+    """Pages a grid step's block holds: ONE derivation for decode, chunk
+    and tree windows and every pool dtype, from what a launch's trace
+    can see. As many whole pages as (a) the double-buffered K and V
+    block buffers fit in their VMEM share at this page's bytes
+    (page_size x lane_width x itemsize), (b) keep the (q_rows, keys)
+    float32 score tile within _SCORE_TILE_BYTES, and (c) the table has
+    (rounded up to whole 128-key lane tiles, which (a) and (b) round
+    down to where a block is that long). `q_rows` is the folded row
+    count, rep x window (paged/scheduler.py counts a launch's kv_blocks
+    with the same call)."""
+    page_bytes = page_size * lane_width * jnp.dtype(pool_dtype).itemsize
+    budget = _vmem_capacity_bytes() // _KV_VMEM_SHARE
+    by_vmem = budget // (4 * page_bytes)          # K, V x two buffers
+    by_score = _SCORE_TILE_BYTES // (4 * max(q_rows, 1) * page_size)
+    tile = max(1, LANES // page_size)             # pages per 128 keys
+    ppb = max(1, min(by_vmem, by_score))
+    if ppb >= tile:
+        ppb -= ppb % tile
+    return max(1, min(ppb, _round_up(table_width, tile)))
+
+
+def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
+                   scale, page_size, ppb, rep, quantized):
+    """One batch entry a grid step: walk the entry's live pages in
+    blocks of `ppb`, each page ONE contiguous (P, Hkv * D) copy from its
+    pooled HBM row into a double-buffered VMEM block, the kv heads lane
+    slices of it. The q heads of a kv group are folded into the row dim
+    (row = window row x rep + head of the group), so a head's scores are
+    one (rows, D) x (D, keys) matmul. The next block — the next ENTRY's
+    first block after the last — is in flight while this one computes;
+    which buffer holds it survives the grid step in SMEM."""
+    if quantized:
+        (ks_ref, vs_ref, anc_ref, o_ref, kbuf, vbuf, sems, par_ref,
+         bias_scr, m_scr, l_scr, acc_scr) = rest
+    else:
+        (anc_ref, o_ref, kbuf, vbuf, sems, par_ref, bias_scr, m_scr,
+         l_scr, acc_scr) = rest
+    b = pl.program_id(0)
+    n_entries = pl.num_programs(0)
+    n_table = pt_ref.shape[1]
+    rows, window = anc_ref.shape
+    keys = ppb * page_size
+    n_heads, _, D = q_ref.shape
+
+    def live_pages(e):
+        # pages up to the entry's visible horizon pos + q_len - 1; a
+        # padded entry (q_len == 0) walks nothing
+        horizon = pos_ref[e] + qlen_ref[e]
+        n = jnp.minimum((horizon + page_size - 1) // page_size, n_table)
+        return jnp.where(qlen_ref[e] > 0, n, 0)
+
+    def block_copies(e, j, buf, fn):
+        """fn(copy) for the K and V copies of entry e's block j."""
+        first = j * ppb
+        n = jnp.clip(live_pages(e) - first, 0, ppb)
+
+        def one(i, _):
+            page = pt_ref[e, first + i]
+            dst = pl.ds(pl.multiple_of(i * page_size, page_size),
+                        page_size)
+            fn(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, dst],
+                                     sems.at[buf]))
+            fn(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, dst],
+                                     sems.at[buf]))
+            return 0
+
+        lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
     def _():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # a block's tail past the live pages is never copied, and its
+        # scores are masked by ADDING: what VMEM held before the launch
+        # must not read as NaN (NaN - 1e30, 0 x NaN)
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        par_ref[0] = 0
+        block_copies(0, 0, 0, lambda c: c.start())
 
     pos = pos_ref[b]
     qlen = qlen_ref[b]
-    # pages wholly past the slot's visible horizon (committed prefix +
-    # its own q_len window rows) contribute nothing, and padded batch
-    # entries (q_len == 0) do no work at all — skip the MXU work
-    # entirely (the masked-out math would be exp(-inf) = 0)
-    @pl.when((j * page_size <= pos + qlen - 1) & (qlen > 0))
+    n_blocks = (live_pages(b) + ppb - 1) // ppb
+    par = par_ref[0]
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # blocks wholly below pos are all visible: the additive mask stays
+    # zero until the walk reaches the window
+    bias_scr[...] = jnp.zeros_like(bias_scr)
+
+    def start_next(j, buf):
+        # the entry's next block, or the next entry's first
+        within = j + 1 < n_blocks
+        e = jnp.minimum(jnp.where(within, b, b + 1), n_entries - 1)
+
+        @pl.when(within | (b + 1 < n_entries))
+        def _():
+            block_copies(e, jnp.where(within, j + 1, 0), buf,
+                         lambda c: c.start())
+
+    @pl.when(n_blocks == 0)
     def _():
-        k = k_ref[...]                       # (P, D)
-        v = v_ref[...]
-        # window visibility without a gather and without an HBM mask:
-        # column c holds cache row j*P + c, i.e. window index
-        # rel[c] = j*P + c - pos. One-hot it against the window rows
-        # (zeroing indices past q_len) and contract with the anc
-        # relation: (anc @ onehot)[t, c] = anc[t, rel[c]] when
-        # 0 <= rel[c] < q_len, else 0. The contraction dim is the
-        # window padded to a lane multiple, so the matmul is aligned.
-        wrow = lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
-        rel = j * page_size - pos + lax.broadcasted_iota(
-            jnp.int32, (window, page_size), 1)
-        onehot = ((rel == wrow) & (wrow < qlen)).astype(jnp.float32)
-        tree_vis = lax.dot_general(
-            anc_ref[...], onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) > 0.5   # (rows, P)
-        col = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
-        vis = (col < pos) | tree_vis
-        if quantized:
-            # quantized pool: the page's per-head scale rode in as a
-            # (1, P) row addressed by the same (b, j) as the page, so
-            # dequant-on-load is a row-broadcast multiply on the SCORES
-            # and the PROBABILITIES — the int8 page is what DMA'd from
-            # HBM and what the MXU contracts; fp K/V never exist
-            cdt = jnp.float32
-        else:
-            # a mixed-precision pool (e.g. bf16 kv_dtype under an fp32
-            # model) computes at q's dtype: dot_general needs matching
-            # operand dtypes
-            cdt = q_ref.dtype
-        k = k.astype(cdt)
-        v = v.astype(cdt)
-        for r in range(rep):  # fflint: host-ok (static unroll in the kernel trace)
-            q = q_ref[r].astype(cdt)         # (rows, D)
+        start_next(-1, par)
+
+    def block(j, _):
+        buf = (par + j) % 2
+        start_next(j, 1 - buf)
+        block_copies(b, j, buf, lambda c: c.wait())
+        first_key = j * keys
+
+        @pl.when(first_key + keys > pos)
+        def _():
+            # the window's visibility without a gather and without an
+            # HBM mask: column c holds cache row first_key + c, window
+            # index rel[c] = first_key + c - pos. One-hot it against
+            # the window rows (zeroing indices past q_len) and contract
+            # with the anc relation: (anc @ onehot)[t, c] =
+            # anc[t, rel[c]] when 0 <= rel[c] < q_len, else 0 (0 / 1 in
+            # bfloat16, one term a sum: exact).
+            wrow = lax.broadcasted_iota(jnp.int32, (window, keys), 0)
+            rel = first_key - pos + lax.broadcasted_iota(
+                jnp.int32, (window, keys), 1)
+            onehot = ((rel == wrow) & (wrow < qlen)).astype(anc_ref.dtype)
+            tree_vis = lax.dot_general(
+                anc_ref[...], onehot, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) > 0.5  # (rows, keys)
+            col = first_key + lax.broadcasted_iota(
+                jnp.int32, (rows, keys), 1)
+            bias_scr[...] = jnp.where((col < pos) | tree_vis, 0.0, NEG_INF)
+
+        bias = bias_scr[...]
+        # quantized pool: the int8 page is what DMA'd from HBM and what
+        # the MXU contracts; the per-page, per-head scales rode in as
+        # one (Hkv, keys) row block a walk block, and dequant-on-load is
+        # a row-broadcast multiply on the SCORES and the PROBABILITIES,
+        # in float32 — fp K/V never exist. Otherwise compute at q's
+        # dtype (a bf16 pool under an fp32 model: dot_general needs
+        # matching operand dtypes)
+        cdt = jnp.float32 if quantized else q_ref.dtype
+
+        def head(h, _):
+            lanes = pl.ds(pl.multiple_of(h * D, D), D)
+            q = q_ref[h].astype(cdt)                        # (rows, D)
+            k = kbuf[buf, :, lanes].astype(cdt)             # (keys, D)
+            v = vbuf[buf, :, lanes].astype(cdt)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if quantized:
-                s = s * ks_ref[...]
-            s = jnp.where(vis, s, NEG_INF)
-            m_prev = m_scr[r, :, 0:1]
-            l_prev = l_scr[r, :, 0:1]
+                s = s * ks_ref[j, pl.ds(h, 1)]
+            s = s + bias
+            m_prev = m_scr[h, :, 0:1]
+            l_prev = l_scr[h, :, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
             l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
             if quantized:
-                p = p * vs_ref[...]
-            pc = p.astype(cdt)  # fflint: dtype-ok (this head's p, in VMEM)
+                p = p * vs_ref[j, pl.ds(h, 1)]
+            pc = p.astype(cdt)                              # in VMEM
             pv = lax.dot_general(pc, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-            acc_scr[r] = acc_scr[r] * corr + pv
-            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            acc_scr[h] = acc_scr[h] * corr + pv
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return 0
 
-    # finalize UNCONDITIONALLY: a padded entry whose every page was
-    # skipped must still write (zeros), not leave o_ref as garbage —
-    # and rows at or past q_len are forced to zero even when they
-    # accumulated prefix attention (they share the entry's pages, so
-    # the compute loop cannot skip them row-wise)
-    @pl.when(j == n_pages - 1)
-    def _():
-        live = lax.broadcasted_iota(jnp.int32, acc_scr.shape[1:], 0) < qlen
-        for r in range(rep):  # fflint: host-ok (static unroll in the kernel trace)
-            l_safe = jnp.maximum(l_scr[r, :, 0:1], 1e-30)
-            o_ref[r] = jnp.where(live, acc_scr[r] / l_safe,
-                                 0.0).astype(o_ref.dtype)
+        lax.fori_loop(0, n_heads, head, 0)
+        return 0
+
+    lax.fori_loop(0, n_blocks, block, 0)
+    par_ref[0] = (par + n_blocks) % 2
+
+    # finalize UNCONDITIONALLY: a padded entry that walked nothing must
+    # still write (zeros), not leave o_ref as garbage — and rows at or
+    # past q_len are forced to zero even when they accumulated prefix
+    # attention (they share the entry's pages, so the walk cannot skip
+    # them row-wise). Folded row i is window row i // rep.
+    live = lax.broadcasted_iota(jnp.int32, acc_scr.shape[1:], 0) < qlen * rep
+
+    def flush(h, _):
+        l_safe = jnp.maximum(l_scr[h, :, 0:1], 1e-30)
+        o_ref[h] = jnp.where(live, acc_scr[h] / l_safe,
+                             0.0).astype(o_ref.dtype)
+        return 0
+
+    lax.fori_loop(0, n_heads, flush, 0)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                            q_lens, anc_mask, *, scale: float,
                            interpret: bool = False, k_scales=None,
@@ -338,70 +464,92 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
     kc/vc_pages: (N, P, Hkv*D) flat-lane pages (module docstring);
     page_tables: (B, max_pages); pos, q_lens: (B,); anc_mask: (B, S, S)
     bool window visibility. The page table, positions AND query lengths
-    ride scalar prefetch, so each grid step's BlockSpec index map
-    resolves `pt[b, j]` BEFORE the DMA and the horizon/padding skip
-    predicates on prefetched scalars; the kv head coordinate picks the
-    page's D-wide lane block. The anc relation is one VMEM block per
-    batch entry — the only mask state, O(B*S^2) instead of a (B, S, L)
-    HBM mask. q rows are padded to the sublane tile and the window to a
-    lane multiple so every in-kernel matmul is tile-aligned. For a
-    quantized pool, k_scales/v_scales are the (N, Hkv) sidecar: the
-    table-mapped scales are gathered here (B * max_pages * Hkv floats)
-    and each grid step reads its page's scale as a (1, P) row. Rows at
-    or past q_lens[b] output zeros."""
+    ride scalar prefetch; the pools stay in HBM and the kernel copies
+    each live page of an entry itself, `ragged_block_pages` pages a
+    block. The anc relation is one VMEM block per batch entry — the
+    only mask state, O(B*S^2) instead of a (B, S, L) HBM mask. The q
+    heads of a kv group fold into the row dim (row = s * rep + r), rows
+    padded to the sublane tile and the window to a lane multiple so
+    every in-kernel matmul is tile-aligned. For a quantized pool,
+    k_scales/v_scales are the (N, Hkv) sidecar: the table-mapped scales
+    are gathered here and repeated along each page's rows (B * max_pages
+    * Hkv * P floats, what the per-page blocks held before), one
+    (Hkv, keys) block a walk block. Rows at or past q_lens[b] output
+    zeros. Jitted so that the layers of a model trace and lower ONE
+    kernel a launch shape."""
     B, S, H, D = q.shape
     P = kc_pages.shape[1]
     Hkv = kc_pages.shape[2] // D
     rep = H // Hkv
     n_pages = page_tables.shape[1]
-    rows = _round_up(S, 8 * (4 // q.dtype.itemsize))
+    rows = _round_up(rep * S, 8 * (4 // q.dtype.itemsize))
     window = _round_up(S, LANES)
-    qr = jnp.pad(q.transpose(0, 2, 1, 3),
-                 ((0, 0), (0, 0), (0, rows - S), (0, 0)))   # (B, H, rows, D)
-    anc_f = jnp.pad(anc_mask.astype(jnp.float32),
-                    ((0, 0), (0, rows - S), (0, window - S)))
+    ppb = ragged_block_pages(P, n_pages, Hkv * D, kc_pages.dtype, rep * S)
+    keys = ppb * P
     quantized = k_scales is not None
+    # (B, S, Hkv, rep, D) -> (B, Hkv, S * rep, D): a kv group's heads
+    # are adjacent rows of one tile
+    qr = q.reshape(B, S, Hkv, rep, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, S * rep, D)
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - S * rep), (0, 0)))
+    anc_f = jnp.pad(
+        jnp.repeat(anc_mask, rep, axis=1).astype(jnp.bfloat16),
+        ((0, 0), (0, rows - S * rep), (0, window - S)))
 
-    qmap = lambda b, g, j, pt, ps, ql: (b, g, 0, 0)         # noqa: E731
-    kvmap = lambda b, g, j, pt, ps, ql: (pt[b, j], 0, g)    # noqa: E731
+    qmap = lambda b, pt, ps, ql: (b, 0, 0, 0)               # noqa: E731
     in_specs = [
-        pl.BlockSpec((None, rep, rows, D), qmap),
-        pl.BlockSpec((None, P, D), kvmap),
-        pl.BlockSpec((None, P, D), kvmap),
+        pl.BlockSpec((None, Hkv, rows, D), qmap),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [qr, kc_pages, vc_pages]
     if quantized:
-        smap = lambda b, g, j, pt, ps, ql: (b, g, j, 0, 0)  # noqa: E731
-        for sc in (k_scales, v_scales):  # fflint: host-ok (trace-time, K then V)
-            rows_sc = sc[page_tables].transpose(0, 2, 1)    # (B, Hkv, pages)
+        n_blocks = -(-n_pages // ppb)
+        for sc in (k_scales, v_scales):   # trace-time, K then V
+            rows_sc = jnp.pad(sc[page_tables],               # (B, pages, Hkv)
+                              ((0, 0), (0, n_blocks * ppb - n_pages),
+                               (0, 0)))
+            rows_sc = rows_sc.reshape(B, n_blocks, ppb, Hkv).transpose(
+                0, 1, 3, 2)
             operands.append(jnp.broadcast_to(
-                rows_sc[..., None, None], (B, Hkv, n_pages, 1, P)))
-            in_specs.append(pl.BlockSpec((None, None, None, 1, P), smap))
+                rows_sc[..., None],
+                (B, n_blocks, Hkv, ppb, P)).reshape(B, n_blocks, Hkv, keys))
+            in_specs.append(pl.BlockSpec((None, n_blocks, Hkv, keys), qmap))
     in_specs.append(pl.BlockSpec((None, rows, window),
-                                 lambda b, g, j, pt, ps, ql: (b, 0, 0)))
+                                 lambda b, pt, ps, ql: (b, 0, 0)))
     operands.append(anc_f)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, n_pages),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, rep, rows, D), qmap),
+        out_specs=pl.BlockSpec((None, Hkv, rows, D), qmap),
         scratch_shapes=[
-            pltpu.VMEM((rep, rows, LANES), jnp.float32),
-            pltpu.VMEM((rep, rows, LANES), jnp.float32),
-            pltpu.VMEM((rep, rows, D), jnp.float32),
+            pltpu.VMEM((2, keys, Hkv * D), kc_pages.dtype),
+            pltpu.VMEM((2, keys, Hkv * D), vc_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, keys), jnp.float32),
+            pltpu.VMEM((Hkv, rows, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=scale, page_size=P,
-                          n_pages=n_pages, rep=rep, quantized=quantized),
+                          ppb=ppb, rep=rep, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, rows, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
+        # the walk carries its buffer parity and an in-flight copy from
+        # one entry to the next: the grid is a sequence
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="ragged_paged_attention",
     )(page_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q_lens.astype(jnp.int32), *operands)
-    return out[:, :, :S].transpose(0, 2, 1, 3)
+    return out[:, :, :S * rep].reshape(B, Hkv, S, rep, D).transpose(
+        0, 2, 1, 3, 4).reshape(B, S, H, D)
 
 
 # ---------------------------------------------------------------------------

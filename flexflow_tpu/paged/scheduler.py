@@ -329,8 +329,12 @@ class PagedGenerationServer(_GenerationServerBase):
         # pool rows are flat-lane (Hkv*D); the gate wants the head dim
         from flexflow_tpu.runtime.executor import node_key as _node_key
 
-        head_dim = next(n.attrs.kdim for n in ex.topo
-                        if _node_key(n) == attn_key)
+        attn = next(n.attrs for n in ex.topo if _node_key(n) == attn_key)
+        head_dim = attn.kdim
+        # what the ragged kernel derives its block of pages from, kept
+        # for the launch_dispatch span's kv_blocks (tracing only)
+        self._block_geom = (kbuf.shape[2], kbuf.dtype,
+                            attn.num_heads // attn.num_kv)
         self.kernel_variant = "ragged_pallas" if paged_attention_available(
             head_dim, self.page_size,
             interpret=os.environ.get("FF_TPU_FLASH_INTERPRET") == "1",
@@ -1297,13 +1301,22 @@ class PagedGenerationServer(_GenerationServerBase):
             if sp:
                 # what the ragged kernel has to walk for THIS launch,
                 # counted at the launch: KV rows and pages of the items
-                # with work, and causal (query, key) pairs
+                # with work, the blocks of block_pages pages it walks
+                # them in, and causal (query, key) pairs
+                from flexflow_tpu.paged.attention import ragged_block_pages
+
                 q = qls[qls > 0].astype(np.int64)
                 p0 = pos[qls > 0].astype(np.int64)
                 P = self.page_size
+                pages = -(-(p0 + q) // P)
+                lanes, pool_dt, rep = self._block_geom
+                ppb = ragged_block_pages(P, self.max_pages_per_seq, lanes,
+                                         pool_dt, rep * window)
                 sp.set(rows=total, padded_rows=padded,
                        kv_rows=int((p0 + q).sum()),
-                       kv_pages=int((-(-(p0 + q) // P)).sum()),
+                       kv_pages=int(pages.sum()),
+                       kv_blocks=int((-(-pages // ppb)).sum()),
+                       block_pages=ppb,
                        qk_pairs=int((q * p0 + q * (q + 1) // 2).sum()))
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,

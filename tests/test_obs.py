@@ -971,7 +971,7 @@ def test_traced_paged_tick_phases(tmp_path):
     """A traced tiny paged server: (a) every phase lies inside its parent
     and siblings never overlap; (b) token times rebuilt from `commit.rids`
     give len(tokens) per request, the first within 1 ms of the record's
-    `first_token_ns`; (c) `kv_rows`/`kv_pages`/`qk_pairs` of every launch
+    `first_token_ns`; (c) `kv_rows`/`kv_pages`/`kv_blocks`/`qk_pairs` of every launch
     equal an independent count from the requests' own progress; (d) the
     beacons parse to stamps inside the run; the request log carries `seq`
     and `fftrace summarize` prints self time from the links."""
@@ -1052,6 +1052,12 @@ def test_traced_paged_tick_phases(tmp_path):
                      for seq in tick[4]["rids"]]
         assert got["kv_rows"] == sum(p + q for p, q in items)
         assert got["kv_pages"] == sum(-(-(p + q) // P) for p, q in items)
+        # the walk's blocks at the kernel's derived block size: the fill
+        # a trace shows is kv_pages / (kv_blocks * block_pages)
+        ppb = got["block_pages"]
+        assert 1 <= ppb and got["kv_pages"] <= got["kv_blocks"] * ppb
+        assert got["kv_blocks"] == sum(
+            -(-(-(-(p + q) // P)) // ppb) for p, q in items)
         assert got["qk_pairs"] == sum(
             sum(p + i for i in range(1, q + 1)) for p, q in items)
         assert got["rows"] - got["padded_rows"] == sum(q for _p, q in items)

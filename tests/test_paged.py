@@ -155,67 +155,134 @@ def test_page_pool_defrag_compacts_and_remaps():
 # chunks, token trees and padded entries in ONE launch
 
 
+def _ragged_anc(kind, S, n):
+    """(S, S) window visibility of one entry with n live rows: a chain
+    (decode, chunk) is lower-triangular, a tree its ancestor-or-self
+    relation (root + two branches sharing it: a real non-causal mask),
+    a padded entry sees nothing."""
+    anc = np.zeros((S, S), bool)
+    if kind == "tree":
+        from flexflow_tpu.spec.tree import ancestor_masks
+
+        parents = np.full((S,), -1, np.int32)
+        parents[:n] = np.array([-1, 0, 1, 0, 3], np.int32)[:n]
+        anc[:] = ancestor_masks(parents[None])[0]
+    elif kind != "pad":
+        anc[:n, :n] = np.tril(np.ones((n, n), bool))
+    return anc
+
+
 def _ragged_entry(kind, S, rs):
     """(pos, q_len, anc) for one batch entry of a window-S launch."""
-    anc = np.zeros((S, S), bool)
     if kind == "pad":
-        return 0, 0, anc
-    if kind == "decode":
-        anc[0, 0] = True
-        return int(rs.randint(1, 28)), 1, anc
-    if kind == "chunk":
+        pos, n = 0, 0
+    elif kind == "decode":
+        pos, n = int(rs.randint(1, 28)), 1
+    elif kind == "chunk":
         n = int(rs.randint(2, S + 1))
-        anc[:n, :n] = np.tril(np.ones((n, n), bool))
-        return int(rs.randint(0, 24)), n, anc
-    # tree: root + two branches sharing the root (a real non-causal mask)
-    from flexflow_tpu.spec.tree import ancestor_masks
-
-    n = min(S, 5)
-    parents = np.full((S,), -1, np.int32)
-    parents[:n] = np.array([-1, 0, 1, 0, 3], np.int32)[:n]
-    anc[:] = ancestor_masks(parents[None])[0]
-    return int(rs.randint(0, 24)), n, anc
+        pos = int(rs.randint(0, 24))
+    else:
+        pos, n = int(rs.randint(0, 24)), min(S, 5)
+    return pos, n, _ragged_anc(kind, S, n)
 
 
-@pytest.mark.parametrize("H,Hkv,S,mix", [
-    (8, 2, 1, ["decode", "decode", "decode"]),
-    (8, 2, 4, ["chunk", "chunk"]),
-    (8, 2, 4, ["decode", "chunk", "pad"]),
-    (8, 2, 6, ["decode", "tree"]),
-    (8, 2, 6, ["decode", "chunk", "tree", "pad"]),
-    (4, 4, 6, ["decode", "chunk", "tree", "pad"]),  # MHA rep=1
+# Launches that cross what the blocked walk adds (name -> entries
+# (kind, pos, q_len) in units the test resolves against the kernel's OWN
+# derived block: K = keys a block, L = rows the table maps, E = where the
+# second block ends, T = where the table's last block starts). The wide
+# geometry is page 64, two kv heads x 128, a table 40 pages wide, so a
+# float32 pool walks blocks of 16 pages (16, 16, 8: the table is no
+# multiple of the block) and a bfloat16 one blocks of 32 (32, 8).
+_WIDE_CASES = {
+    # the horizon pos + q_len - 1 on the first / a middle / the last page
+    # of the second block
+    "horizon_pages": lambda K, L, P, E, T: [
+        ("chunk", K + 10, 6), ("chunk", (K + E) // 2 + 5, 6),
+        ("chunk", E - 20, 6), ("decode", E - 1, 1)],
+    # a window straddling two blocks (chain and tree), and windows that
+    # end / begin exactly on the boundary
+    "straddle": lambda K, L, P, E, T: [
+        ("chunk", K - 3, 6), ("tree", K - 2, 5), ("decode", K - 1, 1),
+        ("decode", K, 1), ("chunk", K - 6, 6)],
+    # pos = 0, slots with fewer live pages than a block, padded entries
+    # between live ones
+    "pos0_short_padded": lambda K, L, P, E, T: [
+        ("chunk", 0, 6), ("pad", 0, 0), ("decode", 100, 1), ("pad", 0, 0),
+        ("pad", 0, 0), ("tree", T + 5, 5), ("decode", 0, 1),
+        ("pad", 0, 0)],
+    # the table's last, partial block: a horizon inside it and the
+    # window on the table's very last rows
+    "table_end": lambda K, L, P, E, T: [
+        ("chunk", T + 300, 4), ("chunk", L - 6, 6),
+        ("decode", L - 1, 1), ("tree", L - P - 2, 5)],
+}
+
+
+@pytest.mark.parametrize("H,Hkv,S,mix,dtype", [
+    (8, 2, 1, ["decode", "decode", "decode"], None),
+    (8, 2, 4, ["chunk", "chunk"], None),
+    (8, 2, 4, ["decode", "chunk", "pad"], None),
+    (8, 2, 6, ["decode", "tree"], None),
+    (8, 2, 6, ["decode", "chunk", "tree", "pad"], None),
+    (4, 4, 6, ["decode", "chunk", "tree", "pad"], None),  # MHA rep=1
+    (4, 2, 6, "horizon_pages", "float32"),
+    (4, 2, 6, "straddle", "float32"),
+    (8, 2, 6, "straddle", "float32"),                     # rep=4 fold
+    (2, 2, 6, "straddle", "float32"),                     # MHA rep=1
+    (4, 2, 6, "pos0_short_padded", "float32"),
+    (4, 2, 6, "table_end", "float32"),
+    (4, 2, 6, "horizon_pages", "bfloat16"),   # bf16 pool under bf16 q
+    (4, 2, 6, "straddle", "bfloat16"),
+    (4, 2, 6, "table_end", "bfloat16"),
 ])
-def test_ragged_kernel_matches_gather_reference(H, Hkv, S, mix):
+def test_ragged_kernel_matches_gather_reference(H, Hkv, S, mix, dtype):
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.paged.attention import (
+        ragged_block_pages,
         ragged_flash_attention,
         ragged_gather_attention,
     )
 
-    B, D, P, N, MAXP = len(mix), 32, 8, 24, 4
-    rs = np.random.RandomState(1000 * S + len(mix))
+    if dtype is None:
+        # the narrow geometry: every slot lives inside one block
+        B, D, P, N, MAXP = len(mix), 32, 8, 24, 4
+        dtype, tol = "float32", 2e-5
+        rs = np.random.RandomState(1000 * S + len(mix))
+        entries = [_ragged_entry(k, S, rs) for k in mix]
+    else:
+        D, P, MAXP = 128, 64, 40
+        ppb = ragged_block_pages(P, MAXP, Hkv * D, dtype, (H // Hkv) * S)
+        # wider than one block, and no multiple of it
+        assert ppb < MAXP and MAXP % ppb
+        K, L = ppb * P, MAXP * P
+        kinds = _WIDE_CASES[mix](K, L, P, min(2 * K, L), (L - 1) // K * K)
+        B, N = len(kinds), len(kinds) * MAXP + 1
+        # bfloat16 on both sides rounds p and the output to 8 bits
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        rs = np.random.RandomState(len(mix))
+        entries = [(pos, n, _ragged_anc(k, S, n)) for k, pos, n in kinds]
+        mix = [k for k, _, _ in kinds]
     ks = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-    kc = jax.random.normal(ks[1], (N, P, Hkv * D), jnp.float32)
-    vc = jax.random.normal(ks[2], (N, P, Hkv * D), jnp.float32)
+    q = jax.random.normal(ks[0], (B, S, H, D), dtype)
+    kc = jax.random.normal(ks[1], (N, P, Hkv * D), dtype)
+    vc = jax.random.normal(ks[2], (N, P, Hkv * D), dtype)
     perm = rs.permutation(N - 1)[:B * MAXP] + 1  # distinct non-null pages
     pt = jnp.asarray(perm.reshape(B, MAXP).astype(np.int32))
-    entries = [_ragged_entry(k, S, rs) for k in mix]
     pos = jnp.asarray(np.array([e[0] for e in entries], np.int32))
     q_lens = jnp.asarray(np.array([e[1] for e in entries], np.int32))
     anc = jnp.asarray(np.stack([e[2] for e in entries]))
     scale = 1.0 / np.sqrt(D)
     ref = np.asarray(ragged_gather_attention(q, kc, vc, pt, pos, q_lens,
-                                             anc, scale=scale))
+                                             anc, scale=scale), np.float32)
     got = np.asarray(ragged_flash_attention(q, kc, vc, pt, pos, q_lens,
                                             anc, scale=scale,
-                                            interpret=True))
+                                            interpret=True), np.float32)
     for b, kind in enumerate(mix):
         n = int(q_lens[b])
-        np.testing.assert_allclose(got[b, :n], ref[b, :n], atol=2e-5,
-                                   rtol=2e-5, err_msg=f"entry {b} {kind}")
+        np.testing.assert_allclose(got[b, :n], ref[b, :n], atol=tol,
+                                   rtol=tol, err_msg=f"entry {b} {kind}")
         # the kernel's contract: rows at or past q_len are exact zeros
         # (the gather fallback's garbage rows differ — both discarded)
         assert not got[b, n:].any(), f"entry {b} {kind} padded tail"

@@ -209,9 +209,63 @@ def _mixed_ragged_outputs(quantized: bool):
                            for b in range(B)])
 
 
+def _blocked_ragged_outputs(quantized: bool):
+    """The mixed batch again, over a pool filled beforehand and a table
+    WIDER than the kernel's block (page 32, 8 kv heads x 128: an int8
+    pool walks blocks of 32 pages, the table has 40): a decode row whose
+    horizon ends inside the second block, a chunk and a tree whose
+    windows straddle the first boundary, a padded entry between live
+    ones, a chunk on the table's last rows. Returns the live rows."""
+    from flexflow_tpu.paged.attention import (ragged_block_pages,
+                                              ragged_paged_attention)
+
+    B, S, H, Hkv, D = 5, 4, 8, 8, 128
+    P, MAXP = 32, 40
+    N = B * MAXP + 1
+    K = P * ragged_block_pages(P, MAXP, Hkv * D, jnp.int8, (H // Hkv) * S)
+    assert K < MAXP * P
+    rs = np.random.RandomState(7)
+    rows = jnp.asarray(rs.randn(2, N, P, Hkv, D).astype(np.float32))
+    if quantized:
+        page = jnp.broadcast_to(jnp.arange(N)[:, None], (N, P))
+        off = jnp.broadcast_to(jnp.arange(P)[None], (N, P))
+        (kc, ks), (vc, vs) = (
+            quantized_append(jnp.zeros((N, P, Hkv * D), jnp.int8),
+                             jnp.zeros((N, Hkv), jnp.float32), x, page,
+                             off, jnp.ones((N, P), bool)) for x in rows)
+        sc = {"k_scales": ks, "v_scales": vs}
+    else:
+        kc, vc = (x.reshape(N, P, Hkv * D) for x in rows)
+        sc = {}
+    pt = jnp.asarray((rs.permutation(N - 1) + 1)
+                     .reshape(B, MAXP).astype(np.int32))
+    pos = jnp.asarray([K + 37, K - 2, 0, K - 1, MAXP * P - S], jnp.int32)
+    q_lens = jnp.asarray([1, 4, 0, 3, 4], jnp.int32)
+    depths = jnp.asarray([[0, 0, 0, 0], [0, 1, 2, 3], [0, 0, 0, 0],
+                          [0, 1, 1, 0], [0, 1, 2, 3]], jnp.int32)
+    anc = np.zeros((B, S, S), bool)
+    anc[0, 0, 0] = True
+    anc[1] = anc[4] = np.tril(np.ones((S, S), bool))
+    anc[3, 0, 0] = True
+    anc[3, 1, [0, 1]] = True
+    anc[3, 2, [0, 2]] = True
+    q, k, v = (jnp.asarray(rs.randn(B, S, h, D).astype(np.float32))
+               for h in (H, Hkv, Hkv))
+    out = ragged_paged_attention(q, k, v, kc, vc, pt, pos, q_lens, depths,
+                                 jnp.asarray(anc), scale=1.0 / np.sqrt(D),
+                                 rope_theta=10000.0, **sc)[0]
+    o = np.asarray(out)
+    return np.concatenate([o[b, :int(q_lens[b])].ravel()
+                           for b in range(B)])
+
+
+@pytest.mark.parametrize("batch", [_mixed_ragged_outputs,
+                                   _blocked_ragged_outputs],
+                         ids=["one-block", "blocks"])
 @pytest.mark.parametrize("interpret", [False, True],
                          ids=["gather", "interpret-kernel"])
-def test_mixed_ragged_batch_quantized_tolerance(interpret, monkeypatch):
+def test_mixed_ragged_batch_quantized_tolerance(interpret, batch,
+                                                monkeypatch):
     """int8 pool vs fp32 pool on the same mixed decode/chunk/tree batch:
     live output rows agree within a small tolerance on BOTH attention
     paths (the Pallas kernel's dequant-on-load and the gather
@@ -220,8 +274,8 @@ def test_mixed_ragged_batch_quantized_tolerance(interpret, monkeypatch):
         monkeypatch.setenv("FF_TPU_FLASH_INTERPRET", "1")
     else:
         monkeypatch.delenv("FF_TPU_FLASH_INTERPRET", raising=False)
-    ref = _mixed_ragged_outputs(quantized=False)
-    got = _mixed_ragged_outputs(quantized=True)
+    ref = batch(quantized=False)
+    got = batch(quantized=True)
     err = float(np.max(np.abs(got - ref)))
     assert 0.0 < err < MIXED_BATCH, err
 

@@ -146,6 +146,50 @@ def test_tree_kernel_matches_gather_reference(H, Hkv):
         assert not got[b, n:].any(), f"tree {b} padded tail"
 
 
+@pytest.mark.parametrize("H,Hkv", [(8, 2), (4, 4)])  # GQA and MHA
+def test_tree_kernel_straddles_block(H, Hkv):
+    """Trees whose nodes lie across the kernel's block boundary (page 64,
+    a table 40 pages wide: blocks of 16 pages at two kv heads x 128, of
+    8 at four): the root in one block and its branches in the next, the
+    whole tree on the next block's first rows, a short tree ending on
+    the boundary's last row."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.paged.attention import (
+        ragged_block_pages,
+        ragged_flash_attention,
+        ragged_gather_attention,
+    )
+
+    B, D, P, MAXP, T = 3, 128, 64, 40, 6
+    N = B * MAXP + 1
+    K = P * ragged_block_pages(P, MAXP, Hkv * D, jnp.float32,
+                               (H // Hkv) * T)
+    assert K < MAXP * P
+    ks = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
+    kc = jax.random.normal(ks[1], (N, P, Hkv * D), jnp.float32)
+    vc = jax.random.normal(ks[2], (N, P, Hkv * D), jnp.float32)
+    pt = jnp.asarray((np.random.RandomState(0).permutation(N - 1) + 1)
+                     .reshape(B, MAXP).astype(np.int32))
+    pos = jnp.asarray(np.array([K - 1, 2 * K, K - 4], np.int32))
+    parents = np.tile(np.array([-1, 0, 1, 2, 1, 0], np.int32), (B, 1))
+    anc = jnp.asarray(ancestor_masks(parents))
+    q_lens = jnp.asarray(np.array([T, T, 4], np.int32))
+    scale = 1.0 / np.sqrt(D)
+    ref = np.asarray(ragged_gather_attention(q, kc, vc, pt, pos, q_lens,
+                                             anc, scale=scale))
+    got = np.asarray(ragged_flash_attention(q, kc, vc, pt, pos, q_lens,
+                                            anc, scale=scale,
+                                            interpret=True))
+    for b in range(B):
+        n = int(q_lens[b])
+        np.testing.assert_allclose(got[b, :n], ref[b, :n], atol=2e-5,
+                                   rtol=2e-5, err_msg=f"tree {b}")
+        assert not got[b, n:].any(), f"tree {b} padded tail"
+
+
 # ---------------------------------------------------------------------------
 # executor level: one verify step over a CHAIN tree must reproduce the
 # sequential paged decode steps' logits exactly (mask/rope/page-write proof)

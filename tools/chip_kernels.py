@@ -9,7 +9,12 @@
     accepts only this run can say.
 
 Shapes: Llama-3-8B attention width (32 q / 8 kv heads x 128) for the ragged
-kernel and the D=128 flash kernels, plus the D=64 padded flash path.
+kernel and the D=128 flash kernels, plus the D=64 padded flash path; and the
+benchmark's own serving launches (`benchmark/configs/mistral-7b-serve1.json`:
+8 slots, page 64, bf16 pool, table 64 wide) as `ragged_bench_*`.
+
+`--time` also prints each ragged case's device microseconds a call (the
+kernel's own events in a profiler trace), for a before / after.
 """
 
 from __future__ import annotations
@@ -41,13 +46,31 @@ def _tree_anc(S):
     return ancestor_masks(parents[None])[0]
 
 
-def _ragged_case(kind, S, qdt, pdt, P, seed=0):
-    """(fn, args, ref_fn) for one ragged launch: 4 entries (two live at
-    different depths, one short, one padded) over a shuffled page table."""
+def _ragged_fns(S):
+    """(kernel fn, reference fn) over one argument list
+    (q, kc, vc, pt, pos, q_lens, anc[, k_scales, v_scales])."""
     from flexflow_tpu.paged.attention import (
         ragged_flash_attention,
         ragged_gather_attention,
     )
+
+    scale = 1.0 / np.sqrt(D)
+
+    def run(impl):
+        def fn(q, kc, vc, pt, pos, q_lens, anc, *sc):
+            skw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+            out = impl(q, kc, vc, pt, pos, q_lens, anc, scale=scale, **skw)
+            # rows at or past q_len are garbage by contract on both paths
+            live = jnp.arange(S)[None, :] < q_lens[:, None]
+            return jnp.where(live[..., None, None], out, 0)
+        return fn
+
+    return run(ragged_flash_attention), run(ragged_gather_attention)
+
+
+def _ragged_case(kind, S, qdt, pdt, P, seed=0):
+    """(fn, args, ref_fn) for one ragged launch: 4 entries (two live at
+    different depths, one short, one padded) over a shuffled page table."""
     from flexflow_tpu.paged.quant import quantized_append
 
     B, MAXP = 4, max(4, -(-(96 + S) // P))
@@ -82,20 +105,48 @@ def _ragged_case(kind, S, qdt, pdt, P, seed=0):
     else:
         kc = jnp.asarray(rs.randn(N, P, HKV * D), pdt)
         vc = jnp.asarray(rs.randn(N, P, HKV * D), pdt)
-    scale = 1.0 / np.sqrt(D)
+    fn, ref = _ragged_fns(S)
+    return fn, (q, kc, vc, pt, pos, q_lens, anc) + scales, ref
 
-    def run(impl, **kw):
-        def fn(q, kc, vc, pt, pos, q_lens, anc, *sc):
-            skw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
-            out = impl(q, kc, vc, pt, pos, q_lens, anc, scale=scale,
-                       **skw, **kw)
-            # rows at or past q_len are garbage by contract on both paths
-            live = jnp.arange(S)[None, :] < q_lens[:, None]
-            return jnp.where(live[..., None, None], out, 0)
-        return fn
 
-    return (run(ragged_flash_attention), (q, kc, vc, pt, pos, q_lens, anc)
-            + scales, run(ragged_gather_attention))
+# the benchmark's serving launches: (window, [(slot, pos, q_len)]). A
+# decode tick is one row a slot; a 64-token chunk rides ONE packed launch
+# as eight 8-row pieces of the same slot (paged/scheduler.py), each
+# walking the prefix below it; a padded entry has q_len 0.
+BENCH_LAUNCHES = {
+    "decode": (1, [(i, p, 1) for i, p in enumerate(
+        (100, 180, 250, 330, 400, 470, 560, 639))]),
+    "chunk64": (8, [(0, 3500 + 8 * i, 8) for i in range(8)]),
+    "packed": (8, [(0, 1500 + 8 * i, 8) for i in range(4)]
+               + [(1, 0, 5), (2, 63, 1), (3, 200, 3), (4, 0, 0)]),
+}
+
+
+def _bench_case(kind, seed=0):
+    """(fn, args, ref_fn) for one launch of the benchmark's server: page
+    64, bfloat16 pool and q, a table 64 pages wide whose entries past a
+    slot's live pages are the null page, as the server leaves them."""
+    P, MAXP, N = 64, 64, 128
+    S, entries = BENCH_LAUNCHES[kind]
+    B = len(entries)
+    rs = np.random.RandomState(seed)
+    q = jnp.asarray(rs.randn(B, S, H, D), jnp.bfloat16)
+    kc = jnp.asarray(rs.randn(N, P, HKV * D), jnp.bfloat16)
+    vc = jnp.asarray(rs.randn(N, P, HKV * D), jnp.bfloat16)
+    free = list(rs.permutation(N - 1) + 1)
+    tables = {}
+    for slot, p, ql in entries:
+        live = -(-(p + ql) // P)
+        row = tables.setdefault(slot, np.zeros((MAXP,), np.int32))
+        for i in range(live):
+            if row[i] == 0:
+                row[i] = free.pop()
+    pt = jnp.asarray(np.stack([tables[slot] for slot, _, _ in entries]))
+    pos = jnp.asarray(np.array([p for _, p, _ in entries], np.int32))
+    q_lens = jnp.asarray(np.array([ql for _, _, ql in entries], np.int32))
+    anc = jnp.asarray(np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
+    fn, ref = _ragged_fns(S)
+    return fn, (q, kc, vc, pt, pos, q_lens, anc), ref
 
 
 def _loss_grads(attn, w):
@@ -205,6 +256,8 @@ def kernel_cases(n_devices: int = 1):
             cases[f"ragged_{kind}{S}_q{qdt}_kv{pdt}_p{P}"] = (
                 lambda kind=kind, S=S, qdt=qdt, pdt=pdt, P=P:
                 _ragged_case(kind, S, qdt, pdt, P))
+    for kind in BENCH_LAUNCHES:
+        cases[f"ragged_bench_{kind}"] = lambda kind=kind: _bench_case(kind)
     return cases
 
 
@@ -221,9 +274,33 @@ def _rel_err(got, ref):
     return max(errs)
 
 
+def _kernel_device_us(fn, fargs, calls=20):
+    """Device microseconds a call of the ragged kernel alone: its events
+    on the device's `XLA Ops` line in a profiler trace of `calls` calls."""
+    import glob
+    import tempfile
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(calls):
+                out = fn(*fargs)
+            jax.block_until_ready(out)
+        path, = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+    ns = [ev.duration_ns
+          for plane in data.planes if plane.name.startswith("/device:TPU:0")
+          for line in plane.lines if line.name == "XLA Ops"
+          for ev in line.events
+          if "ragged_paged_attention" in ev.name.split(" = ", 1)[0]]
+    return sum(ns) / 1e3 / calls if len(ns) == calls else float("nan")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default="", help="substring filter on names")
+    ap.add_argument("--time", action="store_true",
+                    help="print each ragged case's device microseconds")
     args = ap.parse_args(argv)
     dev = jax.devices()
     print(f"platform={dev[0].platform} device_kind={dev[0].device_kind} "
@@ -238,13 +315,17 @@ def main(argv=None) -> int:
             continue
         try:
             fn, fargs, ref = build()
-            got = jax.block_until_ready(jax.jit(fn)(*fargs))
+            jfn = jax.jit(fn)
+            got = jax.block_until_ready(jfn(*fargs))
             want = jax.block_until_ready(jax.jit(ref)(*fargs))
             err = _rel_err(got, want)
             # bf16 inputs, f32 accumulation on both sides
             ok = err < 2e-2
-            print(f"{'OK  ' if ok else 'FAIL'} {name} rel_err={err:.3e}",
-                  flush=True)
+            took = ""
+            if args.time and name.startswith("ragged_"):
+                took = f" kernel_us={_kernel_device_us(jfn, fargs):.1f}"
+            print(f"{'OK  ' if ok else 'FAIL'} {name} rel_err={err:.3e}"
+                  f"{took}", flush=True)
         except Exception as e:  # report every case, then fail the run
             ok = False
             msg = " ".join(str(e).split())
