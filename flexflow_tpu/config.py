@@ -35,6 +35,12 @@ class FFConfig:
     # ---- numerics ----
     compute_dtype: DataType = DataType.FLOAT
     param_sync: ParamSyncType = ParamSyncType.PSUM
+    # storage dtype of the weights compile() draws (None: float32 masters,
+    # the training default). A served checkpoint is stored as published
+    # ("bfloat16" where its config says torch_dtype bfloat16): passed to
+    # Executor.init_params(weight_dtype=), use sites cast to the compute
+    # dtype, and a model too large for float32 masters fits
+    weight_dtype: Optional[str] = None
 
     # ---- strategy search (reference model.cc:3599-3719 flags) ----
     search_budget: int = 0

@@ -129,6 +129,7 @@ class OpType(enum.Enum):
     # attention
     MULTIHEAD_ATTENTION = "multihead_attention"
     RING_ATTENTION = "ring_attention"
+    LATENT_ATTENTION = "latent_attention"
     # elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"
@@ -159,6 +160,7 @@ class OpType(enum.Enum):
     AGGREGATE_SPEC = "aggregate_spec"
     CACHE = "cache"
     EXPERTS = "experts"
+    EXPERT_SHARE = "expert_share"
     # fused
     FUSED = "fused"
     # parallel ops (first-class PCG nodes, SURVEY.md §2.3)

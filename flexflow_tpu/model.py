@@ -60,6 +60,16 @@ class Tensor:
         return f"Tensor({self.node.name}:{self.idx} {self.shape})"
 
 
+def _glorot(fan_in: int, fan_out: int):
+    """Glorot-uniform over one matrix's own fans, for a weight that is
+    stored with more than two dims (the default initializer reads a 3-d
+    weight as a convolution's)."""
+    from flexflow_tpu.runtime.initializer import UniformInitializer
+
+    lim = (6.0 / (fan_in + fan_out)) ** 0.5
+    return UniformInitializer(-lim, lim)
+
+
 class FFModel:
     """Build a layer graph, compile it to a sharded training program, train."""
 
@@ -208,6 +218,56 @@ class FFModel:
         )
         self._record_init(node, wq=kernel_initializer, wk=kernel_initializer,
                           wv=kernel_initializer, wo=kernel_initializer)
+        return Tensor(node)
+
+    def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
+                         q_lora_rank: int, kv_lora_rank: int,
+                         qk_nope_head_dim: int, qk_rope_head_dim: int,
+                         v_head_dim: int, softmax_scale: float,
+                         norm_eps: float = 1e-6, rope_theta: float = 10000.0,
+                         rope_factor: float = 1.0, rope_original_max: int = 0,
+                         rope_beta_fast: float = 32.0,
+                         rope_beta_slow: float = 1.0,
+                         rope_interleave: bool = True,
+                         q_scale_beta: float = 0.0,
+                         name: Optional[str] = None) -> Tensor:
+        """Causal multi-head latent attention (A.LatentAttentionAttrs).
+        Each matrix is drawn Glorot-uniform over ITS fan-in and fan-out
+        (the default reads a 3-d weight as a convolution)."""
+        attrs = A.LatentAttentionAttrs(
+            embed_dim, num_heads, q_lora_rank, kv_lora_rank,
+            qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+            float(softmax_scale), float(norm_eps), float(rope_theta),
+            float(rope_factor), int(rope_original_max),
+            float(rope_beta_fast), float(rope_beta_slow),
+            bool(rope_interleave), float(q_scale_beta))
+        node = self._add(OpType.LATENT_ATTENTION, attrs, [input],
+                         name or "latent_attention")
+        h = num_heads
+        self._record_init(
+            node,
+            w_uq=_glorot(q_lora_rank, h * attrs.qk_head_dim),
+            w_ukv=_glorot(kv_lora_rank, h * (qk_nope_head_dim + v_head_dim)),
+            wo=_glorot(h * v_head_dim, embed_dim))
+        return Tensor(node)
+
+    def expert_share(self, input: Tensor, n_experts: int, k: int,
+                     hidden_dim: int, held: Optional[Sequence[int]] = None,
+                     shared_hidden: int = 0, norm_topk: bool = True,
+                     routed_scale: float = 1.0,
+                     name: Optional[str] = None) -> Tensor:
+        """One chip's share (`held` = (lo, hi), default all) of a dropless
+        SwiGLU expert layer with its shared expert (A.ExpertShareAttrs)."""
+        lo, hi = held if held is not None else (0, n_experts)
+        if not 0 <= lo < hi <= n_experts:
+            raise ValueError(f"held experts {lo}..{hi} of {n_experts}")
+        node = self._add(
+            OpType.EXPERT_SHARE,
+            A.ExpertShareAttrs(n_experts, k, hidden_dim, int(lo), int(hi),
+                               shared_hidden, norm_topk, routed_scale),
+            [input], name or "expert_share")
+        init = _glorot(input.shape[-1], hidden_dim)
+        self._record_init(node, w_gate=init, w_up=init, w_down=init)
         return Tensor(node)
 
     def ring_attention(self, query: Tensor, key: Tensor, value: Tensor,
@@ -708,7 +768,8 @@ class FFModel:
         # timed playoff — reuse it (params re-init below, same seed)
         self._executor = validated_executor or self._build_executor(self.graph)
         rng = jax.random.key(cfg.seed)
-        self._params = self._executor.init_params(rng, self._init_overrides)
+        self._params = self._executor.init_params(
+            rng, self._init_overrides, weight_dtype=cfg.weight_dtype)
         self._opt_state = self._executor.init_opt_state(
             self._optimizer, self._params[0]
         )

@@ -341,6 +341,99 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
     seq_mode: str = "ring"
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionAttrs(OpAttrs):
+    """Multi-head LATENT attention (MLA, the DeepSeek-V2/V3 block that
+    Mistral-Small-4 publishes): queries and keys/values go through
+    low-rank projections, a head is split into a part without rope and a
+    part with it, and one roped key part `k_r` is shared by all heads.
+
+        c_q = RMSNorm(x W_dq)                q_h = [q_nope_h | q_rope_h] = c_q W_uq
+        [c_kv | k_r] = x W_dkv;  c_kv <- RMSNorm(c_kv)
+        [k_nope_h | v_h] = c_kv W_ukv
+        s_h = (q_nope_h . k_nope_h + rope(q_rope_h) . rope(k_r)) * softmax_scale
+
+    An op of its own and not an extension of MultiHeadAttentionAttrs:
+    that op has ONE head size for q, k and v and caches per-head K and V,
+    and every consumer of it (search rules, TP views, the dense decode
+    cache, the int8 sidecar) reads `kdim` / `num_kv` in that sense. What
+    a latent layer caches is one row a token, `[c_kv | k_r]`
+    (`latent_width` values), whatever the head count; the paged lowering
+    attends in the absorbed form and never materialises per-head K/V
+    (paged/latent.py).
+
+    Rope is YaRN's: frequencies blended between `theta^(-2i/d)` and the
+    same over `rope_factor` by the linear ramp between the correction
+    dims of `beta_fast` / `beta_slow`; pairs are interleaved (2i, 2i+1)
+    when `rope_interleave`. `softmax_scale` is the whole score scale
+    (head size and YaRN's mscale^2 folded in by the builder) and
+    `q_scale_beta` > 0 multiplies q by 1 + beta * ln(1 + floor(pos /
+    rope_original_max)) (the Llama-4 position scale)."""
+
+    embed_dim: int
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    softmax_scale: float
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_interleave: bool = True
+    q_scale_beta: float = 0.0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token's cache row holds: c_kv then k_r."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def infer(self, x: Shape):
+        dims = tuple(_carry(d) for d in x.dims[:-1]) + (
+            ParallelDim(self.embed_dim),)
+        return (Shape(dims, x.dtype, x.replica),)
+
+    def weights(self, x: Shape):
+        dt = x.dtype
+        e, h = x.dims[-1].size, self.num_heads
+        return {
+            "w_dq": WeightSpec(TensorShape((e, self.q_lora_rank), dt)),
+            "q_norm": WeightSpec(TensorShape((self.q_lora_rank,), dt),
+                                 "ones"),
+            "w_uq": WeightSpec(TensorShape(
+                (self.q_lora_rank, h, self.qk_head_dim), dt)),
+            "w_dkv": WeightSpec(TensorShape((e, self.latent_width), dt)),
+            "kv_norm": WeightSpec(TensorShape((self.kv_lora_rank,), dt),
+                                  "ones"),
+            "w_ukv": WeightSpec(TensorShape(
+                (self.kv_lora_rank, h,
+                 self.qk_nope_head_dim + self.v_head_dim), dt)),
+            "wo": WeightSpec(TensorShape((h, self.v_head_dim,
+                                          self.embed_dim), dt)),
+        }
+
+    def flops(self, ins, outs):
+        x = ins[0]
+        b, s, e = x.dims[0].size, x.dims[1].size, x.dims[-1].size
+        h = self.num_heads
+        proj = 2 * b * s * (
+            e * self.q_lora_rank + self.q_lora_rank * h * self.qk_head_dim
+            + e * self.latent_width
+            + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                       + self.v_head_dim)
+            + h * self.v_head_dim * e)
+        attn = 2 * b * h * s * s * (self.qk_head_dim + self.v_head_dim)
+        return proj + attn
+
+
 # ---------------------------------------------------------------------------
 # elementwise
 
@@ -726,6 +819,73 @@ class ExpertsAttrs(OpAttrs):
         tokens = math.prod(d.size for d in x.dims[:-1])
         dim = x.dims[-1].size
         return 2 * tokens * self.k * (dim * self.hidden_dim + self.hidden_dim * self.out_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShareAttrs(OpAttrs):
+    """One chip's SHARE of a dropless SwiGLU expert layer, with the
+    layer's shared expert(s): the layer is told which experts it holds
+    (`held_lo` <= e < `held_hi` of `n_experts`), routes every token over
+    all `n_experts` (softmax in float32, top `k`, renormalised over
+    those k when `norm_topk`, times `routed_scale`), and computes
+
+        y = sum over the token's top-k experts HELD HERE of
+              w_e * (silu(x Wg_e) * x Wu_e) Wd_e   +   E_shared(x)
+
+    What experts held elsewhere would add is left out: with the layer
+    whole (`held` = all) this is the layer; with a share it is the
+    partial sum an expert-parallel chip owns before its exchange, and
+    the shares add up to the layer with the shared expert counted once
+    (tests/test_mistral4.py). No token is ever dropped and no capacity
+    exists, so a token's output never depends on its batch-mates; an
+    expert no token reached is not read from HBM (the grouped kernel
+    visits only groups with rows, ops/pallas/grouped_experts.py).
+    Inputs: x (..., d). The router is a weight of the op (its logits
+    are float32 whatever the activation dtype)."""
+
+    n_experts: int
+    k: int
+    hidden_dim: int
+    held_lo: int = 0
+    held_hi: int = 0            # 0: all n_experts
+    shared_hidden: int = 0      # 0: no shared expert
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return (self.held_lo, self.held_hi or self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held
+        return hi - lo
+
+    def infer(self, x: Shape):
+        return (elementwise_like(x),)
+
+    def weights(self, x: Shape):
+        d, dt, g, f = x.dims[-1].size, x.dtype, self.n_held, self.hidden_dim
+        w = {
+            "router": WeightSpec(TensorShape((d, self.n_experts), dt)),
+            "w_gate": WeightSpec(TensorShape((g, d, f), dt)),
+            "w_up": WeightSpec(TensorShape((g, d, f), dt)),
+            "w_down": WeightSpec(TensorShape((g, f, d), dt)),
+        }
+        if self.shared_hidden:
+            fs = self.shared_hidden
+            w["shared_gate"] = WeightSpec(TensorShape((d, fs), dt))
+            w["shared_up"] = WeightSpec(TensorShape((d, fs), dt))
+            w["shared_down"] = WeightSpec(TensorShape((fs, d), dt))
+        return w
+
+    def flops(self, ins, outs):
+        x = ins[0]
+        tokens = math.prod(d.size for d in x.dims[:-1])
+        d = x.dims[-1].size
+        routed = self.k * self.n_held / self.n_experts * self.hidden_dim
+        return (2 * tokens * d * self.n_experts
+                + 6 * tokens * d * (routed + self.shared_hidden))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,0 +1,129 @@
+"""The lowering of ExpertShareAttrs: route over all experts, compute the
+held ones without dropping a token, add the shared expert.
+
+On the TPU (or interpreted on request) the held experts run through the
+grouped kernels of ops/pallas/grouped_experts.py; elsewhere through a
+dense loop over the held experts, which is the kernels' oracle. Either
+way a token's result is a function of that token alone.
+"""
+
+from __future__ import annotations
+
+import logging
+import os
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from flexflow_tpu.ops.pallas import grouped_experts as ge
+
+logger = logging.getLogger(__name__)
+_logged = set()
+
+STATS = ("moe_assignments", "experts_hit", "experts_held",
+         "moe_rows_padded")
+
+
+def route(attrs, x, router):
+    """x: (T, d) -> (ids (T, k) int32 over ALL experts, weights (T, k)
+    float32). Logits, softmax and the renormalisation in float32."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, ids = lax.top_k(probs, attrs.k)
+    if attrs.norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), w * attrs.routed_scale
+
+
+def _swiglu(x, gate, up, down):
+    dt = x.dtype
+    g = jnp.dot(x, gate.astype(dt), preferred_element_type=jnp.float32)
+    u = jnp.dot(x, up.astype(dt), preferred_element_type=jnp.float32)
+    h = (g * jax.nn.sigmoid(g) * u).astype(dt)
+    return jnp.dot(h, down.astype(dt), preferred_element_type=jnp.float32)
+
+
+def kernels_available(d: int, f: int, interpret: bool) -> bool:
+    if interpret:
+        return True
+    why = None
+    if jax.default_backend() != "tpu":
+        why = f"backend is {jax.default_backend()!r}, not tpu"
+    elif d % ge.LANES or f % ge.LANES:
+        why = f"widths {d} / {f} are not multiples of {ge.LANES} lanes"
+    if why and why not in _logged:
+        _logged.add(why)
+        logger.log(logging.WARNING if jax.default_backend() == "tpu"
+                   else logging.INFO,
+                   "expert share: grouped kernels rejected (%s); using "
+                   "the dense loop over held experts", why)
+    return why is None
+
+
+def routed(attrs, x, ids, w, params, live=None):
+    """The held experts' part of the result, (T, d) float32, and the
+    launch's counters (STATS order, int32). `live` (T,) bool, if given,
+    names the rows that are tokens: a launch's pad rows (an idle slot of
+    a decode tick, the tail of a piece) belong to no expert, so they are
+    neither computed nor counted and stream no expert's weights."""
+    T, d = x.shape
+    lo, hi = attrs.held
+    G, f = hi - lo, attrs.hidden_dim
+    held = (ids >= lo) & (ids < hi)
+    if live is not None:
+        held = held & live[:, None]
+    local = jnp.where(held, ids - lo, G).reshape(-1)          # (T * k,)
+    interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
+    if kernels_available(d, f, interp):
+        A = T * attrs.k
+        tm = ge.row_tile(A, G, x.dtype)
+        dest, tile_group, n_active, counts = ge.layout(local, G, tm)
+        rows = ge.num_tiles(A, G, tm) * tm
+        # the padded layout by a GATHER: row r's token, or T (a zero row)
+        token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), attrs.k)
+        src = jnp.full((rows,), T, jnp.int32).at[dest].set(
+            token, mode="drop")
+        xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
+        h = ge.grouped_swiglu(xs, params["w_gate"].astype(x.dtype),
+                              params["w_up"].astype(x.dtype), tile_group,
+                              n_active, tm=tm, interpret=interp)
+        y = ge.grouped_dot(h, params["w_down"].astype(x.dtype), tile_group,
+                           n_active, tm=tm, out_dtype=jnp.float32,
+                           interpret=interp)
+        # rows of inactive tiles were never written: select, do not scale
+        per = jnp.where(held.reshape(-1, 1),
+                        jnp.take(y, jnp.minimum(dest, rows - 1), axis=0),
+                        0.0)
+        out = jnp.sum(per.reshape(T, attrs.k, d) * w[..., None], axis=1)
+        padded = n_active[0] * tm - jnp.sum(counts)
+    else:
+        wt = jnp.sum(jnp.where(
+            (local.reshape(T, attrs.k, 1) == jnp.arange(G)), w[..., None],
+            0.0), axis=1)                                     # (T, G)
+        out = jnp.zeros((T, d), jnp.float32)
+        for g in range(G):
+            out = out + wt[:, g:g + 1] * _swiglu(
+                x, params["w_gate"][g], params["w_up"][g],
+                params["w_down"][g])
+        counts = jnp.sum(local[:, None] == jnp.arange(G), axis=0)
+        padded = jnp.int32(0)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.int32(G), padded]).astype(jnp.int32)
+    return out, stats
+
+
+def expert_share(attrs, x, params, live=None):
+    """x: (..., d) -> (y (..., d) in x's dtype, stats (4,) int32). `live`
+    (...) bool: the rows that are tokens (`routed`); a pad row's result
+    is its shared expert's alone and nobody reads it."""
+    shape = x.shape
+    xt = x.reshape(-1, shape[-1])
+    ids, w = route(attrs, xt, params["router"])
+    y, stats = routed(attrs, xt, ids, w, params,
+                      None if live is None else live.reshape(-1))
+    if attrs.shared_hidden:
+        y = y + _swiglu(xt, params["shared_gate"], params["shared_up"],
+                        params["shared_down"])
+    return y.astype(x.dtype).reshape(shape), stats

@@ -396,6 +396,27 @@ def _mha(attrs, inputs, params, ctx):
     return [y]
 
 
+@register_lowering(OpType.LATENT_ATTENTION)
+def _latent_attention(attrs, inputs, params, ctx):
+    """Multi-head latent attention (ops/latent_attention.py). Without a
+    cache the naive form over the whole sequence; with a page pool the
+    absorbed form over one `[c_kv | k_r]` row a token (entry "c" of the
+    node's cache). There is no dense decode cache for it: the latent row
+    is what makes the layer worth caching, and only the pool holds it."""
+    from flexflow_tpu.ops import latent_attention as la
+
+    (x,) = inputs
+    if ctx.kv_cache is None:
+        return [la.naive_attention(attrs, x, params)]
+    if ctx.page_tables is None:
+        raise NotImplementedError(
+            "latent attention decodes through the page pool only: serve "
+            "with serve_generation(paged=True)")
+    y, pool = la.paged_attention(attrs, x, params, ctx)
+    ctx.cache_updates["c"] = pool
+    return [y]
+
+
 @register_lowering(OpType.RING_ATTENTION)
 def _ring_attention(attrs, inputs, params, ctx):
     # Sequence-parallel lowering lives in flexflow_tpu.parallel.ring; when the
@@ -791,6 +812,29 @@ def _sorted_dispatch(topi, t: int, n_experts: int, cap: int):
     )
     kept = jnp.minimum(counts, cap)
     return slot_of_flat, kept
+
+
+@register_lowering(OpType.EXPERT_SHARE)
+def _expert_share(attrs, inputs, params, ctx):
+    """One chip's share of a dropless SwiGLU expert layer plus its
+    shared expert (ops/expert_share.py). A paged serving step also
+    leaves the launch's counters (assignments to held experts, held
+    experts hit, held, padded rows) in ctx.state_updates["moe_stats"]
+    for the scheduler; no other mode reports them. A ragged launch's pad
+    rows (entry b's rows from q_lens[b] on: an idle or mid-prefill slot
+    of a decode tick carries token 0 in every one) go to no expert: left
+    in, they all chose the same experts, 0 to 4 of them held by the draw
+    of the weights, and streamed those every tick."""
+    from flexflow_tpu.ops.expert_share import expert_share
+
+    live = None
+    if ctx.page_tables is not None and ctx.ragged_q_lens is not None:
+        live = (jnp.arange(inputs[0].shape[1], dtype=jnp.int32)[None, :]
+                < ctx.ragged_q_lens[:, None])
+    y, stats = expert_share(attrs, inputs[0], params, live)
+    if ctx.page_tables is not None:
+        ctx.state_updates["moe_stats"] = stats
+    return [y]
 
 
 @register_lowering(OpType.EXPERTS)

@@ -64,6 +64,7 @@ import numpy as np
 
 from flexflow_tpu import obs
 from flexflow_tpu.paged.pool import EMPTY_HASH, PagePool
+from flexflow_tpu.runtime.executor import LAUNCH_STATS
 from flexflow_tpu.serving import _GenerationServerBase, _GenRequest
 
 # Packed prefill windows are capped at this many rows — the fp32 sublane
@@ -325,20 +326,44 @@ class PagedGenerationServer(_GenerationServerBase):
 
         reset_rejection_log()
         attn_key, kbufs = next(iter(self._caches.items()))
-        kbuf = kbufs["k"]
+        # a latent layer's pool has ONE entry a node, "c" (paged/latent.py)
+        self._latent = "c" in kbufs
+        kbuf = kbufs["c"] if self._latent else kbufs["k"]
         # pool rows are flat-lane (Hkv*D); the gate wants the head dim
         from flexflow_tpu.runtime.executor import node_key as _node_key
 
         attn = next(n.attrs for n in ex.topo if _node_key(n) == attn_key)
-        head_dim = attn.kdim
-        # what the ragged kernel derives its block of pages from, kept
-        # for the launch_dispatch span's kv_blocks (tracing only)
-        self._block_geom = (kbuf.shape[2], kbuf.dtype,
-                            attn.num_heads // attn.num_kv)
-        self.kernel_variant = "ragged_pallas" if paged_attention_available(
-            head_dim, self.page_size,
-            interpret=os.environ.get("FF_TPU_FLASH_INTERPRET") == "1",
-            dtype=kbuf.dtype) else "ragged_gather"
+        interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
+        # what the kernel derives its block of pages from, kept for the
+        # launch_dispatch span's kv_blocks (tracing only)
+        if self._latent:
+            from flexflow_tpu.paged.latent import latent_attention_available
+
+            self._block_geom = (kbuf.shape[2], kbuf.dtype, attn.num_heads)
+            kernel_ok = latent_attention_available(
+                self.page_size, interpret=interp, dtype=kbuf.dtype)
+        else:
+            self._block_geom = (kbuf.shape[2], kbuf.dtype,
+                                attn.num_heads // attn.num_kv)
+            kernel_ok = paged_attention_available(
+                attn.kdim, self.page_size, interpret=interp,
+                dtype=kbuf.dtype)
+        self.kernel_variant = ("ragged_pallas" if kernel_ok
+                               else "ragged_gather")
+        # bytes a cached token takes in the pool, over every layer
+        self.kv_bytes_per_token = sum(
+            b.shape[2] * b.dtype.itemsize
+            for bufs in self._caches.values()
+            for n, b in bufs.items() if not n.endswith("_scale"))
+        # expert layers' launch counters (ops/expert_share.py STATS):
+        # device arrays of launches not yet read, folded into host totals
+        # where the host waits for the device anyway
+        from flexflow_tpu.ffconst import OpType as _OpType
+
+        self._has_moe = any(n.op_type == _OpType.EXPERT_SHARE
+                            for n in ex.topo)
+        self._moe_pending: List[tuple] = []
+        self._moe_totals = np.zeros((4,), np.int64)
         self._g_kernel = self.registry.gauge("ragged_kernel_active")
         self._g_kernel.set(1.0 if self.kernel_variant == "ragged_pallas"
                            else 0.0)
@@ -496,6 +521,12 @@ class PagedGenerationServer(_GenerationServerBase):
         the /v2/models/<name>/metrics endpoint scrapes)."""
         m = super().metrics()
         pool = self.pool
+        if self._has_moe:
+            from flexflow_tpu.ops.expert_share import STATS
+
+            # the loop thread owns the pending list: the totals lag by
+            # the launches it has not read yet (_fold_moe_stats)
+            m.update(zip(STATS, (int(v) for v in self._moe_totals)))
         m.update({
             "preemptions": self.preemptions,
             "defrags": self.defrags,
@@ -507,6 +538,7 @@ class PagedGenerationServer(_GenerationServerBase):
             "fragmentation": pool.fragmentation(),
             "prefill_ticks": self.prefill_ticks,
             "kernel_variant": self.kernel_variant,
+            "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_cache_dtype": self._kv_pool_dtype_name(),
             "kv_quant_error": self._kv_quant_error(),
             "kv_quant_canary": {
@@ -566,10 +598,39 @@ class PagedGenerationServer(_GenerationServerBase):
             self._g_tier_lat.set(tm["fetch_latency_s_avg"])
         return m
 
+    def stop(self):
+        super().stop()
+        if self._thread is None or not self._thread.is_alive():
+            self._fold_moe_stats()
+
+    def _fold_moe_stats(self, keep: int = 0):
+        """Read the expert layers' counters of all but the newest `keep`
+        launches off the device into the host totals, and onto their
+        launch_dispatch spans when traced (one list a counter, an entry a
+        layer). Called from the loop thread every 120 launches, and from
+        stop() for the rest, so the totals in metrics() lag by up to 128
+        launches while the server runs."""
+        from flexflow_tpu.ops.expert_share import STATS
+
+        n = len(self._moe_pending) - keep
+        if n <= 0:
+            return
+        done, self._moe_pending = (self._moe_pending[:n],
+                                   self._moe_pending[n:])
+        for attrs, stats in done:
+            vals = np.asarray(stats, np.int64)              # (layers, 4)
+            self._moe_totals += vals.sum(axis=0)
+            if attrs is not None:
+                attrs.update({name: vals[:, i].tolist()
+                              for i, name in enumerate(STATS)})
+
     def _kv_pool_dtype_name(self) -> str:
         """The pool's actual storage dtype name ("int8" for a quantized
         pool) — what the kv_cache_dtype gauge reports in bits."""
-        return str(next(iter(self._caches.values()))["k"].dtype)
+        bufs = next(iter(self._caches.values()))
+        if self._latent:
+            return str(bufs["c"].dtype)
+        return str(bufs["k"].dtype)
 
     def _dtype_plan_ok(self) -> bool:
         """True while the live pool's storage dtype matches the declared
@@ -1310,8 +1371,24 @@ class PagedGenerationServer(_GenerationServerBase):
                 P = self.page_size
                 pages = -(-(p0 + q) // P)
                 lanes, pool_dt, rep = self._block_geom
-                ppb = ragged_block_pages(P, self.max_pages_per_seq, lanes,
-                                         pool_dt, rep * window)
+                if self._latent:
+                    from flexflow_tpu.paged.latent import latent_block_pages
+
+                    ppb = latent_block_pages(P, self.max_pages_per_seq,
+                                             lanes, pool_dt, rep * window)
+                    # pages the launch has to read, each ONCE a slot: the
+                    # pieces of one slot's chunk walk the same prefix, and
+                    # a kernel that read it once must not read over 100 %
+                    horizon = {}
+                    for s_, e_ in zip(slot_idx[qls > 0], p0 + q):
+                        horizon[int(s_)] = max(horizon.get(int(s_), 0),
+                                               int(e_))
+                    sp.set(latent_pages=sum(-(-e_ // P)
+                                            for e_ in horizon.values()),
+                           kv_bytes_per_token=self.kv_bytes_per_token)
+                else:
+                    ppb = ragged_block_pages(P, self.max_pages_per_seq,
+                                             lanes, pool_dt, rep * window)
                 sp.set(rows=total, padded_rows=padded,
                        kv_rows=int((p0 + q).sum()),
                        kv_pages=int(pages.sum()),
@@ -1321,6 +1398,16 @@ class PagedGenerationServer(_GenerationServerBase):
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
                 ids_d)
+            stats = upd.pop(LAUNCH_STATS, None)
+            if stats is not None:
+                # a (layers, 4) device array: read later, in bulk and long
+                # after the launch that made it has run (_fold_moe_stats:
+                # a read of a ready array is a copy, a read a tick costs
+                # the device 3.6 ms of waiting an iteration, PERF.md
+                # section 6); a traced launch's span gets it then
+                self._moe_pending.append((sp.attrs if sp else None, stats))
+                if len(self._moe_pending) >= 128:
+                    self._fold_moe_stats(keep=8)
         self._caches = upd
         if self._caches_ref is not None:
             # quant-error sampling (FF_TPU_KV_QUANT_DEBUG=1): the same
@@ -1435,8 +1522,7 @@ class PagedGenerationServer(_GenerationServerBase):
                                   None, None))
                 ends.append((len(items) - 1, (take - 1) % W))
             probs, padded, total = self._launch(items, W, tr, ntr)
-            with obs.span("sample"):    # the rows to pick from: eager slices
-                rows = [probs[i:i + 1, r, :] for i, r in ends]
+            rows = [(probs, i, r) for i, r in ends]
         else:
             rows = []
             for s, req, start, take in plan:
@@ -1444,12 +1530,12 @@ class PagedGenerationServer(_GenerationServerBase):
                 p, pad, tot = self._launch(
                     [(s, start, req.prefill_seq[start:start + take],
                       None, None)], bucket, tr, ntr)
-                rows.append(p[0:1, take - 1, :])
+                rows.append((p, 0, take - 1))
                 padded += pad
                 total += tot
         with obs.span("commit") as csp:
             first = []  # seq of each request that got its first token
-            for (s, req, start, take), row in zip(plan, rows):
+            for (s, req, start, take), (p, i, r) in zip(plan, rows):
                 req.prefill_pos = start + take
                 req.prefill_tokens += take
                 self._publish_prefix(req, req.prefill_pos)
@@ -1462,6 +1548,10 @@ class PagedGenerationServer(_GenerationServerBase):
                     # it (the first token is appended below, so
                     # seq_tokens() still equals prefill_seq here)
                     self._publish_tail(req)
+                    with obs.span("sample"):
+                        # the last real row, (1, V): one warmed program
+                        # a launch shape (serving.probs_row)
+                        row = self._probs_row(p, np.int32(i), np.int32(r))
                     self._sample_first_token(s, req, row)
                     first.append(req.seq)
                     self._finish_if_done(s)

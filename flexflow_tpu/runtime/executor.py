@@ -65,6 +65,13 @@ _HLO_DTYPE_NAMES = {
 }
 
 
+# key of a ragged step's per-node launch counters in its second result
+LAUNCH_STATS = "__launch_stats__"
+# the ops whose node owns a pool of the page cache
+PAGED_ATTENTION_OPS = (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
+                       OpType.LATENT_ATTENTION)
+
+
 def _cast_weight_leaf(arr, weight_dtype: str):
     """Storage cast for one initialized weight leaf
     (init_params(weight_dtype=...)): float names are a plain astype;
@@ -813,13 +820,26 @@ class Executor:
                     "graphs (their KV cache is threaded through the layer "
                     "scan); serve with paged=False"
                 )
-            if n.op_type not in (OpType.MULTIHEAD_ATTENTION,
-                                 OpType.RING_ATTENTION):
+            if n.op_type not in PAGED_ATTENTION_OPS:
                 continue
             ins = self.graph.input_shapes(n)
             dt = dtype
             if dt is None:
                 dt = ins[0].dtype.jnp_dtype if ins else jnp.bfloat16
+            if n.op_type == OpType.LATENT_ATTENTION:
+                # ONE entry a node: a token's row is [c_kv | k_r], key and
+                # value of every head at once (paged/latent.py)
+                from flexflow_tpu.paged.latent import pool_lanes
+
+                if is_quantized_dtype(dt):
+                    raise ValueError(
+                        "kv_dtype='int8' is not supported for a latent "
+                        "attention pool: the scale sidecar is per kv head "
+                        "and a latent row has none")
+                specs[node_key(n)] = {"c": jax.ShapeDtypeStruct(
+                    (num_pages, page_size,
+                     pool_lanes(n.attrs.latent_width)), dt)}
+                continue
             shape = (num_pages, page_size, n.attrs.num_kv * n.attrs.kdim)
             specs[node_key(n)] = {
                 "k": jax.ShapeDtypeStruct(shape, dt),
@@ -847,10 +867,26 @@ class Executor:
         TOKENS IN FLIGHT instead of slots x max_len. PIPELINE
         composites keep their layer-scan threaded dense caches and are
         not paged (their cache lives inside the scan carry)."""
-        return jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            self.paged_kv_cache_specs(num_pages, page_size, dtype),
-        )
+        specs = self.paged_kv_cache_specs(num_pages, page_size, dtype)
+        pools = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), specs)
+        latent = {nk for nk, bufs in specs.items() if "c" in bufs}
+        if latent:
+            # a latent pool is born COMMITTED to its device, as every
+            # launch's output pool is: a launch shape then has ONE jit
+            # signature, where a K/V pool's has two (fresh and committed,
+            # warm_launch_shapes warms both) and compiles twice. A latent
+            # graph's step holds two Pallas kernel families a layer and
+            # costs 5 s a compile on the chip; 97 shapes twice were 980 s
+            # of set-up (PERF.md section 6, PR 27)
+            # (the sharding a launch gives its outputs: replicated over
+            # the model's one-device mesh)
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            where = (NamedSharding(self.mesh, PartitionSpec())
+                     if self.mesh is not None else jax.devices()[0])
+            pools = {nk: (jax.device_put(bufs, where) if nk in latent
+                          else bufs) for nk, bufs in pools.items()}
+        return pools
 
     def paged_decode_fn(self):
         """jitted (params, pools, page_tables, pos, ids) ->
@@ -947,12 +983,20 @@ class Executor:
         def step(trainable, nontrainable, caches, page_tables, pos,
                  q_lens, depths, anc, *inputs):
             cache_out = {}
-            out, _, _ = self.run_forward(
+            out, state, _ = self.run_forward(
                 trainable, nontrainable, inputs, training=False,
                 rng=jax.random.key(0), kv_caches=caches,
                 cache_position=pos, cache_out=cache_out,
                 page_tables=page_tables, ragged=(q_lens, depths, anc),
             )
+            moe = [st["moe_stats"] for _nk, st in sorted(state.items())
+                   if "moe_stats" in st]
+            if moe:
+                # what the expert layers counted in this launch, one row
+                # a layer, handed back beside the pools under a key no
+                # node has; the caller takes it out before the pools go
+                # into the next launch
+                cache_out[LAUNCH_STATS] = jnp.stack(moe)
             return out, cache_out
 
         self._ragged_step_fn = self.compile_tracker.wrap(
@@ -1363,7 +1407,8 @@ class Executor:
         return (sum(1 for f in singles if f is not None)
                 + len(self._megastep_fns))
 
-    def warm_launch_shapes(self, catalog, *, params, eos_id=None) -> Dict:
+    def warm_launch_shapes(self, catalog, *, params, eos_id=None,
+                           on_probs=None) -> Dict:
         """Pre-compile every launch shape in a shapecheck catalog
         (analysis.shapecheck.enumerate_catalog) so first-request TTFT
         stops paying compile cost and steady-state serving provably
@@ -1397,7 +1442,9 @@ class Executor:
         Returns {"warmed_shapes", "vocab", "probs_dtype", "probs_ref",
         "rng_ref"} — the serving layer warms its (batch, vocab) sampling
         program (the one entry the executor does not own) from slices of
-        probs_ref and splits of rng_ref."""
+        probs_ref and splits of rng_ref. `on_probs`, if given, is called
+        with every ragged shape's (B, W, V) output, so the caller can warm
+        what it runs on a launch's probs at that shape."""
         import contextlib
         import time
 
@@ -1428,11 +1475,13 @@ class Executor:
             for B, W in entries.get(  # fflint: host-ok (one-time warmup)
                     "ragged_step", {}).get("shapes", ()):
                 B, W = int(B), int(W)
-                tbl = (jnp.zeros((slots, cols), jnp.int32) if B == slots
-                       else jnp.take(jnp.zeros((slots, cols), jnp.int32),
-                                     jnp.asarray(
-                                         np.zeros((B,), np.int32)),
-                                     axis=0))
+                # a packed launch gathers its table rows on the device,
+                # at B == slots too (the canonical decode launch does not)
+                tbl = jnp.take(jnp.zeros((slots, cols), jnp.int32),
+                               jnp.asarray(np.zeros((B,), np.int32)),
+                               axis=0)
+                if B == slots:
+                    tbl = jnp.zeros((slots, cols), jnp.int32)
                 deps = jnp.asarray(np.tile(
                     np.arange(W, dtype=np.int32), (B, 1)))
                 anc = jnp.asarray(np.tile(
@@ -1461,9 +1510,12 @@ class Executor:
                         sp.set(window=W, rows=B * W, **split,
                                call_s=t1 - t0,
                                first_run_s=time.monotonic() - t1)
+                    caches_out.pop(LAUNCH_STATS, None)
                     if caches_c is None:
                         caches_c = caches_out
                     probs, _ = step(tr, ntr, caches_c, *args)
+                if on_probs is not None:
+                    on_probs(probs)
                 if probs_ref is None or B == slots:
                     probs_ref = probs
                 warmed += 1
@@ -1622,9 +1674,7 @@ class Executor:
         PIPELINE composite (whose cache is threaded through the layer
         scan). A pooled-classification graph (BERT's (b, classes) head)
         has attention but nothing to decode."""
-        has_attn = any(n.op_type in (OpType.MULTIHEAD_ATTENTION,
-                                     OpType.RING_ATTENTION)
-                       for n in self.topo)
+        has_attn = any(n.op_type in PAGED_ATTENTION_OPS for n in self.topo)
         no_pipe = all(n.op_type != OpType.PIPELINE for n in self.topo)
         token_in = (len(self.input_nodes) == 1
                     and self.input_nodes[0].outputs[0].ndim == 2
@@ -1740,8 +1790,7 @@ class Executor:
         else:
             # the cache-spec default: the attention input's own dtype
             attn = [n for n in self.topo
-                    if n.op_type in (OpType.MULTIHEAD_ATTENTION,
-                                     OpType.RING_ATTENTION)]
+                    if n.op_type in PAGED_ATTENTION_OPS]
             kv_name = "bf16"
             if attn:
                 ins = self.graph.input_shapes(attn[0])

@@ -51,6 +51,18 @@ def pick_tokens(probs_last, temps, rng):
     return jnp.where(temps > 0.0, sampled, greedy)
 
 
+def probs_row(probs, i, r):
+    """Row r of entry i of a launch's (B, W, V) probs, as (1, V). The
+    indices are operands: one program a launch shape. An eager static
+    slice `probs[i:i + 1, r]` is one program a piece index i, compiled
+    the first time a prompt ends in that piece (about 110 ms on the chip,
+    inside the serving window)."""
+    import jax
+
+    return jax.lax.dynamic_slice(
+        probs, (i, r, 0), (1, 1, probs.shape[2]))[:, 0, :]
+
+
 class ModelInstance:
     """One compiled forward per allowed batch size (the reference's
     per-instance compiled model, triton/src/instance.cc analog)."""
@@ -590,6 +602,7 @@ class _GenerationServerBase:
         # shares (dense, paged, packed spec roots, megastep inner loop)
         self._pick = tracker.wrap("pick_tokens", jax.jit(pick_tokens),
                                   lambda args: (args[0].shape[0],))
+        self._probs_row = jax.jit(probs_row)
         self._queue: "queue.Queue[_GenRequest]" = queue.Queue()
         self._active: List[Optional[_GenRequest]] = [None] * self.slots
         self._tokens = np.zeros((self.slots,), np.int32)
@@ -839,8 +852,22 @@ class _GenerationServerBase:
             from flexflow_tpu.analysis.shapecheck import enumerate_catalog
 
             catalog = enumerate_catalog(**self.shape_config())
+
+        def on_probs(p):
+            # what a tick runs on a launch's probs, at that shape: the
+            # last-row program a completing prefill's first token is
+            # picked from, and the decode tick's eager slice
+            self._probs_row(p, np.int32(0), np.int32(0))
+            if p.shape[:2] == (self.slots, 1):
+                p[:, -1, :]
+
         info = self.ff.executor.warm_launch_shapes(
-            catalog, params=self._params, eos_id=self.eos_id)
+            catalog, params=self._params, eos_id=self.eos_id,
+            on_probs=on_probs)
+        # the rng chain's split: a host-made key first, its own (committed)
+        # output from then on; throwaway keys, as below
+        key, _ = jax.random.split(jax.random.key(0))
+        key, _ = jax.random.split(key)
         probs_ref = info.get("probs_ref")
         if probs_ref is not None:
             # serve-time pick inputs are SLICES of launch outputs —
@@ -1268,6 +1295,44 @@ class GenerationServer(_GenerationServerBase):
                 led.record("decode", dt, batch=len(live))
 
 
+def _refuse_for_latent_attention(ff, *, paged, kv_dtype, speculate,
+                                 megastep_ticks, megastep_mixed,
+                                 overlap_dispatch, host_tier,
+                                 kv_quant_canary, serve_strategy,
+                                 search_budget) -> None:
+    """A graph with latent attention is served by the paged per-tick
+    server over its latent pool (prefix cache, preemption, chunked
+    prefill and packed launches included). Every option whose code reads
+    the pool as per-head K and V, or has not been run on a latent pool,
+    is refused BY NAME here rather than left to misread the row."""
+    from flexflow_tpu.ffconst import OpType
+
+    if not any(n.op_type == OpType.LATENT_ATTENTION
+               for n in ff.executor.topo):
+        return
+    refused = {
+        "paged=False": not paged,
+        "kv_dtype": kv_dtype not in ("auto", "bf16", "fp16", "fp32"),
+        "speculate": speculate is not None,
+        "megastep_ticks": megastep_ticks > 1,
+        "megastep_mixed": bool(megastep_mixed),
+        "overlap_dispatch": bool(overlap_dispatch),
+        "host_tier": host_tier is not None and host_tier != 0,
+        "kv_quant_canary": bool(kv_quant_canary),
+        "serve_strategy": serve_strategy is not None,
+        "search_budget": search_budget is not None,
+    }
+    bad = [name for name, hit in refused.items() if hit]
+    if bad:
+        raise ValueError(
+            f"serve_generation option(s) {bad} are not supported on a "
+            "graph with latent attention: its page pool holds one "
+            "[c_kv | k_r] row a token, not per-head K and V (the dense "
+            "cache, the int8 scale sidecar, tree verify's commit, the "
+            "megasteps' carry, the host tier's page payloads and the "
+            "strategy search's pricing all assume K/V pools)")
+
+
 def serve_generation(ff, slots: int = 4, max_len: int = 512,
                      eos_id: Optional[int] = None, seed: int = 0,
                      paged: bool = False, page_size: int = 64,
@@ -1405,6 +1470,12 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     spill full pages to host RAM instead of dropping them, and prefix
     lookups transparently fetch them back; greedy output stays
     token-identical."""
+    _refuse_for_latent_attention(
+        ff, paged=paged, kv_dtype=kv_dtype, speculate=speculate,
+        megastep_ticks=int(megastep_ticks), megastep_mixed=megastep_mixed,
+        overlap_dispatch=overlap_dispatch, host_tier=host_tier,
+        kv_quant_canary=kv_quant_canary, serve_strategy=serve_strategy,
+        search_budget=search_budget)
     if search_budget is not None and serve_strategy is None:
         from flexflow_tpu.search.servesearch import search_serve_strategy
 

@@ -13,8 +13,13 @@ kernel and the D=128 flash kernels, plus the D=64 padded flash path; and the
 benchmark's own serving launches (`benchmark/configs/mistral-7b-serve1.json`:
 8 slots, page 64, bf16 pool, table 64 wide) as `ragged_bench_*`.
 
-`--time` also prints each ragged case's device microseconds a call (the
-kernel's own events in a profiler trace), for a before / after.
+The latent (MLA) kernel and the grouped expert kernels run at
+Mistral-Small-4's widths (`mla_*`, `moe_grouped_*`), the first also at the
+new cell's launches (`mla_bench_*`).
+
+`--time` also prints each ragged, latent and grouped case's device
+microseconds a call (the kernel's own events in a profiler trace), for a
+before / after.
 """
 
 from __future__ import annotations
@@ -149,6 +154,131 @@ def _bench_case(kind, seed=0):
     return fn, (q, kc, vc, pt, pos, q_lens, anc), ref
 
 
+# the latent (MLA) kernel at Mistral-Small-4's widths: 32 heads over one
+# [c_kv (256) | k_r (64)] row a token, 384 lanes, values the first 256
+MLA_HEADS, MLA_WIDTH, MLA_VALUE = 32, 320, 256
+MLA_POOLS = (("bfloat16", 16), ("bfloat16", 64), ("bfloat16", 128),
+             ("float32", 64))
+MLA_WINDOWS = (("decode", 1), ("chunk", 8), ("tree", 8))
+# the new cell's launches (benchmark/configs/mistral-small-4-serve1.json:
+# 8 slots, page 64, table 196 wide): a decode tick over 4-12k prefixes and
+# a 256-token chunk as 32 8-row pieces of one slot over an 8k prefix
+MLA_BENCH = {
+    "decode": (1, [(i, p, 1) for i, p in enumerate(
+        (4100, 5200, 6300, 7400, 8500, 9600, 11000, 12400))]),
+    "chunk256": (8, [(0, 8192 + 8 * i, 8) for i in range(32)]),
+}
+
+
+def _mla_fns(S):
+    from flexflow_tpu.paged.latent import (
+        latent_flash_attention,
+        latent_gather_attention,
+    )
+
+    def run(impl):
+        def fn(q, pool, pt, pos, q_lens, anc):
+            out = impl(q, pool, pt, pos, q_lens, anc, value_lanes=MLA_VALUE)
+            live = jnp.arange(S)[None, :] < q_lens[:, None]
+            return jnp.where(live[..., None, None], out, 0)
+        return fn
+
+    return run(latent_flash_attention), run(latent_gather_attention)
+
+
+def _mla_rows(rs, shape, dt):
+    """Random rows whose pad lanes (320..383) are zero, as the program's
+    appends leave them; scaled so that scores over 320 lanes stay O(1)."""
+    x = rs.randn(*shape).astype(np.float32) * MLA_WIDTH ** -0.25
+    x[..., MLA_WIDTH:] = 0
+    return jnp.asarray(x, dt)
+
+
+def _mla_case(kind, S, dt, P, seed=0):
+    B, MAXP = 4, max(4, -(-(96 + S) // P))
+    N = B * MAXP + 1
+    rs = np.random.RandomState(seed)
+    q = _mla_rows(rs, (B, S, MLA_HEADS, 384), dt)
+    pool = _mla_rows(rs, (N, P, 384), dt)
+    pt = jnp.asarray((rs.permutation(N - 1)[:B * MAXP] + 1)
+                     .reshape(B, MAXP).astype(np.int32))
+    pos = jnp.asarray(np.array([90, 37, 5, 0], np.int32))
+    q_lens = jnp.asarray(np.array([S, S, max(1, S // 2), 0], np.int32))
+    anc = (np.tile(_tree_anc(S), (B, 1, 1)) if kind == "tree"
+           else np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
+    fn, ref = _mla_fns(S)
+    return fn, (q, pool, pt, pos, q_lens, jnp.asarray(anc)), ref
+
+
+def _mla_bench_case(kind, seed=0):
+    P, MAXP, N = 64, 196, 1600
+    S, entries = MLA_BENCH[kind]
+    B = len(entries)
+    rs = np.random.RandomState(seed)
+    q = _mla_rows(rs, (B, S, MLA_HEADS, 384), jnp.bfloat16)
+    pool = _mla_rows(rs, (N, P, 384), jnp.bfloat16)
+    free = list(rs.permutation(N - 1) + 1)
+    tables = {}
+    for slot, p, ql in entries:
+        row = tables.setdefault(slot, np.zeros((MAXP,), np.int32))
+        for i in range(-(-(p + ql) // P)):
+            if row[i] == 0:
+                row[i] = free.pop()
+    pt = jnp.asarray(np.stack([tables[slot] for slot, _, _ in entries]))
+    pos = jnp.asarray(np.array([p for _, p, _ in entries], np.int32))
+    q_lens = jnp.asarray(np.array([ql for _, _, ql in entries], np.int32))
+    anc = jnp.asarray(np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
+    fn, ref = _mla_fns(S)
+    return fn, (q, pool, pt, pos, q_lens, anc), ref
+
+
+def _moe_case(tokens, seed=0):
+    """The grouped expert kernels at Mistral-Small-4's widths (32 held of
+    128 experts, top 4, 4096 x 2048) for one launch of `tokens` rows,
+    against a dense loop over the held experts."""
+    from flexflow_tpu.ops.pallas import grouped_experts as ge
+
+    G, K, d, f = 32, 4, 4096, 2048
+    rs = np.random.RandomState(seed)
+    x = jnp.asarray(rs.randn(tokens, d), jnp.bfloat16)
+    ids = jnp.asarray(np.stack([rs.permutation(128)[:K]
+                                for _ in range(tokens)]).astype(np.int32))
+    # one random matrix a kind, rolled by a different amount for each
+    # expert: 32 distinct experts without drawing 800 M normals
+    w = [jnp.stack([jnp.roll(base, 17 * g, axis=1) for g in range(G)])
+         for base in (jnp.asarray(rs.randn(a, b) * a ** -0.5, jnp.bfloat16)
+                      for a, b in ((d, f), (d, f), (f, d)))]
+    local = jnp.where(ids < G, ids, G).reshape(-1)
+    tm = ge.row_tile(tokens * K, G, x.dtype)
+    rows = ge.num_tiles(tokens * K, G, tm) * tm
+
+    def fn(x, local, wg, wu, wd):
+        dest, tile_group, n_active, _ = ge.layout(local, G, tm)
+        token = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), K)
+        src = jnp.full((rows,), tokens, jnp.int32).at[dest].set(
+            token, mode="drop")
+        xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
+        h = ge.grouped_swiglu(xs, wg, wu, tile_group, n_active, tm=tm)
+        y = ge.grouped_dot(h, wd, tile_group, n_active, tm=tm,
+                           out_dtype=jnp.float32)
+        per = jnp.where((local < G)[:, None],
+                        jnp.take(y, jnp.minimum(dest, rows - 1), axis=0), 0)
+        return per.reshape(tokens, K, d).sum(1)
+
+    def ref(x, local, wg, wu, wd):
+        hit = (local.reshape(tokens, K, 1) == jnp.arange(G)).any(1)
+        out = jnp.zeros((tokens, d), jnp.float32)
+        for g in range(G):
+            a = jnp.dot(x, wg[g], preferred_element_type=jnp.float32)
+            b = jnp.dot(x, wu[g], preferred_element_type=jnp.float32)
+            h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
+            out = out + hit[:, g:g + 1] * jnp.dot(
+                h, wd[g], preferred_element_type=jnp.float32)
+        return out
+
+    return fn, (x, local, *w), ref
+
+
 def _loss_grads(attn, w):
     """(q, k, v) -> (loss, grads) of a fixed random projection of `attn`'s
     output: one function that runs the forward and the backward kernels."""
@@ -258,6 +388,15 @@ def kernel_cases(n_devices: int = 1):
                 _ragged_case(kind, S, qdt, pdt, P))
     for kind in BENCH_LAUNCHES:
         cases[f"ragged_bench_{kind}"] = lambda kind=kind: _bench_case(kind)
+    for dt, P in MLA_POOLS:
+        for kind, S in MLA_WINDOWS:
+            cases[f"mla_{kind}{S}_{dt}_p{P}"] = (
+                lambda kind=kind, S=S, dt=dt, P=P: _mla_case(kind, S, dt, P))
+    for kind in MLA_BENCH:
+        cases[f"mla_bench_{kind}"] = lambda kind=kind: _mla_bench_case(kind)
+    for tokens in (8, 256):
+        cases[f"moe_grouped_t{tokens}"] = (
+            lambda tokens=tokens: _moe_case(tokens))
     return cases
 
 
@@ -274,9 +413,15 @@ def _rel_err(got, ref):
     return max(errs)
 
 
-def _kernel_device_us(fn, fargs, calls=20):
-    """Device microseconds a call of the ragged kernel alone: its events
-    on the device's `XLA Ops` line in a profiler trace of `calls` calls."""
+# which device operations a timed case's kernel is, by the case's prefix
+KERNEL_MARKS = {"ragged_": "ragged_paged_attention",
+                "mla_": "mla_paged_attention", "moe_": "moe_grouped"}
+
+
+def _kernel_device_us(fn, fargs, mark, per_call=1, calls=20):
+    """Device microseconds a call of the case's kernel(s) alone: the
+    events named `mark` on the device's `XLA Ops` line in a profiler
+    trace of `calls` calls (`per_call` kernels a call)."""
     import glob
     import tempfile
 
@@ -292,8 +437,9 @@ def _kernel_device_us(fn, fargs, calls=20):
           for plane in data.planes if plane.name.startswith("/device:TPU:0")
           for line in plane.lines if line.name == "XLA Ops"
           for ev in line.events
-          if "ragged_paged_attention" in ev.name.split(" = ", 1)[0]]
-    return sum(ns) / 1e3 / calls if len(ns) == calls else float("nan")
+          if mark in ev.name.split(" = ", 1)[0]]
+    return (sum(ns) / 1e3 / calls if len(ns) == calls * per_call
+            else float("nan"))
 
 
 def main(argv=None) -> int:
@@ -322,8 +468,13 @@ def main(argv=None) -> int:
             # bf16 inputs, f32 accumulation on both sides
             ok = err < 2e-2
             took = ""
-            if args.time and name.startswith("ragged_"):
-                took = f" kernel_us={_kernel_device_us(jfn, fargs):.1f}"
+            mark = next((m for p, m in KERNEL_MARKS.items()
+                         if name.startswith(p)), None)
+            if args.time and mark:
+                us = _kernel_device_us(jfn, fargs, mark,
+                                       per_call=2 if mark == "moe_grouped"
+                                       else 1)
+                took = f" kernel_us={us:.1f}"
             print(f"{'OK  ' if ok else 'FAIL'} {name} rel_err={err:.3e}"
                   f"{took}", flush=True)
         except Exception as e:  # report every case, then fail the run
