@@ -55,6 +55,7 @@ Decode flow per tick:
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -398,7 +399,13 @@ class PagedGenerationServer(_GenerationServerBase):
         self._g_plan_ok = self.registry.gauge("dtype_plan_ok")
         self._g_plan_ok.set(1.0 if self._dtype_plan_ok() else 0.0)
 
-        @jax.jit
+        # the pool -> pool programs below CONSUME the pool they are given
+        # (as the executor's launches do) and write it in place: every
+        # caller rebinds what they return (docs/paged.md "Who owns the
+        # pool")
+        consuming = functools.partial(jax.jit, donate_argnums=(0,))
+
+        @consuming
         def copy_page(caches, src, dst):
             # copy-on-write: clone one pool page (every cache buffer) so
             # a new owner can write past a shared partial prefix — the
@@ -408,7 +415,7 @@ class PagedGenerationServer(_GenerationServerBase):
 
         self._copy_page = copy_page
 
-        @jax.jit
+        @consuming
         def reset_page_scales(caches, pages):
             # page lifecycle, not a row write: pages coming OFF the free
             # list get zero scales (grow-only within a lifetime starts
@@ -435,9 +442,10 @@ class PagedGenerationServer(_GenerationServerBase):
         @jax.jit
         def read_page(caches, page):
             # one compiled program for every page id: the index is data
+            # (a reader: it consumes nothing and returns no pool)
             return jax.tree.map(lambda b: b[page], caches)
 
-        @jax.jit
+        @consuming
         def write_page(caches, page, payload):
             return jax.tree.map(
                 lambda b, r: b.at[page].set(
@@ -1147,16 +1155,15 @@ class PagedGenerationServer(_GenerationServerBase):
         perm, old_to_new = self.pool.defrag()
         # the gather covers every leaf of each node's dict — a quantized
         # pool's (num_pages, Hkv) scale sidecar permutes on the same
-        # axis 0 as its pages, so scales follow pages through compaction
-        self._caches = {
-            key: jax.tree.map(lambda b: b[perm], bufs)
-            for key, bufs in self._caches.items()
-        }
-        if self._caches_ref is not None:
-            self._caches_ref = {
-                key: jax.tree.map(lambda b: b[perm], bufs)
-                for key, bufs in self._caches_ref.items()
-            }
+        # axis 0 as its pages, so scales follow pages through compaction.
+        # A permutation cannot be gathered in place, so nothing is
+        # donated here; each leaf is rebound as soon as its gather is
+        # made, so the old leaf goes then and compaction holds one leaf
+        # twice, never a second pool
+        for caches in (self._caches, self._caches_ref):
+            for bufs in (caches or {}).values():
+                for name in bufs:
+                    bufs[name] = bufs[name][perm]
         # EVERY owner's table: the (slots, max_pages) matrix rewrite
         # covers every live slot (decoding and mid-prefill alike); shared
         # pages get the same new id in every owner's row because
@@ -1395,6 +1402,11 @@ class PagedGenerationServer(_GenerationServerBase):
                        kv_blocks=int((-(-pages // ppb)).sum()),
                        block_pages=ppb,
                        qk_pairs=int((q * p0 + q * (q + 1) // 2).sum()))
+                alias = self._pool_alias.get((B, window))
+                if alias is not None:
+                    # whether this shape's pools are written where they
+                    # lie, as its first call in warm-up showed
+                    sp.set(pools_passed=alias[0], pools_in_place=alias[1])
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
                 ids_d)

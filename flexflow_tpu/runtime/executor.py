@@ -67,6 +67,18 @@ _HLO_DTYPE_NAMES = {
 
 # key of a ragged step's per-node launch counters in its second result
 LAUNCH_STATS = "__launch_stats__"
+
+
+def _pool_buffers(caches) -> list:
+    """The device buffer addresses of a pool's leaves, in tree order (a
+    tuple a leaf: one address an addressable shard). What tells a pool
+    written IN PLACE from one that was copied: warm_launch_shapes reads
+    it before and after each shape's first call."""
+    return [tuple(s.data.unsafe_buffer_pointer()
+                  for s in leaf.addressable_shards)
+            for leaf in jax.tree.leaves(caches)]
+
+
 # the ops whose node owns a pool of the page cache
 PAGED_ATTENTION_OPS = (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
                        OpType.LATENT_ATTENTION)
@@ -868,25 +880,20 @@ class Executor:
         composites keep their layer-scan threaded dense caches and are
         not paged (their cache lives inside the scan carry)."""
         specs = self.paged_kv_cache_specs(num_pages, page_size, dtype)
-        pools = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), specs)
-        latent = {nk for nk, bufs in specs.items() if "c" in bufs}
-        if latent:
-            # a latent pool is born COMMITTED to its device, as every
-            # launch's output pool is: a launch shape then has ONE jit
-            # signature, where a K/V pool's has two (fresh and committed,
-            # warm_launch_shapes warms both) and compiles twice. A latent
-            # graph's step holds two Pallas kernel families a layer and
-            # costs 5 s a compile on the chip; 97 shapes twice were 980 s
-            # of set-up (PERF.md section 6, PR 27)
-            # (the sharding a launch gives its outputs: replicated over
-            # the model's one-device mesh)
-            from jax.sharding import NamedSharding, PartitionSpec
+        # a pool is born COMMITTED to its device, as every launch's output
+        # pool is (the sharding a launch gives its outputs: replicated over
+        # the model's mesh): a launch shape then has ONE jit signature and
+        # compiles once. The serving programs CONSUME the pool they are
+        # given (donate_argnums) and return it written in place, so there
+        # is one live buffer a pool from here on (docs/paged.md "Who owns
+        # the pool")
+        from jax.sharding import NamedSharding, PartitionSpec
 
-            where = (NamedSharding(self.mesh, PartitionSpec())
-                     if self.mesh is not None else jax.devices()[0])
-            pools = {nk: (jax.device_put(bufs, where) if nk in latent
-                          else bufs) for nk, bufs in pools.items()}
-        return pools
+        where = (NamedSharding(self.mesh, PartitionSpec())
+                 if self.mesh is not None else jax.devices()[0])
+        return jax.tree.map(
+            lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype), where),
+            specs)
 
     def paged_decode_fn(self):
         """jitted (params, pools, page_tables, pos, ids) ->
@@ -976,7 +983,13 @@ class Executor:
         window visibility; entries padded to the launch shape pass
         q_len 0 and do no work. Compiled once per (B, S) launch shape —
         the scheduler packs items into a small set of launch shapes, so
-        admission order and work mix never recompile it."""
+        admission order and work mix never recompile it.
+
+        The pools are DONATED: the K/V rows are scattered into the
+        buffers passed in, which are gone for the caller (rebind the
+        returned pools; docs/paged.md "Who owns the pool"). Undonated,
+        XLA copies every pool before the scatter of a few rows, every
+        layer, every launch."""
         if self._ragged_step_fn is not None:
             return self._ragged_step_fn
 
@@ -1000,7 +1013,8 @@ class Executor:
             return out, cache_out
 
         self._ragged_step_fn = self.compile_tracker.wrap(
-            "ragged_step", jax.jit(step), lambda args: args[8].shape)
+            "ragged_step", jax.jit(step, donate_argnums=(2,)),
+            lambda args: args[8].shape)
         return self._ragged_step_fn
 
     def paged_megastep_fn(self, max_ticks: int, eos_id=None):
@@ -1029,7 +1043,8 @@ class Executor:
         counts executed iterations; `done` marks who finished, so the
         host scheduler consumes the whole buffer in one transfer.
         Compiled once per (max_ticks, eos_id, slots) — table/positions
-        are contents, never shapes."""
+        are contents, never shapes. The pools are donated, as
+        ragged_step_fn's are."""
         key = (int(max_ticks), eos_id)
         fn = self._megastep_fns.pop(key, None)
         if fn is not None:
@@ -1085,7 +1100,7 @@ class Executor:
             return caches, out, done, rng, t
 
         fn = self.compile_tracker.wrap(
-            "megastep", jax.jit(megastep),
+            "megastep", jax.jit(megastep, donate_argnums=(2,)),
             lambda args, _n=N: (args[4].shape[0], _n))
         self._megastep_fns[key] = fn
         while len(self._megastep_fns) > self.JIT_CACHE_LIMIT:
@@ -1144,7 +1159,8 @@ class Executor:
         (`out_tokens` is (max_ticks, slots, depth+1), -1-padded, with
         `out_counts` the per-tick emission counts). One
         `jax.random.split` per tick keeps picks invariant in max_ticks.
-        Compiled once per (max_ticks, eos, window, depth, slots)."""
+        Compiled once per (max_ticks, eos, window, depth, slots). The
+        pools are donated, as ragged_step_fn's are."""
         key = (int(max_ticks), eos_id, int(window), int(depth), "mixed")
         fn = self._megastep_fns.pop(key, None)
         if fn is not None:
@@ -1287,7 +1303,7 @@ class Executor:
             return caches, seq, out, cnt, done, pf_fin, rng, t
 
         fn = self.compile_tracker.wrap(
-            "megastep_mixed", jax.jit(megastep),
+            "megastep_mixed", jax.jit(megastep, donate_argnums=(2,)),
             lambda args, _n=N, _w=Wl: (args[5].shape[0], _n, _w))
         self._megastep_fns[key] = fn
         while len(self._megastep_fns) > self.JIT_CACHE_LIMIT:
@@ -1302,7 +1318,8 @@ class Executor:
         page table; unused entries point a row at itself (a no-op copy),
         so one fixed-shape program serves every acceptance outcome.
         Rejected rows are NOT touched — they sit past the advanced write
-        head and are masked like any stale page content.
+        head and are masked like any stale page content. The pools are
+        donated: the rows move inside the buffers passed in.
 
         On a QUANTIZED pool (scale sidecar present, paged/quant.py) the
         copy is scale-aware: destination pages first GROW their scales
@@ -1359,7 +1376,8 @@ class Executor:
             return out
 
         self._paged_commit_fn = self.compile_tracker.wrap(
-            "paged_commit", jax.jit(commit), lambda args: args[2].shape)
+            "paged_commit", jax.jit(commit, donate_argnums=(0,)),
+            lambda args: args[2].shape)
         return self._paged_commit_fn
 
     def decode_fn(self):
@@ -1425,26 +1443,37 @@ class Executor:
         warms with active slots whose page capacity is exhausted, so its
         while_loop compiles fully but executes zero iterations.
 
+        ONE pool is threaded through every call: the serving programs
+        consume the pool they are given (donate_argnums) and return it
+        written in place, so each call's output pool is the next call's
+        input and no second pool is ever alive beside the server's. A
+        pool is born committed (init_paged_kv_cache), as a launch's
+        output is, so a launch shape has one jit signature and compiles
+        once. Each ragged shape's first call also records how many pool
+        leaves it was handed and how many came back in the SAME device buffer
+        (the result's `pool_alias`): deleting the argument proves nothing
+        (XLA may decline an alias and copy all the same), and the serving
+        tick puts the pair on its traced launches.
+
         The jit cache keys on each argument's COMMITTEDNESS as well as
         its aval (a jit output is committed to its device; a fresh
-        `jnp.asarray` upload is not), so each shape warms once per
-        committedness signature the serving loop produces: pools start
-        uncommitted and become committed (jit outputs) after the first
-        launch, and the rng key turns committed once a megastep's output
-        key re-enters the host split chain. Per-tick descriptor uploads
-        stay uncommitted forever and warm that way. The committed
-        variants are real launch OUTPUTS (the first warm call's new
-        caches, the megastep's output key) so their sharding matches
-        what the serve loop feeds back — a synthetic `device_put` would
-        both miss the cache key and clash with sharded params on a
-        multi-device mesh.
+        `jnp.asarray` upload is not), so the arguments that do change
+        committedness while serving warm both ways: the rng key turns
+        committed once a megastep's output key re-enters the host split
+        chain, and the mixed megastep's token ledger is its own output
+        between dispatches and a host upload after an admission.
+        Per-tick descriptor uploads stay uncommitted forever and warm
+        that way. The committed variants are real launch OUTPUTS (the
+        megastep's output key and ledger) so their sharding matches what
+        the serve loop feeds back.
 
         Returns {"warmed_shapes", "vocab", "probs_dtype", "probs_ref",
-        "rng_ref"} — the serving layer warms its (batch, vocab) sampling
-        program (the one entry the executor does not own) from slices of
-        probs_ref and splits of rng_ref. `on_probs`, if given, is called
-        with every ragged shape's (B, W, V) output, so the caller can warm
-        what it runs on a launch's probs at that shape."""
+        "rng_ref", "pool_alias"} — the serving layer warms its (batch,
+        vocab) sampling program (the one entry the executor does not own)
+        from slices of probs_ref and splits of rng_ref. `on_probs`, if
+        given, is called with every ragged shape's (B, W, V) output, so
+        the caller can warm what it runs on a launch's probs at that
+        shape."""
         import contextlib
         import time
 
@@ -1456,12 +1485,8 @@ class Executor:
         tr, ntr = params
         slots = int(cfg["slots"])
         warmed = 0
-        # committed stand-ins come from REAL launch outputs, never
-        # jax.device_put: under a multi-device mesh a device_put'd array
-        # carries a different sharding than a jit output, which is both
-        # a wrong cache key and an incompatible-devices error when mixed
-        # with sharded params
         probs = probs_ref = rng_ref = caches_c = None
+        pool_alias: Dict[Tuple[int, int], Tuple[int, int]] = {}
         if cfg.get("paged", True):
             from flexflow_tpu.paged.quant import resolve_kv_dtype
 
@@ -1469,8 +1494,8 @@ class Executor:
             cols = int(cfg["table_cols"])
             num_pages = int(cfg["num_pages"] or slots * cols + 1)
             pool_dt = resolve_kv_dtype(cfg.get("kv_dtype") or "auto")
-            caches_u = self.init_paged_kv_cache(num_pages, page_size,
-                                                dtype=pool_dt)
+            caches = self.init_paged_kv_cache(num_pages, page_size,
+                                              dtype=pool_dt)
             step = self.ragged_step_fn()
             for B, W in entries.get(  # fflint: host-ok (one-time warmup)
                     "ragged_step", {}).get("shapes", ()):
@@ -1491,15 +1516,12 @@ class Executor:
                         jnp.asarray(np.zeros((B,), np.int32)),
                         deps, anc,
                         jnp.asarray(np.zeros((B, W), np.int32)))
-                # pools start uncommitted (host init) and are committed
-                # launch outputs from the first tick on — warm both;
-                # the first call's output IS the serve-loop committed
-                # pool state
+                before = _pool_buffers(caches)
                 with obs.span("warm_shape") as sp:
                     t0 = time.monotonic()
                     with (compile_split() if sp
                           else contextlib.nullcontext()) as split:
-                        probs, caches_out = step(tr, ntr, caches_u, *args)
+                        probs, caches = step(tr, ntr, caches, *args)
                     if sp:
                         # for the record of set-up: what THIS shape's
                         # first call cost (jax's own compile phases; they
@@ -1510,10 +1532,11 @@ class Executor:
                         sp.set(window=W, rows=B * W, **split,
                                call_s=t1 - t0,
                                first_run_s=time.monotonic() - t1)
-                    caches_out.pop(LAUNCH_STATS, None)
-                    if caches_c is None:
-                        caches_c = caches_out
-                    probs, _ = step(tr, ntr, caches_c, *args)
+                    caches.pop(LAUNCH_STATS, None)
+                # the shape's pool leaves, and how many of them came back
+                # in the device buffer they went in with
+                pool_alias[(B, W)] = (len(before), sum(
+                    a == b for a, b in zip(before, _pool_buffers(caches))))
                 if on_probs is not None:
                     on_probs(probs)
                 if probs_ref is None or B == slots:
@@ -1526,12 +1549,11 @@ class Executor:
                 args = (jnp.zeros((int(S), cols), jnp.int32), z, z,
                         jnp.asarray(np.zeros((int(S),), np.float32)),
                         z, z, jnp.asarray(np.ones((int(S),), np.bool_)))
-                # a megastep always follows launches (pools committed);
                 # its rng is host-chain (uncommitted) on the first
                 # dispatch and its own output key (committed) after
-                out = fn(tr, ntr, caches_c, *args, jax.random.key(0))
+                out = fn(tr, ntr, caches, *args, jax.random.key(0))
                 rng_ref = out[3]
-                fn(tr, ntr, caches_c, *args, rng_ref)
+                caches = fn(tr, ntr, out[0], *args, rng_ref)[0]
                 warmed += 1
             for S, NT, _WL in entries.get(  # fflint: host-ok (one-time warmup)
                     "megastep_mixed", {}).get("shapes", ()):
@@ -1554,29 +1576,23 @@ class Executor:
                          bT, bF, bF)
                 # dec_active with zero cap_rows: the while_loop compiles
                 # fully but executes zero iterations (same trick as the
-                # decode megastep warm above). UNLIKE the decode
-                # megastep, the mixed one can be the VERY FIRST dispatch
-                # of a serve (prefill rides it), so the virgin
-                # host-uploaded pool (uncommitted) is a reachable cache
-                # input, not just launch outputs (committed)
-                fnm(tr, ntr, caches_u, *margs, jax.random.key(0))
-                out = fnm(tr, ntr, caches_c, *margs, jax.random.key(0))
+                # decode megastep warm above)
+                out = fnm(tr, ntr, caches, *margs, jax.random.key(0))
                 rng_ref = out[6]
-                fnm(tr, ntr, caches_c, *margs, rng_ref)
+                out = fnm(tr, ntr, out[0], *margs, rng_ref)
                 # steady state carries the previous dispatch's seq
                 # ledger (committed) forward; admission dirties it back
                 # to a host upload — warm both combos
-                seq_c = out[1]
-                margs_c = margs[:1] + (seq_c,) + margs[2:]
-                fnm(tr, ntr, caches_c, *margs_c, rng_ref)
+                margs_c = margs[:1] + (out[1],) + margs[2:]
+                caches = fnm(tr, ntr, out[0], *margs_c, rng_ref)[0]
                 warmed += 1
             commit = (self.paged_commit_fn()
                       if "paged_commit" in entries else None)
             for S, C in entries.get(  # fflint: host-ok (one-time warmup)
                     "paged_commit", {}).get("shapes", ()):
                 z = jnp.asarray(np.zeros((int(S), int(C)), np.int32))
-                commit(caches_c, jnp.zeros((slots, cols), jnp.int32),
-                       z, z)
+                caches = commit(caches, jnp.zeros((slots, cols), jnp.int32),
+                                z, z)
                 warmed += 1
         else:
             max_len = int(cfg["max_len"])
@@ -1612,6 +1628,9 @@ class Executor:
             # rng_ref reproduces the post-megastep committed key chain
             "probs_ref": probs_ref,
             "rng_ref": rng_ref,
+            # ragged launch shape (B, W) -> (pool leaves passed, leaves
+            # that came back in the buffer they went in with)
+            "pool_alias": pool_alias,
         }
 
     # ------------------------------------------------------------------

@@ -603,6 +603,9 @@ class _GenerationServerBase:
         self._pick = tracker.wrap("pick_tokens", jax.jit(pick_tokens),
                                   lambda args: (args[0].shape[0],))
         self._probs_row = jax.jit(probs_row)
+        # ragged launch shape -> (pool leaves passed, leaves written in
+        # place), as warm_launch_shapes saw the shape's first call
+        self._pool_alias: dict = {}
         self._queue: "queue.Queue[_GenRequest]" = queue.Queue()
         self._active: List[Optional[_GenRequest]] = [None] * self.slots
         self._tokens = np.zeros((self.slots,), np.int32)
@@ -864,6 +867,7 @@ class _GenerationServerBase:
         info = self.ff.executor.warm_launch_shapes(
             catalog, params=self._params, eos_id=self.eos_id,
             on_probs=on_probs)
+        self._pool_alias = info["pool_alias"]
         # the rng chain's split: a host-made key first, its own (committed)
         # output from then on; throwaway keys, as below
         key, _ = jax.random.split(jax.random.key(0))

@@ -300,8 +300,10 @@ def test_scale_aware_commit_copies_across_scales(lm):
                 pool, scales, jnp.asarray(rows)[None],
                 jnp.full((1, P), pg), jnp.arange(P)[None],
                 jnp.ones((1, P), bool))
-        return {"n": {"k": pool, "v": pool, "k_scale": scales,
-                      "v_scale": scales}}
+        # the commit consumes the pool it is given: a buffer each for
+        # key and value, never one handed in twice
+        return {"n": {"k": pool, "v": jnp.array(pool), "k_scale": scales,
+                      "v_scale": jnp.array(scales)}}
 
     pt = jnp.asarray([[1, 2]], jnp.int32)   # cache rows 0..3 -> page 1
 
