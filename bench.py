@@ -1,8 +1,9 @@
 """Benchmark: Llama training throughput (tokens/sec) on the local chip.
 
 Compares the framework's compiled train step against a hand-written "naive
-JAX" Llama trainer (the BASELINE.json data-parallel baseline, scaled to the
-available chip count) at identical config/batch/dtype/optimizer. The LAST
+JAX" Llama trainer (plain data parallelism over the available chips) at
+identical config/batch/dtype/optimizer. It is not a cell of the benchmark
+(BENCHMARK.json, benchmark/run.py), which is where speed is recorded. The LAST
 stdout line is the result JSON: {"metric", "value", "unit", "vs_baseline",
 "platform", "device_kind", "n_devices", ...}.
 
@@ -312,533 +313,6 @@ def bench_naive(x, y) -> float:
     return BATCH * SEQ / dt
 
 
-def bench_decode() -> dict:
-    """Serving-side benchmark (bench.py --decode): paged continuous-
-    batching decode throughput, then speculative decoding on a
-    repetitive-prompt fixture (a token-cyclic model, so the n-gram
-    drafter's acceptance is exercised for real). Runs in-process — any
-    backend under --smoke, a TPU otherwise — and reports decode tokens/sec
-    plus the speculation acceptance metrics, so BENCH json covers
-    serving, not just training step time."""
-    from flexflow_tpu import FFConfig, FFModel, LossType
-    from flexflow_tpu.ffconst import DataType
-    from flexflow_tpu.models.llama import LlamaConfig, build_llama
-    from flexflow_tpu.spec import SpecConfig
-
-    smoke = bool(os.environ.get("FLEXFLOW_BENCH_SMOKE"))
-    if smoke:
-        lcfg = LlamaConfig.tiny(vocab=128)
-        n_req, max_new, max_len, page = 6, 16, 64, 8
-    else:
-        lcfg = LlamaConfig(vocab_size=8192, dim=512, layers=6, heads=8,
-                           kv_heads=4, hidden=1408, rope_theta=10000.0)
-        n_req, max_new, max_len, page = 16, 128, 512, 64
-    _log(f"decode bench: building model (vocab={lcfg.vocab_size}, "
-         f"dim={lcfg.dim}, layers={lcfg.layers})")
-    ff = FFModel(FFConfig(batch_size=1, seed=0))
-    build_llama(ff, lcfg, batch_size=1, seq_len=8, dtype=DataType.FLOAT)
-    ff.compile(loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
-    rs = np.random.RandomState(0)
-
-    def run_server(prompts, speculate=None, max_new_tokens=None):
-        mn = max_new if max_new_tokens is None else int(max_new_tokens)
-        server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                     page_size=page, speculate=speculate)
-        try:
-            # warm every compile off the clock: both prefill buckets the
-            # 4..16-token prompts can hit (8 and 16) plus the decode step
-            server.generate(prompts[0][:3], max_new_tokens=2)
-            server.generate(np.tile(prompts[0], 4)[:16], max_new_tokens=2)
-            warm = server.metrics().get("speculative", {})
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=mn)
-                    for p in prompts]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            metrics = server.metrics()
-            sm = metrics.get("speculative")
-            if sm:
-                # report the TIMED window only: subtract the warm-up
-                # requests' raw counters and re-derive the two rates
-                for k in ("steps", "draft_tokens", "accepted_tokens",
-                          "emitted_tokens"):
-                    sm[k] -= warm.get(k, 0)
-                sm["acceptance_rate"] = (sm["accepted_tokens"]
-                                         / sm["draft_tokens"]
-                                         if sm["draft_tokens"] else 0.0)
-                sm["accepted_tokens_per_step"] = (sm["emitted_tokens"]
-                                                  / sm["steps"]
-                                                  if sm["steps"] else 0.0)
-        finally:
-            server.stop()
-        toks = sum(len(o) for o in outs)
-        return toks / dt, toks, metrics
-
-    # fixtures come from the named traffic profiles (search/traffic.py)
-    # so the bench and the serving-strategy search (ISSUE 12) score
-    # against the SAME workloads; each profile draws through `rs` in the
-    # order the inline fixtures always used, so seeded draws are stable
-    from flexflow_tpu.search import traffic as traffic_mod
-
-    smoke_prof = traffic_mod.get_profile("smoke", requests=n_req,
-                                         new_tokens=max_new)
-    prompts = smoke_prof.sample(rs, lcfg.vocab_size).prompts
-    _log("decode bench: plain paged serving")
-    tps, toks, plain_m = run_server(prompts)
-    # tick-latency percentiles ride the always-on serving histograms
-    # (fftrace/obs.metrics) — no tracing needed for these
-    tick_h = plain_m["histograms"]["tick_latency_s"]
-
-    # TTFT compile/serve split (shapecheck runtime arm): percentiles
-    # over ALL requests including the warm-ups — those pay the
-    # first-compile cost, so incl-vs-excl is exactly what catalog
-    # warming (Server.warm_launch_shapes) saves a cold first request
-    recs = [r for r in plain_m["requests"] if r["ttft_s"] is not None]
-    ttft_split = {
-        "ttft_p95_incl_compile_s": round(float(np.percentile(
-            [r["ttft_s"] for r in recs], 95)), 6),
-        "ttft_p95_excl_compile_s": round(float(np.percentile(
-            [r.get("ttft_excl_compile_s", r["ttft_s"]) for r in recs],
-            95)), 6),
-        "first_compile_s_max": round(max(
-            (r.get("first_compile_s") or 0.0) for r in recs), 6),
-        "compile": plain_m.get("compile", {}),
-    }
-
-    # shared-system-prompt fixture: every request opens with the same
-    # system prefix, so the prefix cache serves the bulk of prefill for
-    # the second and later requests — report TTFT p50/p95 and the hit
-    # rate (ISSUE 5: >=50% of 2nd+ prefill tokens from cache)
-    shared_prof = traffic_mod.get_profile("shared-system-prompt",
-                                          page_size=page, requests=n_req,
-                                          new_tokens=max_new)
-    sys_len = shared_prof.shared_prefix_tokens
-    shared_sample = shared_prof.sample(rs, lcfg.vocab_size)
-    sys_prompt = shared_sample.shared_prefix
-    shared = shared_sample.prompts
-    _log("decode bench: shared-system-prompt fixture (prefix cache)")
-    server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                 page_size=page)
-    try:
-        # warm-up OFF the clock: publish the shared blocks and trace
-        # every chunk bucket a measured suffix can hit (4..16 uncached
-        # tokens -> buckets 8/16/32; the full first prompt covers the
-        # larger ones) — same discipline as the plain fixture's bucket
-        # warm-up, so the percentiles measure serving latency, not jit
-        # tracing
-        n_warm = 0
-        for wlen in (17, 12, 4):
-            warm = np.concatenate([
-                sys_prompt,
-                rs.randint(0, lcfg.vocab_size, (wlen,)).astype(np.int32)])
-            server.generate(warm, max_new_tokens=max_new)
-            n_warm += 1
-        futs = [server.submit(p, max_new_tokens=max_new) for p in shared]
-        for f in futs:
-            f.result(timeout=1200)
-        sm = server.metrics()
-    finally:
-        server.stop()
-    # every measured request runs against the warmed cache + traced
-    # buckets; the warm-up records are excluded
-    later = sm["requests"][n_warm:]
-    ttfts = [r["ttft_s"] for r in later if r["ttft_s"] is not None]
-    hit = sum(r["cached_prefill_tokens"] for r in later)
-    computed = sum(r["prefill_tokens"] for r in later)
-    hit_rate = hit / (hit + computed) if hit + computed else 0.0
-    prefix_metrics = {
-        "ttft_p50_s": round(float(np.percentile(ttfts, 50)), 6),
-        "ttft_p95_s": round(float(np.percentile(ttfts, 95)), 6),
-        "prefix_cache_hit_rate": round(hit_rate, 4),
-        "hit_tokens": int(sm["prefix_cache"]["hit_tokens"]),
-        "evictions": int(sm["prefix_cache"]["evictions"]),
-        "fixture": f"{sys_len}-token shared system prompt, "
-                   f"{len(shared)} requests",
-    }
-
-    # ragged work packing A/B (ISSUE 10): a MIXED fixture — long prompts
-    # prefilling chunk by chunk while short prompts decode — served with
-    # packed per-slot descriptors (ragged_pack=True) and with the legacy
-    # fixed-shape rotating-chunk launches (False). Reported per arm:
-    # decode tokens/sec, TTFT p95 and the padded-row waste ratio; the
-    # acceptance bar is packed waste strictly below legacy at
-    # equal-or-better tokens/sec.
-    _log("decode bench: ragged packing A/B (mixed prefill/decode)")
-    chunk = 3 * page
-    mixed_prof = traffic_mod.get_profile("mixed-length", page_size=page,
-                                         prefill_chunk=chunk,
-                                         requests=n_req,
-                                         new_tokens=max_new)
-    mixed = mixed_prof.sample(rs, lcfg.vocab_size).prompts
-    ragged_ab = {}
-    for pack in (True, False):
-        server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                     page_size=page, prefill_chunk=chunk,
-                                     ragged_pack=pack)
-        try:
-            # warm both arms' launch shapes off the clock
-            server.generate(mixed[0][:3], max_new_tokens=2)
-            server.generate(mixed[1], max_new_tokens=2)
-            n_warm = 2
-            m0 = server.metrics()
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=max_new)
-                    for p in mixed]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            m = server.metrics()
-        finally:
-            server.stop()
-        rows = m["launch_rows"] - m0["launch_rows"]
-        pad = m["padded_rows"] - m0["padded_rows"]
-        ttfts = [r["ttft_s"] for r in m["requests"][n_warm:]
-                 if r["ttft_s"] is not None]
-        ragged_ab["packed" if pack else "legacy"] = {
-            "decode_tokens_per_sec": round(
-                sum(len(o) for o in outs) / dt, 2),
-            "ttft_p95_s": round(float(np.percentile(ttfts, 95)), 6),
-            "padding_waste_ratio": round(pad / rows if rows else 0.0, 4),
-            "launch_rows": int(rows),
-            "kernel_variant": m["kernel_variant"],
-        }
-    ragged_ab["fixture"] = (
-        f"{n_req} requests, half short (4..9 tokens), half {chunk}+ "
-        f"tokens chunked at prefill_chunk={chunk}")
-
-    # decode megastep A/B (ISSUE 11): the SAME decode-heavy fixture
-    # served with the one-tick host loop (megastep_ticks=1) and with
-    # 8 ticks fused per dispatch (megastep_ticks=8, the device-resident
-    # while_loop). Reported per arm: decode tokens/sec, effective
-    # per-tick latency p50/p95 (the histogram divides each megastep's
-    # wall time by its tick count, so widths stay comparable) and host
-    # roundtrips per decoded token. The acceptance bar is N=8 strictly
-    # higher tokens/sec AND strictly fewer roundtrips/token than N=1.
-    _log("decode bench: megastep A/B (N=1 vs N=8)")
-    mega_prompts = [rs.randint(0, lcfg.vocab_size, (rs.randint(4, 9),))
-                    .astype(np.int32) for _ in range(n_req)]
-    mega_ab = {}
-    for n_ticks in (1, 8):
-        server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                     page_size=page,
-                                     megastep_ticks=n_ticks)
-        try:
-            # trace both arms' launch shapes off the clock
-            server.generate(mega_prompts[0], max_new_tokens=max_new)
-            m0 = server.metrics()
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=max_new)
-                    for p in mega_prompts]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            m = server.metrics()
-        finally:
-            server.stop()
-        rt = m["megastep"]["host_roundtrips"] \
-            - m0["megastep"]["host_roundtrips"]
-        dtok = m["megastep"]["decode_tokens"] \
-            - m0["megastep"]["decode_tokens"]
-        th = m["histograms"]["tick_latency_s"]
-        mega_ab[f"n{n_ticks}"] = {
-            "decode_tokens_per_sec": round(
-                sum(len(o) for o in outs) / dt, 2),
-            "tick_latency_p50_s": round(float(th["p50"]), 6),
-            "tick_latency_p95_s": round(float(th["p95"]), 6),
-            "host_roundtrips_per_token": round(rt / dtok, 4) if dtok
-            else 0.0,
-            "megastep_breaks": dict(m["megastep"]["breaks"]),
-        }
-    mega_ab["fixture"] = (
-        f"{n_req} short prompts (4..8 tokens), {max_new} new tokens "
-        f"each, page_size={page}")
-
-    # universal-megastep A/B (ISSUE 20): the SAME mixed prefill-heavy/
-    # decode-heavy fixture (the ragged A/B's mixed-length sample: half
-    # short, half chunk-spanning prompts) served three ways — the
-    # one-tick host loop, the decode-only fused megastep (prefill
-    # chunks force one-tick dispatches while in flight), and the
-    # universal megastep with overlapped host dispatch (chunks and
-    # drafted chains ride the fused while_loop; admission runs while
-    # the device computes). Reported per arm: decode tokens/sec, host
-    # roundtrips per decoded token, and TTFT p95. The acceptance bar is
-    # universal strictly dominating decode-only on BOTH rt/token and
-    # tokens/sec on this mixed traffic.
-    _log("decode bench: universal megastep A/B "
-         "(legacy vs decode-fused vs universal+overlap)")
-    fused_ab = {}
-    fused_outs = {}
-    arms = (("legacy", dict(megastep_ticks=1)),
-            ("decode_fused", dict(megastep_ticks=8)),
-            ("universal", dict(megastep_ticks=8, megastep_mixed=True,
-                               overlap_dispatch=True)))
-    for label, kwargs in arms:
-        server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                     page_size=page, prefill_chunk=chunk,
-                                     **kwargs)
-        try:
-            # catalog-driven warmup: every launch family this arm can
-            # dispatch compiles off the clock
-            server.warm_launch_shapes()
-            m0 = server.metrics()
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=max_new)
-                    for p in mixed]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            m = server.metrics()
-        finally:
-            server.stop()
-        fused_outs[label] = outs
-        rt = m["megastep"]["host_roundtrips"] \
-            - m0["megastep"]["host_roundtrips"]
-        dtok = m["megastep"]["decode_tokens"] \
-            - m0["megastep"]["decode_tokens"]
-        ttfts = [r["ttft_s"] for r in m["requests"]
-                 if r["ttft_s"] is not None]
-        fused_ab[label] = {
-            "decode_tokens_per_sec": round(
-                sum(len(o) for o in outs) / dt, 2),
-            "host_roundtrips_per_token": round(rt / dtok, 4) if dtok
-            else 0.0,
-            "ttft_p95_s": round(float(np.percentile(ttfts, 95)), 6),
-            "host_overlap_ratio": round(
-                float(m["megastep"]["host_overlap_ratio"]), 4),
-            "megastep_breaks": dict(m["megastep"]["breaks"]),
-        }
-    fused_ab["greedy_streams_matched"] = sum(
-        int(np.array_equal(a, b) and np.array_equal(a, c))
-        for a, b, c in zip(fused_outs["legacy"],
-                           fused_outs["decode_fused"],
-                           fused_outs["universal"]))
-    fused_ab["universal_dominates_decode_fused"] = bool(
-        fused_ab["universal"]["host_roundtrips_per_token"]
-        < fused_ab["decode_fused"]["host_roundtrips_per_token"]
-        and fused_ab["universal"]["decode_tokens_per_sec"]
-        > fused_ab["decode_fused"]["decode_tokens_per_sec"])
-    fused_ab["fixture"] = (
-        f"{len(mixed)} mixed-length requests (half short, half "
-        f"{chunk}+ tokens), prefill_chunk={chunk}, page_size={page}")
-
-    # searched-vs-default A/B (ISSUE 12): run the serving-strategy
-    # search at a small budget on the smoke profile, then serve BOTH the
-    # hand default and the searched winner on the plain fixture —
-    # simulated objective side by side with realized decode tokens/sec
-    # and TTFT p95, so the search's wins are checked against a real
-    # server, not just its own tick pricing. Must run before
-    # make_token_cyclic below, which rewrites the weights.
-    _log("decode bench: searched-vs-default serving strategy A/B")
-    from flexflow_tpu.search.servesearch import (
-        ServeStrategy,
-        search_serve_strategy,
-    )
-
-    sres = search_serve_strategy(
-        ff, traffic=smoke_prof, budget=120, seed=0, slots=4,
-        max_len=max_len, default=ServeStrategy(page_size=page))
-    searched_ab = {
-        "objective": {
-            "default": round(sres.default_objective, 8),
-            "searched": round(sres.best_objective, 8),
-            "improvement": round(sres.improvement, 4),
-        },
-        "strategy": sres.best.to_json(),
-    }
-    for label, strat in (("default", sres.default),
-                         ("searched", sres.best)):
-        server = ff.serve_generation(slots=4, max_len=max_len,
-                                     serve_strategy=strat)
-        try:
-            # full warm pass off the clock: each strategy compiles its
-            # own launch shapes (chunk buckets, megastep loop, packing
-            # variant), so serve the whole fixture once untimed — the
-            # timed pass then measures serving, not jit tracing
-            for f in [server.submit(p, max_new_tokens=max_new)
-                      for p in prompts]:
-                f.result(timeout=1200)
-            n_warm = len(prompts)
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=max_new)
-                    for p in prompts]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            m = server.metrics()
-        finally:
-            server.stop()
-        ttfts = [r["ttft_s"] for r in m["requests"][n_warm:]
-                 if r["ttft_s"] is not None]
-        searched_ab[label] = {
-            "decode_tokens_per_sec": round(
-                sum(len(o) for o in outs) / dt, 2),
-            "ttft_p95_s": round(float(np.percentile(ttfts, 95)), 6),
-            "describe": strat.describe(),
-        }
-
-    # quantized-KV A/B (ISSUE 13): the SAME shared-prefix fixture served
-    # from the model-dtype pool and from an int8+scale-sidecar pool,
-    # each sized to the SAME HBM budget (a pool two sequences wide at fp
-    # bytes — tight enough that capacity binds). The int8 arm buys ~4x
-    # the pages, so it admits more concurrent requests and keeps more
-    # prefix pages cached; reported per arm: pool pages, concurrent-
-    # request capacity, peak concurrency, preemptions, prefix hit rate,
-    # decode tokens/sec, and the kv_cache_dtype / kv_quant_error gauges.
-    # Greedy outputs are compared stream-for-stream across the arms
-    # (token flips are the documented logit-tolerance story, not bugs).
-    # Must run before make_token_cyclic below (it rewrites the weights).
-    _log("decode bench: quantized KV A/B (fixed HBM budget)")
-    from flexflow_tpu.search.cost_model import kv_cache_token_bytes
-
-    pages_per_seq = -(-max_len // page)
-    kv_fp_b = kv_cache_token_bytes(ff.graph)
-    kv_q_b = kv_cache_token_bytes(ff.graph, kv_dtype="int8",
-                                  page_size=page)
-    hbm_budget = (2 * pages_per_seq + 1) * page * kv_fp_b
-    quant_ab = {
-        "hbm_budget_bytes": int(hbm_budget),
-        "kv_token_bytes": {"fp": int(kv_fp_b), "int8": int(kv_q_b)},
-    }
-    arm_outs = {}
-    for arm, kv_dt in (("fp", "auto"), ("int8", "int8")):
-        kv_b = kv_fp_b if kv_dt == "auto" else kv_q_b
-        pool_pages = max(int(hbm_budget // (page * kv_b)),
-                         pages_per_seq + 1)
-        server = ff.serve_generation(slots=4, max_len=max_len, paged=True,
-                                     page_size=page, num_pages=pool_pages,
-                                     kv_dtype=kv_dt)
-        try:
-            # warm the chunk buckets + decode step off the clock
-            server.generate(shared[0][:3], max_new_tokens=2)
-            server.generate(shared[0], max_new_tokens=2)
-            n_warm = 2
-            t0 = time.perf_counter()
-            futs = [server.submit(p, max_new_tokens=max_new)
-                    for p in shared]
-            outs = [f.result(timeout=1200) for f in futs]
-            dt = time.perf_counter() - t0
-            m = server.metrics()
-        finally:
-            server.stop()
-        arm_outs[arm] = outs
-        later = m["requests"][n_warm:]
-        hit = sum(r["cached_prefill_tokens"] for r in later)
-        computed = sum(r["prefill_tokens"] for r in later)
-        quant_ab[arm] = {
-            "pool_pages": pool_pages,
-            "request_capacity": (pool_pages - 1) // pages_per_seq,
-            "decode_tokens_per_sec": round(
-                sum(len(o) for o in outs) / dt, 2),
-            "peak_active": int(m["peak_active"]),
-            "preemptions": int(m["preemptions"]),
-            "prefix_cache_hit_rate": round(
-                hit / (hit + computed) if hit + computed else 0.0, 4),
-            "kv_cache_dtype": m["kv_cache_dtype"],
-            "kv_quant_error": m["kv_quant_error"],
-        }
-    quant_ab["capacity_ratio"] = round(
-        quant_ab["int8"]["pool_pages"] / quant_ab["fp"]["pool_pages"], 2)
-    quant_ab["greedy_streams_matched"] = sum(
-        int(np.array_equal(a, b))
-        for a, b in zip(arm_outs["fp"], arm_outs["int8"]))
-    quant_ab["fixture"] = (
-        f"{len(shared)} shared-prefix requests, both pools sized to "
-        f"{hbm_budget} KV bytes")
-
-    # production-shaped profiles (search/traffic.py): the ROADMAP's two
-    # serving shapes — long-context summarization (prefill-heavy) and
-    # agentic many-turn (deep shared prefix, decode-heavy) — served
-    # through the same harness, so the bench and the serving-strategy
-    # search score the SAME fixtures the search can now also replay
-    production = {}
-    for prof_name in ("long-context-summarization", "agentic-multiturn"):
-        prof = traffic_mod.get_profile(prof_name, page_size=page,
-                                       requests=n_req)
-        _log(f"decode bench: {prof.name} fixture")
-        p_tps, p_toks, pm = run_server(
-            prof.sample(rs, lcfg.vocab_size).prompts,
-            max_new_tokens=prof.new_tokens)
-        p_recs = pm["requests"]
-        p_ttfts = [r["ttft_s"] for r in p_recs if r["ttft_s"] is not None]
-        p_hit = sum(r["cached_prefill_tokens"] for r in p_recs)
-        p_comp = sum(r["prefill_tokens"] for r in p_recs)
-        production[prof.name] = {
-            "tokens_per_sec": round(p_tps, 2),
-            "decode_tokens": p_toks,
-            "ttft_p95_s": round(float(np.percentile(p_ttfts, 95)), 6),
-            "prefix_cache_hit_rate": round(
-                p_hit / (p_hit + p_comp) if p_hit + p_comp else 0.0, 4),
-            "fixture": prof.description,
-        }
-
-    # repetitive fixture: token-cyclic model (shared with tests/test_spec)
-    from flexflow_tpu.spec.fixtures import make_token_cyclic
-
-    make_token_cyclic(ff)
-    _log("decode bench: speculative serving on the repetitive fixture")
-    spec_tps, _spec_toks, m = run_server(
-        prompts, speculate=SpecConfig(width=2, depth=4))
-    sm = m["speculative"]
-
-    # traced pass (fftrace): a short re-run with the span recorder + tick
-    # ledger on produces the Chrome-trace artifact and a predicted-vs-
-    # measured calibration summary. The timed runs above stay untraced so
-    # the reported throughput is the no-tracing number.
-    from flexflow_tpu import obs
-    from flexflow_tpu.obs.calibrate import (
-        calibration_report,
-        stamp_ledger_meta,
-    )
-
-    _log("decode bench: traced pass (fftrace)")
-    calibration = None
-    rec = obs.enable()
-    try:
-        # short plain + speculative passes so decode, prefill AND verify
-        # tick shapes all land in the calibration ledger
-        run_server(prompts[:2])
-        run_server(prompts[:max(2, n_req // 4)],
-                   speculate=SpecConfig(width=2, depth=4))
-    finally:
-        obs.disable()
-    try:
-        stamp_ledger_meta(rec.ledger, ff, fixture="bench_decode")
-        report = calibration_report(rec.ledger)
-        calibration = {
-            "pricing_mode": report["base"].get("pricing_mode"),
-            "phases": {k: round(v, 4) for k, v in report["phases"].items()},
-            "shapes": len(report["shapes"]),
-        }
-    except Exception as e:
-        _log(f"calibration report unavailable: {type(e).__name__}: {e}")
-    if not smoke:
-        os.makedirs(os.path.dirname(_DECODE_TRACE_PATH), exist_ok=True)
-        rec.export_chrome_trace(_DECODE_TRACE_PATH)
-        _log(f"trace artifact: {_DECODE_TRACE_PATH}")
-
-    return {
-        "metric": "paged_decode_tokens_per_sec",
-        "value": round(tps, 2),
-        "unit": "tokens/s",
-        "requests": n_req,
-        "decode_tokens": toks,
-        "tick_latency_p50_s": round(float(tick_h["p50"]), 6),
-        "tick_latency_p95_s": round(float(tick_h["p95"]), 6),
-        "ttft_compile_split": ttft_split,
-        "calibration": calibration,
-        "prefix_cache": prefix_metrics,
-        "ragged_packing": ragged_ab,
-        "megastep": mega_ab,
-        "fused_megastep": fused_ab,
-        "servesearch": searched_ab,
-        "quantized_kv": quant_ab,
-        "profiles": production,
-        "speculative": {
-            "tokens_per_sec": round(spec_tps, 2),
-            "acceptance_rate": round(sm["acceptance_rate"], 4),
-            "accepted_tokens_per_step": round(
-                sm["accepted_tokens_per_step"], 4),
-            "fixture": "token-cyclic model (repetitive greedy stream)",
-        },
-    }
-
-
 def _start_child_backend() -> dict:
     """First thing every process that touches the device does: pick the
     platform when one was forced (--platform), place the compile cache,
@@ -877,13 +351,6 @@ def _run_side(side: str) -> dict:
 # ---- parent-side orchestration (never touches jax) -------------------------
 
 _BUDGET = float(os.environ.get("FLEXFLOW_BENCH_BUDGET", "3000"))
-
-# Chrome-trace artifact from the decode bench's traced pass (Perfetto-
-# loadable); written only on non-smoke runs, where the chip tool brings
-# chiprun_out/ back
-_DECODE_TRACE_PATH = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)),
-    "chiprun_out", "bench_decode_trace.json.gz")
 
 
 def _remaining() -> float:
@@ -954,13 +421,6 @@ def main():
                      "[--config 1b|200m]")
         os.environ["FLEXFLOW_BENCH_PLATFORM"] = sys.argv[i + 1]
         del sys.argv[i:i + 2]
-    if "--decode" in sys.argv:
-        # serving-side bench: in-process, no subprocess orchestration (it
-        # has no naive-baseline side and is CPU-capable under --smoke)
-        sys.argv.remove("--decode")
-        facts = _start_child_backend()
-        print(json.dumps({**bench_decode(), **facts}))
-        return
     only_config = None
     if "--config" in sys.argv:
         i = sys.argv.index("--config")

@@ -2,7 +2,8 @@
 
 The single home of the config list that used to live in
 tools/rule_coverage.py: each entry is (name, build(ff), mesh_shape) for
-the BASELINE.md targets plus InceptionV3 (where the concat/merge algebra
+the round's five target configs (AlexNet, ResNet-50, BERT-base, Llama
+TP+DP, Mixtral EP; SURVEY.md) plus InceptionV3 (where the concat/merge algebra
 demonstrably fires) plus a seq-parallel llama variant that exercises the
 ring/ulysses comm-spec cross-check. `build_baseline_subjects()` builds
 the PCGs with their canonical hand strategies (default DP where no hand

@@ -9,7 +9,7 @@ the whole step lowers to ONE optimized HLO module that can be parsed
 statically (the full-compilation discipline of "Automatic Full
 Compilation of Julia Programs to Cloud TPUs"). This pass AOT-lowers each
 config's real jitted entry points (Executor.lowered_modules: train_step,
-eval_step, paged_decode_fn, verify_fn) on the multi-device CPU mesh,
+eval_step, ragged_step_fn at two shapes) on the multi-device CPU mesh,
 parses the optimized HLO into a structured program summary —
 
   - the collective schedule: kind / replica groups / payload bytes per

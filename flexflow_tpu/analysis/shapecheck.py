@@ -59,7 +59,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from flexflow_tpu.analysis import AnalysisContext, Finding, register_pass
 
-# The scheduler's packed-prefill window cap (paged/scheduler.py
+# The scheduler's packed-prefill window cap (serve_strategy.py
 # PREFILL_WINDOW_ROWS). Mirrored as a plain int so the pass never
 # imports the serving stack (fflint must run on a bare checkout);
 # tests/test_analysis.py asserts the two constants agree.
@@ -416,7 +416,7 @@ def _packed_prefill_shapes(slots: int, chunk: int,
 
 def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
                       page_size: int = 64,
-                      prefill_chunk: int = 64, ragged_pack: bool = True,
+                      prefill_chunk: int = 64,
                       megastep_ticks: int = 1,
                       megastep_mixed: bool = False,
                       spec_max_nodes: Optional[int] = None,
@@ -442,19 +442,13 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
 
     if paged:
         ragged: Set[Tuple[int, int]] = {(slots, 1)}  # decode tick
-        if ragged_pack:
-            ragged |= _packed_prefill_shapes(slots, int(prefill_chunk),
-                                             int(window_rows))
-        else:
-            ragged |= {(1, W) for W in _pow2_buckets(int(prefill_chunk))}
+        ragged |= _packed_prefill_shapes(slots, int(prefill_chunk),
+                                         int(window_rows))
         if spec_max_nodes:
             T = int(spec_max_nodes)
-            if ragged_pack:
-                # verify packs only drafting + sampled-root slots —
-                # idle/mid-prefill slots pack nothing
-                ragged |= {(b, T) for b in range(1, slots + 1)}
-            else:
-                ragged |= {(slots, T)}
+            # verify packs only drafting + sampled-root slots —
+            # idle/mid-prefill slots pack nothing
+            ragged |= {(b, T) for b in range(1, slots + 1)}
         entry("ragged_step", ragged)
         if megastep_ticks > 1 and not megastep_mixed:
             entry("megastep", [(slots, int(megastep_ticks))])
@@ -491,7 +485,6 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
             "slots": slots, "max_len": max_len, "paged": bool(paged),
             "page_size": int(page_size) if paged else None,
             "prefill_chunk": int(prefill_chunk) if paged else None,
-            "ragged_pack": bool(ragged_pack),
             "megastep_ticks": int(megastep_ticks),
             "megastep_mixed": bool(megastep_mixed),
             "spec_max_nodes": int(spec_max_nodes) if spec_max_nodes else None,
@@ -507,14 +500,13 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
 
 
 def catalog_for_strategy(strategy, *, slots: int, max_len: int) -> Dict:
-    """enumerate_catalog for a search/servesearch.ServeStrategy — the
+    """enumerate_catalog for a serve_strategy.ServeStrategy — the
     `tools/servesearch.py explain` compile_cost line prices this."""
     sp = strategy.spec_config()
     kw = strategy.to_server_kwargs(slots=slots, max_len=max_len)
     return enumerate_catalog(
         slots=slots, max_len=max_len, paged=True,
         page_size=kw["page_size"], prefill_chunk=kw["prefill_chunk"],
-        ragged_pack=kw["ragged_pack"],
         megastep_ticks=kw["megastep_ticks"],
         megastep_mixed=kw.get("megastep_mixed", False),
         spec_max_nodes=sp.max_nodes if sp else None,
@@ -583,7 +575,7 @@ def check_soundness(catalog: Dict, events: Sequence[Dict]) -> List[Finding]:
 # enumerate instantly). Override via AnalysisContext.shapecheck_configs.
 DEFAULT_CONFIGS = {
     "paged_base": dict(slots=4, max_len=128, page_size=16,
-                       prefill_chunk=32, ragged_pack=True),
+                       prefill_chunk=32),
     "paged_megastep": dict(slots=4, max_len=128, page_size=16,
                            prefill_chunk=32, megastep_ticks=8),
     "paged_mixed": dict(slots=4, max_len=128, page_size=16,
@@ -591,8 +583,6 @@ DEFAULT_CONFIGS = {
                         megastep_mixed=True),
     "paged_spec": dict(slots=4, max_len=128, page_size=16,
                        prefill_chunk=32, spec_max_nodes=9, spec_depth=4),
-    "paged_legacy": dict(slots=4, max_len=128, page_size=16,
-                         prefill_chunk=32, ragged_pack=False),
     "dense": dict(slots=4, max_len=128, paged=False),
 }
 

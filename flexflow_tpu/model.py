@@ -1284,79 +1284,13 @@ class FFModel:
         return _serve(self, batch_sizes=batch_sizes, max_delay_ms=max_delay_ms,
                       warmup=warmup)
 
-    def serve_generation(self, slots: int = 4, max_len: int = 512,
-                         eos_id=None, seed: int = 0, paged: bool = False,
-                         page_size: int = 64, num_pages=None,
-                         preemption: bool = True, prefix_cache: bool = True,
-                         prefill_chunk: int = 64, speculate=None,
-                         ragged_pack: bool = True, megastep_ticks: int = 1,
-                         megastep_mixed: bool = False,
-                         overlap_dispatch: bool = False,
-                         kv_dtype: str = "auto",
-                         request_record_limit=None, serve_strategy=None,
-                         search_budget=None, traffic="smoke",
-                         reqlog_capacity=None, slo=None, slo_dump_dir=None,
-                         kv_quant_canary=None, defer_start: bool = False,
-                         host_tier=None):
-        """Continuous-batching autoregressive generation endpoint (KV-cache
-        decode with per-slot positions — flexflow_tpu.serving). With
-        `paged=True` the KV cache is a block-paged pool shared by all
-        requests (flexflow_tpu.paged): HBM scales with tokens in flight,
-        admission is by free-page budget, and page pressure preempts and
-        requeues the youngest request; `prefix_cache` shares
-        content-addressed prompt-prefix pages across requests and
-        `prefill_chunk` bounds the prompt tokens prefilled per decode
-        tick (chunked prefill — long prompts never stall in-flight
-        decodes). `speculate=SpecConfig(...)` (with paged=True) adds
-        speculative tree decoding (flexflow_tpu.spec): drafted token
-        trees verified in one step, greedy output token-identical, up to
-        depth+1 tokens emitted per step. `megastep_ticks=N` (paged, no
-        speculate) fuses up to N decode ticks into one jitted dispatch
-        with zero host syncs in the inner loop — token output stays
-        identical (docs/paged.md "Decode megasteps");
-        `megastep_mixed=True` makes the megastep UNIVERSAL — mid-prefill
-        chunks and on-device drafted spec chains fuse into the same
-        dispatch — and `overlap_dispatch=True` runs the next tick's
-        admission work in the shadow of the in-flight dispatch
-        (docs/paged.md "Universal megasteps").
-        `search_budget=N` auto-tunes the paged/spec/megastep knobs with
-        the serving-strategy search against the `traffic` profile before
-        serving; `serve_strategy` applies a previously searched
-        ServeStrategy (or its JSON dict) directly (docs/search.md,
-        "Serving strategy search"). `kv_dtype="int8"` (paged only)
-        stores KV pages quantized with per-page per-head scales —
-        ~4x more tokens per byte of pool HBM at a bounded logit
-        tolerance (docs/paged.md "Quantized KV pages").
-        `reqlog_capacity` sizes the always-on request-log flight
-        recorder (0 disables), `slo=SLOTarget(...)` arms the live SLO
-        monitor with breach dumps under `slo_dump_dir`, and
-        `kv_quant_canary=N` samples the fp32 quantization-error shadow
-        onto every Nth request (docs/observability.md).
-        `defer_start=True` builds the server without starting its loop —
-        the drain-and-swap handoff warms shapes, adopts the predecessor's
-        pool and absorbs its carried requests before calling .start()
-        (docs/serving.md, "Autopilot & drain-and-swap").
-        `host_tier=HostTier(...)` (or a page count, paged only) backs
-        the pool with a host-RAM KV spill tier: LRU evictions spill
-        instead of dropping and later lookups fetch pages back
-        (docs/disaggregation.md)."""
+    def serve_generation(self, **kw):
+        """Continuous-batching autoregressive generation endpoint over
+        this compiled model: `flexflow_tpu.serving.serve_generation(self,
+        **kw)`, whose signature and docstring are the option list."""
         from flexflow_tpu.serving import serve_generation as _sg
 
-        return _sg(self, slots=slots, max_len=max_len, eos_id=eos_id,
-                   seed=seed, paged=paged, page_size=page_size,
-                   num_pages=num_pages, preemption=preemption,
-                   prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
-                   speculate=speculate, ragged_pack=ragged_pack,
-                   megastep_ticks=megastep_ticks,
-                   megastep_mixed=megastep_mixed,
-                   overlap_dispatch=overlap_dispatch, kv_dtype=kv_dtype,
-                   request_record_limit=request_record_limit,
-                   serve_strategy=serve_strategy,
-                   search_budget=search_budget, traffic=traffic,
-                   reqlog_capacity=reqlog_capacity, slo=slo,
-                   slo_dump_dir=slo_dump_dir,
-                   kv_quant_canary=kv_quant_canary,
-                   defer_start=defer_start, host_tier=host_tier)
+        return _sg(self, **kw)
 
     def predict(self, x: Union[np.ndarray, Sequence[np.ndarray]],
                 batch_size: Optional[int] = None) -> np.ndarray:

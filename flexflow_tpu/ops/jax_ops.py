@@ -468,10 +468,10 @@ def _element_binary(attrs, inputs, params, ctx):
             # tree verify (sibling branches share a row of the table)
             b = b[pos[:, None] + ctx.ragged_depths]
         else:
-            # continuous batching: per-row positions. S=1 is a decode
-            # step; S>1 is a paged prefill CHUNK whose rows sit at
-            # pos..pos+S (Executor.chunked_prefill_fn — the gather clamps
-            # padded tail rows, which later writes overwrite anyway)
+            # continuous batching: per-row positions. S=1 is the dense
+            # server's decode step; S>1 rows sit at pos..pos+S (the paged
+            # step always passes ragged_depths and takes the branch
+            # above — the gather clamps rows past the table)
             rows = pos[:, None] + jnp.arange(a.shape[1])[None, :]
             b = b[rows]
     return [_BINARY[attrs.kind](a, b)]

@@ -19,7 +19,7 @@ cached LRU entries, so a preempted request's resume re-attaches its own
 K/V instead of recomputing it.
 
 CHUNKED PREFILL: the uncached suffix is computed `prefill_chunk` tokens
-per tick straight into pool pages (Executor.chunked_prefill_fn — no
+per tick straight into pool pages (Executor.ragged_step_fn — no
 dense staging cache), INSIDE the decode loop: each tick advances
 mid-prefill slots by one budgeted chunk and then runs the normal decode
 tick for everyone else, so a long prompt never stalls in-flight decodes
@@ -35,9 +35,7 @@ writes redirected to the null page. Splitting a chunk into window
 pieces is sound because every item's K/V rows scatter into the pool
 BEFORE attention runs at each layer, so piece i+1 sees piece i's rows
 as committed (kpos < pos) — the same mechanism that lets chunks span
-ticks. `ragged_pack=False` keeps the kernel but reverts to the
-pre-ragged packing (one full-bucket launch per prefilling slot) — the
-bench baseline the padding-waste metric is judged against.
+ticks.
 
 Decode flow per tick:
   1. admit queued requests into free slots while pages last (FIFO;
@@ -66,12 +64,8 @@ import numpy as np
 from flexflow_tpu import obs
 from flexflow_tpu.paged.pool import EMPTY_HASH, PagePool
 from flexflow_tpu.runtime.executor import LAUNCH_STATS
+from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
 from flexflow_tpu.serving import _GenerationServerBase, _GenRequest
-
-# Packed prefill windows are capped at this many rows — the fp32 sublane
-# tile. Exported so the tick pricer (search/servesearch.py) models the
-# same ceil-to-window padding the scheduler actually launches with.
-PREFILL_WINDOW_ROWS = 8
 
 
 class PagedGenerationServer(_GenerationServerBase):
@@ -87,8 +81,7 @@ class PagedGenerationServer(_GenerationServerBase):
                  page_size: int = 64, num_pages: Optional[int] = None,
                  preemption: bool = True, table_slack_tokens: int = 0,
                  prefix_cache: bool = True, prefill_chunk: int = 64,
-                 ragged_pack: bool = True, megastep_ticks: int = 1,
-                 megastep_mixed: bool = False,
+                 megastep_ticks: int = 1, megastep_mixed: bool = False,
                  overlap_dispatch: bool = False,
                  request_record_limit: Optional[int] = None,
                  kv_dtype: str = "auto",
@@ -122,11 +115,9 @@ class PagedGenerationServer(_GenerationServerBase):
         self.preemption = bool(preemption)
         self.prefix_cache = bool(prefix_cache)
         self.prefill_chunk = max(1, int(prefill_chunk))
-        self.ragged_pack = bool(ragged_pack)
         # packed prefill windows are capped at this many rows (the fp32
-        # sublane tile and the _bucket floor): chunks larger than it
-        # split into pieces, so launch shapes stay within a small
-        # (n_items, window<=8) family instead of per-chunk pow2 buckets
+        # sublane tile): chunks larger than it split into pieces, so
+        # launch shapes stay within a small (n_items, window<=8) family
         self._chunk_rows = PREFILL_WINDOW_ROWS
         ex = ff.executor
         if (jax.default_backend() == "tpu" and ex.mesh is not None
@@ -493,7 +484,6 @@ class PagedGenerationServer(_GenerationServerBase):
             "slots": self.slots, "max_len": self.max_len, "paged": True,
             "page_size": self.page_size,
             "prefill_chunk": self.prefill_chunk,
-            "ragged_pack": self.ragged_pack,
             "megastep_ticks": self.megastep_ticks,
             "megastep_mixed": self.megastep_mixed,
             # num_pages is fixed at pool construction; the loop thread
@@ -835,8 +825,6 @@ class PagedGenerationServer(_GenerationServerBase):
         hand-built servers too. Reads the knobs AFTER any debug-flag
         adjustment (megastep forcing under FF_TPU_KV_QUANT_DEBUG), so
         the fingerprint matches observable behaviour, not the args."""
-        from flexflow_tpu.search.servesearch import ServeStrategy
-
         spec = getattr(self, "spec", None)
         dense_pages = self.slots * self.max_pages_per_seq
         frac = (1.0 if self.pool.num_pages >= dense_pages + 1
@@ -852,7 +840,6 @@ class PagedGenerationServer(_GenerationServerBase):
             megastep_ticks=self.megastep_ticks,
             megastep_mixed=self.megastep_mixed,
             overlap_dispatch=self.overlap_dispatch,
-            ragged_pack=self.ragged_pack,
             pool_fraction=round(frac, 6),
             kv_dtype=self.kv_dtype,
         )
@@ -1491,12 +1478,10 @@ class PagedGenerationServer(_GenerationServerBase):
         own last-row logits — the same rng/_pick discipline as the
         dense server's admission prefill.
 
-        With ragged_pack every slot's chunk is split into window-sized
-        pieces and the whole tick rides ONE packed launch (piece i+1
-        sees piece i's rows as committed because K/V scatter precedes
-        attention at each layer); ragged_pack=False reverts to one
-        full-bucket launch per slot — the rotating-chunk baseline whose
-        padding the packed path is measured against."""
+        Every slot's chunk is split into window-sized pieces and the
+        whole tick rides ONE packed launch (piece i+1 sees piece i's
+        rows as committed because K/V scatter precedes attention at
+        each layer)."""
         budget = self.prefill_chunk
         self.prefill_ticks += 1
         rot = self._prefill_rr % len(slots)
@@ -1504,11 +1489,9 @@ class PagedGenerationServer(_GenerationServerBase):
         slots = slots[rot:] + slots[:rot]
         t0 = time.monotonic()
         sp = obs.span("prefill_tick").__enter__()
-        padded = total = 0
         # plan the tick's chunks first (budget in rotated order), then
-        # launch, then publish/sample per slot in the SAME rotated order
-        # the per-slot launches used — the rng split sequence of a
-        # finishing chunk is packing-invariant
+        # launch, then publish/sample per slot in the SAME rotated
+        # order, which fixes the rng split sequence of finishing chunks
         plan = []  # (slot, req, start, take)
         for s in slots:
             if budget <= 0:
@@ -1517,37 +1500,24 @@ class PagedGenerationServer(_GenerationServerBase):
             take = min(budget, req.prefill_target - req.prefill_pos)
             plan.append((s, req, req.prefill_pos, take))
             budget -= take
-        if self.ragged_pack:
-            items = []
-            ends = []  # index+row of each chunk's last piece in `items`
-            # window = the tick's largest chunk, capped at _chunk_rows:
-            # small chunks never pad past their own length (the legacy
-            # buckets floor at 8) and big chunks split into pieces
-            # instead of rounding up to the next power-of-two bucket
-            W = min(self._chunk_rows, max(take for _, _, _, take in plan))
-            for s, req, start, take in plan:
-                for off in range(0, take, W):
-                    piece = min(W, take - off)
-                    items.append((s, start + off,
-                                  req.prefill_seq[start + off:
-                                                  start + off + piece],
-                                  None, None))
-                ends.append((len(items) - 1, (take - 1) % W))
-            probs, padded, total = self._launch(items, W, tr, ntr)
-            rows = [(probs, i, r) for i, r in ends]
-        else:
-            rows = []
-            for s, req, start, take in plan:
-                bucket = self._bucket(take)
-                p, pad, tot = self._launch(
-                    [(s, start, req.prefill_seq[start:start + take],
-                      None, None)], bucket, tr, ntr)
-                rows.append((p, 0, take - 1))
-                padded += pad
-                total += tot
+        items = []
+        ends = []  # index+row of each chunk's last piece in `items`
+        # window = the tick's largest chunk, capped at _chunk_rows: small
+        # chunks never pad past their own length and big chunks split
+        # into pieces instead of rounding up to a power-of-two bucket
+        W = min(self._chunk_rows, max(take for _, _, _, take in plan))
+        for s, req, start, take in plan:
+            for off in range(0, take, W):
+                piece = min(W, take - off)
+                items.append((s, start + off,
+                              req.prefill_seq[start + off:
+                                              start + off + piece],
+                              None, None))
+            ends.append((len(items) - 1, (take - 1) % W))
+        probs, padded, total = self._launch(items, W, tr, ntr)
         with obs.span("commit") as csp:
             first = []  # seq of each request that got its first token
-            for (s, req, start, take), (p, i, r) in zip(plan, rows):
+            for (s, req, start, take), (i, r) in zip(plan, ends):
                 req.prefill_pos = start + take
                 req.prefill_tokens += take
                 self._publish_prefix(req, req.prefill_pos)
@@ -1563,7 +1533,8 @@ class PagedGenerationServer(_GenerationServerBase):
                     with obs.span("sample"):
                         # the last real row, (1, V): one warmed program
                         # a launch shape (serving.probs_row)
-                        row = self._probs_row(p, np.int32(i), np.int32(r))
+                        row = self._probs_row(probs, np.int32(i),
+                                              np.int32(r))
                     self._sample_first_token(s, req, row)
                     first.append(req.seq)
                     self._finish_if_done(s)

@@ -200,10 +200,8 @@ class Executor:
         self._eval_step = None
         self._forward = None
         self._decode_fn = None
-        self._paged_decode_fn = None
         self._ragged_step_fn = None
         self._megastep_fns: Dict[Any, Any] = {}
-        self._verify_fn = None
         self._paged_commit_fn = None
         # compile-event tracker (obs/compile_tracker.py): each decode-
         # path jit factory below hands its callable through wrap(), so
@@ -895,83 +893,6 @@ class Executor:
             lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype), where),
             specs)
 
-    def paged_decode_fn(self):
-        """jitted (params, pools, page_tables, pos, ids) ->
-        (probs, new_pools): one single-token decode step through the
-        PAGED cached-attention lowering. Compiled once for the
-        (slots, max_pages) table shape; admission/free/preemption only
-        ever change table CONTENTS, so the program never recompiles."""
-        if self._paged_decode_fn is not None:
-            return self._paged_decode_fn
-
-        def step(trainable, nontrainable, caches, page_tables, pos,
-                 *inputs):
-            cache_out = {}
-            out, _, _ = self.run_forward(
-                trainable, nontrainable, inputs, training=False,
-                rng=jax.random.key(0), kv_caches=caches,
-                cache_position=pos, cache_out=cache_out,
-                page_tables=page_tables,
-            )
-            return out, cache_out
-
-        self._paged_decode_fn = self.compile_tracker.wrap(
-            "paged_decode", jax.jit(step), lambda args: args[5].shape)
-        return self._paged_decode_fn
-
-    def chunked_prefill_fn(self):
-        """jitted (params, pools, page_table_row, pos, ids) ->
-        (probs, new_pools): one PREFILL CHUNK written straight into pool
-        pages (flexflow_tpu.paged chunked prefill — no dense staging
-        cache, no scatter afterwards). `ids` is (1, C) — C prompt tokens
-        of a single request starting at absolute position `pos` (a (1,)
-        vector) — and `page_table_row` the request's (1, max_pages)
-        table. Rows land at pos + i through the table; attention masks
-        kpos <= qpos, so each chunk sees the pages earlier chunks (or
-        prefix-cache hits) already populated. Compiled once per chunk
-        bucket; the table shape is fixed, so admission order never
-        recompiles it. Chunks with C=1 are exactly one decode step —
-        it IS the paged decode callable (one traced program per input
-        shape; the paged lowering handles S=1 and S>1 alike), named
-        separately only for the call-site contract above."""
-        return self.paged_decode_fn()
-
-    def verify_fn(self):
-        """jitted (params, pools, page_tables, pos, depths, tree_mask,
-        ids) -> (probs, new_pools): one speculative TREE-VERIFY step
-        (flexflow_tpu.spec). `ids` is (slots, max_nodes) — every slot's
-        flattened draft tree, node 0 the last sampled token — `depths`
-        the (slots, max_nodes) node depths and `tree_mask` the
-        (slots, max_nodes, max_nodes) ancestor relation. Node j's K/V row
-        is written at cache row pos + j; probs[:, j] is the model's
-        next-token distribution after the path root..j, so acceptance is
-        a host-side argmax walk. Compiled once for the (slots, max_nodes)
-        shape — tree CONTENTS (tokens/parents) change per step, the
-        program never recompiles."""
-        if self._verify_fn is not None:
-            return self._verify_fn
-
-        def step(trainable, nontrainable, caches, page_tables, pos,
-                 depths, tree_mask, *inputs):
-            cache_out = {}
-            # all max_nodes window rows live: padding nodes are made
-            # invisible by the anc relation itself (a pad node sees only
-            # itself and nothing sees it), the pre-ragged contract
-            q_lens = jnp.full((inputs[0].shape[0],), inputs[0].shape[1],
-                              jnp.int32)
-            out, _, _ = self.run_forward(
-                trainable, nontrainable, inputs, training=False,
-                rng=jax.random.key(0), kv_caches=caches,
-                cache_position=pos, cache_out=cache_out,
-                page_tables=page_tables,
-                ragged=(q_lens, depths, tree_mask),
-            )
-            return out, cache_out
-
-        self._verify_fn = self.compile_tracker.wrap(
-            "verify", jax.jit(step), lambda args: args[7].shape)
-        return self._verify_fn
-
     def ragged_step_fn(self):
         """jitted (params, pools, page_tables, pos, q_lens, depths, anc,
         ids) -> (probs, new_pools): ONE ragged paged step over a packed
@@ -1419,8 +1340,7 @@ class Executor:
         ff_jit_cache_entries gauge): the single-slot factories plus the
         LRU-bounded per-(max_ticks, eos_id) megastep memos."""
         singles = (self._train_step, self._eval_step, self._forward,
-                   self._decode_fn, self._paged_decode_fn,
-                   self._ragged_step_fn, self._verify_fn,
+                   self._decode_fn, self._ragged_step_fn,
                    self._paged_commit_fn)
         return (sum(1 for f in singles if f is not None)
                 + len(self._megastep_fns))
@@ -1715,7 +1635,11 @@ class Executor:
         analysis.hloaudit diffs the search cost model against).
 
         `entries` defaults to train_step + eval_step, plus
-        paged_decode_fn + verify_fn when can_paged_decode(). The paged
+        "paged_decode" and "verify" when can_paged_decode(). Those two
+        are two SHAPES of the one paged step program, ragged_step_fn(),
+        lowered as a server launches them (pools donated): the
+        (slots, 1) decode launch, and the (slots, max_nodes) launch a
+        speculative server verifies drafted trees with. The paged
         shapes (slots / page_size / pool size / tree width) only scale
         the audit's byte counts, not which collectives appear.
         `kv_dtype` lowers the paged entries against a quantized pool
@@ -1754,19 +1678,20 @@ class Executor:
             caches = self.paged_kv_cache_specs(
                 pages, page_size, dtype=resolve_kv_dtype(kv_dtype))
             tables = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32)
-            pos = jax.ShapeDtypeStruct((slots,), jnp.int32)
-            if "paged_decode" in entries:
-                ids = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
-                out["paged_decode"] = self.paged_decode_fn().lower(
-                    tr, ntr, caches, tables, pos, ids)
-            if "verify" in entries:
-                depths = jax.ShapeDtypeStruct((slots, max_nodes),
-                                              jnp.int32)
-                mask = jax.ShapeDtypeStruct(
-                    (slots, max_nodes, max_nodes), jnp.bool_)
-                ids = jax.ShapeDtypeStruct((slots, max_nodes), jnp.int32)
-                out["verify"] = self.verify_fn().lower(
-                    tr, ntr, caches, tables, pos, depths, mask, ids)
+            per_slot = jax.ShapeDtypeStruct((slots,), jnp.int32)
+            for entry, window in (("paged_decode", 1),
+                                  ("verify", max_nodes)):
+                if entry not in entries:
+                    continue
+                rows = jax.ShapeDtypeStruct((slots, window), jnp.int32)
+                anc = jax.ShapeDtypeStruct((slots, window, window),
+                                           jnp.bool_)
+                # (tables, pos, q_lens, depths, anc, ids): q_lens is all
+                # 1 for a decode launch and a tree's node count for a
+                # verify, which only the values say
+                out[entry] = self.ragged_step_fn().lower(
+                    tr, ntr, caches, tables, per_slot, per_slot, rows,
+                    anc, rows)
         return out
 
     def dtype_plan(self, entries: Optional[Sequence[str]] = None, *,
@@ -1781,7 +1706,8 @@ class Executor:
         weights and that is what every entry is lowered against),
         "accum" (contraction accumulation dtype; always f32 — narrower
         is hlo-accum-downgrade), "kv" (the paged pool payload dtype for
-        the paged entries; s8 carries the scale sidecar), "allowed"
+        the paged entries, lowered_modules' two shapes of
+        ragged_step_fn; s8 carries the scale sidecar), "allowed"
         (every float/payload dtype the entry may legitimately touch —
         converts outside this set are hlo-unplanned-convert), and
         "allow_f64": False everywhere (f64 anywhere is a silent

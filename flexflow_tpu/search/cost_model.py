@@ -910,9 +910,8 @@ class TickPricer:
     def prefill_tick(self, chunk_tokens: int, padded_rows: float = 0.0,
                      batch: int = 1) -> float:
         """Seconds for one chunked-prefill launch: `chunk_tokens` live
-        rows plus the ceil-to-window padding the packed scheduler
-        launches with (paged.scheduler.PREFILL_WINDOW_ROWS pieces, or
-        the legacy pow2 bucket when ragged_pack=False)."""
+        rows plus the ceil-to-window padding the scheduler launches
+        with (pieces of serve_strategy.PREFILL_WINDOW_ROWS rows)."""
         rows = max(int(chunk_tokens), 1) + max(padded_rows, 0.0) * self.pad_row_cost
         comp = (self.token_seconds * rows
                 * self._scale("prefill", batch, chunk=int(chunk_tokens)))

@@ -4,11 +4,13 @@ decode tick.
 The repo's thesis (PAPER.md) is that an MCMC search over a simulator
 beats hand-rolled parallelism choices — but until now every serving knob
 (`page_size`, `prefill_chunk`, spec tree width/depth, `megastep_ticks`,
-`ragged_pack`, pool size, mesh layout) was hand-picked. This module
+pool size, mesh layout) was hand-picked. This module
 closes that gap:
 
-  1. a `ServeStrategy` names one point in the serving knob space and
-     knows how to configure `serve_generation` (`to_server_kwargs`);
+  1. a `ServeStrategy` (flexflow_tpu/serve_strategy.py, the serving
+     side's own leaf; re-exported here) names one point in the serving
+     knob space and knows how to configure `serve_generation`
+     (`to_server_kwargs`);
   2. `ServePricer` prices one strategy's *decode tick* against a named
      traffic profile (search/traffic.py): ragged launch shapes and
      padding waste per the PR 10 packing, chunked-prefill TTFT, the
@@ -38,7 +40,6 @@ strategy search" is the narrative.
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import json
 import logging
 import math
@@ -54,6 +55,7 @@ from flexflow_tpu.search.cost_model import (
     kv_cache_token_bytes,
 )
 from flexflow_tpu.search.table import StrategyTable, coordinate_descent
+from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
 from flexflow_tpu.spec.config import SpecConfig
 
 logger = logging.getLogger(__name__)
@@ -73,159 +75,8 @@ INVALID_OBJECTIVE = 1e9
 DEFAULT_ACCEPTANCE_RATE = 0.6
 
 
-def _prefill_window_rows() -> int:
-    # lazy: keeps `search/` importable without the serving stack
-    from flexflow_tpu.paged.scheduler import PREFILL_WINDOW_ROWS
-
-    return PREFILL_WINDOW_ROWS
-
-
 # ---------------------------------------------------------------------------
-# Strategy + objective
-
-
-@dataclasses.dataclass(frozen=True)
-class ServeStrategy:
-    """One point in the serving knob space — everything
-    `serve_generation(paged=True)` lets a caller choose, in one
-    JSON-serializable value the search walks and the server loads.
-
-    spec_width/spec_depth 0 = speculation off; `mesh` is the serving
-    mesh layout as sorted (axis, size) pairs, () = the compiled mesh.
-    pool_fraction scales the page pool against the dense capacity
-    (slots x pages-per-seq) — the HBM knob; 1.0 keeps the server
-    default. kv_dtype picks the pool's storage dtype
-    (paged.quant.KV_DTYPES; "auto" = the model's own dtype, "int8" =
-    quantized pages with the per-page scale sidecar) — the OTHER HBM
-    knob, trading bytes per cached token against a bounded logit
-    error instead of trading pages away. host_tier_pages sizes the
-    host-RAM KV spill tier (disagg.HostTier) in pages; 0 = no tier
-    (LRU evictions drop pages, prefix misses recompute). A tier lets
-    the pool trade a PCIe fetch for a prefill recompute — whether
-    that wins depends on traffic, which is exactly what the search
-    decides."""
-
-    page_size: int = 64
-    prefill_chunk: int = 64
-    spec_width: int = 0
-    spec_depth: int = 0
-    megastep_ticks: int = 1
-    megastep_mixed: bool = False
-    overlap_dispatch: bool = False
-    ragged_pack: bool = True
-    pool_fraction: float = 1.0
-    kv_dtype: str = "auto"
-    host_tier_pages: int = 0
-    mesh: Tuple[Tuple[str, int], ...] = ()
-
-    def validate(self, max_len: Optional[int] = None) -> None:
-        """Raise ValueError on combinations serve_generation rejects —
-        the SAME constraints, so a searched strategy is a servable one."""
-        if self.page_size < 1:
-            raise ValueError(f"page_size must be >= 1, got {self.page_size}")
-        if self.prefill_chunk < 1:
-            raise ValueError(
-                f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-        if self.megastep_ticks < 1:
-            raise ValueError(
-                f"megastep_ticks must be >= 1, got {self.megastep_ticks}")
-        if not (0.0 < self.pool_fraction <= 1.0):
-            raise ValueError(
-                f"pool_fraction must be in (0, 1], got {self.pool_fraction}")
-        if self.host_tier_pages < 0:
-            raise ValueError(
-                f"host_tier_pages must be >= 0, got {self.host_tier_pages}")
-        if (self.spec_width >= 1) != (self.spec_depth >= 1):
-            raise ValueError(
-                f"spec_width/spec_depth must both be 0 or both >= 1, got "
-                f"{self.spec_width}x{self.spec_depth}")
-        if self.overlap_dispatch and not self.megastep_mixed:
-            raise ValueError(
-                "overlap_dispatch overlaps host work with the in-flight "
-                "MIXED megastep dispatch; it requires megastep_mixed")
-        if (self.spec_width >= 1 and self.megastep_ticks > 1
-                and not self.megastep_mixed):
-            raise ValueError(
-                "speculative decoding and megastep_ticks > 1 are mutually "
-                "exclusive (the fused decode loop cannot host verify "
-                "ticks) — unless megastep_mixed fuses verify on device")
-        # typo'd dtypes fail HERE, not as a silently-fp32 served pool
-        from flexflow_tpu.paged.quant import kv_dtype_info
-
-        kv_dtype_info(self.kv_dtype)
-        if max_len is not None and self.page_size > max_len:
-            raise ValueError(
-                f"page_size {self.page_size} exceeds max_len {max_len}")
-
-    def spec_config(self) -> Optional[SpecConfig]:
-        if self.spec_width < 1:
-            return None
-        return SpecConfig(width=self.spec_width, depth=self.spec_depth)
-
-    def to_server_kwargs(self, slots: int, max_len: int) -> Dict:
-        """The serve_generation(...) kwargs this strategy stands for.
-        num_pages stays None (the server's dense-capacity default) at
-        pool_fraction 1.0; smaller fractions shrink the pool but never
-        below one sequence's worth — the pool must admit SOMETHING."""
-        self.validate(max_len=max_len)
-        pages_per_seq = -(-int(max_len) // self.page_size)
-        num_pages = None
-        if self.pool_fraction < 1.0:
-            num_pages = max(
-                int(math.ceil(self.pool_fraction * slots * pages_per_seq)) + 1,
-                pages_per_seq + 1)
-        return {
-            "paged": True,
-            "page_size": self.page_size,
-            "prefill_chunk": self.prefill_chunk,
-            "ragged_pack": self.ragged_pack,
-            "megastep_ticks": self.megastep_ticks,
-            "megastep_mixed": self.megastep_mixed,
-            "overlap_dispatch": self.overlap_dispatch,
-            "num_pages": num_pages,
-            "speculate": self.spec_config(),
-            "kv_dtype": self.kv_dtype,
-            "host_tier": self.host_tier_pages or None,
-        }
-
-    def describe(self) -> str:
-        spec = (f"spec {self.spec_width}x{self.spec_depth}"
-                if self.spec_width else "spec off")
-        mesh = ",".join(f"{a}={s}" for a, s in self.mesh) or "compiled mesh"
-        tier = (f"tier {self.host_tier_pages}p"
-                if self.host_tier_pages else "tier off")
-        mega = f"megastep {self.megastep_ticks}"
-        if self.megastep_mixed:
-            mega += " mixed"
-        if self.overlap_dispatch:
-            mega += "+overlap"
-        return (f"page {self.page_size} + chunk {self.prefill_chunk} + "
-                f"{mega} + {spec} + "
-                f"{'packed' if self.ragged_pack else 'legacy'} + "
-                f"pool {self.pool_fraction:g} + kv {self.kv_dtype} + "
-                f"{tier} + {mesh}")
-
-    def to_json(self) -> Dict:
-        d = dataclasses.asdict(self)
-        d["mesh"] = [[a, s] for a, s in self.mesh]
-        return d
-
-    @classmethod
-    def from_json(cls, d: Dict) -> "ServeStrategy":
-        kw = dict(d)
-        kw["mesh"] = tuple((str(a), int(s)) for a, s in kw.get("mesh", ()))
-        return cls(**kw)
-
-    def fingerprint(self) -> str:
-        """Stable short content hash over the canonical JSON form — the
-        strategy's identity across processes. Stamped into every reqlog
-        record and the /v2 metrics payload so post-swap records
-        attribute to the strategy that actually served them, and equal
-        for any two strategies with equal knobs regardless of how they
-        were constructed."""
-        doc = json.dumps(self.to_json(), sort_keys=True,
-                         separators=(",", ":"))
-        return hashlib.sha1(doc.encode("utf-8")).hexdigest()[:12]
+# Objective
 
 
 @dataclasses.dataclass(frozen=True)
@@ -416,7 +267,7 @@ class ServePricer:
 
     @staticmethod
     def _bucket(n: float) -> int:
-        """The scheduler's legacy pow2 launch bucket (floor 8)."""
+        """A pow2 launch bucket (floor 8)."""
         n = max(int(math.ceil(n)), 1)
         return max(8, 1 << (n - 1).bit_length())
 
@@ -454,20 +305,14 @@ class ServePricer:
         occupancy = min(1.0, live * resident / pool_tokens)
 
         # -- decode launch shape: packed rows vs padding waste ----------
-        if s.ragged_pack:
-            launch_rows = self._bucket(live)
-        else:
-            launch_rows = max(slots, self._bucket(live))
+        launch_rows = self._bucket(live)
         padded = max(launch_rows - live, 0.0)
 
         # -- chunked prefill padding (both dispatch models below) -------
         uncached_mean = (1.0 - share) * mean_p
         uncached_p95 = (1.0 - share) * p95_p
-        if s.ragged_pack:
-            w = min(_prefill_window_rows(), chunk)
-            pad_pre = -(-chunk // w) * w - chunk
-        else:
-            pad_pre = self._bucket(chunk) - chunk
+        w = min(PREFILL_WINDOW_ROWS, chunk)
+        pad_pre = -(-chunk // w) * w - chunk
 
         # -- decode dispatch: megastep fusion or spec verify ------------
         spec = s.spec_config()
@@ -608,7 +453,6 @@ def default_space(*, max_len: int) -> Dict[str, List]:
         "spec": [(0, 0), (2, 2), (2, 4), (4, 4)],
         "megastep_ticks": [1, 2, 4, 8, 16],
         "fuse": [(False, False), (True, False), (True, True)],
-        "ragged_pack": [True, False],
         "pool_fraction": [1.0, 0.75, 0.5, 0.25],
         "kv_dtype": ["auto", "int8"],
         "host_tier_pages": [0, 256, 1024],
@@ -899,7 +743,6 @@ def search_serve_strategy(
         "spec": (default.spec_width, default.spec_depth),
         "megastep_ticks": default.megastep_ticks,
         "fuse": (default.megastep_mixed, default.overlap_dispatch),
-        "ragged_pack": default.ragged_pack,
         "pool_fraction": default.pool_fraction,
         "kv_dtype": default.kv_dtype,
         "host_tier_pages": default.host_tier_pages,
@@ -910,8 +753,7 @@ def search_serve_strategy(
             vals.insert(0, dval)
     knobs = [(name, values[name]) for name in
              ("page_size", "prefill_chunk", "spec", "megastep_ticks",
-              "fuse", "ragged_pack", "pool_fraction", "kv_dtype",
-              "host_tier_pages")]
+              "fuse", "pool_fraction", "kv_dtype", "host_tier_pages")]
     if len(priced) > 1:
         knobs.append(("mesh", [lay.mesh_key for lay in priced]))
     table = _knob_table(knobs)

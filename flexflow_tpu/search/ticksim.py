@@ -62,6 +62,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS
+
 logger = logging.getLogger(__name__)
 
 # Backstop against a stuck simulation (a bug, never a workload): each
@@ -86,18 +88,6 @@ def _percentile(values: Sequence[float], q: float) -> float:
     ordered = sorted(values)
     rank = min(max(1, math.ceil(q * len(ordered))), len(ordered))
     return float(ordered[rank - 1])
-
-
-def _prefill_window_rows() -> int:
-    from flexflow_tpu.paged.scheduler import PREFILL_WINDOW_ROWS
-
-    return PREFILL_WINDOW_ROWS
-
-
-def _bucket(n: int) -> int:
-    """The scheduler's legacy pow2 launch bucket (floor 8)."""
-    n = max(int(n), 1)
-    return max(8, 1 << (n - 1).bit_length())
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +296,6 @@ class _SimRun:
         kw = strategy.to_server_kwargs(slots=slots, max_len=max_len)
         self.page = int(kw["page_size"])
         self.chunk = int(kw["prefill_chunk"])
-        self.ragged_pack = bool(kw["ragged_pack"])
         self.megastep = int(kw["megastep_ticks"])
         self.mixed = bool(kw.get("megastep_mixed"))
         self.overlap = bool(kw.get("overlap_dispatch"))
@@ -319,7 +308,7 @@ class _SimRun:
         self.tick = tick
         self.acceptance = float(acceptance_rate)
         self.rs = np.random.RandomState(seed)
-        self.window = min(_prefill_window_rows(), self.chunk)
+        self.window = min(PREFILL_WINDOW_ROWS, self.chunk)
 
         self.t = 0.0
         self.ticks = 0
@@ -523,18 +512,12 @@ class _SimRun:
                 budget -= take
         if not plan:
             return 0.0
-        cost = 0.0
-        if self.ragged_pack:
-            w = min(self.window, max(take for _, take in plan))
-            pieces = sum(-(-take // w) for _, take in plan)
-            total = sum(take for _, take in plan)
-            cost += self.tick.prefill_tick(total,
-                                           padded_rows=pieces * w - total,
-                                           batch=pieces)
-        else:
-            for _, take in plan:
-                padded = _bucket(take) - take
-                cost += self.tick.prefill_tick(take, padded_rows=padded)
+        w = min(self.window, max(take for _, take in plan))
+        pieces = sum(-(-take // w) for _, take in plan)
+        total = sum(take for _, take in plan)
+        cost = self.tick.prefill_tick(total,
+                                      padded_rows=pieces * w - total,
+                                      batch=pieces)
         for s, take in plan:
             req = self.active[s]
             req.prefill_pos += take

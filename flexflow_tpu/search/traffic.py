@@ -5,10 +5,9 @@ A serving strategy is only better or worse *for a workload*: chunked
 prefill pays off on long prompts, the prefix cache on shared system
 prompts, megasteps on decode-heavy streams. This module gives those
 workloads names, so the serving-strategy search (search/servesearch.py)
-and the decode bench (`bench.py --decode`) score strategies against the
-SAME fixtures — the bench's shared-system-prompt and mixed-length
-fixtures live here as `shared-system-prompt` and `mixed-length` instead
-of inline ad-hoc draws.
+and the tests score strategies against the SAME fixtures:
+`shared-system-prompt` and `mixed-length` instead of inline ad-hoc
+draws.
 
 Each profile is both ANALYTIC and SAMPLEABLE: `prompt_stats()` feeds
 the search's closed-form tick pricing (mean/p95 prompt length, steady-

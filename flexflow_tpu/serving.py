@@ -567,6 +567,7 @@ class _GenerationServerBase:
         # thread — the drain-and-swap path warms launch shapes and
         # absorbs carried requests first, then calls start()
         self._defer_start = bool(defer_start)
+        self._gc_frozen = False   # warm_launch_shapes() sets it
         # set while detach_for_swap() pauses the loop: the finally-drain
         # must NOT cancel futures that are about to be carried over
         self._detaching = False
@@ -736,6 +737,11 @@ class _GenerationServerBase:
         with self._lock:
             self._running = False
             self._stop.set()
+        if self._gc_frozen:
+            import gc
+
+            self._gc_frozen = False
+            gc.unfreeze()   # warm_launch_shapes froze set-up's objects
         if self._thread is None:  # built deferred, never started
             self._drain()
             return
@@ -899,6 +905,17 @@ class _GenerationServerBase:
                                jax.random.split(rng_ref)[1])
         if mark_steady:
             self._compile_tracker.mark_steady_state()
+        # what set-up made (jax's own objects, 97 traced programs: millions
+        # of containers) lives as long as the server. Left where the cyclic
+        # collector walks it, one generation-2 collection stops the loop
+        # for 0.85 s (measured on the chip, PERF.md section 6, PR 29), a
+        # couple of times a minute. Collect now, then move what is left
+        # out of the collector's reach; stop() gives it back
+        import gc
+
+        gc.collect()
+        gc.freeze()
+        self._gc_frozen = True
         return catalog
 
     # -- shared scheduler pieces -----------------------------------------
@@ -1345,7 +1362,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
                      prefix_cache: bool = True,
                      prefill_chunk: int = 64,
                      speculate=None,
-                     ragged_pack: bool = True,
                      megastep_ticks: int = 1,
                      megastep_mixed: bool = False,
                      overlap_dispatch: bool = False,
@@ -1389,13 +1405,10 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     token-identical to the non-speculative paged path while emitting up
     to depth+1 tokens per step.
 
-    `ragged_pack` (paged only, default True) packs each tick's mixed
-    work — decode rows, chunk pieces, drafted trees — into ragged
-    launches of the one paged-attention step, skipping idle slots and
-    padding (docs/paged.md). `ragged_pack=False` keeps the kernel but
-    reverts to the pre-ragged per-slot, widest-variant packing: the A/B
-    baseline for the `padding_waste_ratio` metric. Token output is
-    identical either way.
+    A paged tick packs its mixed work — decode rows, chunk pieces,
+    drafted trees — into ragged launches of the one paged-attention
+    step, skipping idle slots and padding (docs/paged.md "Ragged work
+    packing"; the `padding_waste_ratio` metric counts what is left).
 
     `megastep_ticks=N` (paged only, N > 1) runs up to N decode ticks
     per dispatch inside ONE jitted `jax.lax.while_loop` — positions,
@@ -1440,7 +1453,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     search/traffic.py or a TrafficProfile) and serves the winning
     strategy; `serve_strategy` applies a known ServeStrategy (or its
     to_json() dict, e.g. from `tools/servesearch.py search`) directly.
-    Either overrides the paged/page_size/prefill_chunk/ragged_pack/
+    Either overrides the paged/page_size/prefill_chunk/
     megastep_ticks/num_pages/speculate knobs wholesale — passing an
     explicit `speculate` alongside is an error, the strategy already
     decides speculation.
@@ -1467,6 +1480,11 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     FF_TPU_KV_QUANT_DEBUG mode (docs/paged.md). 0/None disables; env
     FF_TPU_KV_QUANT_CANARY supplies a default.
 
+    `defer_start=True` builds the server without starting its loop —
+    the drain-and-swap handoff warms shapes, adopts the predecessor's
+    pool and absorbs its carried requests before calling .start()
+    (docs/serving.md, "Autopilot & drain-and-swap").
+
     `host_tier` (paged only) attaches a host-memory KV tier
     (flexflow_tpu.disagg, docs/disaggregation.md): pass a page capacity
     (int) or a `HostTier` instance — SHARING one instance between two
@@ -1487,7 +1505,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             ff, traffic=traffic, budget=int(search_budget), slots=slots,
             max_len=max_len).best
     if serve_strategy is not None:
-        from flexflow_tpu.search.servesearch import ServeStrategy
+        from flexflow_tpu.serve_strategy import ServeStrategy
 
         if isinstance(serve_strategy, dict):
             serve_strategy = ServeStrategy.from_json(serve_strategy)
@@ -1499,7 +1517,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
         paged = True
         page_size = kw["page_size"]
         prefill_chunk = kw["prefill_chunk"]
-        ragged_pack = kw["ragged_pack"]
         megastep_ticks = kw["megastep_ticks"]
         megastep_mixed = kw.get("megastep_mixed", False)
         overlap_dispatch = kw.get("overlap_dispatch", False)
@@ -1541,8 +1558,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             ff, speculate, slots=slots, max_len=max_len, eos_id=eos_id,
             seed=seed, page_size=page_size, num_pages=num_pages,
             preemption=preemption, prefix_cache=prefix_cache,
-            prefill_chunk=prefill_chunk, ragged_pack=ragged_pack,
-            megastep_ticks=megastep_ticks,
+            prefill_chunk=prefill_chunk, megastep_ticks=megastep_ticks,
             megastep_mixed=megastep_mixed,
             overlap_dispatch=overlap_dispatch,
             request_record_limit=request_record_limit,
@@ -1558,8 +1574,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             ff, slots=slots, max_len=max_len, eos_id=eos_id, seed=seed,
             page_size=page_size, num_pages=num_pages, preemption=preemption,
             prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
-            ragged_pack=ragged_pack, megastep_ticks=megastep_ticks,
-            megastep_mixed=megastep_mixed,
+            megastep_ticks=megastep_ticks, megastep_mixed=megastep_mixed,
             overlap_dispatch=overlap_dispatch,
             request_record_limit=request_record_limit,
             kv_dtype=kv_dtype, reqlog_capacity=reqlog_capacity,

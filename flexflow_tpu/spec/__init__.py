@@ -14,7 +14,7 @@ search-over-structure spirit of the source paper applied to inference:
 
 The tree-verify attention itself (Pallas kernel + gather fallback) lives
 in flexflow_tpu.paged.attention next to the decode kernel it extends;
-the jitted step functions are Executor.verify_fn / paged_commit_fn.
+the jitted step functions are Executor.ragged_step_fn / paged_commit_fn.
 See docs/speculative.md.
 """
 

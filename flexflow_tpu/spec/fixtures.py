@@ -1,4 +1,4 @@
-"""Shared speculation fixtures (tests + bench.py --decode).
+"""Shared speculation fixtures for the tests.
 
 Acceptance-quality numbers need a model whose greedy stream is
 PREDICTABLE; an untrained model's argmax walk is arbitrary, so drafts
@@ -15,8 +15,7 @@ def make_token_cyclic(ff) -> None:
     stream is just the token embedding. Greedy decode then settles into
     a cycle within at most vocab steps — a repetitive stream the n-gram
     drafter predicts perfectly once it has repeated once. Used by the
-    >=1.5-accepted-tokens-per-step assertion (tests/test_spec.py) and
-    the bench.py --decode speculation entry."""
+    >=1.5-accepted-tokens-per-step assertion (tests/test_spec.py)."""
     import jax.numpy as jnp
 
     tr, _ = ff._params

@@ -3,7 +3,7 @@
 Each decode tick becomes a TREE-VERIFY step: a host-side drafter proposes
 a token tree per live slot (flexflow_tpu.spec.drafter), one jitted
 forward scores every node under the tree-attention mask
-(Executor.verify_fn), and a greedy host-side walk accepts the longest
+(Executor.ragged_step_fn), and a greedy host-side walk accepts the longest
 verified path. Rollback is nearly free on the paged cache: the accepted
 path's K/V rows are copied onto the contiguous committed positions
 (Executor.paged_commit_fn — one fixed-shape gather/scatter), `pos`
@@ -16,10 +16,8 @@ Tick flow (vs the base scheduler's one-token step):
   2. grow pages to cover pos + max_nodes rows (tree scratch included)
   3. draft: trailing-context trees for the live GREEDY slots
   4. ONE ragged verify launch: tree items for greedy slots (q_len =
-     real node count), single-row items for temperature>0 slots, and —
-     unlike the pre-ragged fixed layout — NO rows at all for idle or
-     mid-prefill slots (ragged_pack=False keeps the old every-slot
-     width as q_len-0 filler items, the bench's padding baseline)
+     real node count), single-row items for temperature>0 slots, and
+     NO rows at all for idle or mid-prefill slots
   5. accept: greedy argmax walk per slot; temperature>0 slots take only
      the root's sample (exactness under sampling needs rejection
      sampling — not implemented), so they decode at 1 token/step
@@ -54,7 +52,6 @@ class SpeculativePagedServer(PagedGenerationServer):
                  seed: int = 0, page_size: int = 64,
                  num_pages: Optional[int] = None, preemption: bool = True,
                  prefix_cache: bool = True, prefill_chunk: int = 64,
-                 ragged_pack: bool = True,
                  megastep_ticks: int = 1,
                  megastep_mixed: bool = False,
                  overlap_dispatch: bool = False,
@@ -86,7 +83,6 @@ class SpeculativePagedServer(PagedGenerationServer):
                          table_slack_tokens=spec.max_nodes,
                          prefix_cache=prefix_cache,
                          prefill_chunk=prefill_chunk,
-                         ragged_pack=ragged_pack,
                          megastep_ticks=megastep_ticks,
                          megastep_mixed=megastep_mixed,
                          overlap_dispatch=overlap_dispatch,
@@ -222,25 +218,18 @@ class SpeculativePagedServer(PagedGenerationServer):
         # single-row decode items instead of max_nodes-wide trees
         # (drafts would be paid for and thrown away, and would
         # dilute the acceptance metrics). Idle and mid-prefill slots
-        # pack NOTHING under ragged_pack (the pre-ragged layout
-        # carried a full tree of null-page scratch for every slot;
-        # ragged_pack=False keeps that for the bench baseline, as
-        # q_len-0 items).
+        # pack NOTHING.
         t0 = time.monotonic()
         tick_drafted = 0
         sp = obs.span("draft").__enter__()
-        order = live if self.ragged_pack else list(range(self.slots))
         slots_of = []   # item index -> slot
         trees = {}
         tree_rows = []  # item indexes carrying a real tree
         parents = []
-        for s in order:
+        for s in live:
             req = self._active[s]
-            if req is None:
-                slots_of.append(s)      # legacy filler: q_len 0
-                continue
-            if s not in live or req.temperature > 0.0:
-                slots_of.append(s)      # 1-row (or filler) item
+            if req.temperature > 0.0:
+                slots_of.append(s)      # 1-row item
                 continue
             chains = self.drafter.draft(req.seq_tokens(),
                                         self.spec.width,
@@ -264,14 +253,13 @@ class SpeculativePagedServer(PagedGenerationServer):
                         for s in range(self.slots)], np.int32)
 
         # items: a tree (q_len = its real node count — padding nodes
-        # are skipped work whose writes land in the null page), one
-        # committed-token row for a sampled slot, or a q_len-0
-        # filler. Mid-prefill slots pack no item, so their partially
-        # filled pages are never a write target — the table-nulling
-        # trick is gone
+        # are skipped work whose writes land in the null page) or one
+        # committed-token row for a sampled slot. Mid-prefill slots
+        # pack no item, so their partially filled pages are never a
+        # write target
         items = []
         ti = iter(range(len(tree_rows)))
-        for i, s in enumerate(slots_of):
+        for s in slots_of:
             req = self._active[s]
             if s in trees:
                 k = next(ti)
@@ -279,11 +267,9 @@ class SpeculativePagedServer(PagedGenerationServer):
                 items.append((s, req.pos,
                               tree.tokens[:tree.n_nodes],
                               tree.depths, anc[k]))
-            elif req is not None and s in live:
+            else:
                 items.append((s, req.pos, [req.tokens[-1]],
                               None, None))
-            else:
-                items.append((s, 0, [], None, None))
         sp = obs.span("verify").__enter__()
         if sp:
             sp.set(live=len(live), width=T,

@@ -911,6 +911,29 @@ def test_lowered_modules_entry_points(audited_llama):
     assert "paged_decode" in str(ei.value)
 
 
+def test_lowered_paged_entries_are_the_ragged_step(audited_llama):
+    """The two paged audit entries are two SHAPES of the one paged step
+    program a server launches, ragged_step_fn(): lowered with the pools
+    donated (every pool leaf carries an alias or donor mark in the
+    module text), and Executor has no other paged step to lower."""
+    executor = audited_llama[0]
+    for dead in ("paged_decode_fn", "chunked_prefill_fn", "verify_fn"):
+        assert not hasattr(executor, dead), dead
+    lows = executor.lowered_modules(["paged_decode", "verify"],
+                                    slots=2, max_nodes=4)
+    assert set(lows) == {"paged_decode", "verify"}
+    pools = executor.paged_kv_cache_specs(3, 16)
+    leaves = sum(len(v) for v in pools.values())
+    for name, window in (("paged_decode", 1), ("verify", 4)):
+        text = lows[name].as_text()
+        donated = (text.count("tf.aliasing_output")
+                   + text.count("jax.buffer_donor"))
+        assert donated == leaves, (name, donated, leaves)
+        # (tables, pos, q_lens, depths, anc, ids) at this window
+        assert f"tensor<2x{window}x{window}xi1>" in text, name
+        assert f"tensor<2x{window}xi32>" in text, name
+
+
 def test_sarif_serialization():
     """Finding -> SARIF: levels map (info -> note), hostsync file:line
     findings become physical locations, logical subjects survive."""
@@ -1607,6 +1630,24 @@ def test_shapecheck_catalog_is_the_expected_closed_set():
     assert shapes == {(2, 1), (1, 8), (1, 16), (1, 32)}, shapes
 
 
+@pytest.mark.parametrize("server,total", [
+    (dict(slots=8, prefill_chunk=64, max_len=4096, num_pages=704), 73),
+    (dict(slots=8, prefill_chunk=256, max_len=12544, num_pages=3200), 97),
+])
+def test_shapecheck_catalog_of_the_benchmark_servers(server, total):
+    """The launch-shape catalogs of the two benchmark server shapes
+    (mistral-7b-serve1, mistral-small-4-serve1): what
+    warm_launch_shapes() compiles and the benchmark reports as
+    `launch_shapes`. Two of each total are the sampling program's."""
+    from flexflow_tpu.analysis.shapecheck import enumerate_catalog
+
+    cat = enumerate_catalog(paged=True, page_size=64, **server)
+    assert cat["total_compilations"] == total
+    assert set(cat["entries"]) == {"ragged_step", "pick_tokens"}
+    assert cat["entries"]["ragged_step"]["count"] == total - 2
+    assert "ragged_pack" not in cat["config"]
+
+
 def test_shapecheck_pass_budget_and_summary():
     """The registered pass scans the repo clean, catalogs every default
     served config under stats, and warns (not errors) when a config's
@@ -1618,7 +1659,7 @@ def test_shapecheck_pass_budget_and_summary():
     assert ctx.shapecheck_summary is not None
     cats = ctx.shapecheck_summary["catalogs"]
     assert set(cats) >= {"paged_base", "paged_megastep", "paged_spec",
-                         "paged_legacy", "dense"}
+                         "dense"}
     for cat in cats.values():
         assert cat["total_compilations"] <= \
             ctx.shapecheck_summary["budget"]

@@ -168,10 +168,26 @@ def test_strategy_validate_rejects_page_over_max_len():
 
 def test_strategy_json_roundtrip():
     s = ServeStrategy(page_size=16, prefill_chunk=32, spec_width=2,
-                      spec_depth=3, ragged_pack=False, pool_fraction=0.5,
+                      spec_depth=3, pool_fraction=0.5,
                       mesh=(("data", 2), ("model", 4)))
     assert ServeStrategy.from_json(s.to_json()) == s
     assert ServeStrategy.from_json(json.loads(json.dumps(s.to_json()))) == s
+
+
+@pytest.mark.parametrize("stored", [True, False])
+def test_strategy_from_json_stored_ragged_pack(stored):
+    """A strategy JSON stored before the packing stopped being an option
+    is outside input: `"ragged_pack": true` (packed, what there is) is
+    dropped; `false` names a launch that no longer exists and is refused
+    by the key's name, not as a dataclass TypeError."""
+    s = ServeStrategy(page_size=16, prefill_chunk=32)
+    doc = dict(s.to_json(), ragged_pack=stored)
+    if stored:
+        assert ServeStrategy.from_json(doc) == s
+    else:
+        with pytest.raises(ValueError, match="ragged_pack"):
+            ServeStrategy.from_json(doc)
+    assert "ragged_pack" not in s.to_json()
 
 
 def test_strategy_kv_dtype_knob_surface():

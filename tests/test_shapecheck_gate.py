@@ -120,8 +120,8 @@ def test_shrunk_catalog_fails_soundness_against_live_events():
 
 
 def test_ttft_records_split_compile_from_serve_time():
-    """Per-request records carry first_compile_s / ttft_excl_compile_s
-    (bench.py --decode percentiles both): a COLD first request's TTFT is
+    """Per-request records carry first_compile_s / ttft_excl_compile_s:
+    a COLD first request's TTFT is
     dominated by compiles; after warm_launch_shapes the same prompt pays
     none. Fresh model so the cold half sees real compiles."""
     ff, lcfg = _causal_lm(seed=11)

@@ -196,6 +196,7 @@ def test_tree_kernel_straddles_block(H, Hkv):
 
 
 def test_tree_verify_matches_sequential_decode():
+    import jax
     import jax.numpy as jnp
 
     ff, lcfg = _causal_lm()
@@ -218,25 +219,30 @@ def test_tree_verify_matches_sequential_decode():
             for n in ("k", "v")
         }
     tables = jnp.asarray(np.array([[1, 2, 3, 0]], np.int32))
-    pstep = ex.paged_decode_fn()
+    # both launches below are shapes of the ONE paged step, which
+    # consumes the pools it is handed: the sequential walk gets a copy
+    pstep = ex.ragged_step_fn()
 
     # three sequential greedy decode steps from pos 5
     cur = int(np.argmax(np.asarray(probs[:, 4, :])[0]))
     chain = [cur]
-    pools_seq, seq_probs = pools, []
+    pools_seq, seq_probs = jax.tree.map(jnp.copy, pools), []
     for pos in range(5, 8):
         pr, pools_seq = pstep(tr, ntr, pools_seq, tables,
                               jnp.asarray(np.array([pos], np.int32)),
+                              jnp.ones((1,), jnp.int32),
+                              jnp.zeros((1, 1), jnp.int32),
+                              jnp.ones((1, 1, 1), jnp.bool_),
                               jnp.asarray(np.array([[cur]], np.int32)))
         seq_probs.append(np.asarray(pr[0, -1]))
         cur = int(np.argmax(seq_probs[-1]))
         chain.append(cur)
 
     # ONE verify step over the same tokens as a depth-3 chain tree
-    vstep = ex.verify_fn()
     parents = np.array([[-1, 0, 1]], np.int32)
-    vp, _ = vstep(tr, ntr, pools, tables,
+    vp, _ = pstep(tr, ntr, pools, tables,
                   jnp.asarray(np.array([5], np.int32)),
+                  jnp.asarray(np.array([3], np.int32)),
                   jnp.asarray(np.array([[0, 1, 2]], np.int32)),
                   jnp.asarray(ancestor_masks(parents)),
                   jnp.asarray(np.array([chain[:3]], np.int32)))
@@ -368,39 +374,38 @@ def test_spec_preemption_stays_correct():
 
 def test_spec_ragged_pack_identity_with_mixed_temperatures():
     """Verify-tick packing (greedy slots send trees, sampled slots send
-    single rows, idle slots send NOTHING) vs the legacy every-slot
-    layout: greedy output is token-identical either way, and the packed
-    path records strictly fewer padded rows."""
+    single rows, idle slots send NOTHING): greedy output is
+    token-identical to ff.generate, and the padded-row share stays under
+    a bound pinned from its value when the every-slot layout was
+    deleted (PR 29: packed 0.7451 in each of ten runs, the every-slot
+    layout 0.8088; the loop starts after the submits so that admission
+    order, and with it the share, does not depend on thread timing)."""
     ff, lcfg = _causal_lm()
     rs = np.random.RandomState(9)
     prompts = [rs.randint(0, lcfg.vocab_size, (n,)).astype(np.int32)
                for n in (4, 6)]
     want = [ff.generate(p[None, :], max_new_tokens=5)[0] for p in prompts]
-    waste = {}
-    for pack in (True, False):
-        # 4 slots for 3 requests: the guaranteed idle slot is exactly
-        # what the legacy layout pays a full-width filler row block for
-        # on every verify tick and the packed path simply omits
-        server = ff.serve_generation(slots=4, max_len=32, paged=True,
-                                     page_size=4, ragged_pack=pack,
-                                     speculate=SpecConfig(width=2, depth=3))
-        try:
-            futs = [server.submit(p, max_new_tokens=5) for p in prompts]
-            # one sampled request rides the same verify ticks (1-row item)
-            fs = server.submit(prompts[0], max_new_tokens=5,
-                               temperature=0.8)
-            got = [f.result(timeout=120) for f in futs]
-            sampled = fs.result(timeout=120)
-            m = server.metrics()
-        finally:
-            server.stop()
-        for i, (w, g) in enumerate(zip(want, got)):
-            np.testing.assert_array_equal(w, g,
-                                          err_msg=f"pack={pack} req {i}")
-        assert 1 <= len(sampled) <= 5
-        assert m["pages_in_use"] == 0
-        waste[pack] = m["padded_rows"] / max(m["launch_rows"], 1)
-    assert waste[True] < waste[False], waste
+    # 4 slots for 3 requests: a verify tick sends no rows for the
+    # guaranteed idle slot
+    server = ff.serve_generation(slots=4, max_len=32, paged=True,
+                                 page_size=4, defer_start=True,
+                                 speculate=SpecConfig(width=2, depth=3))
+    try:
+        futs = [server.submit(p, max_new_tokens=5) for p in prompts]
+        # one sampled request rides the same verify ticks (1-row item)
+        fs = server.submit(prompts[0], max_new_tokens=5,
+                           temperature=0.8)
+        server.start()
+        got = [f.result(timeout=120) for f in futs]
+        sampled = fs.result(timeout=120)
+        m = server.metrics()
+    finally:
+        server.stop()
+    for i, (w, g) in enumerate(zip(want, got)):
+        np.testing.assert_array_equal(w, g, err_msg=f"req {i}")
+    assert 1 <= len(sampled) <= 5
+    assert m["pages_in_use"] == 0
+    assert m["padded_rows"] / max(m["launch_rows"], 1) < 0.78, m
 
 
 def test_spec_requires_paged():
