@@ -39,7 +39,12 @@ class FFConfig:
     # the training default). A served checkpoint is stored as published
     # ("bfloat16" where its config says torch_dtype bfloat16): passed to
     # Executor.init_params(weight_dtype=), use sites cast to the compute
-    # dtype, and a model too large for float32 masters fits
+    # dtype, and a model too large for float32 masters fits. This is
+    # STORAGE AT INIT, for a model that never trains. A trainable model
+    # (None) that is served keeps its masters and needs no setting: its
+    # servers launch with FFModel.serving_params(), the leaves declared
+    # narrower than stored converted once; a tree already stored here is
+    # handed to them as it is
     weight_dtype: Optional[str] = None
 
     # ---- strategy search (reference model.cc:3599-3719 flags) ----

@@ -79,6 +79,7 @@ class FFModel:
         self._executor: Optional[Executor] = None
         self._mesh = None
         self._params = None  # (trainable, nontrainable)
+        self._served = None  # serving_params()'s memo
         self._opt_state = None
         self._optimizer: Optional[Optimizer] = None
         self._loss_type: Optional[LossType] = None
@@ -1291,6 +1292,35 @@ class FFModel:
         from flexflow_tpu.serving import serve_generation as _sg
 
         return _sg(self, **kw)
+
+    def serving_params(self):
+        """(trainable, nontrainable) as a server launches with them
+        (runtime/serving_weights.py): every leaf this graph's serving
+        steps only ever convert to its declared, narrower dtype is stored
+        at that dtype, converted once on the device; every other leaf is
+        `self._params`' own array, and the result IS `self._params`
+        where nothing is converted. `self._params` keeps the masters:
+        fit() trains from them. The tree is built once for the leaves
+        `self._params` holds now, so the servers of one model share it;
+        once fit(), set_weight() or a checkpoint load has replaced a leaf,
+        the next server gets a tree of the new weights and the old one
+        is let go (the memo holds the masters weakly)."""
+        import weakref
+
+        import jax
+
+        from flexflow_tpu.runtime.serving_weights import serving_params
+
+        masters = jax.tree.leaves(self._params)
+        memo = self._served
+        if (memo is None or len(memo[0]) != len(masters)
+                or any(ref() is not leaf
+                       for ref, leaf in zip(memo[0], masters))):
+            served = serving_params(self.executor, self._params)
+            memo = ([weakref.ref(leaf) for leaf in masters],
+                    None if served is self._params else served)
+            self._served = memo
+        return self._params if memo[1] is None else memo[1]
 
     def predict(self, x: Union[np.ndarray, Sequence[np.ndarray]],
                 batch_size: Optional[int] = None) -> np.ndarray:

@@ -389,6 +389,7 @@ class PagedGenerationServer(_GenerationServerBase):
             kv_dtype=None if self.kv_dtype == "auto" else self.kv_dtype)
         self._g_plan_ok = self.registry.gauge("dtype_plan_ok")
         self._g_plan_ok.set(1.0 if self._dtype_plan_ok() else 0.0)
+        self._weight_bytes = self._weights["bytes_served"]
 
         # the pool -> pool programs below CONSUME the pool they are given
         # (as the executor's launches do) and write it in place: every
@@ -1394,6 +1395,8 @@ class PagedGenerationServer(_GenerationServerBase):
                     # whether this shape's pools are written where they
                     # lie, as its first call in warm-up showed
                     sp.set(pools_passed=alias[0], pools_in_place=alias[1])
+                # the weight leaves this launch's program is handed
+                sp.set(weight_bytes=self._weight_bytes)
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
                 ids_d)

@@ -22,7 +22,7 @@ sites, so bf16 compute with fp32 accumulation comes for free.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import cached_property, partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -391,13 +391,13 @@ class Executor:
         `overrides` maps node_key -> weight name -> Initializer (the layer
         methods' kernel_initializer arguments).
 
-        `weight_dtype` optionally casts every leaf AFTER the draw, for
-        serving-memory streaming: a float name ("bf16"/"fp16"/"fp8")
-        stores the leaf at that dtype (use sites re-cast to compute
-        dtype), while "int8" applies per-leaf symmetric fake
-        quantization (paged.quant.quantize_leaf — values snap to the
-        int8 grid, stored bf16, since no executor matmul consumes raw
-        int8). Leave None for the fp32-master training default."""
+        `weight_dtype` stores every leaf at a dtype AFTER the draw, for a
+        model that never trains: a float name ("bf16"/"fp16"/"fp8")
+        casts (use sites re-cast to compute dtype), "int8" snaps values
+        to a per-leaf int8 grid, stored bf16 (paged.quant.quantize_leaf:
+        no executor matmul consumes raw int8). None keeps fp32 masters,
+        the training default; a server of such a model launches with
+        FFModel.serving_params(), the masters narrowed once beside them."""
         specs = self.weight_specs()
         overrides = overrides or {}
 
@@ -1641,7 +1641,11 @@ class Executor:
         (slots, 1) decode launch, and the (slots, max_nodes) launch a
         speculative server verifies drafted trees with. The paged
         shapes (slots / page_size / pool size / tree width) only scale
-        the audit's byte counts, not which collectives appear.
+        the audit's byte counts, not which collectives appear. They are
+        lowered against the weights a server launches with
+        (serving_weights.serving_params of the abstract masters: the
+        leaves the step only converts to their declared dtype arrive at
+        that dtype), train_step and eval_step against the masters.
         `kv_dtype` lowers the paged entries against a quantized pool
         ("int8" adds the scale sidecar to the cache avals, paged/quant)
         so the audit prices the int8 payload bytes, not the fp ones."""
@@ -1677,33 +1681,46 @@ class Executor:
 
             caches = self.paged_kv_cache_specs(
                 pages, page_size, dtype=resolve_kv_dtype(kv_dtype))
-            tables = jax.ShapeDtypeStruct((slots, max_pages), jnp.int32)
-            per_slot = jax.ShapeDtypeStruct((slots,), jnp.int32)
+            from flexflow_tpu.runtime.serving_weights import serving_params
+
+            tr, ntr = serving_params(self, (tr, ntr))
             for entry, window in (("paged_decode", 1),
                                   ("verify", max_nodes)):
                 if entry not in entries:
                     continue
-                rows = jax.ShapeDtypeStruct((slots, window), jnp.int32)
-                anc = jax.ShapeDtypeStruct((slots, window, window),
-                                           jnp.bool_)
-                # (tables, pos, q_lens, depths, anc, ids): q_lens is all
-                # 1 for a decode launch and a tree's node count for a
-                # verify, which only the values say
                 out[entry] = self.ragged_step_fn().lower(
-                    tr, ntr, caches, tables, per_slot, per_slot, rows,
-                    anc, rows)
+                    tr, ntr, caches,
+                    *self.ragged_step_avals(slots, window, max_pages))
         return out
+
+    def ragged_step_avals(self, slots: int, window: int, table_cols: int):
+        """ragged_step_fn()'s arguments after the pools, abstract:
+        (tables, pos, q_lens, depths, anc, ids) of a (slots, window)
+        launch. q_lens is all 1 for a decode launch and a tree's node
+        count for a verify, which only the values say."""
+        per_slot = jax.ShapeDtypeStruct((slots,), jnp.int32)
+        rows = jax.ShapeDtypeStruct((slots, window), jnp.int32)
+        return (jax.ShapeDtypeStruct((slots, table_cols), jnp.int32),
+                per_slot, per_slot, rows,
+                jax.ShapeDtypeStruct((slots, window, window), jnp.bool_),
+                rows)
 
     def dtype_plan(self, entries: Optional[Sequence[str]] = None, *,
                    kv_dtype: Optional[str] = None) -> Dict[str, Dict]:
         """The DECLARED per-entry numerics plan, in HLO dtype names —
         what numcheck's HLO arm diffs each lowered module against
-        (analysis/numcheck.py). Pure metadata from the graph's weight
-        declarations and cache specs; nothing is traced or compiled.
+        (analysis/numcheck.py). Metadata from the graph's weight
+        declarations and cache specs; nothing is compiled, and only the
+        paged entries trace (once an executor: which leaves a server
+        holds at their declared dtype, serving_weights.served_dtypes).
 
-        Per entry: "compute" (the dtype float math runs at — f32, since
-        abstract_params promotes bf16/f16 declarations to f32 master
-        weights and that is what every entry is lowered against),
+        Per entry: "compute" (the dtype the weights arrive at — f32 for
+        train_step and eval_step, since abstract_params promotes
+        bf16/f16 declarations to f32 master weights and that is what
+        they are lowered against; for the paged entries the narrowest
+        float dtype of the tree a server launches with, the declared
+        bf16 of a llama: leaves the step reads wider, a norm's scale,
+        stay f32 and are in "allowed"),
         "accum" (contraction accumulation dtype; always f32 — narrower
         is hlo-accum-downgrade), "kv" (the paged pool payload dtype for
         the paged entries, lowered_modules' two shapes of
@@ -1742,17 +1759,27 @@ class Executor:
                 if ins:
                     dt = jnp.dtype(ins[0].dtype.jnp_dtype)
                     kv_name = _HLO_DTYPE_NAMES.get(dt.name, dt.name)
+        paged = {"paged_decode", "verify"} & set(entries)
+        served = "f32"
+        if paged:
+            from flexflow_tpu.runtime.serving_weights import serving_params
+
+            dts = {jnp.dtype(x.dtype) for x in jax.tree.leaves(
+                serving_params(self, self.abstract_params()))}
+            name = min((d for d in dts if jnp.issubdtype(d, jnp.floating)),
+                       key=lambda d: d.itemsize).name
+            served = _HLO_DTYPE_NAMES.get(name, name)
         plan: Dict[str, Dict] = {}
         for entry in entries:
             allowed = set(declared)
             kv = None
-            if entry in ("paged_decode", "verify"):
+            if entry in paged:
                 kv = kv_name
                 allowed.add(kv_name)
                 if kv_name == "s8":
                     allowed.add("f32")  # dequant target / scale sidecar
             plan[entry] = {
-                "compute": "f32",
+                "compute": served if entry in paged else "f32",
                 "accum": "f32",
                 "kv": kv,
                 "allowed": sorted(allowed),
@@ -1792,3 +1819,11 @@ class Executor:
             if deg <= 1 or batch_size % deg != 0:
                 return None
         return NamedSharding(self.mesh, spec_to_partition_spec(spec))
+
+    @cached_property
+    def served_dtypes_memo(self) -> Dict:
+        """serving_weights.served_dtypes' results for this graph, by the
+        stored dtypes they were asked about: one trace of the serving
+        steps an executor, shared by its servers, lowered_modules() and
+        dtype_plan()."""
+        return {}

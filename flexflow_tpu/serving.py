@@ -583,7 +583,7 @@ class _GenerationServerBase:
                 f"position table ({rows} rows); rebuild with a longer "
                 "seq_len or lower max_len")
         self.eos_id = eos_id
-        self._params = ff._params
+        self._params = ff.serving_params()
         self._rng = jax.random.key(seed)
 
         # compile-event ledger (obs.compile_tracker): shared with the
@@ -807,7 +807,7 @@ class _GenerationServerBase:
                 "capacity": self._reqlog.capacity,
                 "dropped": self._reqlog.dropped,
             },
-            "compile": snap,
+            "compile": snap, "weights": self._weights,
             "histograms": self.registry.to_json(),
         }
         if self.serve_strategy is not None:
@@ -1171,6 +1171,15 @@ class _GenerationServerBase:
 
     def _loop_body(self, tr, ntr):
         raise NotImplementedError
+
+    @property
+    def _weights(self) -> dict:
+        """metrics()'s "weights": the bytes of the model's own tree, of
+        the tree this server's launches are handed (FFModel.
+        serving_params) and the leaves stored narrower in it."""
+        from flexflow_tpu.runtime.serving_weights import weight_stats
+
+        return weight_stats(self.ff._params, self._params)
 
     def _drain(self):
         """Cancel whatever is still queued or mid-decode so callers

@@ -40,7 +40,10 @@ jax.config.update("jax_num_cpu_devices", 8)
 # written about goes, or if one not listed here comes. A `benchmark` issue
 # should make the assertion a subset and delete this with that shim
 # (PERF.md section 7 (c)).
-METRICS_ADDED_SINCE_PR27 = ("pool_in_place_share",)    # PR 28
+METRICS_ADDED_SINCE_PR27 = (
+    "pool_in_place_share",                  # PR 28
+    "weight_bytes_per_launch.prefill",      # PR 30
+)
 
 
 @pytest.fixture(autouse=True)

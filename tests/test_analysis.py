@@ -2158,9 +2158,11 @@ def test_numcheck_hlo_arm_flags_downgrade_f64_and_unplanned_convert():
 
 
 def test_numcheck_executor_dtype_plan_and_real_lowering():
-    """Executor.dtype_plan() declares f32 compute/accum (master
-    weights), the pool payload dtype per paged entry (s8 + f32 dequant
-    targets for int8), and never f64 — and the llama baseline's REAL
+    """Executor.dtype_plan() declares f32 accumulation, the weights'
+    dtype an entry is lowered against (the f32 masters for train_step,
+    the tree a server launches with for the paged entries: a llama's
+    declared bf16), the pool payload dtype per paged entry (s8 + f32
+    dequant targets for int8), and never f64 — and the llama baseline's REAL
     lowered paged_decode diffs clean against it while a zeroed
     (all-bf16) plan mutation makes the same module fail with
     hlo-accum-downgrade."""
@@ -2172,10 +2174,11 @@ def test_numcheck_executor_dtype_plan_and_real_lowering():
     executor, _, _, _ = build_baseline_executor("llama_tp_dp")
     plan = executor.dtype_plan(kv_dtype="int8")
     pd = plan["paged_decode"]
-    assert pd["compute"] == "f32" and pd["accum"] == "f32"
+    assert pd["compute"] == "bf16" and pd["accum"] == "f32"
     assert pd["kv"] == "s8" and not pd["allow_f64"]
-    assert {"s8", "f32"} <= set(pd["allowed"])
+    assert {"s8", "f32", "bf16"} <= set(pd["allowed"])
     assert plan["train_step"]["kv"] is None
+    assert plan["train_step"]["compute"] == "f32"
 
     mods = lower_executor_modules(executor, entries=["paged_decode"],
                                   subject="llama_tp_dp")
