@@ -395,20 +395,18 @@ def _packed_prefill_shapes(slots: int, chunk: int,
     """Closed (n_items, window) family of the scheduler's ragged-packed
     prefill tick: W = min(cap, largest take this tick); each planned
     slot's take splits into ceil(take/W) pieces, all packed into ONE
-    launch; the shared per-tick token budget bounds sum(take) by
-    prefill_chunk. For W < cap, W IS the largest take, so every take
-    fits one piece and n_items <= 1 + min(slots-1, chunk-W). At W == cap
-    takes may exceed the window and split, so n_items is bounded by the
-    worst split: k-1 single-row takes plus one take of the remaining
-    budget."""
+    launch with one q_len 1 item a DECODING slot behind them; the shared
+    per-tick token budget bounds sum(take) by prefill_chunk, and k
+    mid-prefill plus d decoding slots are at most `slots`. For W < cap,
+    W IS the largest take, so every take fits one piece and n_items <=
+    k + d <= slots (one chunk and slots-1 decode rows reach it). At
+    W == cap takes may exceed the window and split, so n_items is
+    bounded by the worst split: one take of the whole budget and a
+    decode row on every other slot (a single-row take in a decode row's
+    place costs the big take a row and never adds a piece)."""
     shapes: Set[Tuple[int, int]] = set()
     for W in range(1, min(cap, chunk) + 1):
-        bmax = 1 + min(slots - 1, chunk - W)
-        if W == cap:
-            for k in range(1, min(slots, chunk) + 1):
-                big = chunk - (k - 1)
-                if big >= W:
-                    bmax = max(bmax, (k - 1) + -(-big // W))
+        bmax = slots if W < cap else slots - 1 + -(-chunk // W)
         for B in range(1, bmax + 1):
             shapes.add((B, W))
     return shapes

@@ -21,9 +21,10 @@ K/V instead of recomputing it.
 CHUNKED PREFILL: the uncached suffix is computed `prefill_chunk` tokens
 per tick straight into pool pages (Executor.ragged_step_fn — no
 dense staging cache), INSIDE the decode loop: each tick advances
-mid-prefill slots by one budgeted chunk and then runs the normal decode
-tick for everyone else, so a long prompt never stalls in-flight decodes
-for more than the one tick its chunk shares.
+mid-prefill slots by one budgeted chunk and the decoding slots by one
+token IN THE SAME LAUNCH, so a long prompt never stalls in-flight
+decodes for more than the one launch its chunk shares, and the weights
+are streamed once an iteration.
 
 RAGGED WORK PACKING: every model call is the ONE ragged step
 (Executor.ragged_step_fn — flexflow_tpu.paged.attention): the tick
@@ -43,12 +44,13 @@ Decode flow per tick:
      prefix-cache hits and allocates the remaining pages — no model run
   2. grow: decoding slots whose next write position crosses a page
      boundary allocate a page, preempting under pressure
-  3. one budgeted prefill launch packing every mid-prefill slot's chunk
-     pieces (a finishing chunk samples the first token)
-  4. one jitted ragged decode step for the decoding slots (idle and
-     mid-prefill slots carry q_len 0: no work, writes to the null page)
-  5. sample, append, publish freshly filled pages to the prefix cache,
-     finish/free
+  3. ONE launch an iteration. With a chunk to run: every mid-prefill
+     slot's chunk pieces, then one q_len 1 item a decoding slot, in a
+     window of the largest piece (a finishing chunk samples the first
+     token). With none: the (slots, 1) decode step (idle slots carry
+     q_len 0: no work, writes to the null page)
+  4. sample the decoding slots' rows at their slot index, append,
+     publish freshly filled pages to the prefix cache, finish/free
 """
 
 from __future__ import annotations
@@ -66,6 +68,17 @@ from flexflow_tpu.paged.pool import EMPTY_HASH, PagePool
 from flexflow_tpu.runtime.executor import LAUNCH_STATS
 from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
 from flexflow_tpu.serving import _GenerationServerBase, _GenRequest
+
+
+def rows_at(tail, at):
+    """Rows `at` (slots,) of `serving.probs_rows`' (slots, V) tail: the
+    decode rows that rode a chunk's launch, each put at its SLOT's index
+    (what the shared `_pick` draws by). ONE program a server: the gather
+    costs a quarter of a second to compile, which a launch shape's own
+    program must not (20 s of set-up over 73 shapes on the chip)."""
+    import jax.numpy as jnp
+
+    return jnp.take(tail, at, axis=0)
 
 
 class PagedGenerationServer(_GenerationServerBase):
@@ -278,6 +291,12 @@ class PagedGenerationServer(_GenerationServerBase):
         self.peak_active = 0
         self.prefill_ticks = 0
         self._prefill_rr = 0  # rotating start slot for the chunk budget
+        # iterations that held a chunk AND decoding slots, and those of
+        # them whose decode rows rode the chunk's launch (all, unless a
+        # subclass ticks them apart: the speculative server's verify)
+        self.iterations_with_both = 0
+        self.one_launch = 0
+        self._rows_at = jax.jit(rows_at)
         # idle-loop accounting (fftrace): ticks the loop slept because
         # nothing was live or admitted, and total seconds spent asleep
         self._c_idle = self.registry.counter("idle_ticks_total")
@@ -536,6 +555,10 @@ class PagedGenerationServer(_GenerationServerBase):
             "pool_occupancy": pool.pages_in_use / pool.capacity,
             "fragmentation": pool.fragmentation(),
             "prefill_ticks": self.prefill_ticks,
+            "launches": {
+                "iterations_with_both": self.iterations_with_both,
+                "one_launch": self.one_launch,
+            },
             "kernel_variant": self.kernel_variant,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_cache_dtype": self._kv_pool_dtype_name(),
@@ -1429,6 +1452,17 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_pad.inc(padded)
         return probs, padded, total
 
+    def _rider_rows(self, probs, at):
+        """(slots, V): the rows of the decode items behind a chunk's
+        pieces, each at its slot's index (`at`: where it lies among the
+        launch's last `slots` entries)."""
+        tail = self._probs_rows(probs, np.int32(0), np.int32(0),
+                                self.slots)[1]
+        return self._rows_at(tail, at)
+
+    def _warm_riders(self, probs):
+        self._rider_rows(probs, np.zeros((self.slots,), np.int32))
+
     def _tick_prep(self) -> Optional[List[int]]:
         """Shared tick prologue (base and speculative loops): defrag if
         requested, admit, grow pages. Returns the live slots (decoding
@@ -1470,7 +1504,7 @@ class PagedGenerationServer(_GenerationServerBase):
         dec = [s for s in live if not self._mid_prefill(s)]
         return pre, dec
 
-    def _prefill_tick(self, slots, tr, ntr):
+    def _prefill_tick(self, slots, tr, ntr, dec=()):
         """Advance mid-prefill slots by chunks, at most `prefill_chunk`
         tokens ACROSS the tick (a shared Sarathi-style token budget —
         it bounds the tick's prefill FLOPs, protecting decode latency),
@@ -1484,7 +1518,14 @@ class PagedGenerationServer(_GenerationServerBase):
         Every slot's chunk is split into window-sized pieces and the
         whole tick rides ONE packed launch (piece i+1 sees piece i's
         rows as committed because K/V scatter precedes attention at
-        each layer)."""
+        each layer). The decoding slots `dec` ride the same launch, one
+        q_len 1 item each after the chunk's pieces: the weights are
+        streamed once for the iteration. Their rows are not sampled
+        here; returns what `_decode_tick` picks them from (None without
+        `dec`)."""
+        waiting = any(not self._mid_prefill(s) for s in self._live())
+        self.iterations_with_both += waiting
+        self.one_launch += bool(dec)
         budget = self.prefill_chunk
         self.prefill_ticks += 1
         rot = self._prefill_rr % len(slots)
@@ -1517,6 +1558,14 @@ class PagedGenerationServer(_GenerationServerBase):
                                               start + off + piece],
                               None, None))
             ends.append((len(items) - 1, (take - 1) % W))
+        # the decoding slots' items come LAST, so their rows are the end
+        # of the launch's last `slots` entries: where each lies there, by
+        # SLOT (the filler names the last one: real probabilities, unread)
+        at = np.full((self.slots,), self.slots - 1, np.int32)
+        for j, s in enumerate(dec):
+            at[s] = self.slots - len(dec) + j
+            items.append((s, self._active[s].pos, [int(self._tokens[s])],
+                          None, None))
         probs, padded, total = self._launch(items, W, tr, ntr)
         with obs.span("commit") as csp:
             first = []  # seq of each request that got its first token
@@ -1535,9 +1584,9 @@ class PagedGenerationServer(_GenerationServerBase):
                     self._publish_tail(req)
                     with obs.span("sample"):
                         # the last real row, (1, V): one warmed program
-                        # a launch shape (serving.probs_row)
-                        row = self._probs_row(probs, np.int32(i),
-                                              np.int32(r))
+                        # a launch shape (serving.probs_rows)
+                        row = self._probs_rows(probs, np.int32(i),
+                                               np.int32(r), self.slots)[0]
                     self._sample_first_token(s, req, row)
                     first.append(req.seq)
                     self._finish_if_done(s)
@@ -1556,42 +1605,51 @@ class PagedGenerationServer(_GenerationServerBase):
             sp.set(slots=len(slots), chunk_tokens=chunked,
                    padded_rows=padded, total_rows=total,
                    rids=[req.seq for _s, req, _a, _t in plan],
-                   takes=[take for _s, _r, _a, take in plan])
+                   takes=[take for _s, _r, _a, take in plan],
+                   decode_waiting=int(waiting), decode_rode=int(bool(dec)))
         sp.__exit__(None, None, None)
         dt = time.monotonic() - t0
         self._h_prefill.observe(dt)
         led = obs.ledger()
         if led is not None:
             led.record("prefill", dt, batch=len(slots), chunk=chunked)
+        return (probs, at) if dec else None
 
-    def _decode_tick(self, live, tr, ntr):
-        """One plain single-token decode tick for the decoding slots
-        (also dispatched by the speculative server when no live slot can
-        use a tree — all-sampled ticks skip the tree-verify FLOPs).
-        Mid-prefill slots ride along with nulled table rows (fixed-shape
-        program) and count the tick as decode/prefill overlap."""
+    def _decode_tick(self, live, tr, ntr, rode=None):
+        """One single-token decode tick for the decoding slots: sample
+        their rows at their slot index with the one shared `_pick`
+        split, fetch, commit. Their rows come from this tick's own
+        (slots, 1) launch, or, in an iteration with a chunk, from the
+        chunk's launch they rode (`rode`, what `_prefill_tick` returned):
+        the tick then launches nothing and its `fetch` waits for the
+        chunk's launch. Mid-prefill slots count the tick as
+        decode/prefill overlap. (Also dispatched by the speculative
+        server when no live slot can use a tree — all-sampled ticks skip
+        the tree-verify FLOPs.)"""
         import jax
 
         t0 = time.monotonic()
         sp = obs.span("decode_tick").__enter__()
         if sp:
             sp.set(live=len(live), pages_in_use=self.pool.pages_in_use)
-        # one item per slot — q_len 1 for the decoding slots, 0 for idle
-        # and mid-prefill ones (no work, writes to the null page), so the
-        # launch compiles once for (slots, 1) and probs stays
-        # slot-indexed for the one shared _pick split
-        dec = set(live)
-        items = [(s, self._active[s].pos if s in dec else 0,
-                  [int(self._tokens[s])] if s in dec else [],
-                  None, None)
-                 for s in range(self.slots)]
-        probs, padded, total = self._launch(items, 1, tr, ntr)
-        self._g_waste.set(padded / total if total else 0.0)
-        if sp:
-            sp.set(padded_rows=padded, total_rows=total)
+        if rode is None:
+            # one item per slot — q_len 1 for the decoding slots, 0 for
+            # idle ones (no work, writes to the null page), so the launch
+            # compiles once for (slots, 1) and probs stays slot-indexed
+            dec = set(live)
+            items = [(s, self._active[s].pos if s in dec else 0,
+                      [int(self._tokens[s])] if s in dec else [],
+                      None, None)
+                     for s in range(self.slots)]
+            probs, padded, total = self._launch(items, 1, tr, ntr)
+            self._g_waste.set(padded / total if total else 0.0)
+            if sp:
+                sp.set(padded_rows=padded, total_rows=total)
         with obs.span("sample"):
+            rows = (probs[:, -1, :] if rode is None
+                    else self._rider_rows(*rode))
             self._rng, sub = jax.random.split(self._rng)
-            picked = self._pick(probs[:, -1, :], self._temps_device(), sub)
+            picked = self._pick(rows, self._temps_device(), sub)
         with obs.span("fetch") as fsp:
             # the host's wait for the device: step, pick and the copy out
             toks = np.asarray(picked)
@@ -1853,11 +1911,7 @@ class PagedGenerationServer(_GenerationServerBase):
             # one legacy host-granularity tick so the loop always makes
             # progress; no rng split was consumed by the empty dispatch.
             sp.__exit__(None, None, None)
-            pre, dec = self._split_live(live)
-            if pre:
-                self._prefill_tick(pre, tr, ntr)
-            if dec:
-                self._decode_tick(dec, tr, ntr)
+            self._host_tick(live, tr, ntr)
             return
         pf_slots = [s for s in live if pf_act[s]]
         dec_slots = [s for s in live if dec_act[s]]
@@ -1959,24 +2013,30 @@ class PagedGenerationServer(_GenerationServerBase):
         does nothing (check_invariants is too hot for the serving
         loop)."""
 
+    def _host_tick(self, live, tr, ntr):
+        """One iteration at the host's granularity, ONE launch: the
+        chunk's launch carries the decoding slots' rows whenever both
+        kinds of work exist (a slot whose prompt finishes in it decodes
+        from the next iteration on, as `dec` is settled before)."""
+        pre, dec = self._split_live(live)
+        if pre:
+            rode = self._prefill_tick(pre, tr, ntr, dec)
+            if dec:
+                self._decode_tick(dec, tr, ntr, rode)
+        elif self._megastep is not None:
+            # _decode_megastep stands down by itself while a canary
+            # window is open (the fp32 shadow must observe every launch)
+            self._decode_megastep(dec, tr, ntr)
+        else:
+            self._decode_tick(dec, tr, ntr)
+
     def _loop_body(self, tr, ntr):
         while not self._stop.is_set():
             live = self._tick_prep()
             if live is None:
                 continue
-            if self._mixed_dispatch(live, tr, ntr):
-                continue
-            pre, dec = self._split_live(live)
-            if pre:
-                self._prefill_tick(pre, tr, ntr)
-            if dec:
-                if self._megastep is not None and not pre:
-                    # _decode_megastep stands down by itself while a
-                    # canary window is open (the fp32 shadow must
-                    # observe every launch)
-                    self._decode_megastep(dec, tr, ntr)
-                else:
-                    self._decode_tick(dec, tr, ntr)
+            if not self._mixed_dispatch(live, tr, ntr):
+                self._host_tick(live, tr, ntr)
 
     def _drain(self):
         super()._drain()

@@ -908,11 +908,15 @@ class TickPricer:
         return comp + self.host_dispatch_s
 
     def prefill_tick(self, chunk_tokens: int, padded_rows: float = 0.0,
-                     batch: int = 1) -> float:
+                     batch: int = 1, decode_rows: int = 0) -> float:
         """Seconds for one chunked-prefill launch: `chunk_tokens` live
         rows plus the ceil-to-window padding the scheduler launches
-        with (pieces of serve_strategy.PREFILL_WINDOW_ROWS rows)."""
-        rows = max(int(chunk_tokens), 1) + max(padded_rows, 0.0) * self.pad_row_cost
+        with (pieces of serve_strategy.PREFILL_WINDOW_ROWS rows), and
+        the `decode_rows` live rows of the decoding slots that ride it
+        (each a window of its own, its padding in `padded_rows`): the
+        iteration's one dispatch."""
+        rows = (max(int(chunk_tokens), 1) + max(int(decode_rows), 0)
+                + max(padded_rows, 0.0) * self.pad_row_cost)
         comp = (self.token_seconds * rows
                 * self._scale("prefill", batch, chunk=int(chunk_tokens)))
         return comp + self.host_dispatch_s

@@ -16,9 +16,12 @@ paged scheduler's tick loop, pricing each dispatch with the SAME
   * chunked prefill with the adaptive packed window — one shared
     `prefill_chunk` token budget per tick, rotating start, takes split
     into `W = min(PREFILL_WINDOW_ROWS, max take)` pieces packed into one
-    launch (or legacy per-slot pow2 buckets), priced with
-    `TickPricer.prefill_tick`;
-  * decode / megastep fusion — one row per slot (idle rows padded), a
+    launch, priced with `TickPricer.prefill_tick`; the decoding slots
+    ride that launch, a q_len 1 item each in its window, so an
+    iteration with both kinds of work is ONE dispatch over the packed
+    rows (the speculative verify alone stays a second launch);
+  * decode / megastep fusion (iterations with no chunk) — one row per
+    slot (idle rows padded), a
     fused run breaking at the first finish, page boundary, or the
     `megastep_ticks` limit, priced with `TickPricer.decode_dispatch`;
     with `megastep_mixed` the in-flight prefill chunks ride the same
@@ -497,7 +500,19 @@ class _SimRun:
 
     # -- tick phases ----------------------------------------------------
 
-    def _prefill_tick(self, slots: List[int]) -> float:
+    def _prefill_tick(self, slots: List[int],
+                      dec: Sequence[int] = ()) -> float:
+        """One budgeted chunk launch. The decoding slots `dec` ride it,
+        a q_len 1 item each in the chunk's window, as in the server's
+        loop: ONE launch prices the iteration and the host is paid
+        once. (The speculative server's verify has its own window and
+        stays a second launch: `play` hands it no `dec`.)"""
+        # pages first, as the server's tick prologue grows them: a grow
+        # under pressure may evict a mid-prefill slot out of this chunk
+        granted = self._grant(dec)
+        slots = [s for s in slots if self.active[s] is not None]
+        if not slots:
+            return self._decode_tick(granted, mixed=True)
         budget = self.chunk
         rot = self.prefill_rr % len(slots)
         self.prefill_rr += 1
@@ -515,9 +530,10 @@ class _SimRun:
         w = min(self.window, max(take for _, take in plan))
         pieces = sum(-(-take // w) for _, take in plan)
         total = sum(take for _, take in plan)
-        cost = self.tick.prefill_tick(total,
-                                      padded_rows=pieces * w - total,
-                                      batch=pieces)
+        cost = self.tick.prefill_tick(
+            total, padded_rows=(pieces + len(granted)) * w - total
+            - len(granted), batch=pieces + len(granted),
+            decode_rows=len(granted))
         for s, take in plan:
             req = self.active[s]
             req.prefill_pos += take
@@ -525,19 +541,24 @@ class _SimRun:
                 if req.first_token_s is None:
                     req.first_token_s = self.t + cost
                 req.pos = 1  # the completion tick samples token one
+        for s in granted:
+            self.active[s].pos += 1
         return cost
 
-    def _decode_tick(self, dec: List[int], mixed: bool) -> float:
+    def _grant(self, dec: Sequence[int]) -> List[int]:
+        """The decoding slots that emit a token this tick: unfinished,
+        and granted the pages for it. A grow under pool pressure can
+        evict the youngest OTHER live slot — one still ahead in this
+        scan, or one already granted. Either way the evicted slot
+        decodes nothing this tick."""
         live = [s for s in dec if self.active[s].pos
                 < self.active[s].new_tokens]
-        if not live:
-            return 0.0
-        # a grow under pool pressure can evict the youngest OTHER live
-        # slot — one still ahead in this scan, or one already granted.
-        # Either way the evicted slot decodes nothing this tick.
         granted = [s for s in live
                    if self.active[s] is not None and self._grow(s)]
-        granted = [s for s in granted if self.active[s] is not None]
+        return [s for s in granted if self.active[s] is not None]
+
+    def _decode_tick(self, dec: List[int], mixed: bool) -> float:
+        granted = self._grant(dec)
         if not granted:
             return 0.0
         padded = self.slots - len(granted)
@@ -675,6 +696,8 @@ class _SimRun:
             self._pending_fetch_s = 0.0
             if self.mixed:
                 cost += self._mixed_tick(pre, dec)
+            elif pre and self.spec is None:
+                cost += self._prefill_tick(pre, dec)
             else:
                 if pre:
                     cost += self._prefill_tick(pre)

@@ -51,16 +51,16 @@ def pick_tokens(probs_last, temps, rng):
     return jnp.where(temps > 0.0, sampled, greedy)
 
 
-def probs_row(probs, i, r):
-    """Row r of entry i of a launch's (B, W, V) probs, as (1, V). The
-    indices are operands: one program a launch shape. An eager static
-    slice `probs[i:i + 1, r]` is one program a piece index i, compiled
-    the first time a prompt ends in that piece (about 110 ms on the chip,
-    inside the serving window)."""
+def probs_rows(probs, i, r, n):
+    """Of a launch's (B, W, V) probs: row r of entry i as (1, V), and row 0
+    of its LAST n entries as (n, V), zero rows first when B < n: where the
+    decode rows that rode a chunk's launch lie. Indices are operands, the
+    rest static: one cheap program a launch shape (an eager static slice
+    is one a piece index, 110 ms each on the chip, inside the window)."""
     import jax
-
-    return jax.lax.dynamic_slice(
-        probs, (i, r, 0), (1, 1, probs.shape[2]))[:, 0, :]
+    row = jax.lax.dynamic_slice(probs, (i, r, 0), (1, 1, probs.shape[2]))
+    return row[:, 0, :], jax.numpy.pad(
+        probs[-n:, 0, :], ((max(n - probs.shape[0], 0), 0), (0, 0)))
 
 
 class ModelInstance:
@@ -603,7 +603,7 @@ class _GenerationServerBase:
         # shares (dense, paged, packed spec roots, megastep inner loop)
         self._pick = tracker.wrap("pick_tokens", jax.jit(pick_tokens),
                                   lambda args: (args[0].shape[0],))
-        self._probs_row = jax.jit(probs_row)
+        self._probs_rows = jax.jit(probs_rows, static_argnums=3)
         # ragged launch shape -> (pool leaves passed, leaves written in
         # place), as warm_launch_shapes saw the shape's first call
         self._pool_alias: dict = {}
@@ -864,9 +864,9 @@ class _GenerationServerBase:
 
         def on_probs(p):
             # what a tick runs on a launch's probs, at that shape: the
-            # last-row program a completing prefill's first token is
-            # picked from, and the decode tick's eager slice
-            self._probs_row(p, np.int32(0), np.int32(0))
+            # rows program (a completing prefill's last row, the rows of
+            # decode items behind a chunk) and the decode tick's eager slice
+            self._probs_rows(p, np.int32(0), np.int32(0), self.slots)
             if p.shape[:2] == (self.slots, 1):
                 p[:, -1, :]
 
@@ -874,6 +874,7 @@ class _GenerationServerBase:
             catalog, params=self._params, eos_id=self.eos_id,
             on_probs=on_probs)
         self._pool_alias = info["pool_alias"]
+        self._warm_riders(info.get("probs_ref"))
         # the rng chain's split: a host-made key first, its own (committed)
         # output from then on; throwaway keys, as below
         key, _ = jax.random.split(jax.random.key(0))
@@ -917,6 +918,10 @@ class _GenerationServerBase:
         gc.freeze()
         self._gc_frozen = True
         return catalog
+
+    def _warm_riders(self, probs):
+        """Hook: warm what a subclass runs on `probs_rows`' output that
+        no launch shape keys (the paged server's `rows_at`)."""
 
     # -- shared scheduler pieces -----------------------------------------
 

@@ -1595,17 +1595,19 @@ def test_shapecheck_repo_hot_paths_clean_and_entry_points_seen():
 
 def test_shapecheck_catalog_is_the_expected_closed_set():
     """slots=2 / prefill_chunk=6 paged catalog: the packed-prefill family
-    plus the decode tick is exactly 11 ragged shapes, and the knobs land
-    in the config echo warm_launch_shapes rebuilds launches from."""
+    plus the decode tick is exactly 12 ragged shapes ((2, 6) is a whole
+    chunk with the other slot's decode row riding its launch), and the
+    knobs land in the config echo warm_launch_shapes rebuilds launches
+    from."""
     from flexflow_tpu.analysis.shapecheck import enumerate_catalog
 
     cat = enumerate_catalog(slots=2, max_len=32, page_size=4,
                             prefill_chunk=6)
     ragged = {tuple(s) for s in cat["entries"]["ragged_step"]["shapes"]}
-    want = {(b, w) for w in range(1, 6) for b in (1, 2)} | {(1, 6)}
+    want = {(b, w) for w in range(1, 7) for b in (1, 2)}
     assert ragged == want, ragged
     assert cat["entries"]["pick_tokens"]["shapes"] == [[1], [2]]
-    assert cat["total_compilations"] == 13
+    assert cat["total_compilations"] == 14
     assert cat["config"]["table_cols"] == 8      # ceil(32 / 4)
     assert cat["config"]["num_pages"] == 17      # slots*cols + null page
 

@@ -1035,9 +1035,18 @@ def test_traced_paged_tick_phases(tmp_path):
                     if e[0] in ("prefill_tick", "decode_tick")),
                    key=lambda e: e[1])
     assert ticks[0][4]["rids"] == [1, 2] and ticks[0][4]["takes"] == [5, 3]
-    for tick in ticks:
+    rode = 0
+    for n, tick in enumerate(ticks):
         launch = [k for k in kids[tick[4]["id"]]
                   if k[0] == "launch_dispatch"]
+        commit = [k for k in kids[tick[4]["id"]] if k[0] == "commit"]
+        assert len(commit) == 1
+        if tick[0] == "decode_tick" and ticks[n - 1][4].get("decode_rode"):
+            # its rows rode the chunk's launch, counted there (below)
+            assert not launch
+            for seq in commit[0][4]["rids"]:
+                made[seq] += 1
+            continue
         assert len(launch) == 1
         got = launch[0][4]
         items = []                              # (pos, q_len) with work
@@ -1047,6 +1056,12 @@ def test_traced_paged_tick_phases(tmp_path):
                 for off in range(0, take, W):
                     items.append((filled[seq] + off, min(W, take - off)))
                 filled[seq] += take
+            if tick[4]["decode_rode"]:
+                # the decoding slots' q_len 1 items, behind the pieces
+                assert ticks[n + 1][0] == "decode_tick"
+                items += [(plen[seq] + made[seq] - 1, 1)
+                          for seq in ticks[n + 1][4]["rids"]]
+                rode += 1
         else:
             items = [(plen[seq] + made[seq] - 1, 1)
                      for seq in tick[4]["rids"]]
@@ -1061,11 +1076,9 @@ def test_traced_paged_tick_phases(tmp_path):
         assert got["qk_pairs"] == sum(
             sum(p + i for i in range(1, q + 1)) for p, q in items)
         assert got["rows"] - got["padded_rows"] == sum(q for _p, q in items)
-        commit = [k for k in kids[tick[4]["id"]] if k[0] == "commit"]
-        assert len(commit) == 1
         for seq in commit[0][4]["rids"]:
             made[seq] += 1
-    assert filled == plen and made == {1: 3, 2: 3}
+    assert filled == plen and made == {1: 3, 2: 3} and rode > 0
 
     # (d) beacons
     stamps = [e[4]["stamp"] for e in rec.events if e[0] == "ffclock"]

@@ -764,10 +764,13 @@ def test_ragged_pack_token_identity_and_less_waste():
     """The packed launches (per-slot work descriptors, chunk pieces of
     at most PREFILL_WINDOW_ROWS rows) emit greedy tokens IDENTICAL to
     ff.generate on a mixed chunked-prefill + decode workload, their
-    padded-row share stays under a bound pinned from its value when
-    the one-bucket-launch-a-slot packing was deleted (PR 29: packed
-    0.2588 in each of ten runs, the bucket launches 0.5039), and the
-    pool invariants hold after the churn."""
+    padded-row share stays under a bound pinned from its value (0.4474
+    in each of three runs since decode rows ride the chunk's launch: 7
+    of this run's iterations hold both kinds of work, and a rider pads
+    5 rows of a 6-row window where it padded a share of the (3, 1)
+    launch, 51 of 114 rows against PR 29's 0.2588 over two launches an
+    iteration; the one-bucket-launch-a-slot packing PR 29 deleted read
+    0.5039), and the pool invariants hold after the churn."""
     ff, lcfg = _causal_lm()
     rs = np.random.RandomState(21)
     prompts = [rs.randint(0, lcfg.vocab_size, (n,)).astype(np.int32)
@@ -786,7 +789,9 @@ def test_ragged_pack_token_identity_and_less_waste():
     assert m["launch_rows"] > 0
     assert 0.0 <= m["padding_waste_ratio"] < 1.0
     assert m["kernel_variant"] in ("ragged_pallas", "ragged_gather")
-    assert m["padded_rows"] / m["launch_rows"] < 0.40, m
+    assert m["padded_rows"] / m["launch_rows"] < 0.50, m
+    assert m["launches"]["one_launch"] == \
+        m["launches"]["iterations_with_both"] > 0
     server.pool.check_invariants(owners={})
 
 

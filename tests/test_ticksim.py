@@ -135,6 +135,72 @@ def test_sim_burst_queues_where_a_trickle_does_not():
         assert bs[k] == ss[k]
 
 
+def _sim_run(spec=False):
+    """A four-slot `_SimRun` (chunks of 32) and two requests: `a` with a
+    prompt of 8, `b` with one of 100."""
+    from flexflow_tpu.search.cost_model import TickPricer
+    from flexflow_tpu.search.ticksim import SimRequest, _SimRun
+
+    strat = (ServeStrategy(page_size=16, prefill_chunk=32, spec_width=2,
+                           spec_depth=2) if spec
+             else ServeStrategy(page_size=16, prefill_chunk=32))
+    run = _SimRun(strat, TickPricer(base_step_s=1e-3, base_tokens=256),
+                  slots=4, max_len=128, acceptance_rate=0.5, seed=0)
+    return run, [SimRequest(rid=r, submit_s=0.0, prompt_tokens=n,
+                            new_tokens=6) for r, n in (("a", 8), ("b", 100))]
+
+
+def test_sim_iteration_with_chunk_and_decode_rows_is_one_launch():
+    """The server's loop launches once an iteration: the decoding slots
+    ride the chunk's launch as q_len 1 items. The simulator prices such
+    an iteration as ONE prefill dispatch over the packed rows: the host
+    is paid once, and a decode row pads its own window where it padded
+    a share of the (slots, 1) launch."""
+    run, (a, b) = _sim_run()
+    run.queue.extend([a, b])
+    run._admit_pending()
+    assert run.admit_order == [0, 1]
+    a.prefill_pos, a.pos = a.prefill_target, 2      # slot 0 decodes
+    tick, w = run.tick, run.window
+    pieces = -(-32 // w)
+    cost = run._prefill_tick([1], [0])
+    assert (b.prefill_pos, a.pos) == (32, 3)
+    assert cost == pytest.approx(tick.prefill_tick(
+        32, padded_rows=(pieces + 1) * w - 33, batch=pieces + 1,
+        decode_rows=1))
+    two = (tick.prefill_tick(32, padded_rows=pieces * w - 32, batch=pieces)
+           + tick.decode_dispatch(1, padded_rows=run.slots - 1))
+    assert two - cost == pytest.approx(
+        tick.host_dispatch_s + tick.token_seconds * tick.pad_row_cost
+        * ((run.slots - 1) - (w - 1)))
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["base", "spec"])
+def test_sim_play_launches_once_an_iteration_but_verifies_apart(spec):
+    """`play` hands the chunk's launch the decoding slots, except under
+    speculation: the verify has its own window and stays a second
+    launch (spec/server.py keeps its own loop)."""
+    run, reqs = _sim_run(spec)
+    calls = []
+    pre_tick, dec_tick = run._prefill_tick, run._decode_tick
+    run._prefill_tick = lambda slots, dec=(): (
+        calls.append(("prefill", list(slots), list(dec))),
+        pre_tick(slots, dec))[1]
+    run._decode_tick = lambda dec, mixed: (
+        calls.append(("decode", list(dec), mixed)), dec_tick(dec, mixed))[1]
+    run.play(reqs)
+    assert all(r.done_s is not None and r.pos == 6 for r in reqs)
+    # the first chunk ends a's prompt (8 + 24 of b's 100); from the second
+    # iteration on a decodes beside b's chunks
+    assert calls[0][:2] == ("prefill", [0, 1])
+    if spec:
+        assert calls[1:4] == [("decode", [], True), ("prefill", [1], []),
+                              ("decode", [0], True)]
+    else:
+        assert calls[1] == ("prefill", [1], [0])
+        assert not any(c[0] == "decode" and c[2] for c in calls)
+
+
 def test_sim_megastep_and_spec_strategies_run():
     prof = _profile([0.0, 0.1, 0.2, 0.3], decode=8)
     for strat in (ServeStrategy(page_size=16, megastep_ticks=8),
