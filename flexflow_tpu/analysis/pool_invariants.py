@@ -223,6 +223,39 @@ def _tier_scales(pool, tier_scale_of: Dict[str, int],
     return [f"tier-scales: {m}" for m in v]
 
 
+def _window_class(tables, rows: Dict[int, Tuple[Dict[int, int], int]],
+                  window: int, page_size: int) -> List[str]:
+    """`tables` is the window class's (slots, max_pages) table; `rows`
+    maps a live slot to (its request's {block: page} map, the next row
+    the request writes)."""
+    v = []
+    seen: Dict[int, int] = {}
+    for slot, (held, nxt) in sorted(rows.items()):
+        row = tables[slot]
+        mapped = {int(b): int(row[b]) for b in range(len(row)) if row[b]}
+        if mapped != {int(b): int(p) for b, p in held.items()}:
+            v.append(f"slot {slot}: table maps {mapped}, its request "
+                     f"holds {dict(held)}")
+        for b, page in mapped.items():
+            if page in seen:
+                v.append(f"page {page} is in the tables of slots "
+                         f"{seen[page]} and {slot}")
+            seen[page] = slot
+        # every committed row a query at `nxt` still sees is backed
+        first = max(nxt - window + 1, 0) // page_size
+        last = (nxt - 1) // page_size if nxt > 0 else -1
+        gone = [b for b in range(first, last + 1) if b not in mapped]
+        if gone:
+            v.append(f"slot {slot}: rows of blocks {gone} are inside the "
+                     f"window of row {nxt} and their pages were released")
+    idle = [s for s in range(len(tables)) if s not in rows
+            and tables[s].any()]
+    if idle:
+        v.append(f"slots {idle} hold no request and their tables are "
+                 "not null")
+    return [f"window-class: {m}" for m in v]
+
+
 CATALOG: Tuple[Invariant, ...] = (
     Invariant(
         "free-accounting", "pool",
@@ -275,6 +308,15 @@ CATALOG: Tuple[Invariant, ...] = (
         "it was read from, and a fetch restores both together",
         _tier_scales),
     Invariant(
+        "window-class", "window",
+        "where sliding-window layers have a class of pages of their own "
+        "(a second PagePool, held to every pool-scope entry above): a "
+        "window table maps exactly the pages its request holds, no page "
+        "is in two tables, an idle slot's table is null, and no row "
+        "inside the window of a request's next row lies behind a "
+        "released page",
+        _window_class),
+    Invariant(
         "cow-write", "op",
         "no row write lands in a page the writer does not own, a page "
         "with refcount != 1, or rows a hash-index entry has published "
@@ -304,6 +346,17 @@ def check_pool(pool, owners: Optional[Dict[object, Sequence[int]]] = None
             v += entry.check(pool)
         elif entry.scope == "owners" and owners is not None:
             v += entry.check(pool, owners)
+    return v
+
+
+def check_window_class(tables, rows, window: int, page_size: int
+                       ) -> List[str]:
+    """Run the window-scope invariant over a server's window-class
+    tables (paged/scheduler.py `_check_invariants`)."""
+    v: List[str] = []
+    for entry in CATALOG:
+        if entry.scope == "window":
+            v += entry.check(tables, rows, window, page_size)
     return v
 
 

@@ -421,7 +421,8 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
                       spec_depth: Optional[int] = None,
                       num_pages: Optional[int] = None,
                       kv_dtype: str = "auto",
-                      window_rows: int = PREFILL_WINDOW_ROWS) -> Dict:
+                      window_rows: int = PREFILL_WINDOW_ROWS,
+                      num_pages_window: Optional[int] = None) -> Dict:
     """The closed set of reachable launch shapes per jit entry point for
     ONE served config, plus the config echo `Executor.warm_launch_shapes`
     needs to rebuild the launch arguments (table width, pool size,
@@ -491,6 +492,9 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
             "table_cols": table_cols,
             "kv_dtype": str(kv_dtype),
             "window_rows": int(window_rows),
+            # the window class's pages, where the graph has window layers
+            **({"num_pages_window": int(num_pages_window)}
+               if num_pages_window else {}),
         },
         "entries": entries,
         "total_compilations": sum(e["count"] for e in entries.values()),

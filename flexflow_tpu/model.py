@@ -60,13 +60,13 @@ class Tensor:
         return f"Tensor({self.node.name}:{self.idx} {self.shape})"
 
 
-def _glorot(fan_in: int, fan_out: int):
-    """Glorot-uniform over one matrix's own fans, for a weight that is
-    stored with more than two dims (the default initializer reads a 3-d
-    weight as a convolution's)."""
+def _glorot(fan_in: int, fan_out: int, scale: float = 1.0):
+    """Glorot-uniform over one matrix's own fans (times `scale`), for a
+    weight that is stored with more than two dims (the default
+    initializer reads a 3-d weight as a convolution's)."""
     from flexflow_tpu.runtime.initializer import UniformInitializer
 
-    lim = (6.0 / (fan_in + fan_out)) ** 0.5
+    lim = scale * (6.0 / (fan_in + fan_out)) ** 0.5
     return UniformInitializer(-lim, lim)
 
 
@@ -208,12 +208,20 @@ class FFModel:
                             causal: bool = False, kv_heads: Optional[int] = None,
                             rope: bool = False, rope_theta: float = 10000.0,
                             kernel_initializer=None,
-                            name: Optional[str] = None) -> Tensor:
+                            name: Optional[str] = None,
+                            window: Optional[int] = None,
+                            rope_scaling: Optional[Sequence[float]] = None
+                            ) -> Tensor:
+        """`window` makes the layer sliding-window attention and
+        `rope_scaling` = (factor, original_max, beta_fast, beta_slow,
+        attention_factor) scales its rope by YaRN
+        (A.MultiHeadAttentionAttrs)."""
         node = self._add(
             OpType.MULTIHEAD_ATTENTION,
             A.MultiHeadAttentionAttrs(
                 embed_dim, num_heads, kv_heads, kdim // num_heads if kdim else None,
-                causal, bias, dropout, rope, rope_theta,
+                causal, bias, dropout, rope, rope_theta, window,
+                tuple(rope_scaling) if rope_scaling is not None else None,
             ),
             [query, key, value], name or "attention",
         )
@@ -279,7 +287,8 @@ class FFModel:
         return self._one(
             OpType.RING_ATTENTION,
             A.RingAttentionAttrs(embed_dim, num_heads, kv_heads, None, causal,
-                                 False, 0.0, rope, rope_theta, seq_mode),
+                                 False, 0.0, rope, rope_theta,
+                                 seq_mode=seq_mode),
             [query, key, value], name or "ring_attention",
         )
 

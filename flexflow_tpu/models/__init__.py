@@ -12,6 +12,7 @@ from flexflow_tpu.models.alexnet import build_alexnet
 from flexflow_tpu.models.resnet import build_resnet50
 from flexflow_tpu.models.bert import BertConfig, build_bert
 from flexflow_tpu.models.llama import LlamaConfig, build_llama, llama_tp_strategy
+from flexflow_tpu.models.mellum2 import Mellum2Config, build_mellum2
 from flexflow_tpu.models.mixtral import MixtralConfig, build_mixtral
 from flexflow_tpu.models.dlrm import build_dlrm
 from flexflow_tpu.models.inception import build_inception_v3
@@ -34,6 +35,8 @@ __all__ = [
     "LlamaConfig",
     "build_llama",
     "llama_tp_strategy",
+    "Mellum2Config",
+    "build_mellum2",
     "MixtralConfig",
     "build_mixtral",
     "build_dlrm",

@@ -130,6 +130,17 @@ class Span:
         return False
 
 
+class _Ring(collections.deque):
+    """The events' ring. Iterating it walks a snapshot taken in one C
+    call: a span that was open on another thread when `obs.disable()`
+    returned (a server's loop idles in 1 ms spans) still closes into the
+    ring, and a plain deque then raises "mutated during iteration" at a
+    reader that walks `recorder.events` itself."""
+
+    def __iter__(self):
+        return iter(list(super().__iter__()))
+
+
 class TraceRecorder:
     """Collects span events in memory, owns the TickLedger, and exports
     Chrome-trace JSON. The events are a RING of `max_events`: once full,
@@ -139,14 +150,13 @@ class TraceRecorder:
     idled a minute, or warmed up cold, with no span of its traffic).
     Appends happen from the scheduler thread while readers may export
     from another — all mutation is deque.append / int adds, safe under
-    the GIL, and export snapshots with copy() first."""
+    the GIL, and a reader iterates a snapshot (`_Ring`)."""
 
     def __init__(self, max_events: int = 200_000,
                  annotate_device: bool = True):
         self.max_events = int(max_events)
         # (name, ts_ns, dur_ns, tid, attrs) complete events, newest kept
-        self.events: Deque[tuple] = collections.deque(
-            maxlen=self.max_events)
+        self.events: Deque[tuple] = _Ring(maxlen=self.max_events)
         self.dropped = 0
         # (rid, label, submit_ns, admit_ns, first_ns, done_ns, attrs)
         self.requests: List[tuple] = []
@@ -239,7 +249,7 @@ class TraceRecorder:
              "args": {"name": "fftrace: requests"}},
         ]
         tids = set()
-        for name, t0, dur, tid, attrs in self.events.copy():
+        for name, t0, dur, tid, attrs in self.events:
             tids.add(tid)
             e = {"name": name, "ph": "X", "cat": "tick", "pid": 1,
                  "tid": tid, "ts": self._us(t0 - self.t0_ns),

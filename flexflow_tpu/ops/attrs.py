@@ -285,6 +285,23 @@ class MultiHeadAttentionAttrs(OpAttrs):
     # rotary position embeddings (TPU-native addition for the Llama family)
     rope: bool = False
     rope_theta: float = 10000.0
+    # sliding window: key j is visible to query i iff j <= i and
+    # i - j < window (the query itself and the window - 1 rows before
+    # it). None is full attention. A model may mix both by layer.
+    window: Optional[int] = None
+    # YaRN on the rope's frequencies, (factor, original_max, beta_fast,
+    # beta_slow, attention_factor): `yarn_inv_freq` blends theta^(-2i/d)
+    # with the same over `factor` between the two correction dims, and
+    # cos and sin are both multiplied by attention_factor. None: plain.
+    rope_scaling: Optional[Tuple[float, int, float, float, float]] = None
+
+    def __post_init__(self):
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.window is not None and not self.causal:
+            raise ValueError("a sliding window is causal: pass causal=True")
+        if self.rope_scaling is not None and not self.rope:
+            raise ValueError("rope_scaling needs rope=True")
 
     @property
     def kdim(self) -> int:

@@ -11,10 +11,12 @@ when profitable.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from flexflow_tpu.ffconst import ActiMode, AggrMode, OpType, PoolType
@@ -118,7 +120,33 @@ def _batch_matmul(attrs, inputs, params, ctx):
 # attention
 
 
-def apply_rope(x, theta: float, pos_offset=0):
+def yarn_inv_freq(theta: float, head_dim: int, scaling) -> np.ndarray:
+    """The rope's head_dim / 2 frequencies under YaRN, float32. `scaling`
+    is MultiHeadAttentionAttrs.rope_scaling, (factor, original_max,
+    beta_fast, beta_slow, attention_factor): pair i keeps theta^(-2i/d)
+    where it turns more than beta_fast times over the original context,
+    takes the same over `factor` where it turns fewer than beta_slow
+    times, and a linear ramp of the two between the correction dims
+    (d / 2) ln(original_max / (2 pi beta)) / ln theta, floored and
+    ceiled and clipped to [0, d / 2 - 1]."""
+    factor, original_max, beta_fast, beta_slow, _ = scaling
+    d2 = head_dim // 2
+    extrap = float(theta) ** (-np.arange(d2, dtype=np.float64) / d2)
+
+    def dim_of(turns):
+        return (d2 * math.log(original_max / (turns * 2 * math.pi))
+                / math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), d2 - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d2) - low) / (high - low), 0.0, 1.0)
+    return (extrap / factor * ramp + extrap * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def apply_rope(x, theta: float, pos_offset=0, scaling=None):
     """Rotary position embedding, half-split (rotate_half) convention.
     x: (B, S, H, D). `pos_offset` is a scalar, a (B,) vector of per-row
     offsets (continuous-batching decode: every slot sits at its own
@@ -131,12 +159,19 @@ def apply_rope(x, theta: float, pos_offset=0):
     (B,S,H,D) tensor to fp32 made the backward materialize fp32 cotangent
     converts+relayouts (~1.3 GB/step at the 1b bench config,
     tools/hlo_transpose_audit.py); rotation values are in [-1,1] so bf16
-    rotation costs ~2^-8 relative error — far below bf16 matmul noise."""
+    rotation costs ~2^-8 relative error — far below bf16 matmul noise.
+
+    `scaling` (MultiHeadAttentionAttrs.rope_scaling) takes the
+    frequencies from `yarn_inv_freq` and multiplies cos and sin by its
+    attention_factor; None is the plain rope."""
     B, S, H, D = x.shape
     if D % 2 != 0:
         raise ValueError(f"RoPE requires an even head dim, got {D}")
     d2 = D // 2
-    freqs = theta ** (-jnp.arange(0, d2, dtype=jnp.float32) / d2)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, d2, dtype=jnp.float32) / d2)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(theta, D, scaling))
     off = jnp.asarray(pos_offset, jnp.float32)
     if off.ndim == 2:
         pos = off                                          # (B, S) absolute
@@ -144,8 +179,12 @@ def apply_rope(x, theta: float, pos_offset=0):
         off = off.reshape(-1, 1)                           # (B|1, 1)
         pos = jnp.arange(S, dtype=jnp.float32)[None, :] + off  # (B|1, S)
     ang = pos[:, :, None] * freqs[None, None, :]  # (B|1, S, d2)
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    if scaling is None:
+        cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
+        sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    else:
+        cos = (jnp.cos(ang) * scaling[4])[:, :, None, :].astype(x.dtype)
+        sin = (jnp.sin(ang) * scaling[4])[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :d2], x[..., d2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1)
@@ -281,7 +320,7 @@ LAST_ATTENTION_KERNEL = "none"
 
 
 def cached_attention(q, k, v, cache_k, cache_v, pos, *, scale,
-                     rope_theta=None):
+                     rope_theta=None, rope_scaling=None, window=None):
     """Autoregressive decode/prefill step shared by MHA, ring attention,
     and the PIPELINE composite: rope at absolute positions (when
     `rope_theta`), append k/v into the cache at `pos`, attend over
@@ -289,14 +328,15 @@ def cached_attention(q, k, v, cache_k, cache_v, pos, *, scale,
     (slots past the write head stay masked). `pos` is a scalar for
     lockstep generate() or a (B,) vector for continuous batching (each
     slot decodes at its own depth; a freshly admitted slot's stale cache
-    rows sit at kpos > qpos until overwritten).
+    rows sit at kpos > qpos until overwritten). A sliding `window` also
+    masks the rows at or beyond `window` before the query.
 
     Returns (attention output, new k cache, new v cache)."""
     dt = q.dtype
     pos_v = jnp.asarray(pos)
     if rope_theta is not None:
-        q = apply_rope(q, rope_theta, pos_offset=pos)
-        k = apply_rope(k, rope_theta, pos_offset=pos)
+        q = apply_rope(q, rope_theta, pos_offset=pos, scaling=rope_scaling)
+        k = apply_rope(k, rope_theta, pos_offset=pos, scaling=rope_scaling)
     if pos_v.ndim == 0:
         kc = lax.dynamic_update_slice(
             cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0)
@@ -307,6 +347,8 @@ def cached_attention(q, k, v, cache_k, cache_v, pos, *, scale,
         qpos = pos + jnp.arange(q.shape[1])      # absolute q positions
         kpos = jnp.arange(kc.shape[1])           # cache slots
         mask = kpos[None, :] <= qpos[:, None]
+        if window is not None:
+            mask &= qpos[:, None] - kpos[None, :] < window
     else:
         def write_row(cache_row, new_row, p):
             return lax.dynamic_update_slice(cache_row, new_row, (p, 0, 0))
@@ -316,6 +358,8 @@ def cached_attention(q, k, v, cache_k, cache_v, pos, *, scale,
         qpos = pos_v[:, None] + jnp.arange(q.shape[1])[None, :]  # (B,S)
         kpos = jnp.arange(kc.shape[1])
         mask = kpos[None, None, :] <= qpos[:, :, None]           # (B,S,T)
+        if window is not None:
+            mask &= qpos[:, :, None] - kpos[None, None, :] < window
     out = _dot_product_attention(
         q, kc.astype(dt), vc.astype(dt), causal=False,
         scale=scale, mask=mask,
@@ -337,6 +381,13 @@ def _mha(attrs, inputs, params, ctx):
         q = q + params["bq"].astype(dt)
         k = k + params["bk"].astype(dt)
         v = v + params["bv"].astype(dt)
+    rope_theta = attrs.rope_theta if attrs.rope else None
+    # a full layer with a plain rope calls the cache paths as it always
+    # has: its programs do not change with what other layers can do
+    rope_kw = {}
+    if attrs.window is not None or attrs.rope_scaling is not None:
+        rope_kw = {"window": attrs.window,
+                   "rope_scaling": attrs.rope_scaling}
     if ctx.kv_cache is not None:
         if ctx.page_tables is not None:
             # every paged step — decode, chunked-prefill chunk, spec
@@ -357,10 +408,9 @@ def _mha(attrs, inputs, params, ctx):
                     q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
                     ctx.page_tables, ctx.cache_position,
                     ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=1.0 / (hd**0.5),
-                    rope_theta=attrs.rope_theta if attrs.rope else None,
+                    scale=1.0 / (hd**0.5), rope_theta=rope_theta,
                     k_scales=ctx.kv_cache["k_scale"],
-                    v_scales=ctx.kv_cache["v_scale"],
+                    v_scales=ctx.kv_cache["v_scale"], **rope_kw,
                 )
                 ctx.cache_updates["k_scale"] = ks
                 ctx.cache_updates["v_scale"] = vs
@@ -369,27 +419,38 @@ def _mha(attrs, inputs, params, ctx):
                     q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
                     ctx.page_tables, ctx.cache_position,
                     ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=1.0 / (hd**0.5),
-                    rope_theta=attrs.rope_theta if attrs.rope else None,
+                    scale=1.0 / (hd**0.5), rope_theta=rope_theta, **rope_kw,
                 )
         else:
             out, kc, vc = cached_attention(
                 q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
                 ctx.cache_position, scale=1.0 / (hd**0.5),
-                rope_theta=attrs.rope_theta if attrs.rope else None,
+                rope_theta=rope_theta, **rope_kw,
             )
         ctx.cache_updates["k"] = kc
         ctx.cache_updates["v"] = vc
     else:
         if attrs.rope:
-            q = apply_rope(q, attrs.rope_theta)
-            k = apply_rope(k, attrs.rope_theta)
+            q = apply_rope(q, attrs.rope_theta, scaling=attrs.rope_scaling)
+            k = apply_rope(k, attrs.rope_theta, scaling=attrs.rope_scaling)
         drop_rng = ctx.rng if (ctx.training and attrs.dropout > 0.0) else None
-        out = fused_attention(
-            q, k, v, causal=attrs.causal, scale=1.0 / (hd**0.5),
-            dropout=attrs.dropout if ctx.training else 0.0,
-            dropout_rng=drop_rng, mesh=ctx.mesh,
-        )
+        if attrs.window is not None:
+            # the flash kernels have no window: the masked einsum (the
+            # S x S scores in HBM; training this layer type at length is
+            # not what it is for)
+            i = jnp.arange(q.shape[1])
+            seen = (i[None, :] <= i[:, None]) & (
+                i[:, None] - i[None, :] < attrs.window)
+            out = _dot_product_attention(
+                q, k, v, False, 1.0 / (hd**0.5),
+                dropout_rate=attrs.dropout if ctx.training else 0.0,
+                dropout_rng=drop_rng, mask=seen)
+        else:
+            out = fused_attention(
+                q, k, v, causal=attrs.causal, scale=1.0 / (hd**0.5),
+                dropout=attrs.dropout if ctx.training else 0.0,
+                dropout_rng=drop_rng, mesh=ctx.mesh,
+            )
     y = attn_out_project(out, params["wo"], dt)
     if attrs.use_bias:
         y = y + params["bo"].astype(dt)

@@ -156,7 +156,7 @@ def paged_attention_available(head_dim: int, page_size: int,
 
 
 def ragged_visibility_mask(page_tables, pos, q_lens, anc_mask,
-                           page_size: int):
+                           page_size: int, window: Optional[int] = None):
     """(B, S, L) bool visibility, L = max_pages x P: the REFERENCE
     semantics both paths implement. Cache row kpos is visible to query
     row t of slot b when it is committed (kpos < pos[b]) or lies in the
@@ -165,7 +165,11 @@ def ragged_visibility_mask(page_tables, pos, q_lens, anc_mask,
     window rows past q_len, stale rows from earlier wider launches, the
     null page — stays masked. Chunks pass a lower-triangular anc_mask
     (causal within the chunk); trees pass the ancestor-or-self
-    relation; decode is the S=1 special case of either."""
+    relation; decode is the S=1 special case of either. A sliding
+    `window` layer also hides every row at or beyond `window` before
+    query row t's own cache row pos[b] + t (a window layer's launches
+    are causal chains: row t scores at that position), which is where
+    its table may already point at the null page."""
     B, S, _ = anc_mask.shape
     L = page_tables.shape[1] * page_size
     kpos = jnp.arange(L)
@@ -173,7 +177,10 @@ def ragged_visibility_mask(page_tables, pos, q_lens, anc_mask,
                            (B, S, L))
     in_window = (rel >= 0) & (rel < q_lens[:, None, None])
     anc = jnp.take_along_axis(anc_mask, jnp.clip(rel, 0, S - 1), axis=2)
-    return (kpos[None, None, :] < pos[:, None, None]) | (in_window & anc)
+    seen = (kpos[None, None, :] < pos[:, None, None]) | (in_window & anc)
+    if window is not None:
+        seen &= jnp.arange(S)[None, :, None] - rel < window
+    return seen
 
 
 def tree_visibility_mask(page_tables, pos, anc_mask, page_size: int):
@@ -188,7 +195,8 @@ def tree_visibility_mask(page_tables, pos, anc_mask, page_size: int):
 
 def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
                             q_lens, anc_mask, *, scale: float,
-                            k_scales=None, v_scales=None):
+                            k_scales=None, v_scales=None,
+                            window: Optional[int] = None):
     """Pure-JAX fallback AND numerical reference for the ragged kernel:
     gather every table-mapped page (`pool[page_table]`) and run dense
     masked dot-product attention under ragged_visibility_mask. q:
@@ -217,7 +225,8 @@ def ragged_gather_attention(q, kc_pages, vc_pages, page_tables, pos,
         vg = vc_pages[page_tables]
     kg = kg.reshape(B, -1, Hkv, D)
     vg = vg.reshape(B, -1, Hkv, D)
-    mask = ragged_visibility_mask(page_tables, pos, q_lens, anc_mask, P)
+    mask = ragged_visibility_mask(page_tables, pos, q_lens, anc_mask, P,
+                                  window)
     from flexflow_tpu.ops.jax_ops import _dot_product_attention
 
     if k_scales is not None:
@@ -289,7 +298,7 @@ def ragged_block_pages(page_size: int, table_width: int, lane_width: int,
 
 
 def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
-                   scale, page_size, ppb, rep, quantized):
+                   scale, page_size, ppb, rep, quantized, sliding=None):
     """One batch entry a grid step: walk the entry's live pages in
     blocks of `ppb`, each page ONE contiguous (P, Hkv * D) copy from its
     pooled HBM row into a double-buffered VMEM block, the kv heads lane
@@ -297,7 +306,14 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
     (row = window row x rep + head of the group), so a head's scores are
     one (rows, D) x (D, keys) matmul. The next block — the next ENTRY's
     first block after the last — is in flight while this one computes;
-    which buffer holds it survives the grid step in SMEM."""
+    which buffer holds it survives the grid step in SMEM.
+
+    A sliding window of `sliding` rows (a static of the call) moves the walk's FIRST
+    block: it starts at the page that holds row pos - window + 1, the
+    oldest row the entry's first query sees, and blocks that hold rows
+    older than a query's window mask them by position. The pages before
+    that one are never read, and the window class of the pool has
+    released them (paged/scheduler.py)."""
     if quantized:
         (ks_ref, vs_ref, anc_ref, o_ref, kbuf, vbuf, sems, par_ref,
          bias_scr, m_scr, l_scr, acc_scr) = rest
@@ -318,9 +334,17 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
         n = jnp.minimum((horizon + page_size - 1) // page_size, n_table)
         return jnp.where(qlen_ref[e] > 0, n, 0)
 
+    def first_page(e):
+        # the page of the oldest row entry e's first query sees
+        return jnp.where(
+            qlen_ref[e] > 0,
+            jnp.maximum(pos_ref[e] - (sliding - 1), 0) // page_size, 0)
+
     def block_copies(e, j, buf, fn):
         """fn(copy) for the K and V copies of entry e's block j."""
         first = j * ppb
+        if sliding is not None:
+            first = first + first_page(e)
         n = jnp.clip(live_pages(e) - first, 0, ppb)
 
         def one(i, _):
@@ -347,7 +371,11 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
 
     pos = pos_ref[b]
     qlen = qlen_ref[b]
-    n_blocks = (live_pages(b) + ppb - 1) // ppb
+    if sliding is None:
+        n_blocks = (live_pages(b) + ppb - 1) // ppb
+    else:
+        n_blocks = (live_pages(b) - first_page(b) + ppb - 1) // ppb
+        key0 = first_page(b) * page_size
     par = par_ref[0]
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -375,8 +403,20 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
         start_next(j, 1 - buf)
         block_copies(b, j, buf, lambda c: c.wait())
         first_key = j * keys
+        if sliding is not None:
+            first_key = first_key + key0
+        at_window = first_key + keys > pos
+        if sliding is not None:
+            # a block that holds rows older than the LAST query's window
+            # masks by position too; one between the two masks nothing
+            behind = first_key <= pos + qlen - 1 - sliding
+            at_window = at_window | behind
 
-        @pl.when(first_key + keys > pos)
+            @pl.when(jnp.logical_not(at_window))
+            def _():
+                bias_scr[...] = jnp.zeros_like(bias_scr)
+
+        @pl.when(at_window)
         def _():
             # the window's visibility without a gather and without an
             # HBM mask: column c holds cache row first_key + c, window
@@ -394,7 +434,14 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
                 preferred_element_type=jnp.float32) > 0.5  # (rows, keys)
             col = first_key + lax.broadcasted_iota(
                 jnp.int32, (rows, keys), 1)
-            bias_scr[...] = jnp.where((col < pos) | tree_vis, 0.0, NEG_INF)
+            seen = (col < pos) | tree_vis
+            if sliding is not None:
+                # folded row i is window row t = i // rep at cache row
+                # pos + t: seen iff pos + t - col < sliding, that is
+                # i < (col - pos + sliding) * rep
+                row = lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+                seen = seen & (row < (col - pos + sliding) * rep)
+            bias_scr[...] = jnp.where(seen, 0.0, NEG_INF)
 
         bias = bias_scr[...]
         # quantized pool: the int8 page is what DMA'd from HBM and what
@@ -454,11 +501,12 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
     lax.fori_loop(0, n_heads, flush, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                            q_lens, anc_mask, *, scale: float,
                            interpret: bool = False, k_scales=None,
-                           v_scales=None):
+                           v_scales=None, window: Optional[int] = None):
     """The ragged Pallas launch. q: (B, S, H, D) — S is the launch's
     window width, per-entry real work is q_lens[b] <= S rows;
     kc/vc_pages: (N, P, Hkv*D) flat-lane pages (module docstring);
@@ -476,17 +524,21 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
     * Hkv * P floats, what the per-page blocks held before), one
     (Hkv, keys) block a walk block. Rows at or past q_lens[b] output
     zeros. Jitted so that the layers of a model trace and lower ONE
-    kernel a launch shape."""
+    kernel a launch shape (two where window and full layers mix:
+    `window` is a static of the call)."""
     B, S, H, D = q.shape
     P = kc_pages.shape[1]
     Hkv = kc_pages.shape[2] // D
     rep = H // Hkv
     n_pages = page_tables.shape[1]
     rows = _round_up(rep * S, 8 * (4 // q.dtype.itemsize))
-    window = _round_up(S, LANES)
+    wcols = _round_up(S, LANES)     # the window, a whole lane tile
     ppb = ragged_block_pages(P, n_pages, Hkv * D, kc_pages.dtype, rep * S)
     keys = ppb * P
     quantized = k_scales is not None
+    if quantized and window is not None:
+        raise ValueError("a window layer's pool is not quantized: the "
+                         "scale blocks follow the table from its start")
     # (B, S, Hkv, rep, D) -> (B, Hkv, S * rep, D): a kv group's heads
     # are adjacent rows of one tile
     qr = q.reshape(B, S, Hkv, rep, D).transpose(0, 2, 1, 3, 4).reshape(
@@ -494,7 +546,7 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
     qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - S * rep), (0, 0)))
     anc_f = jnp.pad(
         jnp.repeat(anc_mask, rep, axis=1).astype(jnp.bfloat16),
-        ((0, 0), (0, rows - S * rep), (0, window - S)))
+        ((0, 0), (0, rows - S * rep), (0, wcols - S)))
 
     qmap = lambda b, pt, ps, ql: (b, 0, 0, 0)               # noqa: E731
     in_specs = [
@@ -515,7 +567,7 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                 rows_sc[..., None],
                 (B, n_blocks, Hkv, ppb, P)).reshape(B, n_blocks, Hkv, keys))
             in_specs.append(pl.BlockSpec((None, n_blocks, Hkv, keys), qmap))
-    in_specs.append(pl.BlockSpec((None, rows, window),
+    in_specs.append(pl.BlockSpec((None, rows, wcols),
                                  lambda b, pt, ps, ql: (b, 0, 0)))
     operands.append(anc_f)
 
@@ -535,9 +587,10 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
             pltpu.VMEM((Hkv, rows, D), jnp.float32),
         ],
     )
+    static = {} if window is None else {"sliding": int(window)}
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=scale, page_size=P,
-                          ppb=ppb, rep=rep, quantized=quantized),
+                          ppb=ppb, rep=rep, quantized=quantized, **static),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
         # the walk carries its buffer parity and an in-flight copy from
@@ -559,7 +612,9 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
 def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
                            q_lens, depths, anc_mask, *, scale: float,
                            rope_theta: Optional[float] = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None,
+                           window: Optional[int] = None,
+                           rope_scaling=None):
     """The single paged-attention step every caller lowers to — decode,
     chunked prefill and tree verify are the same call with different
     descriptors (module docstring). Ropes q/k at pos + depths, scatters
@@ -573,6 +628,12 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
     becomes quantize-on-append under grow-only per-(page, head) scales
     (paged/quant.py): the roped fp rows never reach HBM, and BOTH
     attention paths dequantize on load.
+
+    `window` makes this a sliding-window layer: `page_tables` is then
+    the WINDOW class's table, whose entries behind a request's window
+    are the null page, and neither path reads them (the kernel's walk
+    starts at the window, the gather masks by position). `rope_scaling`
+    is the op's YaRN tuple (ops/jax_ops.py apply_rope).
 
     Returns (attention output, new k pool, new v pool) — plus
     (new k_scales, new v_scales) in the quantized case. Output rows at
@@ -588,8 +649,10 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
     qlen_v = jnp.asarray(q_lens)
     if rope_theta is not None:
         positions = pos_v[:, None] + depths                # (B, S)
-        q = apply_rope(q, rope_theta, pos_offset=positions)
-        k = apply_rope(k, rope_theta, pos_offset=positions)
+        q = apply_rope(q, rope_theta, pos_offset=positions,
+                       scaling=rope_scaling)
+        k = apply_rope(k, rope_theta, pos_offset=positions,
+                       scaling=rope_scaling)
     L = page_tables.shape[1] * P
     rows = pos_v[:, None] + jnp.arange(S)[None, :]         # (B, S)
     safe = jnp.minimum(rows, L - 1)
@@ -614,11 +677,13 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
         out = ragged_flash_attention(q, kc, vc, page_tables, pos_v,
                                      qlen_v, anc_mask, scale=scale,
                                      interpret=force_interp,
-                                     k_scales=ks, v_scales=vs)
+                                     k_scales=ks, v_scales=vs,
+                                     window=window)
     else:
         out = ragged_gather_attention(q, kc, vc, page_tables, pos_v,
                                       qlen_v, anc_mask, scale=scale,
-                                      k_scales=ks, v_scales=vs)
+                                      k_scales=ks, v_scales=vs,
+                                      window=window)
     if k_scales is not None:
         return out, kc, vc, ks, vs
     return out, kc, vc

@@ -102,7 +102,7 @@ class PagedGenerationServer(_GenerationServerBase):
                  slo=None, slo_dump_dir: Optional[str] = None,
                  kv_quant_canary: Optional[int] = None,
                  serve_strategy=None, defer_start: bool = False,
-                 host_tier=None):
+                 host_tier=None, num_pages_window: Optional[int] = None):
         import jax
 
         super().__init__(ff, slots, max_len, eos_id, seed,
@@ -128,6 +128,34 @@ class PagedGenerationServer(_GenerationServerBase):
         self.preemption = bool(preemption)
         self.prefix_cache = bool(prefix_cache)
         self.prefill_chunk = max(1, int(prefill_chunk))
+        # TWO CLASSES OF PAGES where the graph has sliding-window layers
+        # (decided by the graph, Executor.page_classes): `pool` is the
+        # full class, whose layers keep every row of a request, and
+        # `pool_w` the window class, whose layers hold a request's last
+        # `window` rows and the chunk being written: its pages behind the
+        # window go back to its free list after every launch, so it is
+        # sized slots x (window + prefill_chunk + one page), not slots x
+        # max_len, and a request has a table in each class. A graph
+        # without window layers has `pool_w` None and one table, as ever.
+        self._window = int(ff.executor.window_rows())
+        self.pool_w: Optional[PagePool] = None
+        self._tables_w = None
+        self.window_pages_released = 0
+        self._released_at_launch = 0
+        if self._window:
+            self._w_slot_pages = min(
+                -(-(self._window + self.prefill_chunk) // self.page_size)
+                + 1, self.max_pages_per_seq)
+            if num_pages_window is None:
+                num_pages_window = self.slots * self._w_slot_pages + 1
+            self.pool_w = PagePool(int(num_pages_window), self.page_size,
+                                   self.max_pages_per_seq)
+            self._tables_w = np.zeros(
+                (self.slots, self.max_pages_per_seq), np.int32)
+        elif num_pages_window is not None:
+            raise ValueError(
+                "num_pages_window sizes the window class of pages; this "
+                "graph has no sliding-window layer")
         # packed prefill windows are capped at this many rows (the fp32
         # sublane tile): chunks larger than it split into pieces, so
         # launch shapes stay within a small (n_items, window<=8) family
@@ -231,8 +259,10 @@ class PagedGenerationServer(_GenerationServerBase):
         # None = dirty, rebuilt from host truth on next dispatch
         self._seq_cols = self.max_pages_per_seq * self.page_size
         self._seq_dev = None
-        self._caches = ex.init_paged_kv_cache(num_pages, self.page_size,
-                                              dtype=pool_dt)
+        self._caches = ex.init_paged_kv_cache(
+            num_pages, self.page_size, dtype=pool_dt,
+            num_pages_window=(self.pool_w.num_pages if self._window
+                              else None))
         self._caches_ref = (ex.init_paged_kv_cache(
             num_pages, self.page_size, dtype=jax.numpy.float32)
             if self._kv_quant_debug else None)
@@ -366,6 +396,16 @@ class PagedGenerationServer(_GenerationServerBase):
             b.shape[2] * b.dtype.itemsize
             for bufs in self._caches.values()
             for n, b in bufs.items() if not n.endswith("_scale"))
+        if self._window:
+            # bytes of ONE page over the layers of each class, for the
+            # launch spans' pool_bytes_* (tracing only)
+            classes = ex.page_classes()
+            per_class = [0, 0]
+            for nk, bufs in self._caches.items():
+                per_class[classes[nk]] += sum(
+                    b.shape[1] * b.shape[2] * b.dtype.itemsize
+                    for b in bufs.values())
+            self._page_bytes_full, self._page_bytes_window = per_class
         # expert layers' launch counters (ops/expert_share.py STATS):
         # device arrays of launches not yet read, folded into host totals
         # where the host waits for the device anyway
@@ -511,6 +551,8 @@ class PagedGenerationServer(_GenerationServerBase):
             "num_pages": self.pool.num_pages,  # fflint: lock-ok (immutable)
             "kv_dtype": self.kv_dtype,
             "window_rows": self._chunk_rows,
+            **({"num_pages_window": self.pool_w.num_pages}
+               if self._window else {}),
         }
 
     # -- capacity ---------------------------------------------------------
@@ -530,6 +572,12 @@ class PagedGenerationServer(_GenerationServerBase):
                 f"({len(prompt)}+{max_new_tokens} tokens, page_size="
                 f"{self.page_size}) but the pool only holds "
                 f"{self.pool.capacity}; raise num_pages")
+        if self._window and min(need, self._w_slot_pages) > \
+                self.pool_w.capacity:
+            raise ValueError(
+                f"request needs {min(need, self._w_slot_pages)} "
+                f"window-class pages at its longest but that class only "
+                f"holds {self.pool_w.capacity}; raise num_pages_window")
 
     def metrics(self) -> dict:  # fflint: lock-ok (relaxed metrics snapshot; int/float reads are atomic, staleness is fine for scraping)
         """Aggregate serving metrics + the per-request records of the
@@ -601,6 +649,15 @@ class PagedGenerationServer(_GenerationServerBase):
                 "evictions": pool.evictions,
             },
         })
+        if self._window:
+            m["page_classes"] = {
+                "full": {"pages_in_use": pool.pages_in_use,
+                         "free_pages": pool.free_pages},
+                "window": {"pages_in_use": self.pool_w.pages_in_use,
+                           "free_pages": self.pool_w.free_pages,
+                           "window": self._window,
+                           "released": self.window_pages_released},
+            }
         # host-tier block + registry sync: counters follow THIS pool's
         # spill/fetch truth (a shared tier's totals aggregate producers;
         # per-server counters must not double-count), gauges follow the
@@ -802,6 +859,7 @@ class PagedGenerationServer(_GenerationServerBase):
         self.pool.free(list(reversed(req.pages)))
         req.pages = []
         self._tables[slot] = 0
+        self._free_window_pages(slot, req)
         self._mark_tables_dirty()
         self._mark_temps_dirty()
         if slot in self._admit_order:
@@ -821,6 +879,7 @@ class PagedGenerationServer(_GenerationServerBase):
         self._publish_tail(req)
         self.pool.free(list(reversed(req.pages)))  # leaf-first (see above)
         req.pages = []
+        self._free_window_pages(slot, req)
         self._reset_prefill_state(req)
         self._tables[slot] = 0
         self._mark_tables_dirty()
@@ -885,6 +944,7 @@ class PagedGenerationServer(_GenerationServerBase):
             self._publish_tail(req)
             self.pool.free(list(reversed(req.pages)))  # leaf-first
             req.pages = []
+            self._free_window_pages(slot, req)
             self._reset_prefill_state(req)
             self._tables[slot] = 0
             self._active[slot] = None
@@ -928,7 +988,8 @@ class PagedGenerationServer(_GenerationServerBase):
         # defer_start, and the caller already joined the predecessor's
         # loop via detach_for_swap — nothing mutates either server
         # during the geometry comparison
-        same = (self.page_size == old.page_size
+        same = (not self._window and not old._window
+                and self.page_size == old.page_size
                 and self.pool.num_pages  # fflint: lock-ok (loops joined)
                 == old.pool.num_pages
                 and self.max_pages_per_seq == old.max_pages_per_seq
@@ -1124,6 +1185,118 @@ class PagedGenerationServer(_GenerationServerBase):
     def _push_back(self, req: _GenRequest):
         self._requeue.insert(0, req)
 
+    # -- the window class of pages ----------------------------------------
+
+    def _window_peak(self, req: _GenRequest) -> int:
+        """Window-class pages a request holds at its most: its whole
+        length while that is short, else a window, a chunk and a page."""
+        return min(self.pool.pages_for(self._peak_rows(len(req.prompt),
+                                                       req.max_new)),
+                   self._w_slot_pages)
+
+    def _next_rows(self, req: _GenRequest) -> tuple:
+        """(first row the request's next launch writes, how many it may
+        write): a mid-prefill request's next chunk at its largest, a
+        decoding one's next token."""
+        if req.prefill_pos < req.prefill_target:
+            return req.prefill_pos, min(self.prefill_chunk,
+                                        req.prefill_target - req.prefill_pos)
+        return req.pos, 1
+
+    def _window_first_block(self, row: int) -> int:
+        """The block of the oldest row a query at `row` sees in a window
+        layer; every block before it is behind the window."""
+        return max(row - self._window + 1, 0) // self.page_size
+
+    def _window_target(self, req: _GenRequest) -> range:
+        """The blocks a request's window table must map before its next
+        launch: from its next row's window to that launch's last row."""
+        row, n = self._next_rows(req)
+        last = min((row + n - 1) // self.page_size,
+                   self.max_pages_per_seq - 1)
+        return range(self._window_first_block(row), last + 1)
+
+    def _release_window_pages(self, slot: int, req: _GenRequest):
+        """After a launch is dispatched: the window-class pages that lie
+        wholly behind the window of the request's NEXT row go back to
+        their class's free list, and their table entries to the null
+        page. The launch just dispatched still reads them, through the
+        table it was given, and runs before any launch that writes them
+        again for another request."""
+        if not self._window or not req.window_pages:
+            return
+        first = self._window_first_block(self._next_rows(req)[0])
+        behind = [b for b in req.window_pages if b < first]
+        if not behind:
+            return
+        self.pool_w.free([req.window_pages.pop(b) for b in behind])
+        self._tables_w[slot, behind] = 0
+        self.window_pages_released += len(behind)
+        self._mark_tables_dirty()
+
+    def _free_window_pages(self, slot: int, req: _GenRequest):
+        """A request leaves its slot (finished, preempted, carried over):
+        its window-class pages go back and its table row is nulled. A
+        requeued request recomputes its window rows with the rest."""
+        if not self._window:
+            return
+        self.pool_w.free(list(req.window_pages.values()))
+        req.window_pages = {}
+        self._tables_w[slot] = 0
+
+    def _grow_window_pages(self, slot: int, req: _GenRequest) -> bool:
+        """Map the blocks the request's next launch needs and does not
+        hold yet. False when the window class cannot give them (the
+        caller preempts, as for the full class)."""
+        need = [b for b in self._window_target(req)
+                if b not in req.window_pages]
+        if not need:
+            return True
+        got = self.pool_w.alloc(len(need))
+        if got is None:
+            return False
+        for b, page in zip(need, got):
+            req.window_pages[b] = page
+            self._tables_w[slot, b] = page
+        self._mark_tables_dirty()
+        return True
+
+    def _window_debt(self) -> int:
+        """Window-class pages the live requests may still take: each is
+        owed up to its peak (admission must not hand those out)."""
+        debt = 0
+        for s in self._admit_order:
+            req = self._active[s]
+            if req is not None:
+                debt += max(0, self._window_peak(req)
+                            - len(req.window_pages))
+        return debt
+
+    def _check_invariants(self):
+        """Debug hook for the loop's own thread (a test's wrapper of
+        `_launch`; too hot for serving): both classes of pages against
+        the invariant catalog, each with its owners, and the window
+        class's tables against its requests (analysis/
+        pool_invariants.py `check_window_class`)."""
+        live = {s: self._active[s] for s in self._admit_order
+                if self._active[s] is not None}
+        self.pool.check_invariants(
+            {r.seq: r.pages for r in live.values()})
+        if not self._window:
+            return
+        from flexflow_tpu.analysis import pool_invariants
+
+        self.pool_w.check_invariants(
+            {r.seq: list(r.window_pages.values()) for r in live.values()})
+        rows = {s: (r.window_pages, self._next_rows(r)[0])
+                for s, r in live.items()}
+        violations = pool_invariants.check_window_class(
+            self._tables_w, rows, self._window, self.page_size)
+        if violations:
+            raise AssertionError(
+                "window-class invariant violation(s):\n  "
+                + "\n  ".join(violations))
+
     # -- page growth / preemption ----------------------------------------
 
     def _pages_target(self, req: _GenRequest) -> int:
@@ -1153,17 +1326,28 @@ class PagedGenerationServer(_GenerationServerBase):
                     self._tables[slot, len(req.pages) - 1] = got[0]
                     self._mark_tables_dirty()
                     continue
-                victims = [s for s in self._admit_order if s != slot]
-                if self.preemption and victims:
-                    self._evict(victims[-1])  # youngest other request
-                else:
-                    self._evict(slot)  # stall self until pages free up
-                    break
+                self._preempt_for(slot)
+            # the window class's blocks for the next launch, the same way
+            while (self._window and req is self._active[slot]
+                   and not self._grow_window_pages(slot, req)):
+                self._preempt_for(slot)
+
+    def _preempt_for(self, slot: int):
+        """A class of pages cannot give `slot` what its next tick needs:
+        evict the youngest OTHER request, or the starved one itself."""
+        victims = [s for s in self._admit_order if s != slot]
+        if self.preemption and victims:
+            self._evict(victims[-1])  # youngest other request
+        else:
+            self._evict(slot)  # stall self until pages free up
 
     def _apply_defrag(self):
-        import jax
-
-        perm, old_to_new = self.pool.defrag()
+        # each class of pages is compacted by itself (one class unless
+        # the graph has window layers) and each node's leaves are
+        # gathered by their own class's permutation
+        classes = self.ff.executor.page_classes() or {}
+        pools = (self.pool, self.pool_w) if self._window else (self.pool,)
+        perms, remaps = zip(*(pool.defrag() for pool in pools))
         # the gather covers every leaf of each node's dict — a quantized
         # pool's (num_pages, Hkv) scale sidecar permutes on the same
         # axis 0 as its pages, so scales follow pages through compaction.
@@ -1172,7 +1356,8 @@ class PagedGenerationServer(_GenerationServerBase):
         # made, so the old leaf goes then and compaction holds one leaf
         # twice, never a second pool
         for caches in (self._caches, self._caches_ref):
-            for bufs in (caches or {}).values():
+            for nk, bufs in (caches or {}).items():
+                perm = perms[classes.get(nk, 0)]
                 for name in bufs:
                     bufs[name] = bufs[name][perm]
         # EVERY owner's table: the (slots, max_pages) matrix rewrite
@@ -1180,12 +1365,16 @@ class PagedGenerationServer(_GenerationServerBase):
         # pages get the same new id in every owner's row because
         # old_to_new is one global map. The pool rewrote the hash index
         # and LRU inside defrag().
-        self._tables = old_to_new[self._tables]
+        self._tables = remaps[0][self._tables]
+        if self._window:
+            self._tables_w = remaps[1][self._tables_w]
         self._mark_tables_dirty()
         for s in self._admit_order:
             req = self._active[s]
             if req is not None:
-                req.pages = [int(old_to_new[p]) for p in req.pages]
+                req.pages = [int(remaps[0][p]) for p in req.pages]
+                req.window_pages = {b: int(remaps[1][p])
+                                    for b, p in req.window_pages.items()}
         self.defrags += 1
 
     # -- scheduler loop ----------------------------------------------------
@@ -1227,6 +1416,10 @@ class PagedGenerationServer(_GenerationServerBase):
             if (self._admission_pages(req) + self._outstanding_growth()
                     > self.pool.free_pages):
                 self._push_back(req)
+                break
+            if self._window and (self._window_peak(req) + self._window_debt()
+                                 > self.pool_w.free_pages):
+                self._push_back(req)    # the window class's budget
                 break
             if not self._admit(req, slot):
                 break
@@ -1283,7 +1476,11 @@ class PagedGenerationServer(_GenerationServerBase):
         import jax.numpy as jnp
 
         if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
+            # with window layers, a table a class: (2, slots, max_pages),
+            # the full class's first (Executor.page_classes)
+            self._tables_dev = jnp.asarray(
+                np.stack([self._tables, self._tables_w]) if self._window
+                else self._tables)
         return self._tables_dev
 
     def _temps_device(self):
@@ -1371,7 +1568,8 @@ class PagedGenerationServer(_GenerationServerBase):
             tbl = self._tables_device()
             if B != self.slots or not np.array_equal(
                     slot_idx, np.arange(self.slots, dtype=np.int32)):
-                tbl = jnp.take(tbl, jnp.asarray(slot_idx), axis=0)
+                tbl = jnp.take(tbl, jnp.asarray(slot_idx),
+                               axis=1 if self._window else 0)
             pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
                                    jnp.asarray(ids))
         total = B * window
@@ -1413,6 +1611,8 @@ class PagedGenerationServer(_GenerationServerBase):
                        kv_blocks=int((-(-pages // ppb)).sum()),
                        block_pages=ppb,
                        qk_pairs=int((q * p0 + q * (q + 1) // 2).sum()))
+                if self._window:
+                    sp.set(**self._window_counts(slot_idx[qls > 0], p0, q))
                 alias = self._pool_alias.get((B, window))
                 if alias is not None:
                     # whether this shape's pools are written where they
@@ -1451,6 +1651,43 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_rows.inc(total)
         self._c_pad.inc(padded)
         return probs, padded, total
+
+    def _window_counts(self, slots, p0, q) -> dict:
+        """What a traced launch has to read and score in a layer of each
+        class (tracing only): live pages, each ONCE a slot however many
+        pieces of its chunk walk them, and visible (query, key) pairs; in
+        a window layer the pages from the window of the slot's first
+        query to its last row, and min(position + 1, window) keys a
+        query. Beside them what a window layer would have walked with no
+        lower bound, the bytes both classes hold now over their layers,
+        and what the full class's pages would take in every layer."""
+        P, W = self.page_size, self._window
+        first, horizon = {}, {}
+        for s_, p_, q_ in zip(slots, p0, q):
+            s_ = int(s_)
+            first[s_] = min(first.get(s_, int(p_)), int(p_))
+            horizon[s_] = max(horizon.get(s_, 0), int(p_ + q_))
+        full = sum(-(-e // P) for e in horizon.values())
+        win = sum(-(-horizon[s_] // P) - self._window_first_block(first[s_])
+                  for s_ in horizon)
+        pairs_w = 0
+        for p_, q_ in zip(p0, q):
+            seen = np.minimum(np.arange(int(p_), int(p_ + q_)) + 1, W)
+            pairs_w += int(seen.sum())
+        released = self.window_pages_released - self._released_at_launch
+        self._released_at_launch = self.window_pages_released
+        in_use, in_use_w = self.pool.pages_in_use, self.pool_w.pages_in_use
+        return {
+            "kv_pages_full": full, "kv_pages_window": win,
+            "window_pages_walked_if_full": full,
+            "qk_pairs_full": int((q * p0 + q * (q + 1) // 2).sum()),
+            "qk_pairs_window": pairs_w,
+            "window_pages_released": released,
+            "pool_bytes_resident": (in_use * self._page_bytes_full
+                                    + in_use_w * self._page_bytes_window),
+            "pool_bytes_if_one_class": in_use * (
+                self._page_bytes_full + self._page_bytes_window),
+        }
 
     def _rider_rows(self, probs, at):
         """(slots, V): the rows of the decode items behind a chunk's
@@ -1573,6 +1810,8 @@ class PagedGenerationServer(_GenerationServerBase):
                 req.prefill_pos = start + take
                 req.prefill_tokens += take
                 self._publish_prefix(req, req.prefill_pos)
+                if req.prefill_pos < req.prefill_target:
+                    self._release_window_pages(s, req)
                 if req.prefill_pos >= req.prefill_target:
                     # publish the PROMPT's partial tail now, before
                     # decode appends rows to the same page: the entry
@@ -1589,6 +1828,7 @@ class PagedGenerationServer(_GenerationServerBase):
                                                np.int32(r), self.slots)[0]
                     self._sample_first_token(s, req, row)
                     first.append(req.seq)
+                    self._release_window_pages(s, req)
                     self._finish_if_done(s)
                     if self._active[s] is not None:
                         # disagg hook: a PrefillWorker hands the request
@@ -1676,6 +1916,7 @@ class PagedGenerationServer(_GenerationServerBase):
                 req.tokens.append(int(toks[s]))
                 self._tokens[s] = toks[s]
                 self._publish_prefix(req, req.pos)
+                self._release_window_pages(s, req)
                 self._finish_if_done(s)
             if csp:
                 csp.set(finished=sum(1 for s in live

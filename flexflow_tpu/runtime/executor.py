@@ -566,6 +566,13 @@ class Executor:
                                       inputs[0].shape[1])
         ragged_q_lens, ragged_depths, ragged_anc = (
             ragged if ragged is not None else (None, None, None))
+        # a graph with window layers is served from two classes of pages
+        # and its launches carry a (2, B, max_pages) table, the full
+        # class's rows first (`page_classes`); any other graph's table is
+        # the (B, max_pages) matrix it always was
+        classes = (self.page_classes()
+                   if page_tables is not None and page_tables.ndim == 3
+                   else None)
         remat_groups = self._remat_groups if training else {}
         for n in self.topo:
             if n.op_type == OpType.INPUT:
@@ -596,7 +603,8 @@ class Executor:
                 kv_cache=(kv_caches.get(key) if kv_caches is not None
                           else None),
                 cache_position=cache_position,
-                page_tables=page_tables,
+                page_tables=(page_tables if classes is None
+                             else page_tables[classes.get(key, 0)]),
                 ragged_q_lens=ragged_q_lens,
                 ragged_depths=ragged_depths,
                 ragged_anc=ragged_anc,
@@ -808,8 +816,26 @@ class Executor:
             )
         return caches
 
+    def page_classes(self) -> Optional[Dict[str, int]]:
+        """{node key: 0 (full) or 1 (window)} over the paged attention
+        nodes of a graph that has sliding-window layers, else None: one
+        class of pages, one table, as every graph had before. Decided by
+        what the graph holds and by no option."""
+        cls = {node_key(n): int(getattr(n.attrs, "window", None)
+                                is not None)
+               for n in self.topo if n.op_type in PAGED_ATTENTION_OPS}
+        return cls if any(cls.values()) else None
+
+    def window_rows(self) -> int:
+        """The widest sliding window among the graph's layers (0: none);
+        what the window class of pages is sized by."""
+        return max((n.attrs.window or 0 for n in self.topo
+                    if n.op_type in PAGED_ATTENTION_OPS
+                    and hasattr(n.attrs, "window")), default=0)
+
     def paged_kv_cache_specs(self, num_pages: int, page_size: int,
-                             dtype=None) -> Dict[str, Dict[str, Any]]:
+                             dtype=None, num_pages_window: Optional[int]
+                             = None) -> Dict[str, Dict[str, Any]]:
         """Shape/dtype specs (jax.ShapeDtypeStruct) of the paged K/V
         pools init_paged_kv_cache materializes — also the abstract
         arguments lowered_modules() feeds the paged entry points, so the
@@ -819,9 +845,16 @@ class Executor:
         float32 — to every node's dict (paged/quant.py has the layout
         story); putting them inside the same dict is what lets the COW
         clone, the defrag permutation, the megastep carry and the spec
-        commit move scales with their pages by construction."""
+        commit move scales with their pages by construction.
+
+        A sliding-window node's pool has `num_pages_window` pages, its
+        own class's count (`page_classes`; a server sizes it to a window
+        and a chunk a slot, not to whole sequences)."""
         from flexflow_tpu.paged.quant import is_quantized_dtype
 
+        classes = self.page_classes() or {}
+        # a window node's class has its own count (not said: the same)
+        pages_of = (num_pages, num_pages_window or num_pages)
         specs = {}
         for n in self.topo:
             if n.op_type == OpType.PIPELINE:
@@ -836,6 +869,7 @@ class Executor:
             dt = dtype
             if dt is None:
                 dt = ins[0].dtype.jnp_dtype if ins else jnp.bfloat16
+            num_pages = pages_of[classes.get(node_key(n), 0)]
             if n.op_type == OpType.LATENT_ATTENTION:
                 # ONE entry a node: a token's row is [c_kv | k_r], key and
                 # value of every head at once (paged/latent.py)
@@ -869,7 +903,8 @@ class Executor:
         return specs
 
     def init_paged_kv_cache(self, num_pages: int, page_size: int,
-                            dtype=None):
+                            dtype=None, num_pages_window: Optional[int]
+                            = None):
         """Per-attention-node paged K/V POOLS for the paged decode path
         (flexflow_tpu.paged): flat-lane (num_pages, page_size, Hkv*D)
         buffers (paged/attention.py has the layout story) shared by
@@ -877,7 +912,8 @@ class Executor:
         TOKENS IN FLIGHT instead of slots x max_len. PIPELINE
         composites keep their layer-scan threaded dense caches and are
         not paged (their cache lives inside the scan carry)."""
-        specs = self.paged_kv_cache_specs(num_pages, page_size, dtype)
+        specs = self.paged_kv_cache_specs(num_pages, page_size, dtype,
+                                          num_pages_window)
         # a pool is born COMMITTED to its device, as every launch's output
         # pool is (the sharding a launch gives its outputs: replicated over
         # the model's mesh): a launch shape then has ONE jit signature and
@@ -1414,19 +1450,29 @@ class Executor:
             cols = int(cfg["table_cols"])
             num_pages = int(cfg["num_pages"] or slots * cols + 1)
             pool_dt = resolve_kv_dtype(cfg.get("kv_dtype") or "auto")
-            caches = self.init_paged_kv_cache(num_pages, page_size,
-                                              dtype=pool_dt)
+            caches = self.init_paged_kv_cache(
+                num_pages, page_size, dtype=pool_dt,
+                num_pages_window=cfg.get("num_pages_window"))
             step = self.ragged_step_fn()
+            # a graph with window layers launches with a table a class
+            two = self.page_classes() is not None
             for B, W in entries.get(  # fflint: host-ok (one-time warmup)
                     "ragged_step", {}).get("shapes", ()):
                 B, W = int(B), int(W)
                 # a packed launch gathers its table rows on the device,
                 # at B == slots too (the canonical decode launch does not)
-                tbl = jnp.take(jnp.zeros((slots, cols), jnp.int32),
-                               jnp.asarray(np.zeros((B,), np.int32)),
-                               axis=0)
-                if B == slots:
-                    tbl = jnp.zeros((slots, cols), jnp.int32)
+                if two:
+                    tbl = jnp.take(jnp.zeros((2, slots, cols), jnp.int32),
+                                   jnp.asarray(np.zeros((B,), np.int32)),
+                                   axis=1)
+                    if B == slots:
+                        tbl = jnp.zeros((2, slots, cols), jnp.int32)
+                else:
+                    tbl = jnp.take(jnp.zeros((slots, cols), jnp.int32),
+                                   jnp.asarray(np.zeros((B,), np.int32)),
+                                   axis=0)
+                    if B == slots:
+                        tbl = jnp.zeros((slots, cols), jnp.int32)
                 deps = jnp.asarray(np.tile(
                     np.arange(W, dtype=np.int32), (B, 1)))
                 anc = jnp.asarray(np.tile(
@@ -1697,10 +1743,13 @@ class Executor:
         """ragged_step_fn()'s arguments after the pools, abstract:
         (tables, pos, q_lens, depths, anc, ids) of a (slots, window)
         launch. q_lens is all 1 for a decode launch and a tree's node
-        count for a verify, which only the values say."""
+        count for a verify, which only the values say. A graph with
+        window layers takes a table a class of pages, stacked."""
         per_slot = jax.ShapeDtypeStruct((slots,), jnp.int32)
         rows = jax.ShapeDtypeStruct((slots, window), jnp.int32)
-        return (jax.ShapeDtypeStruct((slots, table_cols), jnp.int32),
+        tables = ((slots, table_cols) if self.page_classes() is None
+                  else (2, slots, table_cols))
+        return (jax.ShapeDtypeStruct(tables, jnp.int32),
                 per_slot, per_slot, rows,
                 jax.ShapeDtypeStruct((slots, window, window), jnp.bool_),
                 rows)

@@ -264,6 +264,8 @@ def make_mha_to_ring_attention(axis_sizes: Dict[str, int],
             return None
         if a.dropout or a.use_bias:
             return None  # the ring lowering supports neither
+        if a.window is not None or a.rope_scaling is not None:
+            return None  # nor a sliding window or a scaled rope
         if seq_mode == "ulysses" and a.num_heads % seq_deg != 0:
             # the ulysses exchange turns seq sharding into head sharding;
             # with indivisible heads the lowering would silently fall back
@@ -272,7 +274,7 @@ def make_mha_to_ring_attention(axis_sizes: Dict[str, int],
             return None
         new_attrs = A.RingAttentionAttrs(
             a.embed_dim, a.num_heads, a.kv_heads, a.head_dim, a.causal,
-            a.use_bias, a.dropout, a.rope, a.rope_theta, seq_mode,
+            a.use_bias, a.dropout, a.rope, a.rope_theta, seq_mode=seq_mode,
         )
         ndim = attn.outputs[0].ndim
         seq_spec = (batch_spec(ndim)[:1] + (("seq",),)

@@ -438,7 +438,8 @@ class _GenRequest:
                  "cached_prefill_tokens", "prefill_pos", "prefill_target",
                  "prefill_seq", "hashed_blocks", "decode_overlap_ticks",
                  "compile_s_at_submit", "first_compile_s",
-                 "spilled_pages", "fetched_pages", "routed_to", "seq")
+                 "spilled_pages", "fetched_pages", "routed_to", "seq",
+                 "window_pages")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float):
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -454,6 +455,9 @@ class _GenRequest:
         self.pos = 0  # next cache write position for this slot
         # paged-path bookkeeping / per-request metrics
         self.pages: List[int] = []      # pool pages held (paged only)
+        # window-class pages held, by the block of rows each backs (a
+        # graph with sliding-window layers only; paged/scheduler.py)
+        self.window_pages: Dict[int, int] = {}
         self.submit_t = time.monotonic()
         self.admit_t: Optional[float] = None
         self.first_token_t: Optional[float] = None  # TTFT stamp
@@ -1368,6 +1372,44 @@ def _refuse_for_latent_attention(ff, *, paged, kv_dtype, speculate,
             "strategy search's pricing all assume K/V pools)")
 
 
+def _refuse_for_window_layers(ff, *, paged, prefix_cache, kv_dtype,
+                              speculate, megastep_ticks, megastep_mixed,
+                              overlap_dispatch, host_tier, kv_quant_canary,
+                              serve_strategy, search_budget) -> None:
+    """A graph with sliding-window layers is served by the paged per-tick
+    server over two classes of pages (chunked prefill, packed launches,
+    preemption and defrag included). What has not been built over a
+    table whose pages behind the window are gone is refused BY NAME
+    here, not left to read the null page (docs/paged.md "Two classes of
+    pages")."""
+    if not ff.executor.window_rows():
+        return
+    refused = {
+        "paged=False": not paged,
+        "prefix_cache": bool(prefix_cache),
+        "kv_dtype": kv_dtype not in ("auto", "bf16", "fp16", "fp32"),
+        "speculate": speculate is not None,
+        "megastep_ticks": megastep_ticks > 1,
+        "megastep_mixed": bool(megastep_mixed),
+        "overlap_dispatch": bool(overlap_dispatch),
+        "host_tier": host_tier is not None and host_tier != 0,
+        "kv_quant_canary": bool(kv_quant_canary),
+        "serve_strategy": serve_strategy is not None,
+        "search_budget": search_budget is not None,
+    }
+    bad = [name for name, hit in refused.items() if hit]
+    if bad:
+        raise ValueError(
+            f"serve_generation option(s) {bad} are not supported on a "
+            "graph with sliding-window attention layers: a window "
+            "layer's pages behind the window are released, so a prefix-"
+            "cache hit (prefix_cache=True is the default: pass False) "
+            "would map rows that are gone; the dense cache, the int8 "
+            "scale blocks, tree verify, the megasteps' carry, the host "
+            "tier's payloads and the strategy search's pricing all "
+            "assume ONE table a request")
+
+
 def serve_generation(ff, slots: int = 4, max_len: int = 512,
                      eos_id: Optional[int] = None, seed: int = 0,
                      paged: bool = False, page_size: int = 64,
@@ -1389,7 +1431,8 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
                      slo_dump_dir: Optional[str] = None,
                      kv_quant_canary: Optional[int] = None,
                      defer_start: bool = False,
-                     host_tier=None
+                     host_tier=None,
+                     num_pages_window: Optional[int] = None
                      ) -> "_GenerationServerBase":
     """Continuous-batching generation endpoint over a compiled causal-LM
     FFModel (KV-cache decode path required — see FFModel.generate).
@@ -1505,13 +1548,26 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     servers is the prefill/decode KV-transfer channel. Pool evictions
     spill full pages to host RAM instead of dropping them, and prefix
     lookups transparently fetch them back; greedy output stays
-    token-identical."""
+    token-identical.
+
+    A graph with SLIDING-WINDOW attention layers (paged only) is served
+    from two classes of pages (docs/paged.md "Two classes of pages"):
+    `num_pages` sizes the full layers' class and `num_pages_window` the
+    window layers' (default slots x (window + prefill_chunk + a page));
+    pass `prefix_cache=False`, and see `_refuse_for_window_layers` for
+    the options such a graph refuses by name."""
     _refuse_for_latent_attention(
         ff, paged=paged, kv_dtype=kv_dtype, speculate=speculate,
         megastep_ticks=int(megastep_ticks), megastep_mixed=megastep_mixed,
         overlap_dispatch=overlap_dispatch, host_tier=host_tier,
         kv_quant_canary=kv_quant_canary, serve_strategy=serve_strategy,
         search_budget=search_budget)
+    _refuse_for_window_layers(
+        ff, paged=paged, prefix_cache=prefix_cache, kv_dtype=kv_dtype,
+        speculate=speculate, megastep_ticks=int(megastep_ticks),
+        megastep_mixed=megastep_mixed, overlap_dispatch=overlap_dispatch,
+        host_tier=host_tier, kv_quant_canary=kv_quant_canary,
+        serve_strategy=serve_strategy, search_budget=search_budget)
     if search_budget is not None and serve_strategy is None:
         from flexflow_tpu.search.servesearch import search_serve_strategy
 
@@ -1595,7 +1651,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             slo=slo, slo_dump_dir=slo_dump_dir,
             kv_quant_canary=kv_quant_canary,
             serve_strategy=serve_strategy, defer_start=defer_start,
-            host_tier=host_tier)
+            host_tier=host_tier, num_pages_window=num_pages_window)
     if kv_dtype != "auto":
         raise ValueError(
             "kv_dtype rides the paged KV pool; pass paged=True")

@@ -872,6 +872,23 @@ def test_recorder_is_a_ring_that_keeps_the_newest():
     assert rec.chrome_trace()["otherData"]["dropped_events"] == 5
 
 
+def test_a_span_that_closes_late_does_not_break_a_reader():
+    """A span open on another thread when `disable()` returned (another
+    server's idling loop) closes into the ring while a reader walks
+    `rec.events`: the reader sees the events it started with."""
+    rec = obs.enable()
+    late = obs.span("idle_wait").__enter__()
+    with obs.span("traffic"):
+        pass
+    obs.disable()
+    seen = []
+    for ev in rec.events:
+        late.__exit__(None, None, None)     # closes once, mid-walk
+        seen.append(ev[0])
+    assert seen == ["traffic"]
+    assert [e[0] for e in rec.events][:2] == ["traffic", "idle_wait"]
+
+
 def test_span_left_open_does_not_adopt_later_spans():
     """A manual __enter__ whose __exit__ an exception skipped must not
     become the parent of every later span on the thread."""

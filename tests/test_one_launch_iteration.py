@@ -372,11 +372,13 @@ def test_one_launch_share_reads_100_and_is_left_out_at_the_parent(served):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = [m for m in json.load(f)["per_layer"]
                  if m["name"] == "one_launch_share"]
-    assert entry == [{k: metric[k] for k in
-                      ("name", "unit", "source", "layer", "moves")}
-                     | {"better": "higher", "workloads": [
-                         "mistral-7b-serve1.longdoc-backlog",
-                         "mistral-small-4-serve1.longctx-backlog"]}]
+    # the two cells PR 34 gave it; later cells are appended (PR 36's)
+    assert len(entry) == 1 and entry[0]["workloads"][:2] == [
+        "mistral-7b-serve1.longdoc-backlog",
+        "mistral-small-4-serve1.longctx-backlog"]
+    assert {k: v for k, v in entry[0].items() if k != "workloads"} == (
+        {k: metric[k] for k in ("name", "unit", "source", "layer", "moves")}
+        | {"better": "higher"})
 
 
 # ---------------------------------------------------------------------------

@@ -317,8 +317,11 @@ def test_sim_ttft_p95_closer_to_measured_than_closed_form(profile_name):
     sample = prof.sample(np.random.RandomState(12), lcfg.vocab_size)
     gen = ff.serve_generation(slots=2, max_len=64, paged=True, page_size=8)
     try:
-        # warm pass: same launch shapes (same lengths), different
-        # tokens — the measured burst below is compile-free
+        # every launch shape first, then a warm pass of the same lengths
+        # on different tokens: the measured burst below is compile-free
+        # (which pieces and riders share a launch depends on timing, so
+        # the warm pass alone left shapes to compile inside the burst)
+        gen.warm_launch_shapes()
         for f in [gen.submit(p, max_new_tokens=8) for p in warm.prompts]:
             f.result(timeout=300)
         base = len(gen.request_log.records())
