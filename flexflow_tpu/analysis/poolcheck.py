@@ -753,6 +753,7 @@ _COW_FNS = {"copy_page",
             # twin of the alloc lifecycle, never a shared-page write
             "write_page"}
 _TABLE_FNS = {"__init__", "_admit", "_apply_defrag", "_release_slot",
+              "_vacate",
               "_evict", "_ensure_pages",
               # the release arm of drain-and-swap: joins the loop, frees
               # every slot's pages, then zeroes the rows — the model

@@ -1010,14 +1010,17 @@ PROTOCOL_INVARIANTS = {
     "swap-during-handoff": "the controller never detaches a server "
                            "while a handoff is in flight on its loop "
                            "thread",
-    "dispatch-buffer-owner": "an in-flight megastep's token buffer has "
-                             "exactly one owner at every instant — the "
-                             "device until the fence retires it, host "
-                             "bookkeeping only after",
-    "stale-page-table": "overlapped admission takes only FREE pages; "
-                        "no page referenced by the in-flight "
-                        "dispatch's table is freed or reassigned "
-                        "before its replay lands",
+    "dispatch-buffer-owner": "an in-flight megastep's (or launch's) "
+                             "token buffer has exactly one owner at "
+                             "every instant — the device until the "
+                             "fence retires it, host bookkeeping only "
+                             "after",
+    "stale-page-table": "admission beside a dispatch takes only FREE "
+                        "pages; no page referenced by an in-flight "
+                        "dispatch's table is freed, reassigned or "
+                        "moved before its replay lands (a late EOS "
+                        "keeps its pages a launch longer; defrag "
+                        "fences first)",
     "deadlock": "some thread can always make progress until the "
                 "protocol completes",
 }
@@ -1695,8 +1698,190 @@ class DispatchModel(ProtocolModel):
                 self.bad_read)
 
 
+class LaunchAheadModel(ProtocolModel):
+    """Protocol 5 — the one-deep launch pipeline of the per-tick loop
+    (paged/scheduler.py `_launch` / `_retire`): the host dispatches
+    launch k + 1 BEFORE it takes launch k's picks, so the dispatch
+    protocol's two invariants hold a launch deep. The token buffer of a
+    launch is the device's until the host's fetch retires it (`_retire`
+    waits; nothing reads a pick early), and A PAGE NAMED BY A LAUNCH IN
+    FLIGHT IS NEITHER FREED NOR MOVED: a request that stops on an EOS in
+    launch k already has a row in launch k + 1, so its page goes back
+    only when k + 1 is taken, and a defrag first fences. Admission runs
+    beside a launch in flight: it takes free pages only.
+
+    The scenario: request A holds page 0 and emits its EOS in launch 0;
+    request B is submitted at any time and admitted beside a launch with
+    a free page; a defrag is asked for before launch 2 (the host fences,
+    then compacts: page ids change)."""
+
+    NAME = "launch_ahead"
+    N = 3  # launches
+
+    def __init__(self, mutations: Tuple[str, ...] = ()):
+        super().__init__(mutations)
+        self.launched = 0          # launches dispatched
+        self.computed = 0          # launches the device has run
+        self.taken = 0             # launches whose picks the host took
+        self.host_pc = 0           # 0 dispatch, 1 admit beside, 2 retire
+        self.submitted = 0
+        self.pending = 0
+        self.free: List[int] = [10]
+        self.pages: Dict[str, List[int]] = {"A": [0]}
+        self.stopped: Set[str] = set()   # the host knows of the EOS
+        self.named: List[Tuple[int, ...]] = []   # pages a launch names
+        self.rows: List[Tuple[str, ...]] = []    # requests it has rows of
+        self.defragged = False
+        self.bad_read = False
+
+    def _held(self) -> Set[int]:
+        return {p for ps in self.pages.values() for p in ps}
+
+    def _take_enabled(self) -> bool:
+        # SEEDED DEFECT (read_before_fence): the host reads a launch's
+        # picks without waiting for the device to have run it
+        return (self.computed > self.taken
+                or "read_before_fence" in self.mutations)
+
+    def enabled(self) -> List[Action]:
+        acts: List[Action] = []
+        if self.submitted < 1:
+            acts.append(Action("client", "submit", frozenset(),
+                               frozenset({"pending"})))
+        if self.computed < self.launched:   # the device runs in order
+            acts.append(Action("device", f"compute({self.computed})",
+                               frozenset({"pages"}),
+                               frozenset({"buf"})))
+        if self.launched < self.N or self.taken < self.launched:
+            if self.host_pc == 0 and self.launched < self.N:
+                wants_defrag = self.launched == 2 and not self.defragged
+                # SEEDED DEFECT (defrag_without_fence): pages move under
+                # the launch in flight
+                fenced = (self.taken == self.launched
+                          or "defrag_without_fence" in self.mutations)
+                if wants_defrag and not fenced:
+                    if self._take_enabled():
+                        acts.append(Action(
+                            "host", f"fence_take({self.taken})",
+                            frozenset({"buf"}),
+                            frozenset({"buf", "pages", "free"})))
+                elif wants_defrag:
+                    acts.append(Action("host", "defrag",
+                                       frozenset({"pages"}),
+                                       frozenset({"pages", "free"})))
+                else:
+                    acts.append(Action("host",
+                                       f"dispatch({self.launched})",
+                                       frozenset({"pages"}),
+                                       frozenset({"buf"})))
+            elif self.host_pc == 1:
+                acts.append(Action("host", "admit_beside",
+                                   frozenset({"pending", "free"}),
+                                   frozenset({"pending", "free", "pages"})))
+            elif self.host_pc == 2 or self.launched == self.N:
+                # the pipeline's own step takes every launch but the one
+                # just dispatched; the last pass takes them all
+                upto = self.launched if self.launched == self.N \
+                    and self.host_pc == 0 else self.launched - 1
+                if self.taken < upto:
+                    if self._take_enabled():
+                        acts.append(Action("host", f"take({self.taken})",
+                                           frozenset({"buf"}),
+                                           frozenset({"buf", "pages",
+                                                      "free"})))
+                else:
+                    acts.append(Action("host", "next", frozenset(),
+                                       frozenset()))
+        return acts
+
+    def _take(self):
+        j = self.taken
+        if self.computed <= j:
+            self.bad_read = True
+        self.taken += 1
+        if j == 0:
+            self.stopped.add("A")       # launch 0 picked A's EOS
+        in_flight = {r for rows in self.rows[self.taken:self.launched]
+                     for r in rows}
+        for r in sorted(self.stopped):
+            # SEEDED DEFECT (free_at_late_stop): the pages go back when
+            # the host learns of the stop, under the row in flight
+            if r in self.pages and (
+                    r not in in_flight
+                    or "free_at_late_stop" in self.mutations):
+                self.free += self.pages.pop(r)
+
+    def apply(self, action: Action):
+        op = action.label.split("(")[0]
+        if op == "submit":
+            self.pending += 1
+            self.submitted += 1
+        elif op == "compute":
+            self.computed += 1
+        elif op == "dispatch":
+            rows = tuple(r for r in sorted(self.pages)
+                         if r not in self.stopped)
+            self.rows.append(rows)
+            self.named.append(tuple(p for r in rows
+                                    for p in self.pages[r]))
+            self.launched += 1
+            self.host_pc = 1
+        elif op == "admit_beside":
+            if self.pending and self.free:
+                self.pages["B"] = [self.free.pop()]
+                self.pending -= 1
+            self.host_pc = 2
+        elif op in ("take", "fence_take"):
+            self._take()
+        elif op == "next":
+            self.host_pc = 0
+        elif op == "defrag":
+            # compaction: every held page gets a new id
+            self.pages = {r: [p + 100 for p in ps]
+                          for r, ps in self.pages.items()}
+            self.defragged = True
+
+    def check(self) -> List[str]:
+        v: List[str] = []
+        if self.bad_read:
+            v.append("dispatch-buffer-owner: the host took a launch's "
+                     "picks while the device still owned them — the "
+                     "fetch did not wait for the launch")
+        for j in range(self.taken, self.launched):
+            gone = set(self.named[j]) - self._held()
+            if gone:
+                v.append("stale-page-table: page(s) "
+                         f"{sorted(gone)} named by launch {j}, still in "
+                         "flight, were freed or moved before its picks "
+                         "were taken")
+                break
+        return v
+
+    def done(self) -> bool:
+        return (self.launched == self.N and self.taken == self.N
+                and self.submitted == 1)
+
+    def check_final(self) -> List[str]:
+        total = len(self.free) + len(self._held())
+        if total != 2:
+            return ["free-accounting: free + held pages number "
+                    f"{total}, pool holds 2"]
+        if "A" in self.pages:
+            return ["free-accounting: request A stopped and still holds "
+                    "its page after every launch was taken"]
+        return []
+
+    def key(self) -> tuple:
+        return (self.launched, self.computed, self.taken, self.host_pc,
+                self.submitted, self.pending, tuple(self.free),
+                tuple(sorted((r, tuple(p)) for r, p in self.pages.items())),
+                tuple(sorted(self.stopped)), tuple(self.named),
+                self.defragged, self.bad_read)
+
+
 PROTOCOLS = {m.NAME: m for m in
-             (HandoffModel, TierPoolModel, SwapModel, DispatchModel)}
+             (HandoffModel, TierPoolModel, SwapModel, DispatchModel,
+              LaunchAheadModel)}
 
 
 class InterleaveResult:

@@ -83,23 +83,24 @@ class PrefillWorker(PagedGenerationServer):
         self.handoffs = 0
         super().__init__(ff, host_tier=host_tier, **kwargs)
 
+    def _prefill_tick(self, slots, tr, ntr, dec=()):
+        # the hand-off needs the first token on the host, and frees the
+        # request's pages: take a finishing chunk's pick at once, so no
+        # later launch names them (the worker never decodes)
+        rode = super()._prefill_tick(slots, tr, ntr, dec)
+        if self._flight:
+            self._retire("handoff")
+        return rode
+
     def _on_prefill_complete(self, slot: int):
         req = self._active[slot]
-        if not self._kv_quant_debug:
-            self._close_canary(req)
         # with the first token appended, publish so every FULL page is
         # hash-registered — spill_request only moves registered pages
         self._publish_tail(req)
         req.spilled_pages += self.pool.spill_request(req.pages)
-        self.pool.free(list(reversed(req.pages)))  # leaf-first
-        req.pages = []
+        self._free_pages(req)
         self._reset_prefill_state(req)
-        self._tables[slot] = 0
-        self._mark_tables_dirty()
-        self._mark_temps_dirty()
-        self._active[slot] = None
-        if slot in self._admit_order:
-            self._admit_order.remove(slot)
+        self._vacate(slot, req)
         self.handoffs += 1
         try:
             self._handoff(req)

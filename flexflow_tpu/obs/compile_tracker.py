@@ -141,7 +141,7 @@ class _TrackedJit:
         except Exception:
             return ()
 
-    def __call__(self, *args):
+    def __call__(self, *args, **kwargs):
         if _install_listener():
             stack = getattr(_tls, "stack", None)
             if stack is None:
@@ -149,7 +149,7 @@ class _TrackedJit:
             frame = {"compiles": 0, "seconds": 0.0}
             stack.append(frame)
             try:
-                out = self._fn(*args)
+                out = self._fn(*args, **kwargs)
             finally:
                 stack.pop()
             if frame["compiles"]:
@@ -160,7 +160,7 @@ class _TrackedJit:
         if callable(cache_size):
             before = cache_size()
             t0 = time.monotonic()
-            out = self._fn(*args)
+            out = self._fn(*args, **kwargs)
             if cache_size() > before:
                 self._tracker.record(self._entry, self._shape(args),
                                      time.monotonic() - t0)
@@ -170,9 +170,9 @@ class _TrackedJit:
         # every shape-space escape, the property the gate pins)
         shape = self._shape(args)
         if shape in self._seen:
-            return self._fn(*args)
+            return self._fn(*args, **kwargs)
         t0 = time.monotonic()
-        out = self._fn(*args)
+        out = self._fn(*args, **kwargs)
         self._seen.add(shape)
         self._tracker.record(self._entry, shape, time.monotonic() - t0)
         return out

@@ -49,12 +49,29 @@ Decode flow per tick:
      window of the largest piece (a finishing chunk samples the first
      token). With none: the (slots, 1) decode step (idle slots carry
      q_len 0: no work, writes to the null page)
-  4. sample the decoding slots' rows at their slot index, append,
-     publish freshly filled pages to the prefix cache, finish/free
+  4. sample the decoding slots' rows at their slot index, ON THE
+     DEVICE, and advance what needs no token value (positions, prompt
+     pages published, window pages released)
+  5. take the picks of the launch BEFORE (append, publish generated
+     pages, finish/free): the host waits for launch N only after launch
+     N + 1 is dispatched, so the chip always has its next launch queued
+
+LAUNCH AHEAD (docs/paged.md "Launch ahead"): the loop is a one-deep
+pipeline. A decode row of launch N + 1 reads its token id from the
+device vector of the slots' newest tokens (`_newest`, the step's
+`feed`), which launch N's picks wrote; the host learns those values at
+`_retire()`, a launch late. A request that stops on an EOS therefore has
+one row in the launch after, whose pick is discarded (`late_stop_rows`)
+and whose pages stay the request's until that launch is taken.
+Whatever needs the host's truth of every token (preemption, defrag, a
+drain-and-swap, stop(), the megasteps, speculation's drafting, a
+hand-off) first calls `_retire(reason)`, the FENCE: the serial order is
+this loop with the pipeline drained.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import queue
 import threading
@@ -79,6 +96,33 @@ def rows_at(tail, at):
     import jax.numpy as jnp
 
     return jnp.take(tail, at, axis=0)
+
+
+# a decode row's token id while the host has not seen it: `_launch` reads
+# it from the device (`_newest`)
+ON_DEVICE = -1
+
+
+def set_newest(newest, picked, at):
+    """The slots' newest tokens (slots,) with the picks (n,) put at the
+    slots `at` (n,); an `at` past the last slot is dropped (a row of the
+    pick that no live slot owns). ONE program a pick width (1, slots),
+    whatever the launch's shape."""
+    return newest.at[at].set(picked, mode="drop")  # fflint: cow-ok (a (slots,) vector of token ids, never a pool page)
+
+
+class _Flight:
+    """A launch whose picks the host has not taken: its number, the
+    (slot, request) pairs that got a first token and a decode row's
+    token in it, and the slots' newest tokens after its last pick."""
+
+    __slots__ = ("seq", "firsts", "rows", "newest")
+
+    def __init__(self, seq: int):
+        self.seq = seq
+        self.firsts: list = []
+        self.rows: list = []
+        self.newest = None
 
 
 class PagedGenerationServer(_GenerationServerBase):
@@ -327,6 +371,26 @@ class PagedGenerationServer(_GenerationServerBase):
         self.iterations_with_both = 0
         self.one_launch = 0
         self._rows_at = jax.jit(rows_at)
+        # LAUNCH AHEAD: `_newest` is each slot's newest token as the
+        # DEVICE knows it (born committed where a launch's outputs are,
+        # as the pools), `_flight` the launches whose picks the host has
+        # not taken, oldest first, `_synced` the newest launch the host
+        # knows to be done. A launch is AHEAD when an earlier one was not
+        # known to be done at its dispatch; one dispatched with nothing
+        # before it is charged to the fence that drained the pipeline
+        self._newest = jax.device_put(
+            jax.numpy.zeros((self.slots,), jax.numpy.int32),
+            ex.launch_placement())
+        self._set_newest = jax.jit(set_newest)
+        self._index_dev: dict = {}
+        self._flight: "collections.deque[_Flight]" = collections.deque()
+        self.launches = 0
+        self.launches_ahead = 0
+        self._synced = 0
+        self.fences: dict = {}
+        self.launches_drained: dict = {}
+        self._last_fence = "start"
+        self.late_stop_rows = 0
         # idle-loop accounting (fftrace): ticks the loop slept because
         # nothing was live or admitted, and total seconds spent asleep
         self._c_idle = self.registry.counter("idle_ticks_total")
@@ -607,6 +671,11 @@ class PagedGenerationServer(_GenerationServerBase):
                 "iterations_with_both": self.iterations_with_both,
                 "one_launch": self.one_launch,
             },
+            "launches_dispatched": self.launches,
+            "launches_ahead": self.launches_ahead,
+            "launches_drained": dict(self.launches_drained),
+            "fences": dict(self.fences),
+            "late_stop_rows": self.late_stop_rows,
             "kernel_variant": self.kernel_variant,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_cache_dtype": self._kv_pool_dtype_name(),
@@ -836,6 +905,7 @@ class PagedGenerationServer(_GenerationServerBase):
         self._canary_admits += 1
         if (self._canary_admits % self.kv_quant_canary == 0
                 and self._caches_ref is None):
+            self._retire("canary")  # the window opens on the host's truth
             self._caches_ref = self._shadow_snapshot(self._caches)
             self._canary_req = req
             self._c_canary.inc()
@@ -850,21 +920,44 @@ class PagedGenerationServer(_GenerationServerBase):
 
     def _release_slot(self, slot: int, req: _GenRequest,
                       completed: bool = False):
-        if not self._kv_quant_debug:
-            self._close_canary(req)
+        self._free_pages(req)
+        if self._active[slot] is req:   # not vacated at its last launch
+            self._vacate(slot, req)
+        super()._release_slot(slot, req, completed)
+
+    def _free_pages(self, req: _GenRequest):
+        """The request's side of its leaving (finished, preempted, carried
+        over, handed off): its tail is published and its pages of both
+        classes go back. A requeued request recomputes its window rows
+        with the rest."""
         self._publish_tail(req)
         # free LEAF-first: a chain lookup stops at its first missing
         # block, so under pressure the LRU must reclaim tail pages before
         # the roots that every shared prefix runs through
         self.pool.free(list(reversed(req.pages)))
         req.pages = []
+        if self._window:
+            self.pool_w.free(list(req.window_pages.values()))
+            req.window_pages = {}
+
+    def _vacate(self, slot: int, req: _GenRequest):
+        """The slot's side of a request's leaving: its table rows go to
+        the null page and the next admission may take it. A request whose
+        LAST token (by its count) is in flight vacates at that launch's
+        dispatch, as the serial order frees the slot there, and keeps its
+        PAGES until the token is taken (`_deliver` -> `_release_slot`):
+        the launch in flight names them. A canary window on it closes
+        here: its last launch has been replayed against the shadow."""
+        if not self._kv_quant_debug:
+            self._close_canary(req)
         self._tables[slot] = 0
-        self._free_window_pages(slot, req)
+        if self._window:
+            self._tables_w[slot] = 0
         self._mark_tables_dirty()
         self._mark_temps_dirty()
         if slot in self._admit_order:
             self._admit_order.remove(slot)
-        super()._release_slot(slot, req, completed)
+        self._active[slot] = None
 
     def _evict(self, slot: int):
         """Preempt: free the victim's pages and requeue it (front); its
@@ -874,19 +967,9 @@ class PagedGenerationServer(_GenerationServerBase):
         between (req.seq_tokens() — the prompt itself is never mutated,
         so repeated preemptions cannot double-fold the prefix)."""
         req = self._active[slot]
-        if not self._kv_quant_debug:
-            self._close_canary(req)
-        self._publish_tail(req)
-        self.pool.free(list(reversed(req.pages)))  # leaf-first (see above)
-        req.pages = []
-        self._free_window_pages(slot, req)
+        self._free_pages(req)
         self._reset_prefill_state(req)
-        self._tables[slot] = 0
-        self._mark_tables_dirty()
-        self._mark_temps_dirty()
-        self._active[slot] = None
-        if slot in self._admit_order:
-            self._admit_order.remove(slot)
+        self._vacate(slot, req)
         req.preemptions += 1
         self.preemptions += 1
         self._requeue.insert(0, req)
@@ -933,25 +1016,18 @@ class PagedGenerationServer(_GenerationServerBase):
         the prefix cache first (tail included) and then freed, so when
         the successor adopts this pool its re-admission re-attaches
         whatever content survives the LRU and recomputes only the rest.
-        Not a preemption — futures stay pending, counters untouched."""
+        Not a preemption — futures stay pending, counters untouched.
+        The loop took every pick in flight as it stopped (`_drain`)."""
+        assert not self._flight, "detach with a launch in flight"
         carried: List[_GenRequest] = []
         for slot in list(self._admit_order):
             req = self._active[slot]
             if req is None:
                 continue
-            if not self._kv_quant_debug:
-                self._close_canary(req)
-            self._publish_tail(req)
-            self.pool.free(list(reversed(req.pages)))  # leaf-first
-            req.pages = []
-            self._free_window_pages(slot, req)
+            self._free_pages(req)
             self._reset_prefill_state(req)
-            self._tables[slot] = 0
-            self._active[slot] = None
+            self._vacate(slot, req)
             carried.append(req)
-        self._admit_order.clear()
-        self._mark_tables_dirty()
-        self._mark_temps_dirty()
         carried.extend(self._requeue)
         self._requeue.clear()
         return carried
@@ -1234,16 +1310,6 @@ class PagedGenerationServer(_GenerationServerBase):
         self.window_pages_released += len(behind)
         self._mark_tables_dirty()
 
-    def _free_window_pages(self, slot: int, req: _GenRequest):
-        """A request leaves its slot (finished, preempted, carried over):
-        its window-class pages go back and its table row is nulled. A
-        requeued request recomputes its window rows with the rest."""
-        if not self._window:
-            return
-        self.pool_w.free(list(req.window_pages.values()))
-        req.window_pages = {}
-        self._tables_w[slot] = 0
-
     def _grow_window_pages(self, slot: int, req: _GenRequest) -> bool:
         """Map the blocks the request's next launch needs and does not
         hold yet. False when the window class cannot give them (the
@@ -1280,14 +1346,20 @@ class PagedGenerationServer(_GenerationServerBase):
         pool_invariants.py `check_window_class`)."""
         live = {s: self._active[s] for s in self._admit_order
                 if self._active[s] is not None}
+        # a request whose last token is in flight left its slot and still
+        # owns its pages
+        owners = {r.seq: r for r in live.values()}
+        for rec in self._flight:
+            owners.update((r.seq, r) for _s, r in rec.firsts + rec.rows)
         self.pool.check_invariants(
-            {r.seq: r.pages for r in live.values()})
+            {seq: r.pages for seq, r in owners.items()})
         if not self._window:
             return
         from flexflow_tpu.analysis import pool_invariants
 
         self.pool_w.check_invariants(
-            {r.seq: list(r.window_pages.values()) for r in live.values()})
+            {seq: list(r.window_pages.values())
+             for seq, r in owners.items()})
         rows = {s: (r.window_pages, self._next_rows(r)[0])
                 for s, r in live.items()}
         violations = pool_invariants.check_window_class(
@@ -1314,8 +1386,8 @@ class PagedGenerationServer(_GenerationServerBase):
         answer)."""
         for slot in list(self._admit_order):
             req = self._active[slot]
-            if req is None:
-                continue
+            if req is None or not self._packable(req):
+                continue    # gone, or waiting for its last token only
             target = self._pages_target(req)
             while req is self._active[slot] and len(req.pages) < target:
                 got = self.pool.alloc(1)
@@ -1334,7 +1406,13 @@ class PagedGenerationServer(_GenerationServerBase):
 
     def _preempt_for(self, slot: int):
         """A class of pages cannot give `slot` what its next tick needs:
-        evict the youngest OTHER request, or the starved one itself."""
+        evict the youngest OTHER request, or the starved one itself.
+        Eviction requeues a request with the tokens the HOST has, and
+        frees pages a launch in flight may name: first take what is in
+        flight, and let the caller try the pool again (a request may
+        have left)."""
+        if self._retire("preempt"):
+            return
         victims = [s for s in self._admit_order if s != slot]
         if self.preemption and victims:
             self._evict(victims[-1])  # youngest other request
@@ -1478,9 +1556,12 @@ class PagedGenerationServer(_GenerationServerBase):
         if self._tables_dev is None:
             # with window layers, a table a class: (2, slots, max_pages),
             # the full class's first (Executor.page_classes)
+            # a COPY: the host writes `_tables` in place while a launch
+            # handed this upload is still in flight, and the CPU backend
+            # aliases a numpy buffer it is given
             self._tables_dev = jnp.asarray(
                 np.stack([self._tables, self._tables_w]) if self._window
-                else self._tables)
+                else self._tables.copy())
         return self._tables_dev
 
     def _temps_device(self):
@@ -1494,6 +1575,22 @@ class PagedGenerationServer(_GenerationServerBase):
                 [self._active[s].temperature if self._active[s] else 0.0
                  for s in range(self.slots)], np.float32))
         return self._temps_dev
+
+    def _index_device(self, index):
+        """A small int32 index vector on the device, uploaded once a
+        VALUE: which entries of a launch read `_newest` and which slots a
+        pick writes there change only when the slots' occupants do, so an
+        iteration that launches ahead adds no upload to the launch
+        before's (0.35 ms each on the chip, PERF.md section 6)."""
+        import jax.numpy as jnp
+
+        key = index.tobytes()
+        hit = self._index_dev.get(key)
+        if hit is None:
+            if len(self._index_dev) >= 1024:
+                self._index_dev.clear()
+            hit = self._index_dev[key] = jnp.asarray(index)
+        return hit
 
     def _chain_descriptor_device(self, B, window):
         """Cached device copies of the default causal-chain descriptor
@@ -1515,7 +1612,9 @@ class PagedGenerationServer(_GenerationServerBase):
     def _launch(self, items, window, tr, ntr):
         """Run ONE ragged step over packed work items. Each item is
         (slot, pos, tokens, depths, anc): `tokens` the item's q_len <=
-        window live token ids, depths/anc None for the causal-chain
+        window live token ids ([ON_DEVICE]: ONE row whose id is the
+        slot's newest token on the device, which the host has not seen
+        yet), depths/anc None for the causal-chain
         default (decode rows, chunk pieces) or the (window,) node depths
         and (window, window) ancestor relation of a drafted tree. Rows
         past an item's q_len are padding: the kernel skips them and the
@@ -1534,6 +1633,7 @@ class PagedGenerationServer(_GenerationServerBase):
             pos = np.zeros((B,), np.int32)
             qls = np.zeros((B,), np.int32)
             slot_idx = np.zeros((B,), np.int32)
+            feed = np.full((B,), -1, np.int32)
             # the causal-chain default (decode rows, chunk pieces) is a
             # pure function of the launch shape — reuse its device copy
             # instead of re-uploading it every tick; only drafted trees
@@ -1546,7 +1646,10 @@ class PagedGenerationServer(_GenerationServerBase):
                               (B, 1, 1))
             for i, (slot, p, toks, d, a) in enumerate(items):
                 ql = len(toks)
-                ids[i, :ql] = toks
+                if ql == 1 and toks[0] == ON_DEVICE:
+                    feed[i] = slot
+                else:
+                    ids[i, :ql] = toks
                 pos[i] = p
                 qls[i] = ql
                 slot_idx[i] = slot
@@ -1572,10 +1675,20 @@ class PagedGenerationServer(_GenerationServerBase):
                                axis=1 if self._window else 0)
             pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
                                    jnp.asarray(ids))
+            fed = (self._index_device(feed), self._newest)
         total = B * window
         padded = total - int(qls.sum())
+        # AHEAD: an earlier launch is not known to be done, so the chip
+        # has this one queued before it runs dry
+        ahead = self._synced < self.launches
+        self.launches += 1
+        self.launches_ahead += ahead
+        if not ahead:
+            self.launches_drained[self._last_fence] = (
+                self.launches_drained.get(self._last_fence, 0) + 1)
         with obs.span("launch_dispatch") as sp:
             if sp:
+                sp.set(launches=1, ahead=int(ahead))
                 # what the ragged kernel has to walk for THIS launch,
                 # counted at the launch: KV rows and pages of the items
                 # with work, the blocks of block_pages pages it walks
@@ -1622,7 +1735,7 @@ class PagedGenerationServer(_GenerationServerBase):
                 sp.set(weight_bytes=self._weight_bytes)
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
-                ids_d)
+                ids_d, feed=fed)
             stats = upd.pop(LAUNCH_STATS, None)
             if stats is not None:
                 # a (layers, 4) device array: read later, in bulk and long
@@ -1641,7 +1754,7 @@ class PagedGenerationServer(_GenerationServerBase):
             # materializes it into the kv_quant_error gauge on scrape
             probs_ref, upd_ref = self._step(
                 tr, ntr, self._caches_ref, tbl, pos_d, qls_d, deps_d,
-                anc_d, ids_d)
+                anc_d, ids_d, feed=fed)
             self._caches_ref = upd_ref
             live_rows = jnp.asarray(
                 np.arange(window)[None, :] < qls[:, None])
@@ -1698,18 +1811,32 @@ class PagedGenerationServer(_GenerationServerBase):
         return self._rows_at(tail, at)
 
     def _warm_riders(self, probs):
-        self._rider_rows(probs, np.zeros((self.slots,), np.int32))
+        import jax
+        import jax.numpy as jnp
+
+        rows = self._rider_rows(probs, np.zeros((self.slots,), np.int32))
+        # a pick's way into `_newest`, at both widths (a first token, the
+        # decode rows), from picks of the kind a tick makes; every slot
+        # index is past the last, so nothing is written
+        for n in (1, self.slots):  # fflint: host-ok (one-time warmup)
+            picked = self._pick(rows[:n], jnp.zeros((n,), jnp.float32),
+                                jax.random.key(0))
+            self._set_newest(self._newest, picked, jnp.asarray(
+                np.full((n,), self.slots, np.int32)))
 
     def _tick_prep(self) -> Optional[List[int]]:
         """Shared tick prologue (base and speculative loops): defrag if
-        requested, admit, grow pages. Returns the live slots (decoding
-        AND mid-prefill), or None when this tick should be skipped
-        (nothing live; sleeps briefly when nothing was admitted
-        either)."""
+        requested, admit, grow pages. Returns the slots with work to
+        launch (decoding AND mid-prefill; not a slot whose request has
+        its last token in flight), or None when this tick should be
+        skipped (nothing to launch; sleeps briefly when nothing was
+        admitted or in flight either). Admission runs beside a launch in
+        flight: it touches free slots and free pages only."""
         with obs.span("tick_prep") as sp:
             obs.beacon()    # ties the spans' clock to a device trace's
             if self._defrag_req.is_set():
                 self._defrag_req.clear()
+                self._retire("defrag")      # no page moves under a launch
                 with obs.span("defrag"):
                     self._apply_defrag()
             with obs.span("admit_pending"):
@@ -1722,8 +1849,12 @@ class PagedGenerationServer(_GenerationServerBase):
                                        if self._mid_prefill(s)),
                        pages_in_use=self.pool.pages_in_use,
                        admitted=admitted)
-            if not live:
-                if not admitted:
+            if not any(self._packable(self._active[s]) for s in live):
+                # nothing to launch: whoever is still in a slot waits for
+                # a token in flight. Take it now (a finished request
+                # leaves, and the next pass admits into its slot), never
+                # sleep on it
+                if not self._retire("idle") and not admitted:
                     # idle/busy-wait time is charged to its own span so a
                     # trace separates "waiting for work" from real prep
                     t0 = time.monotonic()
@@ -1733,7 +1864,8 @@ class PagedGenerationServer(_GenerationServerBase):
                     self._c_idle_s.inc(time.monotonic() - t0)
                 return None
             self._ensure_pages()  # may preempt: recompute live after
-            return self._live() or None
+            return [s for s in self._live()
+                    if self._packable(self._active[s])] or None
 
     def _split_live(self, live):
         """(mid-prefill slots, decoding slots) for this tick."""
@@ -1759,8 +1891,17 @@ class PagedGenerationServer(_GenerationServerBase):
         q_len 1 item each after the chunk's pieces: the weights are
         streamed once for the iteration. Their rows are not sampled
         here; returns what `_decode_tick` picks them from (None without
-        `dec`)."""
-        waiting = any(not self._mid_prefill(s) for s in self._live())
+        `dec`).
+
+        After the dispatch the tick ADVANCES what needs no token value
+        (prefill positions, the prompt's pages published, window pages
+        released) and picks a finishing prompt's first token ON THE
+        DEVICE; the host takes it a launch later (`_retire`). Without
+        `dec` the tick also takes the launch before's picks here, as
+        `_decode_tick` does otherwise."""
+        waiting = any(not self._mid_prefill(s)
+                      and self._packable(self._active[s])
+                      for s in self._live())
         self.iterations_with_both += waiting
         self.one_launch += bool(dec)
         budget = self.prefill_chunk
@@ -1801,44 +1942,42 @@ class PagedGenerationServer(_GenerationServerBase):
         at = np.full((self.slots,), self.slots - 1, np.int32)
         for j, s in enumerate(dec):
             at[s] = self.slots - len(dec) + j
-            items.append((s, self._active[s].pos, [int(self._tokens[s])],
+            items.append((s, self._active[s].pos, self._row_ids(s),
                           None, None))
         probs, padded, total = self._launch(items, W, tr, ntr)
         with obs.span("commit") as csp:
-            first = []  # seq of each request that got its first token
+            # ADVANCE: what the launch did whatever its tokens are
+            done = []
             for (s, req, start, take), (i, r) in zip(plan, ends):
                 req.prefill_pos = start + take
                 req.prefill_tokens += take
                 self._publish_prefix(req, req.prefill_pos)
-                if req.prefill_pos < req.prefill_target:
-                    self._release_window_pages(s, req)
                 if req.prefill_pos >= req.prefill_target:
                     # publish the PROMPT's partial tail now, before
                     # decode appends rows to the same page: the entry
                     # only names rows [0, tail) and those are immutable,
                     # so an identical or extending prompt can COW-clone
                     # this page while this request keeps decoding into
-                    # it (the first token is appended below, so
-                    # seq_tokens() still equals prefill_seq here)
+                    # it (no token is appended yet, so seq_tokens()
+                    # still equals prefill_seq here)
+                    req.pos = req.prefill_target
                     self._publish_tail(req)
-                    with obs.span("sample"):
-                        # the last real row, (1, V): one warmed program
-                        # a launch shape (serving.probs_rows)
-                        row = self._probs_rows(probs, np.int32(i),
-                                               np.int32(r), self.slots)[0]
-                    self._sample_first_token(s, req, row)
-                    first.append(req.seq)
-                    self._release_window_pages(s, req)
-                    self._finish_if_done(s)
-                    if self._active[s] is not None:
-                        # disagg hook: a PrefillWorker hands the request
-                        # off to its decode worker here instead of
-                        # decoding it
-                        self._on_prefill_complete(s)
+                    done.append((s, req, i, r))
+                self._release_window_pages(s, req)
             if csp:
-                csp.set(rids=first,
-                        finished=sum(1 for _s, req, _a, _t in plan
-                                     if req.future.done()))
+                csp.set(finishing=len(done))
+        for s, req, i, r in done:
+            with obs.span("sample"):
+                # the last real row, (1, V): one warmed program a launch
+                # shape (serving.probs_rows)
+                row = self._probs_rows(probs, np.int32(i), np.int32(r),
+                                       self.slots)[0]
+                self._keep_pick(self._pick_first_token(req, row),
+                                np.array([s], np.int32), [(s, req)], True)
+            if self._last_in_flight(req):
+                self._vacate(s, req)
+        if not dec:
+            self._retire()
         chunked = self.prefill_chunk - budget
         self._g_waste.set(padded / total if total else 0.0)
         if sp:
@@ -1858,28 +1997,29 @@ class PagedGenerationServer(_GenerationServerBase):
     def _decode_tick(self, live, tr, ntr, rode=None):
         """One single-token decode tick for the decoding slots: sample
         their rows at their slot index with the one shared `_pick`
-        split, fetch, commit. Their rows come from this tick's own
-        (slots, 1) launch, or, in an iteration with a chunk, from the
-        chunk's launch they rode (`rode`, what `_prefill_tick` returned):
-        the tick then launches nothing and its `fetch` waits for the
-        chunk's launch. Mid-prefill slots count the tick as
-        decode/prefill overlap. (Also dispatched by the speculative
-        server when no live slot can use a tree — all-sampled ticks skip
-        the tree-verify FLOPs.)"""
+        split, ON THE DEVICE, advance their positions, and take the
+        picks of the launch BEFORE (`fetch`, the deliver `commit`): this
+        tick's own are the next tick's. Their rows come from this tick's
+        own (slots, 1) launch, or, in an iteration with a chunk, from
+        the chunk's launch they rode (`rode`, what `_prefill_tick`
+        returned): the tick then launches nothing. Mid-prefill slots
+        count the tick as decode/prefill overlap. (Also dispatched by
+        the speculative server when no live slot can use a tree —
+        all-sampled ticks skip the tree-verify FLOPs.)"""
         import jax
 
         t0 = time.monotonic()
         sp = obs.span("decode_tick").__enter__()
         if sp:
-            sp.set(live=len(live), pages_in_use=self.pool.pages_in_use)
+            sp.set(live=len(live), pages_in_use=self.pool.pages_in_use,
+                   rids=[self._active[s].seq for s in live])
         if rode is None:
             # one item per slot — q_len 1 for the decoding slots, 0 for
             # idle ones (no work, writes to the null page), so the launch
             # compiles once for (slots, 1) and probs stays slot-indexed
             dec = set(live)
             items = [(s, self._active[s].pos if s in dec else 0,
-                      [int(self._tokens[s])] if s in dec else [],
-                      None, None)
+                      self._row_ids(s) if s in dec else [], None, None)
                      for s in range(self.slots)]
             probs, padded, total = self._launch(items, 1, tr, ntr)
             self._g_waste.set(padded / total if total else 0.0)
@@ -1890,37 +2030,29 @@ class PagedGenerationServer(_GenerationServerBase):
                     else self._rider_rows(*rode))
             self._rng, sub = jax.random.split(self._rng)
             picked = self._pick(rows, self._temps_device(), sub)
-        with obs.span("fetch") as fsp:
-            # the host's wait for the device: step, pick and the copy out
-            toks = np.asarray(picked)
-            if fsp:
-                fsp.set(bytes=int(toks.nbytes))
-        self._steps += 1
-        # one host round-trip bought len(live) tokens — the same
-        # counters the megastep path feeds, so N=1 vs N>1 compare
-        self._c_rt.inc()
-        self._c_dtok.inc(len(live))
-        if self._c_dtok.value:
-            self._g_rt_tok.set(self._c_rt.value / self._c_dtok.value)
-        for s in self._admit_order:
-            if self._mid_prefill(s):
-                self._active[s].decode_overlap_ticks += 1
-        with obs.span("commit") as csp:
-            if csp:
-                rids = [self._active[s].seq for s in live]
-                csp.set(rids=rids)
-                sp.set(rids=rids)
+            at = np.full((self.slots,), self.slots, np.int32)
+            at[live] = live
+            self._keep_pick(picked, at,
+                            [(s, self._active[s]) for s in live], False)
+        with obs.span("commit"):
+            # ADVANCE: every decoding slot wrote one row
+            self._steps += 1
+            # one host round-trip bought len(live) tokens — the same
+            # counters the megastep path feeds, so N=1 vs N>1 compare
+            self._c_rt.inc()
+            self._c_dtok.inc(len(live))
+            if self._c_dtok.value:
+                self._g_rt_tok.set(self._c_rt.value / self._c_dtok.value)
+            for s in self._admit_order:
+                if self._mid_prefill(s):
+                    self._active[s].decode_overlap_ticks += 1
             for s in live:
                 req = self._active[s]
                 req.pos += 1
-                req.tokens.append(int(toks[s]))
-                self._tokens[s] = toks[s]
-                self._publish_prefix(req, req.pos)
                 self._release_window_pages(s, req)
-                self._finish_if_done(s)
-            if csp:
-                csp.set(finished=sum(1 for s in live
-                                     if self._active[s] is None))
+                if self._last_in_flight(req):
+                    self._vacate(s, req)
+        self._retire()
         sp.__exit__(None, None, None)
         dt = time.monotonic() - t0
         self._h_tick.observe(dt)
@@ -1928,6 +2060,115 @@ class PagedGenerationServer(_GenerationServerBase):
         led = obs.ledger()
         if led is not None:
             led.record("decode", dt, batch=len(live))
+
+    # -- launch ahead: picks in flight, and the fence ----------------------
+
+    def _packable(self, req: _GenRequest) -> bool:
+        """Whether a slot's request has a row for the next launch: not
+        once the host knows it stopped (an EOS, learnt a launch late: it
+        stays in its slot until the row in flight is taken)."""
+        return not self._finished(req)
+
+    def _last_in_flight(self, req: _GenRequest) -> bool:
+        """Whether the request's last token, by its count, is picked."""
+        return len(req.tokens) + req.unseen >= req.max_new
+
+    def _row_ids(self, slot: int):
+        """A decode row's token ids for `_launch`: the host's newest
+        token of the slot, or ON_DEVICE while that token is still in
+        flight (the launch then reads it from `_newest`)."""
+        if self._active[slot].unseen:
+            return [ON_DEVICE]
+        return [int(self._tokens[slot])]
+
+    def _keep_pick(self, picked, at, owners, first: bool):
+        """A pick of the launch just dispatched STAYS ON THE DEVICE: its
+        tokens go into `_newest` at the slots `at`, where the next
+        launch's decode rows read them, and the launch joins `_flight`
+        with the (slot, request) pairs the host owes a token."""
+        self._newest = self._set_newest(self._newest, picked,
+                                        self._index_device(at))
+        if not self._flight or self._flight[-1].seq != self.launches:
+            self._flight.append(_Flight(self.launches))
+        rec = self._flight[-1]
+        (rec.firsts if first else rec.rows).extend(owners)
+        rec.newest = self._newest
+        for _s, req in owners:
+            req.unseen += 1
+
+    def _retire(self, reason: Optional[str] = None) -> bool:
+        """Take the picks of launches in flight: fetch, then DELIVER what
+        needs the values (append, generated pages published, EOS, first-
+        token stamps, release, the futures). Without a `reason` it is the
+        pipeline's own step, called right after a dispatch: every launch
+        but the one just dispatched. With one it is the FENCE, for
+        whatever needs the host's truth of every token: every launch,
+        counted under `reason` when one was in flight. Returns whether
+        anything was taken."""
+        upto = self.launches if reason else self.launches - 1
+        if not self._flight or self._flight[0].seq > upto:
+            return False
+        if reason:
+            self.fences[reason] = self.fences.get(reason, 0) + 1
+            self._last_fence = reason
+        while self._flight and self._flight[0].seq <= upto:
+            self._deliver(self._flight.popleft())
+        return True
+
+    def _deliver(self, rec: _Flight):
+        with obs.span("fetch") as fsp:
+            # the host's wait for the device: step, picks and the copy
+            # out, of the launch BEFORE the one just dispatched
+            toks = np.asarray(rec.newest)
+            if fsp:
+                fsp.set(bytes=int(toks.nbytes))
+        self._synced = max(self._synced, rec.seq)
+        with obs.span("commit") as csp:
+            rids, late = [], 0
+            for s, req in rec.firsts:
+                req.unseen -= 1
+                self._take_first_token(s, req, int(toks[s]))
+                rids.append(req.seq)
+                self._finish_if_done(s, req)
+                if self._active[s] is req and not self._finished(req):
+                    # disagg hook: a PrefillWorker hands the request off
+                    # to its decode worker here instead of decoding it
+                    self._on_prefill_complete(s)
+            for s, req in rec.rows:
+                req.unseen -= 1
+                if self._finished(req):
+                    # it stopped (EOS) in the launch before, which the
+                    # host learnt after this row was dispatched: the row
+                    # emits nothing and its K/V row is forgotten
+                    late += 1
+                    req.pos -= 1
+                else:
+                    tok = int(toks[s])
+                    req.tokens.append(tok)
+                    if self._active[s] is req:
+                        self._tokens[s] = tok
+                    rids.append(req.seq)
+                    # rows whose K/V is written AND whose token the host
+                    # has: all but the newest token's
+                    self._publish_prefix(
+                        req, len(req.prompt) + len(req.tokens) - 1)
+                self._finish_if_done(s, req)
+            self.late_stop_rows += late
+            if csp:
+                # the requests that gained a token HERE (token_gap reads
+                # the span's end as the token's time)
+                csp.set(rids=rids, late_stop_rows=late,
+                        finished=sum(1 for _s, req in rec.firsts + rec.rows
+                                     if req.future.done()))
+
+    def _finish_if_done(self, slot: int, req: Optional[_GenRequest] = None):
+        req = req or self._active[slot]
+        if req is not None and req.unseen:
+            # a launch in flight still names its pages (the row
+            # dispatched before the host learnt of the stop): it leaves
+            # when that launch is taken, not before
+            return
+        super()._finish_if_done(slot, req)
 
     def _decode_megastep(self, live, tr, ntr):
         """Up to `megastep_ticks` decode ticks in ONE jitted dispatch
@@ -1984,6 +2225,7 @@ class PagedGenerationServer(_GenerationServerBase):
         # the ONE host sync of the megastep: token buffer + finish
         # flags + tick count in a single transfer
         out_np, done_np, n = jax.device_get((out, done, ticks))
+        self._synced = self.launches    # the chip ran dry behind it
         n = int(n)
         if done_np.any():
             reason = "finish"
@@ -2064,7 +2306,11 @@ class PagedGenerationServer(_GenerationServerBase):
         Returns True when the tick was handled."""
         if self._mixed_fn is None or self._caches_ref is not None:
             return False
-        self._mixed_megastep(live, tr, ntr)
+        # an option-set path keeps its own order: entered drained
+        if self._retire("megastep"):
+            live = self._live()
+        if live:
+            self._mixed_megastep(live, tr, ntr)
         return True
 
     def _mixed_megastep(self, live, tr, ntr):
@@ -2142,6 +2388,7 @@ class PagedGenerationServer(_GenerationServerBase):
         out_np, cnt_np, done_np, pf_np, n = jax.device_get(
             (out, cnt, done, pf_fin, ticks))
         fence_s = time.monotonic() - f0
+        self._synced = self.launches    # the chip ran dry behind it
         if self.overlap_dispatch:
             wait = host_s + fence_s
             self._g_overlap.set(host_s / wait if wait > 0 else 0.0)
@@ -2265,9 +2512,13 @@ class PagedGenerationServer(_GenerationServerBase):
             if dec:
                 self._decode_tick(dec, tr, ntr, rode)
         elif self._megastep is not None:
+            # an option-set path keeps its own order: entered drained.
             # _decode_megastep stands down by itself while a canary
             # window is open (the fp32 shadow must observe every launch)
-            self._decode_megastep(dec, tr, ntr)
+            if self._retire("megastep"):
+                dec = [s for s in dec if self._active[s] is not None]
+            if dec:
+                self._decode_megastep(dec, tr, ntr)
         else:
             self._decode_tick(dec, tr, ntr)
 
@@ -2280,6 +2531,16 @@ class PagedGenerationServer(_GenerationServerBase):
                 self._host_tick(live, tr, ntr)
 
     def _drain(self):
+        # what the launches in flight emitted is the callers': take it
+        # before anything is cancelled or carried over to a successor
+        try:
+            self._retire("swap" if self._detaching else "stop")
+        except Exception:  # the loop died in a launch: its picks with it
+            for rec in self._flight:
+                for _s, req in rec.firsts + rec.rows:
+                    if not req.future.done():
+                        req.future.cancel()
+            self._flight.clear()
         super()._drain()
         for req in self._requeue:
             if not req.future.done():

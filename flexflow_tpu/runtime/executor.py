@@ -921,17 +921,23 @@ class Executor:
         # given (donate_argnums) and return it written in place, so there
         # is one live buffer a pool from here on (docs/paged.md "Who owns
         # the pool")
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        where = (NamedSharding(self.mesh, PartitionSpec())
-                 if self.mesh is not None else jax.devices()[0])
+        where = self.launch_placement()
         return jax.tree.map(
             lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype), where),
             specs)
 
+    def launch_placement(self):
+        """Where a serving launch puts its outputs (replicated over the
+        model's mesh): what a buffer that re-enters launches is born
+        committed to, so a launch shape has one jit signature."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return (NamedSharding(self.mesh, PartitionSpec())
+                if self.mesh is not None else jax.devices()[0])
+
     def ragged_step_fn(self):
         """jitted (params, pools, page_tables, pos, q_lens, depths, anc,
-        ids) -> (probs, new_pools): ONE ragged paged step over a packed
+        ids, feed=None) -> (probs, new_pools): ONE ragged paged step over a packed
         batch of work items — decode rows, prefill chunks and drafted
         trees in the same launch (flexflow_tpu.paged.attention). Each
         batch entry b carries q_lens[b] live rows of the (B, S) ids
@@ -940,7 +946,11 @@ class Executor:
         window visibility; entries padded to the launch shape pass
         q_len 0 and do no work. Compiled once per (B, S) launch shape —
         the scheduler packs items into a small set of launch shapes, so
-        admission order and work mix never recompile it.
+        admission order and work mix never recompile it. `feed`, a
+        server's ((B,) int32 slot or -1, (slots,) int32 newest tokens),
+        takes an entry's first id from the device (a decode row of the
+        launch after the one that picked its token); without it the
+        program is the one it always was.
 
         The pools are DONATED: the K/V rows are scattered into the
         buffers passed in, which are gone for the caller (rebind the
@@ -951,7 +961,16 @@ class Executor:
             return self._ragged_step_fn
 
         def step(trainable, nontrainable, caches, page_tables, pos,
-                 q_lens, depths, anc, *inputs):
+                 q_lens, depths, anc, *inputs, feed=None):
+            if feed is not None:
+                # LAUNCH AHEAD: the first id of an entry whose `slot` is
+                # not -1 is that slot's newest token, which the launch
+                # before picked and the host has not seen yet
+                slot, newest = feed
+                ids = inputs[0]
+                fed = jnp.where(slot >= 0, newest[jnp.maximum(slot, 0)],
+                                ids[:, 0])
+                inputs = (ids.at[:, 0].set(fed),) + inputs[1:]
             cache_out = {}
             out, state, _ = self.run_forward(
                 trainable, nontrainable, inputs, training=False,
@@ -1382,7 +1401,7 @@ class Executor:
                 + len(self._megastep_fns))
 
     def warm_launch_shapes(self, catalog, *, params, eos_id=None,
-                           on_probs=None) -> Dict:
+                           on_probs=None, newest=None) -> Dict:
         """Pre-compile every launch shape in a shapecheck catalog
         (analysis.shapecheck.enumerate_catalog) so first-request TTFT
         stops paying compile cost and steady-state serving provably
@@ -1429,7 +1448,9 @@ class Executor:
         from slices of probs_ref and splits of rng_ref. `on_probs`, if
         given, is called with every ragged shape's (B, W, V) output, so
         the caller can warm what it runs on a launch's probs at that
-        shape."""
+        shape. `newest`, a paged server's device vector of its slots'
+        newest tokens, is fed to every ragged shape as the server feeds
+        it (`ragged_step_fn`'s `feed`)."""
         import contextlib
         import time
 
@@ -1454,6 +1475,13 @@ class Executor:
                 num_pages, page_size, dtype=pool_dt,
                 num_pages_window=cfg.get("num_pages_window"))
             step = self.ragged_step_fn()
+
+            def feed(B):
+                if newest is None:
+                    return {}
+                return {"feed": (jnp.asarray(np.full((B,), -1, np.int32)),
+                                 newest)}
+
             # a graph with window layers launches with a table a class
             two = self.page_classes() is not None
             for B, W in entries.get(  # fflint: host-ok (one-time warmup)
@@ -1487,7 +1515,8 @@ class Executor:
                     t0 = time.monotonic()
                     with (compile_split() if sp
                           else contextlib.nullcontext()) as split:
-                        probs, caches = step(tr, ntr, caches, *args)
+                        probs, caches = step(tr, ntr, caches, *args,
+                                             **feed(B))
                     if sp:
                         # for the record of set-up: what THIS shape's
                         # first call cost (jax's own compile phases; they

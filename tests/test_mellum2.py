@@ -141,11 +141,14 @@ def _served_logp(ff, prompts, new_tokens, **server):
 
     def launch(items, window, tr, ntr):
         for s, pos, toks, _d, _a in items:
+            if not len(toks):
+                continue        # an idle slot's entry of a decode launch
             rid = srv._active[s].seq
             for b in {(pos + i) // PAGE for i in range(len(toks))}:
                 writers.setdefault(int(srv._tables_w[s, b]), set()).add(rid)
         out = real(items, window, tr, ntr)
-        launches.append(([(srv._active[s].seq, pos, len(toks))
+        launches.append(([(srv._active[s].seq if len(toks) else None,
+                           pos, len(toks))
                           for s, pos, toks, _d, _a in items],
                          np.asarray(out[0], np.float64)))
         srv._check_invariants()

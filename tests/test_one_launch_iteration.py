@@ -321,9 +321,11 @@ def test_iteration_with_both_keeps_its_spans(served, family):
         pre = dict(it)["prefill_tick"]
         assert (pre["decode_waiting"], pre["decode_rode"]) == (1, 1)
         dec = dict(it)["decode_tick"]
-        # the decode tick's commit names the requests that gained a token
+        # the tick names the requests whose rows it picked; its LAST
+        # commit takes the launch BEFORE's tokens and names who gained
+        # one there (tests/test_launch_ahead.py holds the two together)
         commits = [a for n, a in it if n == "commit"]
-        assert dec["rids"] == commits[-1]["rids"] and dec["rids"]
+        assert dec["rids"] and "rids" in commits[-1]
         assert {"sample", "fetch"} <= set(names)
     # iterations with a chunk and nobody decoding say so
     alone = [dict(it)["prefill_tick"] for it in _iterations(one.spans)
@@ -342,6 +344,13 @@ READERS = {
     "step_ms.decode": lambda run: tick_median.read(run, "decode_only"),
     "host_ms.prefill": lambda run: iteration_host.read(run, "with_prefill"),
     "token_gap_p99": lambda run: token_gap.read(run, 99),
+    # PR 37's two, one reader: `ahead` over `launches` of the launch spans
+    # (tests/test_launch_ahead.py holds them to the server's counters and
+    # leaves them out at a program without the keys)
+    "launch_ahead_share.prefill": lambda run: span_counter.read(
+        run, "launch_dispatch", "ahead", over="launches", scale=100),
+    "launch_ahead_share.decode": lambda run: span_counter.read(
+        run, "launch_dispatch", "ahead", over="launches", scale=100),
 }
 
 

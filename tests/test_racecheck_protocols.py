@@ -5,8 +5,10 @@ DisaggPair and ServingAutopilot.
 
 Contracts under test: every protocol model — prefill->decode handoff,
 concurrent spill/fetch/admission against the bounded host tier,
-drain-and-swap under live submits, and the overlapped megastep
-dispatch fence (ISSUE 20) — is FULLY explored violation-free at
+drain-and-swap under live submits, the overlapped megastep dispatch
+fence (ISSUE 20) and the per-tick loop's one-deep launch pipeline
+(ISSUE 37: a page named by a launch in flight is neither freed nor
+moved, admission runs beside it) — is FULLY explored violation-free at
 the default context-switch bound (the explored/distinct state counts
 are pinned: a model edit that shrinks the space is as suspicious as one
 that breaks an invariant); sleep-set pruning is sound (the pruned and
@@ -43,6 +45,7 @@ _CLEAN_SPACE = {
     "swap": (149, 117),
     "tierpool": (16, 15),
     "dispatch": (58, 40),
+    "launch_ahead": (236, 96),
 }
 
 
@@ -77,6 +80,9 @@ def test_sleep_set_pruning_is_sound():
     ("swap", "no_safepoint_join", "swap-during-handoff"),
     ("dispatch", "read_before_fence", "dispatch-buffer-owner"),
     ("dispatch", "admit_steals_live_page", "stale-page-table"),
+    ("launch_ahead", "read_before_fence", "dispatch-buffer-owner"),
+    ("launch_ahead", "free_at_late_stop", "stale-page-table"),
+    ("launch_ahead", "defrag_without_fence", "stale-page-table"),
 ])
 def test_seeded_mutation_produces_named_minimal_counterexample(
         model, mutation, invariant):
