@@ -14,8 +14,12 @@ parses the optimized HLO into a structured program summary —
 
   - the collective schedule: kind / replica groups / payload bytes per
     all-reduce, all-gather, all-to-all, collective-permute,
-    reduce-scatter, attributed back to PCG nodes through the stable-key
-    jax.named_scope the executor stamps into HLO metadata op_names;
+    reduce-scatter, attributed back to PCG nodes, to the phase of the
+    step (forward / recompute / backward / optimizer) and to the mesh
+    axes its replica groups span, through the jax.named_scopes the
+    executor stamps into HLO metadata op_names: obs.scopes.classify and
+    group_axes, the one reader of those names, which the benchmark's
+    reader of a device trace shares;
   - transpose/copy overhead bytes (the round-4 backward-layout audit,
     folded in from tools/hlo_transpose_audit.py — one HLO parser in the
     tree);
@@ -61,6 +65,7 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from flexflow_tpu.analysis import AnalysisContext, Finding, register_pass
+from flexflow_tpu.obs import scopes
 
 # ---------------------------------------------------------------------------
 # HLO text parsing (the one HLO parser in the tree; the transpose audit
@@ -143,8 +148,6 @@ _COLL_RE = re.compile(
     r"%?[\w.\-]+ = (\((?:[^()]|\([^()]*\))*\)|\S+) ("
     + "|".join(_COLL_KINDS) + r")(-start)?\("
 )
-_GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
-_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
@@ -162,7 +165,10 @@ class LoweredCollective:
     plumbing (threefry counter exchanges under dropout): real wire
     traffic, but proportional to mask bits, attributed to whatever op
     holds the dropout — the cost model never prices it and the diff
-    skips it (the bytes stay visible in the schedule stats)."""
+    skips it (the bytes stay visible in the schedule stats). `node` and
+    `phase` are obs.scopes.classify's reading of `op_name`; `axes` the
+    mesh axes the replica groups span (obs.scopes.group_axes), empty
+    when the parser was given no mesh."""
 
     kind: str
     payload: int
@@ -171,6 +177,8 @@ class LoweredCollective:
     op_name: str
     line: str
     rng: bool = False
+    phase: Optional[str] = None
+    axes: Tuple[str, ...] = ()
 
     @property
     def comm_class(self) -> str:
@@ -204,6 +212,19 @@ class HLOSummary:
                 d["rng_bytes"] += c.payload
         return out
 
+    def schedule_by_axes(self) -> Dict[str, Dict[str, float]]:
+        """{"<axes>/<phase>": {count, payload_bytes}}: the same collectives
+        by the mesh axes they cross and the phase of the step they belong
+        to (`model/forward`, `data/backward`), which is how the
+        benchmark's reader of a device trace names them."""
+        out: Dict[str, Dict[str, float]] = {}
+        for c in self.collectives:
+            d = out.setdefault(f"{scopes.axes_label(c.axes)}/{c.phase}",
+                               {"count": 0, "payload_bytes": 0})
+            d["count"] += 1
+            d["payload_bytes"] += c.payload
+        return out
+
 
 def peak_from_memory_stats(mem) -> Optional[int]:
     """Per-chip peak bytes from a CompiledMemoryStats (or the dict the
@@ -218,14 +239,35 @@ def peak_from_memory_stats(mem) -> Optional[int]:
     return int(peak) if peak > 0 else None
 
 
+def collective_payload(line: str) -> Optional[Tuple[str, int, int]]:
+    """(kind, payload bytes, replica-group size) of one HLO line when it
+    is a collective instruction (sync, or the `-start` of an async pair;
+    a `-done` is None, so a payload counts once), by LoweredCollective's
+    byte conventions. The line may be an instruction of a module's text
+    or the name of a device-trace event, which is the same line."""
+    m = _COLL_RE.match(line)
+    if not m:
+        return None
+    result_bytes = _payload_bytes(m.group(1), bool(m.group(3)))
+    kind = m.group(2)
+    groups = scopes.replica_groups(line)
+    group_size = len(groups[0]) if groups else 1
+    payload = result_bytes
+    if kind == "reduce-scatter":
+        payload = result_bytes * max(group_size, 1)
+    return kind, payload, group_size
+
+
 def parse_hlo_module(txt: str, node_keys: Sequence[str],
-                     memory=None) -> HLOSummary:
+                     memory=None,
+                     mesh_axes: Optional[Dict[str, int]] = None
+                     ) -> HLOSummary:
     """Parse one optimized HLO module: every collective instruction
-    (kind, replica-group size, payload bytes, attributed PCG node via the
-    stable-key named_scope in metadata op_name) plus transpose/copy
-    overhead totals."""
-    # longest keys first so 'l0_attn_12' wins over a prefix key
-    keys = sorted(node_keys, key=len, reverse=True)
+    (kind, replica-group size, payload bytes; PCG node and phase of the
+    step through obs.scopes.classify from the named scopes in metadata
+    op_name; with `mesh_axes`, {axis: size} in the mesh's order, the axes
+    its groups span) plus transpose/copy overhead totals."""
+    keys = scopes.sorted_keys(node_keys)
     colls: List[LoweredCollective] = []
     t_bytes = c_bytes = 0
     for line in txt.splitlines():
@@ -238,26 +280,19 @@ def parse_hlo_module(txt: str, node_keys: Sequence[str],
             else:
                 c_bytes += b
             continue
-        m = _COLL_RE.match(s)
-        if not m:
+        coll = collective_payload(s)
+        if coll is None:
             continue
-        result_bytes = _payload_bytes(m.group(1), bool(m.group(3)))
-        kind = m.group(2)
-        g = _GROUPS_RE.search(s)
-        if g:
-            group_size = len(g.group(1).split(","))
-        else:
-            g = _GROUPS_IOTA_RE.search(s)
-            group_size = int(g.group(2)) if g else 1
-        payload = result_bytes
-        if kind == "reduce-scatter":
-            payload = result_bytes * max(group_size, 1)
+        kind, payload, group_size = coll
         om = _OPNAME_RE.search(s)
         op_name = om.group(1) if om else ""
-        node = next((k for k in keys if k in op_name), None)
+        phase, node = scopes.classify(op_name, keys)
         rng = any(mk in op_name for mk in _RNG_MARKERS)
+        axes = (scopes.group_axes(scopes.collective_groups(s), mesh_axes)
+                if mesh_axes else ())
         colls.append(LoweredCollective(kind, payload, group_size, node,
-                                       op_name, s[:240], rng=rng))
+                                       op_name, s[:240], rng=rng,
+                                       phase=phase, axes=axes))
     return HLOSummary(colls, t_bytes, c_bytes,
                       peak_from_memory_stats(memory))
 
@@ -510,7 +545,8 @@ def hloaudit_pass(ctx: AnalysisContext) -> List[Finding]:
                 f"entry point failed to lower/compile: {mod['error']}"))
             continue
         summary = parse_hlo_module(mod["hlo_text"], node_keys,
-                                   memory=mod.get("memory"))
+                                   memory=mod.get("memory"),
+                                   mesh_axes=ctx.axis_sizes)
         training = entry == "train_step"
         priced = entry in PRICED_ENTRIES
         manifest = None
@@ -529,6 +565,7 @@ def hloaudit_pass(ctx: AnalysisContext) -> List[Finding]:
         findings += check_transposes(ctx.subject, entry, summary, opts)
         summary_out[entry] = {
             "collective_schedule": summary.schedule(),
+            "collectives_by_axis": summary.schedule_by_axes(),
             "attributed": sum(1 for c in summary.collectives
                               if c.node is not None),
             "unattributed": sum(1 for c in summary.collectives

@@ -1011,6 +1011,15 @@ class FFModel:
             yield [a[i * batch_size : (i + 1) * batch_size] for a in arrays]
 
     def _device_put_batch(self, arrs):
+        """Host arrays onto the devices at the executor's batch sharding
+        (fit(), the prefetching loader and the benchmark's trainer call
+        it), under the span `batch_put`."""
+        from flexflow_tpu import obs
+
+        with obs.span("batch_put"):
+            return self._device_put_batch_impl(arrs)
+
+    def _device_put_batch_impl(self, arrs):
         import jax
 
         from flexflow_tpu.runtime import distributed as dist
@@ -1111,6 +1120,7 @@ class FFModel:
                   recompile_state):
         import jax
 
+        from flexflow_tpu import obs
         from flexflow_tpu.runtime.dataloader import PrefetchLoader
 
         epochs = epochs or self.config.epochs
@@ -1126,92 +1136,117 @@ class FFModel:
             rng = jax.random.key(self._rng_seed + 1 + self._fit_calls)
         self._fit_calls += 1
         for epoch in range(epochs):
-            self.current_metrics = PerfMetrics()
-            if dataloaders is not None:
-                if explicit_bs is not None:
-                    for dl in dataloaders:
-                        dl.batch_size = explicit_bs
-                batches = iter(PrefetchLoader(self, dataloaders))
-            else:
-                xs = [x] if isinstance(x, np.ndarray) else list(x)
-                batches = (
-                    self._device_put_batch(b)
-                    for b in self._batches(xs + [y], batch_size)
-                )
-            # metrics accumulate ON DEVICE across the epoch (reference
-            # PerfMetrics future-reduction discipline); one host sync at
-            # epoch end — per-step float() would block async dispatch and
-            # serialize the step stream
-            dev_sums = None
-            n_samples = 0
-            for batch in batches:
-                *bx, by = batch
-                rng, sub = jax.random.split(rng)
-                tr, ntr, opt_state, m = step(tr, ntr, opt_state, sub, by, *bx)
-                self._step_count += 1
-                bsz = by.shape[0]
-                n_samples += bsz
-                # scaling by the python batch-size constant implicitly
-                # uploads a scalar — deliberate, so exempt from a
-                # configured transfer guard (which hunts DATA transfers)
-                with jax.transfer_guard("allow"):
-                    scaled = {
-                        k: (v if k == "accuracy_correct" else v * bsz)
-                        for k, v in m.items()
-                        if k != "loss"
-                    }
-                    dev_sums = (
-                        scaled
-                        if dev_sums is None
-                        else jax.tree.map(lambda a, b: a + b, dev_sums, scaled)
+            with obs.span("epoch") as ep:
+                self.current_metrics = PerfMetrics()
+                if dataloaders is not None:
+                    if explicit_bs is not None:
+                        for dl in dataloaders:
+                            dl.batch_size = explicit_bs
+                    batches = iter(PrefetchLoader(self, dataloaders))
+                else:
+                    xs = [x] if isinstance(x, np.ndarray) else list(x)
+                    batches = (
+                        self._device_put_batch(b)
+                        for b in self._batches(xs + [y], batch_size)
                     )
-                if recompile_state is not None:
-                    # reference recompile_on_condition (model.cc:2422);
-                    # trigger functions read device metrics — a deliberate
-                    # sync, exempt from a configured transfer guard
-                    from flexflow_tpu.runtime.recompile import (
-                        recompile_on_condition,
-                    )
-
-                    recompile_state.last_metrics = m
-                    self._params = (tr, ntr)
-                    self._opt_state = opt_state
+                # metrics accumulate ON DEVICE across the epoch (reference
+                # PerfMetrics future-reduction discipline); one host sync
+                # at epoch end — per-step float() would block async
+                # dispatch and serialize the step stream
+                dev_sums = None
+                n_samples = 0
+                while True:
+                    # the wait for the loader; `batch_put` (the host to
+                    # device copy of this or the prefetched batch) nests
+                    with obs.span("data_wait"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        break
+                    obs.beacon()
+                    *bx, by = batch
+                    rng, sub = jax.random.split(rng)
+                    tr, ntr, opt_state, m = step(tr, ntr, opt_state, sub, by,
+                                                 *bx)
+                    self._step_count += 1
+                    bsz = by.shape[0]
+                    n_samples += bsz
+                    # scaling by the python batch-size constant implicitly
+                    # uploads a scalar — deliberate, so exempt from a
+                    # configured transfer guard (which hunts DATA transfers)
                     with jax.transfer_guard("allow"):
-                        recompiled = recompile_on_condition(
-                            self, recompile_state
+                        scaled = {
+                            k: (v if k == "accuracy_correct" else v * bsz)
+                            for k, v in m.items()
+                            if k != "loss"
+                        }
+                        dev_sums = (
+                            scaled
+                            if dev_sums is None
+                            else jax.tree.map(lambda a, b: a + b, dev_sums,
+                                              scaled)
                         )
-                    if recompiled:
-                        step = self.executor.train_step()
-                        tr, ntr = self._params
-                        opt_state = self._opt_state
-                if (
-                    self.config.checkpoint_every
-                    and self.config.checkpoint_dir
-                    and self._step_count % self.config.checkpoint_every == 0
-                ):
-                    from flexflow_tpu.runtime.checkpoint import periodic_save
+                    if recompile_state is not None:
+                        # reference recompile_on_condition (model.cc:2422);
+                        # trigger functions read device metrics — a
+                        # deliberate sync, exempt from a configured
+                        # transfer guard
+                        from flexflow_tpu.runtime.recompile import (
+                            recompile_on_condition,
+                        )
 
-                    self._params = (tr, ntr)
-                    self._opt_state = opt_state
-                    # checkpoint writes gather state to host by design
-                    with jax.transfer_guard("allow"):
-                        periodic_save(self.config.checkpoint_dir, self)
-            self.current_metrics.train_all = n_samples
-            if dev_sums is not None:
-                # the ONE deliberate device->host sync per epoch — exempt
-                # from a configured transfer guard (which exists to catch
-                # transfers inside the step loop, not this one)
-                with jax.transfer_guard("allow"):
-                    host = {k: float(v) for k, v in dev_sums.items()}
-                self.current_metrics.train_correct = int(
-                    round(host.get("accuracy_correct", 0.0))
-                )
-                for k in (
-                    "cce_loss", "sparse_cce_loss", "mse_loss", "rmse_loss",
-                    "mae_loss",
-                ):
-                    if k in host:
-                        setattr(self.current_metrics, k, host[k])
+                        recompile_state.last_metrics = m
+                        self._params = (tr, ntr)
+                        self._opt_state = opt_state
+                        with obs.span("recompile_check") as sp, \
+                                jax.transfer_guard("allow"):
+                            recompiled = recompile_on_condition(
+                                self, recompile_state
+                            )
+                            if sp:
+                                sp.set(recompiled=bool(recompiled))
+                        if recompiled:
+                            step = self.executor.train_step()
+                            tr, ntr = self._params
+                            opt_state = self._opt_state
+                    if (
+                        self.config.checkpoint_every
+                        and self.config.checkpoint_dir
+                        and self._step_count % self.config.checkpoint_every
+                        == 0
+                    ):
+                        from flexflow_tpu.runtime.checkpoint import (
+                            periodic_save,
+                        )
+
+                        self._params = (tr, ntr)
+                        self._opt_state = opt_state
+                        # checkpoint writes gather state to host by design
+                        with obs.span("checkpoint_save") as sp, \
+                                jax.transfer_guard("allow"):
+                            periodic_save(self.config.checkpoint_dir, self)
+                            if sp:
+                                sp.set(step=self._step_count)
+                self.current_metrics.train_all = n_samples
+                if dev_sums is not None:
+                    # the ONE deliberate device->host sync per epoch —
+                    # exempt from a configured transfer guard (which exists
+                    # to catch transfers inside the step loop, not this
+                    # one); the span holds the wait for the epoch's last
+                    # steps
+                    with obs.span("epoch_sync"), \
+                            jax.transfer_guard("allow"):
+                        host = {k: float(v) for k, v in dev_sums.items()}
+                    self.current_metrics.train_correct = int(
+                        round(host.get("accuracy_correct", 0.0))
+                    )
+                    for k in (
+                        "cce_loss", "sparse_cce_loss", "mse_loss",
+                        "rmse_loss", "mae_loss",
+                    ):
+                        if k in host:
+                            setattr(self.current_metrics, k, host[k])
+                if ep:
+                    ep.set(epoch=epoch, samples=n_samples)
             if verbose:
                 print(f"epoch {epoch}: {self.current_metrics.report(self._metrics)}")
         self._params = (tr, ntr)

@@ -1,6 +1,8 @@
-"""fftrace — structured tracing + metrics for the serving tick loop.
+"""fftrace — structured tracing + metrics for the program's host loops:
+the serving tick loop and the training loop (`fit()`), with the names the
+executor stamps into its compiled steps (obs.scopes) for the device side.
 
-Two layers with different overhead budgets:
+Layers with different overhead budgets:
 
   * `MetricsRegistry` (obs.metrics): counters/gauges/fixed-bucket
     histograms. Always on — every generation server owns one and feeds
@@ -8,8 +10,14 @@ Two layers with different overhead budgets:
     An observe() is a bisect + two adds.
   * Span recorder + TickLedger (obs.trace / obs.ledger): opt-in via
     `obs.enable()`. When disabled, `obs.span(name)` returns a shared
-    falsy singleton — zero allocations on the tick path (the
-    disabled-overhead guard in tests/test_obs.py holds this to account).
+    falsy singleton — zero allocations on the tick path and in
+    `fit()`'s loop (the disabled-overhead guards in tests/test_obs.py
+    and tests/test_fit_spans.py hold this to account).
+  * Step scopes (obs.scopes): `forward` / `optimizer` / `step_metrics`
+    around the graph nodes' keys, stamped by the executor into the train
+    and eval steps; `classify` is the one reader of the resulting JAX
+    name stacks (hloaudit, the benchmark's device-trace reader). Named
+    scopes cost nothing at run time.
   * Request log + SLO monitor (obs.reqlog / obs.slo): a bounded
     flight recorder of one record per COMPLETED request (cheap enough
     to leave on in production; `request_log(0)` is the same falsy

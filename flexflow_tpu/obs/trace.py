@@ -1,5 +1,7 @@
-"""Span recorder — thread-aware, monotonic-clock tracing for the
-serving tick loop ("fftrace").
+"""Span recorder — thread-aware, monotonic-clock tracing of the program's
+host loops ("fftrace"): the serving tick loop, `fit()`'s training loop
+(epoch, data_wait, batch_put, train_step, checkpoint_save, ...) and
+whatever else opens an `obs.span`.
 
 Design constraints, in order:
 
@@ -8,7 +10,8 @@ Design constraints, in order:
      allocated per call, `with` enter/exit touch nothing, and the span
      is falsy so call sites guard their attribute computation
      (`if sp: sp.set(live=...)`) — the attrs dict is never even built.
-     The decode tick path pays one module-global load + `is None` test.
+     A decode tick or a training step pays one module-global load + an
+     `is None` test a site.
   2. One clock. Spans stamp `time.monotonic_ns()`; request lifecycle
      events convert the `time.monotonic()` stamps _GenRequest already
      carries — same clock, so tick spans and request tracks line up in

@@ -33,6 +33,21 @@ def enable_compile_cache() -> str:
     return DEFAULT_DIR
 
 
+def keyed_on_metadata():
+    """A context in which a program is compiled under a cache key that
+    holds its metadata. jax strips locations from a module before it
+    hashes it, and a `jax.named_scope` changes nothing but locations: a
+    cache warmed by a checkout without a scope would hand one with it the
+    old executable, whose `op_name`s lack the scope, and a reader of the
+    device trace (obs/scopes.py) would see an unscoped step on a warm
+    cache and a scoped one on a cold. The training and evaluation steps
+    enter this around their calls (`Executor._TracedStep`); the serving
+    programs do not, and keep their keys."""
+    from jax._src import config
+
+    return config.compilation_cache_include_metadata_in_key(True)
+
+
 class CacheCounter:
     """Counts this process's persistent-cache hits and misses from jax's
     monitoring events, so a run can say whether its compile seconds were

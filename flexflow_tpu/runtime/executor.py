@@ -104,11 +104,12 @@ def _cast_weight_leaf(arr, weight_dtype: str):
 
 class _TracedStep:
     """Jitted step function wrapped in an fftrace span (obs.span) so
-    train/eval steps land on the host trace next to the serving ticks.
-    Everything else delegates to the underlying jitted callable —
-    `.lower()` in particular, which lowered_modules()/hloaudit call on
-    the object train_step() returns. Disabled-mode cost is one module
-    attribute load + an `is None` test per step."""
+    train/eval steps land on the host trace next to the serving ticks,
+    and compiled under a cache key that holds its named scopes
+    (compile_cache.keyed_on_metadata). Everything else delegates to the
+    underlying jitted callable: `.lower()` in particular, which
+    lowered_modules()/hloaudit call on the object train_step() returns.
+    With no recorder the span is the shared no-op singleton."""
 
     # __weakref__: jax.jit(step) weak-references the callable it wraps
     __slots__ = ("_fn", "_name", "__weakref__")
@@ -119,10 +120,9 @@ class _TracedStep:
 
     def __call__(self, *args, **kw):
         from flexflow_tpu import obs
+        from flexflow_tpu.runtime.compile_cache import keyed_on_metadata
 
-        if obs.recorder() is None:
-            return self._fn(*args, **kw)
-        with obs.span(self._name):
+        with keyed_on_metadata(), obs.span(self._name):
             return self._fn(*args, **kw)
 
     def lower(self, *args, **kw):
@@ -722,8 +722,8 @@ class Executor:
     def train_step(self):
         if self._train_step is not None:
             return self._train_step
+        from flexflow_tpu.obs import scopes
         opt = self.optimizer
-
         fused = self.fuse_loss_softmax
         sink_is_sm = self.last_op_is_softmax and not fused
 
@@ -731,27 +731,27 @@ class Executor:
             labels = self._maybe_repeat_labels(labels)
 
             def loss_fn(tr):
-                logits, updates, aux = self.run_forward(
-                    tr, nontrainable, inputs, training=True, rng=rng,
-                    skip_sink_softmax=fused,
-                )
-                loss = compute_loss(self.loss_type, logits, labels, sink_is_sm)
+                with jax.named_scope(scopes.FORWARD):
+                    logits, updates, aux = self.run_forward(
+                        tr, nontrainable, inputs, training=True, rng=rng,
+                        skip_sink_softmax=fused)
+                    loss = compute_loss(self.loss_type, logits, labels, sink_is_sm)
                 return loss + aux, (logits, updates, loss)
 
             grads, (logits, updates, loss) = jax.grad(loss_fn, has_aux=True)(trainable)
-            new_tr, new_opt = opt.update(grads, trainable, opt_state)
             opt_sh = getattr(self, "_opt_shardings", None)
-            if opt_sh is not None and self.zero_sharded_opt:
-                # keep ZeRO layout stable across steps; with the state
-                # sharded over data, XLA lowers the grad psum feeding the
-                # update into reduce-scatter + all-gather of new params
-                new_opt = jax.tree.map(
-                    jax.lax.with_sharding_constraint, new_opt, opt_sh
-                )
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_tr, new_opt = opt.update(grads, trainable, opt_state)
+                if opt_sh is not None and self.zero_sharded_opt:
+                    # keep ZeRO layout stable across steps; with the state
+                    # sharded over data, XLA lowers the grad psum feeding the
+                    # update into reduce-scatter + all-gather of new params
+                    new_opt = jax.tree.map(
+                        jax.lax.with_sharding_constraint, new_opt, opt_sh)
             new_ntr = self._merge_state(nontrainable, updates)
-            step_metrics = self._rescale_correct(compute_step_metrics(
-                self.metrics, self.loss_type, logits, labels, sink_is_sm
-            ))
+            with jax.named_scope(scopes.STEP_METRICS):
+                step_metrics = self._rescale_correct(compute_step_metrics(
+                    self.metrics, self.loss_type, logits, labels, sink_is_sm))
             step_metrics["loss"] = loss
             return new_tr, new_ntr, new_opt, step_metrics
 
@@ -763,20 +763,20 @@ class Executor:
     def eval_step(self):
         if self._eval_step is not None:
             return self._eval_step
-
+        from flexflow_tpu.obs import scopes
         fused = self.fuse_loss_softmax
         sink_is_sm = self.last_op_is_softmax and not fused
 
         def step(trainable, nontrainable, labels, *inputs):
             labels = self._maybe_repeat_labels(labels)
-            logits, _, _ = self.run_forward(
-                trainable, nontrainable, inputs, training=False,
-                rng=jax.random.key(0), skip_sink_softmax=fused,
-            )
-            loss = compute_loss(self.loss_type, logits, labels, sink_is_sm)
-            m = self._rescale_correct(compute_step_metrics(
-                self.metrics, self.loss_type, logits, labels, sink_is_sm
-            ))
+            with jax.named_scope(scopes.FORWARD):
+                logits, _, _ = self.run_forward(
+                    trainable, nontrainable, inputs, training=False,
+                    rng=jax.random.key(0), skip_sink_softmax=fused)
+                loss = compute_loss(self.loss_type, logits, labels, sink_is_sm)
+            with jax.named_scope(scopes.STEP_METRICS):
+                m = self._rescale_correct(compute_step_metrics(
+                    self.metrics, self.loss_type, logits, labels, sink_is_sm))
             m["loss"] = loss
             return m
 
