@@ -52,7 +52,7 @@ _TRANSPOSE_MARK = "transpose("
 # `transpose(jvp(forward))` -> `forward`: a transform wraps the scope's name
 _WRAPPED = re.compile(r"^(?:[\w.\-]+\()+([^()]*)\)+$")
 # scopes jax itself puts between a top-level scope and a node's
-_JAX_SCOPES = ("checkpoint", _REMAT_MARK)
+_JAX_SCOPES = ("checkpoint", _REMAT_MARK, "shard_map")
 
 
 def sorted_keys(node_keys: Sequence[str]) -> List[str]:

@@ -654,42 +654,42 @@ class Executor:
     def _run_remat_group(self, group, values, trainable, nontrainable, rng):
         """Execute one remat="hidden" group under jax.checkpoint: only the
         group's external inputs are saved for backward; the hidden
-        activations inside are recomputed. Returns {out_key: value}."""
+        activations inside are recomputed. Returns {out_key: value}. A
+        SwiGLU diamond split by column over one mesh axis runs per shard
+        (runtime/column_group.py): its input gradient is reduced once."""
+        from flexflow_tpu.runtime import column_group
+
         members, _, out_key, ext = group
-        ext_vals = [values[k] for k in ext]
-        gparams = {}
-        for gn in members:
-            key = node_key(gn)
-            p = {}
-            p.update(trainable.get(key, {}))
-            p.update(nontrainable.get(key, {}))
-            if p:
-                gparams[key] = p
+        gparams = {node_key(gn): {**trainable.get(node_key(gn), {}),
+                                  **nontrainable.get(node_key(gn), {})}
+                   for gn in members}
+        split = column_group.column_split(self.graph, self.mesh, members)
+
+        def lower(gn, local, gp, constrain=True):
+            ins = [local[(e.src, e.src_idx)]
+                   for e in self.graph.in_edges(gn)]
+            ctx = LowerCtx(
+                training=True, mesh=self.mesh, seq_length=self.seq_length,
+                node_guid=gn.guid, sharding=gn.sharding,
+                rng=(jax.random.fold_in(rng, gn.guid)
+                     if rng is not None else None))
+            with jax.named_scope(node_key(gn)):
+                outs = get_lowering(gn.op_type)(
+                    gn.attrs, ins, gp.get(node_key(gn), {}), ctx)
+                if constrain:
+                    outs = self._apply_view(gn, outs)
+            local.update({(gn.guid, i): o for i, o in enumerate(outs)})
 
         def group_fn(gp, *xs):
             local = dict(zip(ext, xs))
-            for gn in members:
-                ins = [local[(e.src, e.src_idx)]
-                       for e in self.graph.in_edges(gn)]
-                ctx = LowerCtx(
-                    training=True,
-                    rng=(jax.random.fold_in(rng, gn.guid)
-                         if rng is not None else None),
-                    mesh=self.mesh,
-                    seq_length=self.seq_length,
-                    node_guid=gn.guid,
-                    sharding=gn.sharding,
-                )
-                with jax.named_scope(node_key(gn)):
-                    outs = get_lowering(gn.op_type)(
-                        gn.attrs, ins, gp.get(node_key(gn), {}), ctx
-                    )
-                    outs = self._apply_view(gn, outs)
-                for i, o in enumerate(outs):
-                    local[(gn.guid, i)] = o
+            if split is not None:
+                column_group.run_split(split, self.mesh, lower, local, gp)
+            for gn in (members if split is None else split.rest):
+                lower(gn, local, gp)
             return local[out_key]
 
-        return {out_key: jax.checkpoint(group_fn)(gparams, *ext_vals)}
+        return {out_key: jax.checkpoint(group_fn)(
+            gparams, *[values[k] for k in ext])}
 
     # ------------------------------------------------------------------
     # compiled steps

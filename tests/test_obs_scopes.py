@@ -31,6 +31,18 @@ NAME_STACKS = [
     ("jit(step)/forward/jit(_one_hot)/eq", "forward", None),
     ("jit(step)/jvp(forward)/l0_attn_5/shard_map/pallas_call",
      "forward", "l0_attn_5"),
+    # a column-split remat group runs its diamond per shard (PR 39): the
+    # map's own scope stands between jax's and the node's
+    ("jit(step)/jvp(forward)/shard_map/l0_gate_9/dot_general",
+     "forward", "l0_gate_9"),
+    ("jit(step)/transpose(jvp(forward))/jvp(forward)/checkpoint/"
+     "rematted_computation/shard_map/l0_gate_9/dot_general",
+     "recompute", "l0_gate_9"),
+    ("jit(step)/transpose(jvp(forward))/jvp(forward)/checkpoint/shard_map/"
+     "l0_up_10/dot_general", "backward", "l0_up_10"),
+    # the one reduction of the group's input gradient
+    ("jit(step)/transpose(jvp(forward))/jvp(forward)/checkpoint/l0_gate_9/"
+     "reduce_sum", "backward", "l0_gate_9"),
     ("jit(step)/transpose(jvp())/add_any", None, None),   # a scopeless step
     ("trainable['l0_attn_5']['wq']", None, None),          # a parameter
     ("", None, None),
@@ -41,7 +53,8 @@ NAME_STACKS = [
                          ids=[s[0][-40:] or "empty" for s in NAME_STACKS])
 def test_classify_pins_the_name_stacks(stack, phase, node):
     assert scopes.classify(stack) == (phase, node)
-    keys = scopes.sorted_keys(["l0_attn_5", "l0_attn_55", "l0_gate_9"])
+    keys = scopes.sorted_keys(["l0_attn_5", "l0_attn_55", "l0_gate_9",
+                               "l0_up_10"])
     got_phase, got_node = scopes.classify(stack, keys)
     assert got_phase == phase
     if "[" not in stack:        # a parameter's name holds its node's key
