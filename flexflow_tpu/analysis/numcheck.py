@@ -52,7 +52,12 @@ is garbage that still type-checks. Three arms:
       np.float64 doubled the bytes of everything it touched.
   hlo-accum-downgrade (error)      a dot accumulates NARROWER than the
       plan's accumulation dtype — the mixed-precision win stopped
-      being real.
+      being real. (A `dot`'s result type is its accumulator's on the
+      CPU and GPU, whose modules this arm reads. The TPU's module has
+      `convolution`s, accumulated f32 in the MXU whatever they hand
+      out: there the type says where the sum is rounded, and the
+      program names it where that matters, `ops/jax_ops.py`
+      `contraction`; tests/test_column_group_tpu.py reads them.)
   hlo-unplanned-convert (warning)  convert traffic touching a float
       dtype outside the entry's declared dtype set, above the count
       band — casts the plan never budgeted.
@@ -628,7 +633,8 @@ def scan_paths(paths: Sequence[str]) -> List[Finding]:
 _CONVERT_RE = re.compile(
     r"%?[\w.\-]+ = (\w+)\[[^\]]*\]\S* convert\((\w+)\[")
 # `%d = f32[...]{...} dot(...)` — the result dtype IS the accumulation
-# dtype XLA committed to for this contraction
+# dtype XLA committed to for this contraction (CPU / GPU modules; the
+# TPU's has none, see hlo-accum-downgrade above)
 _DOT_RE = re.compile(r"%?[\w.\-]+ = (\w+)\[[^\]]*\]\S* dot\(")
 _F64_RE = re.compile(r"\bf64\[")
 

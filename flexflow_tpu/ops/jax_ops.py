@@ -62,11 +62,62 @@ def _noop(attrs, inputs, params, ctx):
 # dense / conv / embedding / matmul
 
 
+def contraction(forward, input_grad, kernel_grad):
+    """`x @ w` of activations and a kernel of ONE dtype, the result at that
+    dtype, as a function whose three dots each name the type they hand
+    out: its own, and its transpose's two (the gradient of `x`, contracted
+    over the kernel's columns, and of `w`, contracted over every leading
+    dimension of `x`). None is the arrays' own dtype; a wider type is
+    rounded to theirs after the dot.
+
+    Why a dot's type is worth naming: where the contracted dimension is
+    split over a mesh axis the partitioner places the all-reduce ON the
+    dot, so the dot's type is what crosses the link: float32 partial sums
+    added in float32 and rounded once after, or each chip's sum rounded
+    first, half the bytes, and the sums added at the arrays' dtype. Within
+    a chip nothing differs: the v5e accumulates a bfloat16 dot's
+    contraction in float32 and rounds once, to the bit what the float32
+    dot rounded after gives (`tools/chip_grad_precision.py`
+    `rounded_once`)."""
+
+    def dot(a, b, dims, like, out):
+        return lax.dot_general(
+            a, b, (dims, ((), ())),
+            preferred_element_type=out or like.dtype).astype(like.dtype)
+
+    def product(x, w):
+        return dot(x, w, ((x.ndim - 1,), (0,)), x, forward)
+
+    def run(x, w):
+        return product(x, w), (x, w)
+
+    def transpose(saved, g):
+        x, w = saved
+        rows = tuple(range(x.ndim - 1))
+        return (dot(g, w, ((g.ndim - 1,), (1,)), x, input_grad),
+                dot(x, g, (rows, rows), w, kernel_grad))
+
+    contract = jax.custom_vjp(product)
+    contract.defvjp(run, transpose)
+    return contract
+
+
+# A LINEAR's sums of ACTIVATIONS (forward where its rows are split, its input
+# gradient where its columns are) cross a mesh axis in float32 and are
+# rounded after; its KERNEL's gradient, contracted over the batch, is rounded
+# a shard of the batch first and crosses at the activations' dtype. Decided
+# leaf by leaf against a float32 reference on the chip, one group of
+# reductions at a time (PERF.md section 6, PR 42): rounding the activations'
+# sums first moved EVERY gradient leaf 1.5-4.9 % farther from float32 and the
+# loss with them, the kernels' moved their own leaves by 0.4-0.8 % and no
+# other, at half the gradient sync's bytes.
+_linear_dot = contraction(jnp.float32, jnp.float32, None)
+
+
 @register_lowering(OpType.LINEAR)
 def _linear(attrs, inputs, params, ctx):
     (x,) = inputs
-    y = jnp.dot(x, params["kernel"].astype(x.dtype), preferred_element_type=jnp.float32)
-    y = y.astype(x.dtype)
+    y = _linear_dot(x, params["kernel"].astype(x.dtype))
     if attrs.use_bias:
         y = y + params["bias"].astype(x.dtype)
     return [apply_activation(y, attrs.activation)]

@@ -8,8 +8,9 @@ after each dot whose contracted dimension is split, on the dot's float32
 result. `gate` and `up` share their input, their two transposed dots each
 get an all-reduce of their own, and JAX's `add_any` joins the results
 after: `allreduce(a) + allreduce(b)` where `allreduce(a + b)` is the same
-number (on the v5e the TPU compiler does not merge them: PERF.md section
-6, PRs 38 and 39).
+number (on the v5e the TPU compiler does not merge them while they read
+float32 partial sums, PERF.md section 6, PRs 38 and 39; it would if they
+read bfloat16, PR 42, which costs every leaf precision).
 
 How: the map's body is handed one copy of the shared input a shard, a
 broadcast along a new leading dimension that is split over the axis. Going
@@ -30,13 +31,17 @@ value inside it, and on the v5e the compiler answered by flipping the
 layout of both kernels and their Adam moments on the way in and out: 72
 copies, 15.8 ms a step; PERF.md section 6, PR 39.)
 
-What it rounds: each shard's local sum leaves the body at the activations'
-dtype, so under bfloat16 the partial sums are rounded BEFORE they cross,
-where the fallback's all-reduces read the dots' float32 partial sums and
-round after (half the bytes on the link; every gradient leaf stands as
-near a float32 reference as the fallback's: PERF.md section 6, PR 39,
-`tools/chip_grad_precision.py`). A kernel's gradient is likewise rounded a
-shard of the batch's axes and summed over them in float32.
+What it rounds: each shard's local sum of the two partial input gradients
+leaves the body at the activations' dtype, so under bfloat16 it is rounded
+BEFORE it crosses, where the fallback's two all-reduces read the dots'
+float32 partial sums and round after (half the bytes on the link; every
+gradient leaf stands as near a float32 reference as the fallback's:
+PERF.md section 6, PR 39, `tools/chip_grad_precision.py`). A kernel's
+gradient is rounded a shard of the batch's axes and summed over them at the
+activations' dtype, as `ops/jax_ops.py` `_linear_dot` has every LINEAR
+kernel's: the map is handed its weights ALREADY at that dtype (`handed`),
+or the transpose of its copies would sum float32 (PERF.md section 6, PR 42:
+0.4-0.8 % on those kernels' own leaves, none on any other).
 
 What runs in the map is the diamond alone (the two linears, the activation
 and the product); the trailing contraction, when the group swallowed one,
@@ -128,6 +133,14 @@ def column_split(graph, mesh, members: Sequence) -> Optional[ColumnSplit]:
                        (body[-1].guid, 0))
 
 
+def handed(w, x):
+    """A weight as the map is handed it: ALREADY at the input's dtype, so
+    that its gradient leaves the body at that dtype and the transpose of
+    its copies sums it over the batch's axes at that dtype; the convert
+    back to the master's is this `astype`'s transpose, after the sum."""
+    return w.astype(x.dtype)
+
+
 def run_split(split: ColumnSplit, mesh, lower: Callable, local: Dict,
               gparams: Dict) -> None:
     """Run the diamond per shard and leave its product in `local`.
@@ -165,7 +178,7 @@ def run_split(split: ColumnSplit, mesh, lower: Callable, local: Dict,
         # under the node's own scope: the sum over the batch's axes of a
         # kernel's gradient is read as that node's, as it was
         with jax.named_scope(k):
-            params[k] = {name: copies(w, batch)
+            params[k] = {name: copies(handed(w, x), batch)
                          for name, w in gparams[k].items()}
         specs[k] = {name: P(batch or None,
                             *spec_to_partition_spec(want[name]))

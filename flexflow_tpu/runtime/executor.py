@@ -1785,12 +1785,10 @@ class Executor:
 
     def dtype_plan(self, entries: Optional[Sequence[str]] = None, *,
                    kv_dtype: Optional[str] = None) -> Dict[str, Dict]:
-        """The DECLARED per-entry numerics plan, in HLO dtype names —
-        what numcheck's HLO arm diffs each lowered module against
-        (analysis/numcheck.py). Metadata from the graph's weight
-        declarations and cache specs; nothing is compiled, and only the
-        paged entries trace (once an executor: which leaves a server
-        holds at their declared dtype, serving_weights.served_dtypes).
+        """The DECLARED per-entry numerics plan, in HLO dtype names, that
+        numcheck's HLO arm diffs each lowered module against. Metadata of
+        the graph's weight declarations and cache specs; only the paged
+        entries trace (once: serving_weights.served_dtypes).
 
         Per entry: "compute" (the dtype the weights arrive at — f32 for
         train_step and eval_step, since abstract_params promotes
@@ -1799,14 +1797,16 @@ class Executor:
         float dtype of the tree a server launches with, the declared
         bf16 of a llama: leaves the step reads wider, a norm's scale,
         stay f32 and are in "allowed"),
-        "accum" (contraction accumulation dtype; always f32 — narrower
-        is hlo-accum-downgrade), "kv" (the paged pool payload dtype for
-        the paged entries, lowered_modules' two shapes of
-        ragged_step_fn; s8 carries the scale sidecar), "allowed"
-        (every float/payload dtype the entry may legitimately touch —
-        converts outside this set are hlo-unplanned-convert), and
-        "allow_f64": False everywhere (f64 anywhere is a silent
-        weak-type promotion, hlo-unexpected-f64)."""
+        "accum" (what a contraction ACCUMULATES at: f32. A `dot` of the
+        CPU's module states it, the CPU's compiler widening any narrower
+        one; the TPU's MXU accumulates f32 whatever the result's type,
+        which there says where the sum is ROUNDED, so what crosses a mesh
+        axis: f32 for a LINEAR's sums of activations, the activations'
+        dtype for its kernel's gradient, ops/jax_ops.py `contraction`),
+        "kv" (the paged entries' pool payload dtype; s8 carries the scale
+        sidecar), "allowed" (every float/payload dtype the entry may
+        touch: converts outside it are hlo-unplanned-convert), and
+        "allow_f64": False (a weak-type promotion, hlo-unexpected-f64)."""
         known = ("train_step", "eval_step", "paged_decode", "verify")
         if entries is None:
             entries = ["train_step", "eval_step"]

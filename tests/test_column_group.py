@@ -151,6 +151,14 @@ def test_one_reduction_over_model_for_each_groups_input_gradient(mesh):
                if e.invars[0].aval.shape[1:] == (LCFG.dim, LCFG.hidden)]
     assert len(kernels) == (2 * LCFG.layers if "data" in TP_MESHES[mesh]
                             else 0)
+    # at the activations' dtype: the map is handed the weights converted,
+    # so the float32 master's gradient is converted back AFTER the sum
+    assert {str(e.invars[0].aval.dtype) for e in kernels} <= {"bfloat16"}
+    for e in kernels:
+        user = next(u for u in _eqns(jaxpr)
+                    if any(v is e.outvars[0] for v in u.invars))
+        assert user.primitive.name == "convert_element_type"
+        assert str(user.outvars[0].aval.dtype) == "float32"
 
 
 @pytest.mark.parametrize("mesh", sorted(TP_MESHES))
@@ -235,22 +243,185 @@ def test_loss_and_gradients_agree_with_the_fallback_and_with_dp(monkeypatch):
                                        rtol=2e-3, atol=2e-5)
 
 
+def _linear_dots(jaxpr):
+    """Every `dot_general` traced under a LINEAR node's key: the MLP's
+    three a layer and the head, forward, recomputed and transposed."""
+    under = re.compile(r"\bl\d+_(gate|up|down)_\d+|\blm_head_\d+")
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"
+            and under.search(str(e.source_info.name_stack))]
+
+
+def _assert_bfloat16_parity(ours, theirs):
+    (loss, grads), (loss_other, grads_other) = ours, theirs
+    np.testing.assert_allclose(loss, loss_other, rtol=2e-3)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_other)):
+        scale = max(float(np.abs(b).max()), 1e-8)
+        assert float(np.abs(a - b).max()) / scale < 0.02
+
+
 def test_bfloat16_gradients_agree_with_the_fallback(monkeypatch):
     """At the cell's dtype each shard's local sum is rounded to bfloat16
     before it crosses, where the fallback rounds after the cross-chip
     sum: parity to bfloat16's reassociation noise here (the bound
     tests/test_perf_features.py gives remat against none); against a
     float32 reference, at the cell's size, on the chip:
-    tools/chip_grad_precision.py (PERF.md section 6, PR 39)."""
+    tools/chip_grad_precision.py (PERF.md section 6, PRs 39 and 42)."""
     mesh = TP_MESHES["data2_model2"]
-    loss, grads = _gradients(_llama(mesh, optimizer=SGDOptimizer(lr=1.0)))
+    ours = _gradients(_llama(mesh, optimizer=SGDOptimizer(lr=1.0)))
     _without_the_path(monkeypatch)
-    loss_fb, grads_fb = _gradients(
-        _llama(mesh, optimizer=SGDOptimizer(lr=1.0)))
-    np.testing.assert_allclose(loss, loss_fb, rtol=2e-3)
-    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_fb)):
-        scale = max(float(np.abs(b).max()), 1e-8)
-        assert float(np.abs(a - b).max()) / scale < 0.02
+    _assert_bfloat16_parity(ours, _gradients(
+        _llama(mesh, optimizer=SGDOptimizer(lr=1.0))))
+
+
+def _kernel_gradient(eqn):
+    """Whether a linear's `dot_general` contracts the rows away: its
+    result has the kernel's two dimensions, where an activation's keeps
+    the rows'."""
+    return eqn.outvars[0].aval.ndim == 2
+
+
+def test_bfloat16_gradients_agree_with_float32_partial_sums():
+    """The form before PR 42 (every dot of a linear handing out float32,
+    so that every reduction the partitioner placed on one read float32
+    partial sums and rounded after the sum; the map handed float32
+    kernels), reinstated by the gradient tool's stand-in for one trace:
+    the same loss and gradients within the bound above. What rounding a
+    group of reductions before the link costs against a float32 reference
+    is the chip's to say (tools/chip_grad_precision.py, the columns
+    `*_over_float32_partials`; PERF.md section 6, PR 42)."""
+    from tools.chip_grad_precision import LOWERINGS
+
+    mesh = TP_MESHES["data2_model2"]
+    ours = _llama(mesh, optimizer=SGDOptimizer(lr=1.0))
+    theirs = _llama(mesh, optimizer=SGDOptimizer(lr=1.0))
+    with LOWERINGS["float32-partials"]():
+        jaxpr = _step_jaxpr(theirs)
+        before = _gradients(theirs)
+    # the stand-in is what it says: float32 out of every linear's dot,
+    # float32 kernels into the map, float32 sums of their gradients.
+    # A layer: `gate` and `up` run, recomputed and twice transposed,
+    # `down` run and twice transposed; the head the same
+    assert len(_linear_dots(jaxpr)) == 11 * LCFG.layers + 3
+    assert {str(e.outvars[0].aval.dtype)
+            for e in _linear_dots(jaxpr)} == {"float32"}
+    kernels = [e for e, _smap, _i in _input_gradient_sums(jaxpr, "data")
+               if e.invars[0].aval.shape[1:] == (LCFG.dim, LCFG.hidden)]
+    assert {str(e.invars[0].aval.dtype) for e in kernels} == {"float32"}
+    # and it is gone with the block: the tree's own step traces again, a
+    # kernel's gradient at the activations' dtype and nothing else
+    dots = _linear_dots(_step_jaxpr(ours))
+    assert len(dots) == 11 * LCFG.layers + 3
+    assert {(_kernel_gradient(e), str(e.outvars[0].aval.dtype))
+            for e in dots} == {(True, "bfloat16"), (False, "float32")}
+    assert sum(map(_kernel_gradient, dots)) == 3 * LCFG.layers + 1
+    _assert_bfloat16_parity(_gradients(ours), before)
+
+
+# ---------------------------------------------------------------------------
+# (ii') what a linear's dots hand out: the type every reduction the
+# partitioner places on one will read, forward and in both transposes
+
+
+def _linear_jaxprs(dtype, use_bias):
+    """The jaxprs of a LINEAR node's lowering and of its gradient with
+    respect to input and weights, at activations of `dtype` against
+    float32 master weights."""
+    from flexflow_tpu.ffconst import OpType
+    from flexflow_tpu.ops.attrs import LinearAttrs
+    from flexflow_tpu.ops.registry import LowerCtx, get_lowering
+
+    attrs = LinearAttrs(out_dim=24, use_bias=use_bias)
+    lowering = get_lowering(OpType.LINEAR)
+    x = jax.numpy.ones((2, 8, 16), dtype)
+    params = {"kernel": jax.numpy.ones((16, 24), "float32")}
+    if use_bias:
+        params["bias"] = jax.numpy.zeros((24,), "float32")
+
+    def forward(x, params):
+        (y,) = lowering(attrs, [x], params, LowerCtx())
+        return y
+
+    def loss(x, params):
+        return forward(x, params).astype("float32").sum()
+
+    return (jax.make_jaxpr(forward)(x, params).jaxpr,
+            jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, params).jaxpr)
+
+
+def _dots(jaxpr):
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("use_bias", [False, True],
+                         ids=["no_bias", "bias"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_each_dot_of_a_linear_hands_out_the_type_named_for_it(
+        dtype, use_bias):
+    """Forward and the input gradient's dot: float32, rounded to the
+    activations' dtype AFTER (a sum of activations over a split
+    contraction crosses in float32). The kernel gradient's dot: the
+    activations' dtype (a shard of the batch's is rounded before the sum
+    over the batch's axes). Every one names its type. The float32
+    master's gradient is float32: the convert is the `astype`'s
+    transpose, after the dot. Under float32 activations nothing is
+    narrower than float32 anywhere."""
+    forward, backward = _linear_jaxprs(dtype, use_bias)
+    (dot,) = _dots(forward)
+    back = _dots(backward)
+    assert len(back) == 3       # the forward's again, and the two transposes
+    for e in [dot] + back:
+        assert str(e.outvars[0].aval.dtype) == (
+            dtype if _kernel_gradient(e) else "float32"), e
+        assert e.params["preferred_element_type"] == e.outvars[0].aval.dtype
+    assert sum(map(_kernel_gradient, back)) == 1
+    assert str(forward.outvars[0].aval.dtype) == dtype
+    assert [str(v.aval.dtype) for v in backward.outvars] == [
+        dtype] + ["float32"] * (2 if use_bias else 1)
+
+
+_NAMED = {"float32": ("float32",) * 3, "activations": (None,) * 3,
+          "the_linears": ("float32", "float32", None),
+          "the_converse": (None, None, "float32")}
+
+
+@pytest.mark.parametrize("rank", [2, 3])
+@pytest.mark.parametrize("named", sorted(_NAMED))
+def test_a_contraction_is_the_product_and_its_gradient(named, rank):
+    """`ops.jax_ops.contraction`: whatever its three dots hand out, the
+    product and both gradients are `x @ w`'s own (exactly under float32,
+    to bfloat16's rounding under bfloat16), each dot's result has the
+    type named for it, and the gradients have their arrays' dtypes."""
+    from flexflow_tpu.ops.jax_ops import contraction
+
+    rs = np.random.RandomState(3)
+    shape = (4, 6, 16)[3 - rank:]
+    x32 = jax.numpy.asarray(rs.randn(*shape), "float32")
+    w32 = jax.numpy.asarray(rs.randn(16, 24), "float32")
+    g32 = jax.numpy.asarray(rs.randn(*shape[:-1], 24), "float32")
+
+    def plain(x, w):
+        return ((x @ w) * g32).sum()
+
+    want = (x32 @ w32,) + jax.grad(plain, argnums=(0, 1))(x32, w32)
+    for dtype, tol in (("float32", 1e-5), ("bfloat16", 3e-2)):
+        x, w = x32.astype(dtype), w32.astype(dtype)
+        dot = contraction(*_NAMED[named])
+
+        def ours(x, w):
+            return (dot(x, w).astype("float32") * g32).sum()
+
+        got = (dot(x, w),) + jax.grad(ours, argnums=(0, 1))(x, w)
+        assert [str(a.dtype) for a in got] == [dtype] * 3
+        for a, b in zip(got, want):
+            scale = float(np.abs(b).max())
+            assert float(np.abs(np.asarray(a, "float32") - b).max()) \
+                < tol * scale
+        dots = _dots(jax.make_jaxpr(jax.grad(ours, argnums=(0, 1)))(
+            x, w).jaxpr)
+        assert len(dots) == 3
+        forward, input_grad, kernel_grad = _NAMED[named]
+        assert [str(e.outvars[0].aval.dtype) for e in dots] == [
+            forward or dtype, input_grad or dtype, kernel_grad or dtype]
 
 
 # ---------------------------------------------------------------------------
@@ -447,9 +618,11 @@ def test_the_audit_reports_nothing_at_a_merged_group():
 
 def test_the_gradient_precision_tool_compares_every_leaf(tmp_path):
     """`tools/chip_grad_precision.py --tiny`: the step's gradient under
-    this lowering and under the fallback, each leaf against the plain
-    float32 reference's (on the chip at the train cell's size: PERF.md
-    section 6, PR 39)."""
+    this lowering, under the fallback and under the tool's stand-ins,
+    which round ONE group of a linear's reductions before the link at a
+    time, none (PR 39's form, float32-partials) or both; each leaf
+    against the plain float32 reference's (on the chip at the train
+    cell's size: PERF.md section 6, PRs 39 and 42)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = tmp_path / "grad_precision.json"
     done = subprocess.run(
@@ -458,15 +631,46 @@ def test_the_gradient_precision_tool_compares_every_leaf(tmp_path):
         capture_output=True, text=True, timeout=600,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert done.returncode == 0, done.stderr[-2000:]
-    assert "merged: all-reduce" in done.stdout
-    assert "fallback: all-reduce" in done.stdout
+    names = ["merged", "fallback", "float32-partials",
+             "bfloat16-activations", "bfloat16-kernel-gradients",
+             "bfloat16-partials"]
+    for lowering in names:
+        assert f"{lowering}: all-reduce" in done.stdout
+    assert "merged / float32-partials error, over 21 leaves" in done.stdout
     doc = json.loads(out.read_text())
-    rows = {r[0]: r for r in doc["rows"]}
+    fields = [n.replace("-", "_") for n in names]
+    assert doc["columns"] == (
+        ["leaf", "elements", "ref_norm"] + [f"{f}_err" for f in fields]
+        + [f"{f}_over_float32_partials" for f in fields
+           if f != "float32_partials"]
+        + [f"merged_minus_{f}" for f in fields[1:]])
+    rows = {r[0]: dict(zip(doc["columns"], r)) for r in doc["rows"]}
     assert len(rows) == 3 + 9 * 2       # embed, final norm, head, 2 layers
-    assert abs(doc["losses"]["merged"] - doc["losses"]["reference"]) < 2e-3
-    for _name, _n, _scale, merged, fallback, between in rows.values():
+    assert set(doc["losses"]) == set(names) | {"reference"}
+    assert set(doc["rounded_once"]) == {"forward", "kernel_gradient"}
+    for name in names:
+        assert abs(doc["losses"][name] - doc["losses"]["reference"]) < 2e-3
+    kernels = re.compile(r"\.(gate|up|down|head)$")
+    for leaf, r in rows.items():
+        merged, fallback = r["merged_err"], r["fallback_err"]
         assert 0 < merged < 0.05 and 0 < fallback < 0.05
-        assert 0.8 < merged / fallback < 1.25 and between <= merged + fallback
-    # nothing after the last group's backward differs
-    assert [rows[k][5] for k in (".layers[1].down", ".final_norm", ".head")
-            ] == [0.0, 0.0, 0.0]
+        assert 0.8 < merged / fallback < 1.25
+        assert r["merged_minus_fallback"] <= merged + fallback
+        for f in fields:
+            assert 0 < r[f"{f}_err"] < 0.05
+            if f != "float32_partials":
+                # the column a group is judged by: two errors' quotient
+                assert r[f"{f}_over_float32_partials"] == pytest.approx(
+                    r[f"{f}_err"] / r["float32_partials_err"])
+                assert 0.8 < r[f"{f}_over_float32_partials"] < 1.25
+        # the tree IS the stand-in that rounds the kernels' gradients
+        # alone: the same gradient to the bit; against float32-partials it
+        # differs in the linears' own kernels and in no other leaf
+        assert r["merged_minus_bfloat16_kernel_gradients"] == 0.0
+        assert (r["merged_minus_float32_partials"] > 0) == bool(
+            kernels.search(leaf)), leaf
+        # rounding the activations' sums moves every leaf
+        assert r["merged_minus_bfloat16_activations"] > 0
+    # nothing after the last group's backward differs from the fallback
+    assert [rows[k]["merged_minus_fallback"] for k in (
+        ".layers[1].down", ".final_norm", ".head")] == [0.0, 0.0, 0.0]

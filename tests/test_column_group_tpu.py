@@ -1,7 +1,9 @@
-"""What only the TPU's compiler can show of runtime/column_group.py, shown
-without the chip: the train step compiled HERE for a described v5e 2x2
-(on-chip-measurement guide, section 2). The CPU compiler merges the
-fallback's two reductions by itself; the TPU's does not.
+"""What only the TPU's compiler can show of runtime/column_group.py and of
+what a linear's reductions read, shown without the chip: the train step
+compiled HERE for a described v5e 2x2 (on-chip-measurement guide, section
+2). The CPU compiler merges the fallback's two reductions by itself; the
+TPU's does not while they read the dots' float32 partial sums (PRs 38, 39:
+the tree's form), and would if they read bfloat16 (PR 42).
 
 The topology is described inside a fixture, never at import: one worker
 loads the TPU's library, the others collect the same tests and skip none."""
@@ -61,17 +63,38 @@ def merged(topo):
     return _compiled_step(topo)
 
 
+def _without_the_path(topo):
+    from tools.chip_grad_precision import fallback
+
+    with fallback():
+        return _compiled_step(topo)
+
+
 @pytest.fixture(scope="module")
 def fallback(topo):
-    from flexflow_tpu.runtime import column_group
+    return _without_the_path(topo)
 
-    mp = pytest.MonkeyPatch()
-    mp.setattr(column_group, "column_split",
-               lambda graph, mesh, members: None)
-    try:
-        return _compiled_step(topo)
-    finally:
-        mp.undo()
+
+def _standin_pair(topo, name):
+    """(merged, fallback) under one of the gradient tool's stand-ins for a
+    linear's three dots, for these two traces."""
+    from tools.chip_grad_precision import LOWERINGS
+
+    with LOWERINGS[name]():
+        return _compiled_step(topo), _without_the_path(topo)
+
+
+@pytest.fixture(scope="module")
+def float32_pair(topo):
+    """As PR 39 knew them: every dot of a linear handing out float32."""
+    return _standin_pair(topo, "float32-partials")
+
+
+@pytest.fixture(scope="module")
+def bfloat16_pair(topo):
+    """As PR 42 first had them: every dot of a linear handing out the
+    activations' dtype."""
+    return _standin_pair(topo, "bfloat16-partials")
 
 
 def _model_backward(compiled):
@@ -83,22 +106,59 @@ def _model_backward(compiled):
             if c.axes == ("model",) and c.phase == "backward"]
 
 
-def test_the_tpu_compiler_reduces_the_input_gradient_once(merged, fallback):
-    mlp = re.compile(r"l\d+_(gate|up)_")
-    ours = [c for c in _model_backward(merged) if mlp.match(c.node or "")]
-    theirs = [c for c in _model_backward(fallback)
-              if mlp.match(c.node or "")]
+_MLP = re.compile(r"l\d+_(gate|up)_")
+
+
+def _total(collectives):
+    return sum(c.payload for c in collectives)
+
+
+def test_the_tpu_compiler_reduces_the_input_gradient_once(
+        merged, float32_pair):
+    ours = [c for c in _model_backward(merged) if _MLP.match(c.node or "")]
     # one all-reduce of a chip's input a layer, under `gate`'s key
     assert sorted(c.node.rsplit("_", 1)[0] for c in ours) == [
         f"l{i}_gate" for i in range(LCFG.layers)]
     assert [c.payload for c in ours] == [X_BYTES] * LCFG.layers
-    # the fallback moves both partial gradients (at this size the
-    # all-reduce combiner makes one call of the two, and may fold a small
-    # neighbour in): a layer's input more, and nothing else differs
+    # what the map was written against: on float32 partial sums the
+    # fallback moves both partial gradients (at this size the all-reduce
+    # combiner makes one call of the two, and may fold a small neighbour
+    # in): a layer's input more than the map on the same sums, and nothing
+    # else differs
+    merged32, fallback32 = float32_pair
+    theirs = [c for c in _model_backward(fallback32)
+              if _MLP.match(c.node or "")]
     assert len(theirs) >= LCFG.layers
-    total = lambda cs: sum(c.payload for c in cs)
-    assert (total(_model_backward(fallback))
-            - total(_model_backward(merged))) == X_BYTES * LCFG.layers
+    assert (_total(_model_backward(fallback32))
+            - _total(_model_backward(merged32))) == X_BYTES * LCFG.layers
+
+
+def test_the_tree_needs_the_map_as_pr_39_did(merged, fallback):
+    """A linear's input gradient hands out float32 (PR 42 kept the
+    activations' sums float32 on the link), so the fallback's two
+    all-reduces a group still read float32 partial sums and the TPU's
+    compiler still leaves them two: a layer's input more over `model`
+    going back than the map's program, and nothing else differs."""
+    assert (_total(_model_backward(fallback))
+            - _total(_model_backward(merged))) == X_BYTES * LCFG.layers
+
+
+def test_on_bfloat16_partial_sums_the_compiler_merges_the_fallbacks_two(
+        bfloat16_pair):
+    """Were a linear's dots to hand out the activations' dtype (PR 42's
+    first form; the gradient tool's stand-in), the fallback's two
+    all-reduces a group would read bfloat16 with no convert folded into
+    their output, and the TPU's compiler would make ONE of them by itself
+    (`allreduce(a) + allreduce(b)` -> `allreduce(a + b)`): the same calls
+    and bytes over `model` going back as the map's program. Not taken:
+    rounding the activations' sums before the link moved every gradient
+    leaf 1.5-4.9 % farther from float32 (PERF.md section 6)."""
+    merged, fallback = bfloat16_pair
+    ours, theirs = _model_backward(merged), _model_backward(fallback)
+    assert len(theirs) == len(ours)
+    assert _total(theirs) == _total(ours)
+    mlp = [c for c in theirs if _MLP.match(c.node or "")]
+    assert [c.payload for c in mlp] == [X_BYTES] * LCFG.layers
 
 
 def test_the_merged_reduction_reads_the_activations_dtype(merged):
@@ -115,3 +175,167 @@ def test_the_merged_reduction_reads_the_activations_dtype(merged):
             r"all-reduce(?:-start)?\(([^)]*)\)", ln).group(1).split(", ")
         name = operand.split("%")[-1]
         assert produced[name] == "bf16", (operand, produced[name])
+
+
+# ---------------------------------------------------------------------------
+# what every all-reduce of the step READS (PR 42): a linear KERNEL's gradient
+# is rounded on the chip before it crosses the batch's axes; a linear's sums
+# of ACTIVATIONS cross in float32
+
+
+_LINE = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<tuple>\(*)(?P<dtype>\w+)\[")
+_CALL = re.compile(r" all-reduce(?:-start)?\((.*?)\), channel_id=")
+_LINEAR = re.compile(r"^(l\d+_(gate|up|down)|lm_head)_\d+$")
+_NORM = re.compile(r"^(l\d+_(attn|mlp)_norm|final_norm)_\d+$")
+
+
+def _reads(compiled):
+    """[(mesh axes, operand dtype, node key or None, the operand's own
+    `op_name`)] for every operand of every all-reduce of the program. The
+    all-reduce combiner makes one call of many gradients, so what an
+    operand is is read from the line that MAKES it, not from the call's."""
+    from flexflow_tpu.obs.scopes import (
+        classify,
+        collective_groups,
+        group_axes,
+        sorted_keys,
+    )
+
+    text, keys = compiled
+    keys = sorted_keys(keys)
+    made = {}
+    for ln in text.splitlines():
+        m = _LINE.match(ln)
+        if m:
+            made[m.group("name")] = (
+                "tuple" if m.group("tuple") else m.group("dtype"), ln)
+
+    def op_name(name, hops=4):
+        _dtype, ln = made[name]
+        found = re.search(r'op_name="([^"]*)"', ln)
+        if found or not hops:
+            return found.group(1) if found else ""
+        inner = re.search(r"\(%([\w.\-]+)", ln)      # a bare bitcast
+        return op_name(inner.group(1), hops - 1) if inner else ""
+
+    out = []
+    for ln in text.splitlines():
+        call = _CALL.search(ln)
+        if not call:
+            continue
+        axes = group_axes(collective_groups(ln), MESH)
+        for operand in re.sub(r"/\*index=\d+\*/", "",
+                              call.group(1)).split(", "):
+            name = operand.split("%")[-1].strip()
+            stack = op_name(name)
+            out.append((axes, made[name][0], classify(stack, keys)[1],
+                        stack))
+    return out
+
+
+def _kind(node):
+    """`l1_down_1020` -> `down`, `lm_head_1023` -> `head`."""
+    return node.rsplit("_", 1)[0].split("_")[-1]
+
+
+def test_only_the_named_exceptions_cross_in_float32(merged):
+    """Every all-reduce the partitioner places on a linear kernel's
+    gradient, like the attention's and the merged reduction, reads
+    bfloat16. What still reads float32, each by its node: a linear's sums
+    of ACTIVATIONS over `model` (forward `down`, the head's input
+    gradient: rounding them first costs every leaf precision, PERF.md
+    section 6, PR 42), the embedding table's gradient (a scatter-add into
+    the float32 master), the norms' scales, and the loss (its per-token
+    sums over the split vocabulary, the label's logit picked across the
+    split, its mean)."""
+    _text, keys = merged
+    reads = _reads(merged)
+    assert len(reads) > 8 * LCFG.layers
+    assert {dtype for _axes, dtype, _node, _stack in reads} == {
+        "bf16", "f32"}
+    wide = [(axes, node, stack.rsplit("/", 1)[-1], "transpose(" in stack)
+            for axes, dtype, node, stack in reads if dtype == "f32"]
+    norms = {k for k in keys if _NORM.match(k)}
+    downs = {k for k in keys if re.match(r"l\d+_down_\d+$", k)}
+    (table,) = [k for k in keys if k.startswith("tok_emb_")]
+    (head,) = [k for k in keys if k.startswith("lm_head_")]
+    assert len(norms) == 2 * LCFG.layers + 1 and len(downs) == LCFG.layers
+    assert {w for w in wide if w[1]} == (
+        {(("data",), table, "scatter-add", True)}
+        | {(("data",), k, "reduce_sum", True) for k in norms}
+        | {(("model",), k, "dot_general", False) for k in downs}
+        | {(("model",), head, "dot_general", True)})
+    loss = {(axes, op) for axes, node, op, _back in wide if node is None}
+    assert loss == {(("model",), "reduce_sum"), (("model",), "gather"),
+                    (("data",), "reduce_sum")}
+    # and nothing over the batch's axes under a linear's key
+    assert not [r for r in reads if r[0] != ("model",) and r[1] != "bf16"
+                and r[2] and _LINEAR.match(r[2])]
+
+
+def test_every_linear_kernels_gradient_crosses_in_bfloat16(merged):
+    """By node and axis: the gradient sync of `gate`, `up` (kernels the
+    map is handed at the activations' dtype), `down` and the head over
+    `data` reads bfloat16; over `model` only the group's merged input
+    gradient does, `down`'s forward partial sums and the head's input
+    gradient read float32."""
+    _text, keys = merged
+    linears = {k for k in keys if _LINEAR.match(k)}
+    assert len(linears) == 3 * LCFG.layers + 1
+    reads = [r for r in _reads(merged) if r[2] in linears]
+    over_data = [r for r in reads if r[0] == ("data",)]
+    assert {dtype for _axes, dtype, _node, _stack in over_data} == {"bf16"}
+    assert {node for _axes, _dtype, node, _stack in over_data} == linears
+    # (the merged sum's operand is made by a fusion that the compiler names
+    # for either of the group's two linears)
+    over_model = {(_kind(node).replace("gate", "up"), "transpose(" in stack,
+                   dtype)
+                  for axes, dtype, node, stack in reads
+                  if axes == ("model",)}
+    assert over_model == {("down", False, "f32"), ("head", True, "f32"),
+                          ("up", True, "bf16")}
+
+
+_CONTRACTION = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)(\[[\d,]*\])\S* convolution\(.*"
+    r'op_name="([^"]*)"')
+
+
+def test_what_a_linears_contractions_hand_out_on_the_tpu(merged):
+    """The numerics plan's `accum` (`Executor.dtype_plan()`), read where
+    the TPU's module states it: the TPU's compiler makes a `convolution`
+    of every dot, and under a LINEAR's key each one that keeps the rows
+    (forward, recomputed, the input gradient) hands out float32 and is
+    rounded after; the kernel gradient's, and no other, hands out the
+    activations' dtype (`ops/jax_ops.py` `contraction`: the chip's own
+    accumulator is float32 either way)."""
+    from flexflow_tpu.obs.scopes import classify, sorted_keys
+
+    text, keys = merged
+    keys = sorted_keys(keys)
+    found = set()
+    for ln in text.splitlines():
+        m = _CONTRACTION.match(ln)
+        node = classify(m.group(3), keys)[1] if m else None
+        if node and _LINEAR.match(node):
+            rows_kept = m.group(2).startswith(f"[{BATCH // MESH['data']},")
+            found.add((_kind(node), "transpose(" in m.group(3), rows_kept,
+                       m.group(1)))
+    assert found == (
+        {(k, False, True, "f32") for k in ("gate", "up", "down", "head")}
+        | {(k, True, True, "f32") for k in ("gate", "up", "down", "head")}
+        | {(k, True, False, "bf16") for k in ("gate", "up", "down", "head")})
+
+
+def test_the_float32_form_is_what_the_walk_would_catch(float32_pair):
+    """The walk sees an all-reduce that reads float32 partial sums: PR
+    39's form, reinstated for one trace by the gradient tool's stand-in,
+    fails it at every linear's gradient sync."""
+    before, _fallback = float32_pair
+    wide = {(_kind(node), axes)
+            for axes, dtype, node, _stack in _reads(before)
+            if dtype == "f32" and node and _LINEAR.match(node)}
+    assert wide == {("down", ("model",)), ("head", ("model",)),
+                    ("gate", ("data",)), ("up", ("data",)),
+                    ("down", ("data",)), ("head", ("data",))}
