@@ -39,6 +39,10 @@ class Invariant:
                 checker tracks (check(pool, committed));
       scales  — needs the quantized-pool scale-sidecar mirror the model
                 checker tracks (check(pool, scale_of, content_tag));
+      window  — needs a server's window-class tables and its requests'
+                block maps (check(tables, rows, window, page_size));
+      state   — needs a server's account of its slots' recurrent states
+                (check(states, live, launched));
       op      — only observable at the mutating operation itself; the
                 model checker enforces it inline (check is None).
     """
@@ -256,6 +260,44 @@ def _window_class(tables, rows: Dict[int, Tuple[Dict[int, int], int]],
     return [f"window-class: {m}" for m in v]
 
 
+def _slot_state(states: Sequence[Tuple[Optional[int], int]],
+                live: Dict[int, Tuple[int, int]],
+                launched: Sequence[Tuple[int, int, int]]) -> List[str]:
+    """`states[slot]` is (the request a slot's recurrent state belongs
+    to or None, the rows it holds) as the scheduler accounts for it;
+    `live` maps a live slot to (its request, the next row it writes);
+    `launched` is the newest launch's live items (slot, first row, rows)
+    in launch order."""
+    v = []
+    owned: Dict[int, int] = {}
+    for slot, (owner, rows) in enumerate(states):
+        if owner is None:
+            continue
+        if owner in owned:
+            v.append(f"request {owner} owns the states of slots "
+                     f"{owned[owner]} and {slot}")
+        owned[owner] = slot
+        if slot not in live or live[slot][0] != owner:
+            v.append(f"slot {slot}: its state belongs to request {owner}, "
+                     f"which is not live there")
+        elif rows != live[slot][1]:
+            v.append(f"slot {slot}: its state holds {rows} rows and its "
+                     f"request writes row {live[slot][1]} next (a state "
+                     "is zero at admission and follows every row)")
+    unowned = [s for s in live if states[s][0] is None]
+    if unowned:
+        v.append(f"live slots {unowned} have no state of their own")
+    runs: Dict[int, int] = {}
+    prev = None
+    for slot, first, rows in launched:
+        if slot in runs and (prev != slot or runs[slot] != first):
+            v.append(f"slot {slot}: the launch's items are not "
+                     "consecutive and in row order")
+        runs[slot] = first + rows
+        prev = slot
+    return [f"slot-state: {m}" for m in v]
+
+
 CATALOG: Tuple[Invariant, ...] = (
     Invariant(
         "free-accounting", "pool",
@@ -317,6 +359,16 @@ CATALOG: Tuple[Invariant, ...] = (
         "released page",
         _window_class),
     Invariant(
+        "slot-state", "state",
+        "where layers keep a recurrent state a slot beside the pages: a "
+        "state belongs to exactly one request, live in that slot; it is "
+        "zero at admission (it holds no row) and holds exactly the rows "
+        "its request has written since; a launch's items of one slot are "
+        "consecutive and in row order, and no launch names the state of "
+        "an idle slot (the launch's items are checked against the "
+        "states' owners before a request's last launch vacates it)",
+        _slot_state),
+    Invariant(
         "cow-write", "op",
         "no row write lands in a page the writer does not own, a page "
         "with refcount != 1, or rows a hash-index entry has published "
@@ -357,6 +409,16 @@ def check_window_class(tables, rows, window: int, page_size: int
     for entry in CATALOG:
         if entry.scope == "window":
             v += entry.check(tables, rows, window, page_size)
+    return v
+
+
+def check_slot_state(states, live, launched) -> List[str]:
+    """Run the state-scope invariant over a server's account of its
+    slots' recurrent states (paged/scheduler.py `_check_invariants`)."""
+    v: List[str] = []
+    for entry in CATALOG:
+        if entry.scope == "state":
+            v += entry.check(states, live, launched)
     return v
 
 

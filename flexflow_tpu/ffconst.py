@@ -130,6 +130,7 @@ class OpType(enum.Enum):
     MULTIHEAD_ATTENTION = "multihead_attention"
     RING_ATTENTION = "ring_attention"
     LATENT_ATTENTION = "latent_attention"
+    KDA_ATTENTION = "kda_attention"
     # elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"

@@ -230,7 +230,7 @@ class FFModel:
         return Tensor(node)
 
     def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
-                         q_lora_rank: int, kv_lora_rank: int,
+                         q_lora_rank: Optional[int], kv_lora_rank: int,
                          qk_nope_head_dim: int, qk_rope_head_dim: int,
                          v_head_dim: int, softmax_scale: float,
                          norm_eps: float = 1e-6, rope_theta: float = 10000.0,
@@ -238,7 +238,7 @@ class FFModel:
                          rope_beta_fast: float = 32.0,
                          rope_beta_slow: float = 1.0,
                          rope_interleave: bool = True,
-                         q_scale_beta: float = 0.0,
+                         q_scale_beta: float = 0.0, out_gate: bool = False,
                          name: Optional[str] = None) -> Tensor:
         """Causal multi-head latent attention (A.LatentAttentionAttrs).
         Each matrix is drawn Glorot-uniform over ITS fan-in and fan-out
@@ -249,34 +249,74 @@ class FFModel:
             float(softmax_scale), float(norm_eps), float(rope_theta),
             float(rope_factor), int(rope_original_max),
             float(rope_beta_fast), float(rope_beta_slow),
-            bool(rope_interleave), float(q_scale_beta))
+            bool(rope_interleave), float(q_scale_beta), bool(out_gate))
         node = self._add(OpType.LATENT_ATTENTION, attrs, [input],
                          name or "latent_attention")
         h = num_heads
         self._record_init(
             node,
-            w_uq=_glorot(q_lora_rank, h * attrs.qk_head_dim),
+            w_uq=_glorot(q_lora_rank or input.shape[-1],
+                         h * attrs.qk_head_dim),
             w_ukv=_glorot(kv_lora_rank, h * (qk_nope_head_dim + v_head_dim)),
             wo=_glorot(h * v_head_dim, embed_dim))
+        return Tensor(node)
+
+    def kda_attention(self, input: Tensor, embed_dim: int, num_heads: int,
+                      head_dim: int, conv_taps: int = 4,
+                      lower_bound: float = -5.0, norm_eps: float = 1e-6,
+                      name: Optional[str] = None) -> Tensor:
+        """A delta-rule linear-attention layer (A.KdaAttentionAttrs). The
+        draws that are not Glorot: taps in [-0.5, 0.5], `dt_bias` in
+        [-6, -2] (log-decays of -0.01 to -0.6 a token before the input
+        moves them, so the state remembers tens to hundreds of tokens),
+        `a_log` 0, the norm's scale 1."""
+        from flexflow_tpu.runtime.initializer import UniformInitializer
+
+        node = self._add(
+            OpType.KDA_ATTENTION,
+            A.KdaAttentionAttrs(embed_dim, num_heads, head_dim,
+                                int(conv_taps), float(lower_bound),
+                                float(norm_eps)),
+            [input], name or "kda_attention")
+        taps = UniformInitializer(-0.5, 0.5)
+        self._record_init(node, conv_q=taps, conv_k=taps, conv_v=taps,
+                          dt_bias=UniformInitializer(-6.0, -2.0))
         return Tensor(node)
 
     def expert_share(self, input: Tensor, n_experts: int, k: int,
                      hidden_dim: int, held: Optional[Sequence[int]] = None,
                      shared_hidden: int = 0, norm_topk: bool = True,
-                     routed_scale: float = 1.0,
+                     routed_scale: float = 1.0, score: str = "softmax",
+                     n_group: int = 1, topk_group: int = 1,
+                     select_bias: bool = False,
                      name: Optional[str] = None) -> Tensor:
         """One chip's share (`held` = (lo, hi), default all) of a dropless
-        SwiGLU expert layer with its shared expert (A.ExpertShareAttrs)."""
+        SwiGLU expert layer with its shared expert (A.ExpertShareAttrs).
+        A selection bias is drawn in [-0.1, 0.1], not zero, so that
+        selection and weighting differ."""
         lo, hi = held if held is not None else (0, n_experts)
         if not 0 <= lo < hi <= n_experts:
             raise ValueError(f"held experts {lo}..{hi} of {n_experts}")
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {score!r}: softmax or sigmoid")
+        if n_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"{topk_group} of {n_group} groups over "
+                             f"{n_experts} experts")
         node = self._add(
             OpType.EXPERT_SHARE,
             A.ExpertShareAttrs(n_experts, k, hidden_dim, int(lo), int(hi),
-                               shared_hidden, norm_topk, routed_scale),
+                               shared_hidden, norm_topk, routed_scale,
+                               score, int(n_group), int(topk_group),
+                               bool(select_bias)),
             [input], name or "expert_share")
         init = _glorot(input.shape[-1], hidden_dim)
-        self._record_init(node, w_gate=init, w_up=init, w_down=init)
+        bias = None
+        if select_bias:
+            from flexflow_tpu.runtime.initializer import UniformInitializer
+
+            bias = UniformInitializer(-0.1, 0.1)
+        self._record_init(node, w_gate=init, w_up=init, w_down=init,
+                          bias=bias)
         return Tensor(node)
 
     def ring_attention(self, query: Tensor, key: Tensor, value: Tensor,

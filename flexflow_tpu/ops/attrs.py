@@ -385,11 +385,16 @@ class LatentAttentionAttrs(OpAttrs):
     when `rope_interleave`. `softmax_scale` is the whole score scale
     (head size and YaRN's mscale^2 folded in by the builder) and
     `q_scale_beta` > 0 multiplies q by 1 + beta * ln(1 + floor(pos /
-    rope_original_max)) (the Llama-4 position scale)."""
+    rope_original_max)) (the Llama-4 position scale).
+
+    `q_lora_rank` None (Ling-3.0-flash) drops the query's low-rank step:
+    q_h = x W_uq, W_uq (embed, heads, nope + rope), no W_dq and no norm.
+    `out_gate` multiplies each head's output by sigmoid(x w_gate,h), one
+    scalar a head, before W_o."""
 
     embed_dim: int
     num_heads: int
-    q_lora_rank: int
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -403,6 +408,7 @@ class LatentAttentionAttrs(OpAttrs):
     rope_beta_slow: float = 1.0
     rope_interleave: bool = True
     q_scale_beta: float = 0.0
+    out_gate: bool = False
 
     @property
     def latent_width(self) -> int:
@@ -421,12 +427,18 @@ class LatentAttentionAttrs(OpAttrs):
     def weights(self, x: Shape):
         dt = x.dtype
         e, h = x.dims[-1].size, self.num_heads
-        return {
+        q_in = e if self.q_lora_rank is None else self.q_lora_rank
+        low_rank = {} if self.q_lora_rank is None else {
             "w_dq": WeightSpec(TensorShape((e, self.q_lora_rank), dt)),
             "q_norm": WeightSpec(TensorShape((self.q_lora_rank,), dt),
                                  "ones"),
+        }
+        gate = {"w_gate": WeightSpec(TensorShape((e, h), dt))} \
+            if self.out_gate else {}
+        return {
+            **low_rank,
             "w_uq": WeightSpec(TensorShape(
-                (self.q_lora_rank, h, self.qk_head_dim), dt)),
+                (q_in, h, self.qk_head_dim), dt)),
             "w_dkv": WeightSpec(TensorShape((e, self.latent_width), dt)),
             "kv_norm": WeightSpec(TensorShape((self.kv_lora_rank,), dt),
                                   "ones"),
@@ -435,20 +447,94 @@ class LatentAttentionAttrs(OpAttrs):
                  self.qk_nope_head_dim + self.v_head_dim), dt)),
             "wo": WeightSpec(TensorShape((h, self.v_head_dim,
                                           self.embed_dim), dt)),
+            **gate,
         }
 
     def flops(self, ins, outs):
         x = ins[0]
         b, s, e = x.dims[0].size, x.dims[1].size, x.dims[-1].size
         h = self.num_heads
+        q_proj = (e * h * self.qk_head_dim if self.q_lora_rank is None
+                  else e * self.q_lora_rank
+                  + self.q_lora_rank * h * self.qk_head_dim)
         proj = 2 * b * s * (
-            e * self.q_lora_rank + self.q_lora_rank * h * self.qk_head_dim
+            q_proj + (e * h if self.out_gate else 0)
             + e * self.latent_width
             + self.kv_lora_rank * h * (self.qk_nope_head_dim
                                        + self.v_head_dim)
             + h * self.v_head_dim * e)
         attn = 2 * b * h * s * s * (self.qk_head_dim + self.v_head_dim)
         return proj + attn
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaAttentionAttrs(OpAttrs):
+    """A delta-rule LINEAR attention layer with per-channel decay (KDA,
+    Kimi Linear, arXiv:2510.26692; the linear layers of Ling-3.0-flash).
+    `num_heads` heads of d = `head_dim` key and value channels:
+
+        q~, k~, v~ = x W_q, x W_k, x W_v; a causal depthwise convolution of
+        `conv_taps` taps over time on each channel, then SiLU;
+        q = l2norm(q) d^-1/2, k = l2norm(k) a head
+        a_t = lower_bound * sigmoid(exp(A_log_h) (x W_f + dt_bias))   (< 0)
+        beta_t = sigmoid(x w_beta,h)
+        S_t = (I - beta_t k_t k_t^T) Diag(exp a_t) S_{t-1} + beta_t k_t v_t^T
+        y = [RMSNorm_d(S_t^T q_t) * sigmoid(x W_g)_h] W_o
+
+    What it keeps of the past is a FIXED-SIZE state, whatever the length:
+    S (heads, d, d) float32 and the convolution's last `conv_taps` - 1
+    input rows. A paged server holds one of each a SLOT, beside its pages
+    (runtime/executor.py `paged_kv_cache_specs`; ops/kda_attention.py has
+    the lowerings, ops/pallas/kda_scan.py the kernel)."""
+
+    embed_dim: int
+    num_heads: int
+    head_dim: int
+    conv_taps: int = 4
+    lower_bound: float = -5.0
+    norm_eps: float = 1e-6
+
+    @property
+    def inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    def infer(self, x: Shape):
+        dims = tuple(_carry(d) for d in x.dims[:-1]) + (
+            ParallelDim(self.embed_dim),)
+        return (Shape(dims, x.dtype, x.replica),)
+
+    def weights(self, x: Shape):
+        dt = x.dtype
+        e, h, c = x.dims[-1].size, self.num_heads, self.inner
+
+        def mat(*shape, init="glorot_uniform"):
+            return WeightSpec(TensorShape(shape, dt), init)
+
+        return {
+            "wq": mat(e, c), "wk": mat(e, c), "wv": mat(e, c),
+            "conv_q": mat(self.conv_taps, c), "conv_k": mat(self.conv_taps, c),
+            "conv_v": mat(self.conv_taps, c),
+            "w_f": mat(e, c), "dt_bias": mat(c, init="zeros"),
+            "a_log": mat(h, init="zeros"), "w_beta": mat(e, h),
+            "w_g": mat(e, c), "o_norm": mat(self.head_dim, init="ones"),
+            "wo": mat(c, e),
+        }
+
+    def state_specs(self, slots: int):
+        """{name: (shape, dtype name or None: the activations')} of what a
+        server keeps a slot."""
+        return {
+            "s": ((slots, self.num_heads, self.head_dim, self.head_dim),
+                  "float32"),
+            "conv": ((slots, self.conv_taps - 1, 3 * self.inner), None),
+        }
+
+    def flops(self, ins, outs):
+        x = ins[0]
+        b, s, e = x.dims[0].size, x.dims[1].size, x.dims[-1].size
+        c = self.inner
+        proj = 2 * b * s * (e * (5 * c + self.num_heads) + c * e)
+        return proj + 7 * b * s * c * self.head_dim
 
 
 # ---------------------------------------------------------------------------
@@ -845,6 +931,11 @@ class ExpertShareAttrs(OpAttrs):
     (`held_lo` <= e < `held_hi` of `n_experts`), routes every token over
     all `n_experts` (softmax in float32, top `k`, renormalised over
     those k when `norm_topk`, times `routed_scale`), and computes
+    (`score` "sigmoid", `n_group` / `topk_group` and `select_bias` are the
+    DeepSeek-V3 router Ling-3.0-flash publishes: sigmoid scores, a bias
+    added for SELECTION only, a group's score the sum of its two largest,
+    the `topk_group` best groups open, the top `k` within them, weights
+    the unbiased scores; ops/expert_share.py `route`)
 
         y = sum over the token's top-k experts HELD HERE of
               w_e * (silu(x Wg_e) * x Wu_e) Wd_e   +   E_shared(x)
@@ -868,6 +959,10 @@ class ExpertShareAttrs(OpAttrs):
     shared_hidden: int = 0      # 0: no shared expert
     norm_topk: bool = True
     routed_scale: float = 1.0
+    score: str = "softmax"      # or "sigmoid"
+    n_group: int = 1            # group-limited routing: of n_group groups
+    topk_group: int = 1         #   of experts, the topk_group best open
+    select_bias: bool = False   # a bias a routed expert, for selection only
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -885,6 +980,8 @@ class ExpertShareAttrs(OpAttrs):
         d, dt, g, f = x.dims[-1].size, x.dtype, self.n_held, self.hidden_dim
         w = {
             "router": WeightSpec(TensorShape((d, self.n_experts), dt)),
+            **({"bias": WeightSpec(TensorShape((self.n_experts,), dt),
+                                   "zeros")} if self.select_bias else {}),
             "w_gate": WeightSpec(TensorShape((g, d, f), dt)),
             "w_up": WeightSpec(TensorShape((g, d, f), dt)),
             "w_down": WeightSpec(TensorShape((g, f, d), dt)),

@@ -25,16 +25,43 @@ STATS = ("moe_assignments", "experts_hit", "experts_held",
          "moe_rows_padded")
 
 
-def route(attrs, x, router):
+def route(attrs, x, router, bias=None):
     """x: (T, d) -> (ids (T, k) int32 over ALL experts, weights (T, k)
-    float32). Logits, softmax and the renormalisation in float32."""
+    float32). Logits, scores and the renormalisation in float32. The
+    scoring function and the groups are the attrs': softmax over one
+    group is the default; with `n_group` > 1 only the `topk_group`
+    groups whose two largest scores sum highest stay open, and `bias`
+    (`select_bias`) moves the SELECTION alone: the weights are the
+    unbiased scores of the chosen."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = lax.top_k(probs, attrs.k)
+    probs = (jax.nn.sigmoid(logits) if attrs.score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+    if bias is not None or attrs.n_group > 1:
+        w, ids = _select(attrs, probs, bias)
+    else:
+        w, ids = lax.top_k(probs, attrs.k)
     if attrs.norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return ids.astype(jnp.int32), w * attrs.routed_scale
+
+
+def _select(attrs, scores, bias):
+    """Top k of (T, E) scores by score + bias within the open groups;
+    returns the UNBIASED scores of the chosen and their ids."""
+    T, E = scores.shape
+    chosen_by = scores if bias is None else scores + bias.astype(
+        jnp.float32)
+    if attrs.n_group > 1:
+        groups = chosen_by.reshape(T, attrs.n_group, E // attrs.n_group)
+        group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+        _, best = lax.top_k(group_score, attrs.topk_group)
+        is_open = jnp.any(best[:, :, None] == jnp.arange(attrs.n_group),
+                          axis=1)
+        chosen_by = jnp.where(is_open[:, :, None], groups,
+                              -jnp.inf).reshape(T, E)
+    _, ids = lax.top_k(chosen_by, attrs.k)
+    return jnp.take_along_axis(scores, ids, axis=-1), ids
 
 
 def _swiglu(x, gate, up, down):
@@ -120,7 +147,7 @@ def expert_share(attrs, x, params, live=None):
     is its shared expert's alone and nobody reads it."""
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
-    ids, w = route(attrs, xt, params["router"])
+    ids, w = route(attrs, xt, params["router"], params.get("bias"))
     y, stats = routed(attrs, xt, ids, w, params,
                       None if live is None else live.reshape(-1))
     if attrs.shared_hidden:

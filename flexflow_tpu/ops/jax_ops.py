@@ -529,6 +529,27 @@ def _latent_attention(attrs, inputs, params, ctx):
     return [y]
 
 
+@register_lowering(OpType.KDA_ATTENTION)
+def _kda_attention(attrs, inputs, params, ctx):
+    """A delta-rule linear-attention layer (ops/kda_attention.py). Without
+    a cache the whole sequence from a zero state; in a paged launch the
+    node's cache entry is its STATE a slot ("s", "conv"), continued from
+    the rows the launch's items name. There is no dense decode cache for
+    it: the state lives beside pages only."""
+    from flexflow_tpu.ops import kda_attention as kda
+
+    (x,) = inputs
+    if ctx.kv_cache is None:
+        return [kda.dense_attention(attrs, x, params)]
+    if ctx.page_tables is None:
+        raise NotImplementedError(
+            "a KDA layer decodes from its per-slot state only: serve "
+            "with serve_generation(paged=True)")
+    y, state = kda.paged_attention(attrs, x, params, ctx)
+    ctx.cache_updates.update(state)
+    return [y]
+
+
 @register_lowering(OpType.RING_ATTENTION)
 def _ring_attention(attrs, inputs, params, ctx):
     # Sequence-parallel lowering lives in flexflow_tpu.parallel.ring; when the

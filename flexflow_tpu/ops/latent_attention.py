@@ -99,8 +99,12 @@ def project(attrs, x, params, positions):
     query scale too."""
     B, S, _ = x.shape
     H, n = attrs.num_heads, attrs.qk_nope_head_dim
-    c_q = _rms(_dot(x, params["w_dq"]), params["q_norm"], attrs.norm_eps)
-    q = _dot(c_q, params["w_uq"].reshape(attrs.q_lora_rank, -1))
+    if attrs.q_lora_rank is None:
+        c_q = x
+    else:
+        c_q = _rms(_dot(x, params["w_dq"]), params["q_norm"],
+                   attrs.norm_eps)
+    q = _dot(c_q, params["w_uq"].reshape(c_q.shape[-1], -1))
     q = q.reshape(B, S, H, attrs.qk_head_dim)
     qs = query_scale(attrs, positions)[:, :, None, None]
     q_nope = (q[..., :n].astype(jnp.float32) * qs).astype(x.dtype)
@@ -113,9 +117,15 @@ def project(attrs, x, params, positions):
     return q_nope, q_rope, c_kv, k_r
 
 
-def output(attrs, o, params):
-    """(B, S, H, v) head outputs -> (B, S, E) through W_o."""
+def output(attrs, o, params, x):
+    """(B, S, H, v) head outputs -> (B, S, E) through W_o; with
+    `out_gate` each head's output first times sigmoid(x w_gate,h)."""
     B, S = o.shape[:2]
+    if attrs.out_gate:
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, params["w_gate"].astype(x.dtype),
+            preferred_element_type=jnp.float32))
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
     return _dot(o.reshape(B, S, -1), params["wo"].reshape(-1, attrs.embed_dim))
 
 
@@ -137,7 +147,7 @@ def naive_attention(attrs, x, params):
     p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(x.dtype), v,
                    preferred_element_type=jnp.float32).astype(x.dtype)
-    return output(attrs, o, params)
+    return output(attrs, o, params, x)
 
 
 def absorbed_queries(attrs, q_nope, q_rope, params):
@@ -148,12 +158,12 @@ def absorbed_queries(attrs, q_nope, q_rope, params):
     return jnp.concatenate([q_abs.astype(q_nope.dtype), q_rope], axis=-1)
 
 
-def absorbed_output(attrs, o_lat, params):
+def absorbed_output(attrs, o_lat, params, x):
     """(B, S, H, c) latent outputs -> (B, S, E): W_uv per head, then W_o."""
     w_uv = params["w_ukv"][..., attrs.qk_nope_head_dim:]     # (c, H, v)
     o = jnp.einsum("bshc,chv->bshv", o_lat, w_uv.astype(o_lat.dtype),
                    preferred_element_type=jnp.float32).astype(o_lat.dtype)
-    return output(attrs, o, params)
+    return output(attrs, o, params, x)
 
 
 def paged_attention(attrs, x, params, ctx):
@@ -169,4 +179,4 @@ def paged_attention(attrs, x, params, ctx):
     o_lat, pool = latent_paged_attention(
         q, row, ctx.kv_cache["c"], ctx.page_tables, ctx.cache_position,
         ctx.ragged_q_lens, ctx.ragged_anc, value_width=attrs.kv_lora_rank)
-    return absorbed_output(attrs, o_lat, params), pool
+    return absorbed_output(attrs, o_lat, params, x), pool

@@ -49,6 +49,9 @@ class LowerCtx:
     ragged_q_lens: Optional[object] = None
     ragged_depths: Optional[object] = None
     ragged_anc: Optional[object] = None
+    # (B,) int32: the SLOT whose recurrent state item b continues (a graph
+    # with state layers, ops/kda_attention.py); None: item b is slot b
+    state_slots: Optional[object] = None
     cache_updates: Dict[str, object] = dataclasses.field(default_factory=dict)
     # lowering writes non-trainable state updates here (BatchNorm running
     # stats, Cache buffers): key = weight name within the op

@@ -303,10 +303,41 @@ class PagedGenerationServer(_GenerationServerBase):
         # None = dirty, rebuilt from host truth on next dispatch
         self._seq_cols = self.max_pages_per_seq * self.page_size
         self._seq_dev = None
+        # STATE LAYERS (decided by the graph, Executor.state_layers): a
+        # node that keeps a fixed-size state a SLOT beside the pages has
+        # its leaves in the same `_caches` dict, indexed by slot; a launch
+        # is told its items' slots and the layer does the rest on the
+        # device (ops/kda_attention.py: a request's row 0 starts from
+        # zero, so admission uploads nothing and a slot's reuse cannot
+        # see its predecessor). What no state can follow is refused here
+        # as in serve_generation. `_state_rows` / `_state_owner` are the
+        # host's account of each slot's state for the invariant catalog.
+        self._state_keys = frozenset(ex.state_layers())
+        if self._state_keys:
+            bad = [name for name, hit in (
+                ("prefix_cache", self.prefix_cache),
+                ("megastep_ticks", self.megastep_ticks > 1),
+                ("megastep_mixed", self.megastep_mixed),
+                ("kv_dtype", self._quantized),
+                ("host_tier", host_tier is not None and host_tier != 0),
+                ("kv_quant_canary", bool(kv_quant_canary))) if hit]
+            if bad:
+                raise ValueError(
+                    f"option(s) {bad} are not supported on a graph with "
+                    "state layers: a recurrent state cannot be shared by "
+                    "prefix, carried by a megastep, quantized or spilled")
+        self._state_rows = np.zeros((self.slots,), np.int64)
+        self._state_owner: List[Optional[int]] = [None] * self.slots
+        self._state_launched: List[tuple] = []
+        self.state_resets = 0
+        self.state_resumes = 0
         self._caches = ex.init_paged_kv_cache(
             num_pages, self.page_size, dtype=pool_dt,
             num_pages_window=(self.pool_w.num_pages if self._window
-                              else None))
+                              else None), slots=self.slots)
+        self.state_bytes_per_slot = sum(
+            b.size * b.dtype.itemsize // self.slots
+            for nk in self._state_keys for b in self._caches[nk].values())
         self._caches_ref = (ex.init_paged_kv_cache(
             num_pages, self.page_size, dtype=jax.numpy.float32)
             if self._kv_quant_debug else None)
@@ -430,7 +461,8 @@ class PagedGenerationServer(_GenerationServerBase):
         )
 
         reset_rejection_log()
-        attn_key, kbufs = next(iter(self._caches.items()))
+        attn_key, kbufs = next(kv for kv in self._caches.items()
+                               if kv[0] not in self._state_keys)
         # a latent layer's pool has ONE entry a node, "c" (paged/latent.py)
         self._latent = "c" in kbufs
         kbuf = kbufs["c"] if self._latent else kbufs["k"]
@@ -458,7 +490,8 @@ class PagedGenerationServer(_GenerationServerBase):
         # bytes a cached token takes in the pool, over every layer
         self.kv_bytes_per_token = sum(
             b.shape[2] * b.dtype.itemsize
-            for bufs in self._caches.values()
+            for nk, bufs in self._caches.items()
+            if nk not in self._state_keys
             for n, b in bufs.items() if not n.endswith("_scale"))
         if self._window:
             # bytes of ONE page over the layers of each class, for the
@@ -718,6 +751,13 @@ class PagedGenerationServer(_GenerationServerBase):
                 "evictions": pool.evictions,
             },
         })
+        if self._state_keys:
+            m["state"] = {
+                "layers": len(self._state_keys),
+                "bytes_per_slot": self.state_bytes_per_slot,
+                "resets": self.state_resets,
+                "resumes_by_recompute": self.state_resumes,
+            }
         if self._window:
             m["page_classes"] = {
                 "full": {"pages_in_use": pool.pages_in_use,
@@ -775,7 +815,8 @@ class PagedGenerationServer(_GenerationServerBase):
     def _kv_pool_dtype_name(self) -> str:
         """The pool's actual storage dtype name ("int8" for a quantized
         pool) — what the kv_cache_dtype gauge reports in bits."""
-        bufs = next(iter(self._caches.values()))
+        bufs = next(b for nk, b in self._caches.items()
+                    if nk not in self._state_keys)
         if self._latent:
             return str(bufs["c"].dtype)
         return str(bufs["k"].dtype)
@@ -958,6 +999,7 @@ class PagedGenerationServer(_GenerationServerBase):
         if slot in self._admit_order:
             self._admit_order.remove(slot)
         self._active[slot] = None
+        self._state_owner[slot] = None    # its state is dropped with it
 
     def _evict(self, slot: int):
         """Preempt: free the victim's pages and requeue it (front); its
@@ -1247,6 +1289,15 @@ class PagedGenerationServer(_GenerationServerBase):
         req.admit_t = time.monotonic()
         self._active[slot] = req
         self._admit_order.append(slot)
+        if self._state_keys:
+            # the slot's state is the request's from here and holds no
+            # row: the launch that carries row 0 zeroes it on the device.
+            # A preempted request comes back through here and recomputes
+            # its state with its pages (prompt + emitted tokens)
+            self._state_owner[slot] = req.seq
+            self._state_rows[slot] = 0
+            self.state_resets += 1
+            self.state_resumes += req.preemptions > 0
         self._maybe_open_canary(req)
         return True
 
@@ -1353,9 +1404,20 @@ class PagedGenerationServer(_GenerationServerBase):
             owners.update((r.seq, r) for _s, r in rec.firsts + rec.rows)
         self.pool.check_invariants(
             {seq: r.pages for seq, r in owners.items()})
+        from flexflow_tpu.analysis import pool_invariants
+
+        if self._state_keys:
+            violations = pool_invariants.check_slot_state(
+                [(self._state_owner[s], int(self._state_rows[s]))
+                 for s in range(self.slots)],
+                {s: (r.seq, self._next_rows(r)[0]) for s, r in live.items()},
+                self._state_launched)
+            if violations:
+                raise AssertionError(
+                    "slot-state invariant violation(s):\n  "
+                    + "\n  ".join(violations))
         if not self._window:
             return
-        from flexflow_tpu.analysis import pool_invariants
 
         self.pool_w.check_invariants(
             {seq: list(r.window_pages.values())
@@ -1435,6 +1497,8 @@ class PagedGenerationServer(_GenerationServerBase):
         # twice, never a second pool
         for caches in (self._caches, self._caches_ref):
             for nk, bufs in (caches or {}).items():
+                if nk in self._state_keys:
+                    continue        # indexed by slot, not by page
                 perm = perms[classes.get(nk, 0)]
                 for name in bufs:
                     bufs[name] = bufs[name][perm]
@@ -1675,7 +1739,10 @@ class PagedGenerationServer(_GenerationServerBase):
                                axis=1 if self._window else 0)
             pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
                                    jnp.asarray(ids))
-            fed = (self._index_device(feed), self._newest)
+            fed = {"feed": (self._index_device(feed), self._newest)}
+            if self._state_keys:
+                fed["state_slots"] = jnp.asarray(slot_idx)
+                self._note_state_rows(slot_idx, pos, qls)
         total = B * window
         padded = total - int(qls.sum())
         # AHEAD: an earlier launch is not known to be done, so the chip
@@ -1733,9 +1800,15 @@ class PagedGenerationServer(_GenerationServerBase):
                     sp.set(pools_passed=alias[0], pools_in_place=alias[1])
                 # the weight leaves this launch's program is handed
                 sp.set(weight_bytes=self._weight_bytes)
+                if self._state_keys:
+                    # what the state layers have to do for THIS launch:
+                    # slots whose state it touches, live rows, live items
+                    sp.set(state_slots=len(set(slot_idx[qls > 0].tolist())),
+                           kda_rows=int(q.sum()), kda_pieces=int(q.size),
+                           state_bytes_per_slot=self.state_bytes_per_slot)
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
-                ids_d, feed=fed)
+                ids_d, **fed)
             stats = upd.pop(LAUNCH_STATS, None)
             if stats is not None:
                 # a (layers, 4) device array: read later, in bulk and long
@@ -1754,7 +1827,7 @@ class PagedGenerationServer(_GenerationServerBase):
             # materializes it into the kv_quant_error gauge on scrape
             probs_ref, upd_ref = self._step(
                 tr, ntr, self._caches_ref, tbl, pos_d, qls_d, deps_d,
-                anc_d, ids_d, feed=fed)
+                anc_d, ids_d, **fed)
             self._caches_ref = upd_ref
             live_rows = jnp.asarray(
                 np.arange(window)[None, :] < qls[:, None])
@@ -1764,6 +1837,16 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_rows.inc(total)
         self._c_pad.inc(padded)
         return probs, padded, total
+
+    def _note_state_rows(self, slot_idx, pos, qls):
+        """The host's account of the states a launch continues (state
+        graphs only): the rows each slot's state holds after it, and the
+        launch's live items for `_check_invariants`."""
+        self._state_launched = [
+            (int(s), int(p), int(n))
+            for s, p, n in zip(slot_idx, pos, qls) if n]
+        for s, p, n in self._state_launched:
+            self._state_rows[s] = p + n
 
     def _window_counts(self, slots, p0, q) -> dict:
         """What a traced launch has to read and score in a layer of each

@@ -82,6 +82,8 @@ def _pool_buffers(caches) -> list:
 # the ops whose node owns a pool of the page cache
 PAGED_ATTENTION_OPS = (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
                        OpType.LATENT_ATTENTION)
+# the ops whose node keeps a fixed-size STATE a slot beside the pages
+STATE_OPS = (OpType.KDA_ATTENTION,)
 
 
 def _cast_weight_leaf(arr, weight_dtype: str):
@@ -532,7 +534,7 @@ class Executor:
     def run_forward(self, trainable, nontrainable, inputs: Sequence, *,
                     training: bool, rng, skip_sink_softmax: bool = False,
                     kv_caches=None, cache_position=None, cache_out=None,
-                    page_tables=None, ragged=None):
+                    page_tables=None, ragged=None, state_slots=None):
         """Topo-order lowering. Returns (sink output, state_updates, aux_loss).
         With `skip_sink_softmax` the final Softmax node passes its input
         (raw logits) through — used when the loss fuses the softmax.
@@ -608,6 +610,7 @@ class Executor:
                 ragged_q_lens=ragged_q_lens,
                 ragged_depths=ragged_depths,
                 ragged_anc=ragged_anc,
+                state_slots=state_slots,
             )
             if (
                 skip_sink_softmax
@@ -833,9 +836,15 @@ class Executor:
                     if n.op_type in PAGED_ATTENTION_OPS
                     and hasattr(n.attrs, "window")), default=0)
 
+    def state_layers(self) -> List[str]:
+        """Keys of the nodes that keep a per-slot state (`STATE_OPS`), in
+        graph order; empty for every graph before Ling-3.0-flash's."""
+        return [node_key(n) for n in self.topo if n.op_type in STATE_OPS]
+
     def paged_kv_cache_specs(self, num_pages: int, page_size: int,
                              dtype=None, num_pages_window: Optional[int]
-                             = None) -> Dict[str, Dict[str, Any]]:
+                             = None, slots: Optional[int] = None
+                             ) -> Dict[str, Dict[str, Any]]:
         """Shape/dtype specs (jax.ShapeDtypeStruct) of the paged K/V
         pools init_paged_kv_cache materializes — also the abstract
         arguments lowered_modules() feeds the paged entry points, so the
@@ -849,7 +858,12 @@ class Executor:
 
         A sliding-window node's pool has `num_pages_window` pages, its
         own class's count (`page_classes`; a server sizes it to a window
-        and a chunk a slot, not to whole sequences)."""
+        and a chunk a slot, not to whole sequences).
+
+        A STATE node (`STATE_OPS`) has a fourth kind of leaf, indexed by
+        SLOT and not by page: what its attrs' `state_specs(slots, dtype)`
+        names ("s" float32, "conv" at the activations' dtype), whatever
+        the pool's dtype. Such a graph needs `slots`."""
         from flexflow_tpu.paged.quant import is_quantized_dtype
 
         classes = self.page_classes() or {}
@@ -863,6 +877,18 @@ class Executor:
                     "graphs (their KV cache is threaded through the layer "
                     "scan); serve with paged=False"
                 )
+            if n.op_type in STATE_OPS:
+                if slots is None:
+                    raise ValueError(
+                        "a graph with state layers keeps a state a SLOT: "
+                        "paged_kv_cache_specs needs `slots`")
+                act = self.graph.input_shapes(n)[0].dtype.jnp_dtype
+                specs[node_key(n)] = {
+                    name: jax.ShapeDtypeStruct(shape, jnp.dtype(
+                        act if dt_ is None else dt_))
+                    for name, (shape, dt_) in n.attrs.state_specs(
+                        int(slots)).items()}
+                continue
             if n.op_type not in PAGED_ATTENTION_OPS:
                 continue
             ins = self.graph.input_shapes(n)
@@ -904,7 +930,7 @@ class Executor:
 
     def init_paged_kv_cache(self, num_pages: int, page_size: int,
                             dtype=None, num_pages_window: Optional[int]
-                            = None):
+                            = None, slots: Optional[int] = None):
         """Per-attention-node paged K/V POOLS for the paged decode path
         (flexflow_tpu.paged): flat-lane (num_pages, page_size, Hkv*D)
         buffers (paged/attention.py has the layout story) shared by
@@ -913,7 +939,7 @@ class Executor:
         composites keep their layer-scan threaded dense caches and are
         not paged (their cache lives inside the scan carry)."""
         specs = self.paged_kv_cache_specs(num_pages, page_size, dtype,
-                                          num_pages_window)
+                                          num_pages_window, slots)
         # a pool is born COMMITTED to its device, as every launch's output
         # pool is (the sharding a launch gives its outputs: replicated over
         # the model's mesh): a launch shape then has ONE jit signature and
@@ -961,7 +987,7 @@ class Executor:
             return self._ragged_step_fn
 
         def step(trainable, nontrainable, caches, page_tables, pos,
-                 q_lens, depths, anc, *inputs, feed=None):
+                 q_lens, depths, anc, *inputs, feed=None, state_slots=None):
             if feed is not None:
                 # LAUNCH AHEAD: the first id of an entry whose `slot` is
                 # not -1 is that slot's newest token, which the launch
@@ -977,6 +1003,7 @@ class Executor:
                 rng=jax.random.key(0), kv_caches=caches,
                 cache_position=pos, cache_out=cache_out,
                 page_tables=page_tables, ragged=(q_lens, depths, anc),
+                state_slots=state_slots,
             )
             moe = [st["moe_stats"] for _nk, st in sorted(state.items())
                    if "moe_stats" in st]
@@ -1473,14 +1500,20 @@ class Executor:
             pool_dt = resolve_kv_dtype(cfg.get("kv_dtype") or "auto")
             caches = self.init_paged_kv_cache(
                 num_pages, page_size, dtype=pool_dt,
-                num_pages_window=cfg.get("num_pages_window"))
+                num_pages_window=cfg.get("num_pages_window"), slots=slots)
             step = self.ragged_step_fn()
+            stateful = bool(self.state_layers())
 
             def feed(B):
-                if newest is None:
-                    return {}
-                return {"feed": (jnp.asarray(np.full((B,), -1, np.int32)),
-                                 newest)}
+                kw = {}
+                if stateful:
+                    # the items' slots, as the server uploads them
+                    kw["state_slots"] = jnp.asarray(
+                        np.zeros((B,), np.int32))
+                if newest is not None:
+                    kw["feed"] = (jnp.asarray(np.full((B,), -1, np.int32)),
+                                  newest)
+                return kw
 
             # a graph with window layers launches with a table a class
             two = self.page_classes() is not None
@@ -1755,7 +1788,8 @@ class Executor:
             from flexflow_tpu.paged.quant import resolve_kv_dtype
 
             caches = self.paged_kv_cache_specs(
-                pages, page_size, dtype=resolve_kv_dtype(kv_dtype))
+                pages, page_size, dtype=resolve_kv_dtype(kv_dtype),
+                slots=slots)
             from flexflow_tpu.runtime.serving_weights import serving_params
 
             tr, ntr = serving_params(self, (tr, ntr))

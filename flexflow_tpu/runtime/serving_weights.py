@@ -124,7 +124,7 @@ def _step_jaxprs(executor, params):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     if executor.can_paged_decode():
         yield executor.ragged_step_fn().trace(
-            tr, ntr, executor.paged_kv_cache_specs(2, 16),
+            tr, ntr, executor.paged_kv_cache_specs(2, 16, slots=1),
             *executor.ragged_step_avals(1, 1, 1)).jaxpr.jaxpr
     if any(n.op_type in (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
                          OpType.PIPELINE) for n in executor.topo):

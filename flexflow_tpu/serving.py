@@ -1350,57 +1350,49 @@ class GenerationServer(_GenerationServerBase):
                 led.record("decode", dt, batch=len(live))
 
 
-def _refuse_for_latent_attention(ff, *, paged, kv_dtype, speculate,
-                                 megastep_ticks, megastep_mixed,
-                                 overlap_dispatch, host_tier,
-                                 kv_quant_canary, serve_strategy,
-                                 search_budget) -> None:
-    """A graph with latent attention is served by the paged per-tick
-    server over its latent pool (prefix cache, preemption, chunked
-    prefill and packed launches included). Every option whose code reads
-    the pool as per-head K and V, or has not been run on a latent pool,
-    is refused BY NAME here rather than left to misread the row."""
-    from flexflow_tpu.ffconst import OpType
-
-    if not any(n.op_type == OpType.LATENT_ATTENTION
-               for n in ff.executor.topo):
-        return
-    refused = {
-        "paged=False": not paged,
-        "kv_dtype": kv_dtype not in ("auto", "bf16", "fp16", "fp32"),
-        "speculate": speculate is not None,
-        "megastep_ticks": megastep_ticks > 1,
-        "megastep_mixed": bool(megastep_mixed),
-        "overlap_dispatch": bool(overlap_dispatch),
-        "host_tier": host_tier is not None and host_tier != 0,
-        "kv_quant_canary": bool(kv_quant_canary),
-        "serve_strategy": serve_strategy is not None,
-        "search_budget": search_budget is not None,
-    }
-    bad = [name for name, hit in refused.items() if hit]
-    if bad:
-        raise ValueError(
-            f"serve_generation option(s) {bad} are not supported on a "
-            "graph with latent attention: its page pool holds one "
-            "[c_kv | k_r] row a token, not per-head K and V (the dense "
-            "cache, the int8 scale sidecar, tree verify's commit, the "
-            "megasteps' carry, the host tier's page payloads and the "
-            "strategy search's pricing all assume K/V pools)")
+# what a graph's way of remembering makes a serving option unable to do:
+# (is it such a graph, the options refused on it, why). ONE table of option
+# names (`_refuse_unsupported`); a graph of several kinds (state layers
+# beside a latent one) is refused by the first that objects
+_ALL_BUT_PREFIX = ("paged=False", "kv_dtype", "speculate", "megastep_ticks",
+                   "megastep_mixed", "overlap_dispatch", "host_tier",
+                   "kv_quant_canary", "serve_strategy", "search_budget")
+_GRAPH_KINDS = (
+    (lambda ex: bool(ex.state_layers()),
+     _ALL_BUT_PREFIX + ("prefix_cache",),
+     "state layers (linear attention): a layer's memory is ONE recurrent "
+     "state a slot, which no prefix-cache hit can restore "
+     "(prefix_cache=True is the default: pass False), no tree verify can "
+     "roll back, no megastep carries, no host tier or int8 pool holds, and "
+     "which the dense server and the strategy search know nothing of"),
+    (lambda ex: bool(ex.window_rows()),
+     _ALL_BUT_PREFIX + ("prefix_cache",),
+     "sliding-window attention layers: a window layer's pages behind the "
+     "window are released, so a prefix-cache hit (prefix_cache=True is the "
+     "default: pass False) would map rows that are gone; the dense cache, "
+     "the int8 scale blocks, tree verify, the megasteps' carry, the host "
+     "tier's payloads and the strategy search's pricing all assume ONE "
+     "table a request"),
+    (lambda ex: any(n.op_type.value == "latent_attention" for n in ex.topo),
+     _ALL_BUT_PREFIX,
+     "latent attention: its page pool holds one [c_kv | k_r] row a token, "
+     "not per-head K and V (the dense cache, the int8 scale sidecar, tree "
+     "verify's commit, the megasteps' carry, the host tier's page payloads "
+     "and the strategy search's pricing all assume K/V pools)"),
+)
 
 
-def _refuse_for_window_layers(ff, *, paged, prefix_cache, kv_dtype,
-                              speculate, megastep_ticks, megastep_mixed,
-                              overlap_dispatch, host_tier, kv_quant_canary,
-                              serve_strategy, search_budget) -> None:
-    """A graph with sliding-window layers is served by the paged per-tick
-    server over two classes of pages (chunked prefill, packed launches,
-    preemption and defrag included). What has not been built over a
-    table whose pages behind the window are gone is refused BY NAME
-    here, not left to read the null page (docs/paged.md "Two classes of
-    pages")."""
-    if not ff.executor.window_rows():
-        return
-    refused = {
+def _refuse_unsupported(ff, *, paged, prefix_cache, kv_dtype, speculate,
+                        megastep_ticks, megastep_mixed, overlap_dispatch,
+                        host_tier, kv_quant_canary, serve_strategy,
+                        search_budget) -> None:
+    """A graph with latent attention, sliding-window layers or state
+    layers is served by the paged per-tick server (chunked prefill,
+    packed launches, preemption and launch-ahead included). Every option
+    whose code reads what such a graph does not keep, or has not been run
+    on it, is refused BY NAME here rather than left to misread it
+    (docs/paged.md "Two classes of pages", "A state a slot")."""
+    asked = {
         "paged=False": not paged,
         "prefix_cache": bool(prefix_cache),
         "kv_dtype": kv_dtype not in ("auto", "bf16", "fp16", "fp32"),
@@ -1413,17 +1405,12 @@ def _refuse_for_window_layers(ff, *, paged, prefix_cache, kv_dtype,
         "serve_strategy": serve_strategy is not None,
         "search_budget": search_budget is not None,
     }
-    bad = [name for name, hit in refused.items() if hit]
-    if bad:
-        raise ValueError(
-            f"serve_generation option(s) {bad} are not supported on a "
-            "graph with sliding-window attention layers: a window "
-            "layer's pages behind the window are released, so a prefix-"
-            "cache hit (prefix_cache=True is the default: pass False) "
-            "would map rows that are gone; the dense cache, the int8 "
-            "scale blocks, tree verify, the megasteps' carry, the host "
-            "tier's payloads and the strategy search's pricing all "
-            "assume ONE table a request")
+    for is_kind, refused, why in _GRAPH_KINDS:
+        bad = [name for name in asked if asked[name] and name in refused]
+        if bad and is_kind(ff.executor):
+            raise ValueError(
+                f"serve_generation option(s) {bad} are not supported on a "
+                f"graph with {why}")
 
 
 def serve_generation(ff, slots: int = 4, max_len: int = 512,
@@ -1570,15 +1557,11 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     from two classes of pages (docs/paged.md "Two classes of pages"):
     `num_pages` sizes the full layers' class and `num_pages_window` the
     window layers' (default slots x (window + prefill_chunk + a page));
-    pass `prefix_cache=False`, and see `_refuse_for_window_layers` for
-    the options such a graph refuses by name."""
-    _refuse_for_latent_attention(
-        ff, paged=paged, kv_dtype=kv_dtype, speculate=speculate,
-        megastep_ticks=int(megastep_ticks), megastep_mixed=megastep_mixed,
-        overlap_dispatch=overlap_dispatch, host_tier=host_tier,
-        kv_quant_canary=kv_quant_canary, serve_strategy=serve_strategy,
-        search_budget=search_budget)
-    _refuse_for_window_layers(
+    pass `prefix_cache=False`, and see `_refuse_unsupported` for
+    the options such a graph refuses by name. A graph with STATE layers
+    (linear attention) is served the same way, its states a slot beside
+    the pages (docs/paged.md "A state a slot"), and refuses the same."""
+    _refuse_unsupported(
         ff, paged=paged, prefix_cache=prefix_cache, kv_dtype=kv_dtype,
         speculate=speculate, megastep_ticks=int(megastep_ticks),
         megastep_mixed=megastep_mixed, overlap_dispatch=overlap_dispatch,

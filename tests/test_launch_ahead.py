@@ -479,8 +479,11 @@ def test_launch_ahead_share_reads_the_counter_and_is_left_out_at_the_parent(
                              **reader) is None
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         entry = [e for e in json.load(f)["per_layer"] if e["name"] == name]
+    # the cells it was added with come first; later cells may join it
+    # (PR 44's did) by appending their names
+    assert entry and entry[0].pop("workloads")[:len(cells)] == cells
     assert entry == [{"name": name, "unit": "%", "better": "higher",
                       "source": "program_span", "layer": "serving loop",
-                      "moves": moves, "workloads": cells}]
+                      "moves": moves}]
     assert {k: metric[k] for k in ("unit", "layer", "moves", "source")} == {
         k: entry[0][k] for k in ("unit", "layer", "moves", "source")}

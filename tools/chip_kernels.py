@@ -15,7 +15,9 @@ benchmark's own serving launches (`benchmark/configs/mistral-7b-serve1.json`:
 
 The latent (MLA) kernel and the grouped expert kernels run at
 Mistral-Small-4's widths (`mla_*`, `moe_grouped_*`), the first also at the
-new cell's launches (`mla_bench_*`).
+new cell's launches (`mla_bench_*`) and at Ling-3.0-flash's 576-value row
+(`mla_wide_*`); the delta-rule scan at Ling-3.0-flash's widths and its
+cell's launches (`kda_*`).
 
 `--time` also prints each ragged, latent and grouped case's device
 microseconds a call (the kernel's own events in a profiler trace), for a
@@ -279,6 +281,93 @@ def _moe_case(tokens, seed=0):
     return fn, (x, local, *w), ref
 
 
+def _mla_wide_case(seed=0):
+    """The latent kernel at Ling-3.0-flash's row: [c_kv (512) | k_r (64)]
+    in 640 lanes, values the first 512; a 64-row chunk as eight 8-row
+    pieces of one slot over a 2k prefix, beside two decode-like pieces."""
+    from flexflow_tpu.paged.latent import (
+        latent_flash_attention,
+        latent_gather_attention,
+    )
+
+    P, MAXP, S, width, lanes, value = 64, 40, 8, 576, 640, 512
+    entries = [(0, 2048 + 8 * i, 8) for i in range(8)] + [(1, 700, 1),
+                                                          (2, 0, 0)]
+    B, N = len(entries), 3 * MAXP + 1
+    rs = np.random.RandomState(seed)
+
+    def rows(shape):
+        x = rs.randn(*shape).astype(np.float32) * width ** -0.25
+        x[..., width:] = 0
+        return jnp.asarray(x, jnp.bfloat16)
+
+    q, pool = rows((B, S, MLA_HEADS, lanes)), rows((N, P, lanes))
+    tables = 1 + np.arange(3 * MAXP, dtype=np.int32).reshape(3, MAXP)
+    pt = jnp.asarray(tables[[slot for slot, _, _ in entries]])
+    pos = jnp.asarray(np.array([p for _, p, _ in entries], np.int32))
+    q_lens = jnp.asarray(np.array([ql for _, _, ql in entries], np.int32))
+    anc = jnp.asarray(np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
+
+    def run(impl):
+        def fn(q, pool, pt, pos, q_lens, anc):
+            out = impl(q, pool, pt, pos, q_lens, anc, value_lanes=value)
+            live = jnp.arange(S)[None, :] < q_lens[:, None]
+            return jnp.where(live[..., None, None], out, 0)
+        return fn
+
+    return (run(latent_flash_attention), (q, pool, pt, pos, q_lens, anc),
+            run(latent_gather_attention))
+
+
+def _kda_case(kind, seed=0):
+    """The delta-rule scan (`kda_ragged_scan`) at Ling-3.0-flash's widths,
+    32 heads of 128 x 128 float32 state over 8 slots: "chunk256" is a
+    launch of the benchmark's cell, a 256-row chunk as 32 pieces of one
+    slot with 7 other slots' decode rows riding; "decode" is 8 one-row
+    items, two of them without rows. Against the scan over items and
+    rows (ops/kda_attention.py `scan_items`)."""
+    from flexflow_tpu.ops import kda_attention as kda
+    from flexflow_tpu.ops.pallas import kda_scan
+
+    H, d, N, W = 32, 128, 8, kda_scan.ROWS
+    if kind == "chunk256":
+        items = [(3, 4096 + 8 * i, 8) for i in range(32)] + [
+            (s, 900 + 11 * s, 1) for s in (0, 1, 2, 4, 5, 6, 7)]
+    else:
+        items = [(s, 0 if s == 5 else 50 + s, 0 if s in (2, 6) else 1)
+                 for s in range(8)]
+    B = len(items)
+    slots, pos, q_lens = (jnp.asarray(np.array(col, np.int32))
+                          for col in zip(*items))
+    ks = jax.random.split(jax.random.key(seed), 6)
+    q, k, v = (jax.random.normal(ks[i], (B, W, H, d)) for i in range(3))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    a = -5.0 * jax.nn.sigmoid(jax.random.normal(ks[3], (B, W, H, d)) - 4)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, W, H)))
+    alive = jnp.arange(W)[None, :] < q_lens[:, None]
+    a = jnp.where(alive[:, :, None, None], a, 0.0)
+    beta = jnp.where(alive[:, :, None], beta, 0.0)
+    state = jax.random.normal(ks[5], (N, H, d, d))
+
+    def fn(q, k, v, a, beta, state):
+        slot, start, fresh, _ = kda.item_chain(slots, pos, q_lens)
+        flat = lambda t: t.reshape(B, W, H * d)            # noqa: E731
+        o, s = kda_scan.kda_ragged_scan(
+            flat(q), flat(k), flat(k * beta[..., None]), flat(v), flat(a),
+            state, slot, start.astype(jnp.int32), fresh.astype(jnp.int32),
+            heads=H)
+        return jnp.where(alive[:, :, None, None],
+                         o.reshape(B, W, H, d), 0), s
+
+    def ref(q, k, v, a, beta, state):
+        o, s = kda.scan_items(q, k, v, a, beta,
+                              kda.item_chain(slots, pos, q_lens), state)
+        return jnp.where(alive[:, :, None, None], o, 0), s
+
+    return fn, (q, k, v, a, beta, state), ref
+
+
 def _loss_grads(attn, w):
     """(q, k, v) -> (loss, grads) of a fixed random projection of `attn`'s
     output: one function that runs the forward and the backward kernels."""
@@ -397,6 +486,9 @@ def kernel_cases(n_devices: int = 1):
     for tokens in (8, 256):
         cases[f"moe_grouped_t{tokens}"] = (
             lambda tokens=tokens: _moe_case(tokens))
+    cases["mla_wide_chunk64"] = _mla_wide_case
+    for kind in ("chunk256", "decode"):
+        cases[f"kda_{kind}"] = lambda kind=kind: _kda_case(kind)
     return cases
 
 
@@ -415,7 +507,8 @@ def _rel_err(got, ref):
 
 # which device operations a timed case's kernel is, by the case's prefix
 KERNEL_MARKS = {"ragged_": "ragged_paged_attention",
-                "mla_": "mla_paged_attention", "moe_": "moe_grouped"}
+                "mla_": "mla_paged_attention", "moe_": "moe_grouped",
+                "kda_": "kda_ragged_scan"}
 
 
 def _kernel_device_us(fn, fargs, mark, per_call=1, calls=20):
