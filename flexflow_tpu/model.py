@@ -292,8 +292,12 @@ class FFModel:
                      name: Optional[str] = None) -> Tensor:
         """One chip's share (`held` = (lo, hi), default all) of a dropless
         SwiGLU expert layer with its shared expert (A.ExpertShareAttrs).
-        A selection bias is drawn in [-0.1, 0.1], not zero, so that
-        selection and weighting differ."""
+        A selection bias is drawn in [-0.003, 0.003]: not zero, so that
+        selection and weighting differ (it changes about a fifth of the
+        tokens' choices at 512 sigmoid-scored experts), and small, as a
+        bias that balances the load is: the top scores of a token lie
+        0.005-0.01 apart, and a bias of 0.1 made every token choose the
+        same half of the experts (PERF.md section 6, PR 44)."""
         lo, hi = held if held is not None else (0, n_experts)
         if not 0 <= lo < hi <= n_experts:
             raise ValueError(f"held experts {lo}..{hi} of {n_experts}")
@@ -314,7 +318,7 @@ class FFModel:
         if select_bias:
             from flexflow_tpu.runtime.initializer import UniformInitializer
 
-            bias = UniformInitializer(-0.1, 0.1)
+            bias = UniformInitializer(-0.003, 0.003)
         self._record_init(node, w_gate=init, w_up=init, w_down=init,
                           bias=bias)
         return Tensor(node)

@@ -24,7 +24,8 @@ from flexflow_tpu.model import FFModel, Tensor
 class Ling3Config:
     vocab_size: int = 157184
     dim: int = 2560
-    # "kda" / "mla" a layer; the published rule is `kinds(layers)`
+    # "kda" / "mla" a layer: published layer i is latent iff
+    # (i + 1) % layer_group_size == 0 (6), linear otherwise
     layer_kinds: Tuple[str, ...] = ()
     dense_layers: int = 2           # leading layers with the dense MLP
     dense_hidden: int = 6144
@@ -47,12 +48,6 @@ class Ling3Config:
     routed_scaling_factor: float = 2.5
     experts_held: Optional[Tuple[int, int]] = None     # None: all
     norm_eps: float = 1e-6
-
-    @staticmethod
-    def kinds(layers: int, group_size: int = 6, first: int = 0):
-        """Published layer i is latent iff (i + 1) % group_size == 0."""
-        return tuple("mla" if (first + j + 1) % group_size == 0 else "kda"
-                     for j in range(layers))
 
     @staticmethod
     def tiny(vocab: int = 128) -> "Ling3Config":

@@ -136,22 +136,43 @@ def _store(state, slot, last, values):
 
 def conv_history(attrs, pre, q_lens, chain, conv_state):
     """The rows before each item, (B, taps - 1, 3c), and the conv state
-    after the launch. The chain from item to item is a scan over a few
-    rows an item: the rows a run's next item sees are the last live rows
-    of [history | item]."""
+    after the launch, without a loop over the items. A run's rows form
+    one STREAM: the slot's stored rows (zeros where the run starts a
+    request), then the live rows of its items in order. The rows before
+    an item are the stream's `keep` rows that end where the item begins;
+    what the run's last item stores are the `keep` rows that end where
+    it ends. Live rows are found in the launch's rows packed to the
+    front (`packed`), stored rows in `conv_state`."""
     slot, start, fresh, last = chain
+    B, W, C = pre.shape
     keep = attrs.conv_taps - 1
+    idx = jnp.arange(B, dtype=jnp.int32)
+    begins = jnp.cumsum(q_lens) - q_lens           # live rows before item i
+    first = lax.cummax(jnp.where(start, idx, 0))   # the run's first item
+    in_run = begins - begins[first]                # ... of its own run
+    # a request's row 0 is its run's first live row
+    zeroed = lax.cummax(jnp.where(fresh, idx, -1)) >= first
+    live = jnp.arange(W, dtype=jnp.int32)[None, :] < q_lens[:, None]
+    packed = jnp.zeros((B * W, C), pre.dtype).at[
+        jnp.where(live, begins[:, None] + jnp.arange(W), B * W)
+    ].set(pre, mode="drop")
 
-    def step(hist, xs):
-        rows, ql, st, fr, sl = xs
-        hist = jnp.where(fr, 0, jnp.where(st, conv_state[sl], hist))
-        after = lax.dynamic_slice_in_dim(
-            jnp.concatenate([hist, rows]), ql, keep)
-        return after, (hist, after)
+    def rows_ending_at(end_in_run, end):
+        """(B, keep, C): stream rows [end - keep, end) of each item's
+        run, `end` counted in live rows of the launch, `end_in_run` of
+        the run."""
+        back = jnp.arange(keep, dtype=jnp.int32)[None, :] - keep  # -keep..-1
+        stored_at = end_in_run[:, None] + back + keep   # < keep: a stored row
+        from_state = conv_state[slot[:, None],
+                                jnp.clip(stored_at, 0, keep - 1)]
+        from_launch = packed[jnp.clip(end[:, None] + back, 0, B * W - 1)]
+        stored = stored_at < keep
+        return jnp.where(
+            stored[..., None],
+            jnp.where(zeroed[:, None, None], 0, from_state), from_launch)
 
-    _, (hist, after) = lax.scan(
-        step, jnp.zeros_like(conv_state[0]),
-        (pre, q_lens, start, fresh, slot))
+    hist = rows_ending_at(in_run, begins)
+    after = rows_ending_at(in_run + q_lens, begins + q_lens)
     return hist, _store(conv_state, slot, last, after)
 
 

@@ -309,23 +309,11 @@ class PagedGenerationServer(_GenerationServerBase):
         # is told its items' slots and the layer does the rest on the
         # device (ops/kda_attention.py: a request's row 0 starts from
         # zero, so admission uploads nothing and a slot's reuse cannot
-        # see its predecessor). What no state can follow is refused here
-        # as in serve_generation. `_state_rows` / `_state_owner` are the
-        # host's account of each slot's state for the invariant catalog.
+        # see its predecessor). What no state can follow,
+        # `serve_generation` refuses. `_state_rows` / `_state_owner` are
+        # the host's account of each slot's state for the invariant
+        # catalog.
         self._state_keys = frozenset(ex.state_layers())
-        if self._state_keys:
-            bad = [name for name, hit in (
-                ("prefix_cache", self.prefix_cache),
-                ("megastep_ticks", self.megastep_ticks > 1),
-                ("megastep_mixed", self.megastep_mixed),
-                ("kv_dtype", self._quantized),
-                ("host_tier", host_tier is not None and host_tier != 0),
-                ("kv_quant_canary", bool(kv_quant_canary))) if hit]
-            if bad:
-                raise ValueError(
-                    f"option(s) {bad} are not supported on a graph with "
-                    "state layers: a recurrent state cannot be shared by "
-                    "prefix, carried by a megastep, quantized or spilled")
         self._state_rows = np.zeros((self.slots,), np.int64)
         self._state_owner: List[Optional[int]] = [None] * self.slots
         self._state_launched: List[tuple] = []
@@ -1803,7 +1791,7 @@ class PagedGenerationServer(_GenerationServerBase):
                 if self._state_keys:
                     # what the state layers have to do for THIS launch:
                     # slots whose state it touches, live rows, live items
-                    sp.set(state_slots=len(set(slot_idx[qls > 0].tolist())),
+                    sp.set(state_slots=int(np.unique(slot_idx[qls > 0]).size),
                            kda_rows=int(q.sum()), kda_pieces=int(q.size),
                            state_bytes_per_slot=self.state_bytes_per_slot)
             probs, upd = self._step(

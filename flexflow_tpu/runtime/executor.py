@@ -861,7 +861,7 @@ class Executor:
         and a chunk a slot, not to whole sequences).
 
         A STATE node (`STATE_OPS`) has a fourth kind of leaf, indexed by
-        SLOT and not by page: what its attrs' `state_specs(slots, dtype)`
+        SLOT and not by page: what its attrs' `state_specs(slots)`
         names ("s" float32, "conv" at the activations' dtype), whatever
         the pool's dtype. Such a graph needs `slots`."""
         from flexflow_tpu.paged.quant import is_quantized_dtype
@@ -884,8 +884,7 @@ class Executor:
                         "paged_kv_cache_specs needs `slots`")
                 act = self.graph.input_shapes(n)[0].dtype.jnp_dtype
                 specs[node_key(n)] = {
-                    name: jax.ShapeDtypeStruct(shape, jnp.dtype(
-                        act if dt_ is None else dt_))
+                    name: jax.ShapeDtypeStruct(shape, dt_ or act)
                     for name, (shape, dt_) in n.attrs.state_specs(
                         int(slots)).items()}
                 continue
