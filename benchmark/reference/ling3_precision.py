@@ -1,7 +1,8 @@
 """Calibrate the tolerance of `ling-3-flash-serve1`'s comparison with its
-reference (`check.tie_tol_sigma`), on the chip:
+reference (`check.tie_tol_sigma` and `check.sample`), on the chip:
 
-    python3 benchmark/reference/ling3_precision.py --seed <n> --tokens 4096
+    python3 benchmark/reference/ling3_precision.py --seed <n> \
+        --tokens 16384 --sequences 8 --control-sequences 3 --stand-in 5
 
 The server returns tokens, and `benchmark/serving.py` judges each served
 token by how far the reference's logit for it lies under the reference's
@@ -10,13 +11,23 @@ statistic for the reference ITSELF run in lower precisions (every matrix
 product's operands rounded first, `reference/ling3.py`
 `lower_precision`): bfloat16, the precision the configuration states, and
 float8_e4m3fn, the nearest below it, which has to come out as not correct.
-It also says how often a token's top-8 experts differ from the float32
-reference's in each expert layer, and how far a row's logits move. The
-delta-rule recurrence and the router's product stay float32 in every
-precision, as the program's state and router are. Weights are the
-program's own draw from the seed (`families/ling3.build_server_model`),
-token ids uniform over the vocabulary slice as the traffic draws them. One
-JSON object on the last line.
+It says how many tokens lie beyond each limit (the tails decide how many
+tokens a check has to judge before it sees float8), how often a token's
+top-8 experts differ from the float32 reference's in each expert layer,
+and how far a row's logits move. The delta-rule recurrence and the
+router's product stay float32 in every precision, as the program's state
+and router are. Weights are the program's own draw from the seed
+(`families/ling3.build_server_model`), token ids uniform over the
+vocabulary slice as the traffic draws them.
+
+`--stand-in N` then puts the control through the harness's OWN comparison
+(`benchmark/serving.py` `Served.check`, with the configuration's `check`
+block as committed): N requests whose tokens the reference generated
+greedily with float8 operands, a token at a time, stand in for what a
+program computing in float8 would have served; the line `stand_in` of the
+result says whether `check` called them correct (it must not).
+
+One JSON object on the last line.
 """
 
 from __future__ import annotations
@@ -25,25 +36,120 @@ import argparse
 import json
 import os
 import sys
+import time
+
+import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+LIMITS = ("0.5", "1.0", "1.5", "1.75", "2.0", "2.25", "2.5", "2.75", "3.0",
+          "3.25", "3.5", "4.0")
+# a check judges about 112 served tokens a sampled request
+CHECK_SIZES = (450, 900, 1350, 1800, 2700, 5300)
+
+
+def tails(gap):
+    """What a limit on the largest gap of N judged tokens would see."""
+    n = len(gap)
+    beyond = {t: int((gap > float(t)).sum()) for t in LIMITS}
+    return {
+        "tokens": n,
+        "argmax_share": float((gap == 0).mean()),
+        "gap_sigma": {q: float(np.quantile(gap, float(q)))
+                      for q in ("0.5", "0.9", "0.99", "0.999", "0.9999",
+                                "1.0")},
+        "tokens_beyond": beyond,
+        # the chance that NONE of N judged tokens lies beyond the limit
+        "chance_none_beyond": {
+            t: {str(size): float((1.0 - beyond[t] / n) ** size)
+                for size in CHECK_SIZES}
+            for t in ("2.0", "2.25", "2.5", "2.75", "3.0", "3.25", "3.5")},
+    }
+
+
+def stand_in(args, cfg, ff, weights, arch, log):
+    """Requests a float8 program would have served, through the harness's
+    own `check`."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import harness, serving, spec
+    from benchmark.reference import ling3 as ref
+
+    n_p, n_t = args.stand_in_prompt, args.stand_in_new
+    width = -(-(n_p + n_t) // 128) * 128
+    dtype = jnp.float8_e4m3fn
+
+    def generate(w, ids):
+        def one(p, ids):
+            lg = ref.logits(w, ids, arch=arch, operand_dtype=dtype)
+            return ids.at[p].set(jnp.argmax(lg[p - 1]).astype(jnp.int32))
+        # every layer is causal: what lies at p and after changes no row
+        # before p
+        return jax.lax.fori_loop(n_p, n_p + n_t, one, ids)
+
+    generate = jax.jit(generate)
+    rng = np.random.default_rng(args.seed + 1)
+    done = []
+    t0 = time.monotonic()
+    for i in range(args.stand_in):
+        ids = np.zeros((width,), np.int32)
+        ids[:n_p] = rng.integers(0, cfg["vocab_size"], n_p, dtype=np.int32)
+        out = np.asarray(generate(weights, jnp.asarray(ids)))
+        done.append(serving.Request(
+            index=i, prompt=out[:n_p].copy(), new_tokens=n_t,
+            tokens=out[n_p:n_p + n_t].copy()))
+        log(f"stand-in request {i}: {n_t} tokens after "
+            f"{time.monotonic() - t0:.0f} s")
+    cell = next(c for c in spec.load(ROOT)["cells"].values()
+                if c.config_name == args.config)
+    chk = cell.config["check"]
+    run = harness.Run(
+        cell=cell, seed=args.seed, seconds=0.0, trace=False, root=ROOT,
+        t_process_start=time.monotonic(), device={},
+        compile_clock=harness.CompileClock())
+    # what `check` holds the SERVER to besides the tokens is not the
+    # stand-in's to show: given as the configuration expects it
+    run.extras.update(kernel_variant=chk["kernel_variant"],
+                      kv_cache_dtype=chk["kv_cache_dtype"])
+    run.counters["steady_state_recompiles"] = 0
+    served = object.__new__(serving.Served)
+    served.run, served.ff = run, ff
+    served.check(done)
+    return {"operand_dtype": jnp.dtype(dtype).name, "requests": args.stand_in,
+            "prompt_tokens": n_p, "new_tokens": n_t,
+            "check": {"sample": chk["sample"],
+                      "tie_tol_sigma": chk["tie_tol_sigma"]},
+            "correct": bool(run.correct), "why_not": list(run.why_not)}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--tokens", type=int, default=4096)
+    ap.add_argument("--tokens", type=int, default=4096,
+                    help="tokens a sequence")
+    ap.add_argument("--sequences", type=int, default=1,
+                    help="sequences judged in bfloat16")
+    ap.add_argument("--control-sequences", type=int, default=None,
+                    help="of them, judged in float8 too (default all)")
+    ap.add_argument("--stand-in", type=int, default=0,
+                    help="requests of the float8 stand-in put through the "
+                         "harness's check")
+    ap.add_argument("--stand-in-prompt", type=int, default=320)
+    ap.add_argument("--stand-in-new", type=int, default=192)
     ap.add_argument("--config", default="ling-3-flash-serve1")
     args = ap.parse_args(argv)
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from benchmark.families import ling3 as fam
     from benchmark.reference import ling3 as ref
+
+    def log(msg):
+        print(f"[precision] {msg}", file=sys.stderr, flush=True)
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            args.config + ".json")) as f:
@@ -51,51 +157,89 @@ def main(argv=None) -> int:
     ff = fam.build_server_model(cfg, args.seed % (2 ** 31 - 1))
     weights = fam.reference_weights(ff._params[0], cfg)
     arch = fam.reference_arch(cfg)
-    ids = jnp.asarray(np.random.default_rng(args.seed).integers(
-        0, cfg["vocab_size"], args.tokens, dtype=np.int32))
-
-    def run(dtype):
-        def fn(w, ids):
-            routes = []
-            lg = ref.logits(w, ids, arch=arch, operand_dtype=dtype,
-                            routes=routes)
-            return lg, jnp.stack(routes)
-        return jax.jit(fn)(weights, ids)
-
-    exact, routes = run(None)
-    sigma = exact.std(-1)
-    top = exact.max(-1)
     lo, hi = cfg["experts_held"]
+
+    @jax.jit
+    def exact_fn(w, ids):
+        routes = []
+        lg = ref.logits(w, ids, arch=arch, routes=routes)
+        return lg, jnp.stack(routes)
+
+    def lowered(dtype):
+        def held(r):
+            # a differing choice matters here only if it touches a held
+            # expert
+            return jnp.sort(jnp.where((r >= lo) & (r < hi), r, -1), -1)
+
+        def fn(w, ids, exact, routes):
+            rt = []
+            lg = ref.logits(w, ids, arch=arch, operand_dtype=dtype,
+                            routes=rt)
+            rt = jnp.stack(rt)
+            sigma = exact.std(-1)
+            taken = jnp.take_along_axis(exact, lg.argmax(-1)[:, None],
+                                        -1)[:, 0]
+            same = (jnp.sort(rt, -1) == jnp.sort(routes, -1)).all(-1)
+            same_held = (held(rt) == held(routes)).all(-1)
+            return ((exact.max(-1) - taken) / sigma,
+                    jnp.abs(lg - exact).max(-1) / sigma,
+                    1.0 - same.mean(-1), 1.0 - same_held.mean(-1))
+        return jax.jit(fn)
+
+    names = (("bfloat16", jnp.bfloat16, args.sequences),
+             ("float8_e4m3fn", jnp.float8_e4m3fn,
+              args.sequences if args.control_sequences is None
+              else min(args.control_sequences, args.sequences)))
+    fns = {name: lowered(dtype) for name, dtype, _ in names}
+    got = {name: {"gap": [], "moved": [], "differs": [], "held": []}
+           for name, _, _ in names}
+    rng = np.random.default_rng(args.seed)
+    t0 = time.monotonic()
+    for s in range(args.sequences):
+        ids = jnp.asarray(rng.integers(0, cfg["vocab_size"], args.tokens,
+                                       dtype=np.int32))
+        exact, routes = exact_fn(weights, ids)
+        for name, _, count in names:
+            if s >= count:
+                continue
+            gap, moved, differs, held = fns[name](weights, ids, exact,
+                                                  routes)
+            g = got[name]
+            g["gap"].append(np.asarray(gap))
+            g["moved"].append(np.asarray(moved))
+            g["differs"].append(np.asarray(differs))
+            g["held"].append(np.asarray(held))
+        del exact, routes
+        log(f"sequence {s}: bfloat16 largest gap "
+            f"{got['bfloat16']['gap'][-1].max():.3f} sigma, "
+            f"{time.monotonic() - t0:.0f} s")
     out = {"device": jax.devices()[0].device_kind, "seed": args.seed,
            "tokens": args.tokens, "precisions": {}}
-    for name, dtype in (("bfloat16", jnp.bfloat16),
-                        ("float8_e4m3fn", jnp.float8_e4m3fn)):
-        lg, rt = run(dtype)
-        taken = jnp.take_along_axis(exact, lg.argmax(-1)[:, None], -1)[:, 0]
-        gap = np.asarray((top - taken) / sigma)
-        moved = np.asarray(jnp.abs(lg - exact).max(-1) / sigma)
-        same = (jnp.sort(rt, -1) == jnp.sort(routes, -1)).all(-1)  # (L, S)
-        held = lambda r: ((r >= lo) & (r < hi))
-        # a differing choice matters here only if it touches a held expert
-        same_held = (jnp.sort(jnp.where(held(rt), rt, -1), -1)
-                     == jnp.sort(jnp.where(held(routes), routes, -1), -1)
-                     ).all(-1)
-        out["precisions"][name] = {
-            "argmax_share": float((gap == 0).mean()),
-            "gap_sigma": {q: float(np.quantile(gap, float(q)))
-                          for q in ("0.5", "0.9", "0.99", "0.999", "1.0")},
-            "tokens_beyond": {t: int((gap > float(t)).sum())
-                              for t in ("0.15", "0.3", "0.5", "1.0", "1.5",
-                                        "2.0")},
-            "row_max_logit_move_sigma": {
-                q: float(np.quantile(moved, float(q)))
-                for q in ("0.5", "0.9", "0.99", "1.0")},
-            "top8_differs_share_by_layer": [
-                float(1.0 - s.mean()) for s in same],
-            "held_top8_differs_share_by_layer": [
-                float(1.0 - s.mean()) for s in same_held],
-        }
-    print(json.dumps(out), flush=True)
+    for name, _, count in names:
+        g = got[name]
+        gap, moved = np.concatenate(g["gap"]), np.concatenate(g["moved"])
+        pos = np.tile(np.arange(args.tokens), count)
+        row = tails(gap)
+        row["sequences"] = count
+        row["largest_by_sequence"] = [float(x.max()) for x in g["gap"]]
+        # served tokens follow prompts of 1,024 tokens and more
+        row["after_1024_tokens"] = {
+            "tokens": int((pos >= 1024).sum()),
+            "largest": float(gap[pos >= 1024].max(initial=0.0)),
+            "tokens_beyond": {t: int((gap[pos >= 1024] > float(t)).sum())
+                              for t in LIMITS}}
+        row["row_max_logit_move_sigma"] = {
+            q: float(np.quantile(moved, float(q)))
+            for q in ("0.5", "0.9", "0.99", "0.999", "1.0")}
+        row["top8_differs_share_by_layer"] = [
+            float(x) for x in np.mean(g["differs"], axis=0)]
+        row["held_top8_differs_share_by_layer"] = [
+            float(x) for x in np.mean(g["held"], axis=0)]
+        out["precisions"][name] = row
+    print(json.dumps(out), flush=True)    # kept if the stand-in fails
+    if args.stand_in:
+        out["stand_in"] = stand_in(args, cfg, ff, weights, arch, log)
+        print(json.dumps(out), flush=True)
     return 0
 
 

@@ -288,16 +288,13 @@ class FFModel:
                      shared_hidden: int = 0, norm_topk: bool = True,
                      routed_scale: float = 1.0, score: str = "softmax",
                      n_group: int = 1, topk_group: int = 1,
-                     select_bias: bool = False,
+                     select_bias: bool = False, bias_initializer=None,
                      name: Optional[str] = None) -> Tensor:
         """One chip's share (`held` = (lo, hi), default all) of a dropless
         SwiGLU expert layer with its shared expert (A.ExpertShareAttrs).
-        A selection bias is drawn in [-0.003, 0.003]: not zero, so that
-        selection and weighting differ (it changes about a fifth of the
-        tokens' choices at 512 sigmoid-scored experts), and small, as a
-        bias that balances the load is: the top scores of a token lie
-        0.005-0.01 apart, and a bias of 0.1 made every token choose the
-        same half of the experts (PERF.md section 6, PR 44)."""
+        The selection bias, where there is one, is drawn by
+        `bias_initializer` (default zeros: the model's builder says what a
+        seeded draw of it should look like)."""
         lo, hi = held if held is not None else (0, n_experts)
         if not 0 <= lo < hi <= n_experts:
             raise ValueError(f"held experts {lo}..{hi} of {n_experts}")
@@ -314,13 +311,8 @@ class FFModel:
                                bool(select_bias)),
             [input], name or "expert_share")
         init = _glorot(input.shape[-1], hidden_dim)
-        bias = None
-        if select_bias:
-            from flexflow_tpu.runtime.initializer import UniformInitializer
-
-            bias = UniformInitializer(-0.003, 0.003)
         self._record_init(node, w_gate=init, w_up=init, w_down=init,
-                          bias=bias)
+                          bias=bias_initializer if select_bias else None)
         return Tensor(node)
 
     def ring_attention(self, query: Tensor, key: Tensor, value: Tensor,

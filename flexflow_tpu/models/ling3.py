@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 
 from flexflow_tpu.ffconst import DataType
 from flexflow_tpu.model import FFModel, Tensor
+from flexflow_tpu.runtime.initializer import UniformInitializer
 
 
 @dataclasses.dataclass
@@ -47,6 +48,13 @@ class Ling3Config:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.5
     experts_held: Optional[Tuple[int, int]] = None     # None: all
+    # a seeded draw of the router's selection bias is uniform within this
+    # of zero: not zero, so that selection and weighting differ (it changes
+    # about a fifth of the tokens' choices at 512 sigmoid-scored experts),
+    # and small, as a bias that balances the load is: a token's top scores
+    # lie 0.005-0.01 apart, and a bias of 0.1 made every token choose the
+    # same half of the experts (PERF.md section 6, PR 44)
+    select_bias_range: float = 0.003
     norm_eps: float = 1e-6
 
     @staticmethod
@@ -70,6 +78,8 @@ def build_ling3(ff: FFModel, cfg: Ling3Config, batch_size: int = None,
     if unknown or not cfg.layer_kinds:
         raise ValueError(f"layer_kinds {cfg.layer_kinds}: 'kda' or 'mla' "
                          "a layer")
+    bias_init = UniformInitializer(-cfg.select_bias_range,
+                                   cfg.select_bias_range)
     b = batch_size or ff.config.batch_size
     ids = ff.create_tensor((b, seq_len), DataType.INT32, name="input_ids")
     h = ff.embedding(ids, cfg.vocab_size, cfg.dim, dtype=dtype,
@@ -105,7 +115,8 @@ def build_ling3(ff: FFModel, cfg: Ling3Config, batch_size: int = None,
                 norm_topk=cfg.norm_topk_prob,
                 routed_scale=cfg.routed_scaling_factor, score="sigmoid",
                 n_group=cfg.n_group, topk_group=cfg.topk_group,
-                select_bias=True, name=f"l{i}_moe")
+                select_bias=True, bias_initializer=bias_init,
+                name=f"l{i}_moe")
         h = ff.add(h, m, name=f"l{i}_res2")
     h = ff.rms_norm(h, eps=cfg.norm_eps, name="final_norm")
     logits = ff.dense(h, cfg.vocab_size, use_bias=False, name="lm_head")

@@ -81,7 +81,11 @@ def test_the_ling3_files_load_and_keep_the_published_widths():
     for key in ("published", "assumed", "deployment", "bytes",
                 "server_notes"):
         assert cfg[key]
-    assert cfg["check"]["why"] and cfg["check"]["sample"] == 4
+    # the limit has to fail the float8 control at the check's OWN sample
+    # size: 0.43 % of float8's tokens lie beyond 2.75 sigma (check.why),
+    # so about 112 served tokens a request x 12 requests see 5 or 6
+    chk = cfg["check"]
+    assert chk["why"] and chk["sample"] >= 12 and chk["tie_tol_sigma"] <= 2.75
     assert cfg["vocab_size"] * 8 == cfg["published"]["vocab_size"]
     srv = cfg["server"]
     assert srv["max_len"] >= 32768 + 192 and srv["prefix_cache"] is False
@@ -177,11 +181,9 @@ def test_shape_functions_on_hand_counted_launches():
     assert mla_hybrid_launch.per_launch({"kv_pages": 3}, cfg, 2) is None
 
 
-def test_the_ling3_cell_runs_end_to_end_tiny_and_traced(tmp_path):
-    # the gather fallback, the dense expert loop and the scan over items:
-    # what is rehearsed here is the harness, the family, pages and states
-    # under a real closed loop and the readers (tests/test_ling3.py runs
-    # the kernels, interpreted, against the reference)
+def _tiny_root(tmp_path):
+    """A copy of the benchmark with TINY as the configuration `tiny-l3`
+    and the cell `tiny-l3.tiny-closed` beside the real ones."""
     root = h.make_root(tmp_path)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         doc = json.load(f)
@@ -199,6 +201,15 @@ def test_the_ling3_cell_runs_end_to_end_tiny_and_traced(tmp_path):
                 m["workloads"].append("tiny-l3.tiny-closed")
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
+    return root
+
+
+def test_the_ling3_cell_runs_end_to_end_tiny_and_traced(tmp_path):
+    # the gather fallback, the dense expert loop and the scan over items:
+    # what is rehearsed here is the harness, the family, pages and states
+    # under a real closed loop and the readers (tests/test_ling3.py runs
+    # the kernels, interpreted, against the reference)
+    root = _tiny_root(tmp_path)
     cell = spec.load(root)["cells"]["tiny-l3.tiny-closed"]
     res = harness.run_cell(cell, seed=2 ** 31 + 7, seconds=3.0, trace=True,
                            root=root, t_process_start=time.monotonic(),
@@ -215,3 +226,39 @@ def test_the_ling3_cell_runs_end_to_end_tiny_and_traced(tmp_path):
     # no TPU plane on the CPU: the device metrics are left out, not made up
     assert not {"kda_share", "kda_roofline", "mla_hybrid_roofline",
                 "mla_share", "moe_share", "moe_roofline"} & set(m)
+
+
+def test_the_precision_script_puts_its_control_through_the_harness_check(
+        tmp_path, monkeypatch, capsys):
+    """`ling3_precision.py` rehearsed at the tiny size: the tails of both
+    precisions, and the stand-in's requests judged by `Served.check` under
+    the configuration's own `check` block. At a limit of 0 sigma every
+    token that is not the reference's argmax counts, so the float8
+    stand-in must come out as not correct; at a limit no token can pass,
+    as correct (what is rehearsed is the plumbing: the limits of the real
+    configuration come from the chip)."""
+    from benchmark.reference import ling3_precision as prec
+
+    root = _tiny_root(tmp_path)
+    monkeypatch.setattr(prec, "ROOT", root)
+    path = os.path.join(root, "benchmark/configs/tiny-l3.json")
+    results = {}
+    for tol in (0.0, 1e9):
+        cfg = dict(TINY, check=dict(TINY["check"], tie_tol_sigma=tol))
+        with open(path, "w") as f:
+            json.dump(cfg, f)
+        assert prec.main(["--seed", str(2 ** 31 + 11), "--tokens", "48",
+                          "--sequences", "2", "--control-sequences", "1",
+                          "--stand-in", "2", "--stand-in-prompt", "12",
+                          "--stand-in-new", "20", "--config", "tiny-l3"]) == 0
+        results[tol] = json.loads(capsys.readouterr().out.splitlines()[-1])
+    out = results[0.0]
+    b, f8 = (out["precisions"][k] for k in ("bfloat16", "float8_e4m3fn"))
+    assert (b["tokens"], f8["tokens"]) == (96, 48)
+    assert b["tokens_beyond"]["0.5"] <= f8["tokens_beyond"]["0.5"]
+    assert f8["argmax_share"] < 1.0
+    assert set(b["chance_none_beyond"]["3.0"]) == {
+        str(n) for n in prec.CHECK_SIZES}
+    assert out["stand_in"]["check"] == {"sample": 2, "tie_tol_sigma": 0.0}
+    assert not out["stand_in"]["correct"] and out["stand_in"]["why_not"]
+    assert results[1e9]["stand_in"]["correct"]
