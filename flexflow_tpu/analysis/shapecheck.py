@@ -391,7 +391,8 @@ def _dense_prefill_lens(max_len: int) -> List[int]:
 
 
 def _packed_prefill_shapes(slots: int, chunk: int,
-                           cap: int = PREFILL_WINDOW_ROWS) -> Set[Tuple[int, int]]:
+                           cap: int = PREFILL_WINDOW_ROWS,
+                           item_bucket: int = 1) -> Set[Tuple[int, int]]:
     """Closed (n_items, window) family of the scheduler's ragged-packed
     prefill tick: W = min(cap, largest take this tick); each planned
     slot's take splits into ceil(take/W) pieces, all packed into ONE
@@ -403,7 +404,17 @@ def _packed_prefill_shapes(slots: int, chunk: int,
     W == cap takes may exceed the window and split, so n_items is
     bounded by the worst split: one take of the whole budget and a
     decode row on every other slot (a single-row take in a decode row's
-    place costs the big take a row and never adds a piece)."""
+    place costs the big take a row and never adds a piece).
+
+    With `item_bucket` > 1 (a graph with state layers, whose programs are
+    the dearest to compile) the scheduler always launches the full window
+    and fills the launch with items without rows up to a multiple of
+    `item_bucket`: one shape a multiple up to the worst split."""
+    if item_bucket > 1:
+        W = min(cap, chunk)
+        bmax = slots - 1 + -(-chunk // W)
+        return {(B, W) for B in range(item_bucket, bmax + item_bucket,
+                                      item_bucket)}
     shapes: Set[Tuple[int, int]] = set()
     for W in range(1, min(cap, chunk) + 1):
         bmax = slots if W < cap else slots - 1 + -(-chunk // W)
@@ -422,7 +433,8 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
                       num_pages: Optional[int] = None,
                       kv_dtype: str = "auto",
                       window_rows: int = PREFILL_WINDOW_ROWS,
-                      num_pages_window: Optional[int] = None) -> Dict:
+                      num_pages_window: Optional[int] = None,
+                      item_bucket: int = 1) -> Dict:
     """The closed set of reachable launch shapes per jit entry point for
     ONE served config, plus the config echo `Executor.warm_launch_shapes`
     needs to rebuild the launch arguments (table width, pool size,
@@ -442,7 +454,7 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
     if paged:
         ragged: Set[Tuple[int, int]] = {(slots, 1)}  # decode tick
         ragged |= _packed_prefill_shapes(slots, int(prefill_chunk),
-                                         int(window_rows))
+                                         int(window_rows), int(item_bucket))
         if spec_max_nodes:
             T = int(spec_max_nodes)
             # verify packs only drafting + sampled-root slots —
@@ -495,6 +507,9 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
             # the window class's pages, where the graph has window layers
             **({"num_pages_window": int(num_pages_window)}
                if num_pages_window else {}),
+            # a state graph's launches, filled to a multiple of this
+            **({"item_bucket": int(item_bucket)}
+               if item_bucket > 1 else {}),
         },
         "entries": entries,
         "total_compilations": sum(e["count"] for e in entries.values()),

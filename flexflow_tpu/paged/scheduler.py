@@ -314,6 +314,14 @@ class PagedGenerationServer(_GenerationServerBase):
         # the host's account of each slot's state for the invariant
         # catalog.
         self._state_keys = frozenset(ex.state_layers())
+        # ... and its launches come in FEW shapes: a step program with
+        # state layers is the dearest to compile (each scan unrolled over
+        # heads and rows), and one a (items, window) pair is 127 of them
+        # at 8 slots and a 512-token chunk, 21 minutes of set-up on the
+        # chip (PERF.md section 6, PR 44). A chunk's
+        # launch therefore always has the full window, and is filled
+        # with items without rows up to a multiple of `slots` items
+        self._item_bucket = self.slots if self._state_keys else 1
         self._state_rows = np.zeros((self.slots,), np.int64)
         self._state_owner: List[Optional[int]] = [None] * self.slots
         self._state_launched: List[tuple] = []
@@ -638,6 +646,8 @@ class PagedGenerationServer(_GenerationServerBase):
             "window_rows": self._chunk_rows,
             **({"num_pages_window": self.pool_w.num_pages}
                if self._window else {}),
+            **({"item_bucket": self._item_bucket}
+               if self._item_bucket > 1 else {}),
         }
 
     # -- capacity ---------------------------------------------------------
@@ -1999,6 +2009,8 @@ class PagedGenerationServer(_GenerationServerBase):
         # chunks never pad past their own length and big chunks split
         # into pieces instead of rounding up to a power-of-two bucket
         W = min(self._chunk_rows, max(take for _, _, _, take in plan))
+        if self._item_bucket > 1:
+            W = min(self._chunk_rows, self.prefill_chunk)
         for s, req, start, take in plan:
             for off in range(0, take, W):
                 piece = min(W, take - off)
@@ -2007,6 +2019,10 @@ class PagedGenerationServer(_GenerationServerBase):
                                               start + off + piece],
                               None, None))
             ends.append((len(items) - 1, (take - 1) % W))
+        if self._item_bucket > 1:
+            # a state graph's filler: no rows, the slot of the piece before
+            short = -(len(items) + len(dec)) % self._item_bucket
+            items += [(items[-1][0], 0, [], None, None)] * short
         # the decoding slots' items come LAST, so their rows are the end
         # of the launch's last `slots` entries: where each lies there, by
         # SLOT (the filler names the last one: real probabilities, unread)

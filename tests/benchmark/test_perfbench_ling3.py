@@ -222,7 +222,8 @@ def test_the_ling3_cell_runs_end_to_end_tiny_and_traced(tmp_path):
     assert 0 < m["experts_hit_share"] <= 100
     # the pool and the four state leaves are written where they lie
     assert m["preemptions"] == 0 and m["pool_in_place_share"] == 100
-    assert m["launch_shapes"] == 18 and m["step_ms.prefill"] > 0
+    # a state graph's launches: (2, 1) and (2, 8), and two sampling programs
+    assert m["launch_shapes"] == 4 and m["step_ms.prefill"] > 0
     # no TPU plane on the CPU: the device metrics are left out, not made up
     assert not {"kda_share", "kda_roofline", "mla_hybrid_roofline",
                 "mla_share", "moe_share", "moe_roofline"} & set(m)

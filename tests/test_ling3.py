@@ -265,6 +265,8 @@ def test_state_leaves_are_written_in_place_and_nothing_compiles(tiny):
                                  page_size=8, prefill_chunk=8,
                                  prefix_cache=False)
     server.warm_launch_shapes()
+    # a chunk's launch has the full window and a multiple of `slots` items
+    assert set(server._pool_alias) == {(2, 1), (2, 8)}
     passed, in_place = zip(*server._pool_alias.values())
     assert set(passed) == {5} and passed == in_place   # 1 pool + 2 x (s, conv)
     seen = []
@@ -286,6 +288,25 @@ def test_state_leaves_are_written_in_place_and_nothing_compiles(tiny):
         server.stop()
     assert not seen
     assert server.metrics()["compile"]["steady_state_recompiles"] == 0
+
+
+def test_a_state_graphs_launch_shapes_are_multiples_of_its_slots():
+    """The catalog of the benchmark's cell (8 slots, chunk 512): the
+    decode launch and one shape a multiple of 8 items up to the worst
+    split, 64 pieces and 7 riders, where a graph without state layers has
+    one a (items, window) pair."""
+    from flexflow_tpu.analysis.shapecheck import enumerate_catalog
+
+    kw = dict(slots=8, max_len=33280, page_size=64, prefill_chunk=512)
+    ragged = enumerate_catalog(**kw, item_bucket=8)["entries"]["ragged_step"]
+    assert ragged["shapes"] == [[8, 1]] + [[b, 8] for b in range(8, 73, 8)]
+    assert enumerate_catalog(**kw)["entries"]["ragged_step"]["count"] == 127
+    # a chunk under the window: the window is the chunk
+    small = enumerate_catalog(slots=2, max_len=64, page_size=8,
+                              prefill_chunk=4, item_bucket=2)
+    assert small["entries"]["ragged_step"]["shapes"] == [[2, 1], [2, 4]]
+    assert small["config"]["item_bucket"] == 2
+    assert "item_bucket" not in enumerate_catalog(**kw)["config"]
 
 
 def test_launch_spans_count_the_states(tiny):
