@@ -223,7 +223,8 @@ def paged_attention(attrs, x, params, ctx):
         o, state = kda_scan.kda_ragged_scan(
             flat(q), flat(k), flat(k * beta[..., None]), flat(v), flat(a),
             ctx.kv_cache["s"], slot, start.astype(jnp.int32),
-            fresh.astype(jnp.int32), heads=H, interpret=interp)
+            fresh.astype(jnp.int32), q_lens.astype(jnp.int32), heads=H,
+            interpret=interp)
         o = o[:, :W].reshape(B, W, H, d)
     else:
         o, state = scan_items(q, k, v, a, beta, chain, ctx.kv_cache["s"])

@@ -333,29 +333,64 @@ def test_launch_spans_count_the_states(tiny):
     assert first["kv_bytes_per_token"] == 128 * 4      # one latent layer
 
 
-def test_kernel_interpreted_equals_its_oracle():
+# (slot, first row, live rows) an item. "mixed": three slots' runs (one
+# fresh, one continued, one of a single decode row), items without rows in
+# front, between and behind. "long": an EMPTY item in front of slot 3's run
+# (it carries `start`: the state is still copied in), 17 full items, the
+# run's partial last piece, a rider of slot 1, a filler.
+LAYOUTS = {
+    "mixed": (5, [(4, 0, 0), (4, 0, 8), (4, 8, 3), (2, 7, 1), (2, 0, 0),
+                  (0, 40, 8), (1, 0, 0), (1, 0, 0)]),
+    "long": (5, [(3, 0, 0)] + [(3, 64 + 8 * i, 8) for i in range(17)]
+             + [(3, 200, 5), (1, 77, 1), (1, 0, 0)]),
+}
+
+
+def _decay(regime, key, shape):
+    """Log-decays of a regime: "weak" is what the layer's gate gives on
+    random weights, "strong" the strongest it admits (-5 on every live row
+    and channel: G = -40 across an item), "mixed" a draw over both ends
+    with whole channels and whole rows at either."""
+    if regime == "strong":
+        return jnp.full(shape, -5.0)
+    if regime == "weak":
+        return -5.0 * jax.nn.sigmoid(jax.random.normal(key, shape) - 2)
+    k1, k2, k3 = jax.random.split(key, 3)
+    a = -5.0 * jax.nn.sigmoid(4 * jax.random.normal(k1, shape))
+    rows = jax.random.bernoulli(k2, 0.25, shape[:2] + (1, 1))
+    chans = jax.random.bernoulli(k3, 0.25, (1, 1) + shape[2:])
+    return jnp.where(rows, -5.0, jnp.where(chans, -1e-3, a))
+
+
+@pytest.mark.parametrize("regime,layout", [
+    ("weak", "mixed"), ("strong", "mixed"), ("mixed", "mixed"),
+    ("weak", "long"), ("strong", "long"), ("mixed", "long")])
+def test_kernel_interpreted_equals_its_oracle(regime, layout):
     """`kda_ragged_scan`, interpreted, against the scan over items and
-    rows: three slots' runs (one fresh, one continued, one of a single
-    decode row), items without rows in front, between and behind."""
+    rows, over decay regimes and launch layouts."""
     from flexflow_tpu.ops import kda_attention as kda
     from flexflow_tpu.ops.pallas import kda_scan
 
-    H, d, N = 3, 16, 5
-    slots = jnp.asarray([4, 4, 4, 2, 2, 0, 1, 1], jnp.int32)
-    pos = jnp.asarray([0, 0, 8, 7, 0, 40, 0, 0], jnp.int32)
-    q_lens = jnp.asarray([0, 8, 3, 1, 0, 8, 0, 0], jnp.int32)
+    # 3 heads go one a grid step, 8 all in one (their products merged)
+    H, d = (3 if layout == "mixed" else 8), 16
+    N, items = LAYOUTS[layout]
+    slots, pos, q_lens = (jnp.asarray(col, jnp.int32) for col in zip(*items))
     B = slots.shape[0]
     ks = jax.random.split(jax.random.key(0), 6)
     q, k, v = (jax.random.normal(ks[i], (B, ROWS, H, d)) for i in range(3))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    a = -5.0 * jax.nn.sigmoid(jax.random.normal(ks[3], (B, ROWS, H, d)) - 2)
+    a = _decay(regime, ks[3], (B, ROWS, H, d))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, ROWS, H)))
     state = jax.random.normal(ks[5], (N, H, d, d))
     alive = jnp.arange(ROWS)[None, :] < q_lens[:, None]
     a = jnp.where(alive[:, :, None, None], a, 0.0)
     beta = jnp.where(alive[:, :, None], beta, 0.0)
     chain = kda.item_chain(slots, pos, q_lens)
-    assert np.asarray(chain[0]).tolist() == [4, 4, 4, 2, 2, 0, 0, 0]
+    if layout == "mixed":
+        assert np.asarray(chain[0]).tolist() == [4, 4, 4, 2, 2, 0, 0, 0]
+    else:       # the empty item in front starts slot 3's run
+        assert np.asarray(chain[1]).tolist()[:2] == [True, False]
+        assert int(q_lens[0]) == 0
     want_o, want_s = kda.scan_items(q, k, v, a, beta, chain, state)
 
     def flat(t):
@@ -364,18 +399,24 @@ def test_kernel_interpreted_equals_its_oracle():
     got_o, got_s = kda_scan.kda_ragged_scan(
         flat(q), flat(k), flat(k * beta[..., None]), flat(v), flat(a),
         state, chain[0], chain[1].astype(jnp.int32),
-        chain[2].astype(jnp.int32), heads=H, interpret=True)
-    live = np.asarray(alive)
-    np.testing.assert_allclose(
-        np.asarray(got_o).reshape(B, ROWS, H, d)[live],
-        np.asarray(want_o)[live], atol=2e-5, rtol=0)
-    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
+        chain[2].astype(jnp.int32), q_lens, heads=H, interpret=True)
+    live, rows = np.asarray(alive), np.asarray(q_lens)
+    got_o = np.asarray(got_o).reshape(B, ROWS, H, d)
+    got_s, state = np.asarray(got_s), np.asarray(state)
+    assert np.isfinite(got_o).all() and np.isfinite(got_s).all()
+    np.testing.assert_allclose(got_o[live], np.asarray(want_o)[live],
                                atol=2e-5, rtol=0)
-    # slots 1 and 3 were named by no live item: untouched to the bit;
-    # slot 4 started a request: its old state is gone
-    np.testing.assert_array_equal(np.asarray(got_s)[[1, 3]],
-                                  np.asarray(state)[[1, 3]])
-    assert np.abs(np.asarray(got_s)[4] - np.asarray(state)[4]).max() > 0.1
+    np.testing.assert_allclose(got_s, np.asarray(want_s), atol=2e-5, rtol=0)
+    # an item without rows reads out zeros, whatever its rows held
+    assert not got_o[rows == 0].any()
+    # slots named by no live item: untouched to the bit
+    named = set(np.asarray(chain[0])[rows > 0].tolist())
+    idle = sorted(set(range(N)) - named)
+    assert idle
+    np.testing.assert_array_equal(got_s[idle], state[idle])
+    # a slot whose run ran live rows has another state than before
+    for slot in named:
+        assert np.abs(got_s[slot] - state[slot]).max() > 0.1
 
 
 def test_router_groups_bias_and_scaling_on_a_hand_built_case():
