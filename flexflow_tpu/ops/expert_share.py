@@ -37,31 +37,82 @@ def route(attrs, x, router, bias=None):
                      precision=lax.Precision.HIGHEST)
     probs = (jax.nn.sigmoid(logits) if attrs.score == "sigmoid"
              else jax.nn.softmax(logits, axis=-1))
-    if bias is not None or attrs.n_group > 1:
-        w, ids = _select(attrs, probs, bias)
-    else:
-        w, ids = lax.top_k(probs, attrs.k)
+    w, ids = _select(attrs, probs, bias)
     if attrs.norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return ids.astype(jnp.int32), w * attrs.routed_scale
+    return ids, w * attrs.routed_scale
+
+
+def _top_k(x, k):
+    """The k largest along the last axis and where they lie, (..., k)
+    each, in descending order, a tie to the lower index: `lax.top_k`'s
+    contract, by k rounds of (maximum, first index that holds it, strike
+    it out). The TPU lowers `lax.top_k` to a sort of the whole axis with
+    the indices riding along; k is a static and at most 8 here. The axis
+    must hold k values above -inf: a struck position reads -inf."""
+    n = x.shape[-1]
+    if k > n:
+        raise ValueError(f"the {k} largest of {n} values")
+    at = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    place = lax.broadcasted_iota(jnp.int32, x.shape[:-1] + (k,),
+                                 x.ndim - 1)
+
+    def round_(r, state):
+        taken, vals, ids = state
+        left = jnp.where(taken, -jnp.inf, x)
+        top = jnp.max(left, axis=-1, keepdims=True)
+        first = jnp.min(jnp.where(left == top, at, n), axis=-1,
+                        keepdims=True)
+        return (taken | (at == first), jnp.where(place == r, top, vals),
+                jnp.where(place == r, first, ids))
+
+    # up to four rounds are written out; more run as a loop over ONE
+    # round's code: 2.7 us slower a call at 576 x 512 on a v5e, and the
+    # programs that hold six such layers compile and load a tenth
+    # sooner (PERF.md section 6, PR 46). A loop around two or four
+    # rounds of a short axis costs more than the rounds.
+    _, vals, ids = lax.fori_loop(
+        0, k, round_, (jnp.zeros(x.shape, bool),
+                       jnp.zeros(place.shape, x.dtype),
+                       jnp.zeros(place.shape, jnp.int32)),
+        unroll=k <= 4)
+    return vals, ids
 
 
 def _select(attrs, scores, bias):
     """Top k of (T, E) scores by score + bias within the open groups;
     returns the UNBIASED scores of the chosen and their ids."""
     T, E = scores.shape
+    open_outputs = attrs.topk_group * (E // attrs.n_group)
+    if attrs.k > open_outputs:
+        raise ValueError(
+            f"{attrs.k} experts a token of the {open_outputs} outputs that "
+            f"{attrs.topk_group} of {attrs.n_group} groups over {E} hold")
+    if bias is None and attrs.n_group == 1:
+        # one short sort stays: the rounds save nothing at 128 or 64
+        # outputs (2.1 -> 6.0 us at 8 rows of 64 on a v5e) and a dozen
+        # layers of them compile and load longer (PERF.md section 6)
+        return lax.top_k(scores, attrs.k)
     chosen_by = scores if bias is None else scores + bias.astype(
         jnp.float32)
     if attrs.n_group > 1:
         groups = chosen_by.reshape(T, attrs.n_group, E // attrs.n_group)
-        group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
-        _, best = lax.top_k(group_score, attrs.topk_group)
+        group_score = jnp.sum(_top_k(groups, 2)[0], axis=-1)
+        _, best = _top_k(group_score, attrs.topk_group)
         is_open = jnp.any(best[:, :, None] == jnp.arange(attrs.n_group),
                           axis=1)
         chosen_by = jnp.where(is_open[:, :, None], groups,
                               -jnp.inf).reshape(T, E)
-    _, ids = lax.top_k(chosen_by, attrs.k)
-    return jnp.take_along_axis(scores, ids, axis=-1), ids
+    _, ids = _top_k(chosen_by, attrs.k)
+    # the chosen's scores by a masked maximum, so exact, and ONE (T, k)
+    # array: `route` sums it over k, and a sum whose terms come out of k
+    # separate rounds (or out of a masked sum, which a compiler merges
+    # with it) is added in another order on the TPU and differs in the
+    # last bit. A gather of (T, k) out of (T, E) is 45 us at 576 x 512
+    # on a v5e; this is 3
+    at = lax.broadcasted_iota(jnp.int32, (T, attrs.k, E), 2)
+    return jnp.max(jnp.where(at == ids[:, :, None], scores[:, None, :],
+                             -jnp.inf), axis=-1), ids
 
 
 def _swiglu(x, gate, up, down):

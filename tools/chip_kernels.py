@@ -19,9 +19,16 @@ new cell's launches (`mla_bench_*`) and at Ling-3.0-flash's 576-value row
 (`mla_wide_*`); the delta-rule scan at Ling-3.0-flash's widths and its
 cell's launches (`kda_*`).
 
+The expert router's choice (`route_*`; ops/expert_share.py `_select` and
+`_top_k`) is plain XLA, no Pallas kernel: its cases are `route_cases()`,
+outside the lowering test's catalog, at the three expert cells' launch
+rows, against the form that sorts (`select_by_sort`, `lax.top_k`), to the
+bit.
+
 `--time` also prints each ragged, latent and grouped case's device
 microseconds a call (the kernel's own events in a profiler trace), for a
-before / after.
+before / after; for a `route_*` case the whole program's, beside the
+sorting form's.
 """
 
 from __future__ import annotations
@@ -35,6 +42,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 H, HKV, D = 32, 8, 128
 # page sizes the availability gate admits per pool dtype (one sublane tile
@@ -377,6 +385,80 @@ def _kda_case(kind, seed=0):
     return fn, (q, k, v, a, beta, state), ref
 
 
+# the three routers at a full launch's rows (a chunk's pieces, a filler
+# where the server fills, the other slots' riders, 8 rows an item):
+# name -> (rows, ExpertShareAttrs fields)
+ROUTERS = {
+    "ling512": (576, dict(n_experts=512, k=8, routed_scale=2.5,
+                          score="sigmoid", n_group=8, topk_group=4,
+                          select_bias=True)),
+    "small4_128": (312, dict(n_experts=128, k=4)),
+    "mellum2_64": (152, dict(n_experts=64, k=8)),
+}
+
+
+def select_by_sort(attrs, scores, bias):
+    """ops/expert_share.py `_select` as it was through PR 45: every choice
+    a `lax.top_k`, which the TPU lowers to a sort of the whole axis. The
+    oracle of the rounds that replaced it (tests/
+    test_expert_share_select.py) and what `--time` sets them against."""
+    if bias is None and attrs.n_group == 1:
+        return lax.top_k(scores, attrs.k)
+    T, E = scores.shape
+    chosen_by = scores if bias is None else scores + bias.astype(
+        jnp.float32)
+    if attrs.n_group > 1:
+        groups = chosen_by.reshape(T, attrs.n_group, E // attrs.n_group)
+        group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+        _, best = lax.top_k(group_score, attrs.topk_group)
+        is_open = jnp.any(best[:, :, None] == jnp.arange(attrs.n_group),
+                          axis=1)
+        chosen_by = jnp.where(is_open[:, :, None], groups,
+                              -jnp.inf).reshape(T, E)
+    _, ids = lax.top_k(chosen_by, attrs.k)
+    return jnp.take_along_axis(scores, ids, axis=-1), ids
+
+
+def _route_case(name, seed=0):
+    """The router's choice alone for one launch of the cell, scores (rows,
+    outputs) float32. Ling-3.0-flash's grouped router (sigmoid scores, a
+    selection bias as its builder draws it): the whole selection ->
+    (ids, weights) as `route` goes on to hand them out, normalised over
+    the chosen and scaled, a sum that a fused selection could reorder.
+    The two softmax routers KEEP their one `lax.top_k` (PERF.md section 6,
+    PR 46): their case is the rounds alone against it, which is why."""
+    from flexflow_tpu.ops import expert_share
+    from flexflow_tpu.ops.attrs import ExpertShareAttrs
+
+    rows, fields = ROUTERS[name]
+    attrs = ExpertShareAttrs(hidden_dim=128, **fields)
+    rs = np.random.RandomState(seed)
+    logits = jnp.asarray(rs.randn(rows, attrs.n_experts), jnp.float32)
+    if not attrs.select_bias:
+        return (lambda s: expert_share._top_k(s, attrs.k),
+                (jax.nn.softmax(logits, axis=-1),),
+                lambda s: lax.top_k(s, attrs.k))
+    bias = jnp.asarray(rs.uniform(-0.003, 0.003, attrs.n_experts),
+                       jnp.float32)
+
+    def run(select):
+        def fn(scores, bias):
+            w, ids = select(attrs, scores, bias)
+            return ids, w / jnp.sum(w, axis=-1, keepdims=True) * (
+                attrs.routed_scale)
+        return fn
+
+    return (run(expert_share._select), (jax.nn.sigmoid(logits), bias),
+            run(select_by_sort))
+
+
+def route_cases():
+    """name -> zero-argument builder of (fn, args, ref_fn), as
+    `kernel_cases`; no case holds a Pallas kernel."""
+    return {f"route_{name}": lambda name=name: _route_case(name)
+            for name in ROUTERS}
+
+
 def _loss_grads(attn, w):
     """(q, k, v) -> (loss, grads) of a fixed random projection of `attn`'s
     output: one function that runs the forward and the backward kernels."""
@@ -526,7 +608,8 @@ KERNEL_MARKS = {"ragged_": "ragged_paged_attention",
 def _kernel_device_us(fn, fargs, mark, per_call=1, calls=20):
     """Device microseconds a call of the case's kernel(s) alone: the
     events named `mark` on the device's `XLA Ops` line in a profiler
-    trace of `calls` calls (`per_call` kernels a call)."""
+    trace of `calls` calls (`per_call` kernels a call). Without a `mark`,
+    of the whole program: its events on the `XLA Modules` line."""
     import glob
     import tempfile
 
@@ -540,9 +623,10 @@ def _kernel_device_us(fn, fargs, mark, per_call=1, calls=20):
         data = jax.profiler.ProfileData.from_file(path)
     ns = [ev.duration_ns
           for plane in data.planes if plane.name.startswith("/device:TPU:0")
-          for line in plane.lines if line.name == "XLA Ops"
+          for line in plane.lines
+          if line.name == ("XLA Ops" if mark else "XLA Modules")
           for ev in line.events
-          if mark in ev.name.split(" = ", 1)[0]]
+          if not mark or mark in ev.name.split(" = ", 1)[0]]
     return (sum(ns) / 1e3 / calls if len(ns) == calls * per_call
             else float("nan"))
 
@@ -561,18 +645,19 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     failed = 0
-    for name, build in kernel_cases(len(dev)).items():
+    for name, build in {**kernel_cases(len(dev)), **route_cases()}.items():
         if args.only not in name:
             continue
         try:
             fn, fargs, ref = build()
-            jfn = jax.jit(fn)
+            jfn, jref = jax.jit(fn), jax.jit(ref)
             got = jax.block_until_ready(jfn(*fargs))
-            want = jax.block_until_ready(jax.jit(ref)(*fargs))
+            want = jax.block_until_ready(jref(*fargs))
             err = _rel_err(got, want)
             # bf16 inputs, f32 accumulation on both sides; the scan is
-            # float32 throughout
-            ok = err < (KDA_TOL if name.startswith("kda_") else 2e-2)
+            # float32 throughout; the router's choice is the same bits
+            ok = (err == 0 if name.startswith("route_") else
+                  err < (KDA_TOL if name.startswith("kda_") else 2e-2))
             took = ""
             mark = next((m for p, m in KERNEL_MARKS.items()
                          if name.startswith(p)), None)
@@ -581,6 +666,10 @@ def main(argv=None) -> int:
                                        per_call=2 if mark == "moe_grouped"
                                        else 1)
                 took = f" kernel_us={us:.1f}"
+            elif args.time and name.startswith("route_"):
+                us, by_sort = (_kernel_device_us(f, fargs, None)
+                               for f in (jfn, jref))
+                took = f" program_us={us:.1f} by_sort_us={by_sort:.1f}"
             print(f"{'OK  ' if ok else 'FAIL'} {name} rel_err={err:.3e}"
                   f"{took}", flush=True)
         except Exception as e:  # report every case, then fail the run
