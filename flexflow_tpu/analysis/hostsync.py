@@ -21,9 +21,8 @@ non-transitive — it reads each function's own AST, not its callees):
       `lax.while_loop`/`fori_loop`/`scan`: `.item()`, `np.*`/`numpy.*`
       calls, `jax.device_get` or a host callback
       (`pure_callback`/`io_callback`) in a traced device-loop body
-      either fails on tracers or silently re-enters the host mid-loop —
-      the decode megastep's whole contract is that its inner loop has
-      ZERO of these, so this rule takes no pragma suppression.
+      either fails on tracers or silently re-enters the host mid-loop,
+      so this rule takes no pragma suppression.
       `device_loop_bodies(path)` reports which bodies were analyzed, so
       a gate test can assert the rule engaged (a clean result proves
       nothing if no loop was seen).
@@ -234,8 +233,7 @@ class _DeviceLoopScanner(ast.NodeVisitor):
     """Scan one lax.while_loop/fori_loop/scan body for host syncs. No
     pragma suppression: a sync inside a traced device loop is never an
     intentional per-tick transfer — it is a bug (trace failure or a
-    host re-entry mid-loop), the exact property the decode megastep's
-    inner loop is built to prove away."""
+    host re-entry mid-loop)."""
 
     def __init__(self, findings, rel, kind, body_name):
         self.findings = findings

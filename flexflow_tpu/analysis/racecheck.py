@@ -39,22 +39,22 @@ split:
       `handoff` (prefill→decode handoff through the shared tier),
       `tierpool` (concurrent spill/fetch/admission on a pool pair with
       LRU capacity drops), `swap` (drain-and-swap under live submits,
-      the swap lock modeled explicitly), and `dispatch` (the
-      overlapped megastep handoff: host admission racing the in-flight
-      device dispatch, fenced by one device_get). All interleavings up
-      to a context-switch bound (DEFAULT_SWITCH_BOUND) are explored
-      with DPOR-style sleep-set pruning over declared action
-      read/write footprints; PROTOCOL_INVARIANTS (future never
-      dropped, request owned by exactly one worker, tier partition
-      holds mid-fetch, no swap while a handoff is in flight, single
-      token-buffer owner and no stale-table bookkeeping across the
-      dispatch fence, plus abstract mirrors of the poolcheck catalog's
-      conservation and accounting) are asserted at every state. A
-      violation reports the MINIMAL interleaving (BFS order),
-      replayable via replay_interleaving(); seeded mutations
-      (double_submit, unlocked_submit, no_safepoint_join,
-      fetch_no_remove, read_before_fence, admit_steals_live_page)
-      prove the gate can fail.
+      the swap lock modeled explicitly), and `launch_ahead` (the
+      one-deep launch pipeline: host admission racing the launch in
+      flight, its picks taken a launch late behind a fence). All
+      interleavings up to a context-switch bound
+      (DEFAULT_SWITCH_BOUND) are explored with DPOR-style sleep-set
+      pruning over declared action read/write footprints;
+      PROTOCOL_INVARIANTS (future never dropped, request owned by
+      exactly one worker, tier partition holds mid-fetch, no swap while
+      a handoff is in flight, single token-buffer owner and no
+      stale-table bookkeeping across the dispatch fence, plus abstract
+      mirrors of the poolcheck catalog's conservation and accounting)
+      are asserted at every state. A violation reports the MINIMAL
+      interleaving (BFS order), replayable via replay_interleaving();
+      seeded mutations (double_submit, unlocked_submit,
+      no_safepoint_join, fetch_no_remove, read_before_fence,
+      free_at_late_stop, defrag_without_fence) prove the gate can fail.
 
 poolcheck's `unlocked-cross-thread-read` lint delegates to
 build_lock_model() here, so there is exactly ONE lock model in the
@@ -1010,8 +1010,8 @@ PROTOCOL_INVARIANTS = {
     "swap-during-handoff": "the controller never detaches a server "
                            "while a handoff is in flight on its loop "
                            "thread",
-    "dispatch-buffer-owner": "an in-flight megastep's (or launch's) "
-                             "token buffer has exactly one owner at "
+    "dispatch-buffer-owner": "an in-flight launch's token buffer has "
+                             "exactly one owner at "
                              "every instant — the device until the "
                              "fence retires it, host bookkeeping only "
                              "after",
@@ -1564,145 +1564,11 @@ class SwapModel(ProtocolModel):
                 self.client_pc, self.ctrl_pc, self.in_hand)
 
 
-class DispatchModel(ProtocolModel):
-    """Protocol 4 — the double-buffered megastep handoff
-    (paged/scheduler.py _mixed_megastep under overlap_dispatch=True):
-    the host dispatches a fused megastep asynchronously, runs the next
-    tick's admission work while the device computes, then FENCES on one
-    device_get before replaying the token buffer into bookkeeping. Two
-    invariants carry the overlap: the token buffer has a single owner
-    at every instant (device until the fence retires it, host replay
-    after), and the overlapped admission window only takes FREE pages —
-    no page the in-flight dispatch's table references is ever freed or
-    reassigned before the replay lands."""
-
-    NAME = "dispatch"
-    N = 2  # megastep rounds
-
-    def __init__(self, mutations: Tuple[str, ...] = ()):
-        super().__init__(mutations)
-        self.round = 0
-        self.host_pc = 0           # 0 dispatch, 1 overlap, 2 fence, 3 replay
-        self.buf = "idle"          # idle | inflight | ready | fenced
-        self.submitted = 0
-        self.pending = 0
-        self.free: List[int] = [10, 11]
-        self.live: List[int] = [0]     # pages the running slot holds
-        self.admitted: List[int] = []  # admitted mid-overlap, live next round
-        self.live_at_dispatch: Tuple[int, ...] = ()
-        self.bad_read = False
-
-    def enabled(self) -> List[Action]:
-        acts: List[Action] = []
-        if self.submitted < 1:
-            acts.append(Action("client", "submit", frozenset(),
-                               frozenset({"pending"})))
-        # the device retires the in-flight dispatch: reads the page
-        # table / pool rows the host snapshot referenced, fills the
-        # token buffer
-        if self.buf == "inflight":
-            acts.append(Action("device", f"compute({self.round})",
-                               frozenset({"live"}),
-                               frozenset({"buf"})))
-        if self.round < self.N:
-            if self.host_pc == 0 and self.buf == "idle":
-                acts.append(Action("host", f"dispatch({self.round})",
-                                   frozenset({"live"}),
-                                   frozenset({"buf"})))
-            elif self.host_pc == 1:
-                acts.append(Action("host", "overlap_admit",
-                                   frozenset({"pending", "free"}),
-                                   frozenset({"pending", "free",
-                                              "live"})))
-            elif self.host_pc == 2:
-                if self.buf == "ready" \
-                        or "read_before_fence" in self.mutations:
-                    # SEEDED DEFECT (read_before_fence): bookkeeping
-                    # proceeds without waiting for the device_get — the
-                    # replay reads a token buffer the device still owns
-                    acts.append(Action("host", "fence",
-                                       frozenset({"buf"}),
-                                       frozenset({"buf"})))
-            else:
-                acts.append(Action("host", f"replay({self.round})",
-                                   frozenset({"buf"}),
-                                   frozenset({"buf", "live"})))
-        return acts
-
-    def apply(self, action: Action):
-        op = action.label.split("(")[0]
-        if op == "submit":
-            self.pending += 1
-            self.submitted += 1
-        elif op == "compute":
-            self.buf = "ready"
-        elif op == "dispatch":
-            self.live_at_dispatch = tuple(self.live)
-            self.buf = "inflight"
-            self.host_pc = 1
-        elif op == "overlap_admit":
-            if self.pending:
-                if "admit_steals_live_page" in self.mutations \
-                        and self.live:
-                    # SEEDED DEFECT: admission grabs a page the
-                    # in-flight dispatch's table still references —
-                    # the replay lands against a stale page table
-                    self.admitted.append(self.live.pop())
-                    self.pending -= 1
-                elif self.free:
-                    self.admitted.append(self.free.pop())
-                    self.pending -= 1
-            self.host_pc = 2
-        elif op == "fence":
-            if self.buf == "inflight":
-                self.bad_read = True
-            self.buf = "fenced"
-            self.host_pc = 3
-        elif op == "replay":
-            self.buf = "idle"
-            self.live += self.admitted  # next dispatch's table sees them
-            self.admitted = []
-            self.round += 1
-            self.host_pc = 0
-
-    def check(self) -> List[str]:
-        v: List[str] = []
-        if self.bad_read:
-            v.append("dispatch-buffer-owner: host bookkeeping read the "
-                     "token buffer while the megastep was still in "
-                     "flight — the fence did not retire it first")
-        if self.buf in ("inflight", "ready"):
-            gone = set(self.live_at_dispatch) - set(self.live)
-            if gone:
-                v.append("stale-page-table: page(s) "
-                         f"{sorted(gone)} referenced by the in-flight "
-                         "dispatch's table were reassigned before the "
-                         "replay landed")
-        return v
-
-    def done(self) -> bool:
-        return self.round == self.N and self.buf == "idle" \
-            and self.submitted == 1
-
-    def check_final(self) -> List[str]:
-        total = len(self.free) + len(self.live) + len(self.admitted)
-        if total != 3:
-            return ["free-accounting: free + live + admitted pages "
-                    f"number {total}, pool holds 3"]
-        return []
-
-    def key(self) -> tuple:
-        return (self.round, self.host_pc, self.buf, self.submitted,
-                self.pending, tuple(self.free), tuple(self.live),
-                tuple(self.admitted), self.live_at_dispatch,
-                self.bad_read)
-
-
 class LaunchAheadModel(ProtocolModel):
-    """Protocol 5 — the one-deep launch pipeline of the per-tick loop
+    """Protocol 4 — the one-deep launch pipeline of the serving loop
     (paged/scheduler.py `_launch` / `_retire`): the host dispatches
-    launch k + 1 BEFORE it takes launch k's picks, so the dispatch
-    protocol's two invariants hold a launch deep. The token buffer of a
+    launch k + 1 BEFORE it takes launch k's picks, so the dispatch's
+    two invariants hold a launch deep. The token buffer of a
     launch is the device's until the host's fetch retires it (`_retire`
     waits; nothing reads a pick early), and A PAGE NAMED BY A LAUNCH IN
     FLIGHT IS NEITHER FREED NOR MOVED: a request that stops on an EOS in
@@ -1880,7 +1746,7 @@ class LaunchAheadModel(ProtocolModel):
 
 
 PROTOCOLS = {m.NAME: m for m in
-             (HandoffModel, TierPoolModel, SwapModel, DispatchModel,
+             (HandoffModel, TierPoolModel, SwapModel,
               LaunchAheadModel)}
 
 

@@ -4,10 +4,9 @@ Every distinct input shape hitting one of Executor's `jax.jit` entry
 points is a fresh XLA compilation. The serving hot paths are built so
 that the set of reachable launch shapes per served config is CLOSED and
 small (ragged windows capped at PREFILL_WINDOW_ROWS, pow2 prefill
-buckets, fixed spec-tree node counts, one megastep program per ticks
-knob) — a shape-polymorphic regression turns that into a compile storm
-that blows TTFT SLOs in production. This pass proves the closure holds,
-three ways:
+buckets, fixed spec-tree node counts) — a shape-polymorphic regression
+turns that into a compile storm that blows TTFT SLOs in production. This
+pass proves the closure holds, three ways:
 
   1. AST/dataflow arm: walks the launch sites in `paged/scheduler.py`,
      `spec/server.py`, `serving.py`, and `runtime/executor.py`, and
@@ -426,8 +425,6 @@ def _packed_prefill_shapes(slots: int, chunk: int,
 def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
                       page_size: int = 64,
                       prefill_chunk: int = 64,
-                      megastep_ticks: int = 1,
-                      megastep_mixed: bool = False,
                       spec_max_nodes: Optional[int] = None,
                       spec_depth: Optional[int] = None,
                       num_pages: Optional[int] = None,
@@ -461,19 +458,6 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
             # idle/mid-prefill slots pack nothing
             ragged |= {(b, T) for b in range(1, slots + 1)}
         entry("ragged_step", ragged)
-        if megastep_ticks > 1 and not megastep_mixed:
-            entry("megastep", [(slots, int(megastep_ticks))])
-        if megastep_mixed:
-            # the universal megastep compiles ONE program per config:
-            # its launch window is the derived max over the prefill
-            # window and the on-device drafted chain (depth+1); it
-            # replaces the pure-decode megastep even at ticks == 1
-            # (the fusion of mixed rows is the point, not the tick
-            # count)
-            wl = max(min(int(window_rows), int(prefill_chunk)),
-                     (int(spec_depth) if spec_depth else 0) + 1)
-            entry("megastep_mixed",
-                  [(slots, int(megastep_ticks), wl)])
         if spec_max_nodes:
             depth = int(spec_depth) if spec_depth else 1
             entry("paged_commit", [(slots, depth + 1)])
@@ -496,8 +480,6 @@ def enumerate_catalog(*, slots: int, max_len: int, paged: bool = True,
             "slots": slots, "max_len": max_len, "paged": bool(paged),
             "page_size": int(page_size) if paged else None,
             "prefill_chunk": int(prefill_chunk) if paged else None,
-            "megastep_ticks": int(megastep_ticks),
-            "megastep_mixed": bool(megastep_mixed),
             "spec_max_nodes": int(spec_max_nodes) if spec_max_nodes else None,
             "spec_depth": int(spec_depth) if spec_depth else None,
             "num_pages": int(num_pages) if num_pages else None,
@@ -524,8 +506,6 @@ def catalog_for_strategy(strategy, *, slots: int, max_len: int) -> Dict:
     return enumerate_catalog(
         slots=slots, max_len=max_len, paged=True,
         page_size=kw["page_size"], prefill_chunk=kw["prefill_chunk"],
-        megastep_ticks=kw["megastep_ticks"],
-        megastep_mixed=kw.get("megastep_mixed", False),
         spec_max_nodes=sp.max_nodes if sp else None,
         spec_depth=sp.depth if sp else None,
         num_pages=kw["num_pages"], kv_dtype=kw["kv_dtype"])
@@ -593,11 +573,6 @@ def check_soundness(catalog: Dict, events: Sequence[Dict]) -> List[Finding]:
 DEFAULT_CONFIGS = {
     "paged_base": dict(slots=4, max_len=128, page_size=16,
                        prefill_chunk=32),
-    "paged_megastep": dict(slots=4, max_len=128, page_size=16,
-                           prefill_chunk=32, megastep_ticks=8),
-    "paged_mixed": dict(slots=4, max_len=128, page_size=16,
-                        prefill_chunk=32, megastep_ticks=8,
-                        megastep_mixed=True),
     "paged_spec": dict(slots=4, max_len=128, page_size=16,
                        prefill_chunk=32, spec_max_nodes=9, spec_depth=4),
     "dense": dict(slots=4, max_len=128, paged=False),

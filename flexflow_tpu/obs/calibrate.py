@@ -67,15 +67,12 @@ def predict_step_seconds(ff) -> Dict:
 
 def tick_tokens(phase: str, batch: int, chunk: int, width: int) -> int:
     """Token rows one ledger entry of this shape pushes through the
-    model. For decode, `width` is the MEGASTEP width — fused inner ticks
-    per dispatch (w1 = the one-tick loop), each scoring `batch` rows —
-    so `decode|b4|w8` prices 32 rows and the per-shape calibration
-    ratios (and MeasuredCostModel.decode_tick_time) stay meaningful
-    across megastep configurations."""
+    model. A decode tick scores `batch` rows (its `width` is 1), a
+    verify `width` tree nodes a slot."""
     if phase == "prefill":
         return max(int(chunk), 1)
-    # decode: one row per live slot per fused tick; verify: one row per
-    # tree node per slot
+    # decode: one row per live slot; verify: one row per tree node per
+    # slot
     return max(int(batch) * max(int(width), 1), 1)
 
 

@@ -22,9 +22,9 @@ head per page, symmetric around zero (stored = round(x / scale),
 clipped to [-127, 127]; loaded = stored * scale). Putting the sidecar
 INSIDE the caches dict is the load-bearing trick: every pool-following
 operation — the COW clone's ``copy_page`` tree.map, the defrag
-permutation's ``b[perm]``, the megastep while_loop carry, the spec
-commit — already maps over every leaf of that dict, so scales ride
-along with their pages by construction. The poolcheck scale-sidecar
+permutation's ``b[perm]``, the spec commit — already maps over every
+leaf of that dict, so scales ride along with their pages by
+construction. The poolcheck scale-sidecar
 invariant (analysis/pool_invariants.py) proves that discipline holds.
 
 Quantize-on-append with rescale-on-grow. A page's scale only ever

@@ -64,7 +64,7 @@ device vector of the slots' newest tokens (`_newest`, the step's
 one row in the launch after, whose pick is discarded (`late_stop_rows`)
 and whose pages stay the request's until that launch is taken.
 Whatever needs the host's truth of every token (preemption, defrag, a
-drain-and-swap, stop(), the megasteps, speculation's drafting, a
+drain-and-swap, stop(), speculation's drafting, a
 hand-off) first calls `_retire(reason)`, the FENCE: the serial order is
 this loop with the pipeline drained.
 """
@@ -138,8 +138,6 @@ class PagedGenerationServer(_GenerationServerBase):
                  page_size: int = 64, num_pages: Optional[int] = None,
                  preemption: bool = True, table_slack_tokens: int = 0,
                  prefix_cache: bool = True, prefill_chunk: int = 64,
-                 megastep_ticks: int = 1, megastep_mixed: bool = False,
-                 overlap_dispatch: bool = False,
                  request_record_limit: Optional[int] = None,
                  kv_dtype: str = "auto",
                  reqlog_capacity: Optional[int] = None,
@@ -224,32 +222,10 @@ class PagedGenerationServer(_GenerationServerBase):
         # verify in the speculative subclass): K/V writes land straight
         # in pool pages, there is no dense staging cache
         self._step = ex.ragged_step_fn()
-        # megastep_ticks > 1: pure-decode ticks run up to N ticks per
-        # dispatch inside one jitted while_loop (docs/paged.md "Decode
-        # megasteps"); 1 keeps the per-tick host loop. Ticks with
-        # mid-prefill chunks in flight always take the one-tick path, so
-        # chunk completion resumes the host scheduler between ticks.
-        self.megastep_ticks = int(megastep_ticks)
-        if self.megastep_ticks < 1:
-            raise ValueError(
-                f"megastep_ticks must be >= 1, got {megastep_ticks}")
-        # megastep_mixed: the UNIVERSAL megastep — mid-prefill chunk
-        # rows and on-device drafted spec chains fuse into the same
-        # while_loop as decode rows (docs/paged.md "Universal
-        # megasteps"), so a tick with a chunk in flight no longer drops
-        # to host granularity. overlap_dispatch additionally runs the
-        # next tick's admission work while the fused dispatch is in
-        # flight, fencing on the one device_get.
-        self.megastep_mixed = bool(megastep_mixed)
-        self.overlap_dispatch = bool(overlap_dispatch)
-        if self.overlap_dispatch and not self.megastep_mixed:
-            raise ValueError(
-                "overlap_dispatch overlaps host work with the in-flight "
-                "MIXED megastep dispatch; pass megastep_mixed=True")
         # kv_dtype: "auto" pools at the model's dtype; "int8" stores
         # quantized pages with the per-(page, head) scale sidecar inside
-        # the same caches dict (paged/quant.py), so copy_page/defrag/
-        # megastep carry all move scales with pages by construction;
+        # the same caches dict (paged/quant.py), so copy_page/defrag
+        # move scales with pages by construction;
         # "bf16"/"fp16"/"fp32" are plain storage casts without scales
         from flexflow_tpu.paged.quant import (
             is_quantized_dtype,
@@ -262,47 +238,12 @@ class PagedGenerationServer(_GenerationServerBase):
                            and is_quantized_dtype(pool_dt))
         # FF_TPU_KV_QUANT_DEBUG=1 keeps a shadow fp32 cache and runs
         # every launch twice, exporting the running max abs output delta
-        # as the kv_quant_error gauge (docs/observability.md). The
-        # shadow must observe every tick, so megasteps fall back to the
-        # one-tick loop under the flag.
+        # as the kv_quant_error gauge (docs/observability.md).
         import os as _os
 
         self._kv_quant_debug = (
             self._quantized
             and _os.environ.get("FF_TPU_KV_QUANT_DEBUG") == "1")
-        if self._kv_quant_debug and self.megastep_ticks > 1:
-            import logging
-
-            logging.getLogger(__name__).info(
-                "FF_TPU_KV_QUANT_DEBUG=1: forcing megastep_ticks=1 so "
-                "the fp32 shadow cache observes every tick")
-            self.megastep_ticks = 1
-        if self._kv_quant_debug and self.megastep_mixed:
-            import logging
-
-            logging.getLogger(__name__).info(
-                "FF_TPU_KV_QUANT_DEBUG=1: forcing megastep_mixed=False "
-                "so the fp32 shadow cache observes every launch")
-            self.megastep_mixed = False
-            self.overlap_dispatch = False
-        self._megastep = (ex.paged_megastep_fn(self.megastep_ticks, eos_id)
-                          if self.megastep_ticks > 1
-                          and not self.megastep_mixed else None)
-        # the universal megastep's fused launch window: chunk pieces are
-        # capped at the packed-prefill window, drafted chains at
-        # depth + 1 rows (0 on the non-speculative server)
-        spec_cfg = getattr(self, "spec", None)
-        self._spec_depth = int(spec_cfg.depth) if spec_cfg is not None \
-            else 0
-        self._mixed_window = min(self._chunk_rows, self.prefill_chunk)
-        self._mixed_fn = (ex.paged_mixed_megastep_fn(
-            self.megastep_ticks, eos_id, window=self._mixed_window,
-            depth=self._spec_depth) if self.megastep_mixed else None)
-        # device-resident (slots, Lbuf + 1) token ledger for the mixed
-        # megastep (column Lbuf is the masked-scatter trash column);
-        # None = dirty, rebuilt from host truth on next dispatch
-        self._seq_cols = self.max_pages_per_seq * self.page_size
-        self._seq_dev = None
         # STATE LAYERS (decided by the graph, Executor.state_layers): a
         # node that keeps a fixed-size state a SLOT beside the pages has
         # its leaves in the same `_caches` dict, indexed by slot; a launch
@@ -429,22 +370,6 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_rows = self.registry.counter("launch_rows_total")
         self._c_pad = self.registry.counter("padded_rows_total")
         self._g_waste = self.registry.gauge("padding_waste_ratio")
-        # megastep accounting: ticks fused per dispatch, why each
-        # megastep handed control back, and host round-trips per decoded
-        # token — the one-tick path counts one round-trip per tick, so
-        # the N=1 vs N=8 bench A/B reads the same counters
-        self._h_mega = self.registry.histogram("megastep_ticks",
-                                               obs.COUNT_BUCKETS)
-        self._c_rt = self.registry.counter("host_roundtrips_total")
-        self._c_dtok = self.registry.counter("decode_tokens_total")
-        self._g_rt_tok = self.registry.gauge("host_roundtrips_per_token")
-        self._c_break = {
-            r: self.registry.counter(f"megastep_break_{r}_total")
-            for r in ("finish", "page", "limit", "chunk", "verify")}
-        # overlap-dispatch accounting: host work done in the shadow of
-        # the in-flight fused dispatch over the whole dispatch wait
-        # (host work time / (host work time + fence time))
-        self._g_overlap = self.registry.gauge("host_overlap_ratio")
         # one gate decision, surfaced: which attention path this server's
         # launches take (evaluated host-side at init — the gate only
         # depends on shapes/dtype/backend/env, all fixed for the server's
@@ -622,9 +547,9 @@ class PagedGenerationServer(_GenerationServerBase):
         self._g_tier_ratio = self.registry.gauge("host_tier_occupancy_ratio")
         self._g_tier_lat = self.registry.gauge("host_tier_fetch_latency_s")
         if self.serve_strategy is None:
-            # derive the strategy from the ACTUAL constructor knobs (after
-            # any debug-flag adjustments) so fingerprint() always reflects
-            # what this server runs, even when built without servesearch
+            # derive the strategy from the ACTUAL constructor knobs so
+            # fingerprint() always reflects what this server runs, even
+            # when built without servesearch
             self.serve_strategy = self._derive_strategy()
         self._start()
 
@@ -637,8 +562,6 @@ class PagedGenerationServer(_GenerationServerBase):
             "slots": self.slots, "max_len": self.max_len, "paged": True,
             "page_size": self.page_size,
             "prefill_chunk": self.prefill_chunk,
-            "megastep_ticks": self.megastep_ticks,
-            "megastep_mixed": self.megastep_mixed,
             # num_pages is fixed at pool construction; the loop thread
             # never resizes the pool
             "num_pages": self.pool.num_pages,  # fflint: lock-ok (immutable)
@@ -726,19 +649,6 @@ class PagedGenerationServer(_GenerationServerBase):
             "padding_waste_ratio": (
                 self._c_pad.value / self._c_rows.value
                 if self._c_rows.value else 0.0),
-            "megastep": {
-                "ticks_max": self.megastep_ticks,
-                "mixed": self.megastep_mixed,
-                "overlap_dispatch": self.overlap_dispatch,
-                "host_overlap_ratio": float(self._g_overlap.value),
-                "host_roundtrips": int(self._c_rt.value),
-                "decode_tokens": int(self._c_dtok.value),
-                "host_roundtrips_per_token": (
-                    self._c_rt.value / self._c_dtok.value
-                    if self._c_dtok.value else 0.0),
-                "breaks": {r: int(c.value)
-                           for r, c in self._c_break.items()},
-            },
             "prefix_cache": {
                 "enabled": self.prefix_cache,
                 "hit_tokens": pool.hit_tokens,
@@ -937,8 +847,7 @@ class PagedGenerationServer(_GenerationServerBase):
         shadow window on that request: _caches_ref becomes an fp32
         snapshot of the CURRENT pool, so _launch's replay block measures
         divergence accrued from this admission forward. One window at a
-        time; megasteps stand down while one is open (_loop_body) so the
-        shadow observes every tick."""
+        time; every launch is a tick, so the shadow observes each one."""
         if not self.kv_quant_canary or self._kv_quant_debug:
             return
         self._canary_admits += 1
@@ -1028,9 +937,7 @@ class PagedGenerationServer(_GenerationServerBase):
         """Reconstruct the ServeStrategy this server actually runs —
         called by the constructor when no explicit strategy was passed,
         so reqlog stamping and autopilot window segmentation work on
-        hand-built servers too. Reads the knobs AFTER any debug-flag
-        adjustment (megastep forcing under FF_TPU_KV_QUANT_DEBUG), so
-        the fingerprint matches observable behaviour, not the args."""
+        hand-built servers too."""
         spec = getattr(self, "spec", None)
         dense_pages = self.slots * self.max_pages_per_seq
         frac = (1.0 if self.pool.num_pages >= dense_pages + 1
@@ -1043,9 +950,6 @@ class PagedGenerationServer(_GenerationServerBase):
             prefill_chunk=min(self.prefill_chunk, self.max_len),
             spec_width=(spec.width if spec is not None else 0),
             spec_depth=(spec.depth if spec is not None else 0),
-            megastep_ticks=self.megastep_ticks,
-            megastep_mixed=self.megastep_mixed,
-            overlap_dispatch=self.overlap_dispatch,
             pool_fraction=round(frac, 6),
             kv_dtype=self.kv_dtype,
         )
@@ -1582,33 +1486,6 @@ class PagedGenerationServer(_GenerationServerBase):
 
     def _mark_temps_dirty(self):
         self._temps_dev = None
-        # slot occupancy changed -> the mixed-megastep token ledger no
-        # longer matches host truth; rebuilt on next dispatch. (Page
-        # growth/defrag only move PAGES, never tokens, so the tables
-        # dirty flag does not imply a seq rebuild.)
-        self._seq_dev = None
-
-    def _seq_device(self):
-        """The (slots, Lbuf + 1) committed-token ledger on device for
-        the mixed megastep: row s holds slot s's prompt + generated
-        tokens (the FULL prompt for a mid-prefill slot, so chunk rows
-        gather from it), column Lbuf is the masked-scatter trash
-        column. Between dispatches the megastep's own seq output is
-        reused; any admission/release/eviction rebuilds from host
-        truth."""
-        import jax.numpy as jnp
-
-        if self._seq_dev is None:
-            seq = np.zeros((self.slots, self._seq_cols + 1), np.int32)
-            for s in range(self.slots):
-                req = self._active[s]
-                if req is None:
-                    continue
-                toks = (req.prefill_seq if self._mid_prefill(s)
-                        else req.seq_tokens())
-                seq[s, :len(toks)] = toks
-            self._seq_dev = jnp.asarray(seq)
-        return self._seq_dev
 
     def _tables_device(self):
         """The (slots, max_pages) page-table matrix on device, uploaded
@@ -2124,12 +2001,6 @@ class PagedGenerationServer(_GenerationServerBase):
         with obs.span("commit"):
             # ADVANCE: every decoding slot wrote one row
             self._steps += 1
-            # one host round-trip bought len(live) tokens — the same
-            # counters the megastep path feeds, so N=1 vs N>1 compare
-            self._c_rt.inc()
-            self._c_dtok.inc(len(live))
-            if self._c_dtok.value:
-                self._g_rt_tok.set(self._c_rt.value / self._c_dtok.value)
             for s in self._admit_order:
                 if self._mid_prefill(s):
                     self._active[s].decode_overlap_ticks += 1
@@ -2257,339 +2128,8 @@ class PagedGenerationServer(_GenerationServerBase):
             return
         super()._finish_if_done(slot, req)
 
-    def _decode_megastep(self, live, tr, ntr):
-        """Up to `megastep_ticks` decode ticks in ONE jitted dispatch
-        (Executor.paged_megastep_fn): positions, page-table tail
-        capacity, finish flags, temps, the rng chain and the sampled-
-        token buffer all live on device inside a `jax.lax.while_loop`;
-        the host consumes the whole (ticks, slots) buffer in a single
-        transfer, then replays its bookkeeping (append, prefix
-        publication, finish) token by token in the one-tick order.
-
-        The device loop breaks BEFORE any tick it cannot run alone:
-        after a slot finishes (length, or eos mid-megastep) or when a
-        slot's next write row would cross its allocated pages — so page
-        growth, admission, eviction and defrag stay host-side exactly
-        where poolcheck models them, and the prefix cache sees the same
-        page-boundary publications the one-tick loop produces. Only
-        dispatched on pure-decode ticks: mid-prefill chunks keep host
-        granularity (_loop_body), so a finishing chunk always resumes
-        the host. Greedy AND fixed-seed sampled output are token-
-        identical to the one-tick loop — the rng advances by the same
-        split chain, one split per tick."""
-        import jax
-        import jax.numpy as jnp
-
-        if self._caches_ref is not None:
-            # DYNAMIC stand-down, not a construction-time choice: a
-            # kv_quant_canary window can open on any admission mid-serve
-            # and the fp32 shadow must observe every launch — delegate
-            # this dispatch to the one-tick path (which replays against
-            # the shadow) no matter which call site asked for a megastep
-            return self._decode_tick(live, tr, ntr)
-        t0 = time.monotonic()
-        sp = obs.span("megastep").__enter__()
-        if sp:
-            sp.set(live=len(live), pages_in_use=self.pool.pages_in_use)
-        P = self.page_size
-        pos = np.zeros((self.slots,), np.int32)
-        rem = np.zeros((self.slots,), np.int32)
-        cap = np.zeros((self.slots,), np.int32)
-        act = np.zeros((self.slots,), np.bool_)
-        for s in live:
-            req = self._active[s]
-            pos[s] = req.pos
-            rem[s] = req.max_new - len(req.tokens)
-            cap[s] = len(req.pages) * P
-            act[s] = True
-        caches, out, done, rng, ticks = self._megastep(
-            tr, ntr, self._caches, self._tables_device(),
-            jnp.asarray(pos), jnp.asarray(self._tokens),
-            self._temps_device(), jnp.asarray(rem), jnp.asarray(cap),
-            jnp.asarray(act), self._rng)
-        self._caches = caches
-        self._rng = rng
-        # the ONE host sync of the megastep: token buffer + finish
-        # flags + tick count in a single transfer
-        out_np, done_np, n = jax.device_get((out, done, ticks))
-        self._synced = self.launches    # the chip ran dry behind it
-        n = int(n)
-        if done_np.any():
-            reason = "finish"
-        elif n < self.megastep_ticks:
-            reason = "page"
-        else:
-            reason = "limit"
-        # replay host bookkeeping tick by tick in the one-tick order:
-        # every executed tick emitted a token for every live slot (the
-        # loop breaks before the tick AFTER a finish, so finishes only
-        # ever land on the last replayed tick)
-        for t in range(n):
-            self._steps += 1
-            for s in live:
-                req = self._active[s]
-                tok = int(out_np[t, s])
-                req.pos += 1
-                req.tokens.append(tok)
-                self._tokens[s] = tok
-                self._publish_prefix(req, req.pos)
-                self._finish_if_done(s)
-        self._on_megastep_resume()
-        rows, padded = n * self.slots, n * (self.slots - len(live))
-        self._c_rows.inc(rows)
-        self._c_pad.inc(padded)
-        self._g_waste.set(padded / rows if rows else 0.0)
-        self._c_rt.inc()
-        self._c_dtok.inc(n * len(live))
-        if self._c_dtok.value:
-            self._g_rt_tok.set(self._c_rt.value / self._c_dtok.value)
-        self._h_mega.observe(n)
-        self._c_break[reason].inc()
-        if sp:
-            sp.set(ticks=n, break_reason=reason, fused_rows=n * len(live))
-        sp.__exit__(None, None, None)
-        dt = time.monotonic() - t0
-        # per-tick effective latency: the histogram stays comparable
-        # across megastep widths (the A/B's p50/p95 read)
-        self._h_tick.observe(dt / max(n, 1))
-        self._h_tokens.observe(len(live))
-        led = obs.ledger()
-        if led is not None:
-            led.record("decode", dt, batch=len(live), width=max(n, 1))
-
-    # -- universal (mixed) megastep ---------------------------------------
-
-    def _mixed_spec_slot(self, req) -> bool:
-        """Whether a decoding slot drafts an on-device speculative chain
-        inside the mixed megastep. Base server: never (no SpecConfig);
-        the speculative subclass drafts on greedy slots."""
-        return False
-
-    def _on_mixed_spec_tick(self, req, emitted: int):
-        """Hook: one drafting slot's tick committed `emitted` tokens
-        (accepted prefix + bonus). The speculative subclass feeds its
-        acceptance counters; the base server never drafts."""
-
-    def _overlap_window(self):
-        """Host work run in the SHADOW of the in-flight mixed dispatch
-        (overlap_dispatch=True), against a one-deep staged snapshot of
-        scheduler state: admission of pending requests. Admission is
-        structurally safe here — it only touches FREE slots and FREE
-        pages (never a live slot's table row, so no bookkeeping runs
-        against a page table the in-flight dispatch is using), it never
-        preempts, and its device work (COW clone, scale reset, tier
-        fetches, canary snapshot) chains on the in-flight arrays by
-        data dependency. Page growth, eviction and defrag stay strictly
-        AFTER the fence (the next _tick_prep) — the racecheck `dispatch`
-        protocol model explores exactly this ownership discipline."""
-        with obs.span("overlap_admit"):
-            self._admit_pending()
-
-    def _mixed_dispatch(self, live, tr, ntr) -> bool:
-        """Dispatch this tick as ONE universal megastep when the mode is
-        on and no canary shadow window is open (the shadow must observe
-        every launch, so an open window stands the fused path down
-        dynamically — same discipline as _decode_megastep's guard).
-        Returns True when the tick was handled."""
-        if self._mixed_fn is None or self._caches_ref is not None:
-            return False
-        # an option-set path keeps its own order: entered drained
-        if self._retire("megastep"):
-            live = self._live()
-        if live:
-            self._mixed_megastep(live, tr, ntr)
-        return True
-
-    def _mixed_megastep(self, live, tr, ntr):
-        """Up to `megastep_ticks` MIXED ticks in one jitted dispatch
-        (Executor.paged_mixed_megastep_fn): decode rows, mid-prefill
-        chunk rows and on-device drafted spec chains ride the same
-        while_loop carry, and the host consumes one (ticks, slots, E)
-        token buffer per dispatch. With overlap_dispatch the host runs
-        the next tick's admission work while the device computes and
-        only then blocks on the fence (the single device_get), exporting
-        host_overlap_ratio.
-
-        Break reasons extend the decode megastep's: `chunk` hands
-        control back after a prefill chunk COMPLETES (page publication
-        + first-token bookkeeping are host work — poolcheck's model),
-        `verify` when a drafting slot's next chain would cross its
-        allocated pages; `finish`/`page`/`limit` mean what they mean on
-        the pure-decode path. The first token of a completing prefill
-        is sampled ON DEVICE with the tick's shared rng split, so the
-        sampled stream is megastep-width invariant (N vs 1) by the same
-        one-split-per-tick argument as the decode megastep."""
-        import jax
-        import jax.numpy as jnp
-
-        t0 = time.monotonic()
-        sp = obs.span("megastep").__enter__()
-        if sp:
-            sp.set(live=len(live), pages_in_use=self.pool.pages_in_use)
-        P = self.page_size
-        W = self._mixed_window
-        D = self._spec_depth
-        pos = np.zeros((self.slots,), np.int32)
-        pfp = np.zeros((self.slots,), np.int32)
-        pft = np.zeros((self.slots,), np.int32)
-        rem = np.zeros((self.slots,), np.int32)
-        cap = np.zeros((self.slots,), np.int32)
-        dec_act = np.zeros((self.slots,), np.bool_)
-        pf_act = np.zeros((self.slots,), np.bool_)
-        spec_m = np.zeros((self.slots,), np.bool_)
-        for s in live:
-            req = self._active[s]
-            cap[s] = len(req.pages) * P
-            if self._mid_prefill(s):
-                pf_act[s] = True
-                pfp[s] = req.prefill_pos
-                pft[s] = req.prefill_target
-                rem[s] = req.max_new  # the first token counts
-            else:
-                dec_act[s] = True
-                pos[s] = req.pos
-                rem[s] = req.max_new - len(req.tokens)
-                spec_m[s] = self._mixed_spec_slot(req)
-        caches, seq_d, out, cnt, done, pf_fin, rng, ticks = \
-            self._mixed_fn(
-                tr, ntr, self._caches, self._tables_device(),
-                self._seq_device(), jnp.asarray(pos), jnp.asarray(pfp),
-                jnp.asarray(pft), self._temps_device(),
-                jnp.asarray(rem), jnp.asarray(cap),
-                jnp.asarray(dec_act), jnp.asarray(pf_act),
-                jnp.asarray(spec_m), self._rng)
-        # hand the carry forward immediately (async dispatch): the next
-        # tick's inputs chain on these by data dependency
-        self._caches = caches
-        self._rng = rng
-        self._seq_dev = seq_d
-        host_s = 0.0
-        if self.overlap_dispatch:
-            h0 = time.monotonic()
-            self._overlap_window()
-            host_s = time.monotonic() - h0
-        f0 = time.monotonic()
-        # the ONE host sync of the dispatch — the fence. Everything that
-        # reads the token buffer (the bookkeeping replay below) runs
-        # strictly after it: single token-buffer owner.
-        out_np, cnt_np, done_np, pf_np, n = jax.device_get(
-            (out, cnt, done, pf_fin, ticks))
-        fence_s = time.monotonic() - f0
-        self._synced = self.launches    # the chip ran dry behind it
-        if self.overlap_dispatch:
-            wait = host_s + fence_s
-            self._g_overlap.set(host_s / wait if wait > 0 else 0.0)
-        n = int(n)
-        if n == 0:
-            # defensive only: the device refused the first tick (a
-            # capacity race _ensure_pages should have prevented). Run
-            # one legacy host-granularity tick so the loop always makes
-            # progress; no rng split was consumed by the empty dispatch.
-            sp.__exit__(None, None, None)
-            self._host_tick(live, tr, ntr)
-            return
-        pf_slots = [s for s in live if pf_act[s]]
-        dec_slots = [s for s in live if dec_act[s]]
-        if pf_slots:
-            self.prefill_ticks += 1
-            if dec_slots:
-                for s in pf_slots:
-                    self._active[s].decode_overlap_ticks += n
-        # replay host bookkeeping tick by tick in the one-tick order;
-        # chunk completions and finishes only land on the last executed
-        # tick (the loop breaks on them), so slot release can never race
-        # an earlier tick's replay
-        fused = 0
-        dtok = 0
-        for t in range(n):
-            self._steps += 1
-            last = t == n - 1
-            for s in pf_slots:
-                req = self._active[s]
-                if req is None or req.prefill_pos >= req.prefill_target:
-                    continue
-                take = min(W, req.prefill_target - req.prefill_pos)
-                fused += take
-                req.prefill_pos += take
-                req.prefill_tokens += take
-                self._publish_prefix(req, req.prefill_pos)
-                if pf_np[s] and last:
-                    # mirror _prefill_tick's completion sequence — tail
-                    # published while seq_tokens() still equals
-                    # prefill_seq, THEN the device-sampled first token
-                    self._publish_tail(req)
-                    self._first_token_from_device(
-                        s, req, int(out_np[t, s, 0]))
-                    self._finish_if_done(s)
-                    if self._active[s] is not None:
-                        self._on_prefill_complete(s)
-            for s in dec_slots:
-                req = self._active[s]
-                if req is None:
-                    continue
-                fused += (D + 1) if spec_m[s] else 1
-                c = int(cnt_np[t, s])
-                for j in range(c):
-                    tok = int(out_np[t, s, j])
-                    req.pos += 1
-                    req.tokens.append(tok)
-                    self._tokens[s] = tok
-                dtok += c
-                if spec_m[s]:
-                    self._on_mixed_spec_tick(req, c)
-                if c:
-                    self._publish_prefix(req, req.pos)
-                    self._finish_if_done(s)
-        self._on_megastep_resume()
-        if done_np.any():
-            reason = "finish"
-        elif pf_np.any():
-            reason = "chunk"
-        elif n < self.megastep_ticks:
-            # the blocking slot needs page growth: a drafting slot that
-            # cannot fit its next chain is a verify break, a plain
-            # decode row crossing its pages a page break (cap is the
-            # dispatch-time capacity — the same value the device cond
-            # tested against the advanced positions)
-            blocked_spec = any(
-                spec_m[s] and self._active[s] is not None
-                and self._active[s].pos + D + 1 > cap[s]
-                for s in dec_slots)
-            reason = "verify" if blocked_spec else "page"
-        else:
-            reason = "limit"
-        Wl = max(W, D + 1)
-        rows = n * self.slots * Wl
-        padded = rows - fused
-        self._c_rows.inc(rows)
-        self._c_pad.inc(padded)
-        self._g_waste.set(padded / rows if rows else 0.0)
-        self._c_rt.inc()
-        self._c_dtok.inc(dtok)
-        if self._c_dtok.value:
-            self._g_rt_tok.set(self._c_rt.value / self._c_dtok.value)
-        self._h_mega.observe(n)
-        self._c_break[reason].inc()
-        if sp:
-            sp.set(ticks=n, break_reason=reason, fused_rows=fused,
-                   pf_slots=len(pf_slots), dec_slots=len(dec_slots))
-        sp.__exit__(None, None, None)
-        dt = time.monotonic() - t0
-        self._h_tick.observe(dt / max(n, 1))
-        self._h_tokens.observe(len(live))
-        led = obs.ledger()
-        if led is not None:
-            led.record("decode", dt, batch=len(live), width=max(n, 1))
-
-    def _on_megastep_resume(self):
-        """Hook fired after a megastep's host bookkeeping replay, before
-        its metrics are recorded — the host-resume point. Tests override
-        it to assert pool invariants after every resume; the base server
-        does nothing (check_invariants is too hot for the serving
-        loop)."""
-
     def _host_tick(self, live, tr, ntr):
-        """One iteration at the host's granularity, ONE launch: the
+        """One iteration, ONE launch: the
         chunk's launch carries the decoding slots' rows whenever both
         kinds of work exist (a slot whose prompt finishes in it decodes
         from the next iteration on, as `dec` is settled before)."""
@@ -2598,14 +2138,6 @@ class PagedGenerationServer(_GenerationServerBase):
             rode = self._prefill_tick(pre, tr, ntr, dec)
             if dec:
                 self._decode_tick(dec, tr, ntr, rode)
-        elif self._megastep is not None:
-            # an option-set path keeps its own order: entered drained.
-            # _decode_megastep stands down by itself while a canary
-            # window is open (the fp32 shadow must observe every launch)
-            if self._retire("megastep"):
-                dec = [s for s in dec if self._active[s] is not None]
-            if dec:
-                self._decode_megastep(dec, tr, ntr)
         else:
             self._decode_tick(dec, tr, ntr)
 
@@ -2614,8 +2146,7 @@ class PagedGenerationServer(_GenerationServerBase):
             live = self._tick_prep()
             if live is None:
                 continue
-            if not self._mixed_dispatch(live, tr, ntr):
-                self._host_tick(live, tr, ntr)
+            self._host_tick(live, tr, ntr)
 
     def _drain(self):
         # what the launches in flight emitted is the callers': take it

@@ -137,11 +137,6 @@ class _TracedStep:
 class Executor:
     """Owns the lowered step functions for one compiled PCG."""
 
-    # cap on the per-argument-tuple jit memos (paged_megastep_fn): a
-    # long-lived server churning serve strategies must not leak compiled
-    # executables; the ff_jit_cache_entries gauge watches the live count
-    JIT_CACHE_LIMIT = 8
-
     def __init__(
         self,
         graph: Graph,
@@ -203,7 +198,6 @@ class Executor:
         self._forward = None
         self._decode_fn = None
         self._ragged_step_fn = None
-        self._megastep_fns: Dict[Any, Any] = {}
         self._paged_commit_fn = None
         # compile-event tracker (obs/compile_tracker.py): each decode-
         # path jit factory below hands its callable through wrap(), so
@@ -853,8 +847,8 @@ class Executor:
         sidecar entries "k_scale"/"v_scale" — (num_pages, num_kv)
         float32 — to every node's dict (paged/quant.py has the layout
         story); putting them inside the same dict is what lets the COW
-        clone, the defrag permutation, the megastep carry and the spec
-        commit move scales with their pages by construction.
+        clone, the defrag permutation and the spec commit move scales
+        with their pages by construction.
 
         A sliding-window node's pool has `num_pages_window` pages, its
         own class's count (`page_classes`; a server sizes it to a window
@@ -1019,299 +1013,6 @@ class Executor:
             lambda args: args[8].shape)
         return self._ragged_step_fn
 
-    def paged_megastep_fn(self, max_ticks: int, eos_id=None):
-        """jitted decode MEGASTEP: up to `max_ticks` single-token decode
-        ticks inside one `jax.lax.while_loop`, every fast-path state
-        device-resident (flexflow_tpu.paged megastep driver).
-
-        (params, pools, page_tables, pos, toks, temps, remaining,
-         cap_rows, active, rng) ->
-            (new_pools, out_tokens, done, new_rng, ticks)
-
-        Per-slot inputs are (slots,)-shaped: `pos` the next write row,
-        `toks` the last sampled token (next tick's input), `remaining`
-        tokens the request may still emit, `cap_rows` the rows its
-        ALLOCATED pages cover, `active` which slots decode (inactive
-        rows carry q_len 0: no work, K/V writes redirected to the null
-        page). Each iteration runs the same per-tick compute as
-        ragged_step_fn at window 1, advances the rng by the identical
-        `jax.random.split` chain the host one-tick loop uses, samples
-        via serving.pick_tokens, and appends into the
-        (max_ticks, slots) token buffer (-1 on inactive rows). The loop
-        stops BEFORE a tick that cannot run on device alone: after any
-        active slot finishes (remaining exhausted, or sampled `eos_id`
-        when given) or when a slot's next write row would cross its
-        allocated capacity (page growth is host bookkeeping). `ticks`
-        counts executed iterations; `done` marks who finished, so the
-        host scheduler consumes the whole buffer in one transfer.
-        Compiled once per (max_ticks, eos_id, slots) — table/positions
-        are contents, never shapes. The pools are donated, as
-        ragged_step_fn's are."""
-        key = (int(max_ticks), eos_id)
-        fn = self._megastep_fns.pop(key, None)
-        if fn is not None:
-            self._megastep_fns[key] = fn  # refresh LRU recency
-            return fn
-        from flexflow_tpu.serving import pick_tokens  # lazy: no cycle
-
-        N = int(max_ticks)
-
-        def megastep(trainable, nontrainable, caches, page_tables, pos,
-                     toks, temps, remaining, cap_rows, active, rng):
-            slots = pos.shape[0]
-            q_lens = jnp.where(active, 1, 0).astype(jnp.int32)
-            depths = jnp.zeros((slots, 1), jnp.int32)
-            anc = jnp.ones((slots, 1, 1), jnp.bool_)
-            out0 = jnp.full((N, slots), -1, jnp.int32)
-
-            def cond(state):
-                t, _caches, p, _tk, _rem, done, _rng, _out = state
-                # next tick writes row p per active slot: it needs
-                # cap >= p+1 rows; a finished slot hands control back
-                room = jnp.all(jnp.logical_or(
-                    jnp.logical_not(active), p + 1 <= cap_rows))
-                return (t < N) & jnp.logical_not(jnp.any(done)) & room
-
-            def body(state):
-                t, caches_t, p, tk, rem, _done, rng_t, out = state
-                cache_out = {}
-                probs, _, _ = self.run_forward(
-                    trainable, nontrainable, (tk[:, None],),
-                    training=False, rng=jax.random.key(0),
-                    kv_caches=caches_t, cache_position=p,
-                    cache_out=cache_out, page_tables=page_tables,
-                    ragged=(q_lens, depths, anc),
-                )
-                rng_t, sub = jax.random.split(rng_t)
-                nxt = pick_tokens(probs[:, -1, :], temps, sub)
-                tk2 = jnp.where(active, nxt, tk)
-                p2 = jnp.where(active, p + 1, p)
-                rem2 = jnp.where(active, rem - 1, rem)
-                fin = active & (rem2 <= 0)
-                if eos_id is not None:
-                    fin = fin | (active & (tk2 == eos_id))
-                out2 = out.at[t].set(jnp.where(active, nxt, -1))
-                return (t + 1, cache_out, p2, tk2, rem2, fin, rng_t,
-                        out2)
-
-            t, caches, pos, toks, remaining, done, rng, out = \
-                jax.lax.while_loop(
-                    cond, body,
-                    (jnp.int32(0), caches, pos, toks, remaining,
-                     jnp.zeros_like(active), rng, out0))
-            return caches, out, done, rng, t
-
-        fn = self.compile_tracker.wrap(
-            "megastep", jax.jit(megastep, donate_argnums=(2,)),
-            lambda args, _n=N: (args[4].shape[0], _n))
-        self._megastep_fns[key] = fn
-        while len(self._megastep_fns) > self.JIT_CACHE_LIMIT:
-            # bounded LRU: callers keep their own reference; only the
-            # memo (and, once they drop it, the executable) is let go
-            self._megastep_fns.pop(next(iter(self._megastep_fns)))
-        return fn
-
-    def paged_mixed_megastep_fn(self, max_ticks: int, eos_id=None,
-                                window: int = 1, depth: int = 0):
-        """jitted UNIVERSAL megastep: up to `max_ticks` fused ticks that
-        carry decode rows, MID-PREFILL chunk rows and on-device drafted
-        speculative chains in the same `jax.lax.while_loop` — the mixed
-        generalisation of `paged_megastep_fn` (flexflow_tpu.paged
-        megastep driver, mixed mode).
-
-        (params, pools, page_tables, seq, pos, pf_pos, pf_target, temps,
-         remaining, cap_rows, dec_active, pf_active, spec_mask, rng) ->
-            (new_pools, new_seq, out_tokens, out_counts, done, pf_fin,
-             new_rng, ticks)
-
-        `seq` is the device-resident (slots, Lbuf + 1) token ledger —
-        column Lbuf is a write-only trash column for masked scatters;
-        columns 0..pos hold each slot's committed tokens (prompt rows
-        preloaded by the host through pf_target - 1). Every per-tick
-        input a row needs is GATHERED from it: decode rows feed
-        seq[pos], prefill rows feed seq[pf_pos..pf_pos+take-1], and
-        greedy `spec_mask` rows draft a width-1 unigram chain (the D
-        tokens after the most recent earlier occurrence of seq[pos])
-        so verify -> accept -> commit rides the carry. Emitted tokens
-        scatter back into `seq`, so piece i+1 of a chunk and tick t+1
-        of a chain always read tick t's commits.
-
-        Per tick the row mix maps onto ONE ragged launch of window
-        Wl = max(window, depth + 1): q_lens per slot are `take` for a
-        live prefill row, depth+1 for a drafting row, 1 for plain
-        decode, 0 idle; `depths` is the chain arange and `anc` the
-        triangular chain relation, both constant. Acceptance is the
-        device argmax walk over the drafted prefix; every emitted token
-        is the greedy argmax continuation (or the shared-split sample
-        on temp > 0 rows), so token identity vs the one-tick path holds
-        by construction regardless of draft quality. Rejected-draft K/V
-        rows sit past the advanced write head: masked until the next
-        tick's depth+1 consecutive writes (starting exactly at the
-        first stale row) overwrite them before attention runs.
-
-        The loop stops BEFORE any tick it cannot run alone — a finished
-        slot (remaining exhausted / eos), a slot whose next rows would
-        cross `cap_rows` (page growth is host bookkeeping) — and stops
-        AFTER a tick in which a prefill chunk COMPLETES (`pf_fin`), so
-        the host publishes pages and flips the slot to decode before
-        re-dispatch (poolcheck's publication model stays intact: the
-        break IS the `chunk` reason). A completing chunk samples its
-        first token on device with the tick's shared rng split; plain
-        decode rows emit 1 token/tick and drafting rows up to depth+1
-        (`out_tokens` is (max_ticks, slots, depth+1), -1-padded, with
-        `out_counts` the per-tick emission counts). One
-        `jax.random.split` per tick keeps picks invariant in max_ticks.
-        Compiled once per (max_ticks, eos, window, depth, slots). The
-        pools are donated, as ragged_step_fn's are."""
-        key = (int(max_ticks), eos_id, int(window), int(depth), "mixed")
-        fn = self._megastep_fns.pop(key, None)
-        if fn is not None:
-            self._megastep_fns[key] = fn  # refresh LRU recency
-            return fn
-        from flexflow_tpu.serving import pick_tokens  # lazy: no cycle
-
-        N = int(max_ticks)
-        W = max(int(window), 1)
-        D = max(int(depth), 0)
-        Wl = max(W, D + 1)
-        E = D + 1  # emission capacity per slot per tick
-
-        def megastep(trainable, nontrainable, caches, page_tables, seq,
-                     pos, pf_pos, pf_target, temps, remaining, cap_rows,
-                     dec_active, pf_active, spec_mask, rng):
-            slots = pos.shape[0]
-            Lb = seq.shape[1] - 1  # column Lb is the trash column
-            bidx = jnp.arange(slots)[:, None]
-            win = jnp.arange(Wl, dtype=jnp.int32)
-            ej = jnp.arange(E, dtype=jnp.int32)
-            depths = jnp.broadcast_to(win[None, :], (slots, Wl))
-            anc = jnp.broadcast_to(
-                jnp.tril(jnp.ones((Wl, Wl), jnp.bool_))[None],
-                (slots, Wl, Wl))
-            spec_on = (dec_active & spec_mask) if D > 0 else \
-                jnp.zeros_like(dec_active)
-            out0 = jnp.full((N, slots, E), -1, jnp.int32)
-            cnt0 = jnp.zeros((N, slots), jnp.int32)
-
-            def cond(state):
-                t, _c, _s, p, _pf, _rem, done, pf_fin, _rng, _o, _n = \
-                    state
-                # a drafting row writes K/V at p..p+D, decode at p; a
-                # slot that cannot fit hands control back for growth
-                need = jnp.where(spec_on, p + D + 1, p + 1)
-                room = jnp.all(jnp.logical_or(
-                    jnp.logical_not(dec_active), need <= cap_rows))
-                return ((t < N) & jnp.logical_not(jnp.any(done))
-                        & jnp.logical_not(jnp.any(pf_fin)) & room)
-
-            def body(state):
-                t, caches_t, seq_t, p, pfp, rem, _d, _pf, rng_t, out, \
-                    cntb = state
-                pf_live = pf_active & (pfp < pf_target)
-                take = jnp.where(pf_live,
-                                 jnp.minimum(W, pf_target - pfp), 0)
-                q_lens = jnp.where(
-                    pf_live, take,
-                    jnp.where(spec_on, D + 1,
-                              jnp.where(dec_active, 1, 0))
-                ).astype(jnp.int32)
-                base = jnp.where(pf_live, pfp, p)
-                cols = jnp.clip(base[:, None] + win[None, :], 0, Lb)
-                ids = jnp.take_along_axis(seq_t, cols, axis=1)
-                if D > 0:
-                    # width-1 unigram draft: chain after the most
-                    # recent EARLIER occurrence of the last committed
-                    # token, zeros when no match / past the head
-                    idxs = jnp.arange(seq_t.shape[1], dtype=jnp.int32)
-                    last = jnp.take_along_axis(
-                        seq_t, jnp.clip(p, 0, Lb)[:, None], axis=1)
-                    hit = (seq_t == last) & (idxs[None, :] < p[:, None])
-                    j = jnp.max(jnp.where(hit, idxs[None, :], -1),
-                                axis=1)
-                    dcols = (j[:, None] + 1
-                             + jnp.arange(D, dtype=jnp.int32)[None, :])
-                    dvalid = (j[:, None] >= 0) & (dcols <= p[:, None])
-                    draft = jnp.where(
-                        dvalid,
-                        jnp.take_along_axis(
-                            seq_t, jnp.clip(dcols, 0, Lb), axis=1), 0)
-                    chain = jnp.concatenate(
-                        [last, draft,
-                         jnp.zeros((slots, Wl - E), jnp.int32)], axis=1)
-                    ids = jnp.where(spec_on[:, None], chain, ids)
-                cache_out = {}
-                probs, _, _ = self.run_forward(
-                    trainable, nontrainable, (ids,), training=False,
-                    rng=jax.random.key(0), kv_caches=caches_t,
-                    cache_position=base, cache_out=cache_out,
-                    page_tables=page_tables,
-                    ragged=(q_lens, depths, anc),
-                )
-                rng_t, sub = jax.random.split(rng_t)
-                lastrow = jnp.clip(q_lens - 1, 0, Wl - 1)
-                probs_last = jnp.take_along_axis(
-                    probs, lastrow[:, None, None], axis=1)[:, 0, :]
-                picked = pick_tokens(probs_last, temps, sub)
-                completing = pf_live & (pfp + take >= pf_target)
-                emitting = dec_active | completing
-                if D > 0:
-                    preds = jnp.argmax(probs[:, :E, :],
-                                       axis=-1).astype(jnp.int32)
-                    match = (draft == preds[:, :D]) & spec_on[:, None]
-                    acc = jnp.sum(jnp.cumprod(
-                        match.astype(jnp.int32), axis=1), axis=1)
-                    base_cnt = jnp.where(
-                        spec_on, acc + 1,
-                        jnp.where(emitting, 1, 0))
-                    emit = jnp.where(
-                        spec_on[:, None], preds,
-                        jnp.where(ej[None, :] == 0,
-                                  picked[:, None], -1))
-                else:
-                    base_cnt = jnp.where(emitting, 1, 0)
-                    emit = picked[:, None]
-                cnt = jnp.minimum(base_cnt, jnp.maximum(rem, 0))
-                valid = ej[None, :] < cnt[:, None]
-                if eos_id is not None:
-                    is_eos = valid & (emit == eos_id)
-                    first = jnp.min(
-                        jnp.where(is_eos, ej[None, :], E), axis=1)
-                    cnt = jnp.where(first < E,
-                                    jnp.minimum(cnt, first + 1), cnt)
-                    valid = ej[None, :] < cnt[:, None]
-                oldc = jnp.where(completing, pf_target, p + 1)
-                scols = jnp.where(
-                    valid,
-                    jnp.clip(oldc[:, None] + ej[None, :], 0, Lb), Lb)
-                seq2 = seq_t.at[bidx, scols].set(emit)
-                p2 = jnp.where(cnt > 0, oldc + cnt - 1, p)
-                pfp2 = jnp.where(pf_live, pfp + take, pfp)
-                rem2 = jnp.where(emitting, rem - cnt, rem)
-                fin = emitting & (cnt > 0) & (rem2 <= 0)
-                if eos_id is not None:
-                    fin = fin | (first < E)
-                out2 = out.at[t].set(jnp.where(valid, emit, -1))
-                cnt2 = cntb.at[t].set(cnt)
-                return (t + 1, cache_out, seq2, p2, pfp2, rem2, fin,
-                        completing, rng_t, out2, cnt2)
-
-            state = jax.lax.while_loop(
-                cond, body,
-                (jnp.int32(0), caches, seq, pos, pf_pos, remaining,
-                 jnp.zeros_like(dec_active), jnp.zeros_like(pf_active),
-                 rng, out0, cnt0))
-            t, caches, seq, pos, pf_pos, remaining, done, pf_fin, \
-                rng, out, cnt = state
-            return caches, seq, out, cnt, done, pf_fin, rng, t
-
-        fn = self.compile_tracker.wrap(
-            "megastep_mixed", jax.jit(megastep, donate_argnums=(2,)),
-            lambda args, _n=N, _w=Wl: (args[5].shape[0], _n, _w))
-        self._megastep_fns[key] = fn
-        while len(self._megastep_fns) > self.JIT_CACHE_LIMIT:
-            self._megastep_fns.pop(next(iter(self._megastep_fns)))
-        return fn
-
     def paged_commit_fn(self):
         """jitted (pools, page_tables, src, dst) -> pools: copy the
         accepted tree path's K/V rows onto the contiguous committed
@@ -1418,16 +1119,14 @@ class Executor:
 
     def jit_cache_entries(self) -> int:
         """Live jitted-callable memos this executor holds (the
-        ff_jit_cache_entries gauge): the single-slot factories plus the
-        LRU-bounded per-(max_ticks, eos_id) megastep memos."""
+        ff_jit_cache_entries gauge): the single-slot factories."""
         singles = (self._train_step, self._eval_step, self._forward,
                    self._decode_fn, self._ragged_step_fn,
                    self._paged_commit_fn)
-        return (sum(1 for f in singles if f is not None)
-                + len(self._megastep_fns))
+        return sum(1 for f in singles if f is not None)
 
-    def warm_launch_shapes(self, catalog, *, params, eos_id=None,
-                           on_probs=None, newest=None) -> Dict:
+    def warm_launch_shapes(self, catalog, *, params, on_probs=None,
+                           newest=None) -> Dict:
         """Pre-compile every launch shape in a shapecheck catalog
         (analysis.shapecheck.enumerate_catalog) so first-request TTFT
         stops paying compile cost and steady-state serving provably
@@ -1440,9 +1139,7 @@ class Executor:
         typed rng key — against throwaway zero pools built from the
         catalog's config (zeroed page tables point every row at the null
         page, so the warm writes touch nothing a request will read; the
-        dummy pools are garbage the moment this returns). The megastep
-        warms with active slots whose page capacity is exhausted, so its
-        while_loop compiles fully but executes zero iterations.
+        dummy pools are garbage the moment this returns).
 
         ONE pool is threaded through every call: the serving programs
         consume the pool they are given (donate_argnums) and return it
@@ -1456,22 +1153,10 @@ class Executor:
         (XLA may decline an alias and copy all the same), and the serving
         tick puts the pair on its traced launches.
 
-        The jit cache keys on each argument's COMMITTEDNESS as well as
-        its aval (a jit output is committed to its device; a fresh
-        `jnp.asarray` upload is not), so the arguments that do change
-        committedness while serving warm both ways: the rng key turns
-        committed once a megastep's output key re-enters the host split
-        chain, and the mixed megastep's token ledger is its own output
-        between dispatches and a host upload after an admission.
-        Per-tick descriptor uploads stay uncommitted forever and warm
-        that way. The committed variants are real launch OUTPUTS (the
-        megastep's output key and ledger) so their sharding matches what
-        the serve loop feeds back.
-
         Returns {"warmed_shapes", "vocab", "probs_dtype", "probs_ref",
-        "rng_ref", "pool_alias"} — the serving layer warms its (batch,
-        vocab) sampling program (the one entry the executor does not own)
-        from slices of probs_ref and splits of rng_ref. `on_probs`, if
+        "pool_alias"} — the serving layer warms its (batch, vocab)
+        sampling program (the one entry the executor does not own) from
+        slices of probs_ref. `on_probs`, if
         given, is called with every ragged shape's (B, W, V) output, so
         the caller can warm what it runs on a launch's probs at that
         shape. `newest`, a paged server's device vector of its slots'
@@ -1488,7 +1173,7 @@ class Executor:
         tr, ntr = params
         slots = int(cfg["slots"])
         warmed = 0
-        probs = probs_ref = rng_ref = caches_c = None
+        probs = probs_ref = caches_c = None
         pool_alias: Dict[Tuple[int, int], Tuple[int, int]] = {}
         if cfg.get("paged", True):
             from flexflow_tpu.paged.quant import resolve_kv_dtype
@@ -1569,50 +1254,6 @@ class Executor:
                 if probs_ref is None or B == slots:
                     probs_ref = probs
                 warmed += 1
-            for S, N in entries.get(  # fflint: host-ok (one-time warmup)
-                    "megastep", {}).get("shapes", ()):
-                fn = self.paged_megastep_fn(int(N), eos_id)
-                z = jnp.asarray(np.zeros((int(S),), np.int32))
-                args = (jnp.zeros((int(S), cols), jnp.int32), z, z,
-                        jnp.asarray(np.zeros((int(S),), np.float32)),
-                        z, z, jnp.asarray(np.ones((int(S),), np.bool_)))
-                # its rng is host-chain (uncommitted) on the first
-                # dispatch and its own output key (committed) after
-                out = fn(tr, ntr, caches, *args, jax.random.key(0))
-                rng_ref = out[3]
-                caches = fn(tr, ntr, out[0], *args, rng_ref)[0]
-                warmed += 1
-            for S, NT, _WL in entries.get(  # fflint: host-ok (one-time warmup)
-                    "megastep_mixed", {}).get("shapes", ()):
-                # window/depth come from the config echo — the launch
-                # window in the shape tuple is their derived max, kept
-                # in the catalog for the soundness diff only
-                wnd = min(int(cfg.get("window_rows") or 1),
-                          int(cfg.get("prefill_chunk") or 1))
-                dep = int(cfg.get("spec_depth") or 0)
-                fnm = self.paged_mixed_megastep_fn(
-                    int(NT), eos_id, window=wnd, depth=dep)
-                S = int(S)
-                z = jnp.asarray(np.zeros((S,), np.int32))
-                seqz = jnp.asarray(np.zeros(
-                    (S, cols * page_size + 1), np.int32))
-                bT = jnp.asarray(np.ones((S,), np.bool_))
-                bF = jnp.asarray(np.zeros((S,), np.bool_))
-                margs = (jnp.zeros((S, cols), jnp.int32), seqz, z, z, z,
-                         jnp.asarray(np.zeros((S,), np.float32)), z, z,
-                         bT, bF, bF)
-                # dec_active with zero cap_rows: the while_loop compiles
-                # fully but executes zero iterations (same trick as the
-                # decode megastep warm above)
-                out = fnm(tr, ntr, caches, *margs, jax.random.key(0))
-                rng_ref = out[6]
-                out = fnm(tr, ntr, out[0], *margs, rng_ref)
-                # steady state carries the previous dispatch's seq
-                # ledger (committed) forward; admission dirties it back
-                # to a host upload — warm both combos
-                margs_c = margs[:1] + (out[1],) + margs[2:]
-                caches = fnm(tr, ntr, out[0], *margs_c, rng_ref)[0]
-                warmed += 1
             commit = (self.paged_commit_fn()
                       if "paged_commit" in entries else None)
             for S, C in entries.get(  # fflint: host-ok (one-time warmup)
@@ -1651,10 +1292,8 @@ class Executor:
                             else None),
             # real launch outputs, for the serving layer's pick warm:
             # slicing probs_ref reproduces the exact committedness (and
-            # sharding) of the serve loop's pick inputs, and splitting
-            # rng_ref reproduces the post-megastep committed key chain
+            # sharding) of the serve loop's pick inputs
             "probs_ref": probs_ref,
-            "rng_ref": rng_ref,
             # ragged launch shape (B, W) -> (pool leaves passed, leaves
             # that came back in the buffer they went in with)
             "pool_alias": pool_alias,

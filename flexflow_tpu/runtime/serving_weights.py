@@ -113,9 +113,9 @@ def _qualifying(jaxpr, want) -> set:
 def _step_jaxprs(executor, params):
     """The jaxpr of every step a server of this graph launches, traced
     against abstract copies of `params` at a one-row shape: the paged
-    ragged step (which the megasteps loop over and a speculative server
-    verifies with) and the dense cached step. Each one's leading invars
-    are the leaves of `params`, in tree order."""
+    ragged step (which a speculative server verifies with, too) and the
+    dense cached step. Each one's leading invars are the leaves of
+    `params`, in tree order."""
     from flexflow_tpu.ffconst import OpType
 
     if len(executor.input_nodes) != 1:

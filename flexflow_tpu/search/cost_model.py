@@ -820,23 +820,16 @@ def graph_cost(graph: Graph, strategy: Dict[str, ShardingView],
 # Serving-tick pricing (search/servesearch.py). The training-side model
 # above prices one train_step; serving strategies are judged on the
 # DECODE TICK instead: how many live rows a launch carries, how much of
-# the launch is padding, how many ticks fuse into one dispatch, and how
-# often the host is paid. The per-token compute rate comes from the same
-# graph pricing (eventsim.step_seconds over the compiled forward), so
-# tick prices inherit every sharding/mesh decision the step price saw.
+# the launch is padding, and how often the host is paid. The per-token
+# compute rate comes from the same graph pricing (eventsim.step_seconds
+# over the compiled forward), so tick prices inherit every sharding/mesh
+# decision the step price saw.
 
 # Host-side cost of ONE dispatch: argument marshalling, the jitted-call
 # bridge, and the device->host token readback the scheduler blocks on.
-# This is the constant the decode megastep amortizes (N fused ticks pay
-# it once); `fftrace calibrate` scale factors absorb the machine-specific
-# truth on top of this default.
+# `fftrace calibrate` scale factors absorb the machine-specific truth on
+# top of this default.
 HOST_DISPATCH_SECONDS = 5e-5
-
-# Fraction of the per-dispatch host cost that survives overlap_dispatch:
-# the fence (device_get of the token buffer) and the bookkeeping replay
-# stay on the critical path, only the admission/metrics work between
-# dispatch and fence hides in the device's shadow.
-OVERLAP_RESIDUAL = 0.35
 
 
 @dataclasses.dataclass
@@ -882,16 +875,14 @@ class TickPricer:
         return float(self.tick_scale(phase, max(int(round(batch)), 1),
                                      int(chunk), max(int(round(width)), 1)))
 
-    def decode_dispatch(self, live_rows: float, padded_rows: float = 0.0,
-                        megastep: float = 1.0) -> float:
-        """Seconds for ONE decode dispatch fusing `megastep` ticks over a
-        launch of live_rows + padded_rows. Compute scales with rows and
-        fused ticks; the host is paid once per DISPATCH — which is the
-        whole megastep story: N fused ticks amortize host_dispatch_s to
-        host_dispatch_s / N per tick."""
+    def decode_dispatch(self, live_rows: float,
+                        padded_rows: float = 0.0) -> float:
+        """Seconds for ONE decode dispatch over a launch of live_rows +
+        padded_rows. Compute scales with rows; the host is paid once per
+        DISPATCH."""
         rows = max(live_rows, 0.0) + max(padded_rows, 0.0) * self.pad_row_cost
-        comp = (self.token_seconds * max(rows, 1.0) * max(megastep, 1.0)
-                * self._scale("decode", live_rows, width=megastep))
+        comp = (self.token_seconds * max(rows, 1.0)
+                * self._scale("decode", live_rows))
         return comp + self.host_dispatch_s
 
     def verify_dispatch(self, live_rows: float, tree_nodes: int,
@@ -920,28 +911,6 @@ class TickPricer:
         comp = (self.token_seconds * rows
                 * self._scale("prefill", batch, chunk=int(chunk_tokens)))
         return comp + self.host_dispatch_s
-
-    def mixed_dispatch(self, live_rows: float, chunk_tokens: int = 0,
-                       tree_nodes: int = 0, padded_rows: float = 0.0,
-                       megastep: float = 1.0,
-                       overlap: bool = False) -> float:
-        """Seconds for ONE universal-fused dispatch of `megastep` MIXED
-        ticks: every fused tick launches the live decode rows (each
-        `tree_nodes` wide when a drafted spec chain rides the row, else
-        1), the in-flight prefill chunk's `chunk_tokens` rows, and the
-        padding. The host is paid once per DISPATCH — the universal
-        megastep's whole point is that mixed traffic amortizes it too —
-        and `overlap` further discounts it to OVERLAP_RESIDUAL because
-        the admission/metrics slice of the host work runs in the shadow
-        of the in-flight device computation."""
-        width = max(int(tree_nodes), 1)
-        rows = (max(live_rows, 0.0) * width + max(int(chunk_tokens), 0)
-                + max(padded_rows, 0.0) * self.pad_row_cost)
-        comp = (self.token_seconds * max(rows, 1.0) * max(megastep, 1.0)
-                * self._scale("decode", live_rows, chunk=int(chunk_tokens),
-                              width=max(megastep, 1.0)))
-        host = self.host_dispatch_s * (OVERLAP_RESIDUAL if overlap else 1.0)
-        return comp + host
 
     def fetch_seconds(self, page_bytes: float, pages: int = 1) -> float:
         """Seconds to move `pages` spilled KV pages (each `page_bytes`

@@ -3,8 +3,8 @@ decode tick.
 
 The repo's thesis (PAPER.md) is that an MCMC search over a simulator
 beats hand-rolled parallelism choices — but until now every serving knob
-(`page_size`, `prefill_chunk`, spec tree width/depth, `megastep_ticks`,
-pool size, mesh layout) was hand-picked. This module
+(`page_size`, `prefill_chunk`, spec tree width/depth, pool size, mesh
+layout) was hand-picked. This module
 closes that gap:
 
   1. a `ServeStrategy` (flexflow_tpu/serve_strategy.py, the serving
@@ -15,8 +15,8 @@ closes that gap:
      traffic profile (search/traffic.py): ragged launch shapes and
      padding waste per the PR 10 packing, chunked-prefill TTFT, the
      spec tree's expected accepted tokens/step
-     (SpecConfig.expected_tokens_per_step), megastep host-roundtrip
-     amortization (cost_model.TickPricer), page size vs pool occupancy,
+     (SpecConfig.expected_tokens_per_step), the host's cost a dispatch
+     (cost_model.TickPricer), page size vs pool occupancy,
      and the KV pool's HBM bill (cost_model.kv_cache_token_bytes) —
      with the per-token compute rate coming from the SAME step pricing
      the sharding search uses (eventsim.step_seconds), per candidate
@@ -56,7 +56,6 @@ from flexflow_tpu.search.cost_model import (
 )
 from flexflow_tpu.search.table import StrategyTable, coordinate_descent
 from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
-from flexflow_tpu.spec.config import SpecConfig
 
 logger = logging.getLogger(__name__)
 
@@ -65,7 +64,7 @@ logger = logging.getLogger(__name__)
 CALIBRATION_MAX_AGE_S = 7 * 24 * 3600
 
 # Objective assigned to knob combinations serve_generation would reject
-# (spec + megastep, oversized pages, ...): finite so the anneal's accept
+# (half-set speculation, oversized pages, ...): finite so the anneal's accept
 # rule stays well-defined, large enough that no walk settles there.
 INVALID_OBJECTIVE = 1e9
 
@@ -314,70 +313,21 @@ class ServePricer:
         w = min(PREFILL_WINDOW_ROWS, chunk)
         pad_pre = -(-chunk // w) * w - chunk
 
-        # -- decode dispatch: megastep fusion or spec verify ------------
+        # -- decode dispatch: one token a row, or spec verify -----------
         spec = s.spec_config()
-        if s.megastep_mixed:
-            # universal megastep: chunk rows and on-device drafted spec
-            # chains ride the SAME fused while_loop dispatch, so mixed
-            # ticks amortize the host exactly like pure-decode ones
-            if spec is not None:
-                # the device drafts a width-1 unigram chain per tick
-                accepted = SpecConfig(
-                    width=1, depth=spec.depth).expected_tokens_per_step(
-                        self.acceptance_rate)
-                nodes = spec.depth + 1
-            else:
-                accepted = 1.0
-                nodes = 1
-            # a fused run breaks when ANY live slot finishes
-            # (~accepted/new_t per tick each), crosses a page boundary
-            # (~1/page each), or completes its prefill chunk run (the
-            # `chunk`/`verify` break reasons fold into the same rate)
-            p_break = live * (1.0 / page + accepted / new_t)
-            fused = 1.0
-            if s.megastep_ticks > 1:
-                fused = min(float(s.megastep_ticks),
-                            max(1.0, 1.0 / max(p_break, 1e-9)))
-            t_disp = pricer.mixed_dispatch(
-                live, tree_nodes=nodes, padded_rows=padded,
-                megastep=fused, overlap=s.overlap_dispatch)
-            tokens_per_dispatch = fused * accepted
-            # a tick with a chunk in flight rides the SAME fused launch
-            # — the host is paid once per RUN, not once per chunk tick
-            t_mixed = pricer.mixed_dispatch(
-                live, chunk_tokens=chunk, tree_nodes=nodes,
-                padded_rows=padded + pad_pre, megastep=fused,
-                overlap=s.overlap_dispatch) / fused
-            t_pre = t_mixed
-        elif spec is not None:
+        if spec is not None:
             accepted = spec.expected_tokens_per_step(self.acceptance_rate)
             t_disp = pricer.verify_dispatch(live, spec.max_nodes,
                                             padded_rows=padded)
-            tokens_per_dispatch = accepted
-            fused = 1.0
-            t_tick1 = t_disp
         else:
             accepted = 1.0
-            # a fused run breaks when ANY live slot finishes (~1/new_t
-            # per tick each) or crosses a page boundary (~1/page each)
-            p_break = live * (1.0 / page + 1.0 / new_t)
-            fused = 1.0
-            if s.megastep_ticks > 1:
-                fused = min(float(s.megastep_ticks),
-                            max(1.0, 1.0 / max(p_break, 1e-9)))
-            t_disp = pricer.decode_dispatch(live, padded_rows=padded,
-                                            megastep=fused)
-            tokens_per_dispatch = fused
-            t_tick1 = pricer.decode_dispatch(live, padded_rows=padded,
-                                             megastep=1.0)
+            t_disp = pricer.decode_dispatch(live, padded_rows=padded)
 
         # -- chunked prefill: TTFT -------------------------------------
-        if not s.megastep_mixed:
-            t_pre = pricer.prefill_tick(chunk, padded_rows=pad_pre)
-            # a tick with a chunk in flight runs the prefill launch AND
-            # the one-tick decode for everyone else (megasteps never
-            # fire then)
-            t_mixed = t_pre + t_tick1
+        t_pre = pricer.prefill_tick(chunk, padded_rows=pad_pre)
+        # a tick with a chunk in flight runs the prefill launch AND the
+        # decode for everyone else
+        t_mixed = t_pre + t_disp
         chunks_mean = max(math.ceil(uncached_mean / chunk), 1)
         chunks_p95 = max(math.ceil(uncached_p95 / chunk), 1)
         ttft = chunks_p95 * t_mixed + self.host_dispatch_s
@@ -395,7 +345,7 @@ class ServePricer:
 
         # -- request lifetime + throughput ------------------------------
         t_request = (chunks_mean * t_mixed
-                     + (new_t / tokens_per_dispatch) * t_disp)
+                     + (new_t / accepted) * t_disp)
         if occupancy > 0.9:
             # pool saturation: preemption + prefix recompute stalls
             pressure = 1.0 + 4.0 * (occupancy - 0.9)
@@ -417,8 +367,6 @@ class ServePricer:
             "padding_waste_ratio": padded / max(launch_rows, 1),
             "prefill_pad_rows": float(pad_pre),
             "expected_accepted_per_step": accepted,
-            "expected_fused_ticks": fused,
-            "host_roundtrips_per_token": 1.0 / (tokens_per_dispatch * live),
             "decode_dispatch_s": t_disp,
             "prefill_tick_s": t_pre,
             "step_s": lay.step_s,
@@ -441,18 +389,14 @@ class _Knob:
 
 def default_space(*, max_len: int) -> Dict[str, List]:
     """The searched knob values. `spec` is a joint (width, depth) knob
-    so half-set speculation can never be proposed, and `fuse` a joint
-    (megastep_mixed, overlap_dispatch) knob so overlap-without-mixed
-    can never be proposed; layout values are appended by the search
-    when candidate meshes are given."""
+    so half-set speculation can never be proposed; layout values are
+    appended by the search when candidate meshes are given."""
     return {
         "page_size": [p for p in (8, 16, 32, 64, 128) if p <= max_len]
         or [max_len],
         "prefill_chunk": [c for c in (16, 32, 64, 128, 256) if c <= max_len]
         or [max_len],
         "spec": [(0, 0), (2, 2), (2, 4), (4, 4)],
-        "megastep_ticks": [1, 2, 4, 8, 16],
-        "fuse": [(False, False), (True, False), (True, True)],
         "pool_fraction": [1.0, 0.75, 0.5, 0.25],
         "kv_dtype": ["auto", "int8"],
         "host_tier_pages": [0, 256, 1024],
@@ -741,8 +685,6 @@ def search_serve_strategy(
         "page_size": default.page_size,
         "prefill_chunk": default.prefill_chunk,
         "spec": (default.spec_width, default.spec_depth),
-        "megastep_ticks": default.megastep_ticks,
-        "fuse": (default.megastep_mixed, default.overlap_dispatch),
         "pool_fraction": default.pool_fraction,
         "kv_dtype": default.kv_dtype,
         "host_tier_pages": default.host_tier_pages,
@@ -752,8 +694,8 @@ def search_serve_strategy(
         if dval not in vals:
             vals.insert(0, dval)
     knobs = [(name, values[name]) for name in
-             ("page_size", "prefill_chunk", "spec", "megastep_ticks",
-              "fuse", "pool_fraction", "kv_dtype", "host_tier_pages")]
+             ("page_size", "prefill_chunk", "spec", "pool_fraction",
+              "kv_dtype", "host_tier_pages")]
     if len(priced) > 1:
         knobs.append(("mesh", [lay.mesh_key for lay in priced]))
     table = _knob_table(knobs)
@@ -764,10 +706,7 @@ def search_serve_strategy(
         kv = {name: table.views[i][k]
               for i, (name, k) in enumerate(zip(names, assign))}
         w, d = kv.pop("spec")
-        mixed, overlap = kv.pop("fuse")
         return ServeStrategy(spec_width=w, spec_depth=d,
-                             megastep_mixed=mixed,
-                             overlap_dispatch=overlap,
                              mesh=kv.pop("mesh", default.mesh), **kv)
 
     cache: Dict[Tuple[int, ...], Tuple[float, Optional[Dict]]] = {}

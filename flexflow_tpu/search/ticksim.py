@@ -20,13 +20,8 @@ paged scheduler's tick loop, pricing each dispatch with the SAME
     ride that launch, a q_len 1 item each in its window, so an
     iteration with both kinds of work is ONE dispatch over the packed
     rows (the speculative verify alone stays a second launch);
-  * decode / megastep fusion (iterations with no chunk) — one row per
-    slot (idle rows padded), a
-    fused run breaking at the first finish, page boundary, or the
-    `megastep_ticks` limit, priced with `TickPricer.decode_dispatch`;
-    with `megastep_mixed` the in-flight prefill chunks ride the same
-    fused dispatch (`TickPricer.mixed_dispatch`) and `overlap_dispatch`
-    discounts the host-side admission work that hides behind it;
+  * decode (iterations with no chunk) — one row per slot (idle rows
+    padded), priced with `TickPricer.decode_dispatch`;
   * speculative verify — per-tick accepted-token draws from the
     acceptance rate (a seeded chain through the draft depth), priced
     with `TickPricer.verify_dispatch`;
@@ -299,9 +294,6 @@ class _SimRun:
         kw = strategy.to_server_kwargs(slots=slots, max_len=max_len)
         self.page = int(kw["page_size"])
         self.chunk = int(kw["prefill_chunk"])
-        self.megastep = int(kw["megastep_ticks"])
-        self.mixed = bool(kw.get("megastep_mixed"))
-        self.overlap = bool(kw.get("overlap_dispatch"))
         self.spec = kw["speculate"]
         self.slots = int(slots)
         self.max_len = int(max_len)
@@ -512,7 +504,7 @@ class _SimRun:
         granted = self._grant(dec)
         slots = [s for s in slots if self.active[s] is not None]
         if not slots:
-            return self._decode_tick(granted, mixed=True)
+            return self._decode_tick(granted)
         budget = self.chunk
         rot = self.prefill_rr % len(slots)
         self.prefill_rr += 1
@@ -557,7 +549,7 @@ class _SimRun:
                    if self.active[s] is not None and self._grow(s)]
         return [s for s in granted if self.active[s] is not None]
 
-    def _decode_tick(self, dec: List[int], mixed: bool) -> float:
+    def _decode_tick(self, dec: List[int]) -> float:
         granted = self._grant(dec)
         if not granted:
             return 0.0
@@ -576,80 +568,10 @@ class _SimRun:
                     d += 1
                 req.pos = min(req.new_tokens, req.pos + accepted)
             return cost
-        fused = 1
-        if self.megastep > 1 and not mixed:
-            fused = self.megastep
-            for s in granted:
-                req = self.active[s]
-                fused = min(fused, req.new_tokens - req.pos)
-                held = req.private_pages + req.attached_pages
-                fused = min(fused, max(1, held * self.page - req.seq_len))
-        cost = self.tick.decode_dispatch(len(granted), padded_rows=padded,
-                                         megastep=float(fused))
+        cost = self.tick.decode_dispatch(len(granted), padded_rows=padded)
         for s in granted:
             req = self.active[s]
-            req.pos = min(req.new_tokens, req.pos + fused)
-        return cost
-
-    def _mixed_tick(self, pre: List[int], dec: List[int]) -> float:
-        """One universal-fused dispatch (megastep_mixed): decode rows —
-        each `depth+1` wide when an on-device spec chain rides it — and
-        the in-flight prefill chunks advance together inside one
-        while_loop run, tick by tick until a slot finishes, crosses a
-        page boundary, or completes its prefill (the `chunk` break:
-        page publication is host work). The whole run is priced as ONE
-        TickPricer.mixed_dispatch — the host paid once, discounted
-        further when overlap_dispatch hides the admission work in the
-        device's shadow."""
-        live = [s for s in dec if self.active[s].pos
-                < self.active[s].new_tokens]
-        granted = [s for s in live
-                   if self.active[s] is not None and self._grow(s)]
-        granted = [s for s in granted if self.active[s] is not None]
-        pre = [s for s in pre if self.active[s] is not None]
-        if not granted and not pre:
-            return 0.0
-        depth = self.spec.depth if self.spec is not None else 0
-        nodes = depth + 1 if self.spec is not None else 1
-        w = min(self.window, self.chunk)
-        ticks = 0
-        chunk_rows = 0
-        completed: List[int] = []
-        brk = False
-        while ticks < max(self.megastep, 1) and not brk:
-            ticks += 1
-            for s in pre:
-                req = self.active[s]
-                take = min(w, req.prefill_target - req.prefill_pos)
-                chunk_rows += take
-                req.prefill_pos += take
-                if req.prefill_pos >= req.prefill_target:
-                    req.pos = max(req.pos, 1)  # device samples token one
-                    completed.append(s)
-                    brk = True  # `chunk` break
-            for s in granted:
-                req = self.active[s]
-                emit = 1
-                d = 0
-                while (d < depth
-                       and self.rs.random_sample() < self.acceptance):
-                    emit += 1
-                    d += 1
-                req.pos = min(req.new_tokens, req.pos + emit)
-                if req.pos >= req.new_tokens:
-                    brk = True  # finish break
-                held = req.private_pages + req.attached_pages
-                if req.seq_len + nodes > held * self.page:
-                    brk = True  # page (or spec `verify`) break
-        padded = self.slots - len(granted) - len(pre)
-        cost = self.tick.mixed_dispatch(
-            len(granted), chunk_tokens=chunk_rows / ticks,
-            tree_nodes=nodes, padded_rows=max(padded, 0),
-            megastep=float(ticks), overlap=self.overlap)
-        for s in completed:
-            req = self.active[s]
-            if req is not None and req.first_token_s is None:
-                req.first_token_s = self.t + cost
+            req.pos = min(req.new_tokens, req.pos + 1)
         return cost
 
     def _finish(self) -> None:
@@ -694,14 +616,12 @@ class _SimRun:
             # the prefills they feed — the transfer is simulated time
             cost = self._pending_fetch_s
             self._pending_fetch_s = 0.0
-            if self.mixed:
-                cost += self._mixed_tick(pre, dec)
-            elif pre and self.spec is None:
+            if pre and self.spec is None:
                 cost += self._prefill_tick(pre, dec)
             else:
                 if pre:
                     cost += self._prefill_tick(pre)
-                cost += self._decode_tick(dec, mixed=bool(pre))
+                cost += self._decode_tick(dec)
             if cost <= 0.0:
                 # every live slot stalled: charge one idle host tick so
                 # time always advances

@@ -3,7 +3,7 @@ shapes serving strategies are judged against.
 
 A serving strategy is only better or worse *for a workload*: chunked
 prefill pays off on long prompts, the prefix cache on shared system
-prompts, megasteps on decode-heavy streams. This module gives those
+prompts, speculation on decode-heavy streams. This module gives those
 workloads names, so the serving-strategy search (search/servesearch.py)
 and the tests score strategies against the SAME fixtures:
 `shared-system-prompt` and `mixed-length` instead of inline ad-hoc
@@ -341,7 +341,7 @@ def long_context_summarization_profile(page_size: int = 8,
     """Production shape #1 (ROADMAP): summarization — prompts several
     pages deep (3..5 pages), short generated summaries, no shared
     prefix. Prefill-dominated: chunked prefill and ragged packing earn
-    their keep, megasteps matter less."""
+    their keep."""
     P = int(page_size)
     return TrafficProfile(
         name="long-context-summarization",

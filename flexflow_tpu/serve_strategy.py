@@ -50,9 +50,6 @@ class ServeStrategy:
     prefill_chunk: int = 64
     spec_width: int = 0
     spec_depth: int = 0
-    megastep_ticks: int = 1
-    megastep_mixed: bool = False
-    overlap_dispatch: bool = False
     pool_fraction: float = 1.0
     kv_dtype: str = "auto"
     host_tier_pages: int = 0
@@ -66,9 +63,6 @@ class ServeStrategy:
         if self.prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-        if self.megastep_ticks < 1:
-            raise ValueError(
-                f"megastep_ticks must be >= 1, got {self.megastep_ticks}")
         if not (0.0 < self.pool_fraction <= 1.0):
             raise ValueError(
                 f"pool_fraction must be in (0, 1], got {self.pool_fraction}")
@@ -79,16 +73,6 @@ class ServeStrategy:
             raise ValueError(
                 f"spec_width/spec_depth must both be 0 or both >= 1, got "
                 f"{self.spec_width}x{self.spec_depth}")
-        if self.overlap_dispatch and not self.megastep_mixed:
-            raise ValueError(
-                "overlap_dispatch overlaps host work with the in-flight "
-                "MIXED megastep dispatch; it requires megastep_mixed")
-        if (self.spec_width >= 1 and self.megastep_ticks > 1
-                and not self.megastep_mixed):
-            raise ValueError(
-                "speculative decoding and megastep_ticks > 1 are mutually "
-                "exclusive (the fused decode loop cannot host verify "
-                "ticks) — unless megastep_mixed fuses verify on device")
         # typo'd dtypes fail HERE, not as a silently-fp32 served pool
         from flexflow_tpu.paged.quant import kv_dtype_info
 
@@ -122,9 +106,6 @@ class ServeStrategy:
             "paged": True,
             "page_size": self.page_size,
             "prefill_chunk": self.prefill_chunk,
-            "megastep_ticks": self.megastep_ticks,
-            "megastep_mixed": self.megastep_mixed,
-            "overlap_dispatch": self.overlap_dispatch,
             "num_pages": num_pages,
             "speculate": self.spec_config(),
             "kv_dtype": self.kv_dtype,
@@ -137,15 +118,9 @@ class ServeStrategy:
         mesh = ",".join(f"{a}={s}" for a, s in self.mesh) or "compiled mesh"
         tier = (f"tier {self.host_tier_pages}p"
                 if self.host_tier_pages else "tier off")
-        mega = f"megastep {self.megastep_ticks}"
-        if self.megastep_mixed:
-            mega += " mixed"
-        if self.overlap_dispatch:
-            mega += "+overlap"
         return (f"page {self.page_size} + chunk {self.prefill_chunk} + "
-                f"{mega} + {spec} + "
-                f"pool {self.pool_fraction:g} + kv {self.kv_dtype} + "
-                f"{tier} + {mesh}")
+                f"{spec} + pool {self.pool_fraction:g} + "
+                f"kv {self.kv_dtype} + {tier} + {mesh}")
 
     def to_json(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -161,6 +136,15 @@ class ServeStrategy:
             raise ValueError(
                 "stored strategy has \"ragged_pack\": false, a prefill "
                 "packing that no longer exists; serve it without the key")
+        # ... or a device-resident loop: at its default the key is
+        # dropped, set it asked for a loop that is gone
+        for key, default in (("megastep_ticks", 1), ("megastep_mixed", False),
+                             ("overlap_dispatch", False)):
+            if kw.pop(key, default) != default:
+                raise ValueError(
+                    f"stored strategy has \"{key}\": {json.dumps(d[key])}, "
+                    "a device-resident loop that no longer exists; serve it "
+                    "without the key")
         kw["mesh"] = tuple((str(a), int(s)) for a, s in kw.get("mesh", ()))
         return cls(**kw)
 

@@ -34,12 +34,9 @@ from flexflow_tpu import obs
 
 def pick_tokens(probs_last, temps, rng):
     """Sample one token per row: greedy where temp<=0, else temperature-
-    scaled categorical. Pure jnp on its arguments — safe to trace both as
-    the host-side jitted `_pick` AND inside a `jax.lax.while_loop` carry
-    (the decode megastep), where the rng advances by the SAME
-    `jax.random.split` chain the host loop uses, so megastep and one-tick
-    decode draw identical key sequences. Row b's draw depends only on
-    (rng, row b's logits): padded/idle rows never perturb live rows."""
+    scaled categorical. Pure jnp on its arguments: the host-side jitted
+    `_pick`. Row b's draw depends only on (rng, row b's logits):
+    padded/idle rows never perturb live rows."""
     import jax
     import jax.numpy as jnp
 
@@ -608,7 +605,7 @@ class _GenerationServerBase:
         self._compile_events_base = tracker.compile_events_total
         tracker.mark_warmup()
         # probs_last: (B, V) — the one sampling program every decode path
-        # shares (dense, paged, packed spec roots, megastep inner loop)
+        # shares (dense, paged, packed spec roots)
         self._pick = tracker.wrap("pick_tokens", jax.jit(pick_tokens),
                                   lambda args: (args[0].shape[0],))
         self._probs_rows = jax.jit(probs_rows, static_argnums=3)
@@ -881,8 +878,7 @@ class _GenerationServerBase:
                 p[:, -1, :]
 
         info = self.ff.executor.warm_launch_shapes(
-            catalog, params=self._params, eos_id=self.eos_id,
-            on_probs=on_probs,
+            catalog, params=self._params, on_probs=on_probs,
             newest=self._newest)  # fflint: lock-ok (warm-up: called before traffic; the loop rebinds it only while launching)
         self._pool_alias = info["pool_alias"]
         self._warm_riders(info.get("probs_ref"))
@@ -898,7 +894,6 @@ class _GenerationServerBase:
             # executor warm just produced, not from synthetic arrays
             ref = (probs_ref[:, -1, :] if probs_ref.ndim == 3
                    else probs_ref)
-            rng_ref = info.get("rng_ref")
             picks = catalog.get("entries", {}).get(
                 "pick_tokens", {}).get("shapes", ())
             for (b,) in picks:  # fflint: host-ok (one-time warmup)
@@ -906,15 +901,9 @@ class _GenerationServerBase:
                 probs = (ref[:b] if int(ref.shape[0]) >= b
                          else jnp.concatenate([ref[:1]] * b))
                 temps = jnp.zeros((b,), jnp.float32)
-                # the split key is host-chain (uncommitted) until a
-                # megastep's output key re-enters the chain — warm the
-                # committed variant off rng_ref when megasteps exist.
-                # Throwaway keys: warming must not consume the serving
-                # rng chain (greedy/sampled token identity).
+                # a throwaway key: warming must not consume the serving
+                # rng chain (greedy/sampled token identity)
                 self._pick(probs, temps, jax.random.key(0))
-                if rng_ref is not None:
-                    self._pick(probs, temps,
-                               jax.random.split(rng_ref)[1])
         if mark_steady:
             self._compile_tracker.mark_steady_state()
         # what set-up made (jax's own objects, 97 traced programs: millions
@@ -968,17 +957,8 @@ class _GenerationServerBase:
             fetched = np.asarray(picked)
             if sp:
                 sp.set(bytes=int(fetched.nbytes))
-        self._first_token_from_device(slot, req, int(fetched[0]))
-
-    def _first_token_from_device(self, slot: int, req: _GenRequest,
-                                 tok: int):
-        """Commit a request's FIRST token when the device already
-        sampled it (the mixed megastep samples a completing prefill's
-        first token on device with the tick's shared rng split — the
-        host rng stream is NOT consumed, keeping megastep-width
-        invariance)."""
         req.pos = len(req.seq_tokens())  # before the append below
-        self._take_first_token(slot, req, tok)
+        self._take_first_token(slot, req, int(fetched[0]))
 
     def _take_first_token(self, slot: int, req: _GenRequest, tok: int):
         """Append a request's first token and stamp TTFT; `req.pos` is
@@ -1354,8 +1334,7 @@ class GenerationServer(_GenerationServerBase):
 # (is it such a graph, the options refused on it, why). ONE table of option
 # names (`_refuse_unsupported`); a graph of several kinds (state layers
 # beside a latent one) is refused by the first that objects
-_ALL_BUT_PREFIX = ("paged=False", "kv_dtype", "speculate", "megastep_ticks",
-                   "megastep_mixed", "overlap_dispatch", "host_tier",
+_ALL_BUT_PREFIX = ("paged=False", "kv_dtype", "speculate", "host_tier",
                    "kv_quant_canary", "serve_strategy", "search_budget")
 _GRAPH_KINDS = (
     (lambda ex: bool(ex.state_layers()),
@@ -1363,27 +1342,25 @@ _GRAPH_KINDS = (
      "state layers (linear attention): a layer's memory is ONE recurrent "
      "state a slot, which no prefix-cache hit can restore "
      "(prefix_cache=True is the default: pass False), no tree verify can "
-     "roll back, no megastep carries, no host tier or int8 pool holds, and "
+     "roll back, no host tier or int8 pool holds, and "
      "which the dense server and the strategy search know nothing of"),
     (lambda ex: bool(ex.window_rows()),
      _ALL_BUT_PREFIX + ("prefix_cache",),
      "sliding-window attention layers: a window layer's pages behind the "
      "window are released, so a prefix-cache hit (prefix_cache=True is the "
      "default: pass False) would map rows that are gone; the dense cache, "
-     "the int8 scale blocks, tree verify, the megasteps' carry, the host "
-     "tier's payloads and the strategy search's pricing all assume ONE "
-     "table a request"),
+     "the int8 scale blocks, tree verify, the host tier's payloads and "
+     "the strategy search's pricing all assume ONE table a request"),
     (lambda ex: any(n.op_type.value == "latent_attention" for n in ex.topo),
      _ALL_BUT_PREFIX,
      "latent attention: its page pool holds one [c_kv | k_r] row a token, "
      "not per-head K and V (the dense cache, the int8 scale sidecar, tree "
-     "verify's commit, the megasteps' carry, the host tier's page payloads "
-     "and the strategy search's pricing all assume K/V pools)"),
+     "verify's commit, the host tier's page payloads and the strategy "
+     "search's pricing all assume K/V pools)"),
 )
 
 
 def _refuse_unsupported(ff, *, paged, prefix_cache, kv_dtype, speculate,
-                        megastep_ticks, megastep_mixed, overlap_dispatch,
                         host_tier, kv_quant_canary, serve_strategy,
                         search_budget) -> None:
     """A graph with latent attention, sliding-window layers or state
@@ -1397,9 +1374,6 @@ def _refuse_unsupported(ff, *, paged, prefix_cache, kv_dtype, speculate,
         "prefix_cache": bool(prefix_cache),
         "kv_dtype": kv_dtype not in ("auto", "bf16", "fp16", "fp32"),
         "speculate": speculate is not None,
-        "megastep_ticks": megastep_ticks > 1,
-        "megastep_mixed": bool(megastep_mixed),
-        "overlap_dispatch": bool(overlap_dispatch),
         "host_tier": host_tier is not None and host_tier != 0,
         "kv_quant_canary": bool(kv_quant_canary),
         "serve_strategy": serve_strategy is not None,
@@ -1421,9 +1395,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
                      prefix_cache: bool = True,
                      prefill_chunk: int = 64,
                      speculate=None,
-                     megastep_ticks: int = 1,
-                     megastep_mixed: bool = False,
-                     overlap_dispatch: bool = False,
                      request_record_limit: Optional[int] = None,
                      kv_dtype: str = "auto",
                      serve_strategy=None,
@@ -1470,31 +1441,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     step, skipping idle slots and padding (docs/paged.md "Ragged work
     packing"; the `padding_waste_ratio` metric counts what is left).
 
-    `megastep_ticks=N` (paged only, N > 1) runs up to N decode ticks
-    per dispatch inside ONE jitted `jax.lax.while_loop` — positions,
-    sampler state and sampled tokens stay device-resident and control
-    returns to the host scheduler only when a slot finishes, a page
-    fills, or N ticks elapse (docs/paged.md "Decode megasteps"). Token
-    output is identical to the one-tick loop, greedy and sampled alike;
-    the default N=1 keeps the per-tick host loop. Without
-    `megastep_mixed`, ticks with mid-prefill chunks in flight keep host
-    granularity, so chunk completion always resumes the host between
-    ticks.
-
-    `megastep_mixed=True` (paged only) makes the megastep UNIVERSAL
-    (docs/paged.md "Universal megasteps"): mid-prefill chunk rows and —
-    with `speculate` — on-device drafted spec chains ride the SAME
-    fused while_loop as decode rows, so mixed traffic no longer drops
-    to host granularity. Control returns on the extra `chunk` break
-    reason only when a chunk COMPLETES (page publication + first-token
-    bookkeeping stay host work), and `verify` when a drafting slot
-    needs page growth. Greedy and fixed-seed sampled output stay
-    token-identical to the one-tick loop. `overlap_dispatch=True`
-    additionally overlaps the next tick's admission work with the
-    in-flight dispatch and only then consumes the token buffer (the
-    `host_overlap_ratio` gauge tracks how much host time the overlap
-    hides); it requires megastep_mixed.
-
     `request_record_limit` bounds how many completed requests keep their
     per-request metric record (default _GenerationServerBase
     .MAX_REQUEST_RECORDS); cumulative counters and histograms are
@@ -1514,7 +1460,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     strategy; `serve_strategy` applies a known ServeStrategy (or its
     to_json() dict, e.g. from `tools/servesearch.py search`) directly.
     Either overrides the paged/page_size/prefill_chunk/
-    megastep_ticks/num_pages/speculate knobs wholesale — passing an
+    num_pages/speculate knobs wholesale — passing an
     explicit `speculate` alongside is an error, the strategy already
     decides speculation.
 
@@ -1563,10 +1509,9 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
     the pages (docs/paged.md "A state a slot"), and refuses the same."""
     _refuse_unsupported(
         ff, paged=paged, prefix_cache=prefix_cache, kv_dtype=kv_dtype,
-        speculate=speculate, megastep_ticks=int(megastep_ticks),
-        megastep_mixed=megastep_mixed, overlap_dispatch=overlap_dispatch,
-        host_tier=host_tier, kv_quant_canary=kv_quant_canary,
-        serve_strategy=serve_strategy, search_budget=search_budget)
+        speculate=speculate, host_tier=host_tier,
+        kv_quant_canary=kv_quant_canary, serve_strategy=serve_strategy,
+        search_budget=search_budget)
     if search_budget is not None and serve_strategy is None:
         from flexflow_tpu.search.servesearch import search_serve_strategy
 
@@ -1586,9 +1531,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
         paged = True
         page_size = kw["page_size"]
         prefill_chunk = kw["prefill_chunk"]
-        megastep_ticks = kw["megastep_ticks"]
-        megastep_mixed = kw.get("megastep_mixed", False)
-        overlap_dispatch = kw.get("overlap_dispatch", False)
         speculate = kw["speculate"]
         kv_dtype = kw["kv_dtype"]
         if kw["num_pages"] is not None:
@@ -1597,25 +1539,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
         # did not hand us a tier of their own (a shared disagg tier wins)
         if host_tier is None and kw["host_tier"] is not None:
             host_tier = kw["host_tier"]
-    megastep_ticks = int(megastep_ticks)
-    if megastep_ticks < 1:
-        raise ValueError(
-            f"megastep_ticks must be >= 1, got {megastep_ticks}")
-    if megastep_mixed and not paged:
-        raise ValueError(
-            "megastep_mixed fuses the paged mixed tick; pass paged=True")
-    if overlap_dispatch and not megastep_mixed:
-        raise ValueError(
-            "overlap_dispatch overlaps host work with the in-flight "
-            "MIXED megastep dispatch; pass megastep_mixed=True")
-    if (megastep_ticks > 1 and not megastep_mixed
-            and (not paged or speculate is not None)):
-        raise ValueError(
-            "megastep_ticks > 1 rides the paged one-tick decode loop; "
-            "pass paged=True and no speculate (the speculative server's "
-            "verify step already emits multiple tokens per dispatch), "
-            "or megastep_mixed=True to fuse spec verify into the "
-            "universal megastep")
     if speculate is not None:
         if not paged:
             raise ValueError(
@@ -1627,9 +1550,7 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             ff, speculate, slots=slots, max_len=max_len, eos_id=eos_id,
             seed=seed, page_size=page_size, num_pages=num_pages,
             preemption=preemption, prefix_cache=prefix_cache,
-            prefill_chunk=prefill_chunk, megastep_ticks=megastep_ticks,
-            megastep_mixed=megastep_mixed,
-            overlap_dispatch=overlap_dispatch,
+            prefill_chunk=prefill_chunk,
             request_record_limit=request_record_limit,
             kv_dtype=kv_dtype, reqlog_capacity=reqlog_capacity,
             slo=slo, slo_dump_dir=slo_dump_dir,
@@ -1643,8 +1564,6 @@ def serve_generation(ff, slots: int = 4, max_len: int = 512,
             ff, slots=slots, max_len=max_len, eos_id=eos_id, seed=seed,
             page_size=page_size, num_pages=num_pages, preemption=preemption,
             prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
-            megastep_ticks=megastep_ticks, megastep_mixed=megastep_mixed,
-            overlap_dispatch=overlap_dispatch,
             request_record_limit=request_record_limit,
             kv_dtype=kv_dtype, reqlog_capacity=reqlog_capacity,
             slo=slo, slo_dump_dir=slo_dump_dir,

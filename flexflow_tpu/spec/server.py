@@ -52,9 +52,6 @@ class SpeculativePagedServer(PagedGenerationServer):
                  seed: int = 0, page_size: int = 64,
                  num_pages: Optional[int] = None, preemption: bool = True,
                  prefix_cache: bool = True, prefill_chunk: int = 64,
-                 megastep_ticks: int = 1,
-                 megastep_mixed: bool = False,
-                 overlap_dispatch: bool = False,
                  request_record_limit: Optional[int] = None,
                  kv_dtype: str = "auto",
                  reqlog_capacity: Optional[int] = None,
@@ -83,9 +80,6 @@ class SpeculativePagedServer(PagedGenerationServer):
                          table_slack_tokens=spec.max_nodes,
                          prefix_cache=prefix_cache,
                          prefill_chunk=prefill_chunk,
-                         megastep_ticks=megastep_ticks,
-                         megastep_mixed=megastep_mixed,
-                         overlap_dispatch=overlap_dispatch,
                          request_record_limit=request_record_limit,
                          kv_dtype=kv_dtype,
                          reqlog_capacity=reqlog_capacity,
@@ -145,43 +139,12 @@ class SpeculativePagedServer(PagedGenerationServer):
         }
         return m
 
-    # -- universal megastep hooks ------------------------------------------
-
-    def _mixed_spec_slot(self, req) -> bool:
-        # greedy slots draft an on-device width-1 n-gram chain inside
-        # the mixed megastep; temperature>0 slots decode one token/tick
-        # (exactness under sampling needs rejection sampling)
-        return req.temperature <= 0.0
-
-    def _on_mixed_spec_tick(self, req, emitted: int):
-        # one drafting slot's fused verify→commit tick: the device
-        # emitted the accepted draft prefix + the correcting/bonus
-        # token. accepted = emitted-1 under-counts by at most one on
-        # the rare max_new/EOS-cut tick (the host cannot see how much
-        # of the cut run was verified draft), which only DEFLATES the
-        # acceptance metrics — never inflates them.
-        D = max(self._spec_depth, 1)
-        accepted = max(emitted - 1, 0)
-        self.spec_steps += 1
-        self.spec_drafted += D
-        self.spec_accepted += accepted
-        self.spec_emitted += emitted
-        req.spec_steps += 1
-        req.spec_drafted += D
-        req.spec_accepted += accepted
-        req.spec_emitted += emitted
-        h = getattr(self, "_h_accept", None)
-        if h is not None:
-            h.observe(accepted / D)
-
     # -- the speculative tick ----------------------------------------------
 
     def _loop_body(self, tr, ntr):
         while not self._stop.is_set():
             live = self._tick_prep()
             if live is None:
-                continue
-            if self._mixed_dispatch(live, tr, ntr):
                 continue
             # chunked prefill rides the same tick structure as the base
             # loop: mid-prefill slots advance one budgeted chunk, then

@@ -567,24 +567,23 @@ def test_hostsync_device_loop_clean_body_and_lambda(tmp_path):
     assert "<lambda>" in findings[0].message
 
 
-def test_hostsync_device_loop_gate_covers_megastep_kernel():
-    """The megastep while_loop (Executor.paged_megastep_fn) is inside
-    the device-loop gate AND scans clean — the tentpole's 'zero host
-    syncs in the inner loop' claim, proven by the linter rather than
-    asserted in prose. Pairing device_loop_bodies with scan_file makes
-    the zero-findings half meaningful: the body was actually seen."""
+def test_hostsync_device_loop_gate_covers_the_routers_rounds():
+    """A device loop the tree runs (the expert router's rounds of a
+    maximum, ops/expert_share.py `_top_k`'s `lax.fori_loop`) is inside
+    the device-loop gate AND scans clean. Pairing device_loop_bodies
+    with scan_file makes the zero-findings half meaningful: the body was
+    actually seen."""
     from flexflow_tpu.analysis.hostsync import (
         device_loop_bodies,
         scan_file,
     )
 
     path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "flexflow_tpu", "runtime", "executor.py")
+                        "flexflow_tpu", "ops", "expert_share.py")
     path = os.path.abspath(path)
     bodies = device_loop_bodies(path)
-    kinds = {b["kind"] for b in bodies}
-    assert "while_loop" in kinds, bodies
-    assert {"cond", "body"} <= {b["body"] for b in bodies}
+    assert {(b["kind"], b["body"]) for b in bodies} == {
+        ("fori_loop", "round_")}, bodies
     findings = [f for f in scan_file(path) if f.code == "device-loop"]
     assert findings == [], [(f.where, f.message) for f in findings]
 
@@ -1590,7 +1589,7 @@ def test_shapecheck_repo_hot_paths_clean_and_entry_points_seen():
     execu = [p for p in paths if p.endswith("executor.py")][0]
     sites = shapecheck.jit_entry_points(execu)
     scopes = {s["scope"] for s in sites}
-    assert {"ragged_step_fn", "paged_megastep_fn"} <= scopes, scopes
+    assert {"ragged_step_fn", "paged_commit_fn"} <= scopes, scopes
 
 
 def test_shapecheck_catalog_is_the_expected_closed_set():
@@ -1610,11 +1609,6 @@ def test_shapecheck_catalog_is_the_expected_closed_set():
     assert cat["total_compilations"] == 14
     assert cat["config"]["table_cols"] == 8      # ceil(32 / 4)
     assert cat["config"]["num_pages"] == 17      # slots*cols + null page
-
-    # megastep adds exactly one (slots, ticks) program
-    mega = enumerate_catalog(slots=2, max_len=32, page_size=4,
-                             prefill_chunk=6, megastep_ticks=4)
-    assert mega["entries"]["megastep"]["shapes"] == [[2, 4]]
 
     # a spec tree wider than the prefill chunk adds its verify shapes
     # and the commit program; table slack covers the tree scratch rows
@@ -1660,8 +1654,7 @@ def test_shapecheck_pass_budget_and_summary():
         [(f.code, f.where) for f in report.findings]
     assert ctx.shapecheck_summary is not None
     cats = ctx.shapecheck_summary["catalogs"]
-    assert set(cats) >= {"paged_base", "paged_megastep", "paged_spec",
-                         "dense"}
+    assert set(cats) == {"paged_base", "paged_spec", "dense"}
     for cat in cats.values():
         assert cat["total_compilations"] <= \
             ctx.shapecheck_summary["budget"]
@@ -1717,7 +1710,8 @@ def test_shapecheck_union_catalog_spans_a_strategy_swap():
     old = enumerate_catalog(slots=2, max_len=32, page_size=4,
                             prefill_chunk=6)
     new = enumerate_catalog(slots=2, max_len=32, page_size=4,
-                            prefill_chunk=4, megastep_ticks=4)
+                            prefill_chunk=4, spec_max_nodes=3,
+                            spec_depth=2)
     union = union_catalogs(old, new)
     # entry-wise set union; the shared decode/pick shapes count once
     for cat in (old, new):
@@ -1729,11 +1723,11 @@ def test_shapecheck_union_catalog_spans_a_strategy_swap():
     assert union["config"]["union"] == [old["config"], new["config"]]
 
     # the cutover gate: one event only the OLD side emits (a width-6
-    # prefill), one only the NEW side emits (its fused megastep
+    # prefill), one only the NEW side emits (its verify's commit
     # program) — the union judges both sound
     events = [{"entry": "ragged_step", "shape": (1, 6), "seconds": 0.4,
                "steady_state": False},
-              {"entry": "megastep", "shape": (2, 4), "seconds": 0.4,
+              {"entry": "paged_commit", "shape": (2, 3), "seconds": 0.4,
                "steady_state": False}]
     assert check_soundness(old, [events[1]]) != []
     assert check_soundness(new, [events[0]]) != []
@@ -1889,7 +1883,7 @@ def test_racecheck_pass_reports_findings_summary_and_traces(tmp_path):
     assert "Minimal interleaving" in f.message
     assert ctx.racecheck_summary["explored"] > 0
     assert set(ctx.racecheck_summary["models"]) == \
-        {"handoff", "tierpool", "swap", "dispatch", "launch_ahead"}
+        {"handoff", "tierpool", "swap", "launch_ahead"}
     traces = list(tmp_path.glob("interleave-swap-future-dropped.json"))
     assert traces, list(tmp_path.iterdir())
     with open(traces[0]) as fh:

@@ -5,11 +5,12 @@ iteration N's picks: a decode row reads its token id from the device, the
 host learns it a launch late. These tests hold that pipeline to the serial
 order, which is the same code with the pipeline drained after every
 iteration (a test-only subclass that fences there): the same tokens, greedy
-and at a seeded temperature, with and without an EOS, for the three model
+and at a seeded temperature, with and without an EOS, for the four model
 families the benchmark serves (a K/V pool, a latent pool with experts, two
-classes of pages); every launch accounted for; every fence where it is
-listed; no page freed under a launch that names it; the spans the
-benchmark's readers are written against.
+classes of pages, a state a slot beside a latent pool); every launch
+accounted for; every fence where it is listed; no page freed under a
+launch that names it; the spans the benchmark's readers are written
+against.
 """
 
 import json
@@ -20,6 +21,7 @@ import types
 import numpy as np
 import pytest
 
+import test_ling3 as ling3_tiny
 import test_mellum2 as mellum2_tiny
 import test_one_launch_iteration as one_launch
 from benchmark import tickspans
@@ -50,6 +52,7 @@ FAMILIES = {
     "mistral-7b": ("llama", {}),
     "mistral-small-4": ("mistral4", {}),
     "mellum2": ("mellum2", {"prefix_cache": False}),
+    "ling-3-flash": ("ling3", {"prefix_cache": False}),
 }
 # (prompt length, new tokens): seven requests over four slots, so slots
 # turn over while others decode; prompts that end inside a chunk, that end
@@ -64,16 +67,22 @@ DECODERS = [(3, 12), (4, 12), (3, 12), (4, 12)]
 @pytest.fixture(scope="module")
 def graphs():
     return {"llama": one_launch._llama(), "mistral4": one_launch._mistral4(),
-            "mellum2": mellum2_tiny.build(mellum2_tiny.config())}
+            "mellum2": mellum2_tiny.build(mellum2_tiny.config()),
+            "ling3": ling3_tiny.build(ling3_tiny.config())}
 
 
 def _watch_pages(server):
-    """Wrap `_launch` and the full class's `free`: after every launch the
-    invariant catalog holds, and no page that a launch IN FLIGHT names
-    (dispatched, not known to be done) ever leaves its owners."""
+    """Wrap `_launch` and the full class's `free`: at every launch the
+    invariant catalog holds (after it; before it where the graph has state
+    layers, whose account a launch advances before the tick advances the
+    requests'), and no page that a launch IN FLIGHT names (dispatched, not
+    known to be done) ever leaves its owners."""
     named, real_launch, real_free = {}, server._launch, server.pool.free
+    stateful = bool(server._state_keys)
 
     def launch(items, window, tr, ntr):
+        if stateful:
+            server._check_invariants()
         out = real_launch(items, window, tr, ntr)
         tables = server._tables
         # an item without rows (an idle slot of the decode launch) does
@@ -81,7 +90,8 @@ def _watch_pages(server):
         named[server.launches] = {
             int(p) for s, _pos, toks, *_ in items if len(toks)
             for p in tables[s] if p}
-        server._check_invariants()
+        if not stateful:
+            server._check_invariants()
         return out
 
     def free(pages):
@@ -236,7 +246,7 @@ def test_an_eos_a_launch_late_emits_nothing_after_it(graphs, family, how):
 
 
 @pytest.mark.parametrize("what", ["preempt", "defrag"])
-@pytest.mark.parametrize("family", ["mistral-7b", "mellum2"])
+@pytest.mark.parametrize("family", ["mistral-7b", "mellum2", "ling-3-flash"])
 def test_preemption_and_defrag_fence_first(graphs, family, what):
     """`preempt`: a full class too small for two long requests (the
     younger is preempted and recomputed). `defrag`: a compaction asked for
@@ -371,17 +381,6 @@ def test_the_prefill_worker_hands_off_behind_a_fence(graphs):
     _accounted(pair.decode.metrics())
     pair.prefill._check_invariants()
     pair.decode._check_invariants()
-
-
-def test_a_megastep_is_entered_drained(graphs):
-    ff = graphs["llama"]
-    plain = _serve(ff, DrainedServer, BACKLOG)
-    mega = _serve(ff, PagedGenerationServer, BACKLOG, megastep_ticks=4)
-    for w, g in zip(plain.tokens, mega.tokens):
-        np.testing.assert_array_equal(w, g)
-    m = mega.metrics
-    assert m["fences"]["megastep"] >= 1 and m["megastep"]["ticks_max"] == 4
-    _accounted(m)
 
 
 # ---------------------------------------------------------------------------

@@ -497,9 +497,8 @@ def test_the_chips_shares_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize("option", [
     {"paged": False}, {"prefix_cache": True}, {"kv_dtype": "int8"},
-    {"megastep_ticks": 4}, {"megastep_mixed": True}, {"host_tier": 8},
-    {"kv_quant_canary": 2}, {"speculate": "spec"}, {"search_budget": 2},
-    {"serve_strategy": {}}])
+    {"host_tier": 8}, {"kv_quant_canary": 2}, {"speculate": "spec"},
+    {"search_budget": 2}, {"serve_strategy": {}}])
 def test_unsupported_serving_options_are_refused_by_name(tiny, option):
     """What cannot ride on a recurrent state raises at construction and
     names itself (prefix_cache is on by default: it has to be turned off

@@ -445,8 +445,8 @@ def test_four_shares_of_an_eighth_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize("option", [
     {"paged": False}, {"prefix_cache": True}, {"kv_dtype": "int8"},
-    {"megastep_ticks": 4}, {"megastep_mixed": True}, {"host_tier": 8},
-    {"kv_quant_canary": 2}, {"speculate": "spec"}, {"search_budget": 2}])
+    {"host_tier": 8}, {"kv_quant_canary": 2}, {"speculate": "spec"},
+    {"search_budget": 2}])
 def test_unsupported_serving_options_are_refused_by_name(tiny, option):
     """What is not built over two classes of pages raises at construction
     and names itself (prefix_cache is on by default: it has to be turned

@@ -252,9 +252,8 @@ def test_pool_holds_one_entry_a_node(tiny):
 
 
 @pytest.mark.parametrize("option", [
-    {"paged": False}, {"kv_dtype": "int8"}, {"megastep_ticks": 4},
-    {"megastep_mixed": True}, {"host_tier": 8}, {"kv_quant_canary": 2},
-    {"speculate": "spec"}, {"search_budget": 2}])
+    {"paged": False}, {"kv_dtype": "int8"}, {"host_tier": 8},
+    {"kv_quant_canary": 2}, {"speculate": "spec"}, {"search_budget": 2}])
 def test_unsupported_serving_options_are_refused_by_name(tiny, option):
     """(f) each option whose code reads per-head K/V pools raises at
     construction and names itself."""

@@ -134,8 +134,7 @@ def _serve(ff, cls, kv_dtype, temperature):
     counters = {
         "prefill_ticks": server.prefill_ticks,
         "steps": server._steps,
-        "round_trips": int(server._c_rt.value),
-        "decode_tokens": int(server._c_dtok.value),
+        "decode_tokens": int(server._h_tokens.sum),
         "decode_overlap_ticks": sorted(r["decode_overlap_ticks"]
                                        for r in metrics["requests"]),
         "prefill_observed": server._h_prefill.count,
@@ -398,7 +397,7 @@ def test_one_launch_share_reads_100_and_is_left_out_at_the_parent(served):
 def test_counters_equal_the_two_launch_orders(served, family, how):
     one, two = served(family, how)
     assert one.counters == two.counters
-    assert one.counters["steps"] == one.counters["round_trips"]
+    assert one.counters["steps"] == one.counters["ticks_observed"]
     assert one.counters["decode_tokens"] == sum(
         new - 1 for _n, new in TRAFFIC)
     # the rows the launches carried: a rider pads its window where it

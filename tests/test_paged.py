@@ -833,6 +833,178 @@ def test_ragged_pack_is_no_option():
                             ragged_pack=False)
 
 
+_MEGASTEP_OPTIONS = ("megastep_ticks", "megastep_mixed", "overlap_dispatch")
+
+
+def _spell(ff, spelling, option):
+    """Build what `spelling` builds, with the keyword `option` set."""
+    from flexflow_tpu import serving
+    from flexflow_tpu.paged.scheduler import PagedGenerationServer
+    from flexflow_tpu.serve_strategy import ServeStrategy
+    from flexflow_tpu.spec import SpecConfig
+    from flexflow_tpu.spec.server import SpeculativePagedServer
+
+    kw = dict(slots=1, max_len=16, page_size=4, **{option: 1})
+    if spelling == "serve_generation":
+        return serving.serve_generation(ff, paged=True, **kw)
+    if spelling == "PagedGenerationServer":
+        return PagedGenerationServer(ff, **kw)
+    if spelling == "SpeculativePagedServer":
+        return SpeculativePagedServer(ff, SpecConfig(width=2, depth=2), **kw)
+    return ServeStrategy(**{option: 1})
+
+
+@pytest.mark.parametrize("spelling", [
+    "serve_generation", "PagedGenerationServer", "SpeculativePagedServer",
+    "ServeStrategy"])
+@pytest.mark.parametrize("option", _MEGASTEP_OPTIONS)
+def test_megastep_options_are_no_options(option, spelling):
+    """The serving loop is the code, not a keyword: every spelling of a
+    server's options refuses the three that chose a device-resident
+    loop, at a value that meant "off" too."""
+    ff, _ = _causal_lm()
+    with pytest.raises(TypeError, match=option):
+        _spell(ff, spelling, option)
+
+
+@pytest.mark.parametrize("server", ["Paged", "Speculative"])
+def test_strategy_kwargs_are_the_constructors_parameters(server):
+    """Whatever `ServeStrategy.to_server_kwargs()` names, the server
+    it configures takes: an option deleted from the constructors and
+    left behind in the strategy fails here, not at the first swap."""
+    import inspect
+
+    from flexflow_tpu.paged.scheduler import PagedGenerationServer
+    from flexflow_tpu.serve_strategy import ServeStrategy
+    from flexflow_tpu.spec.server import SpeculativePagedServer
+
+    cls = {"Paged": PagedGenerationServer,
+           "Speculative": SpeculativePagedServer}[server]
+    params = set(inspect.signature(cls.__init__).parameters)
+    kw = set(ServeStrategy().to_server_kwargs(slots=2, max_len=64))
+    # `paged` chooses the class and `speculate` is its `spec`
+    assert kw - {"paged", "speculate"} <= params, kw - params
+    assert "spec" in params or server == "Paged"
+
+
+@pytest.mark.parametrize("row", [0, 1, 2], ids=["state", "window", "latent"])
+def test_graph_kind_rows_name_only_options(row):
+    """Every option a kind of graph refuses by name IS an option of
+    `serve_generation` (`paged=False` names `paged`)."""
+    import inspect
+
+    from flexflow_tpu import serving
+
+    params = set(inspect.signature(serving.serve_generation).parameters)
+    _is_kind, refused, why = serving._GRAPH_KINDS[row]
+    assert why.startswith(["state", "sliding-window", "latent"][row])
+    names = {name.split("=")[0] for name in refused}
+    assert names <= params, names - params
+
+
+def _lifecycle_server(ff, **kw):
+    """A paged server that holds its bookkeeping to the invariant
+    catalog every time the host takes a launch's picks (test-only: the
+    check is too hot for serving, and the server has no hook for it)."""
+    from flexflow_tpu.paged.scheduler import PagedGenerationServer
+
+    class Checked(PagedGenerationServer):
+        delivered = 0
+
+        def _deliver(self, rec):
+            super()._deliver(rec)
+            self._check_invariants()
+            self.delivered += 1
+
+    return Checked(ff, max_len=64, **kw)
+
+
+# scenario -> (seed, prompt lengths, new tokens a request, server options,
+# what it submits with)
+_LIFECYCLES = {
+    # a page fills while the launch that writes its last row is in flight
+    "page-boundary": (2, (5,), (16,), dict(slots=2, page_size=4), {}),
+    # a request ends on its length beside slots that go on
+    "length-finish": (3, (4, 6), (5, 12), dict(slots=2, page_size=16), {}),
+    "stop-token": (4, (5,), (10,), dict(slots=2, page_size=16), {}),
+    "finish-orders": (5, (3, 5, 4, 6), (3, 7, 12, 5),
+                      dict(slots=4, page_size=4), {}),
+    "seeded-temperature": (1, (5,), (14,),
+                           dict(slots=4, page_size=8, seed=11),
+                           dict(temperature=0.8)),
+    "chunk-beside-decoders": (8, (3, 24, 5), (8, 8, 8),
+                              dict(slots=3, page_size=4, prefill_chunk=6),
+                              {}),
+    # four requests over three slots and nine pages: the youngest is
+    # preempted and recomputed, a slot turns over
+    "full-pool-turnover": (6, (3, 6, 5, 4), (10, 10, 10, 10),
+                           dict(slots=3, page_size=4, num_pages=10), {}),
+    "int8-stable": (0, (3, 6, 5), (12, 12, 12),
+                    dict(slots=4, page_size=4, kv_dtype="int8"), {}),
+}
+
+
+@pytest.mark.parametrize("scenario", _LIFECYCLES)
+def test_decode_lifecycle_matches_dense(scenario):
+    """The serving loop through a request's whole life against the dense
+    reference (`FFModel.generate`; the dense server at a temperature),
+    the invariant catalog held at every delivery."""
+    seed, lens, news, server_kw, submit_kw = _LIFECYCLES[scenario]
+    ff, lcfg = _causal_lm()
+    rs = np.random.RandomState(seed)
+    prompts = [rs.randint(0, lcfg.vocab_size, (n,)).astype(np.int32)
+               for n in lens]
+    if submit_kw:
+        dense = ff.serve_generation(slots=2, max_len=64,
+                                    seed=server_kw["seed"])
+        try:
+            want = [dense.generate(p, max_new_tokens=n, **submit_kw)
+                    for p, n in zip(prompts, news)]
+        finally:
+            dense.stop()
+    else:
+        want = [ff.generate(p[None, :], max_new_tokens=n)[0]
+                for p, n in zip(prompts, news)]
+    if scenario == "stop-token":
+        # ends on the fourth token, with launches in flight behind it
+        server_kw = dict(server_kw, eos_id=int(want[0][3]))
+        want = [want[0][:4]]
+
+    def serve():
+        server = _lifecycle_server(ff, **server_kw)
+        try:
+            futs = [server.submit(p, max_new_tokens=n, **submit_kw)
+                    for p, n in zip(prompts, news)]
+            got = [np.asarray(f.result(timeout=600)) for f in futs]
+        finally:
+            server.stop()
+        assert server.delivered > 0
+        server._check_invariants()
+        assert server.pool.pages_in_use == 0
+        return got, server.metrics()
+
+    got, m = serve()
+    assert m["requests_served"] == len(prompts)
+    if scenario == "int8-stable":
+        # an int8 pool is not the dense cache's numerics: its tokens are
+        # its own, the same run to run, all but one request the dense's
+        again, _ = serve()
+        for a, b in zip(got, again):
+            np.testing.assert_array_equal(a, b)
+        assert m["kv_cache_dtype"] == "int8"
+        assert sum(np.array_equal(w, g)
+                   for w, g in zip(want, got)) >= len(prompts) - 1
+        return
+    for i, (w, g) in enumerate(zip(want, got)):
+        np.testing.assert_array_equal(w, g, err_msg=f"request {i}")
+    if scenario == "page-boundary":
+        assert m["launches_ahead"] > 4      # pages grew under launches
+    if scenario == "stop-token":
+        assert m["late_stop_rows"] == 1     # the row dispatched behind it
+    if scenario == "full-pool-turnover":
+        assert m["preemptions"] >= 1
+
+
 _SERVER_IMPORTS = """
 import sys
 import numpy as np

@@ -11,9 +11,10 @@ to reading it while the loop is launching: a use after donation raises
 
 Each case runs on a K/V-pool graph (tiny llama: `"k"`/`"v"` entries, with
 an int8 pool's scale sidecar where the program has one) and on a
-latent-pool graph (tiny mistral4: one `"c"` entry a node). A latent graph
-serves per tick only (megasteps, speculation and the int8 pool are refused
-by name), so its cases are the ragged step's.
+latent-pool graph (tiny mistral4: one `"c"` entry a node), and the ragged
+step also on a graph with two classes of pages (tiny mellum2) and on one
+with a state a slot beside its pages (tiny ling3). Those three refuse
+speculation and the int8 pool by name, so their cases are the ragged step's.
 """
 
 import dataclasses
@@ -29,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import test_ling3 as ling3_tiny
+import test_mellum2 as mellum2_tiny
 from benchmark.families import mistral4 as fam
 from benchmark.readers import span_counter
 from flexflow_tpu import FFConfig, FFModel, LossType, obs
@@ -76,67 +79,58 @@ def _mistral4():
 
 @pytest.fixture(scope="module")
 def graphs():
-    return {"llama": _llama(), "mistral4": _mistral4()}
+    return {"llama": _llama(), "mistral4": _mistral4(),
+            "mellum2": mellum2_tiny.build(mellum2_tiny.config()),
+            "ling3": ling3_tiny.build(ling3_tiny.config())}
 
 
 # (graph, entry, pool dtype): every program that takes the pools and
 # returns them, on every pool family it serves
 PROGRAMS = [
     ("llama", "ragged_step", None),
-    ("llama", "megastep", None),
-    ("llama", "megastep_mixed", None),
     ("llama", "paged_commit", None),
     ("llama", "ragged_step", "int8"),
-    ("llama", "megastep", "int8"),
     ("llama", "paged_commit", "int8"),
     ("mistral4", "ragged_step", None),
+    ("mellum2", "ragged_step", None),
+    ("ling3", "ragged_step", None),
 ]
 IDS = [f"{g}-{e}" + (f"-{d}" if d else "") for g, e, d in PROGRAMS]
 SLOTS, COLS = 2, 3
 
 
 def _program(ff, entry, kv_dtype):
-    """(fn, args before the pools, a fresh pool, args after it, where the
-    pools are among the outputs) for one call of `entry`: two slots, the
-    first with a live row, tables over pages 1.."""
+    """(fn, args before the pools, a fresh pool, args after it, keyword
+    args, where the pools are among the outputs) for one call of `entry`:
+    two slots, the first with a live row, tables over pages 1.. (a table a
+    class where the graph has window layers, the items' slots where it has
+    state layers)."""
     from flexflow_tpu.paged.quant import resolve_kv_dtype
 
     ex = ff.executor
     tr, ntr = ff._params
-    caches = ex.init_paged_kv_cache(1 + SLOTS * COLS, PAGE,
-                                    dtype=resolve_kv_dtype(kv_dtype))
-    tables = jnp.asarray(1 + np.arange(SLOTS * COLS, dtype=np.int32)
-                         .reshape(SLOTS, COLS))
+    pages = 1 + SLOTS * COLS
+    two = ex.page_classes() is not None
+    caches = ex.init_paged_kv_cache(
+        pages, PAGE, dtype=resolve_kv_dtype(kv_dtype),
+        num_pages_window=pages if two else None, slots=SLOTS)
+    tables = 1 + np.arange(SLOTS * COLS, dtype=np.int32).reshape(SLOTS, COLS)
+    tables = jnp.asarray(np.stack([tables, tables]) if two else tables)
     z = jnp.zeros((SLOTS,), jnp.int32)
-    one = jnp.asarray(np.array([1, 0], np.int32))
-    act = jnp.asarray(np.array([True, False]))
-    no = jnp.zeros((SLOTS,), jnp.bool_)
-    temps = jnp.zeros((SLOTS,), jnp.float32)
-    cap = jnp.full((SLOTS,), COLS * PAGE, jnp.int32)
     if entry == "ragged_step":
         W = 4
         deps = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (SLOTS, W))
         anc = jnp.broadcast_to(jnp.tril(jnp.ones((W, W), jnp.bool_)),
                                (SLOTS, W, W))
+        kw = ({"state_slots": jnp.arange(SLOTS, dtype=jnp.int32)}
+              if ex.state_layers() else {})
         return (ex.ragged_step_fn(), (tr, ntr), caches,
                 (tables, z, jnp.asarray(np.array([3, 0], np.int32)), deps,
-                 anc, jnp.ones((SLOTS, W), jnp.int32)),
+                 anc, jnp.ones((SLOTS, W), jnp.int32)), kw,
                 lambda out: out[1])
-    if entry == "megastep":
-        return (ex.paged_megastep_fn(2), (tr, ntr), caches,
-                (tables, z, one, temps, jnp.full((SLOTS,), 2, jnp.int32),
-                 cap, act, jax.random.key(0)),
-                lambda out: out[0])
-    if entry == "megastep_mixed":
-        seq = jnp.ones((SLOTS, COLS * PAGE + 1), jnp.int32)
-        return (ex.paged_mixed_megastep_fn(2, window=4), (tr, ntr), caches,
-                (tables, seq, z, z, z, temps,
-                 jnp.full((SLOTS,), 2, jnp.int32), cap, act, no, no,
-                 jax.random.key(0)),
-                lambda out: out[0])
     assert entry == "paged_commit"
     rows = jnp.asarray(np.array([[0, 1], [0, 0]], np.int32))
-    return (ex.paged_commit_fn(), (), caches, (tables, rows + 2, rows),
+    return (ex.paged_commit_fn(), (), caches, (tables, rows + 2, rows), {},
             lambda out: out)
 
 
@@ -146,12 +140,13 @@ def test_lowered_program_aliases_every_pool_leaf(graphs, graph, entry,
     """(a) the lowering marks EVERY pool leaf (an int8 pool's scale
     sidecar leaves among them) as an output's buffer, and no other
     argument: the weights stay the caller's."""
-    fn, head, caches, tail, _pools = _program(graphs[graph], entry,
-                                              kv_dtype)
+    fn, head, caches, tail, kw, _pools = _program(graphs[graph], entry,
+                                                  kv_dtype)
     leaves = len(jax.tree.leaves(caches))
-    assert leaves == len(caches) * {None: 1 if graph == "mistral4" else 2,
-                                    "int8": 4}[kv_dtype]
-    text = fn.lower(*head, caches, *tail).as_text()
+    if graph != "ling3":    # its nodes differ: states, a latent pool
+        assert leaves == len(caches) * {
+            None: 1 if graph == "mistral4" else 2, "int8": 4}[kv_dtype]
+    text = fn.lower(*head, caches, *tail, **kw).as_text()
     assert text.count("tf.aliasing_output") == leaves
     assert "jax.buffer_donor" not in text   # a donation with no output
 
@@ -161,11 +156,11 @@ def test_call_consumes_the_pool_and_writes_it_in_place(graphs, graph, entry,
                                                        kv_dtype):
     """(b) after one call the pool passed in is gone and each returned
     leaf lies in the device buffer its input had."""
-    fn, head, caches, tail, pools = _program(graphs[graph], entry,
-                                             kv_dtype)
+    fn, head, caches, tail, kw, pools = _program(graphs[graph], entry,
+                                                 kv_dtype)
     given = jax.tree.leaves(caches)
     where = [leaf.unsafe_buffer_pointer() for leaf in given]
-    out = pools(fn(*head, caches, *tail))
+    out = pools(fn(*head, caches, *tail, **kw))
     out.pop(LAUNCH_STATS, None)
     jax.block_until_ready(out)
     assert all(leaf.is_deleted() for leaf in given)

@@ -7,7 +7,7 @@ acceptance criterion is a bounded logit/output delta against the fp32
 reference (pinned here at the attention level and, via the
 FF_TPU_KV_QUANT_DEBUG shadow cache, at the served-model level), plus
 exact TOKEN identity between quantized configurations that must agree
-(megastep fusion, speculative verify, page sharing, defrag — the page
+(speculative verify, page sharing, defrag — the page
 machinery is a memory layout, never a numerics change *within* a
 dtype). Every band asserted here comes from the numerics budget
 catalog (flexflow_tpu/analysis/num_budgets.py) by NAME — changing a
@@ -354,18 +354,6 @@ def test_greedy_int8_server_within_tolerance(lm, monkeypatch):
     assert matched >= len(prompts) - 1, (matched, want, got)
 
 
-def test_megastep_quantized_token_stability(lm):
-    """N=8 device-resident ticks over an int8 pool emit the SAME tokens
-    as N=1: the megastep carry moves the scale sidecar with the pages."""
-    ff, lcfg = lm
-    prompts = _prompts(lcfg)
-    one, m1 = _serve(ff, prompts, 8, kv_dtype="int8", megastep_ticks=1)
-    eight, m8 = _serve(ff, prompts, 8, kv_dtype="int8", megastep_ticks=8)
-    for a, b in zip(one, eight):
-        np.testing.assert_array_equal(a, b)
-    assert m8["kv_cache_dtype"] == "int8"
-
-
 def test_spec_acceptance_floor_on_quantized_pool():
     """Speculative decode over an int8 pool on the token-cyclic fixture:
     acceptance stays above the same floor as fp (the drafter predicts
@@ -521,22 +509,6 @@ def test_kv_quant_canary_env_and_debug_precedence(lm, monkeypatch):
         assert can["window_open"] is True        # the debug shadow is on
     finally:
         srv.stop()
-
-
-def test_kv_quant_canary_with_megastep(lm, monkeypatch):
-    """An open canary window forces the one-tick path (the shadow must
-    observe every tick); between windows the megastep fuses as always —
-    and the emitted tokens match the canary-less megastep run."""
-    monkeypatch.delenv("FF_TPU_KV_QUANT_DEBUG", raising=False)
-    ff, lcfg = lm
-    prompts = _prompts(lcfg)
-    plain, _ = _serve(ff, prompts, 8, kv_dtype="int8", megastep_ticks=8)
-    got, m = _serve(ff, prompts, 8, kv_dtype="int8", megastep_ticks=8,
-                    kv_quant_canary=2)
-    for a, b in zip(plain, got):
-        np.testing.assert_array_equal(a, b)
-    assert m["kv_quant_canary"]["windows"] >= 1
-    assert m["kv_quant_error"] > 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -5,19 +5,18 @@ DisaggPair and ServingAutopilot.
 
 Contracts under test: every protocol model — prefill->decode handoff,
 concurrent spill/fetch/admission against the bounded host tier,
-drain-and-swap under live submits, the overlapped megastep dispatch
-fence (ISSUE 20) and the per-tick loop's one-deep launch pipeline
-(ISSUE 37: a page named by a launch in flight is neither freed nor
-moved, admission runs beside it) — is FULLY explored violation-free at
-the default context-switch bound (the explored/distinct state counts
+drain-and-swap under live submits and the serving loop's one-deep launch
+pipeline (ISSUE 37: a page named by a launch in flight is neither freed
+nor moved, admission runs beside it) — is FULLY explored violation-free
+at the default context-switch bound (the explored/distinct state counts
 are pinned: a model edit that shrinks the space is as suspicious as one
 that breaks an invariant); sleep-set pruning is sound (the pruned and
 unpruned explorations reach the identical distinct-state set); each
 seeded protocol mutation produces its named invariant violation with a
-minimal trace that replays to the same violation from the initial
-state; and the real threaded code the models abstract — DisaggPair
-under overlapped submits, an autopilot hot-swap under live traffic —
-keeps the page-pool invariant catalog green at every resume point.
+minimal trace that replays to the same violation from the initial state;
+and the real threaded code the models abstract — DisaggPair under
+overlapped submits, an autopilot hot-swap under live traffic — keeps the
+page-pool invariant catalog green at every resume point.
 """
 
 import dataclasses
@@ -44,7 +43,6 @@ _CLEAN_SPACE = {
     "handoff": (53, 48),
     "swap": (149, 117),
     "tierpool": (16, 15),
-    "dispatch": (58, 40),
     "launch_ahead": (236, 96),
 }
 
@@ -78,8 +76,6 @@ def test_sleep_set_pruning_is_sound():
     ("tierpool", "fetch_no_remove", "tier-partition"),
     ("swap", "unlocked_submit", "future-dropped"),
     ("swap", "no_safepoint_join", "swap-during-handoff"),
-    ("dispatch", "read_before_fence", "dispatch-buffer-owner"),
-    ("dispatch", "admit_steals_live_page", "stale-page-table"),
     ("launch_ahead", "read_before_fence", "dispatch-buffer-owner"),
     ("launch_ahead", "free_at_late_stop", "stale-page-table"),
     ("launch_ahead", "defrag_without_fence", "stale-page-table"),

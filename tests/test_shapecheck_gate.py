@@ -1,10 +1,9 @@
 """shapecheck runtime soundness gate (ISSUE 14): after catalog-driven
-warmup, mixed packed/megastep and speculative serving must observe
+warmup, mixed packed and speculative serving must observe
 compile events that are (a) all pre-steady-state — `steady_state_recompiles`
 pinned at ZERO — and (b) a subset of the statically enumerated catalog
 (`check_soundness` empty). Plus the satellite contracts that ride the
-same machinery: the TTFT compile/serve split on per-request records,
-and the bounded LRU on the executor's megastep jit-callable memo.
+same machinery: the TTFT compile/serve split on per-request records.
 
 CI runs the same gate as a smoke step (.github/workflows/tests.yml);
 tests/test_analysis.py holds the static-arm seeded defects.
@@ -49,7 +48,7 @@ def test_warmed_serving_observes_only_catalog_shapes_and_never_recompiles(
         gate_model):
     """THE soundness gate: warm from the static catalog, serve mixed
     traffic, then require zero steady-state recompiles and every
-    observed compile event enumerated. Runs a packed+megastep server and
+    observed compile event enumerated. Runs the default server and
     a speculative server back to back on ONE model — which also proves
     the per-server event scoping on the shared executor tracker (the
     spec server's warm compiles must not read as the first server's
@@ -58,15 +57,8 @@ def test_warmed_serving_observes_only_catalog_shapes_and_never_recompiles(
     rs = np.random.RandomState(0)
 
     flavors = (
-        dict(megastep_ticks=4),
+        dict(),
         dict(speculate=SpecConfig(width=2, depth=2)),
-        # the universal (mixed) megastep family: chunk rows and drafted
-        # chains fuse into one dispatch — its (slots, ticks, window)
-        # launch shape must be enumerated and warmed like the rest
-        dict(megastep_ticks=4, megastep_mixed=True),
-        dict(megastep_ticks=4, megastep_mixed=True,
-             overlap_dispatch=True,
-             speculate=SpecConfig(width=2, depth=2)),
     )
     for kwargs in flavors:
         server = ff.serve_generation(slots=2, max_len=32, paged=True,
@@ -155,29 +147,3 @@ def test_ttft_records_split_compile_from_serve_time():
         assert first["first_compile_s"] == 0.0, first
     finally:
         server.stop()
-
-
-def test_megastep_jit_cache_is_lru_bounded(gate_model):
-    """The per-Executor megastep memo (one jitted program per ticks
-    knob) is LRU-bounded at JIT_CACHE_LIMIT, recency-refreshed on reuse,
-    and reported through jit_cache_entries (the ff_jit_cache_entries
-    gauge). Building the callables never compiles (compilation is
-    per-call), so this sweep is cheap."""
-    ex = gate_model[0].executor
-    limit = ex.JIT_CACHE_LIMIT
-    assert limit >= 2
-    ex._megastep_fns.clear()
-    for n in range(2, 2 + limit + 3):
-        ex.paged_megastep_fn(n, None)
-    assert len(ex._megastep_fns) == limit
-    # the oldest entries were evicted, the newest survive
-    ticks = {k[0] for k in ex._megastep_fns}
-    assert 2 not in ticks and (2 + limit + 2) in ticks, ticks
-    # touching the current-oldest refreshes it past a new insertion
-    oldest = next(iter(ex._megastep_fns))
-    ex.paged_megastep_fn(oldest[0], oldest[1])
-    ex.paged_megastep_fn(99, None)
-    assert oldest in ex._megastep_fns
-    assert len(ex._megastep_fns) == limit
-    assert ex.jit_cache_entries() >= limit
-    ex._megastep_fns.clear()

@@ -184,31 +184,32 @@ def test_sim_play_launches_once_an_iteration_but_verifies_apart(spec):
     calls = []
     pre_tick, dec_tick = run._prefill_tick, run._decode_tick
     run._prefill_tick = lambda slots, dec=(): (
-        calls.append(("prefill", list(slots), list(dec))),
+        calls.append(("prefill", list(slots), list(dec), run.ticks)),
         pre_tick(slots, dec))[1]
-    run._decode_tick = lambda dec, mixed: (
-        calls.append(("decode", list(dec), mixed)), dec_tick(dec, mixed))[1]
+    run._decode_tick = lambda dec: (
+        calls.append(("decode", list(dec), run.ticks)), dec_tick(dec))[1]
     run.play(reqs)
     assert all(r.done_s is not None and r.pos == 6 for r in reqs)
     # the first chunk ends a's prompt (8 + 24 of b's 100); from the second
     # iteration on a decodes beside b's chunks
     assert calls[0][:2] == ("prefill", [0, 1])
+    chunked = {c[-1] for c in calls if c[0] == "prefill"}
     if spec:
-        assert calls[1:4] == [("decode", [], True), ("prefill", [1], []),
-                              ("decode", [0], True)]
+        assert calls[1:4] == [("decode", [], 1), ("prefill", [1], [], 2),
+                              ("decode", [0], 2)]
     else:
-        assert calls[1] == ("prefill", [1], [0])
-        assert not any(c[0] == "decode" and c[2] for c in calls)
+        assert calls[1][:3] == ("prefill", [1], [0])
+        # no iteration with a chunk has a decode launch of its own
+        assert not any(c[0] == "decode" and c[-1] in chunked for c in calls)
 
 
-def test_sim_megastep_and_spec_strategies_run():
+def test_sim_spec_strategy_runs():
     prof = _profile([0.0, 0.1, 0.2, 0.3], decode=8)
-    for strat in (ServeStrategy(page_size=16, megastep_ticks=8),
-                  ServeStrategy(page_size=16, spec_width=2, spec_depth=3)):
-        res = TickSimulator(_pricer(prof)).simulate(strat, prof, seed=1)
-        assert all(r["done_s"] is not None for r in res.records)
-        assert sum(r["decode_tokens"] for r in res.records) == 4 * 8
-        assert res.metrics["backend"] == "ticksim"
+    strat = ServeStrategy(page_size=16, spec_width=2, spec_depth=3)
+    res = TickSimulator(_pricer(prof)).simulate(strat, prof, seed=1)
+    assert all(r["done_s"] is not None for r in res.records)
+    assert sum(r["decode_tokens"] for r in res.records) == 4 * 8
+    assert res.metrics["backend"] == "ticksim"
 
 
 def test_sim_pool_pressure_evicts_mid_tick_without_corruption():

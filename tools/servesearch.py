@@ -199,9 +199,7 @@ def _fmt_metrics(m) -> str:
             f"({m['pool_pages']:.0f} pool pages, "
             f"occupancy {m['pool_occupancy']:.2f})\n"
             f"    padding waste    {m['padding_waste_ratio']:10.3f}\n"
-            f"    roundtrips/token {m['host_roundtrips_per_token']:10.3f}\n"
-            f"    accepted/step    {m['expected_accepted_per_step']:10.2f}, "
-            f"fused ticks {m['expected_fused_ticks']:.2f}")
+            f"    accepted/step    {m['expected_accepted_per_step']:10.2f}")
 
 
 # per-compile wall time when no calibration artifact supplies the
