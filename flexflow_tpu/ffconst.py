@@ -162,6 +162,9 @@ class OpType(enum.Enum):
     CACHE = "cache"
     EXPERTS = "experts"
     EXPERT_SHARE = "expert_share"
+    # a residual path of several streams (manifold-constrained
+    # hyper-connections): the mixing around a block
+    HYPER_CONNECTION = "hyper_connection"
     # fused
     FUSED = "fused"
     # parallel ops (first-class PCG nodes, SURVEY.md §2.3)

@@ -239,17 +239,25 @@ class FFModel:
                          rope_beta_slow: float = 1.0,
                          rope_interleave: bool = True,
                          q_scale_beta: float = 0.0, out_gate: bool = False,
+                         index_heads: int = 0, index_dim: int = 128,
+                         index_topk: int = 2048, index_pool: int = 4,
+                         index_rope_dim: int = 64,
+                         index_rope_theta: float = 1e6,
                          name: Optional[str] = None) -> Tensor:
-        """Causal multi-head latent attention (A.LatentAttentionAttrs).
-        Each matrix is drawn Glorot-uniform over ITS fan-in and fan-out
-        (the default reads a 3-d weight as a convolution)."""
+        """Causal multi-head latent attention (A.LatentAttentionAttrs);
+        with `index_heads` > 0 the SPARSE layer whose indexer chooses the
+        blocks of `index_pool` tokens a query attends to. Each matrix is
+        drawn Glorot-uniform over ITS fan-in and fan-out (the default
+        reads a 3-d weight as a convolution)."""
         attrs = A.LatentAttentionAttrs(
             embed_dim, num_heads, q_lora_rank, kv_lora_rank,
             qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
             float(softmax_scale), float(norm_eps), float(rope_theta),
             float(rope_factor), int(rope_original_max),
             float(rope_beta_fast), float(rope_beta_slow),
-            bool(rope_interleave), float(q_scale_beta), bool(out_gate))
+            bool(rope_interleave), float(q_scale_beta), bool(out_gate),
+            int(index_heads), int(index_dim), int(index_topk),
+            int(index_pool), int(index_rope_dim), float(index_rope_theta))
         node = self._add(OpType.LATENT_ATTENTION, attrs, [input],
                          name or "latent_attention")
         h = num_heads
@@ -258,14 +266,19 @@ class FFModel:
             w_uq=_glorot(q_lora_rank or input.shape[-1],
                          h * attrs.qk_head_dim),
             w_ukv=_glorot(kv_lora_rank, h * (qk_nope_head_dim + v_head_dim)),
-            wo=_glorot(h * v_head_dim, embed_dim))
+            wo=_glorot(h * v_head_dim, embed_dim),
+            w_iq=(_glorot(q_lora_rank, index_heads * index_dim)
+                  if index_heads else None))
         return Tensor(node)
 
     def kda_attention(self, input: Tensor, embed_dim: int, num_heads: int,
                       head_dim: int, conv_taps: int = 4,
                       lower_bound: float = -5.0, norm_eps: float = 1e-6,
+                      gate_rank: Optional[int] = None,
                       name: Optional[str] = None) -> Tensor:
-        """A delta-rule linear-attention layer (A.KdaAttentionAttrs). The
+        """A delta-rule linear-attention layer (A.KdaAttentionAttrs);
+        `gate_rank` puts the decay's and the output gate's projections
+        through that rank. The
         draws that are not Glorot: taps in [-0.5, 0.5], `dt_bias` in
         [-6, -2] (log-decays of -0.01 to -0.6 a token before the input
         moves them, so the state remembers tens to hundreds of tokens),
@@ -276,7 +289,9 @@ class FFModel:
             OpType.KDA_ATTENTION,
             A.KdaAttentionAttrs(embed_dim, num_heads, head_dim,
                                 int(conv_taps), float(lower_bound),
-                                float(norm_eps)),
+                                float(norm_eps),
+                                None if gate_rank is None
+                                else int(gate_rank)),
             [input], name or "kda_attention")
         taps = UniformInitializer(-0.5, 0.5)
         self._record_init(node, conv_q=taps, conv_k=taps, conv_v=taps,
@@ -289,6 +304,7 @@ class FFModel:
                      routed_scale: float = 1.0, score: str = "softmax",
                      n_group: int = 1, topk_group: int = 1,
                      select_bias: bool = False, bias_initializer=None,
+                     swiglu_limit: float = 0.0,
                      name: Optional[str] = None) -> Tensor:
         """One chip's share (`held` = (lo, hi), default all) of a dropless
         SwiGLU expert layer with its shared expert (A.ExpertShareAttrs).
@@ -308,11 +324,32 @@ class FFModel:
             A.ExpertShareAttrs(n_experts, k, hidden_dim, int(lo), int(hi),
                                shared_hidden, norm_topk, routed_scale,
                                score, int(n_group), int(topk_group),
-                               bool(select_bias)),
+                               bool(select_bias), float(swiglu_limit)),
             [input], name or "expert_share")
         init = _glorot(input.shape[-1], hidden_dim)
         self._record_init(node, w_gate=init, w_up=init, w_down=init,
                           bias=bias_initializer if select_bias else None)
+        return Tensor(node)
+
+    def hyper_connection(self, part: str, *inputs: Tensor, streams: int = 4,
+                         sinkhorn_iters: int = 20, eps: float = 1e-6,
+                         norm_eps: float = 1e-6, initializers=None,
+                         name: Optional[str] = None):
+        """One part of the mixing of a residual of `streams` streams
+        around a block (A.HyperConnectionAttrs): "expand" x -> X, "pre"
+        X -> (h, coef), "post" (X, coef, y) -> X', "sum" X -> x.
+        `initializers` ({"phi", "b", "alpha"}, part "pre") are the
+        builder's to say: the defaults (Glorot, zeros, zeros) mix
+        nothing."""
+        node = self._add(
+            OpType.HYPER_CONNECTION,
+            A.HyperConnectionAttrs(part, int(streams), int(sinkhorn_iters),
+                                   float(eps), float(norm_eps)),
+            list(inputs), name or f"hc_{part}")
+        if initializers:
+            self._record_init(node, **initializers)
+        if part == "pre":
+            return Tensor(node, 0), Tensor(node, 1)
         return Tensor(node)
 
     def ring_attention(self, query: Tensor, key: Tensor, value: Tensor,
@@ -439,6 +476,13 @@ class FFModel:
 
     def scalar_true_divide(self, x, scalar: float, name=None):
         return self._unary("scalar_truediv", x, name, scalar=scalar)
+
+    def scalar_min(self, x, scalar: float, name=None):
+        return self._unary("scalar_min", x, name, scalar=scalar)
+
+    def clip(self, x, limit: float, name=None):
+        """x clipped to [-limit, limit]."""
+        return self._unary("clip", x, name, scalar=limit)
 
     # ---- shape ----
 

@@ -390,7 +390,30 @@ class LatentAttentionAttrs(OpAttrs):
     `q_lora_rank` None (Ling-3.0-flash) drops the query's low-rank step:
     q_h = x W_uq, W_uq (embed, heads, nope + rope), no W_dq and no norm.
     `out_gate` multiplies each head's output by sigmoid(x w_gate,h), one
-    scalar a head, before W_o."""
+    scalar a head, before W_o. `qk_rope_head_dim` 0 (GLM-5.3-Flash) is a
+    head without a rope part: no `k_r`, the cached row is `c_kv` alone.
+
+    `index_heads` > 0 makes the layer SPARSE (DeepSeek sparse attention
+    with pooled keys, GLM-5.3-Flash): an indexer of its own chooses, a
+    query, the blocks of `index_pool` tokens it attends to,
+
+        qI_i = c_q W_iq,i (i < index_heads)   kI = LayerNorm(x W_ik)
+        w = x W_w * index_heads^-1/2 * index_dim^-1/2
+        rope (interleaved pairs, `index_rope_theta`) on the first
+        `index_rope_dim` values of qI_i and kI
+        kP_b = mean of kI over block b's `index_pool` tokens (whole blocks)
+        I_{t,b} = sum_i w_{t,i} ReLU(qI_{t,i} . kP_b)   for b < t // index_pool
+        B_t = {t // index_pool} + the index_topk / index_pool - 1 blocks of
+              largest I_{t,b} (all where fewer; a tie to the lower b)
+
+    and the softmax runs over the visible tokens of B_t's blocks alone. The
+    indexer is part of THIS op and not an op beside it: it reads the op's
+    own `c_q`, and what it caches (one pooled key a block, entry "kp" of
+    the node's pool dict, `index_pool_specs`) has to live on the pages of
+    the op's latent rows so that one page table, one preemption and one
+    defragmentation move both. Absent (`index_heads` 0) the op is the
+    dense latent layer it was, bit for bit. A context of at most
+    `index_topk` tokens selects everything."""
 
     embed_dim: int
     num_heads: int
@@ -409,11 +432,49 @@ class LatentAttentionAttrs(OpAttrs):
     rope_interleave: bool = True
     q_scale_beta: float = 0.0
     out_gate: bool = False
+    index_heads: int = 0        # 0: no indexer, the dense latent layer
+    index_dim: int = 128
+    index_topk: int = 2048      # TOKENS a query attends to, its own block's too
+    index_pool: int = 4         # tokens a pooled key
+    index_rope_dim: int = 64
+    index_rope_theta: float = 1e6
+
+    def __post_init__(self):
+        if self.index_heads:
+            if self.q_lora_rank is None:
+                raise ValueError("the indexer's queries come from c_q: a "
+                                 "sparse latent layer needs q_lora_rank")
+            if (self.index_topk % self.index_pool
+                    or self.index_topk < 2 * self.index_pool):
+                raise ValueError(
+                    f"index_topk {self.index_topk} counts tokens: a multiple "
+                    f"of index_pool {self.index_pool}, two blocks or more")
+            if not 0 <= self.index_rope_dim <= self.index_dim \
+                    or self.index_rope_dim % 2:
+                raise ValueError("index_rope_dim: an even part of index_dim")
 
     @property
     def latent_width(self) -> int:
         """Values a token's cache row holds: c_kv then k_r."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def index_blocks(self) -> int:
+        """Whole blocks a query chooses beside its own."""
+        return self.index_topk // self.index_pool - 1
+
+    def index_pool_specs(self, num_pages: int, page_size: int):
+        """(shape, lanes) of the pooled keys' pool entry, or None: one row
+        of `index_dim` values a block of `index_pool` tokens, on the page
+        of those tokens."""
+        if not self.index_heads:
+            return None
+        if page_size % self.index_pool:
+            raise ValueError(
+                f"page_size {page_size} is not a multiple of index_pool "
+                f"{self.index_pool}: a block of pooled tokens may not "
+                "straddle two pages")
+        return (num_pages, page_size // self.index_pool, self.index_dim)
 
     @property
     def qk_head_dim(self) -> int:
@@ -435,6 +496,16 @@ class LatentAttentionAttrs(OpAttrs):
         }
         gate = {"w_gate": WeightSpec(TensorShape((e, h), dt))} \
             if self.out_gate else {}
+        indexer = {} if not self.index_heads else {
+            "w_iq": WeightSpec(TensorShape(
+                (self.q_lora_rank, self.index_heads, self.index_dim), dt)),
+            "w_ik": WeightSpec(TensorShape((e, self.index_dim), dt)),
+            "ik_scale": WeightSpec(TensorShape((self.index_dim,), dt),
+                                   "ones"),
+            "ik_bias": WeightSpec(TensorShape((self.index_dim,), dt),
+                                  "zeros"),
+            "w_iw": WeightSpec(TensorShape((e, self.index_heads), dt)),
+        }
         return {
             **low_rank,
             "w_uq": WeightSpec(TensorShape(
@@ -448,6 +519,7 @@ class LatentAttentionAttrs(OpAttrs):
             "wo": WeightSpec(TensorShape((h, self.v_head_dim,
                                           self.embed_dim), dt)),
             **gate,
+            **indexer,
         }
 
     def flops(self, ins, outs):
@@ -493,6 +565,10 @@ class KdaAttentionAttrs(OpAttrs):
     conv_taps: int = 4
     lower_bound: float = -5.0
     norm_eps: float = 1e-6
+    # None: W_f and W_g are full rank (Ling-3.0-flash, `no_kda_lora`);
+    # r: each goes through rank r, x W_fa W_fb and x W_ga W_gb (Kimi
+    # Linear's layout, GLM-5.3-Flash)
+    gate_rank: Optional[int] = None
 
     @property
     def inner(self) -> int:
@@ -510,13 +586,18 @@ class KdaAttentionAttrs(OpAttrs):
         def mat(*shape, init="glorot_uniform"):
             return WeightSpec(TensorShape(shape, dt), init)
 
+        r = self.gate_rank
+        w_f = ({"w_f": mat(e, c)} if r is None else
+               {"w_fa": mat(e, r), "w_fb": mat(r, c)})
+        w_g = ({"w_g": mat(e, c)} if r is None else
+               {"w_ga": mat(e, r), "w_gb": mat(r, c)})
         return {
             "wq": mat(e, c), "wk": mat(e, c), "wv": mat(e, c),
             "conv_q": mat(self.conv_taps, c), "conv_k": mat(self.conv_taps, c),
             "conv_v": mat(self.conv_taps, c),
-            "w_f": mat(e, c), "dt_bias": mat(c, init="zeros"),
+            **w_f, "dt_bias": mat(c, init="zeros"),
             "a_log": mat(h, init="zeros"), "w_beta": mat(e, h),
-            "w_g": mat(e, c), "o_norm": mat(self.head_dim, init="ones"),
+            **w_g, "o_norm": mat(self.head_dim, init="ones"),
             "wo": mat(c, e),
         }
 
@@ -533,7 +614,9 @@ class KdaAttentionAttrs(OpAttrs):
         x = ins[0]
         b, s, e = x.dims[0].size, x.dims[1].size, x.dims[-1].size
         c = self.inner
-        proj = 2 * b * s * (e * (5 * c + self.num_heads) + c * e)
+        gates = (2 * e * c if self.gate_rank is None
+                 else 2 * self.gate_rank * (e + c))
+        proj = 2 * b * s * (e * (3 * c + self.num_heads) + gates + c * e)
         return proj + 7 * b * s * c * self.head_dim
 
 
@@ -963,6 +1046,9 @@ class ExpertShareAttrs(OpAttrs):
     n_group: int = 1            # group-limited routing: of n_group groups
     topk_group: int = 1         #   of experts, the topk_group best open
     select_bias: bool = False   # a bias a routed expert, for selection only
+    # L > 0 clamps every SwiGLU, routed and shared: silu(min(g, L)) *
+    # clip(u, -L, L) (the gpt-oss form; GLM-5.3-Flash's `swiglu_limit`)
+    swiglu_limit: float = 0.0
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -1000,6 +1086,81 @@ class ExpertShareAttrs(OpAttrs):
         routed = self.k * self.n_held / self.n_experts * self.hidden_dim
         return (2 * tokens * d * self.n_experts
                 + 6 * tokens * d * (routed + self.shared_hidden))
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperConnectionAttrs(OpAttrs):
+    """The mixing of a residual path of `streams` = n streams around ONE
+    block F (manifold-constrained hyper-connections, arXiv:2512.24880;
+    GLM-5.3-Flash's `mhc`). A token's residual is X (n, C), carried
+    through the graph as one row of n C values. Four parts, one a node:
+
+      "expand"  x (.., C) -> X (.., n C): the embedding repeated n times
+      "pre"     X -> (h (.., C), coef (.., n + n n) float32): with
+                x~ = RMSNorm(vec X) (no learned scale) and the node's
+                weights phi (n C, n + n + n n), b, alpha (3,),
+                  Hpre  = sigmoid(alpha_0 x~ phi_pre + b_pre)        (n)
+                  Hpost = 2 sigmoid(alpha_1 x~ phi_post + b_post)    (n)
+                  Hres  = Sinkhorn_iters(exp(alpha_2 mat(x~ phi_res)
+                          + b_res)): rows then columns divided by their
+                          sum + `eps`, `sinkhorn_iters` times      (n, n)
+                h = Hpre X is what the block reads; coef = [Hpost | Hres]
+      "post"    (X, coef, y = F(h)) -> X' = Hres X + Hpost^T y
+      "sum"     X -> the n streams added, before the final norm
+
+    The coefficients are float32 whatever the activations. Every part is
+    a function of one token's row alone, so the dense forward and a
+    ragged serving launch share one lowering (ops/hyper_connection.py):
+    caches, states and expert kernels see width C as before."""
+
+    part: str
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        if self.part not in ("expand", "pre", "post", "sum"):
+            raise ValueError(f"hyper-connection part {self.part!r}")
+
+    @property
+    def coef_width(self) -> int:
+        return self.streams + self.streams * self.streams
+
+    def infer(self, x: Shape, *rest):
+        n, last = self.streams, x.dims[-1].size
+        lead = tuple(_carry(d) for d in x.dims[:-1])
+        if self.part == "expand":
+            return (Shape(lead + (ParallelDim(last * n),), x.dtype,
+                          x.replica),)
+        if last % n:
+            raise ValueError(f"a row of {last} values is not {n} streams")
+        if self.part == "post":
+            return (Shape(lead + (ParallelDim(last),), x.dtype, x.replica),)
+        h = Shape(lead + (ParallelDim(last // n),), x.dtype, x.replica)
+        if self.part == "sum":
+            return (h,)
+        return (h, Shape(lead + (ParallelDim(self.coef_width),),
+                         DataType.FLOAT, x.replica))
+
+    def weights(self, x: Shape, *rest):
+        if self.part != "pre":
+            return {}
+        n, dt = self.streams, x.dtype
+        width = 2 * n + n * n
+        return {
+            "phi": WeightSpec(TensorShape((x.dims[-1].size, width), dt)),
+            "b": WeightSpec(TensorShape((width,), dt), "zeros"),
+            "alpha": WeightSpec(TensorShape((3,), dt), "zeros"),
+        }
+
+    def flops(self, ins, outs):
+        x = ins[0]
+        tokens = math.prod(d.size for d in x.dims[:-1])
+        n = self.streams
+        if self.part == "pre":
+            return tokens * x.dims[-1].size * (2 * (2 * n + n * n) + 2)
+        return tokens * outs[0].dims[-1].size * 2 * n
 
 
 @dataclasses.dataclass(frozen=True)

@@ -115,10 +115,11 @@ def _select(attrs, scores, bias):
                              -jnp.inf), axis=-1), ids
 
 
-def _swiglu(x, gate, up, down):
+def _swiglu(x, gate, up, down, limit=0.0):
     dt = x.dtype
     g = jnp.dot(x, gate.astype(dt), preferred_element_type=jnp.float32)
     u = jnp.dot(x, up.astype(dt), preferred_element_type=jnp.float32)
+    g, u = ge.clamp(g, u, limit)
     h = (g * jax.nn.sigmoid(g) * u).astype(dt)
     return jnp.dot(h, down.astype(dt), preferred_element_type=jnp.float32)
 
@@ -166,7 +167,8 @@ def routed(attrs, x, ids, w, params, live=None):
         xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
         h = ge.grouped_swiglu(xs, params["w_gate"].astype(x.dtype),
                               params["w_up"].astype(x.dtype), tile_group,
-                              n_active, tm=tm, interpret=interp)
+                              n_active, tm=tm, limit=attrs.swiglu_limit,
+                              interpret=interp)
         y = ge.grouped_dot(h, params["w_down"].astype(x.dtype), tile_group,
                            n_active, tm=tm, out_dtype=jnp.float32,
                            interpret=interp)
@@ -184,7 +186,7 @@ def routed(attrs, x, ids, w, params, live=None):
         for g in range(G):
             out = out + wt[:, g:g + 1] * _swiglu(
                 x, params["w_gate"][g], params["w_up"][g],
-                params["w_down"][g])
+                params["w_down"][g], attrs.swiglu_limit)
         counts = jnp.sum(local[:, None] == jnp.arange(G), axis=0)
         padded = jnp.int32(0)
     stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
@@ -203,5 +205,5 @@ def expert_share(attrs, x, params, live=None):
                       None if live is None else live.reshape(-1))
     if attrs.shared_hidden:
         y = y + _swiglu(xt, params["shared_gate"], params["shared_up"],
-                        params["shared_down"])
+                        params["shared_down"], attrs.swiglu_limit)
     return y.astype(x.dtype).reshape(shape), stats

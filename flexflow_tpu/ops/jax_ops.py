@@ -524,8 +524,10 @@ def _latent_attention(attrs, inputs, params, ctx):
         raise NotImplementedError(
             "latent attention decodes through the page pool only: serve "
             "with serve_generation(paged=True)")
-    y, pool = la.paged_attention(attrs, x, params, ctx)
-    ctx.cache_updates["c"] = pool
+    y, pools, stats = la.paged_attention(attrs, x, params, ctx)
+    ctx.cache_updates.update(pools)
+    if stats is not None:
+        ctx.state_updates["dsa_stats"] = stats
     return [y]
 
 
@@ -631,6 +633,9 @@ def _element_unary(attrs, inputs, params, ctx):
         "scalar_sub": lambda v: v - s,
         "scalar_multiply": lambda v: v * s,
         "scalar_truediv": lambda v: v / s,
+        "scalar_min": lambda v: jnp.minimum(v, jnp.asarray(s, v.dtype)),
+        "clip": lambda v: jnp.clip(v, jnp.asarray(-s, v.dtype),
+                                   jnp.asarray(s, v.dtype)),
     }
     return [fns[k](x)]
 
@@ -968,6 +973,21 @@ def _expert_share(attrs, inputs, params, ctx):
     if ctx.page_tables is not None:
         ctx.state_updates["moe_stats"] = stats
     return [y]
+
+
+@register_lowering(OpType.HYPER_CONNECTION)
+def _hyper_connection(attrs, inputs, params, ctx):
+    """One part of the mixing of a multi-stream residual around a block
+    (ops/hyper_connection.py); row-wise, so the same in every mode."""
+    from flexflow_tpu.ops import hyper_connection as hc
+
+    if attrs.part == "expand":
+        return [hc.expand(attrs, inputs[0])]
+    if attrs.part == "pre":
+        return list(hc.pre(attrs, inputs[0], params))
+    if attrs.part == "post":
+        return [hc.post(attrs, *inputs)]
+    return [hc.collapse(attrs, inputs[0])]
 
 
 @register_lowering(OpType.EXPERTS)

@@ -40,6 +40,14 @@ def _dot32(x, w):
     return jnp.dot(x, w.astype(x.dtype), preferred_element_type=F32)
 
 
+def _gate_proj(x, params, name):
+    """x W (full rank) or (x W_a) W_b (`gate_rank`), float32."""
+    if name in params:
+        return _dot32(x, params[name])
+    return _dot32(_dot32(x, params[name + "a"]).astype(x.dtype),
+                  params[name + "b"])
+
+
 def project(attrs, x, params):
     """x (B, S, E) -> (pre (B, S, 3 H d) in x's dtype: q~ | k~ | v~ before
     the convolution, what the conv state holds; a (B, S, H, d) float32
@@ -49,12 +57,12 @@ def project(attrs, x, params):
     pre = jnp.concatenate(
         [_dot32(x, params[n]) for n in ("wq", "wk", "wv")],
         axis=-1).astype(x.dtype)
-    f = (_dot32(x, params["w_f"]) + params["dt_bias"].astype(F32)
+    f = (_gate_proj(x, params, "w_f") + params["dt_bias"].astype(F32)
          ).reshape(B, S, H, d)
     a = attrs.lower_bound * jax.nn.sigmoid(
         jnp.exp(params["a_log"].astype(F32))[:, None] * f)
     beta = jax.nn.sigmoid(_dot32(x, params["w_beta"]))
-    gate = jax.nn.sigmoid(_dot32(x, params["w_g"])).reshape(B, S, H, d)
+    gate = jax.nn.sigmoid(_gate_proj(x, params, "w_g")).reshape(B, S, H, d)
     return pre, a, beta, gate
 
 

@@ -88,7 +88,15 @@ def _col_block(k: int, n: int, itemsize: int) -> int:
     return LANES
 
 
-def _kernel(tg_ref, na_ref, x_ref, *refs, swiglu):
+def clamp(g, u, limit: float):
+    """The SwiGLU clamp on the two products before the activation:
+    (min(g, L), clip(u, -L, L)); `limit` 0 is off."""
+    if not limit:
+        return g, u
+    return jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+
+
+def _kernel(tg_ref, na_ref, x_ref, *refs, swiglu, limit):
     o_ref = refs[-1]
 
     @pl.when(pl.program_id(1) < na_ref[0])
@@ -99,12 +107,13 @@ def _kernel(tg_ref, na_ref, x_ref, *refs, swiglu):
         if swiglu:
             b = lax.dot_general(x, refs[1][...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+            a, b = clamp(a, b, limit)
             a = a * jax.nn.sigmoid(a) * b
         o_ref[...] = a.astype(o_ref.dtype)
 
 
 def _grouped(x, weights, tile_group, n_active, *, tm, out_dtype, name,
-             interpret):
+             interpret, limit=0.0):
     M, K = x.shape
     G, _, N = weights[0].shape
     tn = _col_block(K, N, weights[0].dtype.itemsize)
@@ -125,7 +134,7 @@ def _grouped(x, weights, tile_group, n_active, *, tm, out_dtype, name,
                                (row(n, m, tg, na), n)),
     )
     return pl.pallas_call(
-        functools.partial(_kernel, swiglu=len(weights) == 2),
+        functools.partial(_kernel, swiglu=len(weights) == 2, limit=limit),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -137,13 +146,15 @@ def _grouped(x, weights, tile_group, n_active, *, tm, out_dtype, name,
 
 
 def grouped_swiglu(x, w_gate, w_up, tile_group, n_active, *, tm,
-                   interpret=False):
+                   limit=0.0, interpret=False):
     """x: (rows, d) in the padded layout; w_gate, w_up: (G, d, f). Returns
     (rows, f) = silu(x Wg_g) * (x Wu_g), each tile with its group's
-    weights. Rows of tiles that are not active are not written."""
+    weights; with `limit` L > 0 the two products are clamped first
+    (`clamp`), in the kernel's epilogue. Rows of tiles that are not
+    active are not written."""
     return _grouped(x, (w_gate, w_up), tile_group, n_active, tm=tm,
                     out_dtype=x.dtype, name="moe_grouped_swiglu",
-                    interpret=interpret)
+                    interpret=interpret, limit=float(limit))
 
 
 def grouped_dot(x, w, tile_group, n_active, *, tm, out_dtype=None,

@@ -34,6 +34,17 @@ caller (ops/latent_attention.py), so the kernel's scale is 1. The device
 operation is named `mla_paged_attention`: metrics that read the GQA
 kernel by name keep reading only that one.
 
+A SPARSE latent layer (ops/latent_attention.py: an indexer keeps, a
+query row, some blocks of `block_tokens` tokens) hands both forms
+`block_keep` (B, S, blocks of the table) bool. THE MASKED WALK: the
+kernel still walks every live page of the entry and adds the row's mask
+to the scores, block of keys by block of keys; exact, and at a context
+of c tokens it does c / index_topk times the matrix work a walk of the
+selected blocks alone would (PERF.md section 7 keeps the gathering
+kernel). The mask rides into VMEM a grid step as (key blocks, window
+rows, keys) 0/1 values and is spread over the heads' folded rows by a
+one-hot matmul, as the window's visibility is.
+
 `latent_gather_attention` is the pure-JAX fallback and the CPU oracle,
 as `ragged_gather_attention` is for the GQA kernel.
 """
@@ -116,8 +127,16 @@ def latent_block_pages(page_size: int, table_width: int, lanes: int,
 # pure-JAX fallback and oracle
 
 
+def _token_keep(block_keep, block_tokens: int, length: int):
+    """(B, S, blocks) -> (B, S, length) bool: a block's verdict on each
+    of its tokens."""
+    keep = jnp.repeat(block_keep, block_tokens, axis=-1)
+    return jnp.pad(keep, ((0, 0), (0, 0), (0, length - keep.shape[-1])))
+
+
 def latent_gather_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
-                            value_lanes: int):
+                            value_lanes: int, block_keep=None,
+                            block_tokens: int = 1):
     """q: (B, S, H, lanes) absorbed, scaled queries; pool: (N, P, lanes).
     Gathers every table-mapped page and runs dense masked attention of
     all heads against the one row a token: scores over all lanes, values
@@ -131,6 +150,8 @@ def latent_gather_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
     rows = pool[page_tables].reshape(B, -1, pool.shape[2]).astype(
         jnp.float32)
     mask = ragged_visibility_mask(page_tables, pos, q_lens, anc_mask, P)
+    if block_keep is not None:
+        mask = mask & _token_keep(block_keep, block_tokens, mask.shape[-1])
     s = jnp.einsum("bshc,blc->bhsl", q.astype(jnp.float32), rows)
     p = jax.nn.softmax(jnp.where(mask[:, None], s, NEG_INF), axis=-1)
     o = jnp.einsum("bhsl,blc->bshc", p, rows[..., :value_lanes])
@@ -141,15 +162,18 @@ def latent_gather_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
 # the kernel
 
 
-def _latent_kernel(pt_ref, pos_ref, qlen_ref, q_ref, c_hbm, anc_ref, o_ref,
-                   cbuf, sems, par_ref, bias_scr, m_scr, l_scr, acc_scr, *,
+def _latent_kernel(pt_ref, pos_ref, qlen_ref, q_ref, c_hbm, anc_ref, *rest,
                    page_size, ppb, heads, value_lanes):
     """One batch entry a grid step: walk the entry's live pages in blocks
     of `ppb`, each page ONE contiguous (P, lanes) copy into the double-
     buffered block; scores of all folded rows against the block in one
     matmul, values the block's first `value_lanes` lanes. The walk, its
     prefetch across entries and the window's in-kernel visibility are
-    paged/attention.py `_ragged_kernel`'s."""
+    paged/attention.py `_ragged_kernel`'s. A sparse layer's launch has one
+    input more, `keep_ref` (key blocks, window rows, keys), which masks
+    block j's scores besides."""
+    *keep, o_ref, cbuf, sems, par_ref, bias_scr, m_scr, l_scr, acc_scr = rest
+    keep_ref = keep[0] if keep else None
     b = pl.program_id(0)
     n_entries = pl.num_programs(0)
     n_table = pt_ref.shape[1]
@@ -231,6 +255,18 @@ def _latent_kernel(pt_ref, pos_ref, qlen_ref, q_ref, c_hbm, anc_ref, o_ref,
         s = lax.dot_general(q_ref[...], c, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         s = s + bias_scr[...]
+        if keep_ref is not None:
+            # window row w's verdicts to its heads' folded rows
+            # [w heads, (w + 1) heads)
+            kw = keep_ref.shape[1]
+            frow = lax.broadcasted_iota(jnp.int32, (rows, kw), 0)
+            wrow = lax.broadcasted_iota(jnp.int32, (rows, kw), 1) * heads
+            spread = ((frow >= wrow) & (frow < wrow + heads)).astype(
+                keep_ref.dtype)
+            kept = lax.dot_general(
+                spread, keep_ref[j], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) > 0.5
+            s = jnp.where(kept, s, NEG_INF)
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -256,9 +292,11 @@ def _latent_kernel(pt_ref, pos_ref, qlen_ref, q_ref, c_hbm, anc_ref, o_ref,
                            0.0).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("value_lanes", "interpret"))
+@functools.partial(jax.jit, static_argnames=("value_lanes", "interpret",
+                                             "block_tokens"))
 def latent_flash_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
-                           value_lanes: int, interpret: bool = False):
+                           value_lanes: int, interpret: bool = False,
+                           block_keep=None, block_tokens: int = 1):
     """The latent Pallas launch. q: (B, S, H, lanes) absorbed and scaled;
     pool: (N, P, lanes); page_tables (B, max_pages); pos, q_lens (B,);
     anc_mask (B, S, S) bool. Returns (B, S, H, value_lanes); rows at or
@@ -277,6 +315,18 @@ def latent_flash_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
         jnp.repeat(anc_mask, H, axis=1).astype(jnp.bfloat16),
         ((0, 0), (0, rows - S * H), (0, window - S)))
     imap = lambda b, pt, ps, ql: (b, 0, 0)                  # noqa: E731
+    extra_in, extra_specs = (), []
+    if block_keep is not None:
+        # the rows' verdicts a TOKEN, cut into the walk's blocks of keys:
+        # (B, key blocks, window rows to the sublane tile, keys) 0/1
+        n_kb = -(-n_pages // ppb)
+        kw = _round_up(S, 16)
+        tok = _token_keep(block_keep, block_tokens, n_kb * keys)
+        tok = jnp.pad(tok, ((0, 0), (0, kw - S), (0, 0))).astype(
+            jnp.bfloat16)
+        extra_in = (tok.reshape(B, kw, n_kb, keys).transpose(0, 2, 1, 3),)
+        extra_specs = [pl.BlockSpec((None, n_kb, kw, keys),
+                                    lambda b, pt, ps, ql: (b, 0, 0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
@@ -284,7 +334,7 @@ def latent_flash_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
             pl.BlockSpec((None, rows, lanes), imap),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((None, rows, window), imap),
-        ],
+        ] + extra_specs,
         out_specs=pl.BlockSpec((None, rows, value_lanes), imap),
         scratch_shapes=[
             pltpu.VMEM((2, keys, lanes), pool.dtype),
@@ -307,7 +357,7 @@ def latent_flash_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
         interpret=interpret,
         name=KERNEL_NAME,
     )(page_tables.astype(jnp.int32), pos.astype(jnp.int32),
-      q_lens.astype(jnp.int32), qr, pool, anc_f)
+      q_lens.astype(jnp.int32), qr, pool, anc_f, *extra_in)
     return out[:, :S * H].reshape(B, S, H, value_lanes)
 
 
@@ -316,14 +366,17 @@ def latent_flash_attention(q, pool, page_tables, pos, q_lens, anc_mask, *,
 
 
 def latent_paged_attention(q, row, pool, page_tables, pos, q_lens,
-                           anc_mask, *, value_width: int):
+                           anc_mask, *, value_width: int, block_keep=None,
+                           block_tokens: int = 1):
     """One paged latent-attention step. q: (B, S, H, latent_width)
     absorbed queries with every scale folded in; row: (B, S,
     latent_width) the tokens' `[c_kv | k_r]` (k_r roped); pool: (N, P,
     lanes). Scatters the live rows into their table-mapped pages (rows
     past q_len or past the table land in the null page), then attends by
     the kernel or the gather fallback behind the one gate. Returns
-    ((B, S, H, value_width) latent outputs, new pool)."""
+    ((B, S, H, value_width) latent outputs, new pool). `block_keep`
+    (B, S, table blocks of `block_tokens` tokens) bool, a sparse layer's:
+    a row attends to the visible tokens of the blocks it keeps."""
     B, S, H, width = q.shape
     P, lanes = pool.shape[1], pool.shape[2]
     pos_v, qlen_v = jnp.asarray(pos), jnp.asarray(q_lens)
@@ -342,8 +395,12 @@ def latent_paged_attention(q, row, pool, page_tables, pos, q_lens,
     if latent_attention_available(P, interpret=interp, dtype=pool.dtype):
         out = latent_flash_attention(qp, pool, page_tables, pos_v, qlen_v,
                                      anc_mask, value_lanes=value_lanes,
-                                     interpret=interp)
+                                     interpret=interp,
+                                     block_keep=block_keep,
+                                     block_tokens=block_tokens)
     else:
         out = latent_gather_attention(qp, pool, page_tables, pos_v, qlen_v,
-                                      anc_mask, value_lanes=value_lanes)
+                                      anc_mask, value_lanes=value_lanes,
+                                      block_keep=block_keep,
+                                      block_tokens=block_tokens)
     return out[..., :value_width], pool

@@ -76,13 +76,13 @@ import functools
 import queue
 import threading
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from flexflow_tpu import obs
 from flexflow_tpu.paged.pool import EMPTY_HASH, PagePool
-from flexflow_tpu.runtime.executor import LAUNCH_STATS
+from flexflow_tpu.runtime.executor import LAUNCH_DSA_STATS, LAUNCH_STATS
 from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
 from flexflow_tpu.serving import _GenerationServerBase, _GenRequest
 
@@ -408,12 +408,19 @@ class PagedGenerationServer(_GenerationServerBase):
                 dtype=kbuf.dtype)
         self.kernel_variant = ("ragged_pallas" if kernel_ok
                                else "ragged_gather")
-        # bytes a cached token takes in the pool, over every layer
-        self.kv_bytes_per_token = sum(
-            b.shape[2] * b.dtype.itemsize
-            for nk, bufs in self._caches.items()
-            if nk not in self._state_keys
-            for n, b in bufs.items() if not n.endswith("_scale"))
+        # bytes a cached token takes in the pool, over every layer; a
+        # sparse latent layer's pooled indexer keys ("kp", one row a
+        # block of tokens on the same page) are counted apart
+        def per_token(names):
+            return sum(
+                b.shape[1] * b.shape[2] * b.dtype.itemsize // self.page_size
+                for nk, bufs in self._caches.items()
+                if nk not in self._state_keys
+                for n, b in bufs.items() if names(n))
+
+        self.kv_bytes_per_token = per_token(
+            lambda n: n != "kp" and not n.endswith("_scale"))
+        self.index_bytes_per_token = per_token(lambda n: n == "kp")
         if self._window:
             # bytes of ONE page over the layers of each class, for the
             # launch spans' pool_bytes_* (tracing only)
@@ -431,6 +438,15 @@ class PagedGenerationServer(_GenerationServerBase):
 
         self._has_moe = any(n.op_type == _OpType.EXPERT_SHARE
                             for n in ex.topo)
+        # the sparse latent layers' attrs and the residual mixings a
+        # launch runs, for `_sparse_counts`
+        self._sparse = [n.attrs for n in ex.topo
+                        if n.op_type == _OpType.LATENT_ATTENTION
+                        and n.attrs.index_heads]
+        self._hc_mixings = sum(
+            n.op_type == _OpType.HYPER_CONNECTION and n.attrs.part == "pre"
+            for n in ex.topo)
+        self._sparse_totals: Dict[str, int] = {}
         self._moe_pending: List[tuple] = []
         self._moe_totals = np.zeros((4,), np.int64)
         self._g_kernel = self.registry.gauge("ragged_kernel_active")
@@ -611,6 +627,14 @@ class PagedGenerationServer(_GenerationServerBase):
             # the loop thread owns the pending list: the totals lag by
             # the launches it has not read yet (_fold_moe_stats)
             m.update(zip(STATS, (int(v) for v in self._moe_totals)))
+        if self._sparse or self._hc_mixings:
+            # what the sparse latent layers and the residual mixings had
+            # to do, summed over the launches so far (`_sparse_counts`;
+            # `selected_distinct` is counted on the device and lags like
+            # the expert counters)
+            m["sparse"] = dict(
+                self._sparse_totals,
+                index_bytes_per_token=self.index_bytes_per_token)
         m.update({
             "preemptions": self.preemptions,
             "defrags": self.defrags,
@@ -713,12 +737,23 @@ class PagedGenerationServer(_GenerationServerBase):
             return
         done, self._moe_pending = (self._moe_pending[:n],
                                    self._moe_pending[n:])
-        for attrs, stats in done:
-            vals = np.asarray(stats, np.int64)              # (layers, 4)
-            self._moe_totals += vals.sum(axis=0)
-            if attrs is not None:
-                attrs.update({name: vals[:, i].tolist()
-                              for i, name in enumerate(STATS)})
+        from flexflow_tpu.ops.latent_attention import DSA_STATS
+
+        for attrs, stats, dsa in done:
+            if stats is not None:
+                vals = np.asarray(stats, np.int64)          # (layers, 4)
+                self._moe_totals += vals.sum(axis=0)
+                if attrs is not None:
+                    attrs.update({name: vals[:, i].tolist()
+                                  for i, name in enumerate(STATS)})
+            if dsa is not None:
+                vals = np.asarray(dsa, np.int64)    # (sparse layers, 1)
+                for i, name in enumerate(DSA_STATS):
+                    self._sparse_totals[name] = (
+                        self._sparse_totals.get(name, 0)
+                        + int(vals[:, i].sum()))
+                    if attrs is not None:
+                        attrs[name] = vals[:, i].tolist()
 
     def _kv_pool_dtype_name(self) -> str:
         """The pool's actual storage dtype name ("int8" for a quantized
@@ -1615,6 +1650,8 @@ class PagedGenerationServer(_GenerationServerBase):
             pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
                                    jnp.asarray(ids))
             fed = {"feed": (self._index_device(feed), self._newest)}
+            sparse = (self._sparse_counts(slot_idx, pos, qls)
+                      if self._sparse or self._hc_mixings else None)
             if self._state_keys:
                 fed["state_slots"] = jnp.asarray(slot_idx)
                 self._note_state_rows(slot_idx, pos, qls)
@@ -1681,17 +1718,22 @@ class PagedGenerationServer(_GenerationServerBase):
                     sp.set(state_slots=int(np.unique(slot_idx[qls > 0]).size),
                            kda_rows=int(q.sum()), kda_pieces=int(q.size),
                            state_bytes_per_slot=self.state_bytes_per_slot)
+                if sparse is not None:
+                    sp.set(index_bytes_per_token=self.index_bytes_per_token,
+                           **sparse)
             probs, upd = self._step(
                 tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
                 ids_d, **fed)
             stats = upd.pop(LAUNCH_STATS, None)
-            if stats is not None:
+            dsa = upd.pop(LAUNCH_DSA_STATS, None)
+            if stats is not None or dsa is not None:
                 # a (layers, 4) device array: read later, in bulk and long
                 # after the launch that made it has run (_fold_moe_stats:
                 # a read of a ready array is a copy, a read a tick costs
                 # the device 3.6 ms of waiting an iteration, PERF.md
                 # section 6); a traced launch's span gets it then
-                self._moe_pending.append((sp.attrs if sp else None, stats))
+                self._moe_pending.append((sp.attrs if sp else None, stats,
+                                          dsa))
                 if len(self._moe_pending) >= 128:
                     self._fold_moe_stats(keep=8)
         self._caches = upd
@@ -1712,6 +1754,39 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_rows.inc(total)
         self._c_pad.inc(padded)
         return probs, padded, total
+
+    def _sparse_counts(self, slot_idx, pos, qls) -> dict:
+        """What ONE sparse latent layer and the residual mixings have to
+        do for a launch, from its items alone (the SPARSE form's work,
+        whatever kernel does it): `index_blocks_scored`, (row, pooled
+        key) pairs, a live row at position t scoring the t // pool whole
+        blocks before its own; `selected_tokens`, the tokens the rows
+        attend to (min(t // pool, blocks chosen) whole blocks and the
+        t % pool + 1 tokens of the row's own) against `context_tokens`,
+        the t + 1 a dense layer would; `index_pages`, the pooled-key
+        pages read, once a slot; `hc_rows`, live rows times mixings. Kept
+        as totals for metrics()["sparse"] too."""
+        live = qls > 0
+        t = (np.concatenate([np.arange(p, p + q) for p, q in
+                             zip(pos[live], qls[live])])
+             if live.any() else np.zeros((0,), np.int64)).astype(np.int64)
+        out = {"hc_rows": int(t.size) * self._hc_mixings}
+        if self._sparse:
+            a = self._sparse[0]
+            pool = a.index_pool
+            horizon = {}
+            for s_, e_ in zip(slot_idx[live], (pos + qls)[live]):
+                horizon[int(s_)] = max(horizon.get(int(s_), 0), int(e_))
+            out.update(
+                index_blocks_scored=int((t // pool).sum()),
+                selected_tokens=int((np.minimum(t // pool, a.index_blocks)
+                                     * pool + t % pool + 1).sum()),
+                context_tokens=int((t + 1).sum()),
+                index_pages=sum(-(-e_ // self.page_size)
+                                for e_ in horizon.values()))
+        for k, v in out.items():
+            self._sparse_totals[k] = self._sparse_totals.get(k, 0) + v
+        return out
 
     def _note_state_rows(self, slot_idx, pos, qls):
         """The host's account of the states a launch continues (state

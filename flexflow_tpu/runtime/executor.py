@@ -67,6 +67,9 @@ _HLO_DTYPE_NAMES = {
 
 # key of a ragged step's per-node launch counters in its second result
 LAUNCH_STATS = "__launch_stats__"
+# and of its sparse latent layers' counters (ops/latent_attention.py
+# DSA_STATS), one row a layer
+LAUNCH_DSA_STATS = "__launch_dsa_stats__"
 
 
 def _pool_buffers(caches) -> list:
@@ -902,6 +905,15 @@ class Executor:
                 specs[node_key(n)] = {"c": jax.ShapeDtypeStruct(
                     (num_pages, page_size,
                      pool_lanes(n.attrs.latent_width)), dt)}
+                # a SPARSE latent layer keeps a second row of another
+                # grain on the same pages: one pooled indexer key a
+                # block of `index_pool` tokens ("kp"). A leaf of the same
+                # dict, so a page's clone, release, preemption and the
+                # defrag permutation move it with the latent rows
+                pooled = n.attrs.index_pool_specs(num_pages, page_size)
+                if pooled is not None:
+                    specs[node_key(n)]["kp"] = jax.ShapeDtypeStruct(
+                        pooled, dt)
                 continue
             shape = (num_pages, page_size, n.attrs.num_kv * n.attrs.kdim)
             specs[node_key(n)] = {
@@ -1006,6 +1018,11 @@ class Executor:
                 # node has; the caller takes it out before the pools go
                 # into the next launch
                 cache_out[LAUNCH_STATS] = jnp.stack(moe)
+            dsa = [st["dsa_stats"] for _nk, st in sorted(state.items())
+                   if "dsa_stats" in st]
+            if dsa:
+                # the sparse latent layers' counts, likewise
+                cache_out[LAUNCH_DSA_STATS] = jnp.stack(dsa)
             return out, cache_out
 
         self._ragged_step_fn = self.compile_tracker.wrap(
@@ -1245,6 +1262,7 @@ class Executor:
                                call_s=t1 - t0,
                                first_run_s=time.monotonic() - t1)
                     caches.pop(LAUNCH_STATS, None)
+                    caches.pop(LAUNCH_DSA_STATS, None)
                 # the shape's pool leaves, and how many of them came back
                 # in the device buffer they went in with
                 pool_alias[(B, W)] = (len(before), sum(

@@ -1357,6 +1357,18 @@ _GRAPH_KINDS = (
      "not per-head K and V (the dense cache, the int8 scale sidecar, tree "
      "verify's commit, the host tier's page payloads and the strategy "
      "search's pricing all assume K/V pools)"),
+    (lambda ex: any(getattr(n.attrs, "index_heads", 0) for n in ex.topo),
+     _ALL_BUT_PREFIX + ("prefix_cache",),
+     "sparse latent attention (an indexer over pooled keys): a page holds "
+     "one pooled key a block of tokens beside the latent rows, "
+     "accumulated in place while the block fills, so a prefix-cache hit "
+     "(prefix_cache=True is the default: pass False) that shares a "
+     "partial page would add to a row two requests read; the dense "
+     "cache has no pooled keys, the int8 scale sidecar and its canary no "
+     "per-head row to scale, tree verify's commit would copy latent rows "
+     "and leave the pooled sums of the rejected branch behind, the host "
+     "tier's payloads carry K/V pools, and the strategy search prices a "
+     "walk of every page, not of the blocks a row keeps"),
 )
 
 

@@ -339,3 +339,96 @@ def test_the_float32_form_is_what_the_walk_would_catch(float32_pair):
     assert wide == {("down", ("model",)), ("head", ("model",)),
                     ("gate", ("data",)), ("up", ("data",)),
                     ("down", ("data",)), ("head", ("data",))}
+
+
+# ---------------------------------------------------------------------------
+# The serving kernels at the shapes of the GLM-5.3-Flash cell (PR 48), in
+# THIS file because only one worker may load the TPU's library: the
+# largest launch, 72 items of 8 rows, a table of 520 pages of 64 tokens.
+
+
+def _one_chip(topo, shape, dtype):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(topo.devices[0]))
+
+
+@pytest.mark.parametrize("sparse", [False, True],
+                         ids=["dense-latent", "sparse-latent"])
+def test_the_latent_walk_compiles_for_the_v5e_at_the_cells_shape(
+        topo, sparse):
+    """`mla_paged_attention` with 64 heads folded over an 8-row window
+    against rows of 512 lanes, without and with the rows' verdicts a
+    block of four tokens (the masked walk's extra VMEM input)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.paged.latent import latent_flash_attention
+
+    B, W, H, lanes, pages = 72, 8, 64, 512, 520
+    args = [_one_chip(topo, (B, W, H, lanes), jnp.bfloat16),
+            _one_chip(topo, (4161, 64, lanes), jnp.bfloat16),
+            _one_chip(topo, (B, pages), jnp.int32),
+            _one_chip(topo, (B,), jnp.int32),
+            _one_chip(topo, (B,), jnp.int32),
+            _one_chip(topo, (B, W, W), jnp.bool_)]
+    if sparse:
+        args.append(_one_chip(topo, (B, W, pages * 16), jnp.bool_))
+
+    def walk(q, pool, tables, pos, q_lens, anc, keep=None):
+        return latent_flash_attention(q, pool, tables, pos, q_lens, anc,
+                                      value_lanes=lanes, block_keep=keep,
+                                      block_tokens=4)
+
+    text = jax.jit(walk).lower(*args).compile().as_text()
+    assert "mla_paged_attention" in text and "tpu_custom_call" in text
+
+
+def test_the_scan_compiles_for_the_v5e_at_64_heads(topo):
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.pallas import kda_scan
+
+    B, H, d = 72, 64, 128
+    flat = _one_chip(topo, (B, kda_scan.ROWS, H * d), jnp.float32)
+    item = _one_chip(topo, (B,), jnp.int32)
+
+    def scan(q, k, kb, v, a, state, slots, start, fresh, rows):
+        return kda_scan.kda_ragged_scan(q, k, kb, v, a, state, slots, start,
+                                        fresh, rows, heads=H)
+
+    text = jax.jit(scan).lower(
+        flat, flat, flat, flat, flat,
+        _one_chip(topo, (8, H, d, d), jnp.float32), item, item, item,
+        item).compile().as_text()
+    assert "kda_ragged_scan" in text
+
+
+def test_the_clamped_expert_kernel_and_the_selection_compile_for_the_v5e(
+        topo):
+    """The grouped SwiGLU with the clamp in its epilogue at 36 held
+    experts of 4096 x 2048, and `select_blocks` at 511 of 8,320 blocks a
+    row: a loop of comparisons and counts, no sort."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops import latent_attention as la
+    from flexflow_tpu.ops.pallas import grouped_experts as ge
+
+    A, G = 576 * 8, 36
+    tm = ge.row_tile(A, G, jnp.bfloat16)
+    tiles = ge.num_tiles(A, G, tm)
+    w = _one_chip(topo, (G, 4096, 2048), jnp.bfloat16)
+    text = jax.jit(lambda x, wg, wu, tg, na: ge.grouped_swiglu(
+        x, wg, wu, tg, na, tm=tm, limit=10.0)).lower(
+        _one_chip(topo, (tiles * tm, 4096), jnp.bfloat16), w, w,
+        _one_chip(topo, (tiles,), jnp.int32),
+        _one_chip(topo, (1,), jnp.int32)).compile().as_text()
+    assert "moe_grouped_swiglu" in text
+    text = jax.jit(lambda s, v: la.select_blocks(s, v, 511)).lower(
+        _one_chip(topo, (72, 8, 8320), jnp.float32),
+        _one_chip(topo, (72, 8, 8320), jnp.bool_)).compile().as_text()
+    assert not re.search(r"\bsort\(", text) and "while" in text

@@ -145,7 +145,8 @@ def test_absorbed_equals_naive_attention(tiny):
                    ragged_q_lens=jnp.full((1,), 40, jnp.int32),
                    ragged_depths=jnp.arange(40, dtype=jnp.int32)[None],
                    ragged_anc=jnp.tril(jnp.ones((40, 40), jnp.bool_))[None])
-    absorbed, pool = la.paged_attention(node.attrs, x, params, ctx)
+    absorbed, pools, _stats = la.paged_attention(node.attrs, x, params, ctx)
+    pool = pools["c"]
     # the same products in another order: float32 rounding only
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(naive),
                                atol=2e-5, rtol=0)
