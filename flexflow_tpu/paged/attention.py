@@ -21,12 +21,12 @@ per-slot metadata:
 
 One Pallas kernel consumes that descriptor. The grid is (batch,), with
 the page table, positions and query lengths SCALAR-PREFETCHED and the
-pools left in HBM: an entry's grid step walks the entry's LIVE pages —
-up to its visible horizon pos + q_len - 1, never the table's width — in
-blocks of `ragged_block_pages` pages, copying each page's whole
+pools left in HBM: a grid step walks LIVE pages — up to the visible
+horizon pos + q_len - 1, never the table's width — in blocks of
+`ragged_block_pages` pages, copying each page's whole
 (page_size, Hkv * D) row block from its pooled HBM location into a
 double-buffered VMEM block with one DMA, the next block (or the next
-entry's first) in flight while this one computes. No gathered copy of
+walk's first) in flight while this one computes. No gathered copy of
 the sequence ever materializes, and no (B, S, L) HBM mask is built
 either: blocks wholly below pos take no mask, and the block(s) the
 window reaches derive its visibility IN-KERNEL from `anc` via a one-hot
@@ -34,7 +34,21 @@ matmul against the block's relative positions. Padded batch entries
 (q_len == 0) walk nothing and write zeros. Every kv head is computed in
 the step its block arrives in, and GQA folds the q heads of one kv head
 into the ROW dim (row = window row x rep + head), so a head's scores are
-ONE (rows, D) x (D, keys) matmul and kv pages are read once per entry.
+ONE (rows, D) x (D, keys) matmul.
+
+ONE WALK A RUN. A chunk rides a launch as consecutive 8-row entries of
+one slot, each starting where the one before ends: a RUN (`ragged_runs`
+reads the runs from the table, pos, q_lens and anc the launch is handed;
+nothing more is uploaded; a launch narrower than a piece or of one entry
+holds none, `ragged_shares_walks`, and its kernel is the one-entry body
+alone). The run's first entry walks once, from its
+first visible page to the last entry's horizon, and every block it
+brings in is scored against every row of the run, a row tile of whole
+entries at a time, each row masked by its own cache row; the other
+entries of the run do nothing. For that the launch's folded queries,
+output and softmax statistics stay whole in VMEM. A decode row, a tree,
+a rider behind a chunk, a padded entry is a run of one and is scored as
+an entry always was. kv pages are read once per RUN.
 
 The block size is derived at trace time from the page's bytes, the
 window's rows, the table's width and the core's VMEM
@@ -62,13 +76,15 @@ from __future__ import annotations
 import functools
 import logging
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS
 
 NEG_INF = -1e30
 LANES = 128
@@ -297,51 +313,192 @@ def ragged_block_pages(page_size: int, table_width: int, lane_width: int,
     return max(1, min(ppb, _round_up(table_width, tile)))
 
 
-def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
-                   scale, page_size, ppb, rep, quantized, sliding=None):
-    """One batch entry a grid step: walk the entry's live pages in
-    blocks of `ppb`, each page ONE contiguous (P, Hkv * D) copy from its
-    pooled HBM row into a double-buffered VMEM block, the kv heads lane
-    slices of it. The q heads of a kv group are folded into the row dim
-    (row = window row x rep + head of the group), so a head's scores are
-    one (rows, D) x (D, keys) matmul. The next block — the next ENTRY's
-    first block after the last — is in flight while this one computes;
-    which buffer holds it survives the grid step in SMEM.
+# Entries a shared walk's row tile holds: a run of pieces is scored a
+# tile of whole entries at a time, so one (tile rows, D) x (D, keys)
+# product hands the matrix unit what the pieces handed it 8 rows at a
+# time. Four, because the tile's body is straight-line code that grows
+# with its rows and a program holds this kernel once a layer: at four
+# entries of 32 folded rows the kernel is 1,772 bundles where the
+# parent's was 1,957, at eight 2,317 for 9 % less time a row (PERF.md
+# section 6). Held to _SCORE_TILE_BYTES like every other score tile.
+_RUN_TILE_ENTRIES = 4
 
-    A sliding window of `sliding` rows (a static of the call) moves the walk's FIRST
-    block: it starts at the page that holds row pos - window + 1, the
-    oldest row the entry's first query sees, and blocks that hold rows
-    older than a query's window mask them by position. The pages before
-    that one are never read, and the window class of the pool has
-    released them (paged/scheduler.py)."""
+
+def ragged_shares_walks(entries: int, window: int) -> bool:
+    """Whether a launch of this SHAPE can hold a run of several entries.
+    A chunk rides as pieces of PREFILL_WINDOW_ROWS rows, the launch's
+    window then; a launch with a narrower window holds one piece a slot at
+    most (its window IS its widest item: analysis/shapecheck.py
+    `_packed_prefill_shapes`), and one entry continues nothing. Where
+    this is False `ragged_runs` merges nothing and the kernel is the
+    one-entry body alone: a launch shape's program is traced, lowered and
+    cached once a server, and 56 of Mistral-7B's 71 shapes are of this
+    kind. What a launch that CAN share does is still read from its
+    arrays."""
+    return entries > 1 and window >= PREFILL_WINDOW_ROWS
+
+
+def ragged_run_tile(window: int, rows: int, keys: int, entries: int) -> int:
+    """Entries a row tile of a shared walk holds: _RUN_TILE_ENTRIES, or
+    as many as keep the (tile rows, keys) float32 score tile within
+    _SCORE_TILE_BYTES, or as the launch has; 0 where the launch's shape
+    holds no shared walk (`ragged_shares_walks`)."""
+    if not ragged_shares_walks(entries, window):
+        return 0
+    by_score = _SCORE_TILE_BYTES // (4 * rows * keys)
+    return max(1, min(_RUN_TILE_ENTRIES, by_score, entries))
+
+
+class _Launch(NamedTuple):
+    """What `ragged_flash_attention` derives from a launch's shapes."""
+    rows: int        # an entry's folded rows, padded to the sublane tile
+    ppb: int         # pages a block
+    tile: int        # entries a shared walk's row tile (0: none shared)
+    n_rows: int      # rows of the whole-launch operands
+    resident: int    # bytes the launch keeps in VMEM from start to end
+
+
+def _launch_geometry(B: int, S: int, H: int, D: int, page_size: int,
+                     n_pages: int, lane_width: int, pool_dtype,
+                     q_dtype) -> _Launch:
+    rep = H // (lane_width // D)
+    q_item = jnp.dtype(q_dtype).itemsize
+    rows = _round_up(rep * S, 8 * (4 // q_item))
+    ppb = ragged_block_pages(page_size, n_pages, lane_width, pool_dtype,
+                             rep * S)
+    keys = ppb * page_size
+    tile = ragged_run_tile(S, rows, keys, B)
+    # a run's last row tile may reach past its end: padding behind
+    n_rows = (B + max(tile, 1) - 1) * rows
+    hkv = lane_width // D
+    # queries, output and row positions; the three statistics; the block
+    # buffers; the mask and room for a few score tiles of Mosaic's own
+    resident = (2 * hkv * n_rows * D * q_item + n_rows * LANES * 4
+                + hkv * n_rows * (2 * LANES + D) * 4
+                + 4 * keys * lane_width * jnp.dtype(pool_dtype).itemsize
+                + 6 * max(tile, 1) * rows * keys * 4)
+    return _Launch(rows, ppb, tile, n_rows, resident)
+
+
+# Mosaic's own temporaries beside what the launch keeps resident
+_VMEM_HEADROOM = 8 << 20
+
+
+def ragged_launch_fits(B: int, S: int, H: int, D: int, page_size: int,
+                       n_pages: int, lane_width: int, pool_dtype,
+                       q_dtype) -> bool:
+    """Whether a launch's whole-array operands fit the core's VMEM: the
+    folded queries, the output and the float32 statistics of EVERY entry
+    stay resident (so a run's rows can share its blocks), which grows
+    with entries x rows x kv heads where the per-entry blocks of old did
+    not. The serving cells' launches keep 3-9 MB; a speculative launch of
+    twenty 64-row trees over 8 kv heads would pass 128 MiB, and takes the
+    gather fallback (`ragged_paged_attention`) instead of failing in
+    Mosaic."""
+    geo = _launch_geometry(B, S, H, D, page_size, n_pages, lane_width,
+                           pool_dtype, q_dtype)
+    return geo.resident + 2 * _VMEM_HEADROOM <= _vmem_capacity_bytes()
+
+
+@jax.jit
+@jax.named_scope("ragged_runs")
+def ragged_runs(page_tables, pos, q_lens, anc_mask):
+    """(run_len, horizon), both (B,) int32: the RUNS of a launch, read
+    from the descriptor it is handed. Entry b CONTINUES entry b - 1 when
+    both have work, it starts where that one ends (pos[b] == pos[b-1] +
+    q_lens[b-1]), their table rows are EQUAL (the pieces of one slot are
+    handed one row; equal pages are equal bytes, whatever the slots are,
+    and equality carries from a run's first entry to its last) and both
+    are causal chains. A run is a maximal sequence of entries each
+    continuing the one before: its FIRST entry carries the run's length
+    and the horizon of its last entry, the others carry 0 and do nothing.
+    A chunk split into 8-row pieces is one run; a decode row, a tree, a
+    padded entry (length 1, horizon 0) is a run of its own, and so is
+    every entry of a launch whose shape holds no run
+    (`ragged_shares_walks`). Two slots over one shared prefix differ at
+    the page being written and are never merged."""
+    B, S, _ = anc_mask.shape
+    live = q_lens > 0
+    end = pos + q_lens
+    if not ragged_shares_walks(B, S):
+        return (jnp.ones((B,), jnp.int32),
+                jnp.where(live, end, 0).astype(jnp.int32))
+    tril = (lax.broadcasted_iota(jnp.int32, (S, S), 0)
+            >= lax.broadcasted_iota(jnp.int32, (S, S), 1))
+    ok = live & jnp.all(anc_mask == tril, axis=(1, 2))
+    same = jnp.all(page_tables[1:] == page_tables[:-1], axis=1)
+    cont = jnp.concatenate([
+        jnp.zeros((1,), jnp.bool_),
+        ok[1:] & ok[:-1] & same & (pos[1:] == end[:-1])])
+    idx = jnp.arange(B, dtype=jnp.int32)
+    # the first entry after b that starts a run (B past the last)
+    nxt = jnp.min(jnp.where((idx[None, :] > idx[:, None]) & ~cont[None, :],
+                            idx[None, :], B), axis=1)
+    run_len = jnp.where(cont, 0, nxt - idx)
+    last = jnp.sum(jnp.where(idx[None, :] == nxt[:, None] - 1, end[None, :],
+                             0), axis=1)      # end[nxt - 1], no gather
+    horizon = jnp.where(live & ~cont, last, 0)
+    return run_len.astype(jnp.int32), horizon.astype(jnp.int32)
+
+
+def _ragged_kernel(pt_ref, pos_ref, qlen_ref, hor_ref, run_ref, q_ref,
+                   k_hbm, v_hbm, *rest, scale, page_size, ppb, rep, rows,
+                   tile, quantized, sliding=None):
+    """One batch entry a grid step, one WALK a run (`ragged_runs`): the
+    run's first entry walks the run's live pages in blocks of `ppb`,
+    each page ONE contiguous (P, Hkv * D) copy from its pooled HBM row
+    into a double-buffered VMEM block, the kv heads lane slices of it,
+    and scores every block against every row of the run; the run's other
+    entries do nothing. The launch's queries, output and softmax
+    statistics stay whole in VMEM, entry e's folded rows at e * rows (row
+    = window row x rep + head of the kv group), so a head's scores are
+    one (rows, D) x (D, keys) matmul over any span of entries. The next
+    block — the next RUN's first block after the last — is in flight
+    while this one computes; which buffer holds it survives the grid
+    step in SMEM.
+
+    A run of one entry (a decode row, a tree, a rider, a padded entry) is
+    scored `rows` rows a block, its window's visibility derived from
+    `anc`. A run of several (a chunk's pieces: causal chains at
+    contiguous positions over the same pages) is scored a tile of `tile`
+    entries at a time, each row masked by its own absolute position
+    (`rowpos`); a tile past the run's end computes rows nobody reads.
+    `tile` 0 is a launch whose shape holds no run of several
+    (`ragged_shares_walks`): the kernel is then the one-entry body alone.
+
+    A sliding window of `sliding` rows (a static of the call) moves the
+    walk's FIRST block: it starts at the page that holds row pos -
+    window + 1, the oldest row the run's first query sees, and blocks
+    that hold rows older than a query's window mask them by position.
+    The pages before that one are never read, and the window class of
+    the pool has released them (paged/scheduler.py)."""
     if quantized:
-        (ks_ref, vs_ref, anc_ref, o_ref, kbuf, vbuf, sems, par_ref,
-         bias_scr, m_scr, l_scr, acc_scr) = rest
-    else:
-        (anc_ref, o_ref, kbuf, vbuf, sems, par_ref, bias_scr, m_scr,
-         l_scr, acc_scr) = rest
+        ks_ref, vs_ref, *rest = rest
+    (rowpos_ref, anc_ref, o_ref, kbuf, vbuf, sems, par_ref, bias_scr,
+     m_scr, l_scr, acc_scr) = rest
     b = pl.program_id(0)
     n_entries = pl.num_programs(0)
     n_table = pt_ref.shape[1]
-    rows, window = anc_ref.shape
+    window = anc_ref.shape[1]
     keys = ppb * page_size
     n_heads, _, D = q_ref.shape
+    shares = tile > 0
+    tile_rows = tile * rows
 
     def live_pages(e):
-        # pages up to the entry's visible horizon pos + q_len - 1; a
-        # padded entry (q_len == 0) walks nothing
-        horizon = pos_ref[e] + qlen_ref[e]
-        n = jnp.minimum((horizon + page_size - 1) // page_size, n_table)
-        return jnp.where(qlen_ref[e] > 0, n, 0)
+        # pages up to the run's visible horizon; a padded entry (horizon
+        # 0) walks nothing
+        return jnp.minimum((hor_ref[e] + page_size - 1) // page_size,
+                           n_table)
 
     def first_page(e):
-        # the page of the oldest row entry e's first query sees
+        # the page of the oldest row run e's first query sees
         return jnp.where(
-            qlen_ref[e] > 0,
+            hor_ref[e] > 0,
             jnp.maximum(pos_ref[e] - (sliding - 1), 0) // page_size, 0)
 
     def block_copies(e, j, buf, fn):
-        """fn(copy) for the K and V copies of entry e's block j."""
+        """fn(copy) for the K and V copies of run e's block j."""
         first = j * ppb
         if sliding is not None:
             first = first + first_page(e)
@@ -364,86 +521,62 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
         # a block's tail past the live pages is never copied, and its
         # scores are masked by ADDING: what VMEM held before the launch
         # must not read as NaN (NaN - 1e30, 0 x NaN)
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        step = 32 if keys % 32 == 0 else page_size
+
+        def zero_rows(i, _):
+            at = pl.ds(pl.multiple_of(i * step, step), step)
+            zero = jnp.zeros((step, kbuf.shape[2]), kbuf.dtype)
+            kbuf[0, at] = zero
+            kbuf[1, at] = zero
+            vbuf[0, at] = zero.astype(vbuf.dtype)
+            vbuf[1, at] = zero.astype(vbuf.dtype)
+            return 0
+
+        lax.fori_loop(0, keys // step, zero_rows, 0)
         par_ref[0] = 0
         block_copies(0, 0, 0, lambda c: c.start())
 
-    pos = pos_ref[b]
-    qlen = qlen_ref[b]
-    if sliding is None:
-        n_blocks = (live_pages(b) + ppb - 1) // ppb
-    else:
-        n_blocks = (live_pages(b) - first_page(b) + ppb - 1) // ppb
-        key0 = first_page(b) * page_size
-    par = par_ref[0]
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-    # blocks wholly below pos are all visible: the additive mask stays
-    # zero until the walk reaches the window
-    bias_scr[...] = jnp.zeros_like(bias_scr)
+    def span(e, n):
+        # n folded rows from entry e's first
+        return pl.ds(pl.multiple_of(e * rows, rows), n)
 
-    def start_next(j, buf):
-        # the entry's next block, or the next entry's first
-        within = j + 1 < n_blocks
-        e = jnp.minimum(jnp.where(within, b, b + 1), n_entries - 1)
+    # Stores of a constant are written as LOOPS (a head, a group of rows
+    # a step): straight-line they are a bundle a vreg of CODE, and a
+    # program holds this kernel once a layer (the compile cache and the
+    # device hold a launch shape's program each: PERF.md section 6)
+    def entries_heads(e, count, fn):
+        """fn(head, the rows of one entry) for `count` entries from e."""
+        def one(i, _):
+            fn(i % n_heads, span(e + i // n_heads, rows))
+            return 0
 
-        @pl.when(within | (b + 1 < n_entries))
-        def _():
-            block_copies(e, jnp.where(within, j + 1, 0), buf,
-                         lambda c: c.start())
+        lax.fori_loop(0, count * n_heads, one, 0)
 
-    @pl.when(n_blocks == 0)
-    def _():
-        start_next(-1, par)
+    def reset(h, at):
+        m_scr[h, at] = jnp.full((rows, LANES), NEG_INF, jnp.float32)
+        l_scr[h, at] = jnp.zeros((rows, LANES), jnp.float32)
+        acc_scr[h, at] = jnp.zeros((rows, D), jnp.float32)
 
-    def block(j, _):
-        buf = (par + j) % 2
-        start_next(j, 1 - buf)
-        block_copies(b, j, buf, lambda c: c.wait())
-        first_key = j * keys
-        if sliding is not None:
-            first_key = first_key + key0
-        at_window = first_key + keys > pos
-        if sliding is not None:
-            # a block that holds rows older than the LAST query's window
-            # masks by position too; one between the two masks nothing
-            behind = first_key <= pos + qlen - 1 - sliding
-            at_window = at_window | behind
+    def mask_rows(n, fn):
+        """bias_scr[r0 : r0 + step] = fn(r0, step) over the first n rows,
+        `step` rows a loop step."""
+        step = 32 if n % 32 == 0 else 8
 
-            @pl.when(jnp.logical_not(at_window))
-            def _():
-                bias_scr[...] = jnp.zeros_like(bias_scr)
+        def one(i, _):
+            r0 = pl.multiple_of(i * step, step)
+            bias_scr[pl.ds(r0, step)] = fn(r0, step)
+            return 0
 
-        @pl.when(at_window)
-        def _():
-            # the window's visibility without a gather and without an
-            # HBM mask: column c holds cache row first_key + c, window
-            # index rel[c] = first_key + c - pos. One-hot it against
-            # the window rows (zeroing indices past q_len) and contract
-            # with the anc relation: (anc @ onehot)[t, c] =
-            # anc[t, rel[c]] when 0 <= rel[c] < q_len, else 0 (0 / 1 in
-            # bfloat16, one term a sum: exact).
-            wrow = lax.broadcasted_iota(jnp.int32, (window, keys), 0)
-            rel = first_key - pos + lax.broadcasted_iota(
-                jnp.int32, (window, keys), 1)
-            onehot = ((rel == wrow) & (wrow < qlen)).astype(anc_ref.dtype)
-            tree_vis = lax.dot_general(
-                anc_ref[...], onehot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) > 0.5  # (rows, keys)
-            col = first_key + lax.broadcasted_iota(
-                jnp.int32, (rows, keys), 1)
-            seen = (col < pos) | tree_vis
-            if sliding is not None:
-                # folded row i is window row t = i // rep at cache row
-                # pos + t: seen iff pos + t - col < sliding, that is
-                # i < (col - pos + sliding) * rep
-                row = lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
-                seen = seen & (row < (col - pos + sliding) * rep)
-            bias_scr[...] = jnp.where(seen, 0.0, NEG_INF)
+        lax.fori_loop(0, n // step, one, 0)
 
-        bias = bias_scr[...]
+    def unmasked(n):
+        mask_rows(n, lambda r0, step: jnp.zeros((step, keys), jnp.float32))
+
+    def attend(j, buf, e, n):
+        """Fold block j (in buffer `buf`) into the statistics of the n
+        rows from entry e's first, under the additive mask bias_scr[:n]."""
+        at = span(e, n)
+        bias = bias_scr[0:n]
         # quantized pool: the int8 page is what DMA'd from HBM and what
         # the MXU contracts; the per-page, per-head scales rode in as
         # one (Hkv, keys) row block a walk block, and dequant-on-load is
@@ -455,7 +588,7 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
 
         def head(h, _):
             lanes = pl.ds(pl.multiple_of(h * D, D), D)
-            q = q_ref[h].astype(cdt)                        # (rows, D)
+            q = q_ref[h, at].astype(cdt)                    # (n, D)
             k = kbuf[buf, :, lanes].astype(cdt)             # (keys, D)
             v = vbuf[buf, :, lanes].astype(cdt)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -463,8 +596,8 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
             if quantized:
                 s = s * ks_ref[j, pl.ds(h, 1)]
             s = s + bias
-            m_prev = m_scr[h, :, 0:1]
-            l_prev = l_scr[h, :, 0:1]
+            m_prev = m_scr[h, at, 0:1]
+            l_prev = l_scr[h, at, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
@@ -474,31 +607,172 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
             pc = p.astype(cdt)                              # in VMEM
             pv = lax.dot_general(pc, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-            acc_scr[h] = acc_scr[h] * corr + pv
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            acc_scr[h, at] = acc_scr[h, at] * corr + pv
+            m_scr[h, at] = jnp.broadcast_to(m_new, (n, LANES))
+            l_scr[h, at] = jnp.broadcast_to(l_new, (n, LANES))
             return 0
 
         lax.fori_loop(0, n_heads, head, 0)
-        return 0
 
-    lax.fori_loop(0, n_blocks, block, 0)
-    par_ref[0] = (par + n_blocks) % 2
+    def flush(h, at):
+        # rows at or past q_len (rowpos -1) are forced to zero even when
+        # they accumulated prefix attention: they share the entry's
+        # pages, so the walk cannot skip them row-wise
+        l_safe = jnp.maximum(l_scr[h, at, 0:1], 1e-30)
+        o_ref[h, at] = jnp.where(rowpos_ref[at] >= 0, acc_scr[h, at] / l_safe,
+                                 0.0).astype(o_ref.dtype)
 
-    # finalize UNCONDITIONALLY: a padded entry that walked nothing must
-    # still write (zeros), not leave o_ref as garbage — and rows at or
-    # past q_len are forced to zero even when they accumulated prefix
-    # attention (they share the entry's pages, so the walk cannot skip
-    # them row-wise). Folded row i is window row i // rep.
-    live = lax.broadcasted_iota(jnp.int32, acc_scr.shape[1:], 0) < qlen * rep
+    n_run = run_ref[b]
 
-    def flush(h, _):
-        l_safe = jnp.maximum(l_scr[h, :, 0:1], 1e-30)
-        o_ref[h] = jnp.where(live, acc_scr[h] / l_safe,
-                             0.0).astype(o_ref.dtype)
-        return 0
+    @pl.when(n_run > 0)
+    def _():
+        pos = pos_ref[b]
+        qlen = qlen_ref[b]
+        key0 = 0
+        if sliding is None:
+            n_blocks = (live_pages(b) + ppb - 1) // ppb
+        else:
+            n_blocks = (live_pages(b) - first_page(b) + ppb - 1) // ppb
+            key0 = first_page(b) * page_size
+        par = par_ref[0]
+        if shares:
+            shared = n_run > 1
+            alone = jnp.logical_not(shared)
+            n_tiles = (n_run + tile - 1) // tile
+            # a shared walk's last tile may reach past the run: its rows
+            # are reset with the run's (and computed, and never read)
+            entries_heads(b, jnp.where(shared, n_tiles * tile, 1), reset)
+        else:
+            alone = True
+            entries_heads(b, 1, reset)
 
-    lax.fori_loop(0, n_heads, flush, 0)
+        def when(cond, fn):
+            # `alone` is a Python True where nothing is shared
+            fn() if cond is True else pl.when(cond)(fn)
+
+        def tiles(fn):
+            """fn(first entry, last entry) for each row tile of a shared
+            walk."""
+            def one(t, _):
+                e0 = b + t * tile
+                fn(e0, jnp.minimum(e0 + tile, b + n_run) - 1)
+                return 0
+
+            lax.fori_loop(0, n_tiles, one, 0)
+
+        # blocks wholly below pos are all visible: the additive mask
+        # stays zero until the walk reaches the window
+        when(alone, lambda: unmasked(rows))
+
+        def start_next(j, buf):
+            # the run's next block, or the next run's first
+            within = j + 1 < n_blocks
+            nxt = b + n_run
+            e = jnp.minimum(jnp.where(within, b, nxt), n_entries - 1)
+
+            @pl.when(within | (nxt < n_entries))
+            def _():
+                block_copies(e, jnp.where(within, j + 1, 0), buf,
+                             lambda c: c.start())
+
+        @pl.when(n_blocks == 0)
+        def _():
+            start_next(-1, par)
+
+        def entry_mask(first_key):
+            """The one entry's additive mask for the block at first_key,
+            from its window's `anc` relation."""
+            at_window = first_key + keys > pos
+            if sliding is not None:
+                # a block that holds rows older than the LAST query's
+                # window masks by position too; one between the two
+                # masks nothing
+                behind = first_key <= pos + qlen - 1 - sliding
+                at_window = at_window | behind
+
+                pl.when(jnp.logical_not(at_window))(lambda: unmasked(rows))
+
+            @pl.when(at_window)
+            def _():
+                # the window's visibility without a gather and without
+                # an HBM mask: column c holds cache row first_key + c,
+                # window index rel[c] = first_key + c - pos. One-hot it
+                # against the window rows (zeroing indices past q_len)
+                # and contract with the anc relation: (anc @ onehot)[t,
+                # c] = anc[t, rel[c]] when 0 <= rel[c] < q_len, else 0
+                # (0 / 1 in bfloat16, one term a sum: exact).
+                wrow = lax.broadcasted_iota(jnp.int32, (window, keys), 0)
+                rel = first_key - pos + lax.broadcasted_iota(
+                    jnp.int32, (window, keys), 1)
+                onehot = ((rel == wrow)
+                          & (wrow < qlen)).astype(anc_ref.dtype)
+                tree_vis = lax.dot_general(
+                    anc_ref[...], onehot, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) > 0.5
+                col = first_key + lax.broadcasted_iota(
+                    jnp.int32, (rows, keys), 1)
+                seen = (col < pos) | tree_vis                # (rows, keys)
+                if sliding is not None:
+                    # folded row i is window row t = i // rep at cache
+                    # row pos + t: seen iff pos + t - col < sliding,
+                    # that is i < (col - pos + sliding) * rep
+                    row = lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+                    seen = seen & (row < (col - pos + sliding) * rep)
+                bias_scr[0:rows] = jnp.where(seen, 0.0, NEG_INF)
+
+        def tile_step(j, buf, first_key, e0, e1):
+            """Block j against the tile of entries e0..e1 of a run of
+            chains: row r sits at cache row rowpos[r] and sees the keys
+            at or before it (and within its window)."""
+            lo = pos_ref[e0]
+            hi = pos_ref[e1] + qlen_ref[e1] - 1
+            last_key = first_key + keys - 1
+            reach = first_key <= hi
+            clear = last_key <= lo
+            if sliding is not None:
+                reach = reach & (last_key > lo - sliding)
+                clear = clear & (first_key > hi - sliding)
+
+            pl.when(reach & clear)(lambda: unmasked(tile_rows))
+
+            def by_position(r0, step):
+                at_row = rowpos_ref[pl.ds(e0 * rows + r0, step)]  # (step, 1)
+                col = first_key + lax.broadcasted_iota(
+                    jnp.int32, (step, keys), 1)
+                seen = col <= at_row
+                if sliding is not None:
+                    seen = seen & (at_row - col < sliding)
+                return jnp.where(seen, 0.0, NEG_INF)
+
+            pl.when(reach & jnp.logical_not(clear))(
+                lambda: mask_rows(tile_rows, by_position))
+
+            @pl.when(reach)
+            def _():
+                attend(j, buf, e0, tile_rows)
+
+        def block(j, _):
+            buf = (par + j) % 2
+            start_next(j, 1 - buf)
+            block_copies(b, j, buf, lambda c: c.wait())
+            first_key = key0 + j * keys
+
+            def entry():
+                entry_mask(first_key)
+                attend(j, buf, b, rows)
+
+            when(alone, entry)
+            if shares:
+                pl.when(shared)(lambda: tiles(
+                    functools.partial(tile_step, j, buf, first_key)))
+            return 0
+
+        lax.fori_loop(0, n_blocks, block, 0)
+        par_ref[0] = (par + n_blocks) % 2
+
+        # finalize UNCONDITIONALLY: a padded entry that walked nothing
+        # must still write (zeros), not leave its rows as garbage
+        entries_heads(b, n_run, flush)
 
 
 @functools.partial(jax.jit,
@@ -506,51 +780,79 @@ def _ragged_kernel(pt_ref, pos_ref, qlen_ref, q_ref, k_hbm, v_hbm, *rest,
 def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                            q_lens, anc_mask, *, scale: float,
                            interpret: bool = False, k_scales=None,
-                           v_scales=None, window: Optional[int] = None):
+                           v_scales=None, window: Optional[int] = None,
+                           runs=None):
     """The ragged Pallas launch. q: (B, S, H, D) — S is the launch's
     window width, per-entry real work is q_lens[b] <= S rows;
     kc/vc_pages: (N, P, Hkv*D) flat-lane pages (module docstring);
     page_tables: (B, max_pages); pos, q_lens: (B,); anc_mask: (B, S, S)
-    bool window visibility. The page table, positions AND query lengths
-    ride scalar prefetch; the pools stay in HBM and the kernel copies
-    each live page of an entry itself, `ragged_block_pages` pages a
-    block. The anc relation is one VMEM block per batch entry — the
-    only mask state, O(B*S^2) instead of a (B, S, L) HBM mask. The q
-    heads of a kv group fold into the row dim (row = s * rep + r), rows
-    padded to the sublane tile and the window to a lane multiple so
-    every in-kernel matmul is tile-aligned. For a quantized pool,
+    bool window visibility. The page table, positions, query lengths AND
+    the launch's runs (`runs`, what `ragged_runs` derives from those same
+    arrays: nothing more is uploaded; derived here when the caller has
+    not) ride scalar prefetch; the pools
+    stay in HBM and the kernel copies each live page of a RUN itself,
+    `ragged_block_pages` pages a block. The anc relation is one VMEM
+    block per batch entry — the only mask state, O(B*S^2) instead of a
+    (B, S, L) HBM mask. The q heads of a kv group fold into the row dim
+    (row = s * rep + r), rows padded to the sublane tile and the window
+    to a lane multiple so every in-kernel matmul is tile-aligned; the
+    folded queries and the output are whole in VMEM, entry after entry
+    along the rows, with `tile - 1` entries of padding behind the last
+    (a run's last row tile may reach past its end), and a launch whose
+    resident operands do not fit the core's VMEM is refused by name
+    (`ragged_launch_fits`; `ragged_paged_attention` falls back before it
+    gets here). For a quantized pool,
     k_scales/v_scales are the (N, Hkv) sidecar: the table-mapped scales
     are gathered here and repeated along each page's rows (B * max_pages
     * Hkv * P floats, what the per-page blocks held before), one
-    (Hkv, keys) block a walk block. Rows at or past q_lens[b] output
-    zeros. Jitted so that the layers of a model trace and lower ONE
-    kernel a launch shape (two where window and full layers mix:
-    `window` is a static of the call)."""
+    (Hkv, keys) block a walk block; a run's are its first entry's. Rows
+    at or past q_lens[b] output zeros. Jitted so that the layers of a
+    model trace and lower ONE kernel a launch shape (two where window and
+    full layers mix: `window` is a static of the call)."""
     B, S, H, D = q.shape
     P = kc_pages.shape[1]
     Hkv = kc_pages.shape[2] // D
     rep = H // Hkv
     n_pages = page_tables.shape[1]
-    rows = _round_up(rep * S, 8 * (4 // q.dtype.itemsize))
+    shape = (B, S, H, D, P, n_pages, Hkv * D, kc_pages.dtype, q.dtype)
+    rows, ppb, tile, n_rows, resident = _launch_geometry(*shape)
     wcols = _round_up(S, LANES)     # the window, a whole lane tile
-    ppb = ragged_block_pages(P, n_pages, Hkv * D, kc_pages.dtype, rep * S)
     keys = ppb * P
+    if not interpret and not ragged_launch_fits(*shape):
+        raise ValueError(
+            f"a ragged launch of {B} entries x {rows} folded rows x {Hkv} "
+            f"kv heads keeps {resident >> 20} MiB in VMEM, the core has "
+            f"{_vmem_capacity_bytes() >> 20}: take the gather path "
+            "(ragged_paged_attention does)")
     quantized = k_scales is not None
     if quantized and window is not None:
         raise ValueError("a window layer's pool is not quantized: the "
                          "scale blocks follow the table from its start")
-    # (B, S, Hkv, rep, D) -> (B, Hkv, S * rep, D): a kv group's heads
-    # are adjacent rows of one tile
-    qr = q.reshape(B, S, Hkv, rep, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, Hkv, S * rep, D)
-    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - S * rep), (0, 0)))
+    pos = pos.astype(jnp.int32)
+    q_lens = q_lens.astype(jnp.int32)
+    run_len, horizon = runs or ragged_runs(page_tables, pos, q_lens,
+                                           anc_mask)
+    # (B, S, Hkv, rep, D) -> (Hkv, B * rows, D): a kv group's heads are
+    # adjacent rows of one tile, an entry's tiles adjacent too
+    qr = q.reshape(B, S, Hkv, rep, D).transpose(2, 0, 1, 3, 4).reshape(
+        Hkv, B, S * rep, D)
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - S * rep), (0, 0))
+                 ).reshape(Hkv, B * rows, D)
+    # the cache row each folded row sits at; -1 where it is no work
+    i = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    rowpos = jnp.where(i < q_lens[:, None] * rep, pos[:, None] + i // rep,
+                       -1).reshape(B * rows, 1)
+    if n_rows > B * rows:
+        behind = (0, n_rows - B * rows)
+        qr = jnp.pad(qr, ((0, 0), behind, (0, 0)))
+        rowpos = jnp.pad(rowpos, (behind, (0, 0)), constant_values=-1)
     anc_f = jnp.pad(
         jnp.repeat(anc_mask, rep, axis=1).astype(jnp.bfloat16),
         ((0, 0), (0, rows - S * rep), (0, wcols - S)))
 
-    qmap = lambda b, pt, ps, ql: (b, 0, 0, 0)               # noqa: E731
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_specs = [
-        pl.BlockSpec((None, Hkv, rows, D), qmap),
+        whole,
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -566,43 +868,47 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
             operands.append(jnp.broadcast_to(
                 rows_sc[..., None],
                 (B, n_blocks, Hkv, ppb, P)).reshape(B, n_blocks, Hkv, keys))
-            in_specs.append(pl.BlockSpec((None, n_blocks, Hkv, keys), qmap))
-    in_specs.append(pl.BlockSpec((None, rows, wcols),
-                                 lambda b, pt, ps, ql: (b, 0, 0)))
-    operands.append(anc_f)
+            in_specs.append(pl.BlockSpec((None, n_blocks, Hkv, keys),
+                                         lambda b, *_: (b, 0, 0, 0)))
+    in_specs += [whole, pl.BlockSpec((None, rows, wcols),
+                                     lambda b, *_: (b, 0, 0))]
+    operands += [rowpos, anc_f]
 
+    scratch = [
+        pltpu.VMEM((2, keys, Hkv * D), kc_pages.dtype),
+        pltpu.VMEM((2, keys, Hkv * D), vc_pages.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.VMEM((max(tile, 1) * rows, keys), jnp.float32),
+        pltpu.VMEM((Hkv, n_rows, LANES), jnp.float32),
+        pltpu.VMEM((Hkv, n_rows, LANES), jnp.float32),
+        pltpu.VMEM((Hkv, n_rows, D), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=5,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, Hkv, rows, D), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((2, keys, Hkv * D), kc_pages.dtype),
-            pltpu.VMEM((2, keys, Hkv * D), vc_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((rows, keys), jnp.float32),
-            pltpu.VMEM((Hkv, rows, LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rows, LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rows, D), jnp.float32),
-        ],
+        out_specs=whole,
+        scratch_shapes=scratch,
     )
     static = {} if window is None else {"sliding": int(window)}
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=scale, page_size=P,
-                          ppb=ppb, rep=rep, quantized=quantized, **static),
+                          ppb=ppb, rep=rep, rows=rows, tile=tile,
+                          quantized=quantized, **static),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hkv, n_rows, D), q.dtype),
         # the walk carries its buffer parity and an in-flight copy from
         # one entry to the next: the grid is a sequence
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, resident + _VMEM_HEADROOM)),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(page_tables.astype(jnp.int32), pos.astype(jnp.int32),
-      q_lens.astype(jnp.int32), *operands)
-    return out[:, :, :S * rep].reshape(B, Hkv, S, rep, D).transpose(
-        0, 2, 1, 3, 4).reshape(B, S, H, D)
+    )(page_tables.astype(jnp.int32), pos, q_lens, horizon, run_len,
+      *operands)
+    return out[:, :B * rows].reshape(Hkv, B, rows, D)[:, :, :S * rep].reshape(
+        Hkv, B, S, rep, D).transpose(1, 2, 0, 3, 4).reshape(B, S, H, D)
 
 
 # ---------------------------------------------------------------------------
@@ -672,13 +978,26 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
         ks = vs = None
 
     force_interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
-    if paged_attention_available(q.shape[-1], P, interpret=force_interp,
-                                 dtype=kc.dtype):
-        out = ragged_flash_attention(q, kc, vc, page_tables, pos_v,
-                                     qlen_v, anc_mask, scale=scale,
-                                     interpret=force_interp,
-                                     k_scales=ks, v_scales=vs,
-                                     window=window)
+    shape = (B, S, q.shape[2], q.shape[3], P, page_tables.shape[1],
+             kc.shape[2], kc.dtype, q.dtype)
+    kernel = paged_attention_available(q.shape[-1], P,
+                                       interpret=force_interp,
+                                       dtype=kc.dtype)
+    if kernel and not force_interp and not ragged_launch_fits(*shape):
+        kernel = _reject(
+            f"a launch of {B} entries x {S} rows keeps more in VMEM than "
+            "the core has",
+            (q.shape[-1], P, kc.dtype.name, jax.default_backend()))
+    if kernel:
+        # derived HERE, in the step's own trace, where the layers of a
+        # launch are handed the same arrays: XLA merges their equal
+        # derivations into one a launch (inside the kernel's jit each
+        # layer would run its own)
+        out = ragged_flash_attention(
+            q, kc, vc, page_tables, pos_v, qlen_v, anc_mask, scale=scale,
+            interpret=force_interp, k_scales=ks, v_scales=vs,
+            window=window,
+            runs=ragged_runs(page_tables, pos_v, qlen_v, anc_mask))
     else:
         out = ragged_gather_attention(q, kc, vc, page_tables, pos_v,
                                       qlen_v, anc_mask, scale=scale,

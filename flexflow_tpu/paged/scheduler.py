@@ -354,6 +354,10 @@ class PagedGenerationServer(_GenerationServerBase):
         self._flight: "collections.deque[_Flight]" = collections.deque()
         self.launches = 0
         self.launches_ahead = 0
+        # the ragged kernel's walks: items with work, and those of them
+        # that rode the walk of the item before (`_walks`)
+        self.kv_pieces = 0
+        self.kv_pieces_shared = 0
         self._synced = 0
         self.fences: dict = {}
         self.launches_drained: dict = {}
@@ -651,6 +655,9 @@ class PagedGenerationServer(_GenerationServerBase):
             },
             "launches_dispatched": self.launches,
             "launches_ahead": self.launches_ahead,
+            "kv_pieces": self.kv_pieces,
+            "kv_walks": self.kv_pieces - self.kv_pieces_shared,
+            "kv_pieces_shared": self.kv_pieces_shared,
             "launches_drained": dict(self.launches_drained),
             "fences": dict(self.fences),
             "late_stop_rows": self.late_stop_rows,
@@ -1631,6 +1638,7 @@ class PagedGenerationServer(_GenerationServerBase):
                     deps[i] = d
                 if a is not None:
                     anc[i] = a
+            rode = self._walks(items, window, slot_idx, pos, qls, chain)
         with obs.span("launch_h2d") as sp:
             if sp:
                 sp.set(tables_dirty=self._tables_dev is None)
@@ -1677,7 +1685,14 @@ class PagedGenerationServer(_GenerationServerBase):
                 q = qls[qls > 0].astype(np.int64)
                 p0 = pos[qls > 0].astype(np.int64)
                 P = self.page_size
-                pages = -(-(p0 + q) // P)
+                # a page counts ONCE a walk: the horizon of a run of
+                # pieces is its last piece's (a kernel that reads a
+                # chunk's prefix once must not read over 100 %)
+                run = np.cumsum(~rode[qls > 0]) - 1
+                horizon = np.zeros((int(run[-1]) + 1 if run.size else 0,),
+                                   np.int64)
+                np.maximum.at(horizon, run, p0 + q)
+                pages = -(-horizon // P)
                 lanes, pool_dt, rep = self._block_geom
                 if self._latent:
                     from flexflow_tpu.paged.latent import latent_block_pages
@@ -1687,21 +1702,23 @@ class PagedGenerationServer(_GenerationServerBase):
                     # pages the launch has to read, each ONCE a slot: the
                     # pieces of one slot's chunk walk the same prefix, and
                     # a kernel that read it once must not read over 100 %
-                    horizon = {}
+                    by_slot = {}
                     for s_, e_ in zip(slot_idx[qls > 0], p0 + q):
-                        horizon[int(s_)] = max(horizon.get(int(s_), 0),
+                        by_slot[int(s_)] = max(by_slot.get(int(s_), 0),
                                                int(e_))
                     sp.set(latent_pages=sum(-(-e_ // P)
-                                            for e_ in horizon.values()),
+                                            for e_ in by_slot.values()),
                            kv_bytes_per_token=self.kv_bytes_per_token)
                 else:
                     ppb = ragged_block_pages(P, self.max_pages_per_seq,
                                              lanes, pool_dt, rep * window)
                 sp.set(rows=total, padded_rows=padded,
-                       kv_rows=int((p0 + q).sum()),
+                       kv_rows=int(horizon.sum()),
                        kv_pages=int(pages.sum()),
                        kv_blocks=int((-(-pages // ppb)).sum()),
                        block_pages=ppb,
+                       kv_pieces=int(q.size), kv_walks=int(horizon.size),
+                       kv_pieces_shared=int(q.size - horizon.size),
                        qk_pairs=int((q * p0 + q * (q + 1) // 2).sum()))
                 if self._window:
                     sp.set(**self._window_counts(slot_idx[qls > 0], p0, q))
@@ -1754,6 +1771,34 @@ class PagedGenerationServer(_GenerationServerBase):
         self._c_rows.inc(total)
         self._c_pad.inc(padded)
         return probs, padded, total
+
+    def _walks(self, items, window, slot_idx, pos, qls, chain):
+        """(B,) bool: the items the host EXPECTS to ride the walk of the
+        item before them in the ragged kernel: both have work, are causal
+        chains of one slot, and this one starts where that one ends. A
+        chunk's pieces after its first; never a decode row, a tree or a
+        filler. What the kernel does it reads on the device from the
+        table rows it is handed (paged/attention.py `ragged_runs`; one
+        slot is one row); tests/test_paged.py holds the two equal on the
+        scheduler's own launches. The latent kernel walks once a piece
+        (paged/latent.py): nothing rides there."""
+        from flexflow_tpu.paged.attention import ragged_shares_walks
+
+        rode = np.zeros((len(items),), np.bool_)
+        # a decode launch has one item a slot: nothing to look at
+        same = slot_idx[1:] == slot_idx[:-1]
+        if (not self._latent and ragged_shares_walks(len(items), window)
+                and same.any()):
+            ok = qls > 0
+            if not chain:       # a drafted tree is a walk of its own
+                ok &= np.fromiter(
+                    (d is None and a is None
+                     for (_s, _p, _t, d, a) in items), np.bool_, len(items))
+            rode[1:] = (ok[1:] & ok[:-1] & same
+                        & (pos[1:] == pos[:-1] + qls[:-1]))
+        self.kv_pieces += int(np.count_nonzero(qls))
+        self.kv_pieces_shared += int(np.count_nonzero(rode))
+        return rode
 
     def _sparse_counts(self, slot_idx, pos, qls) -> dict:
         """What ONE sparse latent layer and the residual mixings have to

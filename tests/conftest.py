@@ -10,6 +10,7 @@ backend initialization)."""
 import dataclasses
 import os
 
+import numpy as np
 import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -50,6 +51,7 @@ METRICS_ADDED_SINCE = {
     ),
     "test_the_mellum2_files_load_and_keep_the_published_widths": (  # PR 36's
         "launch_ahead_share.prefill",           # PR 37
+        "walk_shared_share",                    # PR 49
     ),
 }
 
@@ -105,3 +107,40 @@ def _the_launch_shapes_two_tiny_cells_were_written_about(request,
         return res
 
     monkeypatch.setattr(harness, "run_cell", run_cell)
+
+
+def _walks_against_runs(srv, drive):
+    """Every launch `drive()` makes `srv` dispatch, as (rode, table rows,
+    pos, q_lens, anc): the host's `_walks` beside the arrays the step was
+    handed, after holding that the items the host counts as riding are
+    exactly the entries `ragged_runs` gives no walk of their own (in
+    every class of tables, where the graph has two)."""
+    from flexflow_tpu.paged.attention import ragged_runs
+
+    seen = []
+    walks, step = srv._walks, srv._step
+
+    def rec_walks(*a):
+        seen.append([walks(*a)])
+        return seen[-1][0]
+
+    def rec_step(tr, ntr, caches, tbl, pos, qls, deps, anc, ids, **fed):
+        seen[-1] += [np.asarray(x) for x in (tbl, pos, qls, anc)]
+        return step(tr, ntr, caches, tbl, pos, qls, deps, anc, ids, **fed)
+
+    srv._walks, srv._step = rec_walks, rec_step
+    try:
+        drive()
+    finally:
+        srv._walks, srv._step = walks, step
+    assert seen
+    for rode, tbl, pos, qls, anc in seen:
+        for rows in (tbl if tbl.ndim == 3 else tbl[None]):
+            run_len, _ = ragged_runs(rows, pos, qls, anc)
+            np.testing.assert_array_equal(np.asarray(run_len) == 0, rode)
+    return seen
+
+
+@pytest.fixture
+def walks_against_runs():
+    return _walks_against_runs

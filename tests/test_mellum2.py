@@ -277,6 +277,35 @@ def test_admission_checks_both_budgets(tiny):
                                 60, np.int32), 4)
 
 
+def test_host_walks_equal_device_runs_in_both_classes(tiny,
+                                                      walks_against_runs):
+    """A chunk's pieces ride one walk in BOTH classes of tables: the
+    host's count (`_walks`) against `ragged_runs` on the rows each class's
+    layers are handed, over every launch of two requests, pages of the
+    window class being released and reused meanwhile
+    (tests/test_paged.py `test_host_walks_equal_device_runs` has the
+    other kinds of launch)."""
+    _cfg, ff = tiny
+    srv = ff.serve_generation(paged=True, slots=2, max_len=96,
+                              page_size=PAGE, prefill_chunk=24,
+                              prefix_cache=False)
+    rng = np.random.default_rng(8)
+
+    def drive():
+        futs = [srv.submit(rng.integers(0, VOCAB, n, dtype=np.int32), 6)
+                for n in (70, 41)]
+        for f in futs:
+            assert len(f.result()) == 6
+
+    try:
+        seen = walks_against_runs(srv, drive)
+    finally:
+        srv.stop()
+    assert all(tbl.ndim == 3 and len(tbl) == 2 for _r, tbl, *_ in seen)
+    assert sum(int(r.sum()) for r, *_ in seen) >= 8
+    assert srv.window_pages_released > 0
+
+
 def test_preemption_requeue_and_defrag_act_on_both_classes(tiny):
     """(c) a full class too small for two long requests: the younger is
     preempted, both its tables are freed, it is requeued and recomputed,
@@ -538,7 +567,10 @@ def test_launch_spans_count_each_class(tiny):
 # the (2, 1) decode launch and a (3, 8) packed launch, through the gather
 # fallback and with the Pallas kernel interpreted (its body is then part of
 # the text). Taken with this container's jax; another jax prints another
-# text, so the test then only checks the argument list.
+# text, so the test then only checks the argument list. The two "kernel"
+# lines were taken again at PR 49, which rewrote the kernel's body (one walk
+# a run); the "gather" lines, where the kernel is not in the text, are still
+# PR 34's: nothing but the kernel moved.
 PARENT_JAX = "0.9.0"
 PARENT_STEP_SHA256 = {
     ("gather", 2, 1):
@@ -546,9 +578,9 @@ PARENT_STEP_SHA256 = {
     ("gather", 3, 8):
         "b7262c81116e4de601ea44ef57a53004100b609c445f0d47f057cb71966e6b5f",
     ("kernel", 2, 1):
-        "8b416a6dd57ee41c873e6be88d4eb8d646053a73cba6a1964b01ad3009af05e9",
+        "1fd8a251b8757b6e6c22bed6a6e6063ce952914bc813d9e7d819c9afafd25cd1",
     ("kernel", 3, 8):
-        "4ea16e4068db8db35dd3ddacbb4fe6db11c34893b8ce85760db29b628f5a08b5",
+        "7f44c499c34fb8e766f3f8b8f440acb73872e346f980580a8d77cd68bae072b9",
 }
 
 

@@ -952,6 +952,39 @@ def _paged_pair(ff, lcfg, rec_annotation=None, **kw):
     return prompts, tokens, records
 
 
+def test_traced_chunk_pieces_share_one_walk():
+    """A 20-token prompt under a 24-token budget rides ONE launch as
+    pieces of 8, 8 and 4 rows: three pieces, one walk, its pages counted
+    once, on the `launch_dispatch` span and in `metrics()`; the decode
+    launches after it share nothing."""
+    ff, lcfg = _causal_lm()
+    rs = np.random.RandomState(9)
+    prompt = rs.randint(0, lcfg.vocab_size, (20,)).astype(np.int32)
+    rec = obs.enable()
+    srv = ff.serve_generation(slots=2, max_len=32, paged=True, page_size=8,
+                              prefill_chunk=24)
+    try:
+        srv.submit(prompt, max_new_tokens=3).result(timeout=300)
+        m = srv.metrics()
+    finally:
+        srv.stop()
+        obs.disable()
+    launches = [e[4] for e in sorted(rec.events, key=lambda e: e[1])
+                if e[0] == "launch_dispatch"]
+    first = launches[0]
+    assert (first["kv_pieces"], first["kv_walks"],
+            first["kv_pieces_shared"]) == (3, 1, 2)
+    assert first["kv_rows"] == 20 and first["kv_pages"] == 3
+    assert first["kv_blocks"] == 1
+    assert first["qk_pairs"] == 20 * 21 // 2
+    for later in launches[1:]:
+        assert later["kv_pieces"] == later["kv_walks"] == 1
+        assert later["kv_pieces_shared"] == 0
+    assert m["kv_pieces"] == sum(a["kv_pieces"] for a in launches)
+    assert m["kv_walks"] == sum(a["kv_walks"] for a in launches)
+    assert m["kv_pieces_shared"] == 2
+
+
 def test_disabled_paged_tick_builds_no_span_attrs_or_beacon(monkeypatch):
     """With tracing off a paged server's whole tick path (launch phases,
     sample, fetch, commit, beacon) touches NULL_SPAN only: no Span is
@@ -1073,30 +1106,44 @@ def test_traced_paged_tick_phases(tmp_path):
             continue
         assert len(launch) == 1
         got = launch[0][4]
-        items = []                              # (pos, q_len) with work
+        items, seqs = [], []            # (pos, q_len) with work, whose
         if tick[0] == "prefill_tick":
             W = min(W_MAX, max(tick[4]["takes"]))
             for seq, take in zip(tick[4]["rids"], tick[4]["takes"]):
                 for off in range(0, take, W):
                     items.append((filled[seq] + off, min(W, take - off)))
+                    seqs.append(seq)
                 filled[seq] += take
             if tick[4]["decode_rode"]:
                 # the decoding slots' q_len 1 items, behind the pieces
                 assert ticks[n + 1][0] == "decode_tick"
                 items += [(plen[seq] + made[seq] - 1, 1)
                           for seq in ticks[n + 1][4]["rids"]]
+                seqs += ticks[n + 1][4]["rids"]
                 rode += 1
         else:
             items = [(plen[seq] + made[seq] - 1, 1)
                      for seq in tick[4]["rids"]]
-        assert got["kv_rows"] == sum(p + q for p, q in items)
-        assert got["kv_pages"] == sum(-(-(p + q) // P) for p, q in items)
+            seqs = list(tick[4]["rids"])
+        # a page counts once a WALK: a piece that continues the item
+        # before it (one request's chunk) rides that item's walk
+        walks = []                              # horizon of each walk
+        for i, (p, q) in enumerate(items):
+            if i and seqs[i] == seqs[i - 1] and p == sum(items[i - 1]):
+                walks[-1] = p + q
+            else:
+                walks.append(p + q)
+        assert got["kv_pieces"] == len(items)
+        assert got["kv_walks"] == len(walks)
+        assert got["kv_pieces_shared"] == len(items) - len(walks)
+        assert got["kv_rows"] == sum(walks)
+        assert got["kv_pages"] == sum(-(-e // P) for e in walks)
         # the walk's blocks at the kernel's derived block size: the fill
         # a trace shows is kv_pages / (kv_blocks * block_pages)
         ppb = got["block_pages"]
         assert 1 <= ppb and got["kv_pages"] <= got["kv_blocks"] * ppb
-        assert got["kv_blocks"] == sum(
-            -(-(-(-(p + q) // P)) // ppb) for p, q in items)
+        assert got["kv_blocks"] == sum(-(-(-(-e // P)) // ppb)
+                                       for e in walks)
         assert got["qk_pairs"] == sum(
             sum(p + i for i in range(1, q + 1)) for p, q in items)
         assert got["rows"] - got["padded_rows"] == sum(q for _p, q in items)

@@ -6,6 +6,7 @@ and logits-identical at the decode-step level — the page indirection is
 a memory layout, never a numerics change.
 """
 
+import functools
 import time
 
 import numpy as np
@@ -286,6 +287,293 @@ def test_ragged_kernel_matches_gather_reference(H, Hkv, S, mix, dtype):
         # the kernel's contract: rows at or past q_len are exact zeros
         # (the gather fallback's garbage rows differ — both discarded)
         assert not got[b, n:].any(), f"entry {b} {kind} padded tail"
+
+
+# ---------------------------------------------------------------------------
+# runs: consecutive entries that continue one another over the same pages
+# share ONE walk (paged/attention.py `ragged_runs`)
+
+# name -> (entries, window, pool, expected run lengths). An entry is
+# (kind, slot, pos, q_len): entries of one slot share a table row, "share"
+# slots share slot 0's pages below the page their first entry writes,
+# "share+" slots that page too.
+# Geometry: page 64, 8 q / 2 kv heads x 128 (32 folded rows an entry), a
+# table 40 pages wide: blocks of 1024 keys (float32) and row tiles of 4
+# entries, so a run of 16 pieces is four tiles over two blocks.
+_PIECES = 8
+
+
+def _pieces(slot, start, n, last=_PIECES):
+    return [("chunk", slot, start + _PIECES * i,
+             last if i == n - 1 else _PIECES) for i in range(n)]
+
+
+_RUN_CASES = {
+    # 16 pieces of one slot crossing a block (1024) and a page boundary
+    "run16_full": (_pieces(0, 950, 16), None, "float32", [16] + [0] * 15),
+    "run16_full_bf16": (_pieces(0, 950, 16), None, "bfloat16",
+                        [16] + [0] * 15),
+    # a sliding layer, window shorter and longer than the prefix
+    "run16_window_short": (_pieces(0, 950, 16), 200, "float32",
+                           [16] + [0] * 15),
+    "run16_window_long": (_pieces(0, 950, 16), 4000, "float32",
+                          [16] + [0] * 15),
+    # a window of about a block: blocks before, at and behind the windows
+    "run16_window_block": (_pieces(0, 2100, 16), 1030, "float32",
+                           [16] + [0] * 15),
+    "run_riders": (_pieces(0, 1000, 5) + [("decode", 1, 70, 1),
+                                          ("decode", 2, 1500, 1),
+                                          ("decode", 3, 0, 1)],
+                   None, "float32", [5, 0, 0, 0, 0, 1, 1, 1]),
+    "run_riders_window": (_pieces(0, 1000, 5) + [("decode", 1, 70, 1),
+                                                 ("decode", 2, 1500, 1)],
+                          300, "float32", [5, 0, 0, 0, 0, 1, 1]),
+    "two_runs": (_pieces(0, 500, 4) + _pieces(1, 1010, 10), None,
+                 "float32", [4, 0, 0, 0, 10] + [0] * 9),
+    "broken_by_pad": (_pieces(0, 300, 3) + [("pad", 0, 0, 0)]
+                      + _pieces(0, 324, 3), None, "float32",
+                      [3, 0, 0, 1, 3, 0, 0]),
+    "short_last": (_pieces(0, 1016, 4, last=3), None, "float32",
+                   [4, 0, 0, 0]),
+    "short_last_window": (_pieces(0, 1016, 4, last=3), 100, "float32",
+                          [4, 0, 0, 0]),
+    # equal tables, positions that do not continue: no run across the gap
+    "not_contiguous": ([("chunk", 0, 100, 8), ("chunk", 0, 108, 8),
+                        ("chunk", 0, 200, 8), ("chunk", 0, 208, 8)],
+                       None, "float32", [2, 0, 2, 0]),
+    # two slots over one prefix, at contiguous positions: the page being
+    # written differs, so neither rides the other's walk
+    "prefix_shared": ([("chunk", 0, 120, 8), ("share", 1, 128, 8),
+                       ("share", 1, 136, 8)], None, "float32", [1, 2, 0]),
+    # three entries at contiguous positions, the second slot's row equal
+    # to the first's up to the second entry's horizon and different at the
+    # page the third reaches: compared a pair at a time up to the later
+    # entry's horizon all three would be one run, walked over the FIRST
+    # entry's row, and the third would read another slot's page
+    "rows_differ_later": ([("chunk", 0, 176, 8), ("share+", 1, 184, 8),
+                           ("share+", 1, 192, 8)], None, "float32",
+                          [1, 2, 0]),
+    "tree_between": ([("chunk", 0, 1000, 8), ("tree", 1, 1008, 5),
+                      ("chunk", 0, 1008, 8), ("chunk", 0, 1016, 8)],
+                     None, "float32", [1, 1, 2, 0]),
+    "int8_run": (_pieces(0, 1000, 4) + [("decode", 1, 40, 1)], None,
+                 "int8", [4, 0, 0, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_CASES))
+def test_ragged_kernel_runs_share_one_walk(case):
+    """The kernel (interpreted) against the gather reference over
+    launches whose entries form runs, and `ragged_runs` itself: which
+    entries share a walk is read from (table, pos, q_lens, anc) alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.paged.attention import (
+        ragged_block_pages,
+        ragged_flash_attention,
+        ragged_gather_attention,
+        ragged_run_tile,
+        ragged_runs,
+    )
+    from flexflow_tpu.paged.quant import quantized_append
+
+    entries, window, pool, want_runs = _RUN_CASES[case]
+    H, Hkv, D, P, MAXP, S = 8, 2, 128, 64, 40, _PIECES
+    qdt = "float32" if pool == "int8" else pool
+    ppb = ragged_block_pages(P, MAXP, Hkv * D, pool, (H // Hkv) * S)
+    B = len(entries)
+    if pool == "float32":
+        assert ppb * P == 1024
+        assert ragged_run_tile(S, (H // Hkv) * S, ppb * P, 16) == 4
+    slots = sorted({s for _, s, _, _ in entries})
+    N = len(slots) * MAXP + 1
+    rs = np.random.RandomState(len(case))
+    perm = (rs.permutation(N - 1) + 1).reshape(len(slots), MAXP)
+    tables = {s: perm[i].astype(np.int32) for i, s in enumerate(slots)}
+    for kind, s, p, _ in entries:
+        if kind in ("share", "share+"):   # slot 0's pages below the one
+            first = min(e[2] for e in entries if e[1] == s)     # written
+            upto = first // P + (kind == "share+")
+            tables[s][:upto] = tables[0][:upto]
+    pt = jnp.asarray(np.stack([tables[s] for _, s, _, _ in entries]))
+    pos = jnp.asarray(np.array([e[2] for e in entries], np.int32))
+    q_lens = jnp.asarray(np.array([e[3] for e in entries], np.int32))
+    anc = jnp.asarray(np.stack([
+        _ragged_anc(k, S, n)
+        if k in ("tree", "pad") else np.tril(np.ones((S, S), bool))
+        for k, _, _, n in entries]))
+    ks = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(ks[0], (B, S, H, D), qdt)
+    sc = {}
+    if pool == "int8":
+        rows = jax.random.normal(ks[1], (2, N, P, Hkv, D), jnp.float32)
+        page = jnp.broadcast_to(jnp.arange(N)[:, None], (N, P))
+        off = jnp.broadcast_to(jnp.arange(P)[None], (N, P))
+        (kc, k_sc), (vc, v_sc) = (
+            quantized_append(jnp.zeros((N, P, Hkv * D), jnp.int8),
+                             jnp.zeros((N, Hkv), jnp.float32), x, page,
+                             off, jnp.ones((N, P), bool)) for x in rows)
+        sc = {"k_scales": k_sc, "v_scales": v_sc}
+    else:
+        kc = jax.random.normal(ks[1], (N, P, Hkv * D), pool)
+        vc = jax.random.normal(ks[2], (N, P, Hkv * D), pool)
+
+    run_len, horizon = ragged_runs(pt, pos, q_lens, anc)
+    assert list(np.asarray(run_len)) == want_runs
+    ends = np.asarray(pos + q_lens)
+    for b, n in enumerate(want_runs):
+        live = n and int(q_lens[b])
+        assert int(horizon[b]) == (ends[b + n - 1] if live else 0)
+
+    kw = dict(scale=1.0 / np.sqrt(D), window=window, **sc)
+    ref = np.asarray(ragged_gather_attention(q, kc, vc, pt, pos, q_lens,
+                                             anc, **kw), np.float32)
+    got = np.asarray(ragged_flash_attention(q, kc, vc, pt, pos, q_lens,
+                                            anc, interpret=True, **kw),
+                     np.float32)
+    tol = 2e-2 if pool == "bfloat16" else 2e-5
+    for b, (kind, _, _, n) in enumerate(entries):
+        np.testing.assert_allclose(got[b, :n], ref[b, :n], atol=tol,
+                                   rtol=tol, err_msg=f"entry {b} {kind}")
+        assert not got[b, n:].any(), f"entry {b} {kind} padded tail"
+    if case == "tree_between":
+        # a tree is a run of its own whatever rides beside it: the same
+        # bits as the launch that holds nothing else
+        alone = ragged_flash_attention(
+            q[1:2], kc, vc, pt[1:2], pos[1:2], q_lens[1:2], anc[1:2],
+            interpret=True, **kw)
+        np.testing.assert_array_equal(got[1], np.asarray(alone[0],
+                                                         np.float32))
+
+
+@pytest.mark.parametrize("case", ["chunk_riders", "trees", "pad"])
+def test_host_walks_equal_device_runs(case, walks_against_runs):
+    """What rides a walk is decided twice: on the device from the arrays
+    of the launch (`ragged_runs`, what the kernel does) and on the host
+    from the items (`_walks`, what `kv_pages`, `kv_blocks` and
+    `walk_shared_share` count). On the scheduler's own launches the two
+    agree: a chunk's pieces with decode rows riding behind them and a
+    narrow last launch, a speculative server's trees beside a chunk, and
+    a run broken by an item without rows. (A graph with window layers
+    and its two classes of tables: tests/test_mellum2.py.)"""
+    ff, lcfg = _causal_lm()
+    rs = np.random.RandomState(3)
+    kw = {}
+    if case == "trees":
+        from flexflow_tpu.spec import SpecConfig
+
+        kw["speculate"] = SpecConfig(width=2, depth=2)
+    srv = ff.serve_generation(slots=3, max_len=64, paged=True, page_size=8,
+                              prefill_chunk=24, **kw)
+
+    def prompt(n):
+        return rs.randint(0, lcfg.vocab_size, (n,)).astype(np.int32)
+
+    def drive():
+        first = srv.submit(prompt(5), max_new_tokens=14)
+        later = [srv.submit(prompt(n), max_new_tokens=3) for n in (43, 27)]
+        for f in [first] + later:
+            f.result(timeout=300)
+
+    try:
+        if case != "pad":
+            seen = walks_against_runs(srv, drive)
+        else:
+            # three pieces, an item without rows, three pieces that go on
+            # where the first three ended: two walks of three
+            srv.generate(prompt(50), max_new_tokens=1)
+            pos = np.array([0, 8, 16, 0, 24, 32, 40], np.int32)
+            qls = np.array([8, 8, 8, 0, 8, 8, 8], np.int32)
+            slot_idx = np.zeros((7,), np.int32)
+            items = [(0, int(p), [1] * int(n), None, None)
+                     for p, n in zip(pos, qls)]
+            tbl = np.asarray(srv._tables_device())[slot_idx]
+            anc = np.tile(np.tril(np.ones((8, 8), bool)), (7, 1, 1))
+
+            def drive():
+                srv._walks(items, 8, slot_idx, pos, qls, True)
+                srv._step(None, None, None, tbl, pos, qls, None, anc, None)
+
+            step = srv._step
+            srv._step = lambda *a, **k: None
+            try:
+                seen = walks_against_runs(srv, drive)
+            finally:
+                srv._step = step
+            assert list(seen[0][0]) == [False, True, True, False, False,
+                                        True, True]
+    finally:
+        srv.stop()
+    rode = [r for r, *_ in seen]
+    assert any(r.sum() >= 2 for r in rode)          # a chunk's pieces
+    if case == "chunk_riders":
+        # decode rows behind the pieces, and a launch narrower than a piece
+        assert any(r.sum() and not r[-1] and q[-1] == 1
+                   for r, _t, _p, q, _a in seen)
+        assert any(a.shape[1] < 8 for *_, a in seen)
+    if case == "trees":
+        assert any((a != np.tril(np.ones(a.shape[1:], bool))).any()
+                   for *_, a in seen)
+
+
+# the widths of the two serving configurations the benchmark runs
+# (H, D, page, table width, pool lanes), bfloat16 pools and queries
+_MISTRAL_7B = (32, 128, 64, 64, 1024, "bfloat16", "bfloat16")
+_MELLUM2 = (32, 128, 64, 516, 512, "bfloat16", "bfloat16")
+
+
+def test_ragged_launch_vmem_is_held_to_the_core(monkeypatch, caplog):
+    """The launch's queries, output and statistics are whole in VMEM, so
+    what a launch keeps there grows with its entries: the cells' largest
+    launches and the widest a speculative catalogue packs fit and lower
+    for the TPU; a launch that does not fit is refused by name in the
+    wrapper and takes the gather path through the one entry point,
+    instead of failing in Mosaic."""
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.analysis.shapecheck import enumerate_catalog
+    from flexflow_tpu.paged import attention as pa
+
+    assert pa.ragged_launch_fits(15, 8, *_MISTRAL_7B)
+    assert pa.ragged_launch_fits(19, 8, *_MELLUM2)
+    shapes = enumerate_catalog(slots=8, max_len=4096, spec_max_nodes=64)[
+        "entries"]["ragged_step"]["shapes"]
+    B, S = max(shapes, key=lambda bw: bw[0] * bw[1])
+    assert (B, S) == (8, 64) and pa.ragged_launch_fits(B, S, *_MISTRAL_7B)
+    assert not pa.ragged_launch_fits(24, 64, *_MISTRAL_7B)
+
+    H, D, P, width, lanes, pdt, qdt = _MISTRAL_7B
+
+    def launch(B, S):
+        args = (jnp.zeros((B, S, H, D), qdt), jnp.zeros((B, S, 8, D), qdt),
+                jnp.zeros((B, S, 8, D), qdt), jnp.zeros((80, P, lanes), pdt),
+                jnp.zeros((80, P, lanes), pdt),
+                jnp.zeros((B, width), jnp.int32), jnp.zeros((B,), jnp.int32),
+                jnp.full((B,), S, jnp.int32), jnp.zeros((B, S), jnp.int32),
+                jnp.ones((B, S, S), bool))
+        return functools.partial(pa.ragged_paged_attention,
+                                 scale=0.1), args
+
+    fn, args = launch(B, S)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *args).mlir_module()
+    assert "tpu_custom_call" in text
+    fn, args = launch(24, 64)
+    pa.reset_rejection_log()
+    with caplog.at_level(logging.WARNING, logger=pa.__name__):
+        text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+            *args).mlir_module()
+    assert "tpu_custom_call" not in text
+    assert "24 entries x 64 rows" in caplog.text
+    with pytest.raises(ValueError, match="MiB in VMEM"):
+        jax.eval_shape(functools.partial(
+            pa.ragged_flash_attention, scale=0.1), args[0], *args[3:8],
+            args[9])
 
 
 # ---------------------------------------------------------------------------

@@ -61,7 +61,7 @@ def _tree_anc(S):
     return ancestor_masks(parents[None])[0]
 
 
-def _ragged_fns(S):
+def _ragged_fns(S, window=None):
     """(kernel fn, reference fn) over one argument list
     (q, kc, vc, pt, pos, q_lens, anc[, k_scales, v_scales])."""
     from flexflow_tpu.paged.attention import (
@@ -74,7 +74,8 @@ def _ragged_fns(S):
     def run(impl):
         def fn(q, kc, vc, pt, pos, q_lens, anc, *sc):
             skw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
-            out = impl(q, kc, vc, pt, pos, q_lens, anc, scale=scale, **skw)
+            out = impl(q, kc, vc, pt, pos, q_lens, anc, scale=scale,
+                       window=window, **skw)
             # rows at or past q_len are garbage by contract on both paths
             live = jnp.arange(S)[None, :] < q_lens[:, None]
             return jnp.where(live[..., None, None], out, 0)
@@ -126,8 +127,12 @@ def _ragged_case(kind, S, qdt, pdt, P, seed=0):
 
 # the benchmark's serving launches: (window, [(slot, pos, q_len)]). A
 # decode tick is one row a slot; a 64-token chunk rides ONE packed launch
-# as eight 8-row pieces of the same slot (paged/scheduler.py), each
-# walking the prefix below it; a padded entry has q_len 0.
+# as eight 8-row pieces of the same slot (paged/scheduler.py), all
+# sharing ONE walk of the prefix below them (a run, paged/attention.py); a
+# padded entry has q_len 0. `chunk128` is Mellum2's launch
+# (benchmark/configs/mellum2-12b-serve1.json: 4 kv heads, a table 516 wide):
+# a 128-token chunk as 16 pieces at position 6,200 and three decode rows
+# behind it, in a full layer and in a sliding one.
 BENCH_LAUNCHES = {
     "decode": (1, [(i, p, 1) for i, p in enumerate(
         (100, 180, 250, 330, 400, 470, 560, 639))]),
@@ -135,19 +140,27 @@ BENCH_LAUNCHES = {
     "packed": (8, [(0, 1500 + 8 * i, 8) for i in range(4)]
                + [(1, 0, 5), (2, 63, 1), (3, 200, 3), (4, 0, 0)]),
 }
+_CHUNK128 = (8, [(0, 6200 + 8 * i, 8) for i in range(16)]
+             + [(1, 3000, 1), (2, 9000, 1), (3, 500, 1)])
+BENCH_LAUNCHES["chunk128_full"] = _CHUNK128
+BENCH_LAUNCHES["chunk128_window"] = _CHUNK128
+# kind -> (kv heads, table width, pool pages, sliding window)
+BENCH_GEOMETRY = {"chunk128_full": (4, 516, 320, None),
+                  "chunk128_window": (4, 516, 320, 1024)}
 
 
 def _bench_case(kind, seed=0):
     """(fn, args, ref_fn) for one launch of the benchmark's server: page
     64, bfloat16 pool and q, a table 64 pages wide whose entries past a
     slot's live pages are the null page, as the server leaves them."""
-    P, MAXP, N = 64, 64, 128
+    P = 64
+    hkv, MAXP, N, window = BENCH_GEOMETRY.get(kind, (HKV, 64, 128, None))
     S, entries = BENCH_LAUNCHES[kind]
     B = len(entries)
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, S, H, D), jnp.bfloat16)
-    kc = jnp.asarray(rs.randn(N, P, HKV * D), jnp.bfloat16)
-    vc = jnp.asarray(rs.randn(N, P, HKV * D), jnp.bfloat16)
+    kc = jnp.asarray(rs.randn(N, P, hkv * D), jnp.bfloat16)
+    vc = jnp.asarray(rs.randn(N, P, hkv * D), jnp.bfloat16)
     free = list(rs.permutation(N - 1) + 1)
     tables = {}
     for slot, p, ql in entries:
@@ -160,7 +173,7 @@ def _bench_case(kind, seed=0):
     pos = jnp.asarray(np.array([p for _, p, _ in entries], np.int32))
     q_lens = jnp.asarray(np.array([ql for _, _, ql in entries], np.int32))
     anc = jnp.asarray(np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
-    fn, ref = _ragged_fns(S)
+    fn, ref = _ragged_fns(S, window)
     return fn, (q, kc, vc, pt, pos, q_lens, anc), ref
 
 
