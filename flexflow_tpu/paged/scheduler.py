@@ -82,7 +82,11 @@ import numpy as np
 
 from flexflow_tpu import obs
 from flexflow_tpu.paged.pool import EMPTY_HASH, PagePool
-from flexflow_tpu.runtime.executor import LAUNCH_DSA_STATS, LAUNCH_STATS
+from flexflow_tpu.runtime.executor import (
+    LAUNCH_DSA_STATS,
+    LAUNCH_STATS,
+    launch_columns,
+)
 from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS, ServeStrategy
 from flexflow_tpu.serving import _GenerationServerBase, _GenRequest
 
@@ -354,6 +358,8 @@ class PagedGenerationServer(_GenerationServerBase):
         self._flight: "collections.deque[_Flight]" = collections.deque()
         self.launches = 0
         self.launches_ahead = 0
+        # host-to-device transfers `_launch` made for its descriptors
+        self.launch_uploads = 0
         # the ragged kernel's walks: items with work, and those of them
         # that rode the walk of the item before (`_walks`)
         self.kv_pieces = 0
@@ -655,6 +661,7 @@ class PagedGenerationServer(_GenerationServerBase):
             },
             "launches_dispatched": self.launches,
             "launches_ahead": self.launches_ahead,
+            "launch_uploads": self.launch_uploads,
             "kv_pieces": self.kv_pieces,
             "kv_walks": self.kv_pieces - self.kv_pieces_shared,
             "kv_pieces_shared": self.kv_pieces_shared,
@@ -1531,13 +1538,15 @@ class PagedGenerationServer(_GenerationServerBase):
 
     def _tables_device(self):
         """The (slots, max_pages) page-table matrix on device, uploaded
-        only when admission/growth/release/defrag dirtied it."""
+        only when admission/growth/release/defrag dirtied it. A launch
+        does not read it (its items' rows ride the launch's one upload,
+        `_launch`); the speculative server's commit does."""
         import jax.numpy as jnp
 
         if self._tables_dev is None:
             # with window layers, a table a class: (2, slots, max_pages),
             # the full class's first (Executor.page_classes)
-            # a COPY: the host writes `_tables` in place while a launch
+            # a COPY: the host writes `_tables` in place while a program
             # handed this upload is still in flight, and the CPU backend
             # aliases a numpy buffer it is given
             self._tables_dev = jnp.asarray(
@@ -1559,10 +1568,11 @@ class PagedGenerationServer(_GenerationServerBase):
 
     def _index_device(self, index):
         """A small int32 index vector on the device, uploaded once a
-        VALUE: which entries of a launch read `_newest` and which slots a
-        pick writes there change only when the slots' occupants do, so an
-        iteration that launches ahead adds no upload to the launch
-        before's (0.35 ms each on the chip, PERF.md section 6)."""
+        VALUE: which slots a pick writes in `_newest` changes only when
+        the slots' occupants do, so an iteration's pick adds no upload to
+        its launch's one (about 0.25 ms each on the chip, PERF.md section
+        6). Which entries of a launch READ `_newest` rides that launch's
+        packed descriptor (`_launch`)."""
         import jax.numpy as jnp
 
         key = index.tobytes()
@@ -1578,17 +1588,24 @@ class PagedGenerationServer(_GenerationServerBase):
         for a (B, window) launch: depths 0..window-1 and the lower-
         triangular ancestor relation, identical every tick of the same
         shape — only tree launches (speculative verify) override them."""
-        import jax.numpy as jnp
-
         key = (B, window)
         hit = self._chain_desc_cache.get(key)
         if hit is None:
             deps = np.tile(np.arange(window, dtype=np.int32), (B, 1))
             anc = np.tile(np.tril(np.ones((window, window), np.bool_)),
                           (B, 1, 1))
-            hit = (jnp.asarray(deps), jnp.asarray(anc))
+            hit = (self._upload(deps), self._upload(anc))
             self._chain_desc_cache[key] = hit
         return hit
+
+    def _upload(self, array):
+        """One host-to-device transfer of a launch's descriptors,
+        counted where it is made (`launch_uploads`; the `launch_h2d`
+        span's `uploads`)."""
+        import jax.numpy as jnp
+
+        self.launch_uploads += 1
+        return jnp.asarray(array)
 
     def _launch(self, items, window, tr, ntr):
         """Run ONE ragged step over packed work items. Each item is
@@ -1604,17 +1621,23 @@ class PagedGenerationServer(_GenerationServerBase):
         slots simply aren't packed. Returns (probs, padded, total) with
         probs (len(items), window, vocab); padding is also rolled into
         the launch counters and the per-tick waste gauge."""
-        import jax.numpy as jnp
-
         B = len(items)
         with obs.span("launch_build") as sp:
             if sp:
                 sp.set(items=B, window=window)
-            ids = np.zeros((B, window), np.int32)
-            pos = np.zeros((B,), np.int32)
-            qls = np.zeros((B,), np.int32)
-            slot_idx = np.zeros((B,), np.int32)
-            feed = np.full((B,), -1, np.int32)
+            # ONE int32 array a launch carries everything the device is
+            # told about its items (executor.launch_columns): `ids`,
+            # `pos`, ... below are views into it. A FRESH array every
+            # launch: launch N is in flight while the host builds N + 1,
+            # and the CPU backend aliases a numpy buffer it is handed
+            at, width = launch_columns(
+                window, 2 if self._window else 1,
+                table_cols=self.max_pages_per_seq)
+            packed = np.zeros((B, width), np.int32)
+            ids, pos, qls = (packed[:, at["ids"]], packed[:, at["pos"]],
+                             packed[:, at["q_lens"]])
+            slot_idx, feed = packed[:, at["slot"]], packed[:, at["feed"]]
+            feed[:] = -1
             # the causal-chain default (decode rows, chunk pieces) is a
             # pure function of the launch shape — reuse its device copy
             # instead of re-uploading it every tick; only drafted trees
@@ -1638,30 +1661,25 @@ class PagedGenerationServer(_GenerationServerBase):
                     deps[i] = d
                 if a is not None:
                     anc[i] = a
+            # the items' table ROWS, gathered here: a few kilobytes, and
+            # the host's tables are the only copy a launch reads
+            for cols_c, tables in zip(at["tables"],
+                                      (self._tables, self._tables_w)):
+                packed[:, cols_c] = tables[slot_idx]
             rode = self._walks(items, window, slot_idx, pos, qls, chain)
         with obs.span("launch_h2d") as sp:
-            if sp:
-                sp.set(tables_dirty=self._tables_dev is None)
+            made = self.launch_uploads
             if chain:
                 deps_d, anc_d = self._chain_descriptor_device(B, window)
             else:
-                deps_d, anc_d = jnp.asarray(deps), jnp.asarray(anc)
-            # page tables ride the dirty-flagged device mirror: the
-            # canonical one-item-per-slot decode launch uses it as-is,
-            # packed launches gather their rows on device from a (B,)
-            # index upload
-            tbl = self._tables_device()
-            if B != self.slots or not np.array_equal(
-                    slot_idx, np.arange(self.slots, dtype=np.int32)):
-                tbl = jnp.take(tbl, jnp.asarray(slot_idx),
-                               axis=1 if self._window else 0)
-            pos_d, qls_d, ids_d = (jnp.asarray(pos), jnp.asarray(qls),
-                                   jnp.asarray(ids))
-            fed = {"feed": (self._index_device(feed), self._newest)}
+                deps_d, anc_d = self._upload(deps), self._upload(anc)
+            fed = {"packed": self._upload(packed),
+                   "feed": (None, self._newest)}
+            if sp:
+                sp.set(launches=1, uploads=self.launch_uploads - made)
             sparse = (self._sparse_counts(slot_idx, pos, qls)
                       if self._sparse or self._hc_mixings else None)
             if self._state_keys:
-                fed["state_slots"] = jnp.asarray(slot_idx)
                 self._note_state_rows(slot_idx, pos, qls)
         total = B * window
         padded = total - int(qls.sum())
@@ -1739,8 +1757,8 @@ class PagedGenerationServer(_GenerationServerBase):
                     sp.set(index_bytes_per_token=self.index_bytes_per_token,
                            **sparse)
             probs, upd = self._step(
-                tr, ntr, self._caches, tbl, pos_d, qls_d, deps_d, anc_d,
-                ids_d, **fed)
+                tr, ntr, self._caches, None, None, None, deps_d, anc_d,
+                **fed)
             stats = upd.pop(LAUNCH_STATS, None)
             dsa = upd.pop(LAUNCH_DSA_STATS, None)
             if stats is not None or dsa is not None:
@@ -1759,9 +1777,11 @@ class PagedGenerationServer(_GenerationServerBase):
             # launch against the fp32 shadow cache; the running max abs
             # output delta over LIVE rows stays on device — metrics()
             # materializes it into the kv_quant_error gauge on scrape
+            import jax.numpy as jnp
+
             probs_ref, upd_ref = self._step(
-                tr, ntr, self._caches_ref, tbl, pos_d, qls_d, deps_d,
-                anc_d, ids_d, **fed)
+                tr, ntr, self._caches_ref, None, None, None, deps_d,
+                anc_d, **fed)
             self._caches_ref = upd_ref
             live_rows = jnp.asarray(
                 np.arange(window)[None, :] < qls[:, None])

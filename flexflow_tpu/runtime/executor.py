@@ -72,6 +72,27 @@ LAUNCH_STATS = "__launch_stats__"
 LAUNCH_DSA_STATS = "__launch_dsa_stats__"
 
 
+def launch_columns(window: int, classes: int = 1, *, table_cols=None,
+                   width=None):
+    """Where a launch's PACKED DESCRIPTOR keeps what: the one (B, width)
+    int32 array a server uploads a launch and `ragged_step_fn`'s `packed`
+    takes apart (docs/paged.md "The launch descriptor"). A row is an
+    item: its `window` token ids, then `pos`, `q_lens`, its `slot` (the
+    state a slot's index too) and the slot its first id is `feed` from
+    or -1, one column each, then its row of the page table, once a class
+    of pages (the full class's first). Sized by a table's columns (the
+    host, which fills it) or by the array's width (the program, which
+    slices it by the same map). Returns ({name: slice or column}, width)."""
+    at = {"ids": slice(0, window), "pos": window, "q_lens": window + 1,
+          "slot": window + 2, "feed": window + 3}
+    lo = window + 4
+    if table_cols is None:
+        table_cols = (width - lo) // classes
+    at["tables"] = [slice(lo + c * table_cols, lo + (c + 1) * table_cols)
+                    for c in range(classes)]
+    return at, lo + classes * table_cols
+
+
 def _pool_buffers(caches) -> list:
     """The device buffer addresses of a pool's leaves, in tree order (a
     tuple a leaf: one address an addressable shard). What tells a pool
@@ -981,7 +1002,13 @@ class Executor:
         server's ((B,) int32 slot or -1, (slots,) int32 newest tokens),
         takes an entry's first id from the device (a decode row of the
         launch after the one that picked its token); without it the
-        program is the one it always was.
+        program is the one it always was. `packed`, a server's ONE upload
+        a launch (`launch_columns`: ids, pos, q_lens, slots, feed slots
+        and table rows in one (B, cols) int32 array), is sliced apart in
+        the program where `page_tables`, `pos`, `q_lens` and `ids` are
+        passed None, `feed` is (None, newest) and `state_slots` is left
+        out: the same program but for the slices, and without the keyword
+        the positional form to the byte.
 
         The pools are DONATED: the K/V rows are scattered into the
         buffers passed in, which are gone for the caller (rebind the
@@ -991,8 +1018,26 @@ class Executor:
         if self._ragged_step_fn is not None:
             return self._ragged_step_fn
 
+        classes = 1 if self.page_classes() is None else 2
+        stateful = bool(self.state_layers())
+
         def step(trainable, nontrainable, caches, page_tables, pos,
-                 q_lens, depths, anc, *inputs, feed=None, state_slots=None):
+                 q_lens, depths, anc, *inputs, feed=None, state_slots=None,
+                 packed=None):
+            if packed is not None:
+                # ONE UPLOAD A LAUNCH: static slices of the descriptor
+                # stand where the positional operands (None then) did
+                at, _ = launch_columns(depths.shape[1], classes,
+                                       width=packed.shape[1])
+                tables = [packed[:, cols] for cols in at["tables"]]
+                page_tables = (tables[0] if classes == 1
+                               else jnp.stack(tables))
+                pos, q_lens = packed[:, at["pos"]], packed[:, at["q_lens"]]
+                inputs = (packed[:, at["ids"]],) + inputs
+                if stateful:
+                    state_slots = packed[:, at["slot"]]
+                if feed is not None:
+                    feed = (packed[:, at["feed"]], feed[1])
             if feed is not None:
                 # LAUNCH AHEAD: the first id of an entry whose `slot` is
                 # not -1 is that slot's newest token, which the launch
@@ -1027,7 +1072,7 @@ class Executor:
 
         self._ragged_step_fn = self.compile_tracker.wrap(
             "ragged_step", jax.jit(step, donate_argnums=(2,)),
-            lambda args: args[8].shape)
+            lambda args: args[6].shape)
         return self._ragged_step_fn
 
     def paged_commit_fn(self):
@@ -1151,8 +1196,9 @@ class Executor:
 
         Warming is CONCRETE calls, not AOT lowering: only a real call
         populates the jit dispatch cache the serving tick hits, so every
-        argument here reproduces the server's exact avals — int32
-        ids/pos/q_lens/tables, bool ancestor masks, float32 temps, a
+        argument here reproduces the server's exact avals — the int32
+        packed descriptor (ids, pos, q_lens, slots, table rows: ONE array
+        a launch), bool ancestor masks, float32 temps, a
         typed rng key — against throwaway zero pools built from the
         catalog's config (zeroed page tables point every row at the null
         page, so the warm writes touch nothing a request will read; the
@@ -1203,54 +1249,29 @@ class Executor:
                 num_pages, page_size, dtype=pool_dt,
                 num_pages_window=cfg.get("num_pages_window"), slots=slots)
             step = self.ragged_step_fn()
-            stateful = bool(self.state_layers())
-
-            def feed(B):
-                kw = {}
-                if stateful:
-                    # the items' slots, as the server uploads them
-                    kw["state_slots"] = jnp.asarray(
-                        np.zeros((B,), np.int32))
-                if newest is not None:
-                    kw["feed"] = (jnp.asarray(np.full((B,), -1, np.int32)),
-                                  newest)
-                return kw
-
             # a graph with window layers launches with a table a class
-            two = self.page_classes() is not None
+            classes = 1 if self.page_classes() is None else 2
+            fed = {} if newest is None else {"feed": (None, newest)}
             for B, W in entries.get(  # fflint: host-ok (one-time warmup)
                     "ragged_step", {}).get("shapes", ()):
                 B, W = int(B), int(W)
-                # a packed launch gathers its table rows on the device,
-                # at B == slots too (the canonical decode launch does not)
-                if two:
-                    tbl = jnp.take(jnp.zeros((2, slots, cols), jnp.int32),
-                                   jnp.asarray(np.zeros((B,), np.int32)),
-                                   axis=1)
-                    if B == slots:
-                        tbl = jnp.zeros((2, slots, cols), jnp.int32)
-                else:
-                    tbl = jnp.take(jnp.zeros((slots, cols), jnp.int32),
-                                   jnp.asarray(np.zeros((B,), np.int32)),
-                                   axis=0)
-                    if B == slots:
-                        tbl = jnp.zeros((slots, cols), jnp.int32)
+                # the launch's ONE upload, as the server packs it: every
+                # table row the null page's, no entry fed from `newest`
+                at, width = launch_columns(W, classes, table_cols=cols)
+                packed = np.zeros((B, width), np.int32)
+                packed[:, at["feed"]] = -1
                 deps = jnp.asarray(np.tile(
                     np.arange(W, dtype=np.int32), (B, 1)))
                 anc = jnp.asarray(np.tile(
                     np.tril(np.ones((W, W), np.bool_)), (B, 1, 1)))
-                args = (tbl,
-                        jnp.asarray(np.zeros((B,), np.int32)),
-                        jnp.asarray(np.zeros((B,), np.int32)),
-                        deps, anc,
-                        jnp.asarray(np.zeros((B, W), np.int32)))
                 before = _pool_buffers(caches)
                 with obs.span("warm_shape") as sp:
                     t0 = time.monotonic()
                     with (compile_split() if sp
                           else contextlib.nullcontext()) as split:
-                        probs, caches = step(tr, ntr, caches, *args,
-                                             **feed(B))
+                        probs, caches = step(
+                            tr, ntr, caches, None, None, None, deps, anc,
+                            packed=jnp.asarray(packed), **fed)
                     if sp:
                         # for the record of set-up: what THIS shape's
                         # first call cost (jax's own compile phases; they

@@ -48,10 +48,12 @@ METRICS_ADDED_SINCE = {
         "weight_bytes_per_launch.prefill",      # PR 30
         "one_launch_share",                     # PR 34
         "launch_ahead_share.prefill",           # PR 37
+        "uploads_per_launch.prefill",           # PR 50
     ),
     "test_the_mellum2_files_load_and_keep_the_published_widths": (  # PR 36's
         "launch_ahead_share.prefill",           # PR 37
         "walk_shared_share",                    # PR 49
+        "uploads_per_launch.prefill",           # PR 50
     ),
 }
 
@@ -124,9 +126,22 @@ def _walks_against_runs(srv, drive):
         seen.append([walks(*a)])
         return seen[-1][0]
 
-    def rec_step(tr, ntr, caches, tbl, pos, qls, deps, anc, ids, **fed):
+    def rec_step(tr, ntr, caches, tbl, pos, qls, deps, anc, *ids, **fed):
+        if "packed" in fed:
+            # the server's launch: one upload, taken apart as the step does
+            from flexflow_tpu.runtime.executor import launch_columns
+
+            packed = np.asarray(fed["packed"])
+            classes = 1 if srv.pool_w is None else 2
+            at, _ = launch_columns(anc.shape[1], classes,
+                                   width=packed.shape[1])
+            tbl = np.stack([packed[:, c] for c in at["tables"]])
+            tbl = tbl[0] if classes == 1 else tbl
+            pos, qls = packed[:, at["pos"]], packed[:, at["q_lens"]]
+            seen[-1] += [tbl, pos, qls, np.asarray(anc)]
+            return step(tr, ntr, caches, None, None, None, deps, anc, **fed)
         seen[-1] += [np.asarray(x) for x in (tbl, pos, qls, anc)]
-        return step(tr, ntr, caches, tbl, pos, qls, deps, anc, ids, **fed)
+        return step(tr, ntr, caches, tbl, pos, qls, deps, anc, *ids, **fed)
 
     srv._walks, srv._step = rec_walks, rec_step
     try:

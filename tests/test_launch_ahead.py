@@ -414,6 +414,17 @@ def test_the_ticks_keep_their_spans_and_keys(backlog, family):
                 "weight_bytes", "pools_passed", "pools_in_place"} <= set(a)
     assert sum(a["ahead"] for a in launches) == \
         ahead.metrics["launches_ahead"]
+    # one launch, one upload span: its transfers counted where they are
+    # made (1 a chain launch, 3 at a shape's first: its cached chain pair)
+    uploads = [a for n, a, _ in tree if n == "launch_h2d"]
+    assert len(uploads) == len(launches)
+    assert all(a["launches"] == 1 and a["uploads"] in (1, 3)
+               for a in uploads)
+    assert sum(a["uploads"] for a in uploads) == \
+        ahead.metrics["launch_uploads"]
+    assert 1.0 <= span_counter.read(
+        types.SimpleNamespace(spans=spans), "launch_h2d", "uploads",
+        over="launches") <= 3.0
     for name, attrs, around in tree:
         if name in ("launch_build", "launch_h2d", "launch_dispatch",
                     "sample"):

@@ -1106,6 +1106,11 @@ def test_traced_paged_tick_phases(tmp_path):
             continue
         assert len(launch) == 1
         got = launch[0][4]
+        # the launch's uploads beside it: the one packed descriptor, and
+        # at a shape's first launch its cached chain pair
+        h2d = [k[4] for k in kids[tick[4]["id"]] if k[0] == "launch_h2d"]
+        assert len(h2d) == 1 and h2d[0]["launches"] == 1
+        assert h2d[0]["uploads"] in (1, 3)
         items, seqs = [], []            # (pos, q_len) with work, whose
         if tick[0] == "prefill_tick":
             W = min(W_MAX, max(tick[4]["takes"]))
