@@ -42,7 +42,7 @@ class Invariant:
       window  — needs a server's window-class tables and its requests'
                 block maps (check(tables, rows, window, page_size));
       state   — needs a server's account of its slots' recurrent states
-                (check(states, live, launched));
+                (check(states, live, launched, leaves));
       op      — only observable at the mutating operation itself; the
                 model checker enforces it inline (check is None).
     """
@@ -262,13 +262,23 @@ def _window_class(tables, rows: Dict[int, Tuple[Dict[int, int], int]],
 
 def _slot_state(states: Sequence[Tuple[Optional[int], int]],
                 live: Dict[int, Tuple[int, int]],
-                launched: Sequence[Tuple[int, int, int]]) -> List[str]:
+                launched: Sequence[Tuple[int, int, int]],
+                leaves: Sequence[Tuple[str, tuple, tuple]] = ()
+                ) -> List[str]:
     """`states[slot]` is (the request a slot's recurrent state belongs
     to or None, the rows it holds) as the scheduler accounts for it;
     `live` maps a live slot to (its request, the next row it writes);
     `launched` is the newest launch's live items (slot, first row, rows)
-    in launch order."""
+    in launch order; `leaves` names each state leaf the server holds
+    with (its shape and dtype name) as held and as its op's
+    `state_specs` declares them for this many slots (a KDA node's
+    (slots, H, d, d) float32 and 3 H d lanes of conv rows, a Mamba-2
+    node's (slots, H, P, N) float32 and H P + 2 N lanes)."""
     v = []
+    for name, held, declared in leaves:
+        if held != declared:
+            v.append(f"leaf {name}: holds {held}, its op declares "
+                     f"{declared}")
     owned: Dict[int, int] = {}
     for slot, (owner, rows) in enumerate(states):
         if owner is None:
@@ -366,7 +376,9 @@ CATALOG: Tuple[Invariant, ...] = (
         "its request has written since; a launch's items of one slot are "
         "consecutive and in row order, and no launch names the state of "
         "an idle slot (the launch's items are checked against the "
-        "states' owners before a request's last launch vacates it)",
+        "states' owners before a request's last launch vacates it); every "
+        "state leaf is indexed by slot at the shape and dtype its op "
+        "declares, whichever state op it is",
         _slot_state),
     Invariant(
         "cow-write", "op",
@@ -412,13 +424,14 @@ def check_window_class(tables, rows, window: int, page_size: int
     return v
 
 
-def check_slot_state(states, live, launched) -> List[str]:
+def check_slot_state(states, live, launched, leaves=()) -> List[str]:
     """Run the state-scope invariant over a server's account of its
-    slots' recurrent states (paged/scheduler.py `_check_invariants`)."""
+    slots' recurrent states and over its state leaves
+    (paged/scheduler.py `_check_invariants`)."""
     v: List[str] = []
     for entry in CATALOG:
         if entry.scope == "state":
-            v += entry.check(states, live, launched)
+            v += entry.check(states, live, launched, leaves)
     return v
 
 

@@ -126,11 +126,15 @@ class OpType(enum.Enum):
     LINEAR = "linear"
     EMBEDDING = "embedding"
     BATCH_MATMUL = "batch_matmul"
+    # h E^T on an embedding's own table (a tied output head)
+    TIED_HEAD = "tied_head"
     # attention
     MULTIHEAD_ATTENTION = "multihead_attention"
     RING_ATTENTION = "ring_attention"
     LATENT_ATTENTION = "latent_attention"
     KDA_ATTENTION = "kda_attention"
+    # a Mamba-2 state-space mixer: a second layer with a state a slot
+    MAMBA2 = "mamba2"
     # elementwise
     ELEMENT_BINARY = "element_binary"
     ELEMENT_UNARY = "element_unary"

@@ -193,14 +193,27 @@ class FFModel:
 
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
                   aggr: AggrMode = AggrMode.NONE, dtype: DataType = DataType.FLOAT,
-                  kernel_initializer=None, name: Optional[str] = None) -> Tensor:
+                  kernel_initializer=None, name: Optional[str] = None,
+                  emit_table: bool = False):
+        """With `emit_table` returns (rows, table): the table itself as a
+        second tensor, for `tied_head`."""
         node = self._add(
             OpType.EMBEDDING,
-            A.EmbeddingAttrs(num_entries, out_dim, AggrMode.coerce(aggr), dtype),
+            A.EmbeddingAttrs(num_entries, out_dim, AggrMode.coerce(aggr), dtype,
+                             bool(emit_table)),
             [input], name or "embedding",
         )
         self._record_init(node, kernel=kernel_initializer)
+        if emit_table:
+            return Tensor(node), Tensor(node, 1)
         return Tensor(node)
+
+    def tied_head(self, input: Tensor, table: Tensor, scale: float = 1.0,
+                  name: Optional[str] = None) -> Tensor:
+        """logits = (input table^T) * scale on an embedding's own table
+        (`embedding(..., emit_table=True)`): one leaf serves both."""
+        return self._one(OpType.TIED_HEAD, A.TiedHeadAttrs(float(scale)),
+                         [input, table], name or "tied_head")
 
     def multihead_attention(self, query: Tensor, key: Tensor, value: Tensor,
                             embed_dim: int, num_heads: int, kdim: int = 0,
@@ -210,18 +223,20 @@ class FFModel:
                             kernel_initializer=None,
                             name: Optional[str] = None,
                             window: Optional[int] = None,
-                            rope_scaling: Optional[Sequence[float]] = None
+                            rope_scaling: Optional[Sequence[float]] = None,
+                            softmax_scale: Optional[float] = None
                             ) -> Tensor:
-        """`window` makes the layer sliding-window attention and
+        """`window` makes the layer sliding-window attention,
         `rope_scaling` = (factor, original_max, beta_fast, beta_slow,
-        attention_factor) scales its rope by YaRN
-        (A.MultiHeadAttentionAttrs)."""
+        attention_factor) scales its rope by YaRN and `softmax_scale`
+        replaces the scores' kdim ** -0.5 (A.MultiHeadAttentionAttrs)."""
         node = self._add(
             OpType.MULTIHEAD_ATTENTION,
             A.MultiHeadAttentionAttrs(
                 embed_dim, num_heads, kv_heads, kdim // num_heads if kdim else None,
                 causal, bias, dropout, rope, rope_theta, window,
                 tuple(rope_scaling) if rope_scaling is not None else None,
+                None if softmax_scale is None else float(softmax_scale),
             ),
             [query, key, value], name or "attention",
         )
@@ -329,6 +344,30 @@ class FFModel:
         init = _glorot(input.shape[-1], hidden_dim)
         self._record_init(node, w_gate=init, w_up=init, w_down=init,
                           bias=bias_initializer if select_bias else None)
+        return Tensor(node)
+
+    def mamba2(self, input: Tensor, embed_dim: int, num_heads: int,
+               head_dim: int, state_dim: int, conv_taps: int = 4,
+               norm_eps: float = 1e-5, name: Optional[str] = None) -> Tensor:
+        """A Mamba-2 state-space mixer (A.Mamba2Attrs). The draws that are
+        not Glorot: taps and their bias in [-0.5, 0.5], `dt_bias` in
+        [-4.6, -2.3] (steps of 0.01-0.1 before the input moves them, the
+        range Mamba-2 initialises to), `a_log` in [0, log 16] (A in
+        [-16, -1], as published: log-decays of -0.01 to -1.6 a token, so a
+        head remembers a few to hundreds of tokens), `d_skip` and the
+        norm's scale 1."""
+        import math
+
+        from flexflow_tpu.runtime.initializer import UniformInitializer
+
+        attrs = A.Mamba2Attrs(embed_dim, num_heads, head_dim, int(state_dim),
+                              int(conv_taps), float(norm_eps))
+        node = self._add(OpType.MAMBA2, attrs, [input], name or "mamba2")
+        taps = UniformInitializer(-0.5, 0.5)
+        self._record_init(
+            node, conv=taps, conv_bias=taps,
+            dt_bias=UniformInitializer(math.log(0.01), math.log(0.1)),
+            a_log=UniformInitializer(0.0, math.log(16.0)))
         return Tensor(node)
 
     def hyper_connection(self, part: str, *inputs: Tensor, streams: int = 4,

@@ -24,6 +24,12 @@ test and is repaired in `classify` alone):
 An XLA fusion carries ONE of its instructions' `op_name`; that is the
 attribution a fused operation gets.
 
+Below a node's key some lowerings name their own parts, for the serving
+benchmark's `scope_share` reader: `hc_mix` (ops/hyper_connection.py),
+`dsa_index` / `dsa_select` / `dsa_attend` (ops/latent_attention.py),
+`ssd_proj` / `ssd_scan` (ops/mamba2.py: a Mamba-2 mixer outside and
+inside its recurrence). Each is defined beside the code it wraps.
+
 A collective is named besides by the mesh axes its replica groups span
 (`group_axes`): an SPMD module's groups hold positions in the device
 assignment, which is the mesh's devices flattened in the order of its

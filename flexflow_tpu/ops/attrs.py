@@ -154,19 +154,25 @@ class Conv2DAttrs(OpAttrs):
 class EmbeddingAttrs(OpAttrs):
     """Embedding lookup (reference src/ops/embedding.cc). Input int ids
     (batch, bag); NONE -> (batch, bag, out_dim); SUM/AVG pool the bag dim ->
-    (batch, out_dim)."""
+    (batch, out_dim). With `emit_table` the node has a second output, the
+    table itself (num_entries, out_dim), which a TIED head multiplies by
+    (TiedHeadAttrs): the graph then holds the table as ONE leaf."""
 
     num_entries: int
     out_dim: int
     aggr: AggrMode = AggrMode.NONE
     dtype: DataType = DataType.FLOAT
+    emit_table: bool = False
 
     def infer(self, x: Shape):
         if self.aggr == AggrMode.NONE:
             dims = tuple(_carry(d) for d in x.dims) + (ParallelDim(self.out_dim),)
         else:
             dims = tuple(_carry(d) for d in x.dims[:-1]) + (ParallelDim(self.out_dim),)
-        return (Shape(dims, self.dtype, x.replica),)
+        out = Shape(dims, self.dtype, x.replica)
+        if not self.emit_table:
+            return (out,)
+        return (out, fresh((self.num_entries, self.out_dim), self.dtype))
 
     def weights(self, x: Shape):
         return {
@@ -177,6 +183,26 @@ class EmbeddingAttrs(OpAttrs):
 
     def flops(self, ins, outs):
         return outs[0].to_shape().num_elements()
+
+
+@dataclasses.dataclass(frozen=True)
+class TiedHeadAttrs(OpAttrs):
+    """The output head of a model whose head IS its embedding's table
+    (`tie_word_embeddings`): logits = (h E^T) * `scale`, on inputs h
+    (batch, seq, dim) and the table (entries, dim), the second output of
+    an embedding built with `emit_table`. No weights of its own."""
+
+    scale: float = 1.0
+
+    def infer(self, h: Shape, table: Shape):
+        if h.dims[-1].size != table.dims[-1].size:
+            raise ValueError(f"tied head: {h} against a table {table}")
+        dims = tuple(_carry(d) for d in h.dims[:-1]) + (
+            ParallelDim(table.dims[0].size),)
+        return (Shape(dims, h.dtype, h.replica),)
+
+    def flops(self, ins, outs):
+        return 2 * outs[0].to_shape().num_elements() * ins[0].dims[-1].size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -294,6 +320,9 @@ class MultiHeadAttentionAttrs(OpAttrs):
     # with the same over `factor` between the two correction dims, and
     # cos and sin are both multiplied by attention_factor. None: plain.
     rope_scaling: Optional[Tuple[float, int, float, float, float]] = None
+    # what the scores are multiplied by; None: kdim ** -0.5 (Granite 4.0's
+    # `attention_multiplier` is 1/64 on heads of 64, not 1/8)
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
         if self.window is not None and self.window < 1:
@@ -310,6 +339,12 @@ class MultiHeadAttentionAttrs(OpAttrs):
     @property
     def num_kv(self) -> int:
         return self.kv_heads or self.num_heads
+
+    @property
+    def scale(self) -> float:
+        if self.softmax_scale is not None:
+            return self.softmax_scale
+        return 1.0 / (self.kdim**0.5)
 
     def infer(self, q: Shape, k: Shape = None, v: Shape = None):
         dims = tuple(_carry(d) for d in q.dims[:-1]) + (ParallelDim(self.embed_dim),)
@@ -618,6 +653,81 @@ class KdaAttentionAttrs(OpAttrs):
                  else 2 * self.gate_rank * (e + c))
         proj = 2 * b * s * (e * (3 * c + self.num_heads) + gates + c * e)
         return proj + 7 * b * s * c * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2Attrs(OpAttrs):
+    """A Mamba-2 STATE-SPACE mixer (arXiv:2405.21060; the `mamba` layers of
+    Granite 4.0-H): `num_heads` heads of P = `head_dim` channels, a state
+    of N = `state_dim` a channel, ONE group (B and C are shared by the
+    heads), a SCALAR decay a head and token:
+
+        [z | xBC | dt] = u W_in       (H P | H P + 2 N | H, no bias)
+        xBC = silu(conv(xBC) + bias)  depthwise, causal, `conv_taps` taps
+        x (H, P), B (N), C (N) = split(xBC)
+        D_t,h = softplus(dt_t,h + dt_bias_h);  a_t,h = -exp(A_log_h) D_t,h
+        S_t,h = exp(a_t,h) S_t-1,h + D_t,h x_t,h B_t^T          (P x N)
+        y_t,h = S_t,h C_t + Dskip_h x_t,h
+        out = [RMSNorm_HP(y * silu(z)) * w] W_out   (the gate BEFORE the
+        norm, one norm over all H P channels)
+
+    What it keeps of the past is a FIXED-SIZE state, whatever the length:
+    S (heads, P, N) float32 and the convolution's last `conv_taps` - 1
+    input rows of xBC. A paged server holds one of each a SLOT, beside its
+    pages, as it does for a KDA layer (ops/mamba2.py has the lowerings,
+    ops/pallas/ssd_scan.py the kernel)."""
+
+    embed_dim: int
+    num_heads: int
+    head_dim: int
+    state_dim: int
+    conv_taps: int = 4
+    norm_eps: float = 1e-5
+
+    @property
+    def inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.inner + 2 * self.state_dim
+
+    def infer(self, x: Shape):
+        dims = tuple(_carry(d) for d in x.dims[:-1]) + (
+            ParallelDim(self.embed_dim),)
+        return (Shape(dims, x.dtype, x.replica),)
+
+    def weights(self, x: Shape):
+        dt = x.dtype
+        e, h, c = x.dims[-1].size, self.num_heads, self.conv_dim
+
+        def mat(*shape, init="glorot_uniform"):
+            return WeightSpec(TensorShape(shape, dt), init)
+
+        return {
+            "w_in": mat(e, self.inner + c + h),
+            "conv": mat(self.conv_taps, c), "conv_bias": mat(c, init="zeros"),
+            "dt_bias": mat(h, init="zeros"), "a_log": mat(h, init="zeros"),
+            "d_skip": mat(h, init="ones"),
+            "norm": mat(self.inner, init="ones"),
+            "w_out": mat(self.inner, self.embed_dim),
+        }
+
+    def state_specs(self, slots: int):
+        """{name: (shape, dtype name or None: the activations')} of what a
+        server keeps a slot."""
+        return {
+            "s": ((slots, self.num_heads, self.head_dim, self.state_dim),
+                  "float32"),
+            "conv": ((slots, self.conv_taps - 1, self.conv_dim), None),
+        }
+
+    def flops(self, ins, outs):
+        x = ins[0]
+        b, s, e = x.dims[0].size, x.dims[1].size, x.dims[-1].size
+        proj = 2 * b * s * e * (2 * self.inner + self.conv_dim
+                                + self.num_heads)
+        return proj + 5 * b * s * self.inner * self.state_dim
 
 
 # ---------------------------------------------------------------------------

@@ -151,7 +151,21 @@ def _embedding(attrs, inputs, params, ctx):
         out = out.mean(axis=-2)
     # masters are fp32; the op's declared dtype sets the activation dtype for
     # everything downstream (bf16 compute on the MXU)
-    return [out.astype(attrs.dtype.jnp_dtype)]
+    out = out.astype(attrs.dtype.jnp_dtype)
+    return [out, table] if attrs.emit_table else [out]
+
+
+@register_lowering(OpType.TIED_HEAD)
+def _tied_head(attrs, inputs, params, ctx):
+    """h E^T on the embedding's own leaf: no transposed copy of the table
+    is made, the product contracts both operands' last dim."""
+    h, table = inputs
+    y = lax.dot_general(h, table.astype(h.dtype),
+                        (((h.ndim - 1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    if attrs.scale != 1.0:
+        y = y * attrs.scale
+    return [y.astype(h.dtype)]
 
 
 @register_lowering(OpType.BATCH_MATMUL)
@@ -424,7 +438,6 @@ def _mha(attrs, inputs, params, ctx):
     k_in = inputs[1] if len(inputs) > 1 else q_in
     v_in = inputs[2] if len(inputs) > 2 else k_in
     dt = q_in.dtype
-    hd = attrs.kdim
     q = qkv_project(q_in, params["wq"], dt)
     k = qkv_project(k_in, params["wk"], dt)
     v = qkv_project(v_in, params["wv"], dt)
@@ -459,7 +472,7 @@ def _mha(attrs, inputs, params, ctx):
                     q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
                     ctx.page_tables, ctx.cache_position,
                     ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=1.0 / (hd**0.5), rope_theta=rope_theta,
+                    scale=attrs.scale, rope_theta=rope_theta,
                     k_scales=ctx.kv_cache["k_scale"],
                     v_scales=ctx.kv_cache["v_scale"], **rope_kw,
                 )
@@ -470,12 +483,12 @@ def _mha(attrs, inputs, params, ctx):
                     q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
                     ctx.page_tables, ctx.cache_position,
                     ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=1.0 / (hd**0.5), rope_theta=rope_theta, **rope_kw,
+                    scale=attrs.scale, rope_theta=rope_theta, **rope_kw,
                 )
         else:
             out, kc, vc = cached_attention(
                 q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
-                ctx.cache_position, scale=1.0 / (hd**0.5),
+                ctx.cache_position, scale=attrs.scale,
                 rope_theta=rope_theta, **rope_kw,
             )
         ctx.cache_updates["k"] = kc
@@ -493,12 +506,12 @@ def _mha(attrs, inputs, params, ctx):
             seen = (i[None, :] <= i[:, None]) & (
                 i[:, None] - i[None, :] < attrs.window)
             out = _dot_product_attention(
-                q, k, v, False, 1.0 / (hd**0.5),
+                q, k, v, False, attrs.scale,
                 dropout_rate=attrs.dropout if ctx.training else 0.0,
                 dropout_rng=drop_rng, mask=seen)
         else:
             out = fused_attention(
-                q, k, v, causal=attrs.causal, scale=1.0 / (hd**0.5),
+                q, k, v, causal=attrs.causal, scale=attrs.scale,
                 dropout=attrs.dropout if ctx.training else 0.0,
                 dropout_rng=drop_rng, mesh=ctx.mesh,
             )
@@ -548,6 +561,25 @@ def _kda_attention(attrs, inputs, params, ctx):
             "a KDA layer decodes from its per-slot state only: serve "
             "with serve_generation(paged=True)")
     y, state = kda.paged_attention(attrs, x, params, ctx)
+    ctx.cache_updates.update(state)
+    return [y]
+
+
+@register_lowering(OpType.MAMBA2)
+def _mamba2(attrs, inputs, params, ctx):
+    """A Mamba-2 state-space mixer (ops/mamba2.py): the whole sequence
+    from a zero state without a cache, the slots' states continued in a
+    paged launch; like a KDA layer it has no dense decode cache."""
+    from flexflow_tpu.ops import mamba2
+
+    (x,) = inputs
+    if ctx.kv_cache is None:
+        return [mamba2.dense_mixer(attrs, x, params)]
+    if ctx.page_tables is None:
+        raise NotImplementedError(
+            "a Mamba-2 layer decodes from its per-slot state only: serve "
+            "with serve_generation(paged=True)")
+    y, state = mamba2.paged_mixer(attrs, x, params, ctx)
     ctx.cache_updates.update(state)
     return [y]
 

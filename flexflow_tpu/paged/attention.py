@@ -65,6 +65,16 @@ head of a page: a block that squeezes the head out of a second-minor
 pallas_call boundary is a physical relayout of the whole pool under TPU
 tiling, per layer per step. The append is one Hkv*D-lane row per token.
 
+HEADS OF 64 (`head_pack`): the pool keeps 64 lanes a head, so a 128-lane
+tile of a row holds TWO kv heads, and the kernel is handed the pair as one
+head of 128: the queries of the pair's two groups fold into the rows as
+they are, each padded with zeros over the OTHER head's 64 lanes (a score
+is then q . k of its own head exactly: the zeros add nothing), and of a
+row's 128 output lanes its own head's 64 are kept. The kernel itself does
+not change and nothing is padded in HBM; the matrix unit contracts 128
+lanes where 64 carry values, which costs it nothing a 64-deep product
+would not.
+
 The single pure-JAX fallback (`ragged_gather_attention`) gathers
 ``pool[page_table]`` and applies the same visibility as a materialized
 (B, S, L) mask (`ragged_visibility_mask`) — it runs anywhere and is the
@@ -123,17 +133,26 @@ def _reject(reason: str, cfg: tuple) -> bool:
     return False
 
 
+def head_pack(head_dim: int) -> int:
+    """kv heads the kernel takes as ONE head of 128 lanes: 2 for heads of
+    64 (module docstring, "HEADS OF 64"), 1 otherwise."""
+    return 2 if 2 * head_dim == LANES else 1
+
+
 def paged_attention_available(head_dim: int, page_size: int,
                               interpret: bool = False,
-                              dtype=jnp.float32) -> bool:
+                              dtype=jnp.float32,
+                              kv_heads: Optional[int] = None) -> bool:
     """True when the ragged Pallas kernel supports these shapes on this
     backend — the ONE gate for decode, chunked prefill and tree verify
     (there is no per-variant rejection matrix any more).
     FF_TPU_NO_PAGED=1 disables the kernel everywhere (A/B runs and
     kernel-bug escape hatch, like FF_TPU_NO_FLASH). On real TPUs the
     head dim must be a lane multiple (the kernel slices one head's
-    D-wide lane block out of a flat-lane page row; smaller head dims
-    take the gather fallback, mirroring the flash bshd gate) and pages
+    D-wide lane block out of a flat-lane page row), or 64 with an even
+    number of `kv_heads`, which the kernel takes two a 128-lane tile
+    (`head_pack`; the caller says how many kv heads a row holds); other
+    head dims take the gather fallback, mirroring the flash bshd gate. Pages
     must tile the sublane dim AT THE POOL'S DTYPE — (8, 128) tiles for
     fp32 but (16, 128) for bf16/fp16 and (32, 128) for int8/fp8, so a
     bf16 pool needs page_size % 16 == 0 and a QUANTIZED int8 pool
@@ -153,10 +172,20 @@ def paged_attention_available(head_dim: int, page_size: int,
         return _reject(
             f"pool dtype {dt.name} is 8-byte (no TPU tiling story)", cfg)
     sublane = 8 * (4 // max(itemsize, 1))
-    if head_dim % LANES != 0:
+    pack = head_pack(head_dim)
+    if pack > 1 and (kv_heads is None or kv_heads % pack):
         return _reject(
-            f"head_dim={head_dim} is not a multiple of the {LANES}-lane "
-            "tile", cfg)
+            f"head_dim={head_dim} takes the kernel {pack} kv heads a "
+            f"{LANES}-lane tile, which needs a multiple of {pack} kv heads "
+            f"a row (kv_heads={kv_heads})", cfg)
+    if pack > 1 and itemsize == 1:
+        return _reject(
+            f"head_dim={head_dim} with an 8-bit pool: the scale sidecar is "
+            "a kv head's and the kernel would take two as one", cfg)
+    if pack == 1 and head_dim % LANES != 0:
+        return _reject(
+            f"head_dim={head_dim} is neither a multiple of the "
+            f"{LANES}-lane tile nor half of it", cfg)
     if page_size % sublane != 0:
         return _reject(
             f"page_size={page_size} does not tile the {sublane}-row "
@@ -782,7 +811,8 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
                            interpret: bool = False, k_scales=None,
                            v_scales=None, window: Optional[int] = None,
                            runs=None):
-    """The ragged Pallas launch. q: (B, S, H, D) — S is the launch's
+    """The ragged Pallas launch. q: (B, S, H, D), D a multiple of 128 or
+    64 (`head_pack`) — S is the launch's
     window width, per-entry real work is q_lens[b] <= S rows;
     kc/vc_pages: (N, P, Hkv*D) flat-lane pages (module docstring);
     page_tables: (B, max_pages); pos, q_lens: (B,); anc_mask: (B, S, S)
@@ -812,6 +842,13 @@ def ragged_flash_attention(q, kc_pages, vc_pages, page_tables, pos,
     B, S, H, D = q.shape
     P = kc_pages.shape[1]
     Hkv = kc_pages.shape[2] // D
+    if head_pack(D) > 1:
+        # heads of 64: two kv heads a 128-lane tile (module docstring)
+        out = ragged_flash_attention(
+            _pack_heads(q, Hkv), kc_pages, vc_pages, page_tables, pos,
+            q_lens, anc_mask, scale=scale, interpret=interpret,
+            k_scales=k_scales, v_scales=v_scales, window=window, runs=runs)
+        return _unpack_heads(out, Hkv)
     rep = H // Hkv
     n_pages = page_tables.shape[1]
     shape = (B, S, H, D, P, n_pages, Hkv * D, kc_pages.dtype, q.dtype)
@@ -978,11 +1015,13 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
         ks = vs = None
 
     force_interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
-    shape = (B, S, q.shape[2], q.shape[3], P, page_tables.shape[1],
+    D = q.shape[-1]
+    pack = head_pack(D)
+    shape = (B, S, q.shape[2], pack * D, P, page_tables.shape[1],
              kc.shape[2], kc.dtype, q.dtype)
-    kernel = paged_attention_available(q.shape[-1], P,
-                                       interpret=force_interp,
-                                       dtype=kc.dtype)
+    kernel = paged_attention_available(D, P, interpret=force_interp,
+                                       dtype=kc.dtype,
+                                       kv_heads=kc.shape[2] // D)
     if kernel and not force_interp and not ragged_launch_fits(*shape):
         kernel = _reject(
             f"a launch of {B} entries x {S} rows keeps more in VMEM than "
@@ -1006,6 +1045,29 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
     if k_scales is not None:
         return out, kc, vc, ks, vs
     return out, kc, vc
+
+
+def _own_half(heads: int, kv_heads: int):
+    """(heads,) bool: whether a q head's kv head is the FIRST of its pair
+    (`head_pack`): q heads lie in the order of their kv heads."""
+    return (jnp.arange(heads) // (heads // kv_heads)) % 2 == 0
+
+
+def _pack_heads(q, kv_heads: int):
+    """(B, S, H, 64) -> (B, S, H, 128): a head's queries over its own kv
+    head's lanes of the pair's tile, zeros over the other's."""
+    first = _own_half(q.shape[2], kv_heads)[:, None]
+    zero = jnp.zeros_like(q)
+    return jnp.concatenate([jnp.where(first, q, zero),
+                            jnp.where(first, zero, q)], axis=-1)
+
+
+def _unpack_heads(out, kv_heads: int):
+    """(B, S, H, 128) -> (B, S, H, 64): of a row's output over the pair's
+    tile, its own head's lanes."""
+    d = out.shape[-1] // 2
+    return jnp.where(_own_half(out.shape[2], kv_heads)[:, None],
+                     out[..., :d], out[..., d:])
 
 
 def chain_descriptor(batch: int, window: int):

@@ -259,6 +259,9 @@ class PagedGenerationServer(_GenerationServerBase):
         # the host's account of each slot's state for the invariant
         # catalog.
         self._state_keys = frozenset(ex.state_layers())
+        # ... of which kinds: "kda" (delta rule), "ssd" (state space); a
+        # launch's span counts its rows and pieces under each kind there
+        self._state_kinds = ex.state_kinds()
         # ... and its launches come in FEW shapes: a step program with
         # state layers is the dearest to compile (each scan unrolled over
         # heads and rows), and one a (items, window) pair is 127 of them
@@ -387,6 +390,7 @@ class PagedGenerationServer(_GenerationServerBase):
         import os
 
         from flexflow_tpu.paged.attention import (
+            head_pack,
             paged_attention_available,
             reset_rejection_log,
         )
@@ -411,11 +415,14 @@ class PagedGenerationServer(_GenerationServerBase):
             kernel_ok = latent_attention_available(
                 self.page_size, interpret=interp, dtype=kbuf.dtype)
         else:
+            # heads of 64 go through the kernel two kv heads a tile, so
+            # twice the q heads fold into an entry's rows
             self._block_geom = (kbuf.shape[2], kbuf.dtype,
-                                attn.num_heads // attn.num_kv)
+                                attn.num_heads // attn.num_kv
+                                * head_pack(attn.kdim))
             kernel_ok = paged_attention_available(
                 attn.kdim, self.page_size, interpret=interp,
-                dtype=kbuf.dtype)
+                dtype=kbuf.dtype, kv_heads=attn.num_kv)
         self.kernel_variant = ("ragged_pallas" if kernel_ok
                                else "ragged_gather")
         # bytes a cached token takes in the pool, over every layer; a
@@ -699,6 +706,7 @@ class PagedGenerationServer(_GenerationServerBase):
         })
         if self._state_keys:
             m["state"] = {
+                "kinds": list(self._state_kinds),
                 "layers": len(self._state_keys),
                 "bytes_per_slot": self.state_bytes_per_slot,
                 "resets": self.state_resets,
@@ -1362,7 +1370,7 @@ class PagedGenerationServer(_GenerationServerBase):
                 [(self._state_owner[s], int(self._state_rows[s]))
                  for s in range(self.slots)],
                 {s: (r.seq, self._next_rows(r)[0]) for s, r in live.items()},
-                self._state_launched)
+                self._state_launched, self._state_leaves())
             if violations:
                 raise AssertionError(
                     "slot-state invariant violation(s):\n  "
@@ -1749,10 +1757,15 @@ class PagedGenerationServer(_GenerationServerBase):
                 sp.set(weight_bytes=self._weight_bytes)
                 if self._state_keys:
                     # what the state layers have to do for THIS launch:
-                    # slots whose state it touches, live rows, live items
+                    # slots whose state it touches, live rows and live
+                    # items, named by the ops that are there
                     sp.set(state_slots=int(np.unique(slot_idx[qls > 0]).size),
-                           kda_rows=int(q.sum()), kda_pieces=int(q.size),
-                           state_bytes_per_slot=self.state_bytes_per_slot)
+                           slots=self.slots,
+                           state_bytes_per_slot=self.state_bytes_per_slot,
+                           kv_bytes_per_token=self.kv_bytes_per_token)
+                    for kind in self._state_kinds:
+                        sp.set(**{kind + "_rows": int(q.sum()),
+                                  kind + "_pieces": int(q.size)})
                 if sparse is not None:
                     sp.set(index_bytes_per_token=self.index_bytes_per_token,
                            **sparse)
@@ -1852,6 +1865,19 @@ class PagedGenerationServer(_GenerationServerBase):
         for k, v in out.items():
             self._sparse_totals[k] = self._sparse_totals.get(k, 0) + v
         return out
+
+    def _state_leaves(self):
+        """(name, held, declared) of every state leaf, each a (shape,
+        dtype name) pair, for the `slot-state` invariant."""
+        specs = self.ff.executor.paged_kv_cache_specs(
+            self.pool.num_pages, self.page_size, slots=self.slots,
+            num_pages_window=(self.pool_w.num_pages if self._window
+                              else None))
+        return [(f"{nk}.{name}",
+                 (tuple(b.shape), b.dtype.name),
+                 (tuple(specs[nk][name].shape), specs[nk][name].dtype.name))
+                for nk in sorted(self._state_keys)
+                for name, b in self._caches[nk].items()]
 
     def _note_state_rows(self, slot_idx, pos, qls):
         """The host's account of the states a launch continues (state

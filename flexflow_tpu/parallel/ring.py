@@ -236,7 +236,6 @@ def ring_attention_lowering(attrs, inputs, params, ctx):
     k_in = inputs[1] if len(inputs) > 1 else q_in
     v_in = inputs[2] if len(inputs) > 2 else k_in
     dt = q_in.dtype
-    hd = attrs.kdim
     from flexflow_tpu.ops.jax_ops import attn_out_project, qkv_project
 
     q = qkv_project(q_in, params["wq"], dt)
@@ -259,7 +258,7 @@ def ring_attention_lowering(attrs, inputs, params, ctx):
         else ring_dot_product_attention
     )
     out = seq_attn(
-        q, k, v, mesh=ctx.mesh, causal=attrs.causal, scale=1.0 / (hd**0.5)
+        q, k, v, mesh=ctx.mesh, causal=attrs.causal, scale=attrs.scale
     )
     y = attn_out_project(out, params["wo"], dt)
     return [y]

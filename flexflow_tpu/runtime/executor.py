@@ -106,8 +106,10 @@ def _pool_buffers(caches) -> list:
 # the ops whose node owns a pool of the page cache
 PAGED_ATTENTION_OPS = (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
                        OpType.LATENT_ATTENTION)
-# the ops whose node keeps a fixed-size STATE a slot beside the pages
-STATE_OPS = (OpType.KDA_ATTENTION,)
+# the ops whose node keeps a fixed-size STATE a slot beside the pages, and
+# what the tracing calls the rows each kind takes of a launch
+STATE_KINDS = {OpType.KDA_ATTENTION: "kda", OpType.MAMBA2: "ssd"}
+STATE_OPS = tuple(STATE_KINDS)
 
 
 def _cast_weight_leaf(arr, weight_dtype: str):
@@ -856,8 +858,14 @@ class Executor:
 
     def state_layers(self) -> List[str]:
         """Keys of the nodes that keep a per-slot state (`STATE_OPS`), in
-        graph order; empty for every graph before Ling-3.0-flash's."""
+        graph order; empty for a graph of attention layers alone."""
         return [node_key(n) for n in self.topo if n.op_type in STATE_OPS]
+
+    def state_kinds(self) -> Tuple[str, ...]:
+        """The kinds of state layer the graph holds (`STATE_KINDS`), in
+        `STATE_OPS` order."""
+        there = {n.op_type for n in self.topo}
+        return tuple(STATE_KINDS[op] for op in STATE_OPS if op in there)
 
     def paged_kv_cache_specs(self, num_pages: int, page_size: int,
                              dtype=None, num_pages_window: Optional[int]
@@ -880,8 +888,10 @@ class Executor:
 
         A STATE node (`STATE_OPS`) has a fourth kind of leaf, indexed by
         SLOT and not by page: what its attrs' `state_specs(slots)`
-        names ("s" float32, "conv" at the activations' dtype), whatever
-        the pool's dtype. Such a graph needs `slots`."""
+        names ("s" float32, "conv" at the activations' dtype: a KDA
+        node's (slots, H, d, d) and 3 H d lanes of conv rows, a Mamba-2
+        node's (slots, H, P, N) and H P + 2 N lanes), whatever the
+        pool's dtype. Such a graph needs `slots`."""
         from flexflow_tpu.paged.quant import is_quantized_dtype
 
         classes = self.page_classes() or {}

@@ -274,7 +274,8 @@ def make_mha_to_ring_attention(axis_sizes: Dict[str, int],
             return None
         new_attrs = A.RingAttentionAttrs(
             a.embed_dim, a.num_heads, a.kv_heads, a.head_dim, a.causal,
-            a.use_bias, a.dropout, a.rope, a.rope_theta, seq_mode=seq_mode,
+            a.use_bias, a.dropout, a.rope, a.rope_theta,
+            softmax_scale=a.softmax_scale, seq_mode=seq_mode,
         )
         ndim = attn.outputs[0].ndim
         seq_spec = (batch_spec(ndim)[:1] + (("seq",),)

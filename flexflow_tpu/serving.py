@@ -1339,8 +1339,9 @@ _ALL_BUT_PREFIX = ("paged=False", "kv_dtype", "speculate", "host_tier",
 _GRAPH_KINDS = (
     (lambda ex: bool(ex.state_layers()),
      _ALL_BUT_PREFIX + ("prefix_cache",),
-     "state layers (linear attention): a layer's memory is ONE recurrent "
-     "state a slot, which no prefix-cache hit can restore "
+     "state layers (delta-rule linear attention, `kda_attention`, or a "
+     "Mamba-2 state-space mixer, `mamba2`): a layer's memory is ONE "
+     "recurrent state a slot, which no prefix-cache hit can restore "
      "(prefix_cache=True is the default: pass False), no tree verify can "
      "roll back, no host tier or int8 pool holds, and "
      "which the dense server and the strategy search know nothing of"),

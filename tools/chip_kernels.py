@@ -17,7 +17,9 @@ The latent (MLA) kernel and the grouped expert kernels run at
 Mistral-Small-4's widths (`mla_*`, `moe_grouped_*`), the first also at the
 new cell's launches (`mla_bench_*`) and at Ling-3.0-flash's 576-value row
 (`mla_wide_*`); the delta-rule scan at Ling-3.0-flash's widths and its
-cell's launches (`kda_*`).
+cell's launches (`kda_*`); the state-space scan at Granite-4.0-H-Micro's
+widths and its cell's launches (`ssd_*`), whose attention launches, heads
+of 64, are `ragged_bench_g64_*`.
 
 The expert router's choice (`route_*`; ops/expert_share.py `_select` and
 `_top_k`) is plain XLA, no Pallas kernel: its cases are `route_cases()`,
@@ -61,7 +63,7 @@ def _tree_anc(S):
     return ancestor_masks(parents[None])[0]
 
 
-def _ragged_fns(S, window=None):
+def _ragged_fns(S, window=None, scale=None):
     """(kernel fn, reference fn) over one argument list
     (q, kc, vc, pt, pos, q_lens, anc[, k_scales, v_scales])."""
     from flexflow_tpu.paged.attention import (
@@ -69,7 +71,7 @@ def _ragged_fns(S, window=None):
         ragged_gather_attention,
     )
 
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if scale is None else scale
 
     def run(impl):
         def fn(q, kc, vc, pt, pos, q_lens, anc, *sc):
@@ -147,6 +149,17 @@ BENCH_LAUNCHES["chunk128_window"] = _CHUNK128
 # kind -> (kv heads, table width, pool pages, sliding window)
 BENCH_GEOMETRY = {"chunk128_full": (4, 516, 320, None),
                   "chunk128_window": (4, 516, 320, 1024)}
+# Granite-4.0-H-Micro's launches (benchmark/configs/
+# granite-4.0-h-micro-serve1.json: 32 slots, 8 kv heads of 64, a table 49
+# wide, scores times 1/64): 32 decode rows over 300-2,900-row contexts, and
+# a 512-row chunk as 64 pieces of one slot beside 31 decode rows and a
+# filler. The kernel takes two kv heads a 128-lane tile.
+_DECODE32 = [(i, 300 + 84 * i, 1) for i in range(32)]
+BENCH_LAUNCHES["g64_decode32"] = (1, _DECODE32)
+BENCH_LAUNCHES["g64_chunk512"] = (
+    8, [(0, 1024 + 8 * i, 8) for i in range(64)] + [(0, 0, 0)]
+    + _DECODE32[1:])
+HEADS_OF_64 = ("g64_decode32", "g64_chunk512")
 
 
 def _bench_case(kind, seed=0):
@@ -155,12 +168,15 @@ def _bench_case(kind, seed=0):
     slot's live pages are the null page, as the server leaves them."""
     P = 64
     hkv, MAXP, N, window = BENCH_GEOMETRY.get(kind, (HKV, 64, 128, None))
+    d, scale = D, None
+    if kind in HEADS_OF_64:
+        d, MAXP, N, scale = 64, 49, 1600, 1.0 / 64
     S, entries = BENCH_LAUNCHES[kind]
     B = len(entries)
     rs = np.random.RandomState(seed)
-    q = jnp.asarray(rs.randn(B, S, H, D), jnp.bfloat16)
-    kc = jnp.asarray(rs.randn(N, P, hkv * D), jnp.bfloat16)
-    vc = jnp.asarray(rs.randn(N, P, hkv * D), jnp.bfloat16)
+    q = jnp.asarray(rs.randn(B, S, H, d), jnp.bfloat16)
+    kc = jnp.asarray(rs.randn(N, P, hkv * d), jnp.bfloat16)
+    vc = jnp.asarray(rs.randn(N, P, hkv * d), jnp.bfloat16)
     free = list(rs.permutation(N - 1) + 1)
     tables = {}
     for slot, p, ql in entries:
@@ -173,7 +189,7 @@ def _bench_case(kind, seed=0):
     pos = jnp.asarray(np.array([p for _, p, _ in entries], np.int32))
     q_lens = jnp.asarray(np.array([ql for _, _, ql in entries], np.int32))
     anc = jnp.asarray(np.tile(np.tril(np.ones((S, S), bool)), (B, 1, 1)))
-    fn, ref = _ragged_fns(S, window)
+    fn, ref = _ragged_fns(S, window, scale)
     return fn, (q, kc, vc, pt, pos, q_lens, anc), ref
 
 
@@ -398,6 +414,90 @@ def _kda_case(kind, seed=0):
     return fn, (q, k, v, a, beta, state), ref
 
 
+def _ssd_case(kind, seed=0):
+    """The state-space scan (`ssd_ragged_scan`) at Granite-4.0-H-Micro's
+    widths, 64 heads of a 64 x 128 float32 state over 32 slots. "decode"
+    is the cell's usual launch, 32 one-row items, two of them without
+    rows; "chunk512" a chunk's: 64 pieces of one slot, a filler item
+    without rows, 31 other slots' decode rows (96 items). Log-decays as
+    the builder's draw gives them (-0.01 to -1.6 a step); "weakdecay" is
+    chunk512 at -0.01 a step on every live row and head, "strongdecay" at
+    -30 (exp(-30) is 1e-13: a state forgotten every step, which the
+    kernel's differences of running sums must survive where exp(-G) would
+    overflow). Against the recurrence row by row in FLOAT64 ON THE HOST
+    (`_ssd_rows_float64`), not the float32 scan over items and rows the
+    CPU tests use (ops/mamba2.py `scan_items`): at -0.01 a step that scan
+    multiplies a state by the same `exp(-0.01)` 520 times, so the rounding
+    of the chip's ONE exponential adds up coherently (the two float32
+    forms read 4.7e-5 apart there, my chip run, PR 51, where every other
+    case read 1e-7 to 1.5e-6), and a float32 oracle cannot say which of
+    the two is off (`KDA_TOL`)."""
+    from flexflow_tpu.ops.pallas import ssd_scan
+    from flexflow_tpu.ops.slot_state import item_chain
+
+    H, P, N, S, W = 64, 64, 128, 32, ssd_scan.ROWS
+    if kind == "decode":
+        items = [(s, 0 if s == 5 else 300 + 84 * s, 0 if s in (2, 6) else 1)
+                 for s in range(S)]
+    else:
+        items = [(3, 1024 + 8 * i, 8) for i in range(64)] + [(3, 0, 0)] + [
+            (s, 300 + 84 * s, 1) for s in range(S) if s != 3]
+    B = len(items)
+    slots, pos, q_lens = (jnp.asarray(np.array(col, np.int32))
+                          for col in zip(*items))
+    ks = jax.random.split(jax.random.key(seed), 6)
+    xh = jax.random.normal(ks[0], (B, W, H, P))
+    b_in, c_out = (jax.random.normal(k, (B, W, N)) for k in ks[1:3])
+    dt = jnp.exp(jax.random.uniform(ks[3], (B, W, H), minval=np.log(0.01),
+                                    maxval=np.log(0.1)))
+    a = -dt * jnp.exp(jax.random.uniform(ks[4], (B, W, H), minval=0.0,
+                                         maxval=np.log(16.0)))
+    if kind in ("weakdecay", "strongdecay"):
+        a = jnp.full_like(a, -0.01 if kind == "weakdecay" else -30.0)
+    alive = (jnp.arange(W)[None, :] < q_lens[:, None])[:, :, None]
+    dt, a = jnp.where(alive, dt, 0.0), jnp.where(alive, a, 0.0)
+    state = jax.random.normal(ks[5], (S, H, P, N))
+
+    def fn(xh, b_in, c_out, dt, a, state):
+        slot, start, fresh, _ = item_chain(slots, pos, q_lens)
+        cb = jnp.einsum("bin,bjn->bij", c_out, b_in,
+                        precision=lax.Precision.HIGHEST)
+        y, s = ssd_scan.ssd_ragged_scan(
+            (dt[..., None] * xh).reshape(B, W, H * P), b_in, c_out,
+            ssd_scan.pack_small(a, cb), state, slot,
+            start.astype(jnp.int32), fresh.astype(jnp.int32), q_lens,
+            heads=H)
+        return jnp.where(alive[..., None], y.reshape(B, W, H, P), 0), s
+
+    def ref(xh, b_in, c_out, dt, a, state):
+        shapes = (jax.ShapeDtypeStruct(xh.shape, jnp.float32),
+                  jax.ShapeDtypeStruct(state.shape, jnp.float32))
+        return jax.pure_callback(
+            lambda *t: _ssd_rows_float64(items, *t), shapes,
+            xh, b_in, c_out, dt, a, state)
+
+    return fn, (xh, b_in, c_out, dt, a, state), ref
+
+
+def _ssd_rows_float64(items, xh, b_in, c_out, dt, a, state):
+    """S_t = exp(a_t) S_t-1 + (D_t x_t) B_t^T, y_t = S_t C_t, a live row
+    at a time in numpy float64; `items` are (slot, first row, live rows),
+    a request's row 0 starts from zero. Dead rows read out zero."""
+    xh, b_in, c_out, dt, a = (np.asarray(t, np.float64)
+                              for t in (xh, b_in, c_out, dt, a))
+    s = np.asarray(state, np.float64).copy()
+    y = np.zeros(xh.shape, np.float64)
+    for i, (slot, first, rows) in enumerate(items):
+        for t in range(rows):
+            if first + t == 0:
+                s[slot] = 0.0
+            s[slot] = (np.exp(a[i, t])[:, None, None] * s[slot]
+                       + (dt[i, t][:, None] * xh[i, t])[:, :, None]
+                       * b_in[i, t][None, None, :])
+            y[i, t] = s[slot] @ c_out[i, t]
+    return y.astype(np.float32), s.astype(np.float32)
+
+
 # the three routers at a full launch's rows (a chunk's pieces, a filler
 # where the server fills, the other slots' riders, 8 rows an item):
 # name -> (rows, ExpertShareAttrs fields)
@@ -593,6 +693,8 @@ def kernel_cases(n_devices: int = 1):
     cases["mla_wide_chunk64"] = _mla_wide_case
     for kind in ("chunk256", "decode", "chunk512", "strongdecay"):
         cases[f"kda_{kind}"] = lambda kind=kind: _kda_case(kind)
+    for kind in ("decode", "chunk512", "weakdecay", "strongdecay"):
+        cases[f"ssd_{kind}"] = lambda kind=kind: _ssd_case(kind)
     return cases
 
 
@@ -615,7 +717,7 @@ KDA_TOL = 2e-5
 # which device operations a timed case's kernel is, by the case's prefix
 KERNEL_MARKS = {"ragged_": "ragged_paged_attention",
                 "mla_": "mla_paged_attention", "moe_": "moe_grouped",
-                "kda_": "kda_ragged_scan"}
+                "kda_": "kda_ragged_scan", "ssd_": "ssd_ragged_scan"}
 
 
 def _kernel_device_us(fn, fargs, mark, per_call=1, calls=20):
@@ -670,7 +772,8 @@ def main(argv=None) -> int:
             # bf16 inputs, f32 accumulation on both sides; the scan is
             # float32 throughout; the router's choice is the same bits
             ok = (err == 0 if name.startswith("route_") else
-                  err < (KDA_TOL if name.startswith("kda_") else 2e-2))
+                  err < (KDA_TOL if name.startswith(("kda_", "ssd_"))
+                       else 2e-2))
             took = ""
             mark = next((m for p, m in KERNEL_MARKS.items()
                          if name.startswith(p)), None)
