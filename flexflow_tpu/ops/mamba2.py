@@ -140,18 +140,13 @@ def paged_mixer(attrs, x, params, ctx):
     interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
     with jax.named_scope(SCAN_SCOPE):
         if ssd_scan.available(P, N, H, interp):
-            pad = ((0, 0), (0, (-W) % ssd_scan.ROWS), (0, 0))
-            b_pad, c_pad = jnp.pad(b_in, pad), jnp.pad(c_out, pad)
-            cb = jnp.einsum("bin,bjn->bij", c_pad, b_pad,
-                            precision=lax.Precision.HIGHEST)
             slot, start, fresh, _last = chain
             y, state = ssd_scan.ssd_ragged_scan(
-                jnp.pad((dt[..., None] * xh).reshape(B, W, H * P), pad),
-                b_pad, c_pad, ssd_scan.pack_small(jnp.pad(a, pad), cb),
+                (dt[..., None] * xh).reshape(B, W, H * P), b_in, c_out, a,
                 ctx.kv_cache["s"], slot, start.astype(jnp.int32),
                 fresh.astype(jnp.int32), q_lens.astype(jnp.int32), heads=H,
                 interpret=interp)
-            y = y[:, :W].reshape(B, W, H, P)
+            y = y.reshape(B, W, H, P)
         else:
             y, state = scan_items(xh, b_in, c_out, dt, a, chain,
                                   ctx.kv_cache["s"])

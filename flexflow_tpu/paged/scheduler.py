@@ -274,6 +274,10 @@ class PagedGenerationServer(_GenerationServerBase):
         self._state_owner: List[Optional[int]] = [None] * self.slots
         self._state_launched: List[tuple] = []
         self.state_resets = 0
+        # live items the state layers were handed, and those of ONE live
+        # row among them (a state kernel's short form: `rows == 1`)
+        self.state_items = 0
+        self.state_items_one_row = 0
         self.state_resumes = 0
         self._caches = ex.init_paged_kv_cache(
             num_pages, self.page_size, dtype=pool_dt,
@@ -711,6 +715,8 @@ class PagedGenerationServer(_GenerationServerBase):
                 "bytes_per_slot": self.state_bytes_per_slot,
                 "resets": self.state_resets,
                 "resumes_by_recompute": self.state_resumes,
+                "items": self.state_items,
+                "items_one_row": self.state_items_one_row,
             }
         if self._window:
             m["page_classes"] = {
@@ -1765,7 +1771,8 @@ class PagedGenerationServer(_GenerationServerBase):
                            kv_bytes_per_token=self.kv_bytes_per_token)
                     for kind in self._state_kinds:
                         sp.set(**{kind + "_rows": int(q.sum()),
-                                  kind + "_pieces": int(q.size)})
+                                  kind + "_pieces": int(q.size),
+                                  kind + "_one_row": int((q == 1).sum())})
                 if sparse is not None:
                     sp.set(index_bytes_per_token=self.index_bytes_per_token,
                            **sparse)
@@ -1888,6 +1895,8 @@ class PagedGenerationServer(_GenerationServerBase):
             for s, p, n in zip(slot_idx, pos, qls) if n]
         for s, p, n in self._state_launched:
             self._state_rows[s] = p + n
+        self.state_items += len(self._state_launched)
+        self.state_items_one_row += int(np.count_nonzero(qls == 1))
 
     def _window_counts(self, slots, p0, q) -> dict:
         """What a traced launch has to read and score in a layer of each

@@ -271,14 +271,33 @@ RAGGED = (
     + [[(0, 5 + i, 1, None), (1, 32 + i, 1, 32 + i)] for i in range(5)])
 
 
+# the same 37 rows where the three forms of an item meet in ONE launch: a
+# chunk of 17 rows whose last piece is ONE row (an item of one live row
+# directly after pieces of its own slot: the state is where the piece left
+# it, nothing is copied in), slot 0's decode row riding behind it and a
+# filler; a chunk of 12 (8, 3 and then 1 in the next launch); decode
+# launches one row wide with an item without rows between the slots.
+TAILS = (
+    [[(1, 0, 6, None)]]                             # a stranger in slot 1
+    + [[(0, 0, 4, None)]]
+    + [[(1, 0, 8, 0), (1, 8, 8, 8), (1, 16, 1, 16), (0, 4, 1, None),
+        (0, 5, 0, None)]]
+    + [[(0, 5, 1, None), (1, 17, 8, 17), (1, 25, 3, 25), (2, 0, 0, None)]]
+    + [[(1, 28, 1, 28), (0, 6, 2, None)]]
+    + [[(0, 8 + i, 1, None), (2, 0, 0, None), (1, 29 + i, 1, 29 + i)]
+       for i in range(8)])
+PLANS = {"ragged": RAGGED, "tails": TAILS}
+
+
 @pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("path", ["scan", "kernel"])
+@pytest.mark.parametrize("plan", sorted(PLANS))
 def test_paged_lowering_equals_the_dense_one_over_ragged_items(
-        decay, path, monkeypatch):
+        decay, path, plan, monkeypatch):
     attrs, params, x = mixer_case(decay, seed=1)
     want = np.asarray(mamba2.dense_mixer(attrs, x, params)[0])
-    got = paged_rows(attrs, params, x, RAGGED, interpret=path == "kernel",
-                     monkeypatch=monkeypatch)
+    got = paged_rows(attrs, params, x, PLANS[plan],
+                     interpret=path == "kernel", monkeypatch=monkeypatch)
     # float32: an item's rows solved together against a token at a time
     np.testing.assert_allclose(got, want, atol=3e-5, rtol=0)
 
@@ -298,12 +317,67 @@ def test_a_bfloat16_state_would_fail():
 # front, between and behind. "long": an EMPTY item in front of slot 3's run
 # (it carries `start`: the state is still copied in), 17 full items, the
 # run's partial last piece, a rider of slot 1, a filler.
+# "decode": a launch ONE row wide (the one-row kernel): distinct slots, an
+# item without rows between them, a request's row 0 (`fresh`), slot 3
+# untouched. "riders": the three forms in one launch: 8-row pieces, a
+# 3-row tail piece, an item of ONE row directly after pieces of its own
+# slot (no `start`: it updates the state where the piece left it), other
+# slots' decode rows riding, one of them a request's row 0, and a filler.
 LAYOUTS = {
     "mixed": (5, [(4, 0, 0), (4, 0, 8), (4, 8, 3), (2, 7, 1), (2, 0, 0),
                   (0, 40, 8), (1, 0, 0), (1, 0, 0)]),
     "long": (5, [(3, 0, 0)] + [(3, 64 + 8 * i, 8) for i in range(17)]
              + [(3, 200, 5), (1, 77, 1), (1, 0, 0)]),
+    "decode": (6, [(0, 12, 1), (1, 0, 0), (2, 0, 1), (4, 33, 1), (4, 0, 0),
+                   (5, 7, 1)]),
+    "riders": (6, [(3, 40, 8), (3, 48, 8), (3, 56, 1), (1, 16, 8),
+                   (1, 24, 3), (0, 9, 1), (2, 0, 1), (5, 70, 1),
+                   (5, 0, 0)]),
 }
+
+
+def scan_case(items, n_slots, heads, decay, seed=3, p=64, n=128):
+    """A launch of `items` through `ssd_ragged_scan` (interpreted) and
+    through the scan over items and rows: ((y, state), (want_y, want_s),
+    the state before), dead rows' read-outs zeroed on both sides. The
+    launch is one row wide where no item has more."""
+    rng = np.random.default_rng(seed)
+    B = len(items)
+    W = ROWS if max(it[2] for it in items) > 1 else 1
+    slots, pos, q_lens = (jnp.asarray([it[k] for it in items], jnp.int32)
+                          for k in range(3))
+    alive = (np.arange(W)[None, :] < np.asarray(q_lens)[:, None])[..., None]
+    xh = jnp.asarray(rng.normal(size=(B, W, heads, p)), jnp.float32)
+    b_in = jnp.asarray(rng.normal(size=(B, W, n)), jnp.float32)
+    c_out = jnp.asarray(rng.normal(size=(B, W, n)), jnp.float32)
+    dt = jnp.asarray(np.where(alive, rng.uniform(0.01, 1.0, (B, W, heads)),
+                              0.0), jnp.float32)
+    a = jnp.asarray(np.where(alive, decay * rng.uniform(0.5, 1.5,
+                                                        (B, W, heads)), 0.0),
+                    jnp.float32)
+    state = jnp.asarray(rng.normal(size=(n_slots, heads, p, n)), jnp.float32)
+    chain = item_chain(slots, pos, q_lens)
+    want_y, want_s = mamba2.scan_items(xh, b_in, c_out, dt, a, chain, state)
+    slot, start, fresh, _last = chain
+    y, s = ssd_scan.ssd_ragged_scan(
+        (dt[..., None] * xh).reshape(B, W, heads * p), b_in, c_out, a, state,
+        slot, start.astype(jnp.int32), fresh.astype(jnp.int32), q_lens,
+        heads=heads, interpret=True)
+    y = np.where(alive[..., None], np.asarray(y).reshape(B, W, heads, p), 0.0)
+    want_y = np.where(alive[..., None], np.asarray(want_y), 0.0)
+    return ((y, np.asarray(s)), (want_y, np.asarray(want_s)),
+            np.asarray(state))
+
+
+def assert_scan_equals_oracle(got, want, before, items):
+    (y, s), (want_y, want_s) = got, want
+    # float32 at full precision; read-outs and states of magnitude ~10-100
+    scale_y, scale_s = np.abs(want_y).max(), np.abs(want_s).max()
+    assert np.abs(y - want_y).max() <= 2e-6 * scale_y
+    assert np.abs(s - want_s).max() <= 2e-6 * scale_s
+    untouched = sorted(set(range(len(before))) - {it[0] for it in items})
+    assert untouched
+    np.testing.assert_array_equal(s[untouched], before[untouched])
 
 
 @pytest.mark.parametrize("decay", DECAYS)
@@ -312,39 +386,41 @@ def test_kernel_interpreted_equals_its_oracle(decay, layout):
     """`ssd_ragged_scan` (interpreted) against the scan over items and
     rows, at the published head shape (P 64, N 128), 16 heads."""
     n_slots, items = LAYOUTS[layout]
-    H, P, N = 16, 64, 128
-    rng = np.random.default_rng(3)
-    B = len(items)
-    slots, pos, q_lens = (jnp.asarray([it[k] for it in items], jnp.int32)
-                          for k in range(3))
-    alive = (np.arange(ROWS)[None, :] < np.asarray(q_lens)[:, None])[..., None]
-    xh = jnp.asarray(rng.normal(size=(B, ROWS, H, P)), jnp.float32)
-    b_in = jnp.asarray(rng.normal(size=(B, ROWS, N)), jnp.float32)
-    c_out = jnp.asarray(rng.normal(size=(B, ROWS, N)), jnp.float32)
-    dt = jnp.asarray(np.where(alive, rng.uniform(0.01, 1.0, (B, ROWS, H)),
-                              0.0), jnp.float32)
-    a = jnp.asarray(np.where(alive, decay * rng.uniform(0.5, 1.5,
-                                                        (B, ROWS, H)), 0.0),
-                    jnp.float32)
-    state = jnp.asarray(rng.normal(size=(n_slots, H, P, N)), jnp.float32)
-    chain = item_chain(slots, pos, q_lens)
-    want_y, want_s = mamba2.scan_items(xh, b_in, c_out, dt, a, chain, state)
-    slot, start, fresh, _last = chain
-    cb = jnp.einsum("bin,bjn->bij", c_out, b_in,
-                    precision=jax.lax.Precision.HIGHEST)
-    y, s = ssd_scan.ssd_ragged_scan(
-        (dt[..., None] * xh).reshape(B, ROWS, H * P), b_in, c_out,
-        ssd_scan.pack_small(a, cb), state, slot, start.astype(jnp.int32),
-        fresh.astype(jnp.int32), q_lens, heads=H, interpret=True)
-    y = np.where(alive[..., None], np.asarray(y).reshape(B, ROWS, H, P), 0.0)
-    want_y = np.where(alive[..., None], np.asarray(want_y), 0.0)
-    # float32 at full precision; read-outs and states of magnitude ~10-100
-    scale_y, scale_s = np.abs(want_y).max(), np.abs(np.asarray(want_s)).max()
-    assert np.abs(y - want_y).max() <= 2e-6 * scale_y
-    assert np.abs(np.asarray(s) - np.asarray(want_s)).max() <= 2e-6 * scale_s
-    untouched = sorted(set(range(n_slots)) - {it[0] for it in items})
-    np.testing.assert_array_equal(np.asarray(s)[untouched],
-                                  np.asarray(state)[untouched])
+    got, want, before = scan_case(items, n_slots, 16, decay)
+    assert_scan_equals_oracle(got, want, before, items)
+
+
+# heads, launch width -> heads a grid step takes at P 64, N 128: a launch
+# one row wide takes every head its budget holds, one that may hold a
+# solve a lane tile of `small`
+HEADS_A_STEP = {(8, 1): 8, (16, 1): 16, (64, 1): 64,
+                (8, ROWS): 8, (16, ROWS): 8, (64, ROWS): 8}
+
+
+@pytest.mark.parametrize("heads,width", sorted(HEADS_A_STEP))
+def test_every_heads_a_step_against_the_oracle(heads, width):
+    """Each number of heads a step the derivation returns for 8, 16 and
+    64 heads, in both kernels, against the scan over items and rows."""
+    assert (ssd_scan._heads_a_step(heads, 64, 128, width)
+            == HEADS_A_STEP[heads, width])
+    items = [(2, 5, 1), (0, 0, 1), (0, 0, 0)]
+    if width > 1:
+        items = [(1, 16, 8), (1, 24, 2), (1, 26, 1)] + items
+    got, want, before = scan_case(items, 4, heads, -1.0, seed=heads)
+    assert_scan_equals_oracle(got, want, before, items)
+
+
+def test_heads_a_step_follow_the_budget():
+    """What the states of a step may take of VMEM decides, in whole lane
+    tiles of `small` that divide the heads; never under one tile."""
+    per_head = 4 * 64 * 128 * 4         # in and out, double-buffered
+    assert ssd_scan.STATE_VMEM // per_head == 64
+    assert ssd_scan._heads_a_step(128, 64, 128, 1) == 64
+    assert ssd_scan._heads_a_step(64, 64, 512, 1) == 16
+    assert ssd_scan._heads_a_step(48, 64, 256, 1) == 24
+    assert ssd_scan._heads_a_step(24, 64, 128, 1) == 24
+    assert ssd_scan._heads_a_step(64, 64, 8192, 1) == 8
+    assert ssd_scan._heads_a_step(64, 64, 128, 2) == 8
 
 
 # ---------------------------------------------------------------------------
@@ -497,25 +573,52 @@ def test_through_the_server_at_three_slots(tiny):
 
 def test_launch_spans_name_the_op_that_is_there(tiny):
     from flexflow_tpu import obs
+    from flexflow_tpu.runtime.executor import launch_columns
 
+    handed = []         # q_lens as each launch's step program is handed them
     rec = obs.enable()
     try:
         srv = tiny.serve_generation(paged=True, slots=2, max_len=64,
                                     page_size=8, prefill_chunk=16,
                                     prefix_cache=False)
+        step = srv._step
+
+        def rec_step(tr, ntr, caches, tbl, pos, qls, deps, anc, **fed):
+            packed = np.asarray(fed["packed"])
+            at, _ = launch_columns(anc.shape[1], width=packed.shape[1])
+            handed.append(packed[:, at["q_lens"]])
+            return step(tr, ntr, caches, tbl, pos, qls, deps, anc, **fed)
+
+        srv._step = rec_step
         try:
-            srv.submit(IDS[:37], 3).result()
+            futs = [srv.submit(IDS[:37], 3), srv.submit(IDS[:9], 4)]
+            for f in futs:
+                f.result()
         finally:
             srv.stop()
     finally:
         obs.disable()
-    first = [ev[4] for ev in rec.events if ev[0] == "launch_dispatch"][0]
+    spans = [ev[4] for ev in rec.events if ev[0] == "launch_dispatch"]
+    first = spans[0]
     assert (first["state_slots"], first["slots"], first["ssd_rows"],
-            first["ssd_pieces"]) == (1, 2, 16, 2)
-    assert "kda_rows" not in first
+            first["ssd_pieces"], first["ssd_one_row"]) == (1, 2, 16, 2, 0)
+    assert "kda_rows" not in first and "kda_one_row" not in first
     assert first["state_bytes_per_slot"] == 3 * (8 * 16 * 128 * 4
                                                  + 3 * 384 * 4)
     assert first["kv_bytes_per_token"] == 2 * 2 * 64 * 4
+    # the host's count of items of ONE live row is what the kernel's
+    # `rows == 1` decides on the device, launch by launch: a chunk's
+    # one-row tail (9 = 8 + 1), decode rows riding and alone
+    assert len(spans) == len(handed)
+    assert ([sp["ssd_one_row"] for sp in spans]
+            == [int((q == 1).sum()) for q in handed])
+    assert ([sp["ssd_pieces"] for sp in spans]
+            == [int((q > 0).sum()) for q in handed])
+    one_row = sum(sp["ssd_one_row"] for sp in spans)
+    assert one_row >= 6
+    state = srv.metrics()["state"]
+    assert state["items_one_row"] == one_row
+    assert state["items"] == sum(sp["ssd_pieces"] for sp in spans)
 
 
 def test_the_tied_head_is_one_leaf(tiny):

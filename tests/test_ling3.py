@@ -329,6 +329,10 @@ def test_launch_spans_count_the_states(tiny):
     assert (first["state_slots"], first["kda_rows"],
             first["kda_pieces"]) == (1, 16, 2)
     assert (third["kda_rows"], third["kda_pieces"]) == (5, 1)
+    # items of ONE live row, named by the op as the rows are (a decode
+    # launch's one item is one; a piece of 16 or 5 rows is not)
+    assert [sp["kda_one_row"] for sp in spans[:4]] == [0, 0, 0, 1]
+    assert "ssd_one_row" not in first
     assert first["state_bytes_per_slot"] == 2 * (4096 + 2304)
     assert first["kv_bytes_per_token"] == 128 * 4      # one latent layer
 

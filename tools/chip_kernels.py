@@ -416,10 +416,13 @@ def _kda_case(kind, seed=0):
 
 def _ssd_case(kind, seed=0):
     """The state-space scan (`ssd_ragged_scan`) at Granite-4.0-H-Micro's
-    widths, 64 heads of a 64 x 128 float32 state over 32 slots. "decode"
-    is the cell's usual launch, 32 one-row items, two of them without
-    rows; "chunk512" a chunk's: 64 pieces of one slot, a filler item
-    without rows, 31 other slots' decode rows (96 items). Log-decays as
+    widths, 64 heads of a 64 x 128 float32 state over 32 slots, called as
+    ops/mamba2.py `paged_mixer` calls it. "decode" is the cell's usual
+    launch, ONE row wide: 32 one-row items, two of them without rows (the
+    one-row kernel, as many heads a step as `_heads_a_step` derives);
+    "chunk512" a chunk's, eight rows wide: 64 pieces of one slot, a filler
+    item without rows, 31 other slots' decode rows (96 items: the solve
+    and the one-row form in one launch). Log-decays as
     the builder's draw gives them (-0.01 to -1.6 a step); "weakdecay" is
     chunk512 at -0.01 a step on every live row and head, "strongdecay" at
     -30 (exp(-30) is 1e-13: a state forgotten every step, which the
@@ -435,11 +438,13 @@ def _ssd_case(kind, seed=0):
     from flexflow_tpu.ops.pallas import ssd_scan
     from flexflow_tpu.ops.slot_state import item_chain
 
-    H, P, N, S, W = 64, 64, 128, 32, ssd_scan.ROWS
+    H, P, N, S = 64, 64, 128, 32
     if kind == "decode":
+        W = 1
         items = [(s, 0 if s == 5 else 300 + 84 * s, 0 if s in (2, 6) else 1)
                  for s in range(S)]
     else:
+        W = ssd_scan.ROWS
         items = [(3, 1024 + 8 * i, 8) for i in range(64)] + [(3, 0, 0)] + [
             (s, 300 + 84 * s, 1) for s in range(S) if s != 3]
     B = len(items)
@@ -460,13 +465,10 @@ def _ssd_case(kind, seed=0):
 
     def fn(xh, b_in, c_out, dt, a, state):
         slot, start, fresh, _ = item_chain(slots, pos, q_lens)
-        cb = jnp.einsum("bin,bjn->bij", c_out, b_in,
-                        precision=lax.Precision.HIGHEST)
         y, s = ssd_scan.ssd_ragged_scan(
-            (dt[..., None] * xh).reshape(B, W, H * P), b_in, c_out,
-            ssd_scan.pack_small(a, cb), state, slot,
-            start.astype(jnp.int32), fresh.astype(jnp.int32), q_lens,
-            heads=H)
+            (dt[..., None] * xh).reshape(B, W, H * P), b_in, c_out, a,
+            state, slot, start.astype(jnp.int32), fresh.astype(jnp.int32),
+            q_lens, heads=H)
         return jnp.where(alive[..., None], y.reshape(B, W, H, P), 0), s
 
     def ref(xh, b_in, c_out, dt, a, state):
@@ -477,6 +479,15 @@ def _ssd_case(kind, seed=0):
             xh, b_in, c_out, dt, a, state)
 
     return fn, (xh, b_in, c_out, dt, a, state), ref
+
+
+def _ssd_heads_a_step(kind):
+    """Heads a grid step of `_ssd_case(kind)`'s launch, as the module
+    derives them from the launch's width."""
+    from flexflow_tpu.ops.pallas import ssd_scan
+
+    return ssd_scan._heads_a_step(64, 64, 128,
+                                 1 if kind == "decode" else ssd_scan.ROWS)
 
 
 def _ssd_rows_float64(items, xh, b_in, c_out, dt, a, state):
@@ -782,6 +793,9 @@ def main(argv=None) -> int:
                                        per_call=2 if mark == "moe_grouped"
                                        else 1)
                 took = f" kernel_us={us:.1f}"
+                if name.startswith("ssd_"):
+                    took += (" heads_a_step="
+                             f"{_ssd_heads_a_step(name[len('ssd_'):])}")
             elif args.time and name.startswith("route_"):
                 us, by_sort = (_kernel_device_us(f, fargs, None)
                                for f in (jfn, jref))
