@@ -136,6 +136,113 @@ def audit_hlo_text(txt: str, min_bytes: int = 0) -> List[Dict]:
     return out
 
 
+# A LAYOUT operation only moves or re-lays data: one of these opcodes, or a
+# fusion XLA named after nothing but these words (`bitcast_bitcast_fusion`;
+# a TPU fusion is named after the opcodes it holds). The benchmark's reader
+# of a serving trace decides the same way from an event's HLO line
+# (benchmark/readers/serve_scope.py; tests hold the two rules equal).
+LAYOUT_OPCODES = frozenset({
+    "copy", "copy-start", "copy-done", "slice-start", "slice-done",
+    "transpose", "reshape", "slice"})
+LAYOUT_WORDS = frozenset({"bitcast", "copy", "transpose", "reshape",
+                          "slice"})
+_INSTR_RE = re.compile(
+    r"^(?:ROOT )?%?([\w.\-]+) = (\((?:[^()]|\([^()]*\))*\)|\S+) "
+    r"([a-z][\w\-]*)\((.*)$")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+_NAMED_RE = re.compile(r"%([\w.\-]+)")
+
+
+def is_layout(name: str, opcode: str) -> bool:
+    """Whether the instruction `name` of `opcode` is a layout operation."""
+    if opcode in LAYOUT_OPCODES:
+        return True
+    words = re.sub(r"\.\d+$", "", name).split("_")
+    return (opcode == "fusion" and len(words) > 1 and words[-1] == "fusion"
+            and all(w in LAYOUT_WORDS for w in words[:-1]))
+
+
+def layout_operations(txt: str) -> List[Dict]:
+    """The layout operations of one compiled SERVING module (the ragged
+    step at a launch shape, compiled here for the CPU or for a described
+    v5e), largest first: [{name, opcode, group, node, part, result,
+    operand, bytes}]. What ROADMAP S10 asked to be named by hand from an
+    HLO dump. `group`, `node` and `part` come from the instruction's
+    `op_name` through obs.scopes.classify_serving; an instruction without
+    one (a copy the compiler made) takes its first named operand's that
+    has one, else, where an operand is a node's PARAMETER (a weight's
+    prefetch), that node's next instruction's, as the trace reader does,
+    so a copy is charged to the node it was made for. `result` and
+    `operand` are shapes WITH their layouts;
+    `bytes` the result's. Instructions inside a fusion's body are not
+    operations of their own and are skipped; a `-done` repeats its
+    `-start` and is skipped too."""
+    fused = set(_CALLS_RE.findall(txt))
+    own: Dict[str, str] = {}            # instruction -> its own name stack
+    instrs: Dict[str, Tuple] = {}       # -> (line number, operand names)
+    by_node: Dict[str, List[Tuple[int, str]]] = {}
+    found = []
+    done_at: Dict[str, int] = {}
+    inside = None
+    for at, line in enumerate(txt.splitlines()):
+        s = line.strip()
+        comp = _COMPUTATION_RE.match(s)
+        if comp:
+            inside = comp.group(1)
+            continue
+        m = _INSTR_RE.match(s)
+        if not m or inside in fused:
+            continue
+        name, result, opcode, rest = m.groups()
+        instrs[name] = (at, _NAMED_RE.findall(rest.split("), ")[0]))
+        om = _OPNAME_RE.search(s)
+        if om and "/" in om.group(1):
+            # (a parameter's `op_name`, and its copy's, is its PATH in the
+            # step's arguments, `trainable['l0_attn_5']['wq']`: no stack)
+            own[name] = om.group(1)
+            node = scopes.classify_serving(om.group(1))[1]
+            if node and node != scopes.UNPACK:
+                by_node.setdefault(node, []).append((at, om.group(1)))
+        if opcode.endswith("-done"):
+            # an asynchronous pair is USED where its `-done` stands
+            done_at.update((o, at) for o in instrs[name][1][:1])
+        elif is_layout(name, opcode):
+            found.append((name, result, opcode, rest))
+    keys = scopes.sorted_keys(by_node)
+
+    def stack_of(name: str, depth: int = 0) -> str:
+        if name in own or name not in instrs or depth > 8:
+            return own.get(name, "")
+        at, operands = instrs[name]
+        stack = next(filter(None, (stack_of(o, depth + 1)
+                                   for o in operands)), "")
+        if not stack:
+            # a node's PARAMETER (a weight's prefetch): the stack of that
+            # node's next instruction in the module's schedule
+            node = next((k for k in keys if any(f"__{k}__" in o
+                                                for o in operands)), None)
+            if node:
+                used = done_at.get(name, at)
+                later = [st for i, st in by_node[node] if i >= used]
+                stack = later[0] if later else by_node[node][-1][1]
+        own[name] = stack
+        return stack
+
+    out = []
+    for name, result, opcode, rest in found:
+        first = _NAMED_RE.search(rest)
+        group, node, part = scopes.classify_serving(stack_of(name))
+        out.append({
+            "name": name, "opcode": opcode, "group": group, "node": node,
+            "part": part, "result": result,
+            "operand": (f"{rest[:first.start()]} %{first.group(1)}".strip()
+                        if first else ""),
+            "bytes": shape_bytes(result)})
+    out.sort(key=lambda d: -d["bytes"])
+    return out
+
+
 _COLL_KINDS = ("all-reduce", "all-gather", "all-to-all",
                "collective-permute", "reduce-scatter")
 

@@ -30,6 +30,28 @@ benchmark's `scope_share` reader: `hc_mix` (ops/hyper_connection.py),
 `ssd_proj` / `ssd_scan` (ops/mamba2.py: a Mamba-2 mixer outside and
 inside its recurrence). Each is defined beside the code it wraps.
 
+The SERVING step (`Executor.ragged_step_fn`) says besides what each node
+is for. `run_forward` wraps a node's own scope in its GROUP, which the
+executor derives from the node's `OpType` and its place in the graph
+(`Executor.node_groups`), never from the spelling of its key; an attention
+node on the paged path names its four PARTS (`ATTN_PARTS`, each a constant
+beside the code it wraps: paged/attention.py); and what the step does
+outside its nodes (the packed descriptor's slices, the fed ids, the launch
+statistics' stacking) is under `UNPACK`. What jax 0.9.0 writes there (a
+CPU lowering; pinned by tests/test_obs_scopes.py), read by
+`classify_serving`:
+
+    jit(step)/attn/l0_attn_1003/qkv/dot_general       attn  l0_attn_1003  qkv
+    jit(step)/attn/l0_attn_1003/kv_write/scatter      attn  l0_attn_1003  kv_write
+    jit(step)/attn/l0_attn_1003/attend/pallas_call    attn  l0_attn_1003  attend
+    jit(step)/attn/l1_attn_7/attend/dsa_index/kv_write/scatter-add
+                          attn  l1_attn_7  kv_write  (the innermost part)
+    jit(step)/ffn/l0_gate_1007/dot_general            ffn   l0_gate_1007  -
+    jit(step)/state/l0_mixer_12/ssd_scan/pallas_call  state l0_mixer_12   -
+    jit(step)/unpack/slice                            glue  unpack        -
+    jit(step)/l0_attn_1003/dot_general     no group: an executable from
+                                           before the groups (a stale cache)
+
 A collective is named besides by the mesh axes its replica groups span
 (`group_axes`): an SPMD module's groups hold positions in the device
 assignment, which is the mesh's devices flattened in the order of its
@@ -59,6 +81,14 @@ _TRANSPOSE_MARK = "transpose("
 _WRAPPED = re.compile(r"^(?:[\w.\-]+\()+([^()]*)\)+$")
 # scopes jax itself puts between a top-level scope and a node's
 _JAX_SCOPES = ("checkpoint", _REMAT_MARK, "shard_map")
+
+# the serving step: what a node is for, the parts of an attention node on
+# the paged path (defined in paged/attention.py, beside what they wrap),
+# and the step's own work outside its nodes
+ATTN, FFN, EXPERTS, STATE, HEAD, GLUE = GROUPS = (
+    "attn", "ffn", "experts", "state", "head", "glue")
+ATTN_PARTS = ("qkv", "kv_write", "attend", "out")
+UNPACK = "unpack"
 
 
 def sorted_keys(node_keys: Sequence[str]) -> List[str]:
@@ -102,6 +132,34 @@ def classify(op_name: str, node_keys: Optional[Sequence[str]] = None
         if parts[i] != plain[i]:
             break       # a nested jit or transform: no node scope below
     return phase, None
+
+
+def classify_serving(op_name: str
+                     ) -> Tuple[Optional[str], Optional[str], Optional[str]]:
+    """(group, node key, attention part) of one `op_name` of the serving
+    step. The group is the first plain scope of the stack that is one of
+    `GROUPS`, the node the scope under it, the part the INNERMOST of
+    `ATTN_PARTS` below an attention node (a sparse layer writes its
+    pooled keys, `kv_write`, inside `attend/dsa_index`). `UNPACK` reads
+    as (`GLUE`, `UNPACK`, None). A stack that names a scope under
+    `jit(...)` and no group gives (None, that scope, None): a node of a
+    step compiled before the groups, which a reader reports. Anything
+    else (another program, a parameter, no stack) is (None, None, None)."""
+    parts = op_name.split("/")
+    inner = parts[1:-1]         # between `jit(step)` and the primitive
+    at = next((i for i, p in enumerate(inner)
+               if p in GROUPS or p == UNPACK), None)
+    if at is None:
+        stray = inner[0] if inner and not _WRAPPED.match(inner[0]) else None
+        return None, stray, None
+    if inner[at] == UNPACK:
+        return GLUE, UNPACK, None
+    group, below = inner[at], inner[at + 1:]
+    part = None
+    if group == ATTN:
+        part = next((p for p in reversed(below[1:]) if p in ATTN_PARTS),
+                    None)
+    return group, (below[0] if below else None), part
 
 
 # ---------------------------------------------------------------------------

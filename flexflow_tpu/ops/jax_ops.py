@@ -432,8 +432,8 @@ def cached_attention(q, k, v, cache_k, cache_v, pos, *, scale,
     return out, kc, vc
 
 
-@register_lowering(OpType.MULTIHEAD_ATTENTION)
-def _mha(attrs, inputs, params, ctx):
+def _mha_qkv(attrs, inputs, params):
+    """The three projections with their bias: (q, k, v, the rows' dtype)."""
     q_in = inputs[0]
     k_in = inputs[1] if len(inputs) > 1 else q_in
     v_in = inputs[2] if len(inputs) > 2 else k_in
@@ -445,52 +445,66 @@ def _mha(attrs, inputs, params, ctx):
         q = q + params["bq"].astype(dt)
         k = k + params["bk"].astype(dt)
         v = v + params["bv"].astype(dt)
-    rope_theta = attrs.rope_theta if attrs.rope else None
-    # a full layer with a plain rope calls the cache paths as it always
-    # has: its programs do not change with what other layers can do
-    rope_kw = {}
-    if attrs.window is not None or attrs.rope_scaling is not None:
-        rope_kw = {"window": attrs.window,
-                   "rope_scaling": attrs.rope_scaling}
-    if ctx.kv_cache is not None:
-        if ctx.page_tables is not None:
-            # every paged step — decode, chunked-prefill chunk, spec
-            # tree verify — is the SAME ragged call: the cache is a
-            # global page pool, this slot's rows are reached through
-            # its page table, and the (q_lens, depths, anc) descriptor
-            # says which of the S window rows are live and what they
-            # may see (flexflow_tpu.paged.attention — one Pallas kernel
-            # or the gather fallback behind one gate)
-            from flexflow_tpu.paged.attention import ragged_paged_attention
+    return q, k, v, dt
 
-            if "k_scale" in ctx.kv_cache:
-                # quantized pool: the scale sidecar rides the same
-                # per-node caches dict (paged/quant.py), so append
-                # quantizes under grow-only scales and both attention
-                # paths dequantize on load
-                out, kc, vc, ks, vs = ragged_paged_attention(
-                    q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
-                    ctx.page_tables, ctx.cache_position,
-                    ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=attrs.scale, rope_theta=rope_theta,
-                    k_scales=ctx.kv_cache["k_scale"],
-                    v_scales=ctx.kv_cache["v_scale"], **rope_kw,
-                )
-                ctx.cache_updates["k_scale"] = ks
-                ctx.cache_updates["v_scale"] = vs
-            else:
-                out, kc, vc = ragged_paged_attention(
-                    q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
-                    ctx.page_tables, ctx.cache_position,
-                    ctx.ragged_q_lens, ctx.ragged_depths, ctx.ragged_anc,
-                    scale=attrs.scale, rope_theta=rope_theta, **rope_kw,
-                )
-        else:
-            out, kc, vc = cached_attention(
-                q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
-                ctx.cache_position, scale=attrs.scale,
-                rope_theta=rope_theta, **rope_kw,
-            )
+
+def _mha_out(attrs, out, params, dt):
+    y = attn_out_project(out, params["wo"], dt)
+    if attrs.use_bias:
+        y = y + params["bo"].astype(dt)
+    return y
+
+
+def _window_kw(attrs):
+    """What a window or a scaled rope adds to a cache path's call: a full
+    layer with a plain rope calls it as it always has, so its programs do
+    not change with what other layers can do."""
+    if attrs.window is None and attrs.rope_scaling is None:
+        return {}
+    return {"window": attrs.window, "rope_scaling": attrs.rope_scaling}
+
+
+def _mha_paged(attrs, inputs, params, ctx):
+    """Every paged step (decode, a chunked-prefill chunk, a tree verify)
+    is the SAME ragged call: the cache is a global page pool, this slot's
+    rows are reached through its page table, and the (q_lens, depths,
+    anc) descriptor says which of the S window rows are live and what
+    they may see (flexflow_tpu.paged.attention: one Pallas kernel or the
+    gather fallback behind one gate). The node names its four parts:
+    `QKV` and `OUT` here, the rope, `KV_WRITE` and `ATTEND` in the call."""
+    from flexflow_tpu.paged import attention as pa
+
+    with jax.named_scope(pa.QKV):
+        q, k, v, dt = _mha_qkv(attrs, inputs, params)
+    kw = _window_kw(attrs)
+    if "k_scale" in ctx.kv_cache:
+        # quantized pool: the scale sidecar rides the same per-node
+        # caches dict (paged/quant.py), so append quantizes under
+        # grow-only scales and both attention paths dequantize on load
+        kw.update(k_scales=ctx.kv_cache["k_scale"],
+                  v_scales=ctx.kv_cache["v_scale"])
+    out, *pools = pa.ragged_paged_attention(
+        q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"], ctx.page_tables,
+        ctx.cache_position, ctx.ragged_q_lens, ctx.ragged_depths,
+        ctx.ragged_anc, scale=attrs.scale,
+        rope_theta=attrs.rope_theta if attrs.rope else None, **kw)
+    ctx.cache_updates.update(zip(("k", "v", "k_scale", "v_scale"), pools))
+    with jax.named_scope(pa.OUT):
+        return [_mha_out(attrs, out, params, dt)]
+
+
+@register_lowering(OpType.MULTIHEAD_ATTENTION)
+def _mha(attrs, inputs, params, ctx):
+    if ctx.kv_cache is not None and ctx.page_tables is not None:
+        return _mha_paged(attrs, inputs, params, ctx)
+    q, k, v, dt = _mha_qkv(attrs, inputs, params)
+    if ctx.kv_cache is not None:
+        out, kc, vc = cached_attention(
+            q, k, v, ctx.kv_cache["k"], ctx.kv_cache["v"],
+            ctx.cache_position, scale=attrs.scale,
+            rope_theta=attrs.rope_theta if attrs.rope else None,
+            **_window_kw(attrs),
+        )
         ctx.cache_updates["k"] = kc
         ctx.cache_updates["v"] = vc
     else:
@@ -515,10 +529,7 @@ def _mha(attrs, inputs, params, ctx):
                 dropout=attrs.dropout if ctx.training else 0.0,
                 dropout_rng=drop_rng, mesh=ctx.mesh,
             )
-    y = attn_out_project(out, params["wo"], dt)
-    if attrs.use_bias:
-        y = y + params["bo"].astype(dt)
-    return [y]
+    return [_mha_out(attrs, out, params, dt)]
 
 
 @register_lowering(OpType.LATENT_ATTENTION)

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from flexflow_tpu.paged.attention import ATTEND, KV_WRITE, OUT, QKV
+
 
 def yarn_inv_freq(attrs) -> np.ndarray:
     """(rope_dim / 2,) float32 rotary frequencies. Plain rope when
@@ -348,9 +350,10 @@ def paged_index_select(attrs, x, c_q, params, ctx, positions):
         safe = jnp.minimum(block, NB - 1)
         page = jnp.take_along_axis(tables, safe // R, axis=1)
         row = safe % R
-        kp = kp.at[jnp.where(starts, page, 0), row].set(0)
-        kp = kp.at[jnp.where(touched, page, 0), row].add(
-            jnp.where(touched[..., None], part, 0.0).astype(kp.dtype))
+        with jax.named_scope(KV_WRITE):
+            kp = kp.at[jnp.where(starts, page, 0), row].set(0)
+            kp = kp.at[jnp.where(touched, page, 0), row].add(
+                jnp.where(touched[..., None], part, 0.0).astype(kp.dtype))
         pooled = kp[tables].reshape(B, NB, kp.shape[2])
         scores = index_scores(q_i, w, pooled)
     own = (positions // p)[..., None]                          # (B, S, 1)
@@ -383,22 +386,31 @@ def paged_attention(attrs, x, params, ctx):
     pos + depths, the tokens' latent rows appended to the pool, absorbed
     attention over the page table; a sparse layer's indexer first (rows
     in chain order: tree verify is refused on a latent graph). Returns
-    (y, {pool entry: new pool}, a sparse layer's stats or None)."""
+    (y, {pool entry: new pool}, a sparse layer's stats or None). The node
+    names the four parts an attention node has on the paged path
+    (paged/attention.py): `QKV` and `OUT` here, the indexer under
+    `ATTEND` with its pooled keys' write `KV_WRITE` inside; the rows'
+    write and the walk are named in the call (paged/latent.py)."""
     from flexflow_tpu.paged.latent import latent_paged_attention
 
     positions = jnp.asarray(ctx.cache_position)[:, None] + ctx.ragged_depths
-    c_q = query_latent(attrs, x, params)
-    q_nope, q_rope, c_kv, k_r = project(attrs, x, params, positions, c_q)
-    q = absorbed_queries(attrs, q_nope, q_rope, params)
-    row = c_kv if k_r is None else jnp.concatenate([c_kv, k_r], axis=-1)
+    with jax.named_scope(QKV):
+        c_q = query_latent(attrs, x, params)
+        q_nope, q_rope, c_kv, k_r = project(attrs, x, params, positions,
+                                            c_q)
+        q = absorbed_queries(attrs, q_nope, q_rope, params)
+        row = c_kv if k_r is None else jnp.concatenate([c_kv, k_r],
+                                                       axis=-1)
     pools, keep, stats = {}, None, None
     if attrs.index_heads:
-        keep, pools["kp"], stats = paged_index_select(
-            attrs, x, c_q, params, ctx, positions)
+        with jax.named_scope(ATTEND):
+            keep, pools["kp"], stats = paged_index_select(
+                attrs, x, c_q, params, ctx, positions)
     with _attend_scope(attrs):
         o_lat, pools["c"] = latent_paged_attention(
             q, row, ctx.kv_cache["c"], ctx.page_tables, ctx.cache_position,
             ctx.ragged_q_lens, ctx.ragged_anc,
             value_width=attrs.kv_lora_rank, block_keep=keep,
             block_tokens=attrs.index_pool)
-    return absorbed_output(attrs, o_lat, params, x), pools, stats
+    with jax.named_scope(OUT):
+        return absorbed_output(attrs, o_lat, params, x), pools, stats

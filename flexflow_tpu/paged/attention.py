@@ -98,6 +98,16 @@ from flexflow_tpu.serve_strategy import PREFILL_WINDOW_ROWS
 
 NEG_INF = -1e30
 LANES = 128
+# The four PARTS an attention node names on the paged path, here, in
+# paged/latent.py and in the two lowerings (ops/jax_ops.py `_mha_paged`,
+# ops/latent_attention.py `paged_attention`); obs/scopes.py `ATTN_PARTS`
+# lists them and `classify_serving` reads them back. An operation is its
+# innermost part's.
+QKV = "qkv"             # the projections, their bias, the rope
+KV_WRITE = "kv_write"   # the launch's new rows into the pool
+ATTEND = "attend"       # from projected rows to attended rows: descriptor
+#                         arithmetic, the operands' layout, kernel or gather
+OUT = "out"             # the output projection
 
 logger = logging.getLogger(__name__)
 _fallback_logged: set = set()
@@ -430,7 +440,6 @@ def ragged_launch_fits(B: int, S: int, H: int, D: int, page_size: int,
 
 
 @jax.jit
-@jax.named_scope("ragged_runs")
 def ragged_runs(page_tables, pos, q_lens, anc_mask):
     """(run_len, horizon), both (B,) int32: the RUNS of a launch, read
     from the descriptor it is handed. Entry b CONTINUES entry b - 1 when
@@ -991,28 +1000,30 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
     pos_v = jnp.asarray(pos)
     qlen_v = jnp.asarray(q_lens)
     if rope_theta is not None:
-        positions = pos_v[:, None] + depths                # (B, S)
-        q = apply_rope(q, rope_theta, pos_offset=positions,
-                       scaling=rope_scaling)
-        k = apply_rope(k, rope_theta, pos_offset=positions,
-                       scaling=rope_scaling)
-    L = page_tables.shape[1] * P
-    rows = pos_v[:, None] + jnp.arange(S)[None, :]         # (B, S)
-    safe = jnp.minimum(rows, L - 1)
-    bidx = jnp.arange(B)[:, None]
-    page = page_tables[bidx, safe // P]                    # (B, S)
-    live = (rows < L) & (jnp.arange(S)[None, :] < qlen_v[:, None])
-    page = jnp.where(live, page, 0)
-    off = safe % P
-    if k_scales is not None:
-        kc, ks = quantized_append(cache_k, k_scales, k, page, off, live)
-        vc, vs = quantized_append(cache_v, v_scales, v, page, off, live)
-    else:
-        kc = cache_k.at[page, off].set(
-            k.reshape(B, S, -1).astype(cache_k.dtype))
-        vc = cache_v.at[page, off].set(
-            v.reshape(B, S, -1).astype(cache_v.dtype))
-        ks = vs = None
+        with jax.named_scope(QKV):
+            positions = pos_v[:, None] + depths                # (B, S)
+            q = apply_rope(q, rope_theta, pos_offset=positions,
+                           scaling=rope_scaling)
+            k = apply_rope(k, rope_theta, pos_offset=positions,
+                           scaling=rope_scaling)
+    with jax.named_scope(KV_WRITE):
+        L = page_tables.shape[1] * P
+        rows = pos_v[:, None] + jnp.arange(S)[None, :]         # (B, S)
+        safe = jnp.minimum(rows, L - 1)
+        bidx = jnp.arange(B)[:, None]
+        page = page_tables[bidx, safe // P]                    # (B, S)
+        live = (rows < L) & (jnp.arange(S)[None, :] < qlen_v[:, None])
+        page = jnp.where(live, page, 0)
+        off = safe % P
+        if k_scales is not None:
+            kc, ks = quantized_append(cache_k, k_scales, k, page, off, live)
+            vc, vs = quantized_append(cache_v, v_scales, v, page, off, live)
+        else:
+            kc = cache_k.at[page, off].set(
+                k.reshape(B, S, -1).astype(cache_k.dtype))
+            vc = cache_v.at[page, off].set(
+                v.reshape(B, S, -1).astype(cache_v.dtype))
+            ks = vs = None
 
     force_interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
     D = q.shape[-1]
@@ -1027,21 +1038,22 @@ def ragged_paged_attention(q, k, v, cache_k, cache_v, page_tables, pos,
             f"a launch of {B} entries x {S} rows keeps more in VMEM than "
             "the core has",
             (q.shape[-1], P, kc.dtype.name, jax.default_backend()))
-    if kernel:
-        # derived HERE, in the step's own trace, where the layers of a
-        # launch are handed the same arrays: XLA merges their equal
-        # derivations into one a launch (inside the kernel's jit each
-        # layer would run its own)
-        out = ragged_flash_attention(
-            q, kc, vc, page_tables, pos_v, qlen_v, anc_mask, scale=scale,
-            interpret=force_interp, k_scales=ks, v_scales=vs,
-            window=window,
-            runs=ragged_runs(page_tables, pos_v, qlen_v, anc_mask))
-    else:
-        out = ragged_gather_attention(q, kc, vc, page_tables, pos_v,
-                                      qlen_v, anc_mask, scale=scale,
-                                      k_scales=ks, v_scales=vs,
-                                      window=window)
+    with jax.named_scope(ATTEND):
+        if kernel:
+            # derived HERE, in the step's own trace, where the layers of
+            # a launch are handed the same arrays: XLA merges their equal
+            # derivations into one a launch (inside the kernel's jit each
+            # layer would run its own)
+            out = ragged_flash_attention(
+                q, kc, vc, page_tables, pos_v, qlen_v, anc_mask,
+                scale=scale, interpret=force_interp, k_scales=ks,
+                v_scales=vs, window=window,
+                runs=ragged_runs(page_tables, pos_v, qlen_v, anc_mask))
+        else:
+            out = ragged_gather_attention(q, kc, vc, page_tables, pos_v,
+                                          qlen_v, anc_mask, scale=scale,
+                                          k_scales=ks, v_scales=vs,
+                                          window=window)
     if k_scales is not None:
         return out, kc, vc, ks, vs
     return out, kc, vc

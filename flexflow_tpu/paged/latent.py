@@ -63,6 +63,8 @@ from jax.experimental.pallas import tpu as pltpu
 from flexflow_tpu.paged.attention import (
     _KV_VMEM_SHARE,
     _SCORE_TILE_BYTES,
+    ATTEND,
+    KV_WRITE,
     LANES,
     NEG_INF,
     _reject,
@@ -380,27 +382,29 @@ def latent_paged_attention(q, row, pool, page_tables, pos, q_lens,
     B, S, H, width = q.shape
     P, lanes = pool.shape[1], pool.shape[2]
     pos_v, qlen_v = jnp.asarray(pos), jnp.asarray(q_lens)
-    L = page_tables.shape[1] * P
-    rows = pos_v[:, None] + jnp.arange(S)[None, :]
-    safe = jnp.minimum(rows, L - 1)
-    page = page_tables[jnp.arange(B)[:, None], safe // P]
-    live = (rows < L) & (jnp.arange(S)[None, :] < qlen_v[:, None])
-    page = jnp.where(live, page, 0)
-    pad = ((0, 0),) * (row.ndim - 1) + ((0, lanes - width),)
-    pool = pool.at[page, safe % P].set(
-        jnp.pad(row, pad).astype(pool.dtype))
-    qp = jnp.pad(q, ((0, 0),) * 3 + ((0, lanes - width),))
-    value_lanes = min(lanes, _round_up(value_width, LANES))
-    interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
-    if latent_attention_available(P, interpret=interp, dtype=pool.dtype):
-        out = latent_flash_attention(qp, pool, page_tables, pos_v, qlen_v,
-                                     anc_mask, value_lanes=value_lanes,
-                                     interpret=interp,
-                                     block_keep=block_keep,
-                                     block_tokens=block_tokens)
-    else:
-        out = latent_gather_attention(qp, pool, page_tables, pos_v, qlen_v,
-                                      anc_mask, value_lanes=value_lanes,
-                                      block_keep=block_keep,
-                                      block_tokens=block_tokens)
-    return out[..., :value_width], pool
+    with jax.named_scope(KV_WRITE):
+        L = page_tables.shape[1] * P
+        rows = pos_v[:, None] + jnp.arange(S)[None, :]
+        safe = jnp.minimum(rows, L - 1)
+        page = page_tables[jnp.arange(B)[:, None], safe // P]
+        live = (rows < L) & (jnp.arange(S)[None, :] < qlen_v[:, None])
+        page = jnp.where(live, page, 0)
+        pad = ((0, 0),) * (row.ndim - 1) + ((0, lanes - width),)
+        pool = pool.at[page, safe % P].set(
+            jnp.pad(row, pad).astype(pool.dtype))
+    with jax.named_scope(ATTEND):
+        qp = jnp.pad(q, ((0, 0),) * 3 + ((0, lanes - width),))
+        value_lanes = min(lanes, _round_up(value_width, LANES))
+        interp = os.environ.get("FF_TPU_FLASH_INTERPRET") == "1"
+        if latent_attention_available(P, interpret=interp,
+                                      dtype=pool.dtype):
+            out = latent_flash_attention(
+                qp, pool, page_tables, pos_v, qlen_v, anc_mask,
+                value_lanes=value_lanes, interpret=interp,
+                block_keep=block_keep, block_tokens=block_tokens)
+        else:
+            out = latent_gather_attention(
+                qp, pool, page_tables, pos_v, qlen_v, anc_mask,
+                value_lanes=value_lanes, block_keep=block_keep,
+                block_tokens=block_tokens)
+        return out[..., :value_width], pool

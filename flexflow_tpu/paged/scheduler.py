@@ -1978,8 +1978,6 @@ class PagedGenerationServer(_GenerationServerBase):
             self.peak_active = max(self.peak_active, len(live))
             if sp:
                 sp.set(live=len(live),
-                       mid_prefill=sum(1 for s in live
-                                       if self._mid_prefill(s)),
                        pages_in_use=self.pool.pages_in_use,
                        admitted=admitted)
             if not any(self._packable(self._active[s]) for s in live):

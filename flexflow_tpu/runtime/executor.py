@@ -110,6 +110,16 @@ PAGED_ATTENTION_OPS = (OpType.MULTIHEAD_ATTENTION, OpType.RING_ATTENTION,
 # what the tracing calls the rows each kind takes of a launch
 STATE_KINDS = {OpType.KDA_ATTENTION: "kda", OpType.MAMBA2: "ssd"}
 STATE_OPS = tuple(STATE_KINDS)
+# the ops of an expert layer, and what may stand on the way from the head's
+# softmax back to its norm or between a feed-forward's linears
+# (`Executor.node_groups`)
+_EXPERT_OPS = (OpType.EXPERT_SHARE, OpType.EXPERTS, OpType.GROUP_BY,
+               OpType.AGGREGATE, OpType.AGGREGATE_SPEC)
+_ROUTER_OPS = (OpType.LINEAR, OpType.SOFTMAX, OpType.TOPK)
+_NORM_OPS = (OpType.RMS_NORM, OpType.LAYER_NORM)
+_HEAD_OPS = (OpType.SOFTMAX, OpType.LINEAR, OpType.TIED_HEAD,
+             OpType.ELEMENT_UNARY, OpType.CAST) + _NORM_OPS
+_ELEMENTWISE_OPS = (OpType.ELEMENT_UNARY, OpType.ELEMENT_BINARY)
 
 
 def _cast_weight_leaf(arr, weight_dtype: str):
@@ -224,6 +234,7 @@ class Executor:
         self._forward = None
         self._decode_fn = None
         self._ragged_step_fn = None
+        self._node_groups = None
         self._paged_commit_fn = None
         # compile-event tracker (obs/compile_tracker.py): each decode-
         # path jit factory below hands its callable through wrap(), so
@@ -596,9 +607,20 @@ class Executor:
                    if page_tables is not None and page_tables.ndim == 3
                    else None)
         remat_groups = self._remat_groups if training else {}
+        # a PAGED step says besides what each node is for: the node's own
+        # scope inside its group's (`node_groups`; obs/scopes.py
+        # `classify_serving`). Every other step's stacks are what they were
+        from flexflow_tpu.obs import scopes
+
+        groups = self.node_groups() if page_tables is not None else None
+
+        def scope_of(key):
+            return jax.named_scope(
+                key if groups is None else f"{groups[key]}/{key}")
+
         for n in self.topo:
             if n.op_type == OpType.INPUT:
-                with jax.named_scope(node_key(n)):
+                with scope_of(node_key(n)):
                     vals = self._apply_view(n, [values[(n.guid, 0)]])
                 values[(n.guid, 0)] = vals[0]
                 continue
@@ -615,6 +637,11 @@ class Executor:
             params = {}
             params.update(trainable.get(key, {}))
             params.update(nontrainable.get(key, {}))
+            tables = page_tables
+            if classes is not None:
+                # the node's class of the launch's two tables
+                with jax.named_scope(scopes.UNPACK):
+                    tables = page_tables[classes.get(key, 0)]
             ctx = LowerCtx(
                 training=training,
                 rng=jax.random.fold_in(rng, n.guid) if rng is not None else None,
@@ -625,8 +652,7 @@ class Executor:
                 kv_cache=(kv_caches.get(key) if kv_caches is not None
                           else None),
                 cache_position=cache_position,
-                page_tables=(page_tables if classes is None
-                             else page_tables[classes.get(key, 0)]),
+                page_tables=tables,
                 ragged_q_lens=ragged_q_lens,
                 ragged_depths=ragged_depths,
                 ragged_anc=ragged_anc,
@@ -646,7 +672,7 @@ class Executor:
             # included: transpose/jvp wrappers keep the scope name), so
             # analysis.hloaudit can attribute lowered collectives back to
             # PCG nodes and diff them against the cost model's manifest
-            with jax.named_scope(key):
+            with scope_of(key):
                 if (
                     training
                     and self.remat == "attention"
@@ -861,6 +887,82 @@ class Executor:
         graph order; empty for a graph of attention layers alone."""
         return [node_key(n) for n in self.topo if n.op_type in STATE_OPS]
 
+    def node_groups(self) -> Dict[str, str]:
+        """{node key: group} for every node of the graph: what the node is
+        FOR, one of `obs.scopes.GROUPS`, derived from its `OpType` and its
+        place in the graph and never from the spelling of its key. `attn`
+        the attention nodes (full, window, latent), `state` the KDA and
+        Mamba-2 mixers, `experts` an expert layer with a router that feeds
+        nothing else, `head` the sink and what leads to it back to the
+        final norm, `ffn` a contracting linear with the elementwise nodes
+        and the expanding linears it alone consumes, `glue` the rest
+        (embedding, norms, residual adds, the streams' mixing). The ragged
+        step wraps each node's own scope in its group (`run_forward`)."""
+        if self._node_groups is not None:
+            return self._node_groups
+        from flexflow_tpu.obs import scopes
+
+        by_guid = {n.guid: n for n in self.topo}
+        group: Dict[int, str] = {}
+        for n in self.topo:
+            if n.op_type in PAGED_ATTENTION_OPS:
+                group[n.guid] = scopes.ATTN
+            elif n.op_type in STATE_OPS:
+                group[n.guid] = scopes.STATE
+            elif n.op_type in _EXPERT_OPS:
+                group[n.guid] = scopes.EXPERTS
+        # the head: from the sink back along first inputs to the final norm
+        chain, n = [], self.sink
+        while n.op_type in _HEAD_OPS and n.guid not in group:
+            chain.append(n)
+            ins = self.graph.in_edges(n)
+            if n.op_type in _NORM_OPS or not ins:
+                break
+            n = by_guid[ins[0].src]
+        if any(n.op_type in (OpType.LINEAR, OpType.TIED_HEAD)
+               for n in chain):
+            group.update((n.guid, scopes.HEAD) for n in chain)
+
+        def consumers(n):
+            return [by_guid[e.dst] for e in self.graph.out_edges(n)]
+
+        def widens(n):
+            return (n.outputs[0].dims[-1].size
+                    > self.graph.input_shapes(n)[0].dims[-1].size)
+
+        for n in reversed(self.topo):
+            if n.guid in group:
+                continue
+            users = consumers(n)
+            if n.op_type in _ROUTER_OPS and users and all(
+                    group.get(u.guid) == scopes.EXPERTS for u in users):
+                group[n.guid] = scopes.EXPERTS
+            elif n.op_type == OpType.LINEAR and not widens(n):
+                # a feed-forward's `down`: back through the activation
+                # and the product to the linears that widen the row
+                members, todo, whole = {n.guid: n}, [n], True
+                while todo and whole:
+                    for e in self.graph.in_edges(todo.pop()):
+                        m = by_guid[e.src]
+                        if m.guid in members:
+                            continue
+                        if m.guid in group or not (
+                                m.op_type in _ELEMENTWISE_OPS
+                                or m.op_type == OpType.LINEAR and widens(m)):
+                            whole = False
+                            break
+                        members[m.guid] = m
+                        if m.op_type != OpType.LINEAR:
+                            todo.append(m)
+                whole = whole and all(
+                    u.guid in members for m in members.values()
+                    if m is not n for u in consumers(m))
+                if whole and len(members) > 1:
+                    group.update((g, scopes.FFN) for g in members)
+        self._node_groups = {
+            node_key(n): group.get(n.guid, scopes.GLUE) for n in self.topo}
+        return self._node_groups
+
     def state_kinds(self) -> Tuple[str, ...]:
         """The kinds of state layer the graph holds (`STATE_KINDS`), in
         `STATE_OPS` order."""
@@ -1028,16 +1130,19 @@ class Executor:
         if self._ragged_step_fn is not None:
             return self._ragged_step_fn
 
+        from flexflow_tpu.obs import scopes
+
         classes = 1 if self.page_classes() is None else 2
         stateful = bool(self.state_layers())
 
-        def step(trainable, nontrainable, caches, page_tables, pos,
-                 q_lens, depths, anc, *inputs, feed=None, state_slots=None,
-                 packed=None):
+        def unpack(page_tables, pos, q_lens, window, inputs, feed,
+                   state_slots, packed):
+            """What the step does before its nodes, under `scopes.UNPACK`:
+            the descriptor's slices and the fed ids."""
             if packed is not None:
                 # ONE UPLOAD A LAUNCH: static slices of the descriptor
                 # stand where the positional operands (None then) did
-                at, _ = launch_columns(depths.shape[1], classes,
+                at, _ = launch_columns(window, classes,
                                        width=packed.shape[1])
                 tables = [packed[:, cols] for cols in at["tables"]]
                 page_tables = (tables[0] if classes == 1
@@ -1057,6 +1162,15 @@ class Executor:
                 fed = jnp.where(slot >= 0, newest[jnp.maximum(slot, 0)],
                                 ids[:, 0])
                 inputs = (ids.at[:, 0].set(fed),) + inputs[1:]
+            return page_tables, pos, q_lens, inputs, state_slots
+
+        def step(trainable, nontrainable, caches, page_tables, pos,
+                 q_lens, depths, anc, *inputs, feed=None, state_slots=None,
+                 packed=None):
+            with jax.named_scope(scopes.UNPACK):
+                page_tables, pos, q_lens, inputs, state_slots = unpack(
+                    page_tables, pos, q_lens, depths.shape[1], inputs,
+                    feed, state_slots, packed)
             cache_out = {}
             out, state, _ = self.run_forward(
                 trainable, nontrainable, inputs, training=False,
@@ -1065,19 +1179,17 @@ class Executor:
                 page_tables=page_tables, ragged=(q_lens, depths, anc),
                 state_slots=state_slots,
             )
-            moe = [st["moe_stats"] for _nk, st in sorted(state.items())
-                   if "moe_stats" in st]
-            if moe:
-                # what the expert layers counted in this launch, one row
-                # a layer, handed back beside the pools under a key no
-                # node has; the caller takes it out before the pools go
-                # into the next launch
-                cache_out[LAUNCH_STATS] = jnp.stack(moe)
-            dsa = [st["dsa_stats"] for _nk, st in sorted(state.items())
-                   if "dsa_stats" in st]
-            if dsa:
-                # the sparse latent layers' counts, likewise
-                cache_out[LAUNCH_DSA_STATS] = jnp.stack(dsa)
+            # what the expert layers and the sparse latent layers counted
+            # in this launch, one row a layer, handed back beside the
+            # pools under keys no node has; the caller takes them out
+            # before the pools go into the next launch
+            for stat, key in (("moe_stats", LAUNCH_STATS),
+                              ("dsa_stats", LAUNCH_DSA_STATS)):
+                rows = [st[stat] for _nk, st in sorted(state.items())
+                        if stat in st]
+                if rows:
+                    with jax.named_scope(scopes.UNPACK):
+                        cache_out[key] = jnp.stack(rows)
             return out, cache_out
 
         self._ragged_step_fn = self.compile_tracker.wrap(

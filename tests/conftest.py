@@ -42,6 +42,14 @@ jax.config.update("jax_num_cpu_devices", 8)
 # written about goes, or if one not listed here comes. A `benchmark` issue
 # should make the assertions subsets and delete this with that shim
 # (PERF.md section 7 (iii)).
+# PR 53: the serving step's device time by group, attention part and kind of
+# operation (benchmark/readers/serve_scope.py), in both cells
+_SERVE_SCOPE = tuple(
+    [f"node_ms.{g}.prefill" for g in ("attn", "head", "glue", "experts")]
+    + [f"attn_part_ms.{p}.prefill"
+       for p in ("qkv", "kv_write", "attend", "out")]
+    + ["layout_ms.prefill", "unscoped_share.prefill",
+       "programs_per_launch.prefill"])
 METRICS_ADDED_SINCE = {
     "test_the_new_files_load_and_keep_the_published_widths": (   # PR 27's
         "pool_in_place_share",                  # PR 28
@@ -49,12 +57,12 @@ METRICS_ADDED_SINCE = {
         "one_launch_share",                     # PR 34
         "launch_ahead_share.prefill",           # PR 37
         "uploads_per_launch.prefill",           # PR 50
-    ),
+    ) + _SERVE_SCOPE,
     "test_the_mellum2_files_load_and_keep_the_published_widths": (  # PR 36's
         "launch_ahead_share.prefill",           # PR 37
         "walk_shared_share",                    # PR 49
         "uploads_per_launch.prefill",           # PR 50
-    ),
+    ) + _SERVE_SCOPE,
 }
 
 

@@ -684,3 +684,31 @@ def test_the_family_maps_a_files_keys_and_refuses_what_is_not_built():
             fam.check(dict(cfg, **{key: bad}))
     with pytest.raises(ValueError, match="lacks"):
         fam.check({k: v for k, v in cfg.items() if k != "logits_scaling"})
+
+
+# ---------------------------------------------------------------------------
+# what the step's operations are FOR (tests/serving_scope_checks.py; the
+# other five graph kinds run the same checks in tests/test_launch_packed.py)
+
+
+@pytest.mark.parametrize("launch", ["decode", "chunk"])
+def test_every_heavy_instruction_of_the_step_has_a_group(tiny, launch):
+    import serving_scope_checks as scope_checks
+
+    seen = scope_checks.check_every_heavy_instruction_has_a_group(
+        tiny, launch)
+    assert {g for g, _n in seen if g} == {"attn", "state", "ffn", "head",
+                                           "glue"}
+    # the group is the node's OpType's, not its key's spelling: both kinds
+    # of mixer are named `l<i>_mixer`
+    mixers = {g for g, n in seen if n and "_mixer_" in n and "norm" not in n}
+    assert mixers == {"attn", "state"}
+
+
+@pytest.mark.parametrize("launch", ["decode", "chunk"])
+def test_the_steps_scopes_change_nothing_but_names(tiny, launch,
+                                                   monkeypatch):
+    import serving_scope_checks as scope_checks
+
+    scope_checks.check_scopes_change_nothing_but_names(tiny, launch,
+                                                       monkeypatch)

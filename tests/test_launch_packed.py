@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import serving_scope_checks as scope_checks
 import test_glm5 as glm5_tiny
 import test_ling3 as ling3_tiny
 import test_mellum2 as mellum2_tiny
@@ -286,3 +287,60 @@ def test_warm_up_leaves_nothing_to_compile(graphs, family):
     assert m["compile"]["steady_state_recompiles"] == 0
     assert m["launch_uploads"] >= m["launches_dispatched"] > 5
     assert catalog["entries"]["ragged_step"]["count"] >= 3
+
+
+# ---------------------------------------------------------------------------
+# what the step's operations are FOR: a group and an attention part in every
+# name stack (obs/scopes.py `classify_serving`), on the same five graphs
+
+
+@pytest.mark.parametrize("launch", scope_checks.LAUNCHES)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_heavy_instruction_of_the_step_has_a_group(graphs, family,
+                                                         launch):
+    seen = scope_checks.check_every_heavy_instruction_has_a_group(
+        graphs[family], launch)
+    want = {"mistral-7b": {"attn", "ffn", "head", "glue"},
+            "mistral-small-4": {"attn", "experts", "head", "glue"},
+            "mellum2": {"attn", "experts", "head", "glue"},
+            "ling-3-flash": {"attn", "state", "ffn", "experts", "head",
+                             "glue"},
+            "glm-5.3-flash": {"attn", "state", "ffn", "experts", "head",
+                              "glue"}}[family]
+    assert {g for g, _n in seen if g} == want
+
+
+@pytest.mark.parametrize("launch", scope_checks.LAUNCHES)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_steps_scopes_change_nothing_but_names(graphs, family, launch,
+                                                   monkeypatch):
+    scope_checks.check_scopes_change_nothing_but_names(
+        graphs[family], launch, monkeypatch)
+
+
+def test_hloaudit_lists_a_modules_layout_operations_by_group(graphs):
+    """ROADMAP S10's "name them", repeatable without a chip: the layout
+    operations of a compiled launch shape with the group and the
+    attention part they were made for, a compiler-made copy (no
+    `op_name` of its own) charged to its operand's."""
+    from flexflow_tpu.analysis import hloaudit
+    from flexflow_tpu.obs import scopes
+
+    txt = scope_checks.lower_step(graphs["mistral-7b"],
+                                  "chunk").compile().as_text()
+    rows = hloaudit.layout_operations(txt)
+    assert rows == sorted(rows, key=lambda r: -r["bytes"]) and len(rows) > 4
+    for r in rows:
+        assert hloaudit.is_layout(r["name"], r["opcode"])
+        assert not r["opcode"].endswith("-done")
+        assert r["group"] in scopes.GROUPS, r
+        assert r["bytes"] > 0 and "[" in r["result"]
+        if r["group"] == scopes.ATTN:
+            assert r["part"] in scopes.ATTN_PARTS, r
+    # the gather path lays the gathered pages out for the scores: an
+    # attention node's `attend`, as on the chip the kernel's operands
+    assert {(r["group"], r["part"]) for r in rows} >= {
+        ("attn", "attend"), ("attn", "qkv")}
+    # nothing inside a fusion's body is listed as an operation
+    names = [r["name"] for r in rows]
+    assert len(names) == len(set(names))

@@ -67,6 +67,88 @@ def test_longest_key_wins():
                            keys) == ("forward", "l0_attn_12")
 
 
+# what jax 0.9.0 writes into the SERVING step (CPU lowerings of the tiny
+# graphs of tests/test_launch_packed.py and tests/test_granite4h.py, and the
+# kernels' stacks of a v5e trace, PR 53): (group, node, attention part)
+SERVING_STACKS = [
+    ("jit(step)/attn/l0_attn_1003/qkv/bse,ef->bsf/dot_general",
+     "attn", "l0_attn_1003", "qkv"),
+    ("jit(step)/attn/l0_attn_1003/qkv/mul", "attn", "l0_attn_1003", "qkv"),
+    ("jit(step)/attn/l0_attn_1003/kv_write/scatter",
+     "attn", "l0_attn_1003", "kv_write"),
+    ("jit(step)/attn/l0_attn_1003/attend/jit(ragged_flash_attention)/"
+     "pallas_call", "attn", "l0_attn_1003", "attend"),
+    ("jit(step)/attn/l0_attn_1003/out/bsf,fe->bse/dot_general",
+     "attn", "l0_attn_1003", "out"),
+    # a node's own constraint, outside its parts
+    ("jit(step)/attn/l0_attn_1003/sharding_constraint",
+     "attn", "l0_attn_1003", None),
+    # a sparse latent layer: the family scopes stay where they were, an
+    # operation is its INNERMOST part's
+    ("jit(step)/attn/l1_attn_1019/attend/dsa_index/bshd,bnd->bshn/"
+     "dot_general", "attn", "l1_attn_1019", "attend"),
+    ("jit(step)/attn/l1_attn_1019/attend/dsa_index/kv_write/scatter-add",
+     "attn", "l1_attn_1019", "kv_write"),
+    ("jit(step)/attn/l1_attn_1019/attend/dsa_select/while/body/add",
+     "attn", "l1_attn_1019", "attend"),
+    ("jit(step)/attn/l1_attn_1019/dsa_attend/attend/gather",
+     "attn", "l1_attn_1019", "attend"),
+    ("jit(step)/attn/l1_attn_1019/dsa_attend/kv_write/scatter",
+     "attn", "l1_attn_1019", "kv_write"),
+    # the group is the node's OpType's: Granite names both mixers alike
+    ("jit(step)/attn/l2_mixer_1028/attend/bshd,bthd->bhst/dot_general",
+     "attn", "l2_mixer_1028", "attend"),
+    ("jit(step)/state/l0_mixer_1004/ssd_scan/while", "state",
+     "l0_mixer_1004", None),
+    ("jit(step)/state/l0_mixer_1004/ssd_proj/dot_general", "state",
+     "l0_mixer_1004", None),
+    ("jit(step)/ffn/l0_gate_1006/dot_general", "ffn", "l0_gate_1006", None),
+    ("jit(step)/ffn/l0_silu_1008/jit(silu)/logistic", "ffn", "l0_silu_1008",
+     None),
+    ("jit(step)/experts/l0_moe_1006/dot_general", "experts", "l0_moe_1006",
+     None),
+    ("jit(step)/head/lm_head_1023/dot_general", "head", "lm_head_1023",
+     None),
+    ("jit(step)/glue/l0_attn_hc_pre_1003/hc_mix/dot_general", "glue",
+     "l0_attn_hc_pre_1003", None),
+    ("jit(step)/glue/tok_emb_1001/jit(_take)/gather", "glue", "tok_emb_1001",
+     None),
+    # the step's own work outside its nodes
+    ("jit(step)/unpack/slice", "glue", "unpack", None),
+    ("jit(step)/unpack/jit(_where)/select_n", "glue", "unpack", None),
+    # a step compiled BEFORE the groups names a node and no group: what a
+    # stale compile cache hands back, and a reader reports
+    ("jit(step)/l0_attn_1003/dot_general", None, "l0_attn_1003", None),
+    ("jit(step)/slice", None, None, None),          # the parent's unpack
+    # other programs of a decode tick, a parameter, no stack
+    ("jit(_pick)/argmax", None, None, None),
+    ("jit(split)/jit(_threefry_split)/threefry2x32", None, None, None),
+    ("trainable['l0_attn_1003']['wq']", None, None, None),
+    ("", None, None, None),
+]
+
+
+@pytest.mark.parametrize("stack,group,node,part", SERVING_STACKS,
+                         ids=[s[0][-44:] or "empty" for s in SERVING_STACKS])
+def test_classify_serving_pins_the_name_stacks(stack, group, node, part):
+    assert scopes.classify_serving(stack) == (group, node, part)
+    # the training reader's view of the same stack is what it was: no
+    # phase, since the serving step enters none of the top-level scopes
+    assert scopes.classify(stack)[0] is None
+
+
+def test_the_parts_and_groups_are_the_ones_the_program_names():
+    from flexflow_tpu.paged import attention as pa
+
+    assert (pa.QKV, pa.KV_WRITE, pa.ATTEND, pa.OUT) == scopes.ATTN_PARTS
+    assert scopes.GROUPS == (scopes.ATTN, scopes.FFN, scopes.EXPERTS,
+                             scopes.STATE, scopes.HEAD, scopes.GLUE)
+    # no group, part or `unpack` can be mistaken for a node's key, which
+    # ends in its guid
+    assert not any(re.search(r"_\d+$", s) for s in
+                   scopes.GROUPS + scopes.ATTN_PARTS + (scopes.UNPACK,))
+
+
 GROUPS = [
     ("replica_groups={{0,1},{2,3}}", [[0, 1], [2, 3]], ("model",)),
     ("replica_groups={{0,2},{1,3}}", [[0, 2], [1, 3]], ("data",)),
@@ -238,3 +320,60 @@ def test_steps_compile_under_a_key_that_holds_their_scopes():
     assert getattr(jax.config, flag) is False
     step()
     assert seen == [True] and getattr(jax.config, flag) is False
+
+
+# ---------------------------------------------------------------------------
+# a compiled SERVING module's layout operations (analysis/hloaudit.py), on
+# the lines a v5e module of the Mistral-7B cell holds (compiled HERE for the
+# described chip, PR 53), cut to what the parse reads
+
+_SERVING_MODULE = """
+HloModule jit_step, is_scheduled=true
+
+%fused_computation.5 (param_0.1: bf16[4096,32,128]) -> bf16[4096,4096] {
+  %param_0.1 = bf16[4096,32,128]{2,1,0} parameter(0)
+  %copy.900 = bf16[4096,32,128]{0,2,1} copy(%param_0.1)
+  ROOT %bitcast.9 = bf16[4096,4096]{0,1} bitcast(%copy.900)
+}
+
+ENTRY %main.54 (packed.1: s32[15,76], w: bf16[4096,32,128]) -> bf16[15,8] {
+  %trainable__l0_attn_1003____wq__.1 = bf16[4096,32,128]{2,1,0} parameter(1), metadata={op_name="trainable['l0_attn_1003']['wq']"}
+  %trainable__l0_attn_1003____wo__.1 = bf16[32,128,4096]{2,1,0} parameter(2), metadata={op_name="trainable['l0_attn_1003']['wo']"}
+  %copy-start = (bf16[32,128,4096]{2,1,0:S(1)}, bf16[32,128,4096]{2,1,0}, u32[]{:S(2)}) copy-start(%trainable__l0_attn_1003____wo__.1), cross_program_prefetch_index=0
+  %bitcast.490 = bf16[4096,4096]{1,0} bitcast(%trainable__l0_attn_1003____wq__.1)
+  %bitcast_bitcast_fusion.5 = bf16[4096,4096]{0,1:T(8,128)(2,1)S(1)} fusion(%bitcast.490), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(step)/attn/l0_attn_1003/qkv/bse,ef->bsf/dot_general"}
+  %copy.92 = bf16[4096,4096]{1,0:T(8,128)(2,1)S(1)} copy(%bitcast_bitcast_fusion.5)
+  %fusion.256 = bf16[15,8,32,128]{3,2,1,0} fusion(%copy.92), kind=kOutput, calls=%fused_computation.214, metadata={op_name="jit(step)/attn/l0_attn_1003/qkv/bse,ef->bsf/dot_general"}
+  %ragged_paged_attention.12 = bf16[15,8,32,128]{3,2,1,0} custom-call(%fusion.256), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/attn/l0_attn_1003/attend/jit(ragged_flash_attention)/pallas_call"}
+  %copy-done = bf16[32,128,4096]{2,1,0:S(1)} copy-done(%copy-start)
+  %fusion.300 = bf16[15,8,4096]{2,1,0} fusion(%ragged_paged_attention.12, %copy-done), kind=kOutput, calls=%fused_computation.215, metadata={op_name="jit(step)/attn/l0_attn_1003/out/bsf,fe->bse/dot_general"}
+  ROOT %slice.7 = bf16[15,8]{1,0} slice(%fusion.300), slice={[0:15], [0:8], [0:1]}, metadata={op_name="jit(step)/head/softmax_1064/slice"}
+}
+"""
+
+
+def test_hloaudit_names_a_serving_modules_layout_operations():
+    from flexflow_tpu.analysis import hloaudit
+
+    rows = {r["name"]: r for r in
+            hloaudit.layout_operations(_SERVING_MODULE)}
+    # a fusion's body, a `-done` and a free `bitcast` are not operations
+    assert set(rows) == {"copy-start", "bitcast_bitcast_fusion.5",
+                         "copy.92", "slice.7"}
+    relaid = rows["bitcast_bitcast_fusion.5"]
+    assert (relaid["group"], relaid["node"], relaid["part"]) == (
+        "attn", "l0_attn_1003", "qkv")
+    assert relaid["result"] == "bf16[4096,4096]{0,1:T(8,128)(2,1)S(1)}"
+    assert relaid["bytes"] == 4096 * 4096 * 2
+    # a copy the compiler made names nothing: its operand's node and part
+    assert (rows["copy.92"]["group"], rows["copy.92"]["part"]) == (
+        "attn", "qkv")
+    assert rows["copy.92"]["operand"] == "%bitcast_bitcast_fusion.5"
+    # a weight's prefetch: the parameter's own `op_name` is its path in the
+    # arguments and says nothing; the pair is charged to the node whose
+    # key the parameter's name holds, and to the part that runs where its
+    # `-done` stands (`out`, not the `qkv` that follows its `-start`)
+    pre = rows["copy-start"]
+    assert (pre["group"], pre["node"], pre["part"]) == (
+        "attn", "l0_attn_1003", "out")
+    assert rows["slice.7"]["group"] == "head"
